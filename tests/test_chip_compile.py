@@ -1,0 +1,154 @@
+"""The main path's Pallas kernels compile for the chip — without the chip.
+
+The TPU compiler is installed here and compiles for a ``v5e:2x2`` that is
+DESCRIBED, not attached, so these catch what interpret mode cannot: a
+kernel with no grid whose operands do not fit VMEM (``fused_sgd_update``
+at AlexNet's fc6/fc7 before it got its row grid), a slice not aligned to
+the tiling, a dot precision Mosaic refuses.  About two seconds each, at
+the real widths of the models chip_smoke.py runs.  The whole-program
+compiles (AlexNet step, LM engine programs; up to minutes) live in
+tools/aot_compile.py and are run by hand.
+
+ALL of these stay in this ONE file, and the topology is described inside
+a fixture: only one process may load the TPU library, so under xdist only
+the worker that is handed this file may touch it — never at import, in a
+``skipif`` or in ``parametrize``.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from veles_tpu.ops import pallas_kernels as PK
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — no TPU compiler here
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_compile_cache():
+    """A compile for a described device is written to jax's persistent
+    cache but cannot be read back without the chip; keep it off here."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def compile_for(one_chip, fn, *shapes):
+    """Compile ``fn`` for the described chip; returns the compiled text
+    (raises what the chip's compiler would raise)."""
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
+
+
+@pytest.mark.parametrize("shape", [(784, 100), (4096, 4096), (9216, 4096)],
+                         ids=["mnist", "alexnet_fc7", "alexnet_fc6"])
+def test_fused_sgd_update_compiles(one_chip, shape):
+    def update(p, v, g):
+        return PK.fused_sgd_update(p, v, g, jnp.int32(128), 0.01, 0.9,
+                                   0.0005, 0.0, interpret=False)
+    text = compile_for(one_chip, update, *[(shape, F32)] * 3)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("shape", [(128, 55, 55, 96), (128, 27, 27, 256)],
+                         ids=["alexnet_lrn1", "alexnet_lrn2"])
+def test_lrn_forward_and_vjp_compile(one_chip, shape):
+    def lrn(a):
+        return PK.lrn_forward(a, 1e-4, 0.75, 5, 2.0, False)
+
+    def backward(x, dy):
+        return jax.vjp(lrn, x)[1](dy)[0]
+    assert "tpu_custom_call" in compile_for(one_chip, lrn, (shape, F32))
+    text = compile_for(one_chip, backward, (shape, F32), (shape, F32))
+    assert text.count("tpu_custom_call") == 2      # forward + backward
+
+
+def test_dropout_compiles(one_chip):
+    text = compile_for(
+        one_chip, lambda x: PK.dropout(x, 7, 0.5, interpret=False),
+        ((128, 4096), F32))
+    assert "tpu_custom_call" in text
+
+
+def paged_shapes(heads, kv, page, dtype, chunk, slots=8, dh=128,
+                 max_len=2048):
+    m = max_len // page
+    pool = ((slots * m + 1, kv, page, dh), dtype)
+    return dict(q=((slots, heads, chunk, dh), dtype),
+                new=((slots, kv, chunk, dh), dtype), pool=pool,
+                ptab=((slots, m), I32), pos=((slots,), I32))
+
+
+PAGED = [(16, 16, 16, F32), (16, 16, 32, F32), (16, 16, 128, F32),
+         (16, 16, 16, BF16), (16, 16, 32, BF16), (32, 4, 16, F32)]
+PAGED_IDS = ["mha_p16_f32", "mha_p32_f32", "mha_p128_f32", "mha_p16_bf16",
+             "mha_p32_bf16", "gqa32x4_p16_f32"]
+
+
+@pytest.mark.parametrize("heads,kv,page,dtype", PAGED, ids=PAGED_IDS)
+def test_paged_flash_decode_compiles(one_chip, heads, kv, page, dtype):
+    s = paged_shapes(heads, kv, page, dtype, chunk=1)
+    text = compile_for(
+        one_chip,
+        lambda q, k, v, pt, ps: PK.paged_flash_decode(
+            q, k, v, pt, ps, interpret=False),
+        s["q"], s["pool"], s["pool"], s["ptab"], s["pos"])
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("heads,kv,page,dtype", PAGED, ids=PAGED_IDS)
+def test_paged_flash_prefill_compiles(one_chip, heads, kv, page, dtype):
+    s = paged_shapes(heads, kv, page, dtype, chunk=page)
+    text = compile_for(
+        one_chip,
+        lambda q, kn, vn, k, v, pt, ps: PK.paged_flash_prefill(
+            q, kn, vn, k, v, pt, ps, interpret=False),
+        s["q"], s["new"], s["new"], s["pool"], s["pool"], s["ptab"],
+        s["pos"])
+    assert "tpu_custom_call" in text
+
+
+def test_flash_attention_tpu_traces_at_policy_precision(one_chip,
+                                                        monkeypatch):
+    """The bundled flash-attention kernel names no dot precision; under
+    the fp32 policy every dot, forward and backward, must be traced at
+    HIGHEST (Mosaic's default is bf16 passes: 1e-2 off on the chip), and
+    the chip's compiler must take them."""
+    from veles_tpu.ops import attention as A
+    monkeypatch.setattr(PK, "on_tpu", lambda: True)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: A.flash_attention_tpu(*a).sum(),
+                        (0, 1, 2))(q, k, v)
+    shape = ((2, 4, 256, 64), F32)
+    jaxpr = str(jax.make_jaxpr(grads)(
+        *[jax.ShapeDtypeStruct(*shape)] * 3))
+    assert jaxpr.count("dot_general") == jaxpr.count(
+        "precision=(Precision.HIGHEST, Precision.HIGHEST)") == 9
+    text = compile_for(one_chip, grads, shape, shape, shape)
+    assert text.count("tpu_custom_call") == 3      # forward, dkv, dq

@@ -97,13 +97,13 @@ class TestPallasDropout:
         numpy.testing.assert_array_equal(numpy.asarray(PK.dropout(x, 1, 0.0)),
                                          numpy.asarray(x))
 
-    @pytest.mark.skipif(not PK.on_tpu(),
-                        reason="real-kernel path needs the TPU PRNG")
     @pytest.mark.parametrize("rate", [0.3, 0.5, 0.7])
     def test_real_kernel_statistics(self, rate):
         """Keep fraction of the NON-interpret kernel — the signed int32
         random bits must be compared in the signed domain (the unsigned
         misread made rate<=0.5 a silent no-op on hardware)."""
+        if not PK.on_tpu():
+            pytest.skip("real-kernel path needs the TPU PRNG")
         keep_prob = 1.0 - rate
         x = jnp.ones((256, 512), jnp.float32)
         out = numpy.asarray(PK.dropout(x, 5, rate, interpret=False))
@@ -530,18 +530,30 @@ class TestFlashAttentionTPUCoverage:
         assert not A.serving_kernel_default()
         numpy.testing.assert_array_equal(got, ref)
 
-    @pytest.mark.skipif(not PK.on_tpu(),
-                        reason="the bundled kernel has no CPU lowering")
     def test_matches_attention_on_tpu(self):
         """The hardware parity pin: the bundled kernel vs our
         ``attention`` oracle at serving-ish shape."""
+        if not PK.on_tpu():
+            pytest.skip("the bundled kernel has no CPU lowering")
         from veles_tpu.ops import attention as A
         key = jax.random.PRNGKey(2)
         q = jax.random.normal(key, (2, 4, 256, 64), jnp.float32)
         k = jax.random.normal(jax.random.fold_in(key, 1), q.shape)
         v = jax.random.normal(jax.random.fold_in(key, 2), q.shape)
+        w = jax.random.normal(jax.random.fold_in(key, 3), q.shape)
+
+        def grads(attend):
+            return jax.grad(
+                lambda *a: (attend(*a, causal=True) * w).sum(),
+                (0, 1, 2))(q, k, v)
         ref = A.attention(q, k, v, causal=True)
         got = A.flash_attention_tpu(q, k, v, causal=True)
         numpy.testing.assert_allclose(numpy.asarray(got),
                                       numpy.asarray(ref),
                                       rtol=2e-3, atol=2e-3)
+        # the backward kernels run under the fp32 policy too
+        for g, r in zip(grads(A.flash_attention_tpu),
+                        grads(A.attention)):
+            numpy.testing.assert_allclose(numpy.asarray(g),
+                                          numpy.asarray(r),
+                                          rtol=2e-3, atol=2e-3)

@@ -247,6 +247,34 @@ class TestRuntimeGauges:
         assert frac == pytest.approx(4 / 16)
 
 
+class TestPeaksTable:
+    """ONE peaks table, keyed by device kind; a TPU that is not in it
+    is an error, never a default (bench.py reads the same table)."""
+
+    @pytest.mark.parametrize("kind,peak", [
+        ("TPU v5 lite", 197e12), ("TPU v5e", 197e12), ("TPU v4", 275e12)])
+    def test_known_kinds(self, kind, peak):
+        from veles_tpu.serving.timeseries import tpu_peak_flops
+        assert tpu_peak_flops(kind)[0] == peak
+
+    def test_unknown_tpu_kind_raises(self, monkeypatch):
+        import jax
+        from veles_tpu.ops import pallas_kernels
+        from veles_tpu.serving import timeseries as ts
+
+        class _Device:
+            platform, device_kind = "tpu", "TPU v99"
+        with pytest.raises(ValueError, match="TPU v99"):
+            ts.tpu_peak_flops("TPU v99")
+        monkeypatch.delenv("VELES_PEAK_FLOPS", raising=False)
+        monkeypatch.setattr(pallas_kernels, "on_tpu", lambda: True)
+        monkeypatch.setattr(jax, "devices", lambda *a: [_Device()])
+        with pytest.raises(ValueError, match="TPU_PEAK_FLOPS"):
+            ts.peak_flops_estimate()
+        monkeypatch.setenv("VELES_PEAK_FLOPS", "1e12")
+        assert ts.peak_flops_estimate() == (1e12, "env:VELES_PEAK_FLOPS")
+
+
 class TestLiveLedger:
     def test_live_ledger_equals_ring_and_trace_report(self, tmp_path):
         """The acceptance criterion: the tracer's incrementally-

@@ -79,7 +79,7 @@ def test_check_stream_records_builtin_contract():
     summary_record — bench.py, lm_bench, chaos_bench, profile_ops,
     trace_report — carries the shared required keys even for the
     empty-results worst case, so a schema drift fails HERE instead of
-    silently breaking bench_report.py."""
+    silently breaking whoever reads the stream."""
     import check_stream_records
     assert check_stream_records.check_builtin() == []
 

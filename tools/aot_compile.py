@@ -1,0 +1,226 @@
+"""Compile the chip smoke's step programs for a DESCRIBED TPU, no chip attached.
+
+The third rehearsal before a chip call (README "Verify"): the TPU compiler
+is installed here and compiles for a ``v5e:2x2`` that is described, not
+attached — what it refuses here costs no chip time.  tests/
+test_chip_compile.py keeps the two-second kernel compiles in tier-1; this
+script holds the whole-program ones, which take up to minutes and are run
+by hand::
+
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \\
+        python tools/aot_compile.py [alexnet] [lm] [mesh] [tp]
+
+- ``alexnet``: the graph loop's train step and the epoch-scan window
+  program at minibatch 128, 227x227 crops, 1000 classes, fp32 and bf16;
+- ``lm``: the char_lm train step and the engine's prefill-chunk and decode
+  programs at d_model 2048 / 16 heads / 4 layers / vocab 32768, Pallas
+  serving kernels active;
+- ``mesh``: the ``ShardedTrainer`` AlexNet step on a data 2 x model 2 mesh
+  (checks for an all-reduce);
+- ``tp``: the ``LMEngine(tp=4)`` decode program over four chips.
+
+Nothing runs, so this says nothing about results or times.  Code under
+trace that asks ``on_tpu()`` sees the CPU; the script steers it (the
+program has no option for that).
+"""
+
+from __future__ import annotations
+
+import os
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import (NamedSharding, PartitionSpec as P,  # noqa: E402
+                          SingleDeviceSharding)
+
+import chip_smoke  # noqa: E402
+
+MB, HW, CLASSES = 128, (256, 256), 1000
+LM = chip_smoke.FULL_LM
+
+
+def abstract(tree, sharding):
+    """Shapes of ``tree`` placed by ``sharding`` (one sharding, or a
+    matching tree of them)."""
+    if not isinstance(sharding, jax.sharding.Sharding):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree, sharding)
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), tree)
+
+
+def compile_(name, jitted, *args):
+    begin = time.time()
+    compiled = jitted.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    print("%-34s OK %6.1fs  args %.2f GB  temp %.2f GB  out %.2f GB  "
+          "tpu_custom_call x%d  all-reduce x%d"
+          % (name, time.time() - begin,
+             mem.argument_size_in_bytes / 1e9, mem.temp_size_in_bytes / 1e9,
+             mem.output_size_in_bytes / 1e9, text.count("tpu_custom_call"),
+             text.count("all-reduce(") + text.count("all-reduce-start(")),
+          flush=True)
+    return text
+
+
+def build_alexnet():
+    from veles_tpu import prng
+    from veles_tpu.config import root
+    from veles_tpu.samples import imagenet
+    prng.reset()
+    prng.seed_all(1)
+    root.__dict__.pop("imagenet", None)
+    root.imagenet.update({
+        "loader": {"minibatch_size": MB, "n_train": MB, "n_valid": MB,
+                   "image_hw": HW, "n_classes": CLASSES},
+        "decision": {"max_epochs": 1, "fail_iterations": 5},
+        "layers": imagenet.alexnet_layers(),
+    })
+    wf = imagenet.build(fused=True)
+    wf.initialize()
+    return wf._fused_runner
+
+
+def step_args(sharding, batch_sharding=None):
+    s = lambda shape, dt, sh=sharding: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=sh)
+    b = batch_sharding or sharding
+    return (s((MB,) + HW + (3,), jnp.float32, b), s((MB,), jnp.int32, b),
+            s((MB,), jnp.float32, b), s((), jnp.int32),
+            s((2,), jnp.uint32), s((), jnp.int32))
+
+
+def alexnet(one_chip):
+    from veles_tpu.ops import functional as F
+    window = 3
+    for precision in ("float32", "bfloat16"):
+        with F.matmul_precision(precision):
+            runner = build_alexnet()
+            state = abstract(runner.state, one_chip)
+            compile_("alexnet step %s" % precision,
+                     jax.jit(runner._step_fn), state, *step_args(one_chip))
+            s = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+                shape, dt, sharding=one_chip)
+            compile_("alexnet epoch-scan window %s" % precision,
+                     jax.jit(runner._epoch_train), state,
+                     s((window * MB,) + HW + (3,), jnp.float32),
+                     s((window * MB,), jnp.int32),
+                     s((window, MB), jnp.int32),
+                     s((window, MB), jnp.float32), s((2,), jnp.uint32),
+                     s((), jnp.int32))
+
+
+def mesh(topo):
+    from veles_tpu.parallel import (ShardedTrainer, make_mesh,
+                                    model_shard_candidates)
+
+    class AbstractTrainer(ShardedTrainer):
+        def _put(self, arr, sharding):      # shapes, not arrays
+            return None if arr is None else jax.ShapeDtypeStruct(
+                arr.shape, arr.dtype, sharding=sharding)
+
+    runner = build_alexnet()
+    trainer = AbstractTrainer(
+        runner, make_mesh(4, model_parallel=2, devices=topo.devices),
+        model_shard_layers=model_shard_candidates(runner, min_width=4096))
+    text = compile_("alexnet step data2 x model2", trainer._train,
+                    trainer.state,
+                    *step_args(trainer._repl, trainer._batch))
+    if "all-reduce" not in text:
+        raise SystemExit("no all-reduce in the compiled mesh step")
+
+
+def build_lm():
+    wf = chip_smoke._build_char_lm(1, LM, run=False)
+    trainer = wf.trainer
+    return trainer, trainer._to_portable(trainer.params)
+
+
+def lm(one_chip):
+    from veles_tpu.ops import pallas_kernels as PK
+    from veles_tpu.serving import LMEngine
+    trainer, params = build_lm()
+    s = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    compile_("char_lm train step", trainer._train,
+             abstract(trainer.params, one_chip),
+             abstract(trainer.opt_state, one_chip),
+             s((LM["minibatch"], LM["seq_len"]), jnp.int32),
+             s((LM["minibatch"],), jnp.float32), s((), jnp.int32))
+    PK.on_tpu = lambda: True        # trace the compiled-kernel branch
+    slots, page = 8, 32
+    eng = LMEngine(params, n_heads=trainer.n_heads, max_len=LM["max_len"],
+                   slots=slots, prefill_chunk=page, paged_kv=True,
+                   attn_kernel="auto")
+    if not eng._kernel_active:
+        raise SystemExit("the engine did not select the Pallas kernels")
+    a_params = abstract(eng.params, one_chip)
+    pools = abstract(eng._kv_pools, one_chip)
+    m = LM["max_len"] // page
+    compile_("engine prefill chunk (kernel)", eng._chunk_jit,
+             a_params, pools, s((m,), jnp.int32), s((page,), jnp.int32),
+             s((), jnp.int32), s((), jnp.int32))
+    for width in (1, 8, m):
+        text = compile_("engine decode step width %d" % width,
+                        eng._step_jit, a_params, pools,
+                        s((slots, width), jnp.int32),
+                        s((slots,), jnp.int32), s((slots,), jnp.int32))
+        if "tpu_custom_call" not in text:
+            raise SystemExit("no Pallas kernel in the decode program")
+
+
+def tp(topo):
+    from veles_tpu.ops.transformer import lm_param_specs
+    from veles_tpu.parallel import make_tp_mesh
+    from veles_tpu.serving import LMEngine
+    trainer, params = build_lm()
+    slots, page = 8, 32
+    eng = LMEngine(params, n_heads=trainer.n_heads, max_len=LM["max_len"],
+                   slots=slots, prefill_chunk=page, paged_kv=True,
+                   attn_kernel="auto", tp=4, devices=jax.devices()[:4])
+    tmesh = make_tp_mesh(4, devices=topo.devices)
+    on = lambda spec: NamedSharding(tmesh, spec)  # noqa: E731
+    a_params = abstract(eng.params, jax.tree.map(
+        on, lm_param_specs(eng.params)))
+    kv = on(eng._kv_shard.spec)
+    pools = abstract(eng._kv_pools, kv)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=on(P()))
+    # the engine pinned its out_shardings to the CPU mesh: re-jit the same
+    # function with the described mesh's
+    step = jax.jit(eng._step_jit.__wrapped__, out_shardings=(
+        [(kv, kv)] * len(eng.params["blocks"]), on(P())))
+    compile_("engine decode step tp=4", step, a_params, pools,
+             s((slots, 8), jnp.int32), s((slots,), jnp.int32),
+             s((slots,), jnp.int32))
+
+
+def main(argv):
+    from jax.experimental import topologies
+    want = argv or ["alexnet", "lm", "mesh", "tp"]
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    print("compiling for %s (%d described devices)"
+          % (topo.devices[0].device_kind, len(topo.devices)), flush=True)
+    if "alexnet" in want:
+        alexnet(one_chip)
+    if "lm" in want:
+        lm(one_chip)
+    if "mesh" in want:
+        mesh(topo)
+    if "tp" in want:
+        tp(topo)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

@@ -47,7 +47,9 @@ def build_argparser():
                              "none exists) — crash recovery")
     parser.add_argument("-d", "--device", default=None,
                         choices=("tpu", "cpu"),
-                        help="JAX platform to run on (default: auto)")
+                        help="JAX platform to run on (default: jax's own "
+                             "choice, which is the CPU where it finds no "
+                             "chip — the launcher logs what it got)")
     parser.add_argument("--epoch-scan", type=int, default=0, nargs="?",
                         const=1, metavar="CHUNK",
                         help="train via the epoch-scan driver: each "
@@ -430,11 +432,13 @@ def main(argv=None):
     args.overrides = list(args.overrides) + extra
 
     if args.device:
-        # must win before the first jax import; a sitecustomize may force a
-        # plugin platform, so also set the config knob once jax loads
+        # the env var covers a jax not imported yet, the config knob one
+        # that is; a platform jax cannot start is an error, not a fallback
         os.environ["JAX_PLATFORMS"] = args.device
         import jax
         jax.config.update("jax_platforms", args.device)
+    from veles_tpu import compile_cache
+    compile_cache.enable(before_distributed_init=args.distributed)
 
     if args.list_units:
         from veles_tpu.units import UnitRegistry

@@ -97,6 +97,10 @@ class Launcher(Logger):
             self.info("joined distributed run as process %d/%d "
                       "(%d-device mesh)", index, count,
                       mesh.devices.size)
+        import jax
+        device = jax.devices()[0]
+        self.info("running on %s (%s), %d device(s)", device.platform,
+                  device.device_kind, jax.device_count())
         wf.initialize(**kwargs)
         if mesh is not None:
             runner = getattr(wf, "_fused_runner", None)

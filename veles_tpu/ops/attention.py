@@ -23,6 +23,8 @@ module is BEYOND-PARITY capability, designed TPU-first rather than ported:
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -173,22 +175,55 @@ def blockwise_attention(q, k, v, block_size=128, causal=False,
     return o / l[..., None]
 
 
+def _bundled_flash(q, k, v, causal):
+    from jax.experimental.pallas.ops.tpu.flash_attention import (
+        flash_attention)
+    return flash_attention(q, k, v, causal=causal,
+                           sm_scale=float(1.0 / (q.shape[-1] ** 0.5)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _flash_highest(q, k, v, causal):
+    """The bundled kernel with every dot at HIGHEST.  Its dots name no
+    precision, so they take jax's default at TRACE time; the backward
+    kernels are traced when the cotangent is pulled back, outside any
+    context around the forward call — hence the vjp of our own."""
+    with jax.default_matmul_precision("highest"):
+        return _bundled_flash(q, k, v, causal)
+
+
+def _flash_highest_fwd(q, k, v, causal):
+    with jax.default_matmul_precision("highest"):
+        return jax.vjp(functools.partial(_bundled_flash, causal=causal),
+                       q, k, v)
+
+
+def _flash_highest_bwd(causal, pullback, g):
+    with jax.default_matmul_precision("highest"):
+        return pullback(g)
+
+
+_flash_highest.defvjp(_flash_highest_fwd, _flash_highest_bwd)
+
+
 def flash_attention_tpu(q, k, v, causal=True):
     """The official TPU Pallas flash-attention kernel (bundled with jax)
     as a drop-in for ``attention``: (b, h, s, dh) in/out, our scaling
     convention (1/√dh) applied via sm_scale.  TPU-only — the kernel has
     no interpret-mode escape hatch, so off-TPU callers get a loud error
-    instead of a silent fallback."""
+    instead of a silent fallback.  Mosaic's default dot precision runs
+    fp32 operands through bf16 passes (max abs 0.0117 off ``attention``
+    at (2, 4, 256, 64) on a v5e), so under the fp32 policy the kernel is
+    traced at HIGHEST — the same repair as ``pallas_kernels._flash_step``."""
+    from veles_tpu.ops import functional as F
     from veles_tpu.ops.pallas_kernels import on_tpu
     if not on_tpu():
         raise RuntimeError("flash_attention_tpu needs a TPU backend "
                            "(the bundled Pallas kernel has no CPU "
                            "lowering); use attention/blockwise_attention")
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        flash_attention)
-    dh = q.shape[-1]
-    return flash_attention(q, k, v, causal=causal,
-                           sm_scale=float(1.0 / (dh ** 0.5)))
+    if q.dtype == jnp.float32 and F._PRECISION == jax.lax.Precision.HIGHEST:
+        return _flash_highest(q, k, v, causal)
+    return _bundled_flash(q, k, v, causal)
 
 
 def rolling_slot_update(slot_pos, pos, window, sinks=0):

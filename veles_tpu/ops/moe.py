@@ -105,7 +105,6 @@ def moe_ffn_ep(params, x, mesh, expert_axis="expert"):
     Numerically equals :func:`moe_ffn`.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from veles_tpu.compat import shard_map
 
     n = mesh.shape[expert_axis]
     n_experts = params["w1"].shape[0]
@@ -131,9 +130,9 @@ def moe_ffn_ep(params, x, mesh, expert_axis="expert"):
         return out.reshape(xloc.shape)
 
     espec = P(expert_axis)
-    fn = shard_map(run, mesh=mesh,
-                   in_specs=(P(), espec, espec, espec, espec, P()),
-                   out_specs=P(), check_vma=False)
+    fn = jax.shard_map(run, mesh=mesh,
+                       in_specs=(P(), espec, espec, espec, espec, P()),
+                       out_specs=P(), check_vma=False)
     put = lambda a: jax.device_put(a, NamedSharding(mesh, espec))  # noqa
     return fn(jax.device_put(params["router"], NamedSharding(mesh, P())),
               put(params["w1"]), put(params["b1"]),

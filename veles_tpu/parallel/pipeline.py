@@ -68,7 +68,6 @@ def pipeline_blocks(stacked_blocks, h, mesh, n_heads, n_microbatches,
     ``stacked_blocks`` must divide by the stage count.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from veles_tpu.compat import shard_map
 
     n_stages = mesh.shape["stage"]
     n_layers = jax.tree.leaves(stacked_blocks)[0].shape[0]
@@ -118,8 +117,8 @@ def pipeline_blocks(stacked_blocks, h, mesh, n_heads, n_microbatches,
         return jax.lax.psum(
             jnp.where(stage == n - 1, outs, jnp.zeros_like(outs)), "stage")
 
-    fn = shard_map(run, mesh=mesh, in_specs=(P("stage"), P()),
-                   out_specs=P(), check_vma=False)
+    fn = jax.shard_map(run, mesh=mesh, in_specs=(P("stage"), P()),
+                       out_specs=P(), check_vma=False)
     want = NamedSharding(mesh, P("stage"))
     leaf = jax.tree.leaves(stacked_blocks)[0]
     already_placed = (

@@ -27,8 +27,6 @@ def make_seq_mesh(n_devices=None, data_parallel=1, devices=None):
     """(data, seq) mesh: batch over 'data', sequence ring over 'seq'."""
     import numpy
     from jax.sharding import Mesh
-    from veles_tpu.compat import ensure_partitionable_rng
-    ensure_partitionable_rng()
     devices = list(devices if devices is not None else jax.devices())
     n = n_devices or len(devices)
     if n % data_parallel:
@@ -118,18 +116,15 @@ def ring_attention(q, k, v, mesh, causal=True, seq_axis="seq",
     O(s_local + W) keys per query block rather than O(seq).
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from veles_tpu.compat import shard_map
 
     if window and not causal:
         raise ValueError("window requires causal=True")
     spec = P(data_axis, None, seq_axis, None)
-    # check_vma=False: jax 0.4.x's replication checker cannot unify the
-    # two branches of the early-exit lax.cond under grad ("mismatched
-    # replication types"); the check is a static analysis only — every
+    # check_vma=False: the check is a static analysis only — every
     # array here is device-varying along the ring anyway, so disabling
     # it changes nothing numerically (forward+grad parity pinned in
     # tests/test_attention.py)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_attention_local, axis_name=seq_axis,
                           causal=causal, window=window, sinks=sinks),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

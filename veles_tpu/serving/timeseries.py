@@ -62,13 +62,15 @@ from veles_tpu.logger import Logger
 from veles_tpu.serving import lockcheck
 from veles_tpu.serving.metrics import ServingMetrics, monotonic_offset
 
-#: advertised peak FLOPs by TPU device kind (bf16 matmul peak — the
-#: MFU denominator convention; fp32 serving reads lower, which only
-#: makes the reported MFU conservative).  Overridable via
-#: VELES_PEAK_FLOPS for new silicon or calibrated CPU baselines.
+#: THE peaks table: advertised bf16 matmul peak FLOP/s per chip by
+#: ``device_kind`` substring (Google Cloud TPU documentation, per-chip
+#: figures) — the MFU denominator convention; fp32 serving reads lower,
+#: which only makes the reported MFU conservative.  bench.py reads it
+#: too.  A kind that is not here is an error, not a default
+#: (VELES_PEAK_FLOPS covers new silicon).
 TPU_PEAK_FLOPS = (
     ("v5 lite", 197e12), ("v5e", 197e12), ("v5p", 459e12),
-    ("v4", 275e12), ("v6", 918e12),
+    ("v4", 275e12), ("v6", 918e12), ("v7", 2300e12),
 )
 #: nominal single-core CPU matmul ceiling — keeps the MFU column
 #: well-defined (and honestly tiny) on CPU runs; real MFU claims come
@@ -76,22 +78,31 @@ TPU_PEAK_FLOPS = (
 CPU_NOMINAL_FLOPS = 1e11
 
 
+def tpu_peak_flops(device_kind):
+    """(peak_flops, table_key) of a TPU ``device_kind``; raises on a
+    kind the table does not hold."""
+    kind = device_kind.lower()
+    for name, peak in TPU_PEAK_FLOPS:
+        if name in kind:
+            return peak, name
+    raise ValueError(
+        "TPU device kind %r is not in timeseries.TPU_PEAK_FLOPS: add its "
+        "published peak there (or set VELES_PEAK_FLOPS)" % (device_kind,))
+
+
 def peak_flops_estimate():
     """(peak_flops, source_label) for the MFU denominator: the env
-    override wins, then the TPU device-kind table, then the CPU
-    nominal.  The label travels in every record so a reader can tell a
-    calibrated number from a nominal one."""
+    override wins, then the TPU device-kind table (an unknown kind
+    raises), then the CPU nominal.  The label travels in every record
+    so a reader can tell a calibrated number from a nominal one."""
     import jax
     env = os.environ.get("VELES_PEAK_FLOPS")
     if env:
         return float(env), "env:VELES_PEAK_FLOPS"
     from veles_tpu.ops.pallas_kernels import on_tpu
     if on_tpu():
-        kind = jax.devices()[0].device_kind.lower()
-        for name, peak in TPU_PEAK_FLOPS:
-            if name in kind:
-                return peak, "tpu:%s" % name
-        return 197e12, "tpu:unknown-kind-default"
+        peak, name = tpu_peak_flops(jax.devices()[0].device_kind)
+        return peak, "tpu:%s" % name
     return CPU_NOMINAL_FLOPS, "cpu:nominal"
 
 
