@@ -1,7 +1,7 @@
 """Continuous telemetry + SLO burn-rate alerting (ISSUE 14): the
 time-series store over the serving metrics, runtime/device gauges, the
-tracer's incremental cost ledger, the SLO state machine and its
-health-checker hook, and the new HTTP endpoints."""
+SLO state machine and its health-checker hook, and the new HTTP
+endpoints."""
 
 import json
 import os
@@ -273,68 +273,6 @@ class TestPeaksTable:
             ts.peak_flops_estimate()
         monkeypatch.setenv("VELES_PEAK_FLOPS", "1e12")
         assert ts.peak_flops_estimate() == (1e12, "env:VELES_PEAK_FLOPS")
-
-
-class TestLiveLedger:
-    def test_live_ledger_equals_ring_and_trace_report(self, tmp_path):
-        """The acceptance criterion: the tracer's incrementally-
-        maintained ledger is EXACTLY the ring-aggregated cost_ledger
-        on the same traced run (same rows, same dedup-by-did counts,
-        same rounded quantiles), and matches tools/trace_report.py's
-        rebuild from the Chrome export (counts exact; durations to
-        the export's 0.1 us rounding)."""
-        from veles_tpu.serving import (LMEngine, ServingMetrics,
-                                       SpanTracer)
-        import trace_report
-        params = _tiny_params()
-        tracer = SpanTracer(mode="all", last=64)
-        engine = LMEngine(params, n_heads=2, max_len=48, slots=2,
-                          prefill_chunk=8, spec_k=2, name="led_t",
-                          metrics=ServingMetrics("led_t"),
-                          tracer=tracer).start()
-        try:
-            prompts = [[1, 2, 3], [2, 4, 6, 8], [5, 1, 5, 1, 5],
-                       [7, 7]]
-            futures = [engine.submit(p, 6) for p in prompts]
-            for f in futures:
-                f.result(timeout=60)
-        finally:
-            engine.stop()
-        ring = tracer.ledger()
-        live = tracer.live_ledger()
-        assert ring and live
-        assert ring == live          # bit-exact, full-row equality
-        # the export→trace_report round trip agrees row for row
-        path = tmp_path / "trace.json"
-        path.write_text(json.dumps(tracer.export_chrome()))
-        rebuilt = trace_report.rebuild_requests(
-            trace_report.load_trace(str(path)))
-        from veles_tpu.serving import cost_ledger
-        reported = cost_ledger(rebuilt)
-        key = lambda r: (r["op"], r["bucket"], r["backend"])  # noqa
-        assert {key(r) for r in reported} == {key(r) for r in live}
-        by_key = {key(r): r for r in reported}
-        for row in live:
-            rep = by_key[key(row)]
-            assert rep["dispatches"] == row["dispatches"]
-            assert rep["lanes"] == row["lanes"]
-            for q in ("p50_ms", "p95_ms", "mean_ms"):
-                assert rep[q] == pytest.approx(row[q], abs=2e-3)
-
-    def test_errors_mode_ledger_survives_ring_discard(self):
-        """'errors' retention discards successful records from the
-        ring — the live ledger still counts their dispatches (it is
-        the aggregate view, not the post-mortem one)."""
-        from veles_tpu.serving import SpanTracer
-        tr = SpanTracer(mode="errors", last=8)
-        ctx = tr.start_request(name="r1")
-        tr.add(ctx, "decode.step", "decode", 0.0, 0.001,
-               attrs={"bucket": 2, "backend": "xla"})
-        tr.finish_request(ctx)           # success: ring discards it
-        assert tr.requests() == []
-        assert tr.ledger() == []         # ring view: empty
-        live = tr.live_ledger()
-        assert len(live) == 1 and live[0]["dispatches"] == 1
 
 
 class TestSLOMonitor:
@@ -643,8 +581,8 @@ class TestSLOMonitor:
 class TestTelemetryEndpoints:
     def _serve(self):
         """A tiny server with every ISSUE 14 surface armed: metrics,
-        a sampled store, an SLO monitor, and a tracer with ledger
-        rows — no engine needed (the endpoints read components)."""
+        a sampled store, an SLO monitor, and a tracer — no engine
+        needed (the endpoints read components)."""
         from veles_tpu.restful_api import RESTfulAPI
         from veles_tpu.serving import (Objective, ServingMetrics,
                                        SLOMonitor, SpanTracer,
@@ -683,10 +621,6 @@ class TestTelemetryEndpoints:
             assert slo["worst_state_name"] == "ok"
             assert slo["objectives"][0]["objective"] == "avail"
             assert slo["sampled_at"] > 0
-            led = _get_json(api.port, "/ledger.json")
-            assert led["dispatches_total"] == 1
-            assert led["rows"][0]["op"] == "decode.step"
-            assert led["sampled_at"] > 0
             ms = _get_json(api.port, "/metrics.json")
             assert ms["sampled_at"] > 0       # the small fix
             with urllib.request.urlopen(
@@ -697,7 +631,6 @@ class TestTelemetryEndpoints:
                 text = r.read().decode()
             assert "veles_tpu serving status" in text
             assert "[slo" in text and "[telemetry" in text
-            assert "[cost ledger" in text
             # schema guard: the live payloads conform to the shapes
             # tools/check_stream_records.py enforces tier-1
             import check_stream_records as csr
@@ -725,8 +658,7 @@ class TestTelemetryEndpoints:
         api = RESTfulAPI(None, handler=lambda p: {"ok": True})
         api.start(port=0)
         try:
-            for path in ("/timeseries.json", "/slo.json",
-                         "/ledger.json"):
+            for path in ("/timeseries.json", "/slo.json"):
                 with pytest.raises(urllib.error.HTTPError) as err:
                     _get_json(api.port, path)
                 assert err.value.code == 404

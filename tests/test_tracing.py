@@ -596,3 +596,334 @@ class TestReviewHardening:
         verify_integrity(recs)
         assert any(r["error"] and "injected" in r["error"]
                    for r in recs)
+
+
+# ------------------------------------------------- the loop recorder (ISSUE 26)
+def _stamps(row):
+    from veles_tpu.serving import tracing
+    return row[tracing.COL_STAMPS:tracing.COL_END + 1]
+
+
+class TestLoopRecorder:
+    """The always-on engine-loop recorder: what it records and that it
+    changes nothing.  No case asserts a duration: only order, counts
+    and identities."""
+
+    N_NEW = 8
+    PROMPTS = [[1, 2, 3], [2, 4, 6, 8], [5, 1, 5, 1, 5, 1, 5, 1, 5, 1],
+               [7, 7], [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5]]
+    #: the four decode drivers of LMEngine._serve_loop
+    DRIVERS = {
+        "plain": dict(prefill_chunk=8, paged_kv=True),
+        "speculative": dict(prefill_chunk=8, paged_kv=True, spec_k=2),
+        "megastep": dict(prefill_chunk=8, paged_kv=True, megastep=4),
+        "while": dict(prefill_chunk=8, paged_kv=True, megastep=4,
+                      megastep_mode="while"),
+    }
+
+    def _engine(self, name="rec_t", **kw):
+        from veles_tpu.serving import LMEngine, ServingMetrics
+        return LMEngine(tiny_params(), n_heads=2, max_len=64, slots=2,
+                        metrics=ServingMetrics(name), name=name, **kw)
+
+    def _serve(self, prompts=None, **kw):
+        engine = self._engine(**kw).start()
+        try:
+            futures = [engine.submit(p, self.N_NEW)
+                       for p in prompts or self.PROMPTS]
+            outs = [f.result(timeout=120) for f in futures]
+        finally:
+            engine.stop()
+        return engine, outs
+
+    def test_ring_wraps_and_keeps_the_newest(self):
+        from veles_tpu.serving import tracing
+        rec = tracing.LoopRecorder("wrap", capacity=8)
+        for _ in range(21):
+            rec.turn()
+            rec.mark(tracing.ADMIT)
+        rec.close()
+        turns = rec.turns()
+        assert rec.head == 21
+        # the newest, less the one place the writer may be filling now
+        assert turns[:, tracing.COL_SEQ].tolist() == list(range(15, 22))
+        assert rec.turns(last=3)[:, tracing.COL_SEQ].tolist() \
+            == [19, 20, 21]
+        with pytest.raises(ValueError, match="power of two"):
+            tracing.LoopRecorder("odd", capacity=12)
+
+    def test_skipped_phases_have_no_length(self):
+        """A turn that went tick -> admit -> wait: every later phase
+        starts and ends at the turn's end; the next turn starts there."""
+        from veles_tpu.serving import tracing
+        rec = tracing.LoopRecorder("skip", capacity=4)
+        rec.turn()
+        rec.mark(tracing.ADMIT)
+        rec.mark(tracing.WAIT)
+        rec.turn()
+        rec.mark(tracing.ADMIT)
+        rec.dispatch(tracing.STEP_DISPATCH, _program_stub, lanes=2)
+        rec.close()
+        first, second = rec.turns()
+        s = _stamps(first)
+        assert (numpy.diff(s) >= 0).all()
+        assert len(set(s[tracing.WAIT + 1:].tolist())) == 1
+        assert s[-1] == _stamps(second)[0]
+        assert first[tracing.COL_STEP_PROGRAM] == 0
+        assert rec.programs[second[tracing.COL_STEP_PROGRAM]] \
+            == "_program_stub"
+        assert second[tracing.COL_ACTIVE] == 2
+        # prefill phases were skipped: they collapse onto step.dispatch
+        s2 = _stamps(second)
+        assert s2[tracing.WAIT] == s2[tracing.STEP_DISPATCH]
+
+    @pytest.mark.parametrize("driver", sorted(DRIVERS))
+    def test_turns_and_requests_match_the_engine(self, driver):
+        """Per decode driver: tokens are the greedy ones; the phases of
+        every turn partition it and turns leave no hole; the turns'
+        programs and lane counts match the dispatch counters; request
+        stamps are ordered and the token stamps number n_new, summing
+        to the tokens_out counter."""
+        from veles_tpu.serving import tracing
+        engine, outs = self._serve(**self.DRIVERS[driver])
+        expect = greedy_rows(tiny_params(), self.PROMPTS, self.N_NEW)
+        for p, out, exp in zip(self.PROMPTS, outs, expect):
+            numpy.testing.assert_array_equal(
+                numpy.concatenate([p, out]), exp)
+        rec = engine.recorder
+        assert rec is tracing.recorders()[-1]
+        turns = rec.turns()
+        counters = engine.metrics.snapshot()["counters"]
+        # partition: stamps never go back, a turn ends where the next
+        # begins, sequence numbers are consecutive from 1
+        stamps = turns[:, tracing.COL_STAMPS:tracing.COL_END + 1]
+        assert (numpy.diff(stamps, axis=1) >= 0).all()
+        assert (stamps[1:, 0] == stamps[:-1, -1]).all()
+        assert turns[:, tracing.COL_SEQ].tolist() \
+            == list(range(1, len(turns) + 1))
+        # programs and counts
+        step = turns[:, tracing.COL_STEP_PROGRAM]
+        chunk = turns[:, tracing.COL_PREFILL_PROGRAM]
+        assert int((step > 0).sum()) == counters["decode_dispatches"]
+        assert int((chunk > 0).sum()) == counters["prefill_dispatches"]
+        want = {"plain": "step_all", "speculative": "verify_all",
+                "megastep": "mega_plain", "while": "mega_while"}[driver]
+        assert {rec.programs[i] for i in set(step[step > 0].tolist())} \
+            == {want}
+        assert {rec.programs[i] for i in set(chunk[chunk > 0].tolist())} \
+            == {"chunk_slot"}
+        # a turn that dispatched a decode passed all four step phases in
+        # order; one that did not has no step.dispatch/fetch/emit time
+        idle = stamps[step == 0]
+        assert (idle[:, tracing.STEP_DISPATCH] == idle[:, -1]).all()
+        active = turns[:, tracing.COL_ACTIVE]
+        busy = turns[:, tracing.COL_BUSY]
+        assert ((active >= 1) == (step > 0)).all()
+        assert (active <= busy).all() and busy.max() <= engine.slots
+        assert int(turns[:, tracing.COL_TOKENS].sum()) \
+            == counters["tokens_out"]
+        # requests
+        reqs = rec.requests()
+        assert len(reqs) == len(self.PROMPTS)
+        for r, p in zip(sorted(reqs, key=lambda r: r.enqueue),
+                        self.PROMPTS):
+            assert r.outcome == "ok" and r.prompt_len == len(p)
+            assert r.enqueue <= r.admit <= r.first_token <= r.done
+            assert r.tokens_out == len(r.token_ns) == r.n_new \
+                == self.N_NEW
+            assert list(r.token_ns) == sorted(r.token_ns)
+            assert r.first_token == r.token_ns[0]
+            assert 0 <= r.lane < engine.slots
+        assert sum(r.tokens_out for r in reqs) == counters["tokens_out"]
+
+    def test_standby_ring_requests_are_recorded(self):
+        """The while driver's refill ring admits outside the slot array:
+        its requests still leave ordered records whose token stamps sum
+        to the counter."""
+        engine, outs = self._serve(
+            prefill_chunk=8, paged_kv=True, megastep=4,
+            megastep_mode="while", refill_ring=2)
+        counters = engine.metrics.snapshot()["counters"]
+        reqs = engine.recorder.requests()
+        assert len(reqs) == len(self.PROMPTS)
+        for r in reqs:
+            assert r.outcome == "ok"
+            assert r.enqueue <= r.admit <= r.first_token <= r.done
+            assert r.tokens_out == self.N_NEW
+        assert sum(r.tokens_out for r in reqs) == counters["tokens_out"]
+
+    def test_shed_request_leaves_its_outcome(self):
+        from veles_tpu.serving import DeadlineExceeded
+        engine = self._engine(deadline_s=0.0, prefill_chunk=8).start()
+        try:
+            fut = engine.submit([1, 2, 3], 4)
+            with pytest.raises(DeadlineExceeded):
+                fut.result(timeout=60)
+        finally:
+            engine.stop()
+        (r,) = engine.recorder.requests()
+        assert r.outcome == "shed"
+        assert r.admit == 0 and r.first_token == 0 and r.tokens_out == 0
+        assert r.enqueue <= r.done and r.lane == -1
+
+    def test_failed_request_leaves_its_outcome(self):
+        from veles_tpu.serving import FaultPlan, InjectedFault
+        plan = FaultPlan(seed=0).arm("engine.step", kind="error",
+                                     calls={1})
+        engine = self._engine(faults=plan).start()
+        try:
+            fut = engine.submit([1, 2, 3], 4)
+            with pytest.raises(InjectedFault):
+                fut.result(timeout=60)
+            ok = engine.submit([1, 2, 3], 4).result(timeout=60)
+        finally:
+            engine.stop()
+        first, second = engine.recorder.requests()
+        assert first.outcome == "failed" and second.outcome == "ok"
+        # the failed one was admitted and had its first token (whole-
+        # prompt prefill) before the decode step raised
+        assert first.enqueue <= first.admit <= first.first_token \
+            <= first.done
+        assert first.tokens_out == 1 and len(ok) == second.tokens_out
+
+    def test_cancelled_request_leaves_its_outcome(self):
+        engine = self._engine(prefill_chunk=8)
+        engine.start()
+        try:
+            # both lanes busy, so the third waits in the queue
+            busy = [engine.submit([1, 2, 3, 4], 40) for _ in range(2)]
+            queued = engine.submit([4, 3, 2, 1], 4)
+            engine._cancel(queued.request)
+            for f in busy:
+                f.result(timeout=120)
+        finally:
+            engine.stop()
+        by = {r.outcome: r for r in engine.recorder.requests()}
+        assert set(by) == {"ok", "cancelled"}
+        assert by["cancelled"].tokens_out == 0
+        assert queued.cancelled()
+
+    def test_stopped_engines_stay_readable_and_registry_is_bounded(self):
+        from veles_tpu.serving import tracing
+        engines = []
+        for i in range(6):
+            engine, _ = self._serve(prompts=[[1, 2, 3]],
+                                    name="rec_b%d" % i)
+            engines.append(engine)
+        kept = tracing.recorders()
+        assert len(kept) == 4
+        assert [r.name for r in kept] == ["rec_b%d" % i
+                                          for i in range(2, 6)]
+        # stopped, and still whole
+        for engine in engines:
+            assert engine._thread is None
+            assert len(engine.recorder.turns()) == engine.recorder.head
+            assert len(engine.recorder.requests()) == 1
+
+    def test_tokens_identical_with_trace_off_and_all(self):
+        """The recorder is on whatever --serve-trace says, an engine
+        with it off still makes no SpanTracer, and both serve the same
+        tokens with records of the same shape."""
+        from veles_tpu.serving import SpanTracer
+        off, outs_off = self._serve(prefill_chunk=8, paged_kv=True,
+                                    tracer=SpanTracer.from_spec("off"))
+        on, outs_on = self._serve(prefill_chunk=8, paged_kv=True,
+                                  tracer=SpanTracer.from_spec("all"))
+        assert off._tracer is None and on._tracer is not None
+        for a, b in zip(outs_off, outs_on):
+            numpy.testing.assert_array_equal(a, b)
+        for engine in (off, on):
+            assert len(engine.recorder.requests()) == len(self.PROMPTS)
+            assert engine.recorder.head > 0
+        assert off.recorder.programs == on.recorder.programs
+
+    def test_tracer_and_recorder_share_one_clock(self):
+        """SpanTracer stamps against the process origin (no per-tracer
+        origin): a span lies between two monotonic_offset() readings
+        taken around it, on a tracer made at any time, and the recorder's
+        stamps convert onto the same axis."""
+        from veles_tpu.serving import SpanTracer, tracing
+        from veles_tpu.serving.metrics import _ORIGIN, monotonic_offset
+        before = monotonic_offset()
+        tr = SpanTracer(mode="all")
+        ctx = tr.start_request(name="clock")
+        rec = tracing.LoopRecorder("clock", capacity=2)
+        rec.turn()
+        rec.close()
+        out = tr.finish_request(ctx)
+        after = monotonic_offset()
+        root = out["spans"][0]
+        assert before <= root["t0"] <= root["t1"] <= after
+        (turn,) = rec.turns()
+        t0 = turn[tracing.COL_STAMPS] * 1e-9 - _ORIGIN
+        assert root["t0"] <= t0 <= root["t1"]
+        (ev,) = [e for e in rec.chrome_events(9) if e["ph"] == "X"] or \
+            [{"ts": t0 * 1e6}]
+        assert abs(ev["ts"] - t0 * 1e6) < 1.0
+
+    def test_http_records_and_trace_json_loop_track(self):
+        """do_POST leaves one record per request, ordered, with its
+        status; /trace.json carries the engine loop beside the request
+        tracks."""
+        from veles_tpu.restful_api import RESTfulAPI
+        from veles_tpu.serving import SpanTracer, tracing
+        tracer = SpanTracer(mode="all", last=8)
+        engine = self._engine(prefill_chunk=8, tracer=tracer,
+                              name="rec_http").start()
+
+        def handler(request):
+            return {"tokens": engine.generate(
+                numpy.asarray(request["input"], numpy.int32), 4).tolist()}
+
+        api = RESTfulAPI(None, handler=handler, tracer=tracer)
+        api.lm_engine = engine
+        api.start(port=0)
+        n0 = len(tracing.http_records())
+        try:
+            req = urllib.request.Request(
+                "http://127.0.0.1:%d/predict" % api.port,
+                data=json.dumps({"input": [[1, 2, 3]]}).encode(),
+                headers={"Content-Type": "application/json"})
+            with urllib.request.urlopen(req, timeout=60) as resp:
+                assert len(json.loads(resp.read())["tokens"][0]) == 7
+            bad = urllib.request.Request(
+                "http://127.0.0.1:%d/predict" % api.port,
+                data=b"{not json")
+            with pytest.raises(urllib.error.HTTPError) as err:
+                urllib.request.urlopen(bad, timeout=60)
+            assert err.value.code == 400
+            with urllib.request.urlopen(
+                    "http://127.0.0.1:%d/trace.json" % api.port,
+                    timeout=60) as resp:
+                trace = json.loads(resp.read())
+        finally:
+            api.stop()
+        # the reply is written before the record: wait for both
+        deadline = time.monotonic() + 30
+        while len(tracing.http_records()) < n0 + 2 \
+                and time.monotonic() < deadline:
+            time.sleep(0.01)
+        ok, bad = tracing.http_records()[n0:n0 + 2]
+        assert ok.status == 200
+        assert ok.recv <= ok.submit <= ok.result <= ok.reply
+        assert bad.status == 400 and bad.submit == 0 == bad.result
+        assert bad.recv <= bad.reply
+        tracks = {e["args"]["name"]: e["tid"]
+                  for e in trace["traceEvents"] if e["ph"] == "M"}
+        assert "engine loop rec_http" in tracks
+        loop = [e for e in trace["traceEvents"] if e["ph"] == "X"
+                and e["tid"] == tracks["engine loop rec_http"]]
+        assert {e["name"] for e in loop} <= set(tracing.PHASES)
+        assert any(e["name"] == "step.dispatch"
+                   and e["args"]["program"] == "step_one" for e in loop)
+        # a request's decode.step spans lie inside the loop track's span
+        steps = [e for e in trace["traceEvents"] if e["ph"] == "X"
+                 and e["name"] == "decode.step"]
+        assert steps
+        lo = min(e["ts"] for e in loop)
+        hi = max(e["ts"] + e["dur"] for e in loop)
+        assert all(lo <= e["ts"] <= hi for e in steps)
+
+
+def _program_stub():
+    """Stands in for a jitted program: the recorder reads ``__name__``."""

@@ -51,8 +51,7 @@ injects at the same dispatches:
   asserts the sum < 2% (armed tracing's span cost is recorded for
   PERF.md, not bounded).  The ISSUE 14 telemetry bound rides here
   too: the ARMED sampler (one ``sample_once()`` amortized over its
-  interval) plus the tracer's per-dispatch incremental-ledger update
-  are measured and asserted < 1% of a decode step.
+  interval) is measured and asserted < 1% of a decode step.
 
 A bench.py-style summary JSON line streams after EVERY completed
 scenario (last-line-wins under an outer watchdog kill), and the final
@@ -71,6 +70,7 @@ between TPU sessions.
 from __future__ import annotations
 
 import argparse
+import array
 import json
 import os
 import sys
@@ -464,6 +464,39 @@ def scenario_traced_flight_recorder(params, n_heads, max_len, prompts,
     }
 
 
+def recorder_turn_cost(slots, turns=20000):
+    """Seconds the always-on loop recorder (ISSUE 26) costs per turn of
+    the engine loop: every call a decode turn with one prefill chunk
+    makes (turn, seven marks, two dispatches, the lane counts, one token
+    stamp per lane), timed over ``turns`` turns on this host."""
+    from veles_tpu.serving import tracing
+
+    class _Req:
+        pass
+
+    def program():
+        pass
+
+    rec = tracing.LoopRecorder("cost")
+    req = _Req()
+    req.token_ns = array.array("q")
+    t0 = time.perf_counter()
+    for _ in range(turns):
+        rec.turn()
+        rec.mark(tracing.ADMIT)
+        rec.lanes(slots, 0)
+        rec.mark(tracing.PREFILL_PREPARE)
+        rec.dispatch(tracing.PREFILL_DISPATCH, program)
+        rec.mark(tracing.STEP_PREPARE)
+        rec.dispatch(tracing.STEP_DISPATCH, program, slots)
+        rec.mark(tracing.STEP_FETCH)
+        rec.mark(tracing.STEP_EMIT)
+        for _lane in range(slots):
+            rec.emitted(req, 1)
+    rec.close()
+    return (time.perf_counter() - t0) / turns
+
+
 def scenario_overhead(params, n_heads, max_len, prompts, n_new,
                       slots=2, hook_calls=200000):
     """Fault-free overhead: the UNARMED fault layer, the UNARMED
@@ -476,6 +509,8 @@ def scenario_overhead(params, n_heads, max_len, prompts, n_new,
     unarmed TRACE site — literally ``engine._tracer is None`` —
     scaled the same way; (c) the health checker's per-scan cost on a
     BUSY fleet (counter reads, no probe) amortized over its interval.
+    (d) the ALWAYS-ON loop recorder (ISSUE 26): every call one turn
+    of the engine loop makes, per decode step.
     All expressed against a decode-step wall measured live on this
     host.  ARMED tracing cost (span begin/end pair, scaled to the
     spans a traced tick records) is measured and RECORDED for the
@@ -564,14 +599,16 @@ def scenario_overhead(params, n_heads, max_len, prompts, n_new,
         # the decode rate — its amortized cost is simply the fraction
         # of wall clock a scan occupies
         health_frac = scan_s / checker.interval_s
-        overhead = hook_frac + trace_frac + lock_frac + health_frac
-        # ---- ISSUE 14: the ARMED continuous-telemetry bound.  (a)
-        # the sampler: one full sample_once() — runtime probes +
-        # source snapshots + ring folds — amortized over its
-        # interval_s of wall clock, exactly like the health scan;
-        # (b) the tracer's incremental cost-ledger update, paid once
-        # per device dispatch on the armed path — together they must
-        # stay under 1% of a decode step
+        # (d) the always-on loop recorder: one turn's calls per step
+        recorder_s = recorder_turn_cost(slots)
+        recorder_frac = recorder_s / step_s
+        overhead = hook_frac + trace_frac + lock_frac + health_frac \
+            + recorder_frac
+        # ---- ISSUE 14: the ARMED continuous-telemetry bound: the
+        # sampler, one full sample_once() — runtime probes + source
+        # snapshots + ring folds — amortized over its interval_s of
+        # wall clock, exactly like the health scan; it must stay
+        # under 1% of a decode step
         from veles_tpu.serving import telemetry_for
         store = telemetry_for(router, interval_s=1.0)
         store.sample_once()          # warm the probes' first pass
@@ -581,18 +618,7 @@ def scenario_overhead(params, n_heads, max_len, prompts, n_new,
             store.sample_once()
         sample_s = (time.perf_counter() - t0) / samples
         sampler_frac = sample_s / store.interval_s
-        ledger_tr = SpanTracer(mode="all", last=4)
-        ledger_attrs = {"batch": slots, "bucket": slots,
-                        "backend": "xla"}
-        t0 = time.perf_counter()
-        notes = 50000
-        with ledger_tr._lock:
-            for _ in range(notes):
-                ledger_tr._ledger_note("decode.step", ledger_attrs,
-                                       0.0, 0.001, slots)
-        ledger_note_s = (time.perf_counter() - t0) / notes
-        ledger_frac = ledger_note_s / step_s
-        telemetry_frac = sampler_frac + ledger_frac
+        telemetry_frac = sampler_frac
         record = {
             "scenario": "fault_free_overhead",
             "decode_step_ewma_s": round(step_s, 6),
@@ -619,25 +645,26 @@ def scenario_overhead(params, n_heads, max_len, prompts, n_new,
             "health_scan_s": round(scan_s, 6),
             "health_scan_interval_s": checker.interval_s,
             "health_frac_of_decode_step": round(health_frac, 6),
+            "recorder_turn_ns": round(recorder_s * 1e9, 1),
+            "recorder_frac_of_decode_step": round(recorder_frac, 6),
             "overhead_frac": round(overhead, 6),
             "bound": 0.02,
             # ISSUE 14: the armed-telemetry rows and their own bound
             "telemetry_sample_s": round(sample_s, 6),
             "telemetry_interval_s": store.interval_s,
             "sampler_frac_of_decode_step": round(sampler_frac, 6),
-            "ledger_note_ns": round(ledger_note_s * 1e9, 1),
-            "ledger_frac_of_decode_step": round(ledger_frac, 6),
             "telemetry_frac": round(telemetry_frac, 6),
             "telemetry_bound": 0.01,
         }
         if overhead >= 0.02:
             raise AssertionError(
                 "unarmed fault layer + unarmed tracing + unarmed "
-                "lock shim + health prober cost %.3f%% of a decode "
-                "step (bound: 2%%)" % (100 * overhead))
+                "lock shim + health prober + loop recorder cost "
+                "%.3f%% of a decode step (bound: 2%%)"
+                % (100 * overhead))
         if telemetry_frac >= 0.01:
             raise AssertionError(
-                "armed telemetry sampler + incremental ledger cost "
+                "armed telemetry sampler cost "
                 "%.3f%% of a decode step (bound: 1%%)"
                 % (100 * telemetry_frac))
         return record

@@ -174,6 +174,7 @@ class RESTfulAPI(Logger):
 
     # ---------------------------------------------------------------- server
     def start(self, host="127.0.0.1", port=8180):
+        from veles_tpu.serving import tracing
         from veles_tpu.serving.batcher import DeadlineExceeded, Overloaded
         api = self
         if self.batcher is not None:
@@ -233,23 +234,11 @@ class RESTfulAPI(Logger):
                 elif path == "/slo.json" and api.slo is not None:
                     # burn-rate objectives (ISSUE 14)
                     self._reply(200, api.slo.snapshot())
-                elif path == "/ledger.json" and api.tracer is not None:
-                    # the LIVE per-op cost ledger (ISSUE 14): the same
-                    # dedup-by-dispatch-id rows tools/trace_report.py
-                    # aggregates, maintained incrementally in-process
-                    from veles_tpu.serving.metrics import \
-                        monotonic_offset
-                    rows = api.tracer.live_ledger()
-                    self._reply(200, {
-                        "sampled_at": round(monotonic_offset(), 6),
-                        "dispatches_total": sum(r["dispatches"]
-                                                for r in rows),
-                        "rows": rows})
                 elif path == "/status":
                     # the human panel (ISSUE 14): plain text, curl-able
                     body = render_status(
                         metrics=api.metrics, telemetry=api.telemetry,
-                        slo=api.slo, tracer=api.tracer).encode()
+                        slo=api.slo).encode()
                     self.send_response(200)
                     self.send_header("Content-Type",
                                      "text/plain; charset=utf-8")
@@ -259,7 +248,9 @@ class RESTfulAPI(Logger):
                 elif path == "/trace.json" and api.tracer is not None:
                     # the flight recorder as Chrome-trace/Perfetto JSON
                     # (ISSUE 12): ?last=N trims to the newest N
-                    # requests; load at ui.perfetto.dev
+                    # requests; the served engines' loop recorders ride
+                    # along as `engine loop` tracks (ISSUE 26); load at
+                    # ui.perfetto.dev
                     query = urllib.parse.parse_qs(split.query)
                     last = None
                     try:
@@ -269,8 +260,13 @@ class RESTfulAPI(Logger):
                         self._reply(400, {"error": "last must be an "
                                           "integer"})
                         return
+                    engines = getattr(api.lm_engine, "replicas",
+                                      [api.lm_engine])
                     self._reply(200, api.tracer.export_chrome(
-                        last=last))
+                        last=last,
+                        loops=[e.recorder for e in engines
+                               if getattr(e, "recorder", None)
+                               is not None]))
                 elif path == "/metrics":
                     from veles_tpu.serving import metrics as metrics_mod
                     # merge this server's instance into the registry
@@ -296,7 +292,11 @@ class RESTfulAPI(Logger):
                 # client's X-Request-Id (or mint one) on EVERY reply —
                 # success and structured error — so client logs,
                 # traces, and load_gen records join on one key
-                t0 = time.monotonic()
+                recv = time.monotonic_ns()
+                t0 = recv * 1e-9
+                #: the loop recorder's HTTP record (ISSUE 26): stamps
+                #: around the handler call, written with the reply
+                self._stamps = [0, 0]
                 rid = (self.headers.get("X-Request-Id") or "").strip()
                 rid = rid[:64] or uuid.uuid4().hex[:16]
                 ctx = None
@@ -311,7 +311,6 @@ class RESTfulAPI(Logger):
                 code, payload, headers = 500, {"error": "internal"}, []
                 try:
                     if api.tracer is not None:
-                        from veles_tpu.serving import tracing
                         # ctx None = the sampler skipped this request:
                         # bind the sentinel so the router/engine below
                         # do not re-roll and root partial trees
@@ -336,6 +335,8 @@ class RESTfulAPI(Logger):
                     payload.setdefault("request_id", rid)
                 self._reply(code, payload,
                             list(headers) + [("X-Request-Id", rid)])
+                tracing.note_http(recv, *self._stamps,
+                                  time.monotonic_ns(), code)
 
             def _handle_post(self, t0):
                 """Run one POST; returns (code, json_payload, headers)
@@ -378,9 +379,13 @@ class RESTfulAPI(Logger):
                             "error": str(e),
                             "retry_after": e.retry_after}, headers
                 try:    # dispatch
-                    result = (api._handler(payload)
-                              if api._handler is not None
-                              else api.predict(batch))
+                    self._stamps[0] = time.monotonic_ns()
+                    try:
+                        result = (api._handler(payload)
+                                  if api._handler is not None
+                                  else api.predict(batch))
+                    finally:
+                        self._stamps[1] = time.monotonic_ns()
                 except Overloaded as e:
                     # Retry-After is integer delta-seconds per RFC 9110
                     # (the exact float rides in the JSON body)
@@ -444,13 +449,12 @@ class RESTfulAPI(Logger):
             self.lm_engine.stop()
 
 
-def render_status(metrics=None, telemetry=None, slo=None, tracer=None,
-                  window_s=60.0):
+def render_status(metrics=None, telemetry=None, slo=None, window_s=60.0):
     """The ``GET /status`` text panel (ISSUE 14): the operator's
     one-glance view — live gauges, windowed rates and tail latency
-    from the telemetry store, every SLO objective's state and burn,
-    and the top live-ledger rows.  Plain text by design: readable in
-    a terminal over curl, no client tooling required."""
+    from the telemetry store, every SLO objective's state and burn.
+    Plain text by design: readable in a terminal over curl, no client
+    tooling required."""
     from veles_tpu.serving.metrics import monotonic_offset
     lines = ["veles_tpu serving status",
              "sampled_at %.3fs (monotonic offset)"
@@ -507,18 +511,6 @@ def render_status(metrics=None, telemetry=None, slo=None, tracer=None,
             lines.append("  %-5s %-24s %-12s target %g  burn %s"
                          % (row["state_name"].upper(), row["source"],
                             row["objective"], row["target"], burns))
-        lines.append("")
-    if tracer is not None:
-        rows = tracer.live_ledger()
-        lines.append("[cost ledger — %d dispatch(es), top rows]"
-                     % sum(r["dispatches"] for r in rows))
-        for r in rows[:8]:
-            lines.append(
-                "  %-18s bucket %-6s %-8s n=%-7d p50 %8.3fms  "
-                "p95 %8.3fms  total %10.1fms"
-                % (r["op"], r["bucket"], r["backend"],
-                   r["dispatches"], r["p50_ms"], r["p95_ms"],
-                   r["total_ms"]))
         lines.append("")
     return "\n".join(lines) + "\n"
 
@@ -634,10 +626,33 @@ def serve_lm(workflow, host="127.0.0.1", port=8180, max_new=256,
     ``tools/trace_report.py`` renders waterfalls + the per-op cost
     ledger.  Default off: every site is one attribute-is-None check
     (the ``faults.py`` discipline; the chaos bench pins unarmed
-    overhead <2%% of a decode step).  Every JSON reply (success and
-    error) is stamped with a ``request_id`` echoed from the
-    ``X-Request-Id`` header or generated server-side, whether or not
-    tracing is armed.
+    overhead <2%% of a decode step).  An armed tracer FENCES every
+    traced dispatch, so its spans are for post-mortems, not for
+    measuring.  Every JSON reply (success and error) is stamped with a
+    ``request_id`` echoed from the ``X-Request-Id`` header or generated
+    server-side, whether or not tracing is armed.
+
+    THE LOOP RECORDER (ISSUE 26) is on in every engine whatever
+    ``trace`` says: ``serving/tracing.py::LoopRecorder`` keeps one
+    record per turn of the engine loop (phases that partition the
+    turn), one per request (enqueue, admit, first token, done, a stamp
+    per emitted token) and one per HTTP POST, all on
+    ``time.monotonic_ns()``, with no lock, fence or transfer.
+    ``tracing.recorders()`` returns them, after ``stop()`` too; the
+    benchmark's per-layer readers are their consumer.
+
+    ENDPOINTS, and what each is for: ``POST /predict`` the service;
+    ``GET /metrics`` (Prometheus text) and ``/metrics.json`` the
+    counters, gauges and fixed-bucket histograms for scraping and for
+    the router's placement; ``/timeseries.json?window=S`` and
+    ``/slo.json`` (with ``telemetry`` / ``slo``) windowed rates and
+    burn-rate states for alerting; ``/status`` the same as a text panel
+    for a human; ``/trace.json?last=N`` (with ``trace``) the flight
+    recorder's newest requests as Chrome-trace tracks, for the
+    post-mortem of one request, with the engine loop's newest turns as
+    an ``engine loop`` track beside them so that a request's
+    ``decode.step`` spans stand above the loop phases that produced
+    them.
 
     CONTINUOUS TELEMETRY + SLOs (ISSUE 14, engine path only):
     ``telemetry=S`` starts a
@@ -657,14 +672,11 @@ def serve_lm(workflow, host="127.0.0.1", port=8180, max_new=256,
     ``health=True`` — a page-level burn on ONE replica feeds the
     HealthChecker (``note_slo_page``) toward the same quarantine path
     a failed probe takes.  ``slo`` implies ``telemetry`` (default
-    1 s).  A traced server additionally serves the LIVE per-op cost
-    ledger at ``GET /ledger.json`` (same dedup rules as
-    ``tools/trace_report.py``, no export round trip), and every
-    server serves the human-readable ``GET /status`` text panel.
-    The hot path has zero telemetry sites: the store samples on its
-    own thread (the pull model) — overhead is bounded by the chaos
-    bench's ``fault_free_overhead`` leg (<1%% of a decode step
-    together with the incremental ledger).
+    1 s).  Every server serves the human-readable ``GET /status``
+    text panel.  The hot path has zero telemetry sites: the store
+    samples on its own thread (the pull model) — overhead is bounded
+    by the chaos bench's ``fault_free_overhead`` leg (<1%% of a decode
+    step).
 
     The direct path decodes one prompt batch at a time via the
     KV-cached ``transformer.generate``, one jitted dispatch per

@@ -73,6 +73,14 @@ request, this package amortizes dispatch across concurrent clients.
   (``tools/trace_report.py``).  ``serve_lm(trace=)``, CLI
   ``--serve-trace off|errors|sample:P|all``; unarmed cost is one
   attribute-is-None check per site (the ``faults.py`` discipline).
+  The same module holds :class:`~tracing.LoopRecorder` (ISSUE 26),
+  ON in every engine whatever ``--serve-trace`` says: one record per
+  turn of the engine loop (nine phases that partition it), one per
+  request with the stamp of every emitted token, one per HTTP POST,
+  all on ``time.monotonic_ns()``; no lock, no fence, no transfer.
+  ``tracing.recorders()`` keeps the newest four, stopped engines'
+  included; ``benchmark/lib/spans.py`` puts them on the device
+  trace's clock.
 - :mod:`veles_tpu.serving.metrics` — :class:`ServingMetrics`:
   lock-cheap counters/histograms (queue wait, batch size, latency
   percentiles, shed/429, slot occupancy) with a snapshot API and a
@@ -85,10 +93,7 @@ request, this package amortizes dispatch across concurrent clients.
   ``jax`` device memory, live MFU from the lm_bench FLOPs model,
   megastep waste fraction) written by :func:`runtime_probe` each
   tick.  ``GET /timeseries.json?window=S``; the serving hot path has
-  zero telemetry sites (pull model).  The tracer additionally keeps
-  the per-op cost ledger INCREMENTALLY (``SpanTracer.live_ledger``,
-  ``GET /ledger.json``) — same dedup-by-dispatch-id rows as
-  ``tools/trace_report.py``, no export round-trip.
+  zero telemetry sites (pull model).
 - :mod:`veles_tpu.serving.lockcheck` — :class:`LockOrderWitness`
   (ISSUE 15): the runtime half of the concurrency-analysis layer.
   Serving locks are built through :func:`lockcheck.make_lock` /

@@ -178,6 +178,7 @@ contiguous / one per ladder entry paged, the jit-guard-asserted bound.
 
 from __future__ import annotations
 
+import array
 import collections
 import threading
 import time
@@ -196,7 +197,7 @@ from veles_tpu.serving.metrics import ServingMetrics
 class _Request:
     __slots__ = ("prompt", "true_len", "n_new", "future", "t_enq",
                  "deadline", "cancelled", "pages", "trace", "tspan",
-                 "seed")
+                 "seed", "t_enq_ns", "t_admit_ns", "token_ns", "lane")
 
     def __init__(self, prompt, n_new, deadline_s, pages=0):
         self.prompt = prompt          # (s,) int32, unpadded
@@ -204,7 +205,14 @@ class _Request:
         self.n_new = n_new
         self.future = Future()
         self.future.request = self    # cancellation handle
-        self.t_enq = time.monotonic()
+        #: the loop recorder's stamps (ISSUE 26), ``time.monotonic_ns()``:
+        #: enqueue, lane assigned (0 until then), one per emitted token;
+        #: ``lane`` is the slot (-1: none yet, or a standby-ring entry)
+        self.t_enq_ns = time.monotonic_ns()
+        self.t_admit_ns = 0
+        self.token_ns = array.array("q")
+        self.lane = -1
+        self.t_enq = self.t_enq_ns * 1e-9
         self.deadline = self.t_enq + deadline_s
         self.cancelled = False
         #: paged mode: worst-case page demand (admission reservation)
@@ -794,6 +802,9 @@ class LMEngine(Logger):
         #: supervisor can re-admit in-flight work after a crash
         self._journal = {}
         self._rid = 0
+        #: the always-on loop recorder (ISSUE 26, serving/tracing.py):
+        #: made in start(), kept after stop() for whoever reads later
+        self.recorder = None
         self._build_jits()
         if self._paged:
             self._update_pool_gauges()
@@ -824,8 +835,12 @@ class LMEngine(Logger):
                 lockcheck._witness.dispatch("engine.fence")
             jax.block_until_ready(state)
 
-    def _trace_admitted(self, req):
-        """Close the request's queue-wait span at slot assignment."""
+    def _trace_admitted(self, req, slot=-1):
+        """Lane assignment (``slot``; -1 for a standby-ring entry): the
+        recorder's ``admit`` stamp, and the request's queue-wait span
+        closes when it is traced."""
+        req.t_admit_ns = time.monotonic_ns()
+        req.lane = slot
         if req.tspan is not None:
             req.trace.tracer.end(req.tspan, attrs={
                 "wait_s": round(time.monotonic() - req.t_enq, 6)})
@@ -1660,6 +1675,7 @@ class LMEngine(Logger):
         # through the explicit xfer shims, like the worker loop).
         with xfer.guard():
             self._warmup()
+        self.recorder = tracing.register(tracing.LoopRecorder(self.name))
         with self._cond:
             self._stop = False
         self._thread = threading.Thread(target=self._worker, daemon=True,
@@ -1980,7 +1996,7 @@ class LMEngine(Logger):
             req.seed = rid
             self._journal[rid] = req
             req.future.add_done_callback(
-                lambda f, rid=rid: self._journal_pop(rid))
+                lambda f, rid=rid, req=req: self._settled(rid, req, f))
             self._queue.append(req)
             self._queued_tokens += req.true_len
             self._queued_pages += req.pages
@@ -2041,7 +2057,21 @@ class LMEngine(Logger):
         req.future.cancel()
 
     # --------------------------------------------------- crash-safe recovery
-    def _journal_pop(self, rid):
+    def _settled(self, rid, req, future):
+        """A request's future settled (result, exception or cancel; the
+        thread that settled it): its recorder record is written — THE
+        one site, whatever path ended the request — and it leaves the
+        admission journal."""
+        exc = None if future.cancelled() else future.exception()
+        if future.cancelled():
+            outcome = "cancelled"
+        elif exc is None:
+            outcome = "ok"
+        elif isinstance(exc, DeadlineExceeded):
+            outcome = "shed"
+        else:
+            outcome = "failed"
+        self.recorder.finished(req, outcome)
         with self._cond:
             self._journal.pop(rid, None)
 
@@ -2284,7 +2314,7 @@ class LMEngine(Logger):
             if bucket > req.true_len:
                 prompt = numpy.pad(prompt,
                                    (0, bucket - req.true_len))
-            self._trace_admitted(req)
+            self._trace_admitted(req, slot)
             t0p = time.monotonic()
             try:
                 self._fault("engine.prefill")
@@ -2331,7 +2361,7 @@ class LMEngine(Logger):
         lane's cache rows, and queue the rest as per-tick chunk work."""
         C = self.prefill_chunk
         n_full = (req.true_len - 1) // C
-        self._trace_admitted(req)
+        self._trace_admitted(req, slot)
         lane = _Slot(req)
         matched = 0
         if self._trie is not None:
@@ -2426,7 +2456,7 @@ class LMEngine(Logger):
             tail = numpy.pad(tail, (0, C - len(tail)))
         lane.pending.append((tail, n_full * C, True))
         self.metrics.record_queue_wait(time.monotonic() - req.t_enq)
-        self._trace_admitted(req)
+        self._trace_admitted(req, slot)
         if nodes and req.trace is not None:
             req.trace.tracer.instant(
                 req.trace, "prefix.hit", cat="prefill",
@@ -2627,13 +2657,15 @@ class LMEngine(Logger):
         t0 = time.monotonic()
         try:
             self._fault("engine.chunk")
+            args = (xfer.to_device(tokens, numpy.int32),
+                    xfer.to_device(slot, numpy.int32),
+                    xfer.to_device(start, numpy.int32),
+                    xfer.to_device(last_idx, numpy.int32)) \
+                + self._seed_args(req.seed)
+            self.recorder.dispatch(tracing.PREFILL_DISPATCH,
+                                   self._chunk_jit)
             self._caches, tok = self._chunk_jit(
-                self.params, self._caches,
-                xfer.to_device(tokens, numpy.int32),
-                xfer.to_device(slot, numpy.int32),
-                xfer.to_device(start, numpy.int32),
-                xfer.to_device(last_idx, numpy.int32),
-                *self._seed_args(req.seed))
+                self.params, self._caches, *args)
             if not is_tail and self._trie is not None \
                     and lane.cursor is not None:
                 rows = self._chunk_extract_jit(
@@ -2720,13 +2752,15 @@ class LMEngine(Logger):
         try:
             self._fault("engine.chunk")
             self._cow_guard(slot, lane, start, start + C)
+            args = (xfer.to_device(self._page_tables[slot]),
+                    xfer.to_device(tokens, numpy.int32),
+                    xfer.to_device(start, numpy.int32),
+                    xfer.to_device(last_idx, numpy.int32)) \
+                + self._seed_args(req.seed)
+            self.recorder.dispatch(tracing.PREFILL_DISPATCH,
+                                   self._chunk_jit)
             self._kv_pools, tok = self._chunk_jit(
-                self.params, self._kv_pools,
-                xfer.to_device(self._page_tables[slot]),
-                xfer.to_device(tokens, numpy.int32),
-                xfer.to_device(start, numpy.int32),
-                xfer.to_device(last_idx, numpy.int32),
-                *self._seed_args(req.seed))
+                self.params, self._kv_pools, *args)
             if not is_tail and self._trie is not None \
                     and lane.cursor is not None:
                 page = lane.pages[page_idx]
@@ -2773,13 +2807,20 @@ class LMEngine(Logger):
         else:
             self._pos[slot] = lane.pending[0][1]
 
+    def _count_tokens(self, req, n=1):
+        """THE emit site: ``n`` tokens of ``req`` reached the host.  The
+        ``tokens_out`` counter and the recorder's per-token stamps move
+        here and nowhere else, so the two agree by construction."""
+        self.metrics.inc("tokens_out", n)
+        self.recorder.emitted(req, n)
+
     def _emit_first(self, slot, lane, tok):
         """First generated token (prefill just finished): the lane
         becomes a decode lane (or finishes outright at n_new=1)."""
         req = lane.request
         lane.emitted.append(tok)
         lane.remaining -= 1
-        self.metrics.inc("tokens_out")
+        self._count_tokens(req)
         self.metrics.record_ttft(time.monotonic() - req.t_enq)
         self._pos[slot] = req.true_len
         self._last[slot] = tok
@@ -2845,6 +2886,30 @@ class LMEngine(Logger):
         for slot in active:
             self._teardown_slot(slot, self._lanes[slot], exc)
 
+    def _dispatch_decode(self, decode_jit, args, lanes, tctxs):   # hot-path
+        """THE decode dispatch all four drivers share (tick, verify, scan
+        and while megastep): ``decode_jit`` over the parameters, the KV
+        storage and ``args`` — already on the device, the puts belong to
+        ``step.prepare`` — then the storage swapped for its first output
+        and the others fetched to the host.  The recorder's
+        ``step.dispatch`` spans the jit call until it returns,
+        ``step.fetch`` the wait for the device and the copy out (an armed
+        tracer's fence too, when a sampled lane rides the dispatch), and
+        ``step.emit`` opens as this returns."""
+        rec = self.recorder
+        rec.dispatch(tracing.STEP_DISPATCH, decode_jit, lanes)
+        if self._paged:
+            out = decode_jit(self.params, self._kv_pools, *args)
+            self._kv_pools = out[0]
+        else:
+            out = decode_jit(self.params, self._caches, *args)
+            self._caches = out[0]
+        rec.mark(tracing.STEP_FETCH)
+        host = xfer.to_host(tuple(out[1:]))
+        self._tfence(out[0], any(c is not None for c in tctxs))
+        rec.mark(tracing.STEP_EMIT)
+        return host
+
     def _step_plain(self, active):   # hot-path
         """ONE dispatch advances every active lane by one token;
         inactive lanes step too (their writes land at a frozen position
@@ -2864,22 +2929,14 @@ class LMEngine(Logger):
         t0 = time.monotonic()
         try:
             self._fault("engine.step")
+            args = ()
             if self._paged:
                 w = self._live_width(1)
-                self._kv_pools, toks = self._step_jit(
-                    self.params, self._kv_pools,
-                    xfer.to_device(self._page_tables[:, :w]),
-                    xfer.to_device(self._last),
-                    xfer.to_device(self._pos), *self._seed_vec())
-            else:
-                self._caches, toks = self._step_jit(
-                    self.params, self._caches,
-                    xfer.to_device(self._last),
-                    xfer.to_device(self._pos), *self._seed_vec())
-            toks = xfer.to_host(toks)
-            self._tfence(self._kv_pools if self._paged
-                         else self._caches,
-                         any(c is not None for c in tctxs))
+                args = (xfer.to_device(self._page_tables[:, :w]),)
+            args += (xfer.to_device(self._last),
+                     xfer.to_device(self._pos)) + self._seed_vec()
+            toks, = self._dispatch_decode(self._step_jit, args,
+                                          len(active), tctxs)
         except Exception as e:   # noqa: BLE001 — fails the lanes
             if self._tracer is not None:
                 self._tracer.add_many(
@@ -2902,7 +2959,7 @@ class LMEngine(Logger):
             lane = self._lanes[slot]
             lane.emitted.append(int(toks[slot]))
             lane.remaining -= 1
-            self.metrics.inc("tokens_out")
+            self._count_tokens(lane.request)
             self._pos[slot] += 1
             self._last[slot] = int(toks[slot])
             if lane.remaining == 0 or lane.request.cancelled:
@@ -2950,21 +3007,14 @@ class LMEngine(Logger):
         t0 = time.monotonic()
         try:
             self._fault("engine.verify")
+            args = ()
             if self._paged:
                 w = self._live_width(k + 1)
-                self._kv_pools, out = self._verify_jit(
-                    self.params, self._kv_pools,
-                    xfer.to_device(self._page_tables[:, :w]),
-                    xfer.to_device(toks_in), xfer.to_device(self._pos),
-                    *self._seed_vec())
-            else:
-                self._caches, out = self._verify_jit(
-                    self.params, self._caches, xfer.to_device(toks_in),
-                    xfer.to_device(self._pos), *self._seed_vec())
-            out = xfer.to_host(out)
-            self._tfence(self._kv_pools if self._paged
-                         else self._caches,
-                         any(c is not None for c in tctxs))
+                args = (xfer.to_device(self._page_tables[:, :w]),)
+            args += (xfer.to_device(toks_in),
+                     xfer.to_device(self._pos)) + self._seed_vec()
+            out, = self._dispatch_decode(self._verify_jit, args,
+                                         len(active), tctxs)
         except Exception as e:   # noqa: BLE001 — fails the lanes
             if self._tracer is not None:
                 self._tracer.add_many(
@@ -3003,7 +3053,7 @@ class LMEngine(Logger):
             take = min(len(emit), lane.remaining)
             lane.emitted.extend(emit[:take])
             lane.remaining -= take
-            self.metrics.inc("tokens_out", take)
+            self._count_tokens(lane.request, take)
             self._pos[slot] += accepted + 1
             self._last[slot] = int(out[slot, accepted])
             if lane.remaining == 0 or lane.request.cancelled:
@@ -3052,27 +3102,16 @@ class LMEngine(Logger):
         t0 = time.monotonic()
         try:
             self._fault("engine.step")
+            args = ()
             if self._paged:
                 w = self._live_width(span)
-                out = self._megastep_jit(
-                    self.params, self._kv_pools,
-                    xfer.to_device(self._page_tables[:, :w]),
-                    xfer.to_device(self._last),
-                    xfer.to_device(self._pos),
-                    xfer.to_device(left), *extra)
-                self._kv_pools = out[0]
-            else:
-                out = self._megastep_jit(
-                    self.params, self._caches,
-                    xfer.to_device(self._last),
-                    xfer.to_device(self._pos),
-                    xfer.to_device(left), *extra)
-                self._caches = out[0]
-            last, pos, emitted = xfer.to_host((out[1], out[2], out[3]))
-            accs = xfer.to_host(out[4]) if k else None
-            self._tfence(self._kv_pools if self._paged
-                         else self._caches,
-                         any(c is not None for c in tctxs))
+                args = (xfer.to_device(self._page_tables[:, :w]),)
+            args += (xfer.to_device(self._last),
+                     xfer.to_device(self._pos),
+                     xfer.to_device(left)) + extra
+            last, pos, emitted, *accs = self._dispatch_decode(
+                self._megastep_jit, args, len(active), tctxs)
+            accs = accs[0] if k else None
         except Exception as e:   # noqa: BLE001 — fails the lanes
             if self._tracer is not None:
                 self._tracer.add_many(
@@ -3099,7 +3138,8 @@ class LMEngine(Logger):
             lane.emitted.extend(int(t) for t in toks)
             lane.remaining -= len(toks)
             lane_tokens[slot] = int(len(toks))
-            self.metrics.inc("tokens_out", len(toks))
+            if len(toks):
+                self._count_tokens(lane.request, len(toks))
         if accs is not None:
             # in-graph drafts are always k wide (padded), so the
             # megastep meters k proposed per live iteration — the
@@ -3235,33 +3275,21 @@ class LMEngine(Logger):
         t0 = time.monotonic()
         try:
             self._fault("engine.step")
+            args = []
             if self._paged:
                 floor = max([e.pos for e in pub] or [0])
                 w = self._live_width(span, floor)
-                args = [self.params, self._kv_pools,
-                        xfer.to_device(self._page_tables[:, :w]),
-                        xfer.to_device(self._last),
-                        xfer.to_device(self._pos),
-                        xfer.to_device(left)] + list(extra)
-                if self.refill_ring:
-                    args += self._ring_args(pub, w)
-                out = self._whilestep_jit(*args)
-                self._kv_pools = out[0]
-            else:
-                out = self._whilestep_jit(
-                    self.params, self._caches,
-                    xfer.to_device(self._last),
-                    xfer.to_device(self._pos),
-                    xfer.to_device(left), *extra)
-                self._caches = out[0]
-            last, pos, emitted, iters = xfer.to_host(
-                (out[1], out[2], out[3], out[4]))
-            accs = xfer.to_host(out[5]) if k else None
-            assign = (xfer.to_host(out[5 + (1 if k else 0)])
-                      if self.refill_ring else None)
-            self._tfence(self._kv_pools if self._paged
-                         else self._caches,
-                         any(c is not None for c in tctxs))
+                args = [xfer.to_device(self._page_tables[:, :w])]
+            args += [xfer.to_device(self._last),
+                     xfer.to_device(self._pos),
+                     xfer.to_device(left)] + list(extra)
+            if self.refill_ring:
+                args += self._ring_args(pub, w)
+            last, pos, emitted, iters, *rest = self._dispatch_decode(
+                self._whilestep_jit, args, len(active) + len(pub),
+                tctxs)
+            accs = rest[0] if k else None
+            assign = rest[-1] if self.refill_ring else None
         except Exception as e:   # noqa: BLE001 — fails the lanes
             if self._tracer is not None:
                 self._tracer.add_many(
@@ -3304,7 +3332,8 @@ class LMEngine(Logger):
                 lane.emitted.extend(toks[:take])
                 lane.remaining -= take
                 toks = toks[take:]
-            self.metrics.inc("tokens_out", lane_tokens[slot])
+                if take:
+                    self._count_tokens(lane.request, take)
         if accs is not None:
             live_iters = int((accs[:iters] >= 0).sum())
             self.metrics.inc("draft_tokens", k * live_iters)
@@ -3349,6 +3378,7 @@ class LMEngine(Logger):
                     # _finish's vacate reset, and the full-width page
                     # table row from the entry's own reservation
                     self._lanes[slot] = lane
+                    lane.request.lane = slot
                     if slot in self._free:
                         self._free.remove(slot)
                     self._page_tables[slot] = entry.table
@@ -3382,6 +3412,7 @@ class LMEngine(Logger):
                 continue
             slot = self._free.pop()
             self._lanes[slot] = lane
+            lane.request.lane = slot
             self._page_tables[slot] = entry.table
             self._pos[slot] = entry.pos
             self._last[slot] = entry.last
@@ -3529,7 +3560,7 @@ class LMEngine(Logger):
         tok = int(xfer.to_host(tok))
         lane.emitted.append(tok)
         lane.remaining -= 1
-        self.metrics.inc("tokens_out")
+        self._count_tokens(req)
         self.metrics.record_ttft(time.monotonic() - req.t_enq)
         entry.pos = req.true_len
         entry.last = tok
@@ -3675,7 +3706,11 @@ class LMEngine(Logger):
 
     def _serve_loop(self):   # hot-path
         rr = 0
+        rec = self.recorder
         while True:
+            # the recorder's turn (ISSUE 26): the phases marked below
+            # partition it; no lock, no fence, no transfer
+            rec.turn()
             # per-tick fault site (latency spikes / replica freezes —
             # a freeze here wedges the worker exactly like a hung
             # device call, the shape the health prober must catch);
@@ -3691,6 +3726,7 @@ class LMEngine(Logger):
                         [i for i, ln in enumerate(self._lanes)
                          if ln is not None], e)
             self._maybe_apply_swap()
+            rec.mark(tracing.ADMIT)
             # the boundary sweep (one pass per loop turn = per
             # megastep when fused decode is on): sheds EVERY expired
             # queued request now, not just those the admission loop
@@ -3703,7 +3739,10 @@ class LMEngine(Logger):
                     if lane is not None]
             self.metrics.set_gauge("slots_busy", len(busy))
             self.metrics.set_gauge_max("slots_busy_peak", len(busy))
+            # lint: allow(lock-discipline): the recorder takes no lock; len() of a deque is one atomic read
+            rec.lanes(len(busy), len(self._queue))
             if not busy:
+                rec.mark(tracing.WAIT)
                 with self._cond:
                     if self._stop:
                         break
@@ -3727,8 +3766,10 @@ class LMEngine(Logger):
             # per token, never its whole prefill
             prefilling = [i for i in busy if self._lanes[i].pending]
             if prefilling:
+                rec.mark(tracing.PREFILL_PREPARE)
                 rr += 1
                 self._advance_prefill(prefilling[rr % len(prefilling)])
+            rec.mark(tracing.STEP_PREPARE)
             active = [i for i, lane in enumerate(self._lanes)
                       if lane is not None and not lane.pending]
             if not active:
@@ -3741,6 +3782,7 @@ class LMEngine(Logger):
                 self._step_speculative(active)
             else:
                 self._step_plain(active)
+        rec.close()
         # drain: engine stopping fails whatever is still queued
         with self._cond:
             pending = list(self._queue)

@@ -28,7 +28,10 @@ Design rules (the ``faults.py`` discipline):
   (and only when) tracing is armed, each dispatch site calls
   ``jax.block_until_ready`` on its outputs before closing the span, so
   durations are device wall time.  That sync is the documented cost of
-  ARMED tracing; unarmed engines never fence.
+  ARMED tracing; unarmed engines never fence.  It also serialises host
+  and device, so a traced run is not the run that is measured: the
+  tracer's spans are for POST-MORTEMS, the loop recorder's (below) for
+  MEASUREMENT.
 - THE FLIGHT RECORDER IS BOUNDED.  Finished requests land in a ring
   buffer (``last`` requests), so the recent past is always
   reconstructable after the fact; a request that errors or blows its
@@ -56,7 +59,21 @@ Chrome-trace/Perfetto JSON (load at https://ui.perfetto.dev or
 chrome://tracing — one track per request), and ``tools/trace_report.py``
 renders per-request waterfalls and aggregates spans into the per-op
 cost ledger (op family x bucket x backend -> p50/p95 duration, dispatch
-count) that the ROADMAP's cost-model autotuning item needs.
+count).
+
+THE LOOP RECORDER (ISSUE 26) is the other half of this module and does
+not depend on the tracer: :class:`LoopRecorder` is on in EVERY engine,
+whatever ``--serve-trace`` says, costs no lock, no fence and no device
+transfer, and stamps ``time.monotonic_ns()`` — the clock of the
+benchmark's window and (through ``benchmark/lib/spans.py``'s fit) of
+the device trace.  One record per turn of ``LMEngine._serve_loop``
+(:data:`PHASES` partition the turn), one per finished request (with
+the stamp of every emitted token), one per HTTP POST
+(:func:`note_http`).  :func:`recorders` keeps the newest four of the
+process, stopped engines' included, so a reader that runs after
+``api.stop()`` still finds them.  All span times of this module — the
+tracer's too — are on that one clock, against the process origin
+``metrics._ORIGIN``.
 
 Context plumbing: the REQUEST context travels two ways.  Down a call
 stack, :func:`use` binds a :class:`TraceContext` to the thread and
@@ -78,6 +95,7 @@ import numpy
 
 from veles_tpu.logger import Logger
 from veles_tpu.serving import lockcheck
+from veles_tpu.serving.metrics import _ORIGIN
 
 _tls = threading.local()
 
@@ -185,14 +203,14 @@ class SpanTracer(Logger):
     _guarded_by = {
         "_sid": "_lock", "_did": "_lock", "_auto_rid": "_lock",
         "_live": "_lock", "_ring": "_lock", "_dumps": "_lock",
-        "_events": "_lock", "_ledger_live": "_lock", "_rng": "_lock",
+        "_events": "_lock", "_rng": "_lock",
         "started": "_lock", "finished": "_lock",
         "sampled_out": "_lock", "dropped_spans": "_lock",
         "dump_count": "_lock",
     }
 
     def __init__(self, mode="all", sample=1.0, last=64, max_spans=4096,
-                 seed=0, name="trace", clock=time.monotonic):
+                 seed=0, name="trace"):
         if mode not in self.MODES:
             raise ValueError("trace mode %r (one of %r)"
                              % (mode, self.MODES))
@@ -200,8 +218,12 @@ class SpanTracer(Logger):
         self.mode = mode
         self.sample = float(sample)
         self.max_spans = int(max_spans)
-        self._clock = clock
-        self._origin = clock()
+        #: ONE clock (ISSUE 26): span times are ``time.monotonic_ns()``
+        #: readings against the PROCESS origin every observability
+        #: endpoint shares — not a per-tracer origin — so a request's
+        #: spans line up with the loop recorder's phases and with
+        #: ``sampled_at`` stamps
+        self._origin = _ORIGIN
         self._lock = lockcheck.make_lock("tracing._lock")
         self._rng = numpy.random.RandomState(seed)
         self._sid = 0
@@ -213,16 +235,6 @@ class SpanTracer(Logger):
         #: engine-scope spans with no request (weight-swap applies,
         #: router drains/deploys) — exported on their own track
         self._events = collections.deque(maxlen=512)
-        #: the LIVE per-op cost ledger (ISSUE 14): maintained
-        #: incrementally as device spans are recorded — same rows, same
-        #: dedup-by-dispatch-id rule as :func:`cost_ledger` over the
-        #: ring (asserted equal on the same trace), but O(1) to serve
-        #: (``GET /ledger.json``) and unbounded in TIME: it survives
-        #: ring eviction and errors-mode discards.  Memory stays
-        #: bounded: exact dispatch/lane counts, quantiles over the
-        #: newest ``ledger_durs`` dispatch durations per row.
-        self.ledger_durs = 2048
-        self._ledger_live = {}           # key -> {durs, lanes, n}
         self.started = 0
         self.finished = 0
         self.sampled_out = 0
@@ -253,7 +265,7 @@ class SpanTracer(Logger):
             % (spec,))
 
     def _now(self):
-        return self._clock() - self._origin
+        return time.monotonic_ns() * 1e-9 - self._origin
 
     # ------------------------------------------------------------ recording
     def start_request(self, rid=None, name="request", cat="request",
@@ -360,7 +372,6 @@ class SpanTracer(Logger):
         still counts once.  Returns the did (None when nothing
         recorded)."""
         did = None
-        recorded = 0
         t0 -= self._origin
         t1 -= self._origin
         with self._lock:
@@ -383,51 +394,11 @@ class SpanTracer(Logger):
                 rec["spans"][self._sid] = _Span(
                     self._sid, ctx.parent, name, cat, t0, t1,
                     span_attrs)
-                recorded += 1
-            if recorded:
-                self._ledger_note(name, attrs, t0, t1, recorded)
         return did
 
     def add(self, ctx, name, cat, t0, t1, attrs=None):
         """One completed span on one request (unbatched dispatches)."""
         return self.add_many((ctx,), name, cat, t0, t1, attrs)
-
-    def _ledger_note(self, name, attrs, t0, t1, lanes):
-        # caller-holds: _lock
-        """Fold one recorded dispatch into the live cost ledger
-        (tracer lock held).  Mirrors :func:`cost_ledger` exactly: only
-        device spans (a ``backend`` attr) count, one duration per
-        dispatch id (this call), ``lanes`` per recorded span copy.
-        Cost: one dict lookup + a deque append — measured and bounded
-        (with the telemetry sampler) by the chaos overhead leg."""
-        backend = (attrs or {}).get("backend") if attrs else None
-        if backend is None:
-            return
-        key = (name, str((attrs or {}).get("bucket", "-")),
-               str(backend))
-        row = self._ledger_live.get(key)
-        if row is None:
-            row = self._ledger_live[key] = {
-                "durs": collections.deque(maxlen=self.ledger_durs),
-                "lanes": 0, "dispatches": 0}
-        row["durs"].append(max(0.0, t1 - t0) * 1e3)
-        row["lanes"] += lanes
-        row["dispatches"] += 1
-
-    def live_ledger(self):
-        """The incrementally-maintained per-op cost ledger — the same
-        row shape (and, while nothing has aged past the ring or the
-        per-row duration window, the same values) as
-        :func:`cost_ledger` over this tracer's records, served without
-        touching the flight recorder.  ``dispatches``/``lanes`` are
-        exact lifetime counts; p50/p95/mean/total cover the newest
-        ``ledger_durs`` dispatches per row."""
-        with self._lock:
-            table = {key: {"durs": list(row["durs"]),
-                           "lanes": row["lanes"],
-                           "dispatches": row["dispatches"]}
-                     for key, row in self._ledger_live.items()}
-        return _ledger_rows(table)
 
     def event(self, name, cat="engine", t0=None, t1=None, attrs=None):
         """An ENGINE-scope span with no owning request (weight-swap
@@ -540,11 +511,16 @@ class SpanTracer(Logger):
                     "dropped_spans": self.dropped_spans,
                     "dumps": self.dump_count}
 
-    def export_chrome(self, last=None):
+    def export_chrome(self, last=None, loops=()):
         """The ring (newest ``last`` requests) + engine events as a
         Chrome-trace/Perfetto JSON object — one track (tid) per
-        request, engine events on tid 0, ts/dur in microseconds.  Load
-        at https://ui.perfetto.dev or chrome://tracing."""
+        request, engine events on tid 0, ts/dur in microseconds.
+        ``loops`` (:class:`LoopRecorder` objects — ``GET /trace.json``
+        passes the served engines') adds one ``engine loop`` track each
+        with the newest turns' phases, below the request tracks and on
+        the same clock: a request's ``decode.step`` spans stand above
+        the loop phases that produced them.  Load at
+        https://ui.perfetto.dev or chrome://tracing."""
         recs = self.requests(last)
         with self._lock:
             events = list(self._events)
@@ -581,6 +557,8 @@ class SpanTracer(Logger):
                             "dur": round(max(0.0, (sp["t1"] or sp["t0"])
                                          - sp["t0"]) * 1e6, 1),
                             "args": args})
+        for i, loop in enumerate(loops):
+            out.extend(loop.chrome_events(len(recs) + 1 + i))
         return {"traceEvents": out, "displayTimeUnit": "ms",
                 "otherData": {"tracer": self.name, "mode": self.mode,
                               "stats": self.stats()}}
@@ -664,7 +642,7 @@ def _pct(sorted_vals, q):
 
 def cost_ledger(records):
     """Aggregate DEVICE spans (those stamped with a ``backend`` attr)
-    into the per-op cost table the autotuning item needs: one row per
+    into the per-op cost table: one row per
     (op family x bucket x backend) with dispatch count and p50/p95/mean
     duration (ms).  Batched spans are deduplicated by dispatch id, so
     ``dispatches`` counts device programs launched, not lanes served
@@ -688,21 +666,12 @@ def cost_ledger(records):
                 seen.add((key, did))
             row["durs"].append(
                 max(0.0, (sp["t1"] or sp["t0"]) - sp["t0"]) * 1e3)
-    return _ledger_rows(table)
-
-
-def _ledger_rows(table):
-    """``{(op, bucket, backend): {durs, lanes[, dispatches]}}`` into
-    the sorted ledger-row list — ONE builder for :func:`cost_ledger`
-    (record aggregation) and :meth:`SpanTracer.live_ledger` (the
-    ISSUE 14 incremental ledger), so the two cannot drift in shape or
-    rounding."""
     rows = []
     for (op, bucket, backend), row in table.items():
         durs = sorted(row["durs"])
         rows.append({
             "op": op, "bucket": bucket, "backend": backend,
-            "dispatches": row.get("dispatches", len(durs)),
+            "dispatches": len(durs),
             "lanes": row["lanes"],
             "p50_ms": round(_pct(durs, 0.50), 4),
             "p95_ms": round(_pct(durs, 0.95), 4),
@@ -750,3 +719,242 @@ def format_waterfall(record, width=40):
             bar, (end - s["t0"]) * 1e3, "  " * depth[s["sid"]],
             s["name"], (" {%s}" % extras) if extras else ""))
     return "\n".join(lines)
+
+
+# ------------------------------------------------------- the loop recorder
+#: the phases of one turn of ``LMEngine._serve_loop``, in the order a turn
+#: passes them.  They PARTITION the turn: phase ``i`` runs from stamp ``i``
+#: to stamp ``i + 1`` of the turn's record, a phase the turn skipped has
+#: no length, and a turn ends at the instant the next one begins.  A phase
+#: includes any wait for the interpreter lock inside it.
+PHASES = ("loop.tick", "loop.admit", "loop.wait", "prefill.prepare",
+          "prefill.dispatch", "step.prepare", "step.dispatch",
+          "step.fetch", "step.emit")
+(TICK, ADMIT, WAIT, PREFILL_PREPARE, PREFILL_DISPATCH, STEP_PREPARE,
+ STEP_DISPATCH, STEP_FETCH, STEP_EMIT) = range(len(PHASES))
+
+#: columns of a turn record (one int64 row of ``LoopRecorder.turns()``):
+#: the sequence number (from 1), the ``len(PHASES) + 1`` stamps
+#: (``time.monotonic_ns()``), the ids of the prefill and the decode
+#: program the turn dispatched (indices into ``LoopRecorder.programs``,
+#: 0 for none), lanes holding a request, lanes the decode dispatch
+#: advanced, queue depth after admission, tokens emitted
+COL_SEQ = 0
+COL_STAMPS = 1
+COL_END = COL_STAMPS + len(PHASES)
+COL_PREFILL_PROGRAM = COL_END + 1
+COL_STEP_PROGRAM = COL_END + 2
+COL_BUSY = COL_END + 3
+COL_ACTIVE = COL_END + 4
+COL_QUEUE = COL_END + 5
+COL_TOKENS = COL_END + 6
+TURN_WIDTH = COL_END + 7
+#: the column that holds the program a dispatch phase called
+_PROGRAM_COL = {PREFILL_DISPATCH: COL_PREFILL_PROGRAM,
+                STEP_DISPATCH: COL_STEP_PROGRAM}
+
+#: one finished (or failed, shed, cancelled) request: stamps in
+#: nanoseconds on the monotonic clock, 0 where the request never got
+#: that far; ``token_ns`` (an ``array('q')``) holds the stamp of every
+#: token the engine emitted for it, in order — ``n_new`` of them for a
+#: request that finished, and their sum over requests is the engine's
+#: ``tokens_out`` counter (a request a weight swap put back in the queue
+#: keeps the stamps of the tokens that swap threw away: the counter
+#: counted them too)
+RequestRecord = collections.namedtuple(
+    "RequestRecord", "enqueue admit first_token done prompt_len n_new "
+    "tokens_out lane outcome token_ns")
+
+#: one HTTP POST: stamps at the request line read, the handler entered,
+#: the handler returned, the reply written; and the status code.  The
+#: HTTP layer's own time is ``(reply - recv) - (result - submit)``.
+HttpRecord = collections.namedtuple(
+    "HttpRecord", "recv submit result reply status")
+
+_recorders = collections.deque(maxlen=4)
+_http = collections.deque(maxlen=8192)
+
+
+def recorders():
+    """The loop recorders of this process, oldest first: the newest four
+    that an engine's ``start()`` made, stopped engines' included (tier-1
+    builds hundreds of engines in one process; the benchmark reads after
+    ``api.stop()``)."""
+    return list(_recorders)
+
+
+def register(recorder):
+    """Make ``recorder`` findable by :func:`recorders` (an engine's
+    ``start()`` does)."""
+    _recorders.append(recorder)
+    return recorder
+
+
+def note_http(recv, submit, result, reply, status):
+    """One HTTP POST's stamps (``restful_api.py::do_POST``); ``deque``
+    appends need no lock."""
+    _http.append(HttpRecord(recv, submit, result, reply, status))
+
+
+def http_records():
+    """The newest HTTP records of this process, oldest first."""
+    return list(_http)
+
+
+class LoopRecorder:
+    """The engine loop's always-on recorder; see the module docstring.
+
+    ONE writer, the engine's worker thread, fills the turn ring: it
+    builds the open turn in a scratch list and commits it to the ring as
+    one row assignment, so a reader's copy never holds a half-written
+    turn.  Request records go to a bounded deque when the request's
+    future settles (the worker thread but for a client's own cancel of
+    a queued request; a deque append needs no lock).  Nothing here takes
+    a lock, touches the device, or depends on a :class:`SpanTracer`."""
+
+    #: turns kept: a power of two; an hour and a half at 12 turns a
+    #: second, seven minutes at 150
+    CAPACITY = 1 << 16
+    #: finished requests kept
+    REQUESTS = 4096
+
+    _synchronized_externally = ("engine worker thread (single writer); "
+                                "readers copy")
+
+    def __init__(self, name="lm", capacity=CAPACITY, requests=REQUESTS):
+        if capacity < 1 or capacity & (capacity - 1):
+            raise ValueError("capacity must be a power of two (got %r)"
+                             % (capacity,))
+        self.name = name
+        self.capacity = capacity
+        self._mask = capacity - 1
+        self._ring = numpy.zeros((capacity, TURN_WIDTH), numpy.int64)
+        self._cur = [0] * TURN_WIDTH
+        self._blank = (0,) * TURN_WIDTH
+        #: turns committed so far (the newest record's sequence number)
+        self.head = 0
+        #: program names by id; id 0 is "none dispatched"
+        self.programs = [""]
+        self._program_ids = {}
+        self._requests = collections.deque(maxlen=requests)
+
+    # ------------------------------------------------ writer: engine thread
+    def turn(self):
+        """A turn of the loop begins now; the open one ends at the same
+        instant."""
+        t = time.monotonic_ns()
+        cur = self._cur
+        if cur[COL_STAMPS]:
+            self._commit(t)
+        cur[COL_STAMPS] = t
+
+    def close(self):
+        """The loop has left its last turn."""
+        if self._cur[COL_STAMPS]:
+            self._commit(time.monotonic_ns())
+
+    def _commit(self, t_end):
+        cur = self._cur
+        cur[COL_END] = t_end
+        # a phase the turn skipped starts where the next one starts
+        for i in range(COL_END - 1, COL_STAMPS, -1):
+            if not cur[i]:
+                cur[i] = cur[i + 1]
+        seq = self.head + 1
+        cur[COL_SEQ] = seq
+        self._ring[(seq - 1) & self._mask] = cur
+        self.head = seq
+        cur[:] = self._blank
+
+    def mark(self, phase):
+        """Phase ``phase`` of the open turn begins now (and the one
+        before it ends)."""
+        self._cur[COL_STAMPS + phase] = time.monotonic_ns()
+
+    def dispatch(self, phase, fn, lanes=0):
+        """:meth:`mark` for a dispatch phase: ``fn`` is the jitted
+        program about to be called (recorded by its name, the one the
+        device trace shows with ``jit_`` before it); ``lanes`` the lanes
+        a decode dispatch advances."""
+        name = fn.__name__
+        pid = self._program_ids.get(name)
+        if pid is None:
+            pid = self._program_ids[name] = len(self.programs)
+            self.programs.append(name)
+        cur = self._cur
+        cur[_PROGRAM_COL[phase]] = pid
+        if lanes:
+            cur[COL_ACTIVE] = lanes
+        cur[COL_STAMPS + phase] = time.monotonic_ns()
+
+    def lanes(self, busy, queued):
+        self._cur[COL_BUSY] = busy
+        self._cur[COL_QUEUE] = queued
+
+    def emitted(self, request, n):
+        """``n`` tokens of ``request`` reached the host now: the stamp of
+        each, and the turn's count."""
+        t = time.monotonic_ns()
+        if n == 1:
+            request.token_ns.append(t)
+        else:
+            request.token_ns.extend([t] * n)
+        self._cur[COL_TOKENS] += n
+
+    def finished(self, request, outcome):
+        """The request's future settled (any thread)."""
+        stamps = request.token_ns
+        self._requests.append(RequestRecord(
+            request.t_enq_ns, request.t_admit_ns,
+            stamps[0] if stamps else 0, time.monotonic_ns(),
+            request.true_len, request.n_new, len(stamps), request.lane,
+            outcome, stamps))
+
+    # -------------------------------------------------------------- readers
+    def turns(self, last=None):
+        """A copy of the turns kept, oldest first, as an int64 array of
+        ``TURN_WIDTH`` columns (``COL_*``).  A record the writer replaced
+        while the copy was taken, or whose sequence number is not the one
+        its place in the ring calls for, is dropped."""
+        h0 = self.head
+        used = min(h0, self.capacity)       # a ring not yet full: its head
+        ring = self._ring[:used].copy()
+        h1 = self.head
+        seq = ring[:, COL_SEQ]
+        # the writer replaced (h0, h1] while the copy ran and may be
+        # committing h1 + 1 now: what those slots held before is dropped
+        keep = (seq > 0) & (seq <= h0) & (seq > h1 + 1 - self.capacity) \
+            & (((seq - 1) & self._mask) == numpy.arange(used))
+        out = ring[keep]
+        out = out[numpy.argsort(out[:, COL_SEQ], kind="stable")]
+        if last is not None:
+            out = out[len(out) - min(int(last), len(out)):]
+        return out
+
+    def requests(self):
+        """The request records kept, oldest first."""
+        return list(self._requests)
+
+    def chrome_events(self, tid, last=256):
+        """The newest ``last`` turns as Chrome-trace events on track
+        ``tid`` (one slice per phase that took time), microseconds
+        against the process origin like the tracer's spans."""
+        origin = int(_ORIGIN * 1e9)
+        out = [{"ph": "M", "pid": 1, "tid": tid, "name": "thread_name",
+                "args": {"name": "engine loop %s" % self.name}}]
+        for row in self.turns(last).tolist():
+            args = {"turn": row[COL_SEQ], "busy": row[COL_BUSY],
+                    "active": row[COL_ACTIVE], "queue": row[COL_QUEUE],
+                    "tokens": row[COL_TOKENS]}
+            for i, phase in enumerate(PHASES):
+                t0, t1 = row[COL_STAMPS + i], row[COL_STAMPS + i + 1]
+                if t1 <= t0:
+                    continue
+                named = args
+                if i in _PROGRAM_COL:
+                    named = dict(
+                        args, program=self.programs[row[_PROGRAM_COL[i]]])
+                out.append({"ph": "X", "pid": 1, "tid": tid, "name": phase,
+                            "cat": "loop", "ts": (t0 - origin) / 1e3,
+                            "dur": (t1 - t0) / 1e3, "args": named})
+        return out
