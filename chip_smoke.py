@@ -682,6 +682,15 @@ def phase_serve(seed, lm=FULL_LM, slots=8, prefill_chunk=32, clients=4,
                   "attn_kernel='auto' fell back to the XLA path on the "
                   "TPU: %s", engine._kernel_fallback_reason)
 
+        in_place = int(engine.metrics.gauge("kv_storage_in_place"))
+        rebuilds = int(engine.metrics.counter("kv_storage_rebuilds"))
+        say("serve", "kv_storage_in_place %d, kv_storage_rebuilds %d",
+            in_place, rebuilds)
+        check(in_place == 1 and rebuilds == 0,
+              "the KV storage is not updated in place (or was lost and "
+              "rebuilt): kv_storage_in_place %d, kv_storage_rebuilds %d",
+              in_place, rebuilds)
+
         params = trainer._to_portable(trainer.params)
         plist = [prompts[k] for k in order]
         with timed("serve", "reference generate x%d" % len(plist)):

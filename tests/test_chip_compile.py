@@ -152,3 +152,62 @@ def test_flash_attention_tpu_traces_at_policy_precision(one_chip,
         "precision=(Precision.HIGHEST, Precision.HIGHEST)") == 9
     text = compile_for(one_chip, grads, shape, shape, shape)
     assert text.count("tpu_custom_call") == 3      # forward, dkv, dq
+
+
+@pytest.fixture(scope="module")
+def kernel_engine(one_chip):
+    """A tiny ``LMEngine`` with the Pallas serving kernels active and
+    the shapes of its arguments on the DESCRIBED chip: the head size is
+    64, under the chip's 128 lanes, where the chip's own layout for a
+    pool of such rows is not the kernels' (the geometry of the
+    benchmark's OPT-1.3B)."""
+    import numpy
+    from veles_tpu import prng
+    from veles_tpu.ops.transformer import init_transformer_params
+    from veles_tpu.serving import LMEngine
+    params = init_transformer_params(
+        prng.get("init"), 256, d_model=128, n_heads=2, n_layers=2,
+        max_len=256)
+    monkeypatch = pytest.MonkeyPatch()
+    monkeypatch.setattr(PK, "on_tpu", lambda: True)
+    try:
+        engine = LMEngine(params, n_heads=2, max_len=256, slots=4,
+                          prefill_chunk=32, paged_kv=16,
+                          attn_kernel="auto", name="aot_tiny")
+        assert engine._kernel_active
+        shapes = lambda tree, where: jax.tree.map(  # noqa: E731
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=where), tree)
+        yield (engine, shapes(engine.params, one_chip),
+               shapes(engine._kv_pools, one_chip),
+               lambda *shape: jax.ShapeDtypeStruct(shape, numpy.int32,
+                                                   sharding=one_chip))
+    finally:
+        monkeypatch.undo()
+
+
+@pytest.mark.parametrize("program", ["chunk", "decode_w1", "decode_w8"])
+def test_engine_programs_update_the_pool_in_place(kernel_engine, program):
+    """ISSUE 27: compiled for the chip, the decode and the chunk program
+    hold no copy with a pool's shape — not of the arguments (they are
+    donated), not around the row writes (update slices, not a scatter),
+    not into or out of the kernel (a pool row packs two heads of 64 into
+    the chip's 128 lanes, so the pool lies the way the kernel reads it)
+    — and list every pool leaf under ``input_output_alias``."""
+    from veles_tpu.serving.lm_engine import compiled_storage_report
+    engine, params, pools, ints = kernel_engine
+    if program == "chunk":
+        lowered = engine._chunk_jit.lower(
+            params, pools, ints(engine._max_pages),
+            ints(engine.prefill_chunk), ints(), ints())
+    else:
+        width = int(program.rsplit("w", 1)[1])
+        lowered = engine._step_jit.lower(
+            params, pools, ints(engine.slots, width), ints(engine.slots),
+            ints(engine.slots))
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    leaves = jax.tree.leaves(engine._kv_pools)
+    copies, aliased = compiled_storage_report(text, leaves[0])
+    assert copies == 0, "%d whole-pool copies in %s" % (copies, program)
+    assert aliased == len(leaves) == 4

@@ -436,3 +436,175 @@ class TestEngineLifecycle:
         finally:
             engine._whilestep_jit = real
             engine.stop()
+
+
+def _greedy(params, prompt, n_new, max_len=96, n_heads=2):
+    import jax.numpy as jnp
+    from veles_tpu.ops.transformer import generate
+    return numpy.asarray(generate(
+        params, jnp.asarray([prompt], jnp.int32), n_new, n_heads,
+        temperature=0.0, max_len=max_len))[0]
+
+
+class TestStorageLost:
+    """ISSUE 27: the engine's programs take the KV storage DONATED, so a
+    dispatch that raises once the runtime has consumed its arguments
+    leaves no storage behind.  The rule (``LMEngine._donating``): inputs
+    intact, behave as before (the faulted request or lanes fail, the
+    survivors finish on their rows); inputs consumed, fail every request
+    that held rows, drop the trie, bring the allocator home whole, put
+    fresh storage in place, count it, keep serving."""
+
+    LONG = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9, 7, 9, 3, 2, 3, 8, 4,
+            6, 2, 6, 4, 3, 3, 8, 3, 2, 7, 9, 5, 1, 2, 8, 8, 4, 1, 9, 7]
+
+    @staticmethod
+    def _engine(layout, **extra):
+        from veles_tpu.serving import LMEngine
+        features = {"prefill_chunk": 8, "prefix_cache": 32}
+        if layout == "paged":
+            features["paged_kv"] = True
+        params = _params()
+        return params, LMEngine(params, n_heads=2, max_len=96, slots=2,
+                                name="kv_lost", **features, **extra)
+
+    @staticmethod
+    def _consuming(engine, attr, ready):
+        """Replace the program at ``attr`` by a stub that, the first time
+        ``ready()`` holds, DELETES the storage it was handed and raises —
+        what a dispatch does that fails after the runtime took its
+        donated arguments.  Every other call goes through."""
+        real = getattr(engine, attr)
+        fired = []
+
+        def stub(p, storage, *args):
+            if not fired and ready():
+                fired.append(True)
+                for pair in storage:
+                    for a in pair:
+                        a.delete()
+                raise RuntimeError("device lost mid-dispatch")
+            return real(p, storage, *args)
+
+        setattr(engine, attr, stub)
+        return fired
+
+    @pytest.mark.parametrize("layout", ["paged", "contiguous"])
+    @pytest.mark.parametrize("path", ["decode", "chunk"])
+    def test_consumed_storage_fails_holders_and_rebuilds(self, layout,
+                                                         path):
+        params, engine = self._engine(layout)
+        engine.start()
+        lanes = engine._lanes
+        if path == "decode":
+            # the first decode dispatch that runs beside a prefilling lane
+            fired = self._consuming(engine, "_step_jit", lambda: any(
+                ln is not None and ln.pending for ln in lanes))
+        else:
+            # the first prompt chunk that runs beside a decoding lane
+            fired = self._consuming(engine, "_chunk_jit", lambda: any(
+                ln is not None and not ln.pending and ln.emitted
+                for ln in lanes))
+        try:
+            # something for the trie to hold (and, paged, pages with it)
+            seed_prompt = self.LONG[:20]
+            got = engine.submit(seed_prompt, 3).result(timeout=120)
+            numpy.testing.assert_array_equal(
+                numpy.concatenate([seed_prompt, got]),
+                _greedy(params, seed_prompt, 3))
+            assert engine._trie.size >= 2
+            fa = engine.submit([1, 2, 3], 30)        # decodes
+            fb = engine.submit(self.LONG[::-1], 4)   # prefills, 5 chunks
+            fc = engine.submit([2, 4, 6, 8], 6)      # queued: no slot
+            for f in (fa, fb):
+                with pytest.raises(RuntimeError, match="device lost"):
+                    f.result(timeout=120)
+            assert fired
+            # the queued request held nothing: served token for token
+            numpy.testing.assert_array_equal(
+                numpy.concatenate([[2, 4, 6, 8], fc.result(timeout=120)]),
+                _greedy(params, [2, 4, 6, 8], 6))
+            assert engine.metrics.counter("kv_storage_rebuilds") == 1
+            assert engine._trie.size == 0       # dropped with the rows
+            leaves = [a for pair in engine._storage() for a in pair]
+            assert not any(a.is_deleted() for a in leaves)
+            # and the next one, through the fresh storage, as well
+            got = engine.submit(self.LONG, 5).result(timeout=120)
+            numpy.testing.assert_array_equal(
+                numpy.concatenate([self.LONG, got]),
+                _greedy(params, self.LONG, 5))
+            assert engine.metrics.counter("kv_storage_rebuilds") == 1
+        finally:
+            engine.stop()
+        if layout == "paged":
+            # the allocator is whole: what is not free, the trie holds
+            # for the prompts served since
+            engine.verify_pool_invariants()
+            assert engine._pool.pinned_pages == 0
+            assert engine._pool.free_pages + engine._trie.size \
+                == engine._pool.num_pages
+            engine._trie.clear()
+            assert engine._pool.free_pages == engine._pool.num_pages
+
+    def test_allocator_whole_right_after_the_loss(self):
+        """Between the loss and the next admission nothing is held:
+        ``kv_pages_free`` equals the total, no pin, no trie entry, every
+        table row on scratch."""
+        params, engine = self._engine("paged")
+        engine.start()
+        lanes = engine._lanes
+        self._consuming(engine, "_step_jit", lambda: any(
+            ln is not None and ln.pending for ln in lanes))
+        try:
+            engine.submit(self.LONG[:20], 3).result(timeout=120)
+            fa = engine.submit([1, 2, 3], 30)
+            fb = engine.submit(self.LONG[::-1], 4)
+            for f in (fa, fb):
+                with pytest.raises(RuntimeError, match="device lost"):
+                    f.result(timeout=120)
+            deadline = time.monotonic() + 30.0
+            while engine.metrics.counter("kv_storage_rebuilds") < 1:
+                assert time.monotonic() < deadline
+                time.sleep(0.01)
+            g = engine.metrics.snapshot()["gauges"]
+            assert g["kv_pages_free"] == g["kv_pages_total"]
+            assert g["kv_pages_pinned"] == 0
+            assert g["prefix_cache_chunks"] == 0
+            assert (engine._page_tables == KVPagePool.SCRATCH).all()
+            engine.verify_pool_invariants()
+        finally:
+            engine.stop()
+
+    @pytest.mark.parametrize("layout", ["paged", "contiguous"])
+    @pytest.mark.parametrize("site", ["engine.step", "engine.chunk"])
+    def test_injected_fault_keeps_todays_behaviour(self, layout, site):
+        """An injected fault fires BEFORE the program is called: the
+        storage is intact, so only the faulted lanes fail and the
+        survivor finishes on its rows, token for token — 0 rebuilds."""
+        from veles_tpu.serving import FaultPlan
+        from veles_tpu.serving.faults import InjectedFault
+        # the first decode dispatch runs while the long prompt still
+        # prefills (its lane survives a step fault); the 4th chunk is
+        # the long prompt's (the short one has a single chunk), run
+        # while the short one decodes (ITS lane survives a chunk fault)
+        plan = FaultPlan().arm(site, calls={1 if site == "engine.step"
+                                            else 4})
+        params, engine = self._engine(layout, faults=plan)
+        engine.start()
+        try:
+            long_prompt = self.LONG[::-1]
+            fa = engine.submit([1, 2, 3], 30)
+            fb = engine.submit(long_prompt, 4)
+            failed, survivor, prompt, n_new = (
+                (fa, fb, long_prompt, 4) if site == "engine.step"
+                else (fb, fa, [1, 2, 3], 30))
+            with pytest.raises(InjectedFault):
+                failed.result(timeout=120)
+            numpy.testing.assert_array_equal(
+                numpy.concatenate([prompt, survivor.result(timeout=120)]),
+                _greedy(params, prompt, n_new))
+            assert engine.metrics.counter("kv_storage_rebuilds") == 0
+        finally:
+            engine.stop()
+        if layout == "paged":
+            engine.verify_pool_invariants()

@@ -337,6 +337,112 @@ class TestFastPathParity:
             engine.stop()
 
 
+#: ISSUE 27: both KV layouts, with and without speculation and the two
+#: fused decode loops — every family that returns the storage
+IN_PLACE_SETS = [
+    {},
+    {"prefill_chunk": 8, "prefix_cache": 32},
+    {"spec_k": 3},
+    {"megastep": 4},
+    {"megastep": 4, "megastep_mode": "while", "spec_k": 3},
+    {"paged_kv": True, "prefill_chunk": 8},
+    {"paged_kv": True, "prefill_chunk": 8, "prefix_cache": 32,
+     "spec_k": 3},
+    {"paged_kv": True, "prefill_chunk": 8, "megastep": 4},
+    {"paged_kv": True, "prefill_chunk": 8, "megastep": "while",
+     "refill_ring": 2},
+    {"paged_kv": True, "prefill_chunk": 8, "attn_kernel": "force"},
+    {"tp": 2, "paged_kv": True, "prefill_chunk": 8},
+]
+
+
+class TestStorageInPlace:
+    """ISSUE 27: every engine program that returns the KV storage takes
+    it DONATED — the arrays that go into a dispatch are consumed by it
+    (no dispatch copies a pool or holds a second one), and the tokens
+    are what they were."""
+
+    @staticmethod
+    def _leaves(engine):
+        return [a for pair in engine._storage() for a in pair]
+
+    @pytest.mark.parametrize("features", IN_PLACE_SETS,
+                             ids=lambda f: "+".join(sorted(f)) or "off")
+    def test_dispatches_consume_their_storage(self, features,
+                                              serving_mesh):
+        from veles_tpu.serving import LMEngine
+        if features.get("tp"):
+            serving_mesh(features["tp"])
+        params = _params()
+        engine = LMEngine(params, n_heads=2, max_len=96, slots=2,
+                          name="in_place", **features)
+        made = self._leaves(engine)
+        assert not any(a.is_deleted() for a in made)
+        engine.start()
+        try:
+            # warm-up ran every family once: what the constructor made
+            # went into the first program and never came back
+            assert all(a.is_deleted() for a in made)
+            assert engine.metrics.snapshot()["gauges"][
+                "kv_storage_in_place"] == 1
+            warm = self._leaves(engine)
+            assert not any(a.is_deleted() for a in warm)
+            # the decode program of this engine, watched: what storage
+            # each of its dispatches was handed
+            name = next(n for n in ("_whilestep_jit", "_megastep_jit",
+                                    "_verify_jit", "_step_jit")
+                        if getattr(engine, n) is not None)
+            real, handed = getattr(engine, name), []
+
+            def watched(p, storage, *args):
+                handed.append([a for pair in storage for a in pair])
+                return real(p, storage, *args)
+
+            setattr(engine, name, watched)
+            prompt = [5, 1, 5, 1, 5, 1, 5, 1, 5, 2, 3]
+            got = numpy.concatenate(
+                [prompt, engine.submit(prompt, 9).result(timeout=120)])
+            numpy.testing.assert_array_equal(
+                got, _greedy(params, prompt, 9, 96))
+            assert handed, "no decode dispatch ran"
+            assert all(a.is_deleted() for a in warm)
+            for leaves in handed:
+                assert all(a.is_deleted() for a in leaves)
+            live = self._leaves(engine)
+            assert len(live) == len(made)
+            assert not any(a.is_deleted() for a in live)
+            assert engine.metrics.counter("kv_storage_rebuilds") == 0
+        finally:
+            engine.stop()
+
+    def test_reading_programs_do_not_donate(self):
+        """``chunk_extract`` only READS the caches and ``prefill`` never
+        sees them: neither may consume anything — and the parameters go
+        into every program and stay."""
+        import jax
+        from veles_tpu.serving import LMEngine
+        params = _params()
+        engine = LMEngine(params, n_heads=2, max_len=96, slots=2,
+                          prefill_chunk=8, prefix_cache=32,
+                          name="in_place_ro").start()
+        try:
+            p = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9, 7, 9, 3, 2, 3]
+            for _ in range(2):           # the second one hits the trie
+                got = numpy.concatenate(
+                    [p, engine.submit(p, 5).result(timeout=120)])
+                numpy.testing.assert_array_equal(
+                    got, _greedy(params, p, 5, 96))
+            assert engine.metrics.counter("prefix_hit_chunks") >= 1
+            # the trie's rows came out of chunk_extract and are alive
+            node = next(iter(engine._trie.root.children.values()))
+            assert not any(a.is_deleted()
+                           for pair in node.rows for a in pair)
+            assert not any(a.is_deleted()
+                           for a in jax.tree.leaves(engine.params))
+        finally:
+            engine.stop()
+
+
 class TestPagedKV:
     """ISSUE 6 acceptance: zero-copy prefix sharing, the paged compile
     bound, and pool-pressure behavior (queue/shed, never a hang)."""

@@ -286,24 +286,32 @@ class TestPagedFlashDecode:
         (4, 10, 2),            # window + sinks, multi-query
         (1, 10, 1),            # single query at the sink edge
     ])
-    def test_matches_xla_paged_path(self, c, window, sinks):
+    @pytest.mark.parametrize("pack", [1, 2])
+    def test_matches_xla_paged_path(self, c, window, sinks, pack):
+        """``pack`` heads to a pool row (ISSUE 27: the layout an engine
+        with the kernels active holds, ``pool_pack``) read the same."""
         from veles_tpu.ops import pallas_kernels as PK
         q, kp, vp, ptab, pos = self._setup(c=c, m=6, n_pages=13,
                                            seed=c + (window or 0))
-        got = PK.paged_flash_decode(q, kp, vp, ptab, pos,
-                                    window=window, sinks=sinks)
+        got = PK.paged_flash_decode(
+            q, PK.pack_heads(kp, pack), PK.pack_heads(vp, pack), ptab,
+            pos, window=window, sinks=sinks)
         ref = self._xla(q, kp, vp, ptab, pos, c, window, sinks)
         numpy.testing.assert_allclose(numpy.asarray(got),
                                       numpy.asarray(ref),
                                       rtol=1e-5, atol=1e-6)
 
-    @pytest.mark.parametrize("h,kv", [(4, 1), (4, 4), (8, 2)])
-    def test_grouped_query_layouts(self, h, kv):
+    @pytest.mark.parametrize("h,kv,pack", [
+        (4, 1, 1), (4, 4, 1), (8, 2, 1), (4, 4, 4), (8, 2, 2),
+        (8, 4, 2)])
+    def test_grouped_query_layouts(self, h, kv, pack):
         """GQA folds into the kernel as a (kv, g·c) row reshape — every
-        grouping must agree with jnp.repeat's head mapping."""
+        grouping must agree with jnp.repeat's head mapping, and so must
+        every packing of the heads into pool rows."""
         from veles_tpu.ops import pallas_kernels as PK
         q, kp, vp, ptab, pos = self._setup(h=h, kv=kv, c=3, seed=h * kv)
-        got = PK.paged_flash_decode(q, kp, vp, ptab, pos)
+        got = PK.paged_flash_decode(q, PK.pack_heads(kp, pack),
+                                    PK.pack_heads(vp, pack), ptab, pos)
         ref = self._xla(q, kp, vp, ptab, pos, 3)
         numpy.testing.assert_allclose(numpy.asarray(got),
                                       numpy.asarray(ref),
@@ -322,11 +330,14 @@ class TestPagedFlashDecode:
                                       numpy.asarray(ref),
                                       rtol=1e-5, atol=1e-6)
 
-    def test_mha_paged_chunk_step_kernel_route(self):
+    @pytest.mark.parametrize("pack", [1, 2])
+    def test_mha_paged_chunk_step_kernel_route(self, pack):
         """attention.mha_paged_chunk_step(attn_kernel='decode') —
         the wired route the engine's step/verify programs take —
         matches its own XLA path: same projections, same rope, same
-        pool writes (bit-identical), attention to fp32 roundoff."""
+        pool writes (bit-identical, in the packed rows too),
+        attention to fp32 roundoff."""
+        from veles_tpu.ops.pallas_kernels import pack_heads
         from veles_tpu import prng
         from veles_tpu.ops.attention import (init_mha_params,
                                              mha_paged_chunk_step)
@@ -345,12 +356,13 @@ class TestPagedFlashDecode:
             params, x, kp, vp, ptab, pos, n_heads, rope=True,
             window=16, sinks=1)
         got_o, got_k, got_v = mha_paged_chunk_step(
-            params, x, kp, vp, ptab, pos, n_heads, rope=True,
-            window=16, sinks=1, attn_kernel="decode")
-        numpy.testing.assert_array_equal(numpy.asarray(got_k),
-                                         numpy.asarray(ref_k))
-        numpy.testing.assert_array_equal(numpy.asarray(got_v),
-                                         numpy.asarray(ref_v))
+            params, x, pack_heads(kp, pack), pack_heads(vp, pack), ptab,
+            pos, n_heads, rope=True, window=16, sinks=1,
+            attn_kernel="decode")
+        numpy.testing.assert_array_equal(
+            numpy.asarray(got_k), numpy.asarray(pack_heads(ref_k, pack)))
+        numpy.testing.assert_array_equal(
+            numpy.asarray(got_v), numpy.asarray(pack_heads(ref_v, pack)))
         numpy.testing.assert_allclose(numpy.asarray(got_o),
                                       numpy.asarray(ref_o),
                                       rtol=1e-4, atol=1e-5)
@@ -395,20 +407,24 @@ class TestPagedFlashPrefill:
         (2, None, 0),
         (3, 20, 2),            # window reaching into history + sinks
     ])
-    def test_matches_xla_and_installs(self, n_hist, window, sinks):
+    @pytest.mark.parametrize("pack", [1, 2])
+    def test_matches_xla_and_installs(self, n_hist, window, sinks, pack):
         from veles_tpu.ops import pallas_kernels as PK
         q, kn, vn, kp, vp, ptab, pos = self._setup(
             n_hist=n_hist, seed=n_hist + (window or 0))
         got_o, got_k, got_v = PK.paged_flash_prefill(
-            q, kn, vn, kp, vp, ptab, pos, window=window, sinks=sinks)
+            q, kn, vn, PK.pack_heads(kp, pack), PK.pack_heads(vp, pack),
+            ptab, pos, window=window, sinks=sinks)
         ref_o, ref_k, ref_v = self._xla(q, kn, vn, kp, vp, ptab, pos,
                                         window, sinks)
         # the install is a ROW COPY — bit-identical, and pages outside
         # the chunk's target untouched (the aliasing contract)
-        numpy.testing.assert_array_equal(numpy.asarray(got_k),
-                                         numpy.asarray(ref_k))
-        numpy.testing.assert_array_equal(numpy.asarray(got_v),
-                                         numpy.asarray(ref_v))
+        numpy.testing.assert_array_equal(
+            numpy.asarray(got_k),
+            numpy.asarray(PK.pack_heads(ref_k, pack)))
+        numpy.testing.assert_array_equal(
+            numpy.asarray(got_v),
+            numpy.asarray(PK.pack_heads(ref_v, pack)))
         numpy.testing.assert_allclose(numpy.asarray(got_o),
                                       numpy.asarray(ref_o),
                                       rtol=1e-5, atol=1e-6)
@@ -434,10 +450,12 @@ class TestPagedFlashPrefill:
             PK.paged_flash_prefill(q[:, :, :4], kn[:, :, :4],
                                    vn[:, :, :4], kp, vp, ptab, pos)
 
-    def test_mha_paged_chunk_step_prefill_route(self):
+    @pytest.mark.parametrize("pack", [1, 4])
+    def test_mha_paged_chunk_step_prefill_route(self, pack):
         """The engine's chunk program route ('prefill') against the
         XLA path at a page-aligned frontier — outputs to roundoff,
-        pool installs bit-identical."""
+        pool installs bit-identical (in the packed rows too)."""
+        from veles_tpu.ops.pallas_kernels import pack_heads
         from veles_tpu import prng
         from veles_tpu.ops.attention import (init_mha_params,
                                              mha_paged_chunk_step)
@@ -455,12 +473,12 @@ class TestPagedFlashPrefill:
         ref_o, ref_k, ref_v = mha_paged_chunk_step(
             params, x, kp, vp, ptab, pos, n_heads, rope=True)
         got_o, got_k, got_v = mha_paged_chunk_step(
-            params, x, kp, vp, ptab, pos, n_heads, rope=True,
-            attn_kernel="prefill")
-        numpy.testing.assert_array_equal(numpy.asarray(got_k),
-                                         numpy.asarray(ref_k))
-        numpy.testing.assert_array_equal(numpy.asarray(got_v),
-                                         numpy.asarray(ref_v))
+            params, x, pack_heads(kp, pack), pack_heads(vp, pack), ptab,
+            pos, n_heads, rope=True, attn_kernel="prefill")
+        numpy.testing.assert_array_equal(
+            numpy.asarray(got_k), numpy.asarray(pack_heads(ref_k, pack)))
+        numpy.testing.assert_array_equal(
+            numpy.asarray(got_v), numpy.asarray(pack_heads(ref_v, pack)))
         numpy.testing.assert_allclose(numpy.asarray(got_o),
                                       numpy.asarray(ref_o),
                                       rtol=1e-4, atol=1e-5)
@@ -475,6 +493,25 @@ class TestServingKernelSupport:
         assert not ok and "paged_kv" in reason
         ok, reason = PK.serving_kernels_supported(True, 4, 3, 16, 8)
         assert not ok and "divisible" in reason
+
+    @pytest.mark.parametrize("kv,dh,pack", [
+        (32, 64, 2),           # OPT-1.3B: two heads fill the 128 lanes
+        (16, 128, 1), (8, 256, 1),
+        (2, 8, 2), (4, 16, 4),  # as far as the heads divide
+        (3, 64, 1), (32, 96, 1)])
+    def test_pool_pack(self, kv, dh, pack):
+        """ISSUE 27: heads to a pool row — as many as fill 128 lanes, as
+        far as kv_heads divides; and packing is a pure relabelling."""
+        from veles_tpu.ops import pallas_kernels as PK
+        assert PK.pool_pack(kv, dh) == pack
+        x = jnp.arange(2 * kv * 3 * dh, dtype=jnp.float32).reshape(
+            2, kv, 3, dh)
+        packed = numpy.asarray(PK.pack_heads(x, pack))
+        assert packed.shape == (2, kv // pack, 3, pack * dh)
+        for e in range(pack):
+            numpy.testing.assert_array_equal(
+                packed[..., e * dh:(e + 1) * dh],
+                numpy.asarray(x[:, e::pack]))
 
 
 class TestFlashAttentionTPUCoverage:

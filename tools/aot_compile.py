@@ -14,7 +14,12 @@ by hand::
   program at minibatch 128, 227x227 crops, 1000 classes, fp32 and bf16;
 - ``lm``: the char_lm train step and the engine's prefill-chunk and decode
   programs at d_model 2048 / 16 heads / 4 layers / vocab 32768, Pallas
-  serving kernels active;
+  serving kernels active; then the same two programs at the benchmark's
+  head size (32 heads of 64, 2 layers, 320 pages), where the chip's
+  default layout for the pool is not the kernels'.  For each engine
+  program it prints the copies of a whole KV pool and the pool leaves
+  updated in place (``compiled_storage_report``), and exits non-zero when
+  a copy is there or a leaf is not aliased;
 - ``mesh``: the ``ShardedTrainer`` AlexNet step on a data 2 x model 2 mesh
   (checks for an all-reduce);
 - ``tp``: the ``LMEngine(tp=4)`` decode program over four chips.
@@ -138,15 +143,59 @@ def mesh(topo):
         raise SystemExit("no all-reduce in the compiled mesh step")
 
 
-def build_lm():
-    wf = chip_smoke._build_char_lm(1, LM, run=False)
+def build_lm(lm=LM):
+    wf = chip_smoke._build_char_lm(1, lm, run=False)
     trainer = wf.trainer
     return trainer, trainer._to_portable(trainer.params)
 
 
-def lm(one_chip):
+def kernel_engine(params, n_heads, **kwargs):
+    """An ``LMEngine`` with the Pallas serving kernels active."""
     from veles_tpu.ops import pallas_kernels as PK
     from veles_tpu.serving import LMEngine
+    PK.on_tpu = lambda: True        # trace the compiled-kernel branch
+    eng = LMEngine(params, n_heads=n_heads, attn_kernel="auto", **kwargs)
+    if not eng._kernel_active:
+        raise SystemExit("the engine did not select the Pallas kernels")
+    return eng
+
+
+def storage_in_place(name, text, eng):
+    """Print what the compiled program does to the KV storage; exit
+    non-zero on a whole-pool copy or a pool leaf not updated in place."""
+    from veles_tpu.serving.lm_engine import compiled_storage_report
+    leaves = jax.tree.leaves(eng._kv_pools)
+    copies, aliased = compiled_storage_report(text, leaves[0])
+    print("%-34s pool copies x%d  pool leaves in place %d of %d"
+          % (name, copies, aliased, len(leaves)), flush=True)
+    if copies or aliased < len(leaves):
+        raise SystemExit("%s: the KV storage is not updated in place"
+                         % name)
+
+
+def engine_programs(tag, eng, one_chip, widths):
+    """Compile ``eng``'s chunk program and its decode program at
+    ``widths`` of the page table; each must hold a Pallas kernel and
+    update the pools in place."""
+    s = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    a_params = abstract(eng.params, one_chip)
+    pools = abstract(eng._kv_pools, one_chip)
+    slots, page = eng.slots, eng.prefill_chunk
+    name = "%s prefill chunk (kernel)" % tag
+    storage_in_place(name, compile_(
+        name, eng._chunk_jit, a_params, pools, s((eng._max_pages,)),
+        s((page,)), s(()), s(())), eng)
+    for width in widths:
+        name = "%s decode step width %d" % (tag, width)
+        text = compile_(name, eng._step_jit, a_params, pools,
+                        s((slots, width)), s((slots,)), s((slots,)))
+        if "tpu_custom_call" not in text:
+            raise SystemExit("no Pallas kernel in the decode program")
+        storage_in_place(name, text, eng)
+
+
+def lm(one_chip):
     trainer, params = build_lm()
     s = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
@@ -155,26 +204,17 @@ def lm(one_chip):
              abstract(trainer.opt_state, one_chip),
              s((LM["minibatch"], LM["seq_len"]), jnp.int32),
              s((LM["minibatch"],), jnp.float32), s((), jnp.int32))
-    PK.on_tpu = lambda: True        # trace the compiled-kernel branch
-    slots, page = 8, 32
-    eng = LMEngine(params, n_heads=trainer.n_heads, max_len=LM["max_len"],
-                   slots=slots, prefill_chunk=page, paged_kv=True,
-                   attn_kernel="auto")
-    if not eng._kernel_active:
-        raise SystemExit("the engine did not select the Pallas kernels")
-    a_params = abstract(eng.params, one_chip)
-    pools = abstract(eng._kv_pools, one_chip)
-    m = LM["max_len"] // page
-    compile_("engine prefill chunk (kernel)", eng._chunk_jit,
-             a_params, pools, s((m,), jnp.int32), s((page,), jnp.int32),
-             s((), jnp.int32), s((), jnp.int32))
-    for width in (1, 8, m):
-        text = compile_("engine decode step width %d" % width,
-                        eng._step_jit, a_params, pools,
-                        s((slots, width), jnp.int32),
-                        s((slots,), jnp.int32), s((slots,), jnp.int32))
-        if "tpu_custom_call" not in text:
-            raise SystemExit("no Pallas kernel in the decode program")
+    eng = kernel_engine(params, trainer.n_heads, max_len=LM["max_len"],
+                        slots=8, prefill_chunk=32, paged_kv=True)
+    engine_programs("engine", eng, one_chip,
+                    (1, 8, LM["max_len"] // 32))
+    # the benchmark's geometry (OPT-1.3B: 32 heads of 64, 8 lanes, 320 pages
+    # of 32), cut to 2 layers: under 128 lanes of head size the chip's own
+    # layout for a pool is not row-major, which is where the copies were
+    _, params = build_lm(dict(LM, n_heads=32, n_layers=2))
+    eng = kernel_engine(params, 32, max_len=LM["max_len"], slots=8,
+                        prefill_chunk=32, paged_kv=320)
+    engine_programs("engine dh64", eng, one_chip, (1, 64))
 
 
 def tp(topo):
@@ -195,12 +235,20 @@ def tp(topo):
     s = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=on(P()))
     # the engine pinned its out_shardings to the CPU mesh: re-jit the same
-    # function with the described mesh's
-    step = jax.jit(eng._step_jit.__wrapped__, out_shardings=(
-        [(kv, kv)] * len(eng.params["blocks"]), on(P())))
-    compile_("engine decode step tp=4", step, a_params, pools,
-             s((slots, 8), jnp.int32), s((slots,), jnp.int32),
-             s((slots,), jnp.int32))
+    # function with the described mesh's, the pools donated as the engine's
+    step = jax.jit(eng._step_jit.__wrapped__, donate_argnums=(1,),
+                   out_shardings=([(kv, kv)] * len(eng.params["blocks"]),
+                                  on(P())))
+    text = compile_("engine decode step tp=4", step, a_params, pools,
+                    s((slots, 8), jnp.int32), s((slots,), jnp.int32),
+                    s((slots,), jnp.int32))
+    from veles_tpu.serving.lm_engine import compiled_storage_report
+    leaves = jax.tree.leaves(pools)
+    _, aliased = compiled_storage_report(text, leaves[0])
+    print("%-34s pool leaves in place %d of %d"
+          % ("engine decode step tp=4", aliased, len(leaves)), flush=True)
+    if aliased < len(leaves):
+        raise SystemExit("tp=4: a pool leaf is not updated in place")
 
 
 def main(argv):

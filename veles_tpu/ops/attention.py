@@ -515,17 +515,33 @@ def paged_view(pool, ptab):
 
 
 def paged_write(pool, ptab, pos, rows, write_mask=None):
-    """Scatter ``c`` new K (or V) rows into the pool at the lanes'
+    """Write ``c`` new K (or V) rows into the pool at the lanes'
     LINEAR positions [pos, pos+c) — the paged sibling of the contiguous
     ``dynamic_update_slice`` write.
 
     rows: (..., kv_heads, c, head_dim); ptab (..., m); pos (...,) —
-    leading dims are the lane batch (absent for a single lane).  Each
-    position p maps to (page ptab[p // page], offset p % page), so a
-    write may straddle two pages; the scatter handles that uniformly.
+    leading dims are the lane batch (absent for a single lane).  The
+    pool is (n_pages, kv_heads/r, page, r·head_dim): r heads to a row
+    where the serving kernels are active (``pallas_kernels.pool_pack``),
+    r = 1 otherwise; r is read off the pool's last axis.  Each position
+    p maps to (page ptab[p // page], offset p % page), so a write may
+    straddle two pages; every position is written on its own.
     Duplicate targets (every free lane parks on the scratch page) are
-    resolved arbitrarily — by construction only garbage rows collide,
-    and nothing live ever attends them.
+    resolved by order, the last lane winning — by construction only
+    garbage rows collide, and nothing live ever attends them.
+
+    The write is one ``dynamic_update_slice`` of a (1, kv_heads/r, 1,
+    r·head_dim) window per position — b·c of them, unrolled — and NOT
+    one scatter.  A scatter over the page and offset axes made the TPU
+    compiler hold the pool with those two axes major-most: it converted
+    the whole pool before and after every write (two copies of each
+    layer's pool per dispatch; 1.03 ms for 0.03 on a f32[321,32,32,64]
+    pool).  A scatter over the three leading axes
+    leaves the layout alone and is slow itself (the cell served 95
+    tokens/s with it for 229; PERF.md section 6, PR 27).  An update
+    slice takes the pool in whatever layout it lies and touches the
+    rows it writes, nothing else; with the pool donated to the program
+    it is an update in place.
 
     ``write_mask`` (traced bool, one per lane) REDIRECTS a masked-out
     lane's whole write onto the reserved scratch page (pool row 0 —
@@ -541,10 +557,29 @@ def paged_write(pool, ptab, pos, rows, write_mask=None):
     offsets = linear % page
     if write_mask is not None:
         page_ids = jnp.where(write_mask[..., None], page_ids, 0)
-    # advanced indices split by the head slice: index dims move to the
-    # front (numpy rules), so the update is (..., c, kv, dh)
-    return pool.at[page_ids, :, offsets, :].set(
-        jnp.moveaxis(rows, -3, -2))
+    # one (1, kv/r, 1, r·dh) window per written position (a position's
+    # (kv, dh) rows ARE its packed rows, reshaped), lanes major like
+    # the ids
+    new = jnp.moveaxis(rows, -3, -2).reshape(
+        -1, pool.shape[1], 1, pool.shape[3])
+    return _write_rows(pool, new, page_ids.reshape(-1),
+                       offsets.reshape(-1))
+
+
+@jax.jit
+def _write_rows(pool, new, page_ids, offsets):
+    """``paged_write``'s update slices, one per row of ``new``.  A jit
+    of its own so that a program traces it ONCE for all its layers and
+    the lowering holds one function, called per pool: unrolled inline,
+    the 384 slices of a 24-layer decode program doubled the time to
+    trace and lower it, for each width of the ladder (setup_s; PERF.md
+    section 6, PR 27).  XLA inlines the calls: the compiled program is
+    the same."""
+    zero = jnp.zeros((), page_ids.dtype)
+    for j in range(new.shape[0]):
+        pool = jax.lax.dynamic_update_slice(
+            pool, new[j:j + 1], (page_ids[j], zero, offsets[j], zero))
+    return pool
 
 
 def mha_paged_chunk_step(params, x, k_pool, v_pool, ptab, pos, n_heads,

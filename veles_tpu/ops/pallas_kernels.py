@@ -31,7 +31,7 @@ the kernel instead, so no densified view ever exists:
   K/V enter as VMEM operands (never read back from HBM), history pages
   stream like decode, and the kernel's EPILOGUE installs the chunk's
   rows into the lane's pool page through aliased outputs — the
-  ``paged_write`` scatter folded into the same program.
+  ``paged_write`` row install folded into the same program.
 
 Both run in interpret mode off-TPU (the CPU parity suite,
 ``tests/test_pallas.py -m kernel_parity`` / ``tools/
@@ -43,6 +43,7 @@ on real TPU hardware (or when forced) — see ``serving/lm_engine.py``'s
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -323,16 +324,79 @@ def dropout(x, seed, rate, interpret=None):
 # reshaping the h = kv·g query heads to (kv, g·c) rows per kv head, so
 # the scores matmul runs once per kv head with no repeated K/V — query
 # row r serves chunk offset r % c.
+#
+# A pool ROW may pack several kv heads (``pool_pack``).  A Mosaic operand
+# lies row-major with its minor axis tiled to the chip's 128 lanes.  The
+# chip's own layout for an array whose minor axis is narrower is another
+# (``f32[321,32,32,64]`` lies pages-minor-most), so a pool of head size
+# 64 handed to these kernels was converted whole on the way in and back
+# on the way out of every dispatch — and the converted copy padded each
+# 64-wide row to 128 lanes, so the kernels read twice the bytes.  A pool
+# whose rows are lane-wide lies the way the kernels read it: r =
+# ``pool_pack(kv_heads, head_dim)`` heads side by side in one row, pool
+# (n_pages, kv_heads/r, page, r·head_dim), head k·r+e of a position in
+# lanes [e·dh, (e+1)·dh) of row k (``pack_heads``) — the same bytes as
+# the plain pool, none padded.  The kernels need no lane slicing for
+# it: the queries of a row's r heads are stacked as r·g·c query ROWS,
+# each zero outside its own head's lanes (``_pack_queries``), so one
+# matmul over the full row gives every head its own scores (the other
+# heads' lanes meet exact zeros), and P·V yields r·dh lanes per query
+# row of which the wrapper keeps the head's own (``_unpack_outputs``).
+# r = 1 is the plain pool, and every helper is the identity there.
 
 
-def _flash_step(q, k, v, live, acc_ref, l_ref, m_ref):
+def pool_pack(kv_heads, head_dim):
+    """How many kv heads share one row of the serving kernels' pool: as
+    many as fill the chip's 128 lanes (2 for a head size of 64), as far
+    as ``kv_heads`` divides; 1 where the head size does not divide 128
+    (128 and wider included)."""
+    if 128 % head_dim:
+        return 1
+    return math.gcd(kv_heads, 128 // head_dim)
+
+
+def pack_heads(x, r):
+    """(..., kv, t, dh) -> (..., kv/r, t, r·dh): the pool's row packing
+    applied to K or V rows (or to a whole plain pool)."""
+    if r == 1:
+        return x
+    kv, t, dh = x.shape[-3:]
+    x = x.reshape(x.shape[:-3] + (kv // r, r, t, dh))
+    return jnp.swapaxes(x, -3, -2).reshape(
+        x.shape[:-4] + (kv // r, t, r * dh))
+
+
+def _pack_queries(qg, r):
+    """(b, kv, gc, dh) -> (b, kv/r, r·gc, r·dh): head e of a pack in
+    query rows [e·gc, (e+1)·gc), its values in lanes [e·dh, (e+1)·dh)
+    and exact zeros in the others."""
+    if r == 1:
+        return qg
+    b, kv, gc, dh = qg.shape
+    eye = jnp.eye(r, dtype=qg.dtype)[:, None, :, None]
+    return (qg.reshape(b, kv // r, r, gc, 1, dh) * eye).reshape(
+        b, kv // r, r * gc, r * dh)
+
+
+def _unpack_outputs(o, r):
+    """(b, kv/r, r·gc, r·dh) -> (b, kv, gc, dh): each query row keeps
+    its own head's lanes."""
+    if r == 1:
+        return o
+    b, kvp, rows, lanes = o.shape
+    o = o.reshape(b, kvp, r, rows // r, r, lanes // r)
+    return jnp.stack([o[:, :, e, :, e] for e in range(r)],
+                     axis=2).reshape(b, kvp * r, rows // r, lanes // r)
+
+
+def _flash_step(q, k, v, live, dh, acc_ref, l_ref, m_ref):
     """One online-softmax accumulation against a K/V block — the
-    ``attention._online_update`` recurrence on kernel refs.  NEG_INF
+    ``attention._online_update`` recurrence on kernel refs; ``dh`` is
+    the head size the scores scale by (a packed row is wider).  NEG_INF
     masking (finite) keeps fully-masked blocks harmless: their
     transient terms rescale to exactly 0.0 (fp32 exp underflow) once a
     live block arrives, the same argument ``blockwise_attention``
     documents."""
-    dh = q.shape[-1]
     # the XLA twin's matmuls follow functional's precision policy (fp32
     # HIGHEST by default); Mosaic's own default runs fp32 operands
     # through bf16 passes, 2e-3 off at head size 128 on the chip
@@ -374,9 +438,12 @@ def paged_flash_decode(q, k_pool, v_pool, ptab, pos, window=None,
     pool page per grid step, masked by the ``chunk_live_mask`` band.
 
     The pool must already hold the lane's rows for positions
-    [0, pos+c) — the caller ``paged_write``s the c new rows first (the
-    write is a c-row scatter; the kernel eliminates the L-row gather,
-    which is the asymmetry that matters).  Numerically the
+    [0, pos+c) — the caller ``paged_write``s the c new rows first (c
+    row-sized update slices; the kernel eliminates the L-row gather,
+    which is the asymmetry that matters).  The pool is (n_pages, kv/r,
+    page, r·dh), r heads to a row (``pool_pack``; the section's head
+    says how the kernel reads it) — r is read off its last axis, and a
+    plain (n_pages, kv, page, dh) pool is r = 1.  Numerically the
     online-softmax result of ``blockwise_attention`` — equal to the
     XLA ``mha_paged_chunk_step`` path to fp32 roundoff (the greedy
     argmax downstream is what the serving parity matrix pins).
@@ -386,11 +453,12 @@ def paged_flash_decode(q, k_pool, v_pool, ptab, pos, window=None,
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, c, dh = q.shape
-    kv, page = k_pool.shape[1], k_pool.shape[2]
+    kvp, page, lanes = k_pool.shape[1:]
+    r = lanes // dh
     m_pages = ptab.shape[1]
-    g = h // kv
-    gc = g * c
-    qg = q.reshape(b, kv, g, c, dh).reshape(b, kv, gc, dh)
+    g = h // (kvp * r)
+    rows = r * g * c            # query rows per pool row
+    qp = _pack_queries(q.reshape(b, kvp * r, g * c, dh), r)
 
     def kernel(ptab_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                acc_ref, l_ref, m_ref):
@@ -404,11 +472,11 @@ def paged_flash_decode(q, k_pool, v_pool, ptab, pos, window=None,
 
         pos = pos_ref[i]
         k_pos = j * page + jax.lax.broadcasted_iota(
-            jnp.int32, (gc, page), 1)
+            jnp.int32, (rows, page), 1)
         q_pos = pos + jax.lax.broadcasted_iota(
-            jnp.int32, (gc, page), 0) % c
+            jnp.int32, (rows, page), 0) % c
         live = _band(k_pos, q_pos, window, sinks, k_pos <= q_pos)
-        _flash_step(q_ref[0], k_ref[0], v_ref[0], live,
+        _flash_step(q_ref[0], k_ref[0], v_ref[0], live, dh,
                     acc_ref, l_ref, m_ref)
 
         @pl.when(j == m_pages - 1)
@@ -420,26 +488,26 @@ def paged_flash_decode(q, k_pool, v_pool, ptab, pos, window=None,
         num_scalar_prefetch=2,
         grid=(b, m_pages),
         in_specs=[
-            pl.BlockSpec((1, kv, gc, dh),
+            pl.BlockSpec((1, kvp, rows, lanes),
                          lambda i, j, pt, ps: (i, 0, 0, 0)),
-            pl.BlockSpec((1, kv, page, dh),
+            pl.BlockSpec((1, kvp, page, lanes),
                          lambda i, j, pt, ps: (pt[i, j], 0, 0, 0)),
-            pl.BlockSpec((1, kv, page, dh),
+            pl.BlockSpec((1, kvp, page, lanes),
                          lambda i, j, pt, ps: (pt[i, j], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, kv, gc, dh),
+        out_specs=pl.BlockSpec((1, kvp, rows, lanes),
                                lambda i, j, pt, ps: (i, 0, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((kv, gc, dh), jnp.float32),
-                        pltpu.VMEM((kv, gc), jnp.float32),
-                        pltpu.VMEM((kv, gc), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((kvp, rows, lanes), jnp.float32),
+                        pltpu.VMEM((kvp, rows), jnp.float32),
+                        pltpu.VMEM((kvp, rows), jnp.float32)],
     )
     o = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kv, gc, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kvp, rows, lanes), q.dtype),
         interpret=_interpret(interpret),
     )(jnp.asarray(ptab, jnp.int32), jnp.asarray(pos, jnp.int32),
-      qg, k_pool, v_pool)
-    return o.reshape(b, kv, g, c, dh).reshape(b, h, c, dh)
+      qp, k_pool, v_pool)
+    return _unpack_outputs(o, r).reshape(b, h, c, dh)
 
 
 def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
@@ -452,7 +520,9 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
     epilogue, never written-then-gathered through HBM.  The same
     epilogue installs them into the lane's pool page through ALIASED
     outputs: the ``paged_write`` row install is part of this program,
-    not a separate scatter dispatch.
+    not a separate write.  The pool's rows may pack r heads
+    as :func:`paged_flash_decode` says; ``k_new``/``v_new`` arrive
+    plain, (b, kv, c, dh), and are packed here.
 
     Caller contract (the engine's chunk program guarantees both):
     ``pos`` is page-aligned and the chunk occupies exactly the pool
@@ -463,14 +533,15 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, c, dh = q.shape
-    kv, page = k_pool.shape[1], k_pool.shape[2]
+    kvp, page, lanes = k_pool.shape[1:]
     if c != page:
         raise ValueError("prefill kernel needs chunk (%d) == page (%d)"
                          % (c, page))
+    r = lanes // dh
     m_pages = ptab.shape[1]
-    g = h // kv
-    gc = g * c
-    qg = q.reshape(b, kv, g, c, dh).reshape(b, kv, gc, dh)
+    g = h // (kvp * r)
+    rows = r * g * c            # query rows per pool row
+    qp = _pack_queries(q.reshape(b, kvp * r, g * c, dh), r)
 
     def kernel(ptab_ref, pos_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
                o_ref, ko_ref, vo_ref, acc_ref, l_ref, m_ref):
@@ -483,26 +554,26 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
             m_ref[...] = jnp.full_like(m_ref, NEG_INF)
 
         pos = pos_ref[i]
-        q_rows = jax.lax.broadcasted_iota(jnp.int32, (gc, page), 0) % c
+        q_rows = jax.lax.broadcasted_iota(jnp.int32, (rows, page), 0) % c
         # history page j: live strictly below the chunk frontier (the
         # chunk's own page sits in the pool UNWRITTEN — its rows come
         # from the VMEM operands in the epilogue)
         k_pos = j * page + jax.lax.broadcasted_iota(
-            jnp.int32, (gc, page), 1)
+            jnp.int32, (rows, page), 1)
         live = _band(k_pos, pos + q_rows, window, sinks, k_pos < pos)
-        _flash_step(q_ref[0], k_ref[0], v_ref[0], live,
+        _flash_step(q_ref[0], k_ref[0], v_ref[0], live, dh,
                     acc_ref, l_ref, m_ref)
 
         @pl.when(j == m_pages - 1)
         def _():
             # the chunk block: intra-chunk causal over the VMEM K/V
             k_pos_new = pos + jax.lax.broadcasted_iota(
-                jnp.int32, (gc, c), 1)
+                jnp.int32, (rows, c), 1)
             q_pos = pos + jax.lax.broadcasted_iota(
-                jnp.int32, (gc, c), 0) % c
+                jnp.int32, (rows, c), 0) % c
             live_new = _band(k_pos_new, q_pos, window, sinks,
                              k_pos_new <= q_pos)
-            _flash_step(q_ref[0], kn_ref[0], vn_ref[0], live_new,
+            _flash_step(q_ref[0], kn_ref[0], vn_ref[0], live_new, dh,
                         acc_ref, l_ref, m_ref)
             o_ref[0] = (acc_ref[...]
                         / l_ref[...][..., None]).astype(o_ref.dtype)
@@ -517,30 +588,30 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
         num_scalar_prefetch=2,
         grid=(b, m_pages),
         in_specs=[
-            pl.BlockSpec((1, kv, gc, dh),
+            pl.BlockSpec((1, kvp, rows, lanes),
                          lambda i, j, pt, ps: (i, 0, 0, 0)),
-            pl.BlockSpec((1, kv, c, dh),
+            pl.BlockSpec((1, kvp, c, lanes),
                          lambda i, j, pt, ps: (i, 0, 0, 0)),
-            pl.BlockSpec((1, kv, c, dh),
+            pl.BlockSpec((1, kvp, c, lanes),
                          lambda i, j, pt, ps: (i, 0, 0, 0)),
-            pl.BlockSpec((1, kv, page, dh),
+            pl.BlockSpec((1, kvp, page, lanes),
                          lambda i, j, pt, ps: (pt[i, j], 0, 0, 0)),
-            pl.BlockSpec((1, kv, page, dh),
+            pl.BlockSpec((1, kvp, page, lanes),
                          lambda i, j, pt, ps: (pt[i, j], 0, 0, 0)),
         ],
         out_specs=(
-            pl.BlockSpec((1, kv, gc, dh),
+            pl.BlockSpec((1, kvp, rows, lanes),
                          lambda i, j, pt, ps: (i, 0, 0, 0)),
-            pl.BlockSpec((1, kv, page, dh), tgt),
-            pl.BlockSpec((1, kv, page, dh), tgt),
+            pl.BlockSpec((1, kvp, page, lanes), tgt),
+            pl.BlockSpec((1, kvp, page, lanes), tgt),
         ),
-        scratch_shapes=[pltpu.VMEM((kv, gc, dh), jnp.float32),
-                        pltpu.VMEM((kv, gc), jnp.float32),
-                        pltpu.VMEM((kv, gc), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((kvp, rows, lanes), jnp.float32),
+                        pltpu.VMEM((kvp, rows), jnp.float32),
+                        pltpu.VMEM((kvp, rows), jnp.float32)],
     )
     o, k_out, v_out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((b, kv, gc, dh), q.dtype),
+        out_shape=(jax.ShapeDtypeStruct((b, kvp, rows, lanes), q.dtype),
                    jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
                    jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)),
         # aliased in-place pool update: operand indices INCLUDE the two
@@ -548,9 +619,8 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
         input_output_aliases={5: 1, 6: 2},
         interpret=_interpret(interpret),
     )(jnp.asarray(ptab, jnp.int32), jnp.asarray(pos, jnp.int32),
-      qg, k_new, v_new, k_pool, v_pool)
-    return (o.reshape(b, kv, g, c, dh).reshape(b, h, c, dh),
-            k_out, v_out)
+      qp, pack_heads(k_new, r), pack_heads(v_new, r), k_pool, v_pool)
+    return _unpack_outputs(o, r).reshape(b, h, c, dh), k_out, v_out
 
 
 def serving_kernels_supported(paged, n_heads, kv_heads, head_dim,

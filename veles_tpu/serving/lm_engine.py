@@ -160,6 +160,28 @@ step program), which the parity matrix pins across the full
 feature set.  With the Pallas ``paged_flash_decode`` kernel active the
 whole K-step loop never leaves the device.
 
+The KV STORAGE IS UPDATED IN PLACE (ISSUE 27).  The pools (paged) or
+the caches (contiguous) are one tree of device arrays that every
+program except ``prefill`` and ``chunk_extract`` takes and returns.
+Each of those programs takes it DONATED (:meth:`LMEngine._jit`,
+``storage=``): the compiled program aliases the output to the input and
+writes the new rows into the arrays as they lie — no dispatch copies a
+pool, none holds a second one, and the jit call allocates no output
+buffers for them.  The caller's tree is dead when the call returns;
+every call site rebinds the storage to the first output in the same
+statement.  What donation makes necessary is a rule for a dispatch
+that raises once its arguments are consumed: :meth:`LMEngine._donating`
+(requests that held rows fail, fresh storage goes in, the engine keeps
+serving).  Where the Pallas kernels are active a pool row packs as many
+kv heads as fill the chip's 128 lanes (``ops/pallas_kernels.py::
+pool_pack``: pool (pages, kv_heads/r, page, r·head_dim)): the kernels
+take the pool row-major, the chip's own layout for a row narrower than
+its lanes is another, and that cost a conversion of each layer's whole
+pool into and out of every kernel dispatch.  The gauge
+``kv_storage_in_place`` (1 after warm-up when the last warm-up dispatch
+consumed its storage) and the counter ``kv_storage_rebuilds`` are the
+witnesses in ``/metrics.json``.
+
 Decoding is GREEDY (temperature 0) — bit-identical to
 ``ops/transformer.py::generate`` for the same prompt WHATEVER fast-path
 combination is enabled, which is the serving contract (sampled
@@ -180,6 +202,7 @@ from __future__ import annotations
 
 import array
 import collections
+import contextlib
 import threading
 import time
 from concurrent.futures import Future
@@ -311,6 +334,28 @@ def propose_draft(history, k, max_ngram=3):
     return None
 
 
+def compiled_storage_report(text, leaf):
+    """What one COMPILED engine program's text (``compiled.as_text()``)
+    says about the KV storage, whose leaves all have ``leaf``'s shape
+    and dtype: ``(copies, aliased)`` — the instructions whose result is
+    a copy with a whole leaf's shape, in any layout (each is a second
+    pool held and 2x its bytes moved), and the number of outputs the
+    module header lists under ``input_output_alias`` (each donated
+    leaf that the program updates in place).  The check
+    ``tools/aot_compile.py`` and ``tests/test_chip_compile.py`` make for
+    a described chip, no chip attached: no copy, every leaf aliased."""
+    import re
+    name = {"float32": "f32", "bfloat16": "bf16", "float16": "f16"}[
+        str(leaf.dtype)]
+    shape = "%s[%s]" % (name, ",".join(str(n) for n in leaf.shape))
+    copies = re.findall(
+        r"= %s(?:\{[^}]*\})? copy(?:-start)?\(" % re.escape(shape), text)
+    header = text.split("\n", 1)[0]
+    aliased = re.findall(r"\{\d+\}: \(\d+, \{\}, (?:may|must)-alias\)",
+                         header)
+    return len(copies), len(aliased)
+
+
 class _PrefixNode:
     __slots__ = ("key", "rows", "children", "refs", "last_use", "parent")
 
@@ -416,6 +461,21 @@ class RadixPrefixCache:
         nothing more can go).  Returns True when an entry was dropped."""
         return self._evict_one()
 
+    def clear(self):
+        """Drop EVERY entry, pinned or not, firing ``on_evict`` for
+        each (the paged engine's page references go home) — the rows
+        the entries pointed at are gone with the KV storage
+        (``LMEngine._storage_lost``); the lanes that pinned them were
+        failed first."""
+        stack = list(self.root.children.values())
+        self.root.children = {}
+        self.size = 0
+        while stack:
+            node = stack.pop()
+            stack.extend(node.children.values())
+            if self.on_evict is not None:
+                self.on_evict(node.rows)
+
     def evictable(self):
         """Upper bound on entries pool-pressure eviction can reclaim:
         the UNPINNED count (an unpinned interior node above a pinned
@@ -520,8 +580,6 @@ class LMEngine(Logger):
                  tracer=None, megastep=0, megastep_mode=None,
                  refill_ring=0, temperature=0.0, top_k=0,
                  sample_seed=None):
-        import jax
-        import jax.numpy as jnp
         if slots < 1:
             raise ValueError("slots must be >= 1")
         self.name = name
@@ -762,21 +820,24 @@ class LMEngine(Logger):
             if num_pages < 1:
                 raise ValueError("paged_kv pool must hold >= 1 page")
             self._pool = KVPagePool(num_pages, self.prefill_chunk)
-            pool_shape = (num_pages + 1, kv_heads, self.prefill_chunk,
-                          head_dim)          # +1: the scratch page
-            self._kv_pools = [
-                (self._place_kv(jnp.zeros(pool_shape, embed.dtype)),
-                 self._place_kv(jnp.zeros(pool_shape, embed.dtype)))
-                for _ in params["blocks"]]
+            # +1: the scratch page.  With the serving kernels active a
+            # row packs as many heads as fill the chip's lanes
+            # (pool_pack) — the pool then lies on the chip the way the
+            # kernels read it, and no dispatch converts it
+            pack = 1
+            if self._kernel_active:
+                from veles_tpu.ops.pallas_kernels import pool_pack
+                pack = pool_pack(kv_heads, head_dim)
+            self._storage_shape = (num_pages + 1, kv_heads // pack,
+                                   self.prefill_chunk, head_dim * pack)
             self._page_tables = numpy.zeros(
                 (self.slots, self._max_pages), numpy.int32)
             self.metrics.set_gauge("kv_pages_total", num_pages)
         else:
-            cache_shape = (self.slots, kv_heads, self.max_len, head_dim)
-            self._caches = [
-                (self._place_kv(jnp.zeros(cache_shape, embed.dtype)),
-                 self._place_kv(jnp.zeros(cache_shape, embed.dtype)))
-                for _ in params["blocks"]]
+            self._storage_shape = (self.slots, kv_heads, self.max_len,
+                                   head_dim)
+        self._storage_dtype = embed.dtype
+        self._set_storage(self._zero_storage())
         self._trie = (RadixPrefixCache(
             prefix_cache, self.prefill_chunk,
             on_evict=self._pool.release if self._paged else None)
@@ -877,33 +938,67 @@ class LMEngine(Logger):
         # every dispatch (and trip the armed transfer guard)
         return jax.device_put(params)
 
-    def _place_kv(self, arr):
-        """Place one KV array per the engine's layout: head-sharded
-        over the tp mesh, committed to the replica's device, or left
-        uncommitted (the single-device default)."""
+    def _zero_storage(self):
+        """Fresh zero KV storage — one (k, v) pair per block of the
+        pool's (paged) or the caches' (contiguous) shape — placed per
+        the engine's layout: head-sharded over the tp mesh, committed
+        to the replica's device, or left uncommitted (the
+        single-device default)."""
         import jax
-        if self._mesh is not None:
-            return jax.device_put(arr, self._kv_shard)
-        if self._device is not None:
-            return jax.device_put(arr, self._device)
-        return arr
+        import jax.numpy as jnp
+        where = (self._kv_shard if self._mesh is not None
+                 else self._device)
 
-    def _jit(self, fn, out_shardings=None):
-        """``jax.jit`` with the output layout PINNED under a tp mesh:
-        without the pin, GSPMD's chosen output sharding compares
-        unequal to the device_put input layout and the second call of
-        every family silently compiles a twin program — the exact
-        recompile ladder the jit-guard forbids.  Off-mesh, a plain
-        jit."""
+        def zeros():
+            arr = jnp.zeros(self._storage_shape, self._storage_dtype)
+            return arr if where is None else jax.device_put(arr, where)
+
+        return [(zeros(), zeros()) for _ in self.params["blocks"]]
+
+    def _storage(self):
+        return self._kv_pools if self._paged else self._caches
+
+    def _set_storage(self, storage):
+        if self._paged:
+            self._kv_pools = storage
+        else:
+            self._caches = storage
+
+    def _jit(self, fn, out_shardings=None, storage=None):
+        """``jax.jit`` of one engine program.  ``storage`` is the
+        position of the KV storage (the pools when paged, the caches
+        when contiguous) among ``fn``'s arguments, for every program
+        that RETURNS the storage: that argument is DONATED, so the
+        compiled program updates it in place — no dispatch copies a
+        pool or holds a second one, and the caller's old tree is dead
+        the moment the call returns (every call site rebinds the
+        storage to the program's first output in the same statement;
+        :meth:`_donating` is the rule for a call that raises).  A
+        program that only reads the storage (``chunk_extract``) or
+        never sees it (``prefill``) passes None.  The parameters are
+        never donated.
+
+        Under a tp mesh the output layout is PINNED: without the pin,
+        GSPMD's chosen output sharding compares unequal to the
+        device_put input layout and the second call of every family
+        silently compiles a twin program — the exact recompile ladder
+        the jit-guard forbids (and an output laid out otherwise could
+        not take the donated buffer)."""
         import jax
-        if self._mesh is None or out_shardings is None:
-            return jax.jit(fn)
-        return jax.jit(fn, out_shardings=out_shardings)
+        kwargs = {}
+        if storage is not None:
+            kwargs["donate_argnums"] = (storage,)
+        if out_shardings is not None:
+            kwargs["out_shardings"] = out_shardings
+        return jax.jit(fn, **kwargs)
 
     def _out_shard_trees(self):
-        """(kv_tree, repl) building blocks for out_shardings: one
-        (k, v) sharding pair per block, and the replicated sharding
-        for token outputs."""
+        """(kv_tree, repl) building blocks for out_shardings under a
+        tp mesh — one (k, v) sharding pair per block, and the
+        replicated sharding for token outputs — and (None, None) off
+        it, where nothing is pinned."""
+        if self._mesh is None:
+            return None, None
         kv_pair = (self._kv_shard, self._kv_shard)
         return [kv_pair] * len(self.params["blocks"]), self._repl_shard
 
@@ -1005,22 +1100,18 @@ class LMEngine(Logger):
                 return new_rows, jnp.argmax(logits).astype(jnp.int32)
             return new_rows, pick1(logits, seed, pos + 1)
 
-        kv_tree = repl = None
-        if self._mesh is not None:
-            kv_tree, repl = self._out_shard_trees()
+        kv_tree, repl = self._out_shard_trees()
+        pair = (kv_tree, repl) if kv_tree is not None else None
         step_all = jax.vmap(
             step_one, in_axes=(None, 0, 0, 0) if pick1 is None
             else (None, 0, 0, 0, 0))
         # programs: prefill
         self._prefill_jit = self._jit(
-            prefill_one,
-            (repl, kv_tree) if self._mesh is not None else None)
+            prefill_one, (repl, kv_tree) if kv_tree is not None else None)
         # programs: install
-        self._install_jit = self._jit(install, kv_tree)
+        self._install_jit = self._jit(install, kv_tree, storage=0)
         # programs: step
-        self._step_jit = self._jit(
-            step_all,
-            (kv_tree, repl) if self._mesh is not None else None)
+        self._step_jit = self._jit(step_all, pair, storage=1)
 
         self._chunk_jit = None
         self._chunk_install_jit = None
@@ -1081,13 +1172,12 @@ class LMEngine(Logger):
                     for (kc, vc), (rk, rv) in zip(caches, rows)]
 
             # programs: chunk
-            self._chunk_jit = self._jit(
-                chunk_slot,
-                (kv_tree, repl) if self._mesh is not None else None)
+            self._chunk_jit = self._jit(chunk_slot, pair, storage=1)
             # programs: chunk_extract
             self._chunk_extract_jit = self._jit(chunk_extract, kv_tree)
             # programs: chunk_install
-            self._chunk_install_jit = self._jit(chunk_install, kv_tree)
+            self._chunk_install_jit = self._jit(chunk_install, kv_tree,
+                                                storage=0)
 
         self._verify_jit = None
         verify_all = None
@@ -1115,9 +1205,7 @@ class LMEngine(Logger):
                 verify_one, in_axes=(None, 0, 0, 0) if pick1 is None
                 else (None, 0, 0, 0, 0))
             # programs: verify
-            self._verify_jit = self._jit(
-                verify_all,
-                (kv_tree, repl) if self._mesh is not None else None)
+            self._verify_jit = self._jit(verify_all, pair, storage=1)
 
         # ---- decode megastep (ISSUE 13): K fused iterations of the
         # step (or propose→verify→accept) per dispatch — the scan body
@@ -1193,16 +1281,14 @@ class LMEngine(Logger):
             return [(kp.at[dst].set(kp[src]), vp.at[dst].set(vp[src]))
                     for kp, vp in pools]
 
-        kv_tree = repl = None
-        if self._mesh is not None:
-            kv_tree, repl = self._out_shard_trees()
-        pair = (kv_tree, repl) if self._mesh is not None else None
+        kv_tree, repl = self._out_shard_trees()
+        pair = (kv_tree, repl) if kv_tree is not None else None
         # programs: chunk
-        self._chunk_jit = self._jit(chunk_slot, pair)
+        self._chunk_jit = self._jit(chunk_slot, pair, storage=1)
         # programs: step
-        self._step_jit = self._jit(step_all, pair)
+        self._step_jit = self._jit(step_all, pair, storage=1)
         # programs: page_copy
-        self._page_copy_jit = self._jit(page_copy, kv_tree)
+        self._page_copy_jit = self._jit(page_copy, kv_tree, storage=0)
         self._prefill_jit = None
         self._install_jit = None
         self._chunk_install_jit = None
@@ -1228,7 +1314,7 @@ class LMEngine(Logger):
                         logits, sargs[0], pp)
 
             # programs: verify
-            self._verify_jit = self._jit(verify_all, pair)
+            self._verify_jit = self._jit(verify_all, pair, storage=1)
 
         # decode megastep (ISSUE 13): the fused K-iteration program —
         # the page-table slice stays a traced-data argument, so the
@@ -1257,15 +1343,15 @@ class LMEngine(Logger):
             n_out = 5 + (1 if self.spec_k else 0) \
                 + (1 if self.refill_ring else 0)
             out_sh = ((kv_tree,) + (repl,) * (n_out - 1)
-                      if self._mesh is not None else None)
+                      if kv_tree is not None else None)
             # programs: whilestep
-            self._whilestep_jit = self._jit(mega, out_sh)
+            self._whilestep_jit = self._jit(mega, out_sh, storage=1)
             return
         n_out = 5 if self.spec_k else 4
         out_sh = ((kv_tree,) + (repl,) * (n_out - 1)
-                  if self._mesh is not None else None)
+                  if kv_tree is not None else None)
         # programs: megastep
-        self._megastep_jit = self._jit(mega, out_sh)
+        self._megastep_jit = self._jit(mega, out_sh, storage=1)
 
     def _make_megastep_body(self, step_all=None, verify_all=None):
         """Build the fused K-iteration decode program (ISSUE 13) for
@@ -1577,7 +1663,21 @@ class LMEngine(Logger):
     def _warmup(self):
         """Compile every program family before traffic, with every
         dispatch argument an explicit transfer (xfer shims) — the
-        first code to run under the armed transfer guard."""
+        first code to run under the armed transfer guard.  Ends by
+        saying whether the storage is updated in place: the gauge
+        ``kv_storage_in_place`` is 1 when the leaves that went into the
+        last dispatch (a decode program) came back consumed."""
+        went_in = self._warm_programs()
+        in_place = went_in.is_deleted()
+        self.metrics.set_gauge("kv_storage_in_place", int(in_place))
+        if not in_place:
+            self.warning(
+                "the KV storage was NOT donated to the last warm-up "
+                "dispatch: every dispatch copies it and holds two")
+
+    def _warm_programs(self):
+        """One dispatch of every program family and table width;
+        returns one storage leaf as it went into the last."""
         zero = xfer.to_device(0, numpy.int32)
         zeros = xfer.to_device(numpy.zeros(self.slots, numpy.int32))
         # seeded sampling appends a trailing seed argument per program
@@ -1615,8 +1715,8 @@ class LMEngine(Logger):
                     if self._whilestep_jit is not None \
                             and self.refill_ring:
                         args += self._ring_zero_args(w)
-                    out = fused(*args)
-                    self._kv_pools = out[0]
+                    went_in = self._kv_pools[0][0]
+                    self._kv_pools = fused(*args)[0]
                     continue
                 if self._verify_jit is not None:
                     self._kv_pools, _ = self._verify_jit(
@@ -1624,6 +1724,7 @@ class LMEngine(Logger):
                         xfer.to_device(numpy.zeros(
                             (self.slots, self.spec_k + 1),
                             numpy.int32)), zeros, *sv)
+                went_in = self._kv_pools[0][0]
                 self._kv_pools, _ = self._step_jit(
                     self.params, self._kv_pools, wtab, zeros, zeros,
                     *sv)
@@ -1653,6 +1754,7 @@ class LMEngine(Logger):
                         (self.slots, self.max_len), numpy.int32)),
                         zeros]
                 args += sv
+                went_in = self._caches[0][0]
                 self._caches = fused(*args)[0]
             else:
                 if self._verify_jit is not None:
@@ -1661,10 +1763,12 @@ class LMEngine(Logger):
                         xfer.to_device(numpy.zeros(
                             (self.slots, self.spec_k + 1),
                             numpy.int32)), zeros, *sv)
+                went_in = self._caches[0][0]
                 self._caches, _ = self._step_jit(
                     self.params, self._caches, zeros,
                     xfer.to_device(numpy.ones(self.slots,
                                               numpy.int32)), *sv)
+        return went_in
 
     def start(self):
         # warm every program before traffic: the discarded warmup
@@ -2323,10 +2427,11 @@ class LMEngine(Logger):
                     xfer.to_device(prompt[None], numpy.int32),
                     xfer.to_device(req.true_len, numpy.int32),
                     *self._seed_args(req.seed))
-                self._caches = self._install_jit(
-                    self._caches, rows,
-                    xfer.to_device(slot, numpy.int32))
-                self._tfence(self._caches, req.trace is not None)
+                with self._donating():
+                    self._caches = self._install_jit(
+                        self._caches, rows,
+                        xfer.to_device(slot, numpy.int32))
+                    self._tfence(self._caches, req.trace is not None)
             except Exception as e:   # noqa: BLE001 — fails THIS request
                 # a prefill fault (bad bucket compile, device error)
                 # must fail its own request, not wedge the engine
@@ -2371,11 +2476,12 @@ class LMEngine(Logger):
             lane.pinned.extend(nodes)
             lane.cursor = nodes[-1] if nodes else self._trie.root
             try:
-                for i, node in enumerate(nodes):
-                    self._caches = self._chunk_install_jit(
-                        self._caches, node.rows,
-                        xfer.to_device(slot, numpy.int32),
-                        xfer.to_device(i * C, numpy.int32))
+                with self._donating():
+                    for i, node in enumerate(nodes):
+                        self._caches = self._chunk_install_jit(
+                            self._caches, node.rows,
+                            xfer.to_device(slot, numpy.int32),
+                            xfer.to_device(i * C, numpy.int32))
             except Exception as e:   # noqa: BLE001 — fails THIS request
                 self.metrics.record_error()
                 self.warning("prefix-cache install failed: %s", e)
@@ -2518,11 +2624,12 @@ class LMEngine(Logger):
             t0c = time.monotonic()
             try:
                 self._fault("engine.cow")
-                self._kv_pools = self._page_copy_jit(
-                    self._kv_pools, xfer.to_device(p, numpy.int32),
-                    xfer.to_device(q, numpy.int32))
-                self._tfence(self._kv_pools,
-                             lane.request.trace is not None)
+                with self._donating():
+                    self._kv_pools = self._page_copy_jit(
+                        self._kv_pools, xfer.to_device(p, numpy.int32),
+                        xfer.to_device(q, numpy.int32))
+                    self._tfence(self._kv_pools,
+                                 lane.request.trace is not None)
             except Exception:
                 # nobody owns q yet (not in lane.pages) — hand it back
                 # or a faulting device shrinks the pool for good
@@ -2553,6 +2660,8 @@ class LMEngine(Logger):
         alive = []
         for slot in active:
             lane = self._lanes[slot]
+            if lane is None:     # failed with the storage (_donating)
+                continue
             try:
                 self._cow_guard(slot, lane, int(self._pos[slot]),
                                 int(self._pos[slot]) + span)
@@ -2601,9 +2710,8 @@ class LMEngine(Logger):
     def kv_bytes_resident(self):
         """Device bytes held for KV storage — the pool (paged) or the
         contiguous slot caches; what the bench reports as footprint."""
-        arrs = [a for pair in (self._kv_pools if self._paged
-                               else self._caches) for a in pair]
-        return sum(a.size * a.dtype.itemsize for a in arrs)
+        return sum(a.size * a.dtype.itemsize
+                   for pair in self._storage() for a in pair)
 
     def _advance_prefill(self, slot):   # hot-path
         """Run ONE pending prompt chunk for this lane (a tick's worth of
@@ -2632,10 +2740,11 @@ class LMEngine(Logger):
                 lane.cursor, tuple(int(t) for t in tokens))
             if node is not None:
                 try:
-                    self._caches = self._chunk_install_jit(
-                        self._caches, node.rows,
-                        xfer.to_device(slot, numpy.int32),
-                        xfer.to_device(start, numpy.int32))
+                    with self._donating():
+                        self._caches = self._chunk_install_jit(
+                            self._caches, node.rows,
+                            xfer.to_device(slot, numpy.int32),
+                            xfer.to_device(start, numpy.int32))
                 except Exception as e:   # noqa: BLE001 — this request
                     self._trie.release([node])
                     self.metrics.record_error()
@@ -2664,21 +2773,25 @@ class LMEngine(Logger):
                 + self._seed_args(req.seed)
             self.recorder.dispatch(tracing.PREFILL_DISPATCH,
                                    self._chunk_jit)
-            self._caches, tok = self._chunk_jit(
-                self.params, self._caches, *args)
-            if not is_tail and self._trie is not None \
-                    and lane.cursor is not None:
-                rows = self._chunk_extract_jit(
-                    self._caches, xfer.to_device(slot, numpy.int32),
-                    xfer.to_device(start, numpy.int32))
-                node = self._trie.insert(
-                    lane.cursor, tuple(int(t) for t in tokens), rows)
-                if node is not None:
-                    lane.pinned.append(node)
-                lane.cursor = node
-                self.metrics.set_gauge("prefix_cache_chunks",
-                                       self._trie.size)
-            self._tfence(self._caches, req.trace is not None)
+            with self._donating():
+                self._caches, tok = self._chunk_jit(
+                    self.params, self._caches, *args)
+                if not is_tail and self._trie is not None \
+                        and lane.cursor is not None:
+                    rows = self._chunk_extract_jit(
+                        self._caches, xfer.to_device(slot, numpy.int32),
+                        xfer.to_device(start, numpy.int32))
+                    node = self._trie.insert(
+                        lane.cursor, tuple(int(t) for t in tokens),
+                        rows)
+                    if node is not None:
+                        lane.pinned.append(node)
+                    lane.cursor = node
+                    self.metrics.set_gauge("prefix_cache_chunks",
+                                           self._trie.size)
+                self._tfence(self._caches, req.trace is not None)
+                if is_tail:      # the first token crosses in here too
+                    tok = int(xfer.to_host(tok))
         except Exception as e:   # noqa: BLE001 — fails THIS request
             self.metrics.record_error()
             self.warning("chunk prefill failed: %s", e)
@@ -2697,7 +2810,8 @@ class LMEngine(Logger):
         self.metrics.inc("prefill_tokens",
                          (req.true_len - start) if is_tail
                          else len(tokens))
-        # lint: allow(host-sync): enqueue-time EWMA by design; device wall rides traced spans (_tfence)
+        # enqueue time by design (a tail chunk's includes the wait for
+        # its token); device wall rides traced spans (_tfence)
         self.metrics.record_decode_step(time.monotonic() - t0)
         if req.trace is not None:
             req.trace.tracer.add(
@@ -2707,7 +2821,7 @@ class LMEngine(Logger):
                        "bucket": self.prefill_chunk,
                        "backend": self._backend})
         if is_tail:
-            self._emit_first(slot, lane, int(xfer.to_host(tok)))
+            self._emit_first(slot, lane, tok)
         else:
             self._pos[slot] = lane.pending[0][1]
 
@@ -2759,8 +2873,12 @@ class LMEngine(Logger):
                 + self._seed_args(req.seed)
             self.recorder.dispatch(tracing.PREFILL_DISPATCH,
                                    self._chunk_jit)
-            self._kv_pools, tok = self._chunk_jit(
-                self.params, self._kv_pools, *args)
+            with self._donating():
+                self._kv_pools, tok = self._chunk_jit(
+                    self.params, self._kv_pools, *args)
+                self._tfence(self._kv_pools, req.trace is not None)
+                if is_tail:      # the first token crosses in here too
+                    tok = int(xfer.to_host(tok))
             if not is_tail and self._trie is not None \
                     and lane.cursor is not None:
                 page = lane.pages[page_idx]
@@ -2776,7 +2894,6 @@ class LMEngine(Logger):
                 self.metrics.set_gauge("prefix_cache_chunks",
                                        self._trie.size)
                 self._update_pool_gauges()
-            self._tfence(self._kv_pools, req.trace is not None)
         except Exception as e:   # noqa: BLE001 — fails THIS request
             self.metrics.record_error()
             self.warning("paged chunk prefill failed: %s", e)
@@ -2793,7 +2910,8 @@ class LMEngine(Logger):
         self.metrics.inc("prefill_tokens",
                          (req.true_len - start) if is_tail
                          else len(tokens))
-        # lint: allow(host-sync): enqueue-time EWMA by design; device wall rides traced spans (_tfence)
+        # enqueue time by design (a tail chunk's includes the wait for
+        # its token); device wall rides traced spans (_tfence)
         self.metrics.record_decode_step(time.monotonic() - t0)
         if req.trace is not None:
             req.trace.tracer.add(
@@ -2803,7 +2921,7 @@ class LMEngine(Logger):
                        "bucket": self.prefill_chunk, "paged": True,
                        "backend": self._backend})
         if is_tail:
-            self._emit_first(slot, lane, int(xfer.to_host(tok)))
+            self._emit_first(slot, lane, tok)
         else:
             self._pos[slot] = lane.pending[0][1]
 
@@ -2864,7 +2982,7 @@ class LMEngine(Logger):
         fut = lane.request.future
         if exc is None:
             fut.cancel()
-        elif not fut.cancelled():
+        elif not fut.done():     # cancelled, or failed by _storage_lost
             fut.set_exception(exc)
 
     def _finish(self, slot):
@@ -2877,36 +2995,102 @@ class LMEngine(Logger):
             fut.version = self.weights_version
             fut.set_result(numpy.asarray(lane.emitted, numpy.int32))
 
+    @contextlib.contextmanager
+    def _donating(self):   # hot-path
+        """THE failure rule of a donating dispatch — every call of a
+        program that takes the KV storage donated, and the fetch of its
+        outputs, runs inside this context, under the site's own
+        ``except``.  A program that raises before the runtime has taken
+        its arguments (an injected ``_fault`` fires before the call;
+        trace and compile errors) leaves the storage as it was, and the
+        site's handler does what it always did: it fails its own
+        request or lanes, and the survivors' rows are intact.  One that
+        raises AFTER — in the call once the buffers are consumed, or in
+        the fetch when the program failed on the device — leaves none:
+        the arrays that went in are deleted, and what came out, if
+        anything, cannot be trusted.  So on any exception this looks at
+        the storage that was passed in (``is_deleted()`` on one leaf);
+        consumed, :meth:`_storage_lost` fails every request that held
+        rows in it and installs fresh storage BEFORE the exception
+        reaches the handler, which then finds its own work already
+        failed (``_teardown_slot`` / ``_fail_standby`` tolerate that).
+        ``_kv_pools`` / ``_caches`` never point at deleted or poisoned
+        buffers when the loop takes its next turn."""
+        leaf = self._storage()[0][0]
+        try:
+            yield
+        except Exception as e:
+            if leaf.is_deleted():
+                self._storage_lost(e)
+            raise
+
+    def _storage_lost(self, exc):
+        """The KV storage went down with a failed dispatch
+        (:meth:`_donating`): every request that holds pages or a slot
+        row — decoding lanes, prefilling lanes, standby-ring entries —
+        fails with ``exc``; the prefix trie is dropped (its rows are
+        gone); the page allocator comes home whole through those
+        releases and every table row parks on scratch; fresh zero
+        storage takes the place of the lost one; ``kv_storage_rebuilds``
+        counts it.  Queued requests are untouched: they hold nothing
+        yet, and are served from the fresh storage."""
+        held = [i for i, lane in enumerate(self._lanes)
+                if lane is not None]
+        self.warning(
+            "KV storage consumed by a failed dispatch (%s): failing %d "
+            "lane(s) and %d standby entr(ies), rebuilding the storage",
+            exc, len(held), len(self._ring))
+        for slot in held:
+            self._teardown_slot(slot, self._lanes[slot], exc)
+        for entry in list(self._ring):
+            self._fail_standby(entry, exc)
+        if self._trie is not None:
+            self._trie.clear()
+            self.metrics.set_gauge("prefix_cache_chunks", 0)
+        if self._paged:
+            self._page_tables[:] = KVPagePool.SCRATCH
+            self._update_pool_gauges()
+        # a declared boundary: making the zeros is no hot-path transfer
+        with xfer.boundary():
+            self._set_storage(self._zero_storage())
+        self.metrics.inc("kv_storage_rebuilds")
+
     def _fail_active(self, active, exc):
-        """A step/verify fault poisons every in-flight decode lane; fail
-        them to their clients and keep serving — never wedge with
-        futures that no one will ever resolve."""
+        """A step/verify fault fails every in-flight decode lane of the
+        dispatch to its client and keeps serving — never wedge with
+        futures that no one will ever resolve.  The lanes' rows are
+        simply abandoned: the storage itself is intact here (the fault
+        fired before the program took it), or :meth:`_donating` has
+        already replaced it and failed these lanes with the rest, in
+        which case there is nothing left to do for them."""
         self.metrics.record_error()
         self.warning("decode step failed: %s", exc)
         for slot in active:
-            self._teardown_slot(slot, self._lanes[slot], exc)
+            if self._lanes[slot] is not None:
+                self._teardown_slot(slot, self._lanes[slot], exc)
 
     def _dispatch_decode(self, decode_jit, args, lanes, tctxs):   # hot-path
         """THE decode dispatch all four drivers share (tick, verify, scan
         and while megastep): ``decode_jit`` over the parameters, the KV
-        storage and ``args`` — already on the device, the puts belong to
-        ``step.prepare`` — then the storage swapped for its first output
-        and the others fetched to the host.  The recorder's
+        storage — DONATED: the program updates it in place and the tree
+        passed in is dead when the call returns — and ``args``, already
+        on the device (the puts belong to ``step.prepare``); then the
+        storage rebound to the first output and the others fetched to
+        the host.  Call and fetch run under :meth:`_donating`, the rule
+        for a dispatch that raises once its storage is consumed; the
+        drivers' own ``except`` follows it.  The recorder's
         ``step.dispatch`` spans the jit call until it returns,
         ``step.fetch`` the wait for the device and the copy out (an armed
         tracer's fence too, when a sampled lane rides the dispatch), and
         ``step.emit`` opens as this returns."""
         rec = self.recorder
         rec.dispatch(tracing.STEP_DISPATCH, decode_jit, lanes)
-        if self._paged:
-            out = decode_jit(self.params, self._kv_pools, *args)
-            self._kv_pools = out[0]
-        else:
-            out = decode_jit(self.params, self._caches, *args)
-            self._caches = out[0]
-        rec.mark(tracing.STEP_FETCH)
-        host = xfer.to_host(tuple(out[1:]))
-        self._tfence(out[0], any(c is not None for c in tctxs))
+        with self._donating():
+            out = decode_jit(self.params, self._storage(), *args)
+            self._set_storage(out[0])
+            rec.mark(tracing.STEP_FETCH)
+            host = xfer.to_host(tuple(out[1:]))
+            self._tfence(out[0], any(c is not None for c in tctxs))
         rec.mark(tracing.STEP_EMIT)
         return host
 
@@ -3520,14 +3704,17 @@ class LMEngine(Logger):
         t0 = time.monotonic()
         try:
             self._fault("engine.chunk")
-            self._kv_pools, tok = self._chunk_jit(
-                self.params, self._kv_pools,
-                xfer.to_device(entry.table),
-                xfer.to_device(tokens, numpy.int32),
-                xfer.to_device(start, numpy.int32),
-                xfer.to_device(last_idx, numpy.int32),
-                *self._seed_args(req.seed))
-            self._tfence(self._kv_pools, req.trace is not None)
+            with self._donating():
+                self._kv_pools, tok = self._chunk_jit(
+                    self.params, self._kv_pools,
+                    xfer.to_device(entry.table),
+                    xfer.to_device(tokens, numpy.int32),
+                    xfer.to_device(start, numpy.int32),
+                    xfer.to_device(last_idx, numpy.int32),
+                    *self._seed_args(req.seed))
+                self._tfence(self._kv_pools, req.trace is not None)
+                if is_tail:      # the first token crosses in here too
+                    tok = int(xfer.to_host(tok))
         except Exception as e:   # noqa: BLE001 — fails THIS request
             self.metrics.record_error()
             self.warning("standby prefill failed: %s", e)
@@ -3544,7 +3731,8 @@ class LMEngine(Logger):
         self.metrics.inc("prefill_tokens",
                          (req.true_len - start) if is_tail
                          else len(tokens))
-        # lint: allow(host-sync): enqueue-time EWMA by design; device wall rides traced spans (_tfence)
+        # enqueue time by design (a tail chunk's includes the wait for
+        # its token); device wall rides traced spans (_tfence)
         self.metrics.record_decode_step(time.monotonic() - t0)
         if req.trace is not None:
             req.trace.tracer.add(
@@ -3557,7 +3745,6 @@ class LMEngine(Logger):
         if not is_tail:
             entry.pos = lane.pending[0][1]
             return
-        tok = int(xfer.to_host(tok))
         lane.emitted.append(tok)
         lane.remaining -= 1
         self._count_tokens(req)
@@ -3602,7 +3789,7 @@ class LMEngine(Logger):
             self._ring.remove(entry)
         self._release_lane(entry.lane)
         fut = entry.lane.request.future
-        if not fut.cancelled():
+        if not fut.done():       # cancelled, or failed by _storage_lost
             fut.set_exception(exc)
         self.metrics.set_gauge("standby_ring_occupancy",
                                len(self._ring))
