@@ -705,6 +705,116 @@ def phase_serve(seed, lm=FULL_LM, slots=8, prefill_chunk=32, clients=4,
 
 
 # --------------------------------------------------------------- four chips
+#: the block of the benchmark's second configuration at its published
+#: widths (heads of 128 under a hidden size of 3072, 48 query heads on 8 KV
+#: heads, expert width 3072), cut to one layer of each kind and 4 held
+#: experts of 256 routed, a window of two pages
+KINDS_LM = {
+    "model_type": "afmoe", "hidden_size": 3072, "num_attention_heads": 48,
+    "num_key_value_heads": 8, "head_dim": 128, "intermediate_size": 12288,
+    "moe_intermediate_size": 3072, "vocab_size": 4096,
+    "num_hidden_layers": 3, "num_dense_layers": 1,
+    "layer_types": ["sliding_attention", "sliding_attention",
+                    "full_attention"],
+    "num_experts": 4, "router_width": 256, "held_experts": [0, 4],
+    "num_experts_per_tok": 4, "sliding_window": 512, "rope_theta": 10000,
+    "rms_norm_eps": 1e-5, "route_scale": 2.448, "route_norm": True,
+    "score_func": "sigmoid", "num_shared_experts": 1,
+    "initializer_std": 0.02, "max_position_embeddings": 2048,
+}
+
+
+def phase_kinds(seed, lm=KINDS_LM, slots=16, page=256, prompt_len=700,
+                n_new=200, gap_limit=1.5, kernel="auto"):
+    """The sandwich block with two kinds of layer on the serving path: one
+    request through ``LMEngine`` (Pallas kernels on the chip: the fused
+    prefill with its head-block grid axis, flash decode through a table
+    per kind, the one-call row write of 16 lanes; expert layers through
+    the grouped matmul), its context running past the window so that
+    sliding pages are released, against the benchmark's plain reference
+    (``benchmark/reference/afmoe.py``, float32): the served tokens are the
+    reference's own but for bfloat16 roundoff, which a router's near-ties
+    amplify (``gap_limit`` on the reference's logit scale, whose standard
+    deviation is 1.1: a program that dropped a norm or a gate reads 3 and
+    more); and against an engine on the XLA path (the
+    kernels' twin), which serves the same request first."""
+    import jax
+    from benchmark.reference import afmoe
+    from veles_tpu import model_config
+    from veles_tpu.serving import LMEngine
+    record = model_config.from_published(lm)
+    say("kinds", "%d layers %s, %d of %d experts held, window %d, page %d, "
+        "%s; %d lanes", lm["num_hidden_layers"], list(record.attn_kinds),
+        lm["held_experts"][1], lm["router_width"], lm["sliding_window"],
+        page, record.dtype, slots)
+    weights = jax.tree.map(lambda a: a.astype(record.dtype),
+                           afmoe.make_weights(seed, lm))
+    prompt = numpy.random.RandomState(seed).randint(
+        0, lm["vocab_size"], prompt_len)
+
+    def serve(name, attn_kernel):
+        engine = LMEngine(weights, record,
+                          max_len=lm["max_position_embeddings"],
+                          slots=slots, prefill_chunk=page, paged_kv=True,
+                          attn_kernel=attn_kernel, deadline_s=600.0,
+                          name=name)
+        with timed("kinds", "%s: engine start (every program and table "
+                   "width)" % name):
+            engine.start()
+        with timed("kinds", "%s: one request: prompt %d, n_new %d"
+                   % (name, prompt_len, n_new)):
+            return engine, engine.submit(prompt, n_new).result(timeout=600)
+
+    # the XLA twin first (gather + dense softmax, update slices): the
+    # kernels' engine must serve its tokens but for roundoff ties
+    twin, want = serve("kinds_xla", 0)
+    twin.stop()
+    engine, out = serve("kinds", kernel)
+    try:
+        snap = engine.metrics.snapshot()
+        gauges, counters = snap["gauges"], snap["counters"]
+        released = counters.get("kv_pages_released_window", 0)
+        say("kinds", "attn_kernel_active %d, kv_storage_in_place %d, "
+            "kv_storage_rebuilds %d, window pages released %d, experts hit "
+            "a step %.2f, assignments held %d elsewhere %d",
+            gauges["attn_kernel_active"], gauges["kv_storage_in_place"],
+            counters.get("kv_storage_rebuilds", 0), released,
+            counters["moe_experts_hit"] / counters["decode_dispatches"],
+            counters["moe_assignments_held"],
+            counters["moe_assignments_elsewhere"])
+        if on_tpu():
+            check(gauges["attn_kernel_active"] == 1
+                  and counters.get("attn_kernel_fallbacks", 0) == 0,
+                  "attn_kernel='auto' fell back to the XLA path on the "
+                  "TPU: %s", engine._kernel_fallback_reason)
+        check(gauges["kv_storage_in_place"] == 1
+              and counters.get("kv_storage_rebuilds", 0) == 0,
+              "the pools of two kinds are not updated in place")
+        check(released > 0, "no sliding-layer page was released")
+        check(engine.verify_pool_invariants()["used_pages"] == 0,
+              "pages still held after the request")
+    finally:
+        engine.stop()
+    sequence = numpy.concatenate([prompt, out])
+    rows = numpy.arange(prompt_len - 1, len(sequence) - 1)
+    with timed("kinds", "reference over %d tokens" % len(sequence)):
+        ref = numpy.asarray(afmoe.logits(weights, sequence, rows, lm))
+    for name, row in (("kernels", out), ("XLA twin", want)):
+        gap = ref.max(-1) - ref[numpy.arange(n_new), row]
+        say("kinds", "%s: served tokens that are the reference's choice "
+            "%d of %d (off at %s); widest gap below its best %.4f (limit "
+            "%.3f)", name, int((gap == 0).sum()), n_new,
+            numpy.nonzero(gap > 0)[0].tolist(), float(gap.max()), gap_limit)
+        check(float(gap.max()) <= gap_limit,
+              "%s: served tokens lie %.4f below the reference's best",
+              name, float(gap.max()))
+    # teacher forcing ends where the two engines first part: up to there
+    # they saw the same context
+    same = int((numpy.cumsum(out != want) == 0).sum())
+    say("kinds", "kernels and XLA twin serve the same first %d of %d "
+        "tokens", same, n_new)
+
+
 def _sharding_line(name, arr):
     return "%s %s on %d device(s), shard %s" % (
         name, tuple(arr.shape), len(arr.sharding.device_set),
@@ -905,7 +1015,8 @@ def main(argv=None):
         phases = [("sync", lambda: phase_sync(peak_flops=peak)),
                   ("train", lambda: phase_train(args.seed, workdir)),
                   ("kernels", lambda: phase_kernels(args.seed)),
-                  ("serve", lambda: phase_serve(args.seed))]
+                  ("serve", lambda: phase_serve(args.seed)),
+                  ("kinds", lambda: phase_kinds(args.seed))]
     failed = []
     begin = time.perf_counter()
     try:

@@ -1,0 +1,396 @@
+"""ISSUE 28: the ``sandwich`` block (RMSNorm x4, q/k norm, gated attention,
+rotary positions on sliding layers only, gated-SiLU feed forward, sigmoid-
+routed experts beside a shared expert of which a chip HOLDS a share) against
+the benchmark's plain reference ``benchmark/reference/afmoe.py`` (float32,
+no cache, no kernel, imports nothing of veles_tpu), and the engine's two
+kinds of KV cache: a page table and an allocator per kind, a sliding layer's
+pages released as they leave the window.
+
+Tolerances: the program in float32 and the reference compute the same
+sums in another order, so logits agree to float32 roundoff (1e-4 on logits
+of magnitude 3; the greedy tokens are then the reference's own, gap 0)."""
+
+import numpy
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmark.reference import afmoe
+from veles_tpu import model_config
+from veles_tpu.serving.kv_pool import KVPagePool, WindowTables
+
+SMALL = {
+    "model_type": "afmoe", "hidden_size": 64, "num_attention_heads": 6,
+    "num_key_value_heads": 2, "head_dim": 16, "intermediate_size": 160,
+    "moe_intermediate_size": 48, "vocab_size": 96, "num_hidden_layers": 4,
+    "num_dense_layers": 1,
+    "layer_types": ["sliding_attention", "sliding_attention",
+                    "full_attention", "sliding_attention"],
+    "num_experts": 4, "router_width": 16, "held_experts": [4, 4],
+    "num_experts_per_tok": 3, "sliding_window": 8, "rope_theta": 10000,
+    "rms_norm_eps": 1e-5, "route_scale": 2.448, "route_norm": True,
+    "score_func": "sigmoid", "num_shared_experts": 1,
+    "initializer_std": 0.1, "max_position_embeddings": 64,
+}
+PAGE = 4
+
+
+def record(cfg=SMALL, dtype="float32"):
+    return model_config.from_published(dict(cfg, dtype=dtype))
+
+
+@pytest.fixture(scope="module")
+def weights():
+    """(the reference's bfloat16-valued tree, the same raised to float32)."""
+    w = afmoe.make_weights(3, SMALL)
+    return w, jax.tree.map(lambda a: a.astype(jnp.float32), w)
+
+
+def tokens(n, seed=0):
+    return numpy.random.default_rng(seed).integers(0, SMALL["vocab_size"], n)
+
+
+def test_whole_forward_matches_the_reference(weights):
+    from veles_tpu.ops.transformer import transformer_forward
+    w, wf = weights
+    toks = tokens(40)
+    ref = afmoe.logits(w, toks, numpy.arange(40), SMALL)
+    got = transformer_forward(wf, jnp.asarray(toks)[None], record())[0]
+    numpy.testing.assert_allclose(got, ref, atol=1e-4)
+
+
+def test_contiguous_decode_picks_the_references_tokens(weights):
+    from veles_tpu.ops.transformer import generate
+    w, wf = weights
+    toks = tokens(20, 1)
+    out = numpy.asarray(generate(wf, jnp.asarray(toks)[None], 14, record(),
+                                 temperature=0.0, max_len=40)[0])
+    ref = afmoe.logits(w, out, numpy.arange(19, 33), SMALL)
+    numpy.testing.assert_array_equal(ref.argmax(-1), out[20:])
+
+
+@pytest.mark.parametrize("kernel", [None, "kernel"])
+def test_paged_prefill_then_decode_matches_the_reference(weights, kernel):
+    """Prefill chunks, then single steps, through the two kinds of pool
+    with the sliding kind's table sliding as ``WindowTables`` says: the
+    logits of every decoded position are the reference's over the whole
+    sequence.  The context (36) is 4.5 windows, so pages are released
+    mid-request, in prefill and in decode."""
+    from veles_tpu.ops.transformer import head_logits, paged_chunk_apply
+    w, wf = weights
+    cfg = record()
+    full, sliding = model_config.FULL, model_config.SLIDING
+    seq = tokens(36, 2)
+    prompt_len, max_pages = 20, 10
+    wt = WindowTables(KVPagePool(8, PAGE), 1, SMALL["sliding_window"])
+    wt.admit(0, 9)
+    shape = {full: (max_pages + 1, 2, PAGE, 16), sliding: (9, 2, PAGE, 16)}
+    pools = [(jnp.zeros(shape[cfg.kind(i)]), jnp.zeros(shape[cfg.kind(i)]))
+             for i in range(4)]
+    table = numpy.arange(1, max_pages + 1, dtype=numpy.int32)
+
+    @jax.jit
+    def apply(pools, chunk, tables, pos, base):
+        h, new = paged_chunk_apply(
+            wf, chunk[None], pools, tables, pos, cfg,
+            attn_kernel=({1: "decode", PAGE: "prefill"}[chunk.shape[0]]
+                         if kernel else None),
+            base={full: None, sliding: base})
+        return head_logits(wf, h, cfg)[0], new
+
+    def run(chunk, pos):
+        wt.advance(0, pos, pos + len(chunk))
+        wt.verify()
+        assert wt.count[0] <= wt.width
+        # the row as a numpy array of its own: a put may alias host memory
+        # until the dispatch has run, and the next ``advance`` shifts the
+        # row in place (the engine's next shift comes after its fetch)
+        return apply(pools, jnp.asarray(chunk),
+                     {full: jnp.asarray(table)[None],
+                      sliding: jnp.asarray(wt.tables[:1].copy())},
+                     jnp.asarray([pos]), jnp.asarray(wt.base[:1] * PAGE))
+
+    for pos in range(0, prompt_len, PAGE):
+        logits, pools = run(seq[pos:pos + PAGE], pos)
+    got = [logits[-1]]
+    for pos in range(prompt_len, 35):
+        logits, pools = run(seq[pos:pos + 1], pos)
+        got.append(logits[0])
+    assert wt.released > 0
+    ref = afmoe.logits(w, seq, numpy.arange(prompt_len - 1, 35), SMALL)
+    numpy.testing.assert_allclose(jnp.stack(got), ref, atol=1e-4)
+
+
+@pytest.mark.parametrize("features", [
+    {"slots": 3}, {"slots": 16, "attn_kernel": "force", "prefill_chunk": 8}],
+    ids=["xla", "kernels_row_write"])
+def test_engine_serves_the_references_tokens(weights, features):
+    """Through ``LMEngine`` (admission, chunked prefill interleaved with
+    decode, the live-width ladder, window pages released per lane): every
+    served token is the reference's choice, the allocators of both kinds
+    come home whole, and no lane ever held more than W/page + 2 pages of
+    the sliding kind.  16 lanes on pages of 8 take the kernels' one-call
+    row write (it moves whole tiles of 8 float32 rows)."""
+    from veles_tpu.serving import LMEngine
+    w, wf = weights
+    page = features.get("prefill_chunk", PAGE)
+    eng = LMEngine(wf, record(), max_len=48, **dict(
+        {"paged_kv": 48, "prefill_chunk": PAGE}, **features)).start()
+    held = []
+    if eng._wt is not None:
+        real = eng._wt.advance
+
+        def watched(slot, lo, hi):
+            out = real(slot, lo, hi)
+            held.append(int(eng._wt.count[slot]))
+            return out
+        eng._wt.advance = watched
+    try:
+        prompts = [tokens(n, 10 + n) for n in (5, 17, 26, 9)]
+        outs = [f.result(timeout=300)
+                for f in [eng.submit(p, 22) for p in prompts]]
+        for p, o in zip(prompts, outs):
+            seq = numpy.concatenate([p, o])
+            ref = afmoe.logits(w, seq, numpy.arange(len(p) - 1, len(seq) - 1),
+                               SMALL)
+            gap = ref.max(-1) - ref[numpy.arange(len(o)), o]
+            assert float(gap.max()) <= 1e-4
+        if eng._paged:
+            assert eng.verify_pool_invariants()["used_pages"] == 0
+            assert eng._wt.verify()["held"] == 0
+            assert max(held) <= SMALL["sliding_window"] // page + 2
+            snap = eng.metrics.snapshot()
+            assert snap["counters"]["kv_pages_released_window"] > 0
+            assert snap["gauges"]["kv_pages_free.window"] \
+                == snap["gauges"]["kv_pages_total.window"]
+            assert snap["gauges"]["kv_pages_free.full"] == 48
+            assert snap["gauges"]["kv_storage_in_place"] == 1
+            assert snap["counters"].get("kv_storage_rebuilds", 0) == 0
+            # the step's counts, fetched with its tokens: counters, and
+            # the recorder's per-turn columns
+            c = snap["counters"]
+            steps = c["decode_dispatches"]
+            assert c["moe_assignments_held"] + c["moe_assignments_elsewhere"] \
+                == steps * eng.slots * 3 * 3      # lanes x top_k x layers
+            from veles_tpu.serving import tracing
+            turns = eng.recorder.turns()
+            assert int(turns[:, tracing.COL_MOE_HIT].sum()) \
+                == c["moe_experts_hit"]
+            assert int(turns[:, tracing.COL_MOE_LOAD].max()) \
+                == snap["gauges"]["moe_max_expert_load"]
+    finally:
+        eng.stop()
+
+
+def test_dispatches_consume_both_kinds_of_pool(weights):
+    """ISSUE 27's rule for the new block: the step program takes the pools
+    of BOTH kinds donated (every leaf that went in is consumed, none is
+    copied or held twice), and the tokens are what they were."""
+    from veles_tpu.serving import LMEngine
+    _, wf = weights
+    eng = LMEngine(wf, record(), max_len=64, slots=2, paged_kv=24,
+                   prefill_chunk=PAGE)
+    leaves = lambda: [a for pair in eng._storage() for a in pair]  # noqa
+    made = leaves()
+    assert len({a.shape for a in made}) == 2       # two kinds of pool
+    eng.start()
+    try:
+        assert all(a.is_deleted() for a in made)
+        warm, handed, real = leaves(), [], eng._step_jit
+
+        def watched(p, storage, *args):
+            handed.append([a for pair in storage for a in pair])
+            return real(p, storage, *args)
+        eng._step_jit = watched
+        assert len(eng.submit(tokens(11, 5), 9).result(timeout=120)) == 9
+        assert handed and all(a.is_deleted() for a in warm)
+        assert all(a.is_deleted() for ls in handed for a in ls)
+        assert not any(a.is_deleted() for a in leaves())
+        assert eng.metrics.counter("kv_storage_rebuilds") == 0
+    finally:
+        eng.stop()
+
+
+def test_eight_shares_and_the_shared_expert_make_the_uncut_layer():
+    """The share test: a layer that holds ALL 16 experts equals the shared
+    expert once plus the routed parts of its 8 shares of 2 (each computed
+    by the layer that is told it holds only those), to float32 roundoff."""
+    from veles_tpu.ops import moe
+    rng = numpy.random.default_rng(4)
+    d, f, e = 32, 24, 16
+    mk = lambda *s: jnp.asarray(rng.normal(0, 0.2, s), jnp.float32)  # noqa
+    p = {"router": mk(d, e), "bias": mk(e) * 0.05, "w_gate": mk(e, d, f),
+         "w_up": mk(e, d, f), "w_down": mk(e, f, d),
+         "shared": {"w_gate": mk(d, f), "w_up": mk(d, f),
+                    "w_down": mk(f, d)}}
+    x = mk(2, 9, d)
+    base = dict(router_width=e, top_k=4, score="sigmoid", route_norm=True,
+                route_scale=2.448)
+    mm = lambda a, b: jnp.matmul(a, b, precision="highest")  # noqa: E731
+    whole, stats = moe.routed_ffn(
+        p, x, model_config.MoEConfig(shared=True, **base), mm)
+    assert int(stats[0]) == 18 * 4 and int(stats[1]) == 0
+    parts = moe.gated_ffn(p["shared"], x.reshape(-1, d), mm).reshape(x.shape)
+    held = 0
+    for lo in range(0, e, 2):
+        share = dict(p, **{k: p[k][lo:lo + 2]
+                           for k in ("w_gate", "w_up", "w_down")})
+        out, st = moe.routed_ffn(
+            share, x, model_config.MoEConfig(held=(lo, 2), **base), mm)
+        parts = parts + out
+        held += int(st[0])
+        assert int(st[0]) + int(st[1]) == 18 * 4
+    assert held == 18 * 4
+    numpy.testing.assert_allclose(parts, whole, atol=2e-5)
+
+
+def test_routing_properties():
+    """top_k DISTINCT experts; weights sum to route_scale; the selection
+    bias changes the choice and not the weight of an expert."""
+    from veles_tpu.ops import moe
+    rng = numpy.random.default_rng(5)
+    d, e = 16, 12
+    p = {"router": jnp.asarray(rng.normal(0, 1, (d, e)), jnp.float32)}
+    x = jnp.asarray(rng.normal(0, 1, (50, d)), jnp.float32)
+    cfg = model_config.MoEConfig(router_width=e, top_k=4, score="sigmoid",
+                                 route_norm=True, route_scale=2.448)
+    scores, idx, w = moe.route(p, x, cfg)
+    assert all(len(set(row)) == 4 for row in numpy.asarray(idx).tolist())
+    numpy.testing.assert_allclose(w.sum(-1), 2.448, rtol=1e-5)
+    bias = jnp.zeros(e).at[7].set(10.0)          # expert 7 always chosen
+    _, idx_b, w_b = moe.route(dict(p, bias=bias), x, cfg)
+    assert (numpy.asarray(idx_b) == 7).any(axis=1).all()
+    assert not (numpy.asarray(idx) == 7).any(axis=1).all()
+    # its weight is its score over the chosen scores' sum: the bias is
+    # nowhere in it
+    chosen = numpy.take_along_axis(numpy.asarray(scores),
+                                   numpy.asarray(idx_b), axis=1)
+    numpy.testing.assert_allclose(
+        w_b, 2.448 * chosen / chosen.sum(-1, keepdims=True), rtol=1e-5)
+
+
+def test_window_tables_accounting():
+    """``KVPagePool.verify`` per kind through a long request: a lane never
+    holds more than W/page + 2 pages, every key its next query can see is
+    on a held page, admission commits against the pool, and a vacated lane
+    gives everything back."""
+    pool = KVPagePool(7, 4)
+    wt = WindowTables(pool, 3, 8)
+    assert wt.width == 8 // 4 + 2
+    wt.admit(0, 25)                       # commits min(25, 4) = 4
+    assert wt.can_admit(3) and not wt.can_admit(4)
+    wt.admit(1, 3)
+    assert not wt.can_admit(1)
+    for start in range(0, 40, 4):         # prefill chunks of one page
+        wt.advance(0, start, start + 4)
+        assert wt.verify()["held"] <= 4
+    pos = numpy.zeros(3, numpy.int64)
+    for p in range(40, 100):              # decode
+        pos[0] = p
+        if wt.due(pos)[0]:
+            wt.advance(0, p, p + 1)
+        assert wt.count[0] <= wt.width
+        assert wt.base[0] * 4 <= max(0, p - 8 + 1)
+        assert p // 4 < wt.base[0] + wt.count[0]
+    assert wt.released == 100 // 4 - wt.count[0]
+    with pytest.raises(RuntimeError, match="committed"):
+        wt.advance(1, 0, 16)              # 4 pages, committed 3
+    wt.vacate(0)
+    wt.vacate(1)
+    assert wt.verify() == {"held": 0, "committed": 0,
+                           "released": wt.released}
+    assert pool.free_pages == 7
+
+
+@pytest.mark.parametrize("option,match", [
+    ({"prefix_cache": 8}, "prefix_cache"), ({"spec_k": 2}, "spec_k"),
+    ({"megastep": 4}, "megastep"),
+    ({"megastep": "while", "refill_ring": 2}, "megastep"),
+    ({"tp": 2}, "tp >= 2"), ({"paged_kv": 0}, "expert layer needs paged"),
+    ({"temperature": 0.7, "sample_seed": 1}, "temperature")])
+def test_what_was_not_widened_says_so(weights, option, match):
+    from veles_tpu.serving import LMEngine
+    with pytest.raises(ValueError, match=match):
+        LMEngine(weights[1], record(), max_len=64, slots=2,
+                 **dict({"paged_kv": 24, "prefill_chunk": PAGE}, **option))
+
+
+def test_pipeline_stages_refuse_the_block():
+    from veles_tpu.ops.nn_units import NNWorkflow
+    from veles_tpu.ops.transformer import TransformerTrainer
+    with pytest.raises(ValueError, match="pipeline"):
+        TransformerTrainer(NNWorkflow(None, name="t"), config=record(),
+                           pipeline_stages=2)
+
+
+def test_record_from_the_published_keys():
+    cfg = record(dtype="bfloat16")
+    assert cfg.kinds == (model_config.FULL, model_config.SLIDING)
+    assert [cfg.layer_rope(i) for i in range(4)] == [True, True, False, True]
+    assert [cfg.layer_window(i) for i in range(4)] == [8, 8, None, 8]
+    assert cfg.ffn_kinds == ("dense", "moe", "moe", "moe")
+    assert cfg.moe.held == (4, 4) and cfg.moe.router_width == 16
+    assert cfg.window_pages(4) == 4 and cfg.head_size(64) == 16
+    assert model_config.of(4, rope=True, window=16) \
+        == model_config.classic(4, True, 16)
+    with pytest.raises(ValueError, match="record"):
+        model_config.of(cfg, rope=True)
+
+
+def test_the_controls_float8_rounding_is_the_types_own():
+    """``afmoe.round_to_e4m3`` (float32 arithmetic, the same on every
+    backend) gives ``float8_e4m3fn``'s own values for bfloat16 weights of
+    every scale the configuration draws, subnormals and ties included."""
+    rng = numpy.random.default_rng(0)
+    w = numpy.concatenate([
+        rng.normal(0, 0.02, 20000), rng.normal(0, 1, 5000),
+        [0.0, 2 ** -6, 2 ** -9, 2 ** -10, 1.5 * 2 ** -9, 448.0, -0.0156,
+         0.017578125]]).astype(numpy.float32)
+    w = jnp.asarray(w).astype(jnp.bfloat16).astype(jnp.float32)
+    numpy.testing.assert_array_equal(
+        afmoe.round_to_e4m3(w),
+        w.astype(jnp.float8_e4m3fn).astype(jnp.float32))
+
+
+@pytest.mark.parametrize("c, taken", [(1, True), (2, False)])
+def test_row_kernel_only_for_one_row_a_lane(monkeypatch, c, taken):
+    """``paged_write(kernel=True)`` hands ``pallas_kernels.paged_row_write``
+    only a decode write (one row a lane, 16 lanes or more): that kernel
+    rewrites a whole tile per row, and c adjacent positions of a lane (the
+    speculative verify) share tiles, where the chip's pipelined grid would
+    keep only the last row of a tile.  Either way the pool reads the same
+    as with the update slices."""
+    from veles_tpu.ops import attention as A, pallas_kernels as PK
+    lanes, kv, page, dh = 16, 2, 8, 16
+    rng = numpy.random.default_rng(c)
+    pool = jnp.asarray(rng.normal(size=(lanes * 2 + 1, kv, page, dh)),
+                       jnp.float32)
+    ptab = jnp.asarray(1 + numpy.arange(lanes * 2).reshape(lanes, 2),
+                       jnp.int32)
+    pos = jnp.asarray(rng.integers(0, 2 * page - c, lanes), jnp.int32)
+    rows = jnp.asarray(rng.normal(size=(lanes, kv, c, dh)), jnp.float32)
+    calls = []
+    real = PK.paged_row_write
+    monkeypatch.setattr(
+        PK, "paged_row_write",
+        lambda *a, **k: calls.append(1) or real(*a, **k))
+    got = A.paged_write(pool, ptab, pos, rows, kernel=True)
+    want = A.paged_write(pool, ptab, pos, rows)
+    assert bool(calls) == taken
+    numpy.testing.assert_array_equal(got, want)
+
+
+def test_selection_bias_is_balanced_across_shares(weights):
+    """``make_weights`` shifts each chip's share of experts together so
+    that every share has the same mean selection bias (bfloat16 rounding
+    of values near 0.01 moves a mean of 4 by under 4e-5), and keeps the
+    spread inside a share: the share of assignments this chip receives
+    does not depend on the seed through the draw of the bias."""
+    w, _ = weights
+    share = SMALL["held_experts"][1]
+    for blk in w["blocks"][SMALL["num_dense_layers"]:]:
+        bias = numpy.asarray(blk["moe"]["bias"].astype(jnp.float32))
+        means = bias.reshape(-1, share).mean(1)
+        assert numpy.abs(means - means.mean()).max() < 4e-5
+        assert bias.reshape(-1, share).std(1).min() > 1e-3

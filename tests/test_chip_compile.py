@@ -133,6 +133,34 @@ def test_paged_flash_prefill_compiles(one_chip, heads, kv, page, dtype):
     assert "tpu_custom_call" in text
 
 
+def test_paged_flash_prefill_compiles_with_a_head_block_axis(one_chip):
+    """ISSUE 28: 48 query heads of 128 on 8 KV heads over a 256-token
+    chunk are 1536 query rows a KV head; all heads in one grid step ran
+    the kernel out of its 16 MiB (28.6 asked), so the grid takes them a
+    block at a time."""
+    assert PK._heads_per_step(8, 1536, 256) == 1
+    assert PK._heads_per_step(16, 64, 32) == 16       # OPT-1.3B: as it was
+    pool = ((65, 8, 256, 128), BF16)
+    text = compile_for(
+        one_chip,
+        lambda q, kn, vn, k, v, pt, ps: PK.paged_flash_prefill(
+            q, kn, vn, k, v, pt, ps, window=4096, interpret=False),
+        ((1, 48, 256, 128), BF16), ((1, 8, 256, 128), BF16),
+        ((1, 8, 256, 128), BF16), pool, pool, ((1, 18), I32), ((1,), I32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+def test_paged_row_write_compiles(one_chip, dtype):
+    text = compile_for(
+        one_chip,
+        lambda pool, new, pid, off: PK.paged_row_write(
+            pool, new, pid, off, interpret=False),
+        ((65, 8, 256, 128), dtype), ((32, 8, 128), dtype), ((32,), I32),
+        ((32,), I32))
+    assert "tpu_custom_call" in text
+
+
 def test_flash_attention_tpu_traces_at_policy_precision(one_chip,
                                                         monkeypatch):
     """The bundled flash-attention kernel names no dot precision; under
@@ -211,3 +239,77 @@ def test_engine_programs_update_the_pool_in_place(kernel_engine, program):
     copies, aliased = compiled_storage_report(text, leaves[0])
     assert copies == 0, "%d whole-pool copies in %s" % (copies, program)
     assert aliased == len(leaves) == 4
+
+
+@pytest.fixture(scope="module")
+def kinds_engine(one_chip):
+    """A tiny ``LMEngine`` for the sandwich block with two kinds of layer
+    (and so two kinds of pool), the Pallas serving kernels active, 16
+    lanes (the one-call row write) of heads of 128 in bfloat16."""
+    import numpy
+    from benchmark.reference import afmoe
+    from veles_tpu import model_config
+    from veles_tpu.serving import LMEngine
+    cfg = {
+        "model_type": "afmoe", "hidden_size": 256,
+        "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 128,
+        "intermediate_size": 512, "moe_intermediate_size": 256,
+        "vocab_size": 512, "num_hidden_layers": 3, "num_dense_layers": 1,
+        "layer_types": ["sliding_attention", "sliding_attention",
+                        "full_attention"],
+        "num_experts": 4, "router_width": 16, "held_experts": [0, 4],
+        "num_experts_per_tok": 4, "sliding_window": 64, "rope_theta": 10000,
+        "rms_norm_eps": 1e-5, "route_scale": 2.448, "route_norm": True,
+        "score_func": "sigmoid", "num_shared_experts": 1,
+        "initializer_std": 0.02, "max_position_embeddings": 256}
+    params = afmoe.make_weights(1, cfg)
+    monkeypatch = pytest.MonkeyPatch()
+    monkeypatch.setattr(PK, "on_tpu", lambda: True)
+    try:
+        engine = LMEngine(params, model_config.from_published(cfg),
+                          max_len=256, slots=16, prefill_chunk=32,
+                          paged_kv=96, attn_kernel="auto", name="aot_kinds")
+        assert engine._kernel_active and engine._wt is not None
+        shapes = lambda tree: jax.tree.map(  # noqa: E731
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+        yield (engine, shapes(engine.params), shapes(engine._kv_pools),
+               lambda *shape: jax.ShapeDtypeStruct(shape, numpy.int32,
+                                                   sharding=one_chip))
+    finally:
+        monkeypatch.undo()
+
+
+@pytest.mark.parametrize("program", ["chunk", "decode_w1", "decode_w8"])
+def test_programs_of_two_kinds_update_both_pools_in_place(kinds_engine,
+                                                          program):
+    """ISSUE 28: the check of ISSUE 27 for the new block: compiled for
+    the chip, the chunk and the decode program of a stack with two kinds
+    of layer hold no copy with either pool's shape and list every leaf of
+    both kinds under ``input_output_alias``."""
+    from veles_tpu.serving.lm_engine import compiled_storage_report
+    engine, params, pools, ints = kinds_engine
+    wide = engine._wt.width
+    if program == "chunk":
+        lowered = engine._chunk_jit.lower(
+            params, pools,
+            ({"full": ints(engine._max_pages),
+              "sliding": ints(min(engine._max_pages, wide))}, ints()),
+            ints(engine.prefill_chunk), ints(), ints())
+    else:
+        width = int(program.rsplit("w", 1)[1])
+        lowered = engine._step_jit.lower(
+            params, pools,
+            ({"full": ints(engine.slots, width),
+              "sliding": ints(engine.slots, min(width, wide))},
+             ints(engine.slots)),
+            ints(engine.slots), ints(engine.slots))
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    leaves = jax.tree.leaves(engine._kv_pools)
+    kinds = {leaf.shape: leaf for leaf in leaves}
+    assert len(kinds) == 2
+    for leaf in kinds.values():
+        copies, aliased = compiled_storage_report(text, leaf)
+        assert copies == 0, "%d whole-pool copies in %s" % (copies, program)
+    assert aliased == len(leaves) == 6

@@ -82,6 +82,19 @@ def test_serve_phase_answers_like_generate():
         requests_per_client=2, mean_len=24, n_new=6)
 
 
+def test_kinds_phase_serves_the_references_tokens():
+    """ISSUE 28: the sandwich block with two kinds of layer, kernels in
+    interpret mode, 16 lanes (the one-call row write), float32 so that the
+    served tokens are the reference's own."""
+    tiny = dict(chip_smoke.KINDS_LM, hidden_size=64, num_attention_heads=6,
+                num_key_value_heads=2, head_dim=16, intermediate_size=160,
+                moe_intermediate_size=48, vocab_size=96, router_width=16,
+                held_experts=[4, 4], sliding_window=16, initializer_std=0.1,
+                max_position_embeddings=64, dtype="float32")
+    chip_smoke.phase_kinds(3, lm=tiny, slots=16, page=8, prompt_len=14,
+                           n_new=30, gap_limit=1e-4, kernel="force")
+
+
 def test_serve_phase_treats_a_fallback_as_failure(monkeypatch):
     """On the chip attn_kernel='auto' must select the kernels.  The
     engine here is on the CPU and falls back; tell the phase it is on

@@ -16,7 +16,10 @@ by hand::
   programs at d_model 2048 / 16 heads / 4 layers / vocab 32768, Pallas
   serving kernels active; then the same two programs at the benchmark's
   head size (32 heads of 64, 2 layers, 320 pages), where the chip's
-  default layout for the pool is not the kernels'.  For each engine
+  default layout for the pool is not the kernels'; then the same two
+  programs for the sandwich block with two kinds of layer and two kinds
+  of pool (``chip_smoke.KINDS_LM``: heads of 128 in bfloat16, 32 lanes,
+  pages of 256, expert layers through the grouped matmul).  For each engine
   program it prints the copies of a whole KV pool and the pool leaves
   updated in place (``compiled_storage_report``), and exits non-zero when
   a copy is there or a leaf is not aliased;
@@ -165,7 +168,10 @@ def storage_in_place(name, text, eng):
     non-zero on a whole-pool copy or a pool leaf not updated in place."""
     from veles_tpu.serving.lm_engine import compiled_storage_report
     leaves = jax.tree.leaves(eng._kv_pools)
-    copies, aliased = compiled_storage_report(text, leaves[0])
+    # one report per kind of pool (a stack of two kinds has two shapes)
+    kinds = {leaf.shape: leaf for leaf in leaves}.values()
+    copies = sum(compiled_storage_report(text, leaf)[0] for leaf in kinds)
+    _, aliased = compiled_storage_report(text, leaves[0])
     print("%-34s pool copies x%d  pool leaves in place %d of %d"
           % (name, copies, aliased, len(leaves)), flush=True)
     if copies or aliased < len(leaves):
@@ -182,14 +188,24 @@ def engine_programs(tag, eng, one_chip, widths):
     a_params = abstract(eng.params, one_chip)
     pools = abstract(eng._kv_pools, one_chip)
     slots, page = eng.slots, eng.prefill_chunk
+
+    def tables(*lead):
+        """The page-table argument at width ``lead[-1]``
+        (``LMEngine._table_args``): one table, or for a stack of two
+        kinds of layer one per kind and where the sliding kind's begins."""
+        if eng._wt is None:
+            return s(lead)
+        narrow = lead[:-1] + (min(lead[-1], eng._wt.width),)
+        return {"full": s(lead), "sliding": s(narrow)}, s(lead[:-1])
+
     name = "%s prefill chunk (kernel)" % tag
     storage_in_place(name, compile_(
-        name, eng._chunk_jit, a_params, pools, s((eng._max_pages,)),
+        name, eng._chunk_jit, a_params, pools, tables(eng._max_pages),
         s((page,)), s(()), s(())), eng)
     for width in widths:
         name = "%s decode step width %d" % (tag, width)
         text = compile_(name, eng._step_jit, a_params, pools,
-                        s((slots, width)), s((slots,)), s((slots,)))
+                        tables(slots, width), s((slots,)), s((slots,)))
         if "tpu_custom_call" not in text:
             raise SystemExit("no Pallas kernel in the decode program")
         storage_in_place(name, text, eng)
@@ -215,6 +231,17 @@ def lm(one_chip):
     eng = kernel_engine(params, 32, max_len=LM["max_len"], slots=8,
                         prefill_chunk=32, paged_kv=320)
     engine_programs("engine dh64", eng, one_chip, (1, 64))
+    # ISSUE 28: the sandwich block, two kinds of layer and of pool
+    from benchmark.reference import afmoe
+    from veles_tpu import model_config
+    kinds = chip_smoke.KINDS_LM
+    params = jax.tree.map(
+        lambda a: jnp.zeros(a.shape, a.dtype),
+        jax.eval_shape(lambda: afmoe.make_weights(1, kinds)))
+    eng = kernel_engine(params, model_config.from_published(kinds),
+                        max_len=kinds["max_position_embeddings"], slots=32,
+                        prefill_chunk=256, paged_kv=True)
+    engine_programs("engine kinds", eng, one_chip, (1, 8))
 
 
 def tp(topo):

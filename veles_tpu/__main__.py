@@ -173,6 +173,11 @@ def build_argparser():
                              "to SLOTS prompts concurrently over one "
                              "shared KV cache (continuous batching); "
                              "0 = one prompt batch at a time")
+    parser.add_argument("--serve-max-new", type=int, default=256,
+                        metavar="TOKENS",
+                        help="with --serve on an LM workflow: the most "
+                             "new tokens one request may ask for (a "
+                             "larger n_new is cut to it)")
     parser.add_argument("--serve-prefix-cache", type=int, default=0,
                         metavar="CHUNKS",
                         help="with --serve-slots: radix prefix cache "
@@ -586,6 +591,7 @@ def main(argv=None):
             # transformer-trainer workflows serve token continuation
             from veles_tpu.restful_api import serve_lm
             api = serve_lm(wf, port=args.serve, slots=args.serve_slots,
+                           max_new=args.serve_max_new,
                            prefix_cache=args.serve_prefix_cache,
                            prefill_chunk=args.serve_prefill_chunk,
                            spec_k=args.serve_spec_k,

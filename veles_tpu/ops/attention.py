@@ -23,11 +23,13 @@ module is BEYOND-PARITY capability, designed TPU-first rather than ported:
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
 import jax.numpy as jnp
 
+from veles_tpu import model_config
 from veles_tpu.ops.functional import matmul
 
 NEG_INF = -1e30
@@ -71,12 +73,15 @@ def rope_rotate(x, positions, theta=10000.0):
     rotation is final).  ``positions``: (seq,) int array (traced ok)."""
     dh = x.shape[-1]
     half = dh // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=x.dtype) / half)
-    ang = positions.astype(x.dtype)[:, None] * freqs[None, :]  # (s, half)
+    # angles are float32 whatever the activations are (a bfloat16 angle
+    # at position 8000 is off by tens of radians)
+    ct = jnp.promote_types(x.dtype, jnp.float32)
+    freqs = theta ** (-jnp.arange(0, half, dtype=ct) / half)
+    ang = positions.astype(ct)[:, None] * freqs[None, :]  # (s, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., :half], x[..., half:]
+    x1, x2 = x[..., :half].astype(ct), x[..., half:].astype(ct)
     return jnp.concatenate([x1 * cos - x2 * sin,
-                            x2 * cos + x1 * sin], axis=-1)
+                            x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
 def rope_rotate_batched(x, positions, theta=10000.0):
@@ -334,10 +339,81 @@ def _repeat_kv(k, n_heads):
     return k if reps == 1 else jnp.repeat(k, reps, axis=-3)
 
 
+def rms_norm(x, g, eps, dtype=None):
+    """``x / sqrt(mean(x^2) + eps) * g`` over the last axis, computed in
+    float32 whatever ``x`` is; returns ``dtype`` (default ``x``'s)."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + eps)
+    return (y * g.astype(jnp.float32)).astype(dtype or x.dtype)
+
+
+def cfg_matmul(cfg, a, b):
+    """``a @ b`` by the record's rule: a float32 model follows
+    ``functional``'s process-wide policy; any other dtype multiplies its
+    own operands, accumulates in float32 and rounds once."""
+    if cfg.dtype == "float32":
+        return matmul(a, b)
+    return jnp.matmul(a, b, preferred_element_type=jnp.float32) \
+        .astype(a.dtype)
+
+
+def _qkv(params, x, cfg):
+    """q (b, h, s, dh), k and v (b, kv, s, dh) and the output gate
+    (b, s, h·dh; None where the block has none) of ``x`` (b, s, d), not
+    yet rotated.  The ``sandwich`` block norms q and k per head."""
+    b, s, d = x.shape
+    dh = cfg.head_size(d)
+    kv = cfg.kv_heads(params, d)
+
+    def split(w, heads):
+        return cfg_matmul(cfg, x, w).reshape(b, s, heads, dh) \
+            .transpose(0, 2, 1, 3)
+
+    q = split(params["wq"], cfg.n_heads)
+    k = split(params["wk"], kv)
+    v = split(params["wv"], kv)
+    if cfg.block != "sandwich":
+        return q, k, v, None
+    q = rms_norm(q, params["q_norm"], cfg.eps)
+    k = rms_norm(k, params["k_norm"], cfg.eps)
+    return q, k, v, cfg_matmul(cfg, x, params["wg"])
+
+
+def _merge(params, o, gate, cfg):
+    """Heads' outputs (b, h, s, dh) through the sigmoid output gate (where
+    the block has one) and ``wo``."""
+    b, h, s, dh = o.shape
+    o = o.transpose(0, 2, 1, 3).reshape(b, s, h * dh)
+    if gate is not None:
+        o = (o.astype(jnp.float32)
+             * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(o.dtype)
+    return cfg_matmul(cfg, o, params["wo"])
+
+
+def _attend(q, k, v, live):
+    """softmax(q k^T / sqrt(dh), masked by ``live``) v — the dense core of
+    every cached path.  float32 operands follow ``functional``'s policy;
+    narrower ones keep scores, softmax and accumulation in float32."""
+    dh = q.shape[-1]
+    kt = jnp.swapaxes(k, -1, -2)
+    if q.dtype == jnp.float32:
+        scores = matmul(q, kt) / jnp.sqrt(jnp.asarray(dh, q.dtype))
+        scores = jnp.where(live, scores, NEG_INF)
+        return matmul(jax.nn.softmax(scores, axis=-1), v)
+    scores = jnp.matmul(q, kt, preferred_element_type=jnp.float32) \
+        / jnp.sqrt(jnp.float32(dh))
+    scores = jnp.where(live, scores, NEG_INF)
+    return jnp.matmul(jax.nn.softmax(scores, axis=-1).astype(v.dtype), v,
+                      preferred_element_type=jnp.float32).astype(v.dtype)
+
+
 def mha_forward(params, x, n_heads, causal=True, block_size=None,
                 return_kv=False, rope=False, window=None,
-                positions=None, sinks=0):
-    """Multi-head attention over (batch, seq, d_model).
+                positions=None, sinks=0, layer=0):
+    """Multi-head attention over (batch, seq, d_model).  ``n_heads`` is a
+    head count with the classic keywords beside it, or a
+    :class:`~veles_tpu.ops.model_config.ModelConfig` (then ``layer``
+    says which layer's attention kind applies).
 
     ``return_kv=True`` additionally returns the projected (k, v) heads
     — the prefill half of KV-cached decoding (autoregressive serving
@@ -345,21 +421,23 @@ def mha_forward(params, x, n_heads, causal=True, block_size=None,
     under GQA those are the n_kv_heads, i.e. the smaller cache).
     ``rope`` rotates q/k (``positions`` defaults to 0..s-1); ``window``
     restricts attention to the last W positions."""
-    b, s, d = x.shape
-    dh = d // n_heads
-    kv = kv_heads_of(params, n_heads, d)
-
-    def split(w, heads):
-        return matmul(x, w).reshape(b, s, heads, dh).transpose(0, 2, 1, 3)
-
-    q = split(params["wq"], n_heads)
-    k = split(params["wk"], kv)
-    v = split(params["wv"], kv)
+    cfg = model_config.of(n_heads, rope, window, sinks)
+    rope, window = cfg.layer_rope(layer), cfg.layer_window(layer)
+    n_heads, sinks = cfg.n_heads, cfg.sinks
+    s = x.shape[1]
+    q, k, v, gate = _qkv(params, x, cfg)
     if rope:
         pos = positions if positions is not None else jnp.arange(s)
-        q, k = rope_rotate(q, pos), rope_rotate(k, pos)
+        q = rope_rotate(q, pos, cfg.rope_theta)
+        k = rope_rotate(k, pos, cfg.rope_theta)
     kr, vr = _repeat_kv(k, n_heads), _repeat_kv(v, n_heads)
-    if _ATTN_BACKEND == "flash_pallas" and not window:
+    if cfg.block == "sandwich":
+        if not causal:
+            raise ValueError("the sandwich block is causal")
+        with jax.named_scope("attn.window" if window else "attn.full"):
+            o = _attend(q, kr, vr,
+                        chunk_live_mask(0, s, s, window)[None, None])
+    elif _ATTN_BACKEND == "flash_pallas" and not window:
         o = flash_attention_tpu(q, kr, vr, causal=causal)
     elif block_size:
         o = blockwise_attention(q, kr, vr, block_size, causal=causal,
@@ -367,43 +445,31 @@ def mha_forward(params, x, n_heads, causal=True, block_size=None,
     else:
         o = attention(q, kr, vr, causal=causal, window=window,
                       sinks=sinks)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, d)
-    out = matmul(o, params["wo"])
+    out = _merge(params, o, gate, cfg)
     return (out, k, v) if return_kv else out
 
 
 def _decode_attend(params, x, k_cache, v_cache, write_idx, live,
-                   rope_pos, n_heads):
+                   rope_pos, cfg):
     """THE decode-step core shared by the linear-cache and ring-buffer
     paths (they must never drift numerically): project q/k/v for one
     position, optionally rotate q/k at ``rope_pos``, write the new k/v
     at cache index ``write_idx``, attend over the cache under the
     precomputed ``live`` mask (cache_len,), and project out."""
-    b, _, d = x.shape
-    dh = d // n_heads
-    kv = kv_heads_of(params, n_heads, d)
-
-    def split(w, heads):
-        return matmul(x, w).reshape(b, 1, heads, dh).transpose(0, 2, 1, 3)
-
-    q = split(params["wq"], n_heads)            # (b, h, 1, dh)
-    k_new = split(params["wk"], kv)
+    cfg = model_config.of(cfg)
+    q, k_new, v_new, gate = _qkv(params, x, cfg)     # (b, h, 1, dh)
     if rope_pos is not None:
         pos_arr = jnp.asarray(rope_pos)[None]
-        q = rope_rotate(q, pos_arr)
-        k_new = rope_rotate(k_new, pos_arr)
+        q = rope_rotate(q, pos_arr, cfg.rope_theta)
+        k_new = rope_rotate(k_new, pos_arr, cfg.rope_theta)
     k_cache = jax.lax.dynamic_update_slice(
         k_cache, k_new, (0, 0, write_idx, 0))
     v_cache = jax.lax.dynamic_update_slice(
-        v_cache, split(params["wv"], kv), (0, 0, write_idx, 0))
-    scores = matmul(q, jnp.swapaxes(_repeat_kv(k_cache, n_heads),
-                                    -1, -2)) / jnp.sqrt(
-        jnp.asarray(dh, q.dtype))               # (b, h, 1, cache_len)
-    scores = jnp.where(live[None, None, None, :], scores, NEG_INF)
-    o = matmul(jax.nn.softmax(scores, axis=-1),
-               _repeat_kv(v_cache, n_heads))
-    o = o.transpose(0, 2, 1, 3).reshape(b, 1, d)
-    return matmul(o, params["wo"]), k_cache, v_cache
+        v_cache, v_new, (0, 0, write_idx, 0))
+    o = _attend(q, _repeat_kv(k_cache, cfg.n_heads),
+                _repeat_kv(v_cache, cfg.n_heads),
+                live[None, None, None, :])       # (b, h, 1, cache_len)
+    return _merge(params, o, gate, cfg), k_cache, v_cache
 
 
 def chunk_live_mask(pos, c, cache_len, window=None, sinks=0):
@@ -423,7 +489,7 @@ def chunk_live_mask(pos, c, cache_len, window=None, sinks=0):
 
 
 def mha_chunk_step(params, x, k_cache, v_cache, pos, n_heads,
-                   rope=False, window=None, sinks=0):
+                   rope=False, window=None, sinks=0, layer=0):
     """``c`` decode/prefill positions against the KV cache in ONE pass —
     the multi-token generalization of :func:`mha_decode_step` (which is
     the c=1 case) serving both CHUNKED PREFILL (a prompt slice lands in
@@ -440,36 +506,27 @@ def mha_chunk_step(params, x, k_cache, v_cache, pos, n_heads,
     caller must guarantee ``pos + c <= max_len`` — dynamic_update_slice
     CLAMPS out-of-range starts, which would silently shift the write
     onto committed rows."""
-    b, c, d = x.shape
-    dh = d // n_heads
-    kv = kv_heads_of(params, n_heads, d)
-
-    def split(w, heads):
-        return matmul(x, w).reshape(b, c, heads, dh).transpose(0, 2, 1, 3)
-
-    q = split(params["wq"], n_heads)            # (b, h, c, dh)
-    k_new = split(params["wk"], kv)
+    cfg = model_config.of(n_heads, rope, window, sinks)
+    rope, window = cfg.layer_rope(layer), cfg.layer_window(layer)
+    c = x.shape[1]
+    q, k_new, v_new, gate = _qkv(params, x, cfg)    # (b, h, c, dh)
     if rope:
         pos_arr = pos + jnp.arange(c)
-        q = rope_rotate(q, pos_arr)
-        k_new = rope_rotate(k_new, pos_arr)
+        q = rope_rotate(q, pos_arr, cfg.rope_theta)
+        k_new = rope_rotate(k_new, pos_arr, cfg.rope_theta)
     k_cache = jax.lax.dynamic_update_slice(
         k_cache, k_new, (0, 0, pos, 0))
     v_cache = jax.lax.dynamic_update_slice(
-        v_cache, split(params["wv"], kv), (0, 0, pos, 0))
-    scores = matmul(q, jnp.swapaxes(_repeat_kv(k_cache, n_heads),
-                                    -1, -2)) / jnp.sqrt(
-        jnp.asarray(dh, q.dtype))               # (b, h, c, cache_len)
-    live = chunk_live_mask(pos, c, k_cache.shape[2], window, sinks)
-    scores = jnp.where(live[None, None, :, :], scores, NEG_INF)
-    o = matmul(jax.nn.softmax(scores, axis=-1),
-               _repeat_kv(v_cache, n_heads))
-    o = o.transpose(0, 2, 1, 3).reshape(b, c, d)
-    return matmul(o, params["wo"]), k_cache, v_cache
+        v_cache, v_new, (0, 0, pos, 0))
+    live = chunk_live_mask(pos, c, k_cache.shape[2], window, cfg.sinks)
+    o = _attend(q, _repeat_kv(k_cache, cfg.n_heads),
+                _repeat_kv(v_cache, cfg.n_heads),
+                live[None, None, :, :])          # (b, h, c, cache_len)
+    return _merge(params, o, gate, cfg), k_cache, v_cache
 
 
 def mha_decode_step(params, x, k_cache, v_cache, pos, n_heads,
-                    rope=False, window=None, sinks=0):
+                    rope=False, window=None, sinks=0, layer=0):
     """One autoregressive decode step with a KV cache.
 
     x: (batch, 1, d_model) — the current position's activations;
@@ -483,15 +540,17 @@ def mha_decode_step(params, x, k_cache, v_cache, pos, n_heads,
     rotates the new q/k at ``pos`` (cached keys are pre-rotated);
     ``window`` masks cache entries older than W positions.
     """
+    cfg = model_config.of(n_heads, rope, window, sinks)
+    rope, window = cfg.layer_rope(layer), cfg.layer_window(layer)
     idx = jnp.arange(k_cache.shape[2])
     live = idx <= pos
     if window:
         in_window = idx > pos - window
-        if sinks:
-            in_window |= idx < sinks     # sinks bypass the window only
+        if cfg.sinks:
+            in_window |= idx < cfg.sinks   # sinks bypass the window only
         live &= in_window
     return _decode_attend(params, x, k_cache, v_cache, pos, live,
-                          pos if rope else None, n_heads)
+                          pos if rope else None, cfg)
 
 
 # ------------------------------------------------------------- paged KV
@@ -514,7 +573,14 @@ def paged_view(pool, ptab):
                                      g.shape[-1]))
 
 
-def paged_write(pool, ptab, pos, rows, write_mask=None):
+#: rows of one write from which the serving kernels' engine installs them
+#: with ONE Pallas call (``pallas_kernels.paged_row_write``) instead of an
+#: update slice each: a chain of 32 slices on each of ten pools took the
+#: chip's compiler two minutes a decode program (PERF.md section 6, PR 28)
+ROW_KERNEL_MIN = 16
+
+
+def paged_write(pool, ptab, pos, rows, write_mask=None, kernel=False):
     """Write ``c`` new K (or V) rows into the pool at the lanes'
     LINEAR positions [pos, pos+c) — the paged sibling of the contiguous
     ``dynamic_update_slice`` write.
@@ -549,7 +615,16 @@ def paged_write(pool, ptab, pos, rows, write_mask=None):
     (ISSUE 13) keeps early-exit lanes inside the batched program, and
     their dead iterations must not be able to touch ANY allocated page
     — not their own (possibly trie-shared) pages, not a clamped table
-    edge — no matter what garbage position the frozen carry holds."""
+    edge — no matter what garbage position the frozen carry holds.
+
+    ``kernel`` (static; the caller runs the Pallas serving kernels) hands
+    a DECODE write (c = 1) of ``ROW_KERNEL_MIN`` lanes or more to
+    ``pallas_kernels.paged_row_write``: the same rows at the same places,
+    one call.  Only c = 1: that kernel rewrites a whole tile of pool rows
+    per written row, so it is right only while no two rows of a call lie
+    in one tile of a live page, which one row a lane guarantees (lanes own
+    distinct pages) and c adjacent positions of a lane (the speculative
+    verify, a prefill chunk) do not; those keep their update slices."""
     page = pool.shape[2]
     c = rows.shape[-2]
     linear = jnp.asarray(pos)[..., None] + jnp.arange(c)   # (..., c)
@@ -562,6 +637,13 @@ def paged_write(pool, ptab, pos, rows, write_mask=None):
     # the ids
     new = jnp.moveaxis(rows, -3, -2).reshape(
         -1, pool.shape[1], 1, pool.shape[3])
+    if kernel and c == 1 and new.shape[0] >= ROW_KERNEL_MIN \
+            and page % (32 // pool.dtype.itemsize) == 0:
+        # (the kernel moves whole tiles of 32 / itemsize pool rows)
+        from veles_tpu.ops import pallas_kernels as PK
+        return PK.paged_row_write(pool, new[:, :, 0, :],
+                                  page_ids.reshape(-1),
+                                  offsets.reshape(-1))
     return _write_rows(pool, new, page_ids.reshape(-1),
                        offsets.reshape(-1))
 
@@ -584,7 +666,8 @@ def _write_rows(pool, new, page_ids, offsets):
 
 def mha_paged_chunk_step(params, x, k_pool, v_pool, ptab, pos, n_heads,
                          rope=False, window=None, sinks=0,
-                         attn_kernel=None, write_mask=None):
+                         attn_kernel=None, write_mask=None, layer=0,
+                         base=None):
     """``c`` positions per lane against the PAGED KV pool in one pass —
     :func:`mha_chunk_step` with the storage indirected through a page
     table, batched over lanes (each at its own traced ``pos``).
@@ -608,6 +691,16 @@ def mha_paged_chunk_step(params, x, k_pool, v_pool, ptab, pos, n_heads,
     pins outputs bit-identical to the contiguous path across the
     ladder on the test platform.
 
+    ``base`` (traced (b,), a multiple of the page size; None = 0) says
+    where each lane's table BEGINS: entry j of the row maps linear
+    positions [base + j·page, base + (j+1)·page).  A sliding layer's
+    table holds only the pages its window still reaches, so it starts
+    at the lane's first live page instead of at position 0.  Writes,
+    the gathered view, the band and the kernels' grids all work on
+    ``pos - base``: causality and the window depend on differences of
+    positions only (so no sinks with a base).  Rotary angles are the one
+    place the absolute ``pos`` enters.
+
     ``attn_kernel`` (STATIC) routes the attention through the Pallas
     serving kernels (ISSUE 7) instead of the gather + dense softmax:
     'decode' (any c, any alignment — the pool is written first, then
@@ -626,47 +719,47 @@ def mha_paged_chunk_step(params, x, k_pool, v_pool, ptab, pos, n_heads,
     for them.  Not supported with ``attn_kernel='prefill'`` (the fused
     install has no mask slot; the megastep never uses that leg —
     prefill chunks stay per-lane host dispatches)."""
-    b, c, d = x.shape
-    dh = d // n_heads
-    kv = kv_heads_of(params, n_heads, d)
-
-    def split(w, heads):
-        return matmul(x, w).reshape(b, c, heads, dh).transpose(0, 2, 1, 3)
-
-    q = split(params["wq"], n_heads)            # (b, h, c, dh)
-    k_new = split(params["wk"], kv)
-    v_new = split(params["wv"], kv)
+    cfg = model_config.of(n_heads, rope, window, sinks)
+    rope, window = cfg.layer_rope(layer), cfg.layer_window(layer)
+    n_heads, sinks = cfg.n_heads, cfg.sinks
+    c = x.shape[1]
+    q, k_new, v_new, gate = _qkv(params, x, cfg)    # (b, h, c, dh)
     if rope:
         positions = jnp.asarray(pos)[:, None] + jnp.arange(c)   # (b, c)
-        q = rope_rotate_batched(q, positions)
-        k_new = rope_rotate_batched(k_new, positions)
-    if attn_kernel:
-        from veles_tpu.ops import pallas_kernels as PK
-        if attn_kernel == "prefill":
-            if write_mask is not None:
-                raise ValueError("write_mask is not supported with "
-                                 "attn_kernel='prefill' (fused install)")
-            o, k_pool, v_pool = PK.paged_flash_prefill(
-                q, k_new, v_new, k_pool, v_pool, ptab, pos,
-                window=window, sinks=sinks)
-        else:
-            k_pool = paged_write(k_pool, ptab, pos, k_new, write_mask)
-            v_pool = paged_write(v_pool, ptab, pos, v_new, write_mask)
-            o = PK.paged_flash_decode(q, k_pool, v_pool, ptab, pos,
-                                      window=window, sinks=sinks)
-        o = o.transpose(0, 2, 1, 3).reshape(b, c, d)
-        return matmul(o, params["wo"]), k_pool, v_pool
-    k_pool = paged_write(k_pool, ptab, pos, k_new, write_mask)
-    v_pool = paged_write(v_pool, ptab, pos, v_new, write_mask)
-    kx = paged_view(k_pool, ptab)               # (b, kv, L, dh)
-    vx = paged_view(v_pool, ptab)
-    scores = matmul(q, jnp.swapaxes(_repeat_kv(kx, n_heads),
-                                    -1, -2)) / jnp.sqrt(
-        jnp.asarray(dh, q.dtype))               # (b, h, c, L)
-    live = jax.vmap(lambda p: chunk_live_mask(
-        p, c, kx.shape[-2], window, sinks))(jnp.asarray(pos))
-    scores = jnp.where(live[:, None, :, :], scores, NEG_INF)
-    o = matmul(jax.nn.softmax(scores, axis=-1),
-               _repeat_kv(vx, n_heads))
-    o = o.transpose(0, 2, 1, 3).reshape(b, c, d)
-    return matmul(o, params["wo"]), k_pool, v_pool
+        q = rope_rotate_batched(q, positions, cfg.rope_theta)
+        k_new = rope_rotate_batched(k_new, positions, cfg.rope_theta)
+    if base is not None:
+        if sinks:
+            raise ValueError("attention sinks need absolute positions: "
+                             "not with a table base")
+        pos = jnp.asarray(pos) - base
+    scope = (jax.named_scope("attn.window" if window else "attn.full")
+             if cfg.attn_kinds is not None else contextlib.nullcontext())
+    with scope:
+        if attn_kernel:
+            from veles_tpu.ops import pallas_kernels as PK
+            if attn_kernel == "prefill":
+                if write_mask is not None:
+                    raise ValueError(
+                        "write_mask is not supported with "
+                        "attn_kernel='prefill' (fused install)")
+                o, k_pool, v_pool = PK.paged_flash_prefill(
+                    q, k_new, v_new, k_pool, v_pool, ptab, pos,
+                    window=window, sinks=sinks)
+            else:
+                k_pool = paged_write(k_pool, ptab, pos, k_new, write_mask,
+                                     kernel=True)
+                v_pool = paged_write(v_pool, ptab, pos, v_new, write_mask,
+                                     kernel=True)
+                o = PK.paged_flash_decode(q, k_pool, v_pool, ptab, pos,
+                                          window=window, sinks=sinks)
+            return _merge(params, o, gate, cfg), k_pool, v_pool
+        k_pool = paged_write(k_pool, ptab, pos, k_new, write_mask)
+        v_pool = paged_write(v_pool, ptab, pos, v_new, write_mask)
+        kx = paged_view(k_pool, ptab)               # (b, kv, L, dh)
+        vx = paged_view(v_pool, ptab)
+        live = jax.vmap(lambda p: chunk_live_mask(
+            p, c, kx.shape[-2], window, sinks))(jnp.asarray(pos))
+        o = _attend(q, _repeat_kv(kx, n_heads), _repeat_kv(vx, n_heads),
+                    live[:, None, :, :])            # (b, h, c, L)
+    return _merge(params, o, gate, cfg), k_pool, v_pool

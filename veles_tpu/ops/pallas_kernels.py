@@ -510,6 +510,23 @@ def paged_flash_decode(q, k_pool, v_pool, ptab, pos, window=None,
     return _unpack_outputs(o, r).reshape(b, h, c, dh)
 
 
+#: float32 scores (kv heads x query rows x page) that one grid step of the
+#: prefill kernel may hold: with their exponentials, the accumulator and
+#: the double-buffered operands this keeps a step under the 16 MiB that
+#: the chip's compiler gives a kernel
+_SCORES_BYTES = 2 << 20
+
+
+def _heads_per_step(kvp, rows, page):
+    """The (packed) kv heads one grid step of the prefill kernel takes:
+    all of them while their scores stay under ``_SCORES_BYTES``, else the
+    largest divisor of ``kvp`` that does (at least one)."""
+    for hb in range(kvp, 0, -1):
+        if kvp % hb == 0 and hb * rows * page * 4 <= _SCORES_BYTES:
+            return hb
+    return 1
+
+
 def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
                         window=None, sinks=0, interpret=None):
     """Fused chunked-prefill attention: one page-aligned chunk of
@@ -523,6 +540,12 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
     not a separate write.  The pool's rows may pack r heads
     as :func:`paged_flash_decode` says; ``k_new``/``v_new`` arrive
     plain, (b, kv, c, dh), and are packed here.
+
+    A grid step takes all the pool's (packed) kv heads while their scores
+    fit the kernel's memory (``_heads_per_step``), else a block of them:
+    the grid then has a head-block axis between the lane's and the
+    page's (6 query heads of 128 over a 256-token chunk are 1536 query
+    rows per kv head, 1.5 MB of float32 scores a head and page).
 
     Caller contract (the engine's chunk program guarantees both):
     ``pos`` is page-aligned and the chunk occupies exactly the pool
@@ -542,10 +565,12 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
     g = h // (kvp * r)
     rows = r * g * c            # query rows per pool row
     qp = _pack_queries(q.reshape(b, kvp * r, g * c, dh), r)
+    hb = _heads_per_step(kvp, rows, page)
+    grid = (b, m_pages) if hb == kvp else (b, kvp // hb, m_pages)
 
     def kernel(ptab_ref, pos_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
                o_ref, ko_ref, vo_ref, acc_ref, l_ref, m_ref):
-        i, j = pl.program_id(0), pl.program_id(1)
+        i, j = pl.program_id(0), pl.program_id(len(grid) - 1)
 
         @pl.when(j == 0)
         def _():
@@ -581,33 +606,39 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
             ko_ref[0] = kn_ref[0]
             vo_ref[0] = vn_ref[0]
 
-    def tgt(i, j, pt, ps):
-        return (pt[i, ps[i] // page], 0, 0, 0)
+    # index maps over (lane, [head block,] page, page table, positions)
+    def head_block(idx):
+        return idx[1] if len(grid) == 3 else 0
+
+    def lane(*idx):
+        return (idx[0], head_block(idx), 0, 0)
+
+    def history(*idx):
+        (i, j), pt = (idx[0], idx[-3]), idx[-2]
+        return (pt[i, j], head_block(idx), 0, 0)
+
+    def tgt(*idx):
+        i, pt, ps = idx[0], idx[-2], idx[-1]
+        return (pt[i, ps[i] // page], head_block(idx), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, m_pages),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((1, kvp, rows, lanes),
-                         lambda i, j, pt, ps: (i, 0, 0, 0)),
-            pl.BlockSpec((1, kvp, c, lanes),
-                         lambda i, j, pt, ps: (i, 0, 0, 0)),
-            pl.BlockSpec((1, kvp, c, lanes),
-                         lambda i, j, pt, ps: (i, 0, 0, 0)),
-            pl.BlockSpec((1, kvp, page, lanes),
-                         lambda i, j, pt, ps: (pt[i, j], 0, 0, 0)),
-            pl.BlockSpec((1, kvp, page, lanes),
-                         lambda i, j, pt, ps: (pt[i, j], 0, 0, 0)),
+            pl.BlockSpec((1, hb, rows, lanes), lane),
+            pl.BlockSpec((1, hb, c, lanes), lane),
+            pl.BlockSpec((1, hb, c, lanes), lane),
+            pl.BlockSpec((1, hb, page, lanes), history),
+            pl.BlockSpec((1, hb, page, lanes), history),
         ],
         out_specs=(
-            pl.BlockSpec((1, kvp, rows, lanes),
-                         lambda i, j, pt, ps: (i, 0, 0, 0)),
-            pl.BlockSpec((1, kvp, page, lanes), tgt),
-            pl.BlockSpec((1, kvp, page, lanes), tgt),
+            pl.BlockSpec((1, hb, rows, lanes), lane),
+            pl.BlockSpec((1, hb, page, lanes), tgt),
+            pl.BlockSpec((1, hb, page, lanes), tgt),
         ),
-        scratch_shapes=[pltpu.VMEM((kvp, rows, lanes), jnp.float32),
-                        pltpu.VMEM((kvp, rows), jnp.float32),
-                        pltpu.VMEM((kvp, rows), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hb, rows, lanes), jnp.float32),
+                        pltpu.VMEM((hb, rows), jnp.float32),
+                        pltpu.VMEM((hb, rows), jnp.float32)],
     )
     o, k_out, v_out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
@@ -647,3 +678,59 @@ def serving_kernels_supported(paged, n_heads, kv_heads, head_dim,
     if page < 1 or head_dim < 1:
         return False, "degenerate geometry"
     return True, None
+
+
+def paged_row_write(pool, new, page_ids, offsets, interpret=None):
+    """Write row ``new[i]`` (kv/r, r·dh) of every kv head into pool page
+    ``page_ids[i]`` at offset ``offsets[i]``, in place: the decode step's
+    K/V install for MANY lanes as one kernel.
+
+    ``attention.paged_write`` writes each row with an update slice of its
+    own, which is right for a few lanes; a chain of 32 of them on each of
+    ten pools took the chip's compiler two minutes a program (six programs
+    a ladder; PERF.md section 6, PR 28).  Here the grid walks the rows; a
+    step reads the aligned tile of ``32 / itemsize`` pool rows that holds
+    its target (the smallest block the chip's tiling allows), replaces the
+    one row, and writes the tile back through the aliased output.
+
+    NO TWO ROWS OF A CALL MAY LIE IN ONE TILE OF A LIVE PAGE.  On the chip
+    the grid is pipelined: a step's tile is fetched before the step
+    before it has written its own back, and a tile whose block index
+    repeats is not fetched again, so of several rows in one tile only the
+    last would survive (interpret mode runs the steps in order and cannot
+    show it).  The caller (``attention.paged_write``) therefore comes here
+    with ONE row a lane: lanes own distinct pages.  Free lanes parked on
+    the scratch page may share a tile; nothing attends what they write.
+
+    pool: (n_pages, kv/r, page, r·dh); new: (n, kv/r, r·dh); page_ids,
+    offsets: (n,) int32.  Returns the pool."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, kvp, lanes = new.shape
+    tile = 32 // pool.dtype.itemsize
+
+    def kernel(pid_ref, off_ref, new_ref, pool_ref, out_ref):
+        row = off_ref[pl.program_id(0)] % tile
+        rows = jax.lax.broadcasted_iota(jnp.int32, (kvp, tile, lanes), 1)
+        fresh = jnp.broadcast_to(new_ref[0][:, None, :], (kvp, tile, lanes))
+        out_ref[0] = jnp.where(rows == row, fresh, pool_ref[0])
+
+    def target(i, pid, off):
+        return (pid[i], 0, off[i] // tile, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n,),
+        in_specs=[pl.BlockSpec((1, kvp, lanes), lambda i, pid, off: (i, 0, 0)),
+                  pl.BlockSpec((1, kvp, tile, lanes), target)],
+        out_specs=pl.BlockSpec((1, kvp, tile, lanes), target),
+    )
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        # operand indices include the two scalar-prefetch arguments
+        input_output_aliases={3: 0},
+        interpret=_interpret(interpret),
+    )(jnp.asarray(page_ids, jnp.int32), jnp.asarray(offsets, jnp.int32),
+      new, pool)

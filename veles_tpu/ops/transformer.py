@@ -22,7 +22,9 @@ from veles_tpu import prng as prng_mod
 from veles_tpu.accel import AcceleratedUnit
 from veles_tpu.workflow import DeferredInitError
 from veles_tpu.ops import functional as F
-from veles_tpu.ops.attention import mha_forward, init_mha_params
+from veles_tpu import model_config
+from veles_tpu.ops.attention import (cfg_matmul, init_mha_params,
+                                     mha_forward, rms_norm)
 from veles_tpu.ops.decision import DecisionBase
 
 
@@ -88,73 +90,137 @@ def _layernorm(x, g, b, eps=1e-5):
     return (x - mean) / jnp.sqrt(var + eps) * g + b
 
 
+def _wire(blk, h, cfg, layer, attend, with_aux=False, token_mask=None):
+    """One decoder block around ``attend(attention params, normed input)
+    -> (attention output, state)`` — THE wiring every path shares (whole
+    forward, contiguous and paged decode, pipeline stages), so training
+    and serving can never drift on it.  ``pre_ln``: LayerNorm, attention,
+    residual; LayerNorm, feed forward, residual.  ``sandwich``: RMSNorm
+    before AND after each half, the second norm inside the residual.
+    Returns (h, state, extra) with ``extra`` the expert layer's load-
+    balancing loss (``with_aux``, pre_ln) or its counts (sandwich; None
+    for a dense layer)."""
+    if cfg.block == "sandwich":
+        # the residual stream ``h`` is float32 whatever the model's dtype
+        # (``_scaled_embed``); what a sublayer reads and returns is the
+        # model's.  A router's near-ties amplify every rounding of its
+        # input, so it reads the normed stream before that is rounded
+        # (``normed``: float32).
+        out, state = attend(blk["attn"], rms_norm(h, blk["ln_in"], cfg.eps,
+                                                  cfg.dtype))
+        h = h + rms_norm(out, blk["ln_post_attn"], cfg.eps, h.dtype)
+        normed = rms_norm(h, blk["ln_pre_mlp"], cfg.eps)
+        ff, stats = _block_ffn(blk, normed.astype(cfg.dtype), cfg, layer,
+                               normed)
+        return (h + rms_norm(ff, blk["ln_post_mlp"], cfg.eps, h.dtype),
+                state, stats)
+    hn = _layernorm(h, blk["ln1"]["g"], blk["ln1"]["b"])
+    out, state = attend(blk["attn"], hn)
+    h = h + out
+    hn = _layernorm(h, blk["ln2"]["g"], blk["ln2"]["b"])
+    if "moe" in blk and with_aux:
+        from veles_tpu.ops.moe import moe_ffn
+        ff, aux = moe_ffn(blk["moe"], hn, return_aux=True,
+                          token_mask=token_mask)
+        return h + ff, state, aux
+    return h + _block_ffn(blk, hn, cfg, layer)[0], state, 0.0
+
+
 def block_forward(blk, h, n_heads, block_size=None, attn_fn=None,
                   with_aux=False, token_mask=None, rope=False,
-                  window=None, sinks=0):
-    """One decoder block (pre-LN attention + FFN with residuals) — shared
-    by the sequential forward and the pipeline-parallel stage runner
-    (veles_tpu.parallel.pipeline).  A block carrying ``moe`` params uses
-    the routed expert FFN in place of the dense one; ``with_aux=True``
-    returns (h, moe_load_balancing_loss) (0 for dense blocks;
-    ``token_mask`` keeps padded rows out of the router statistics)."""
-    hn = _layernorm(h, blk["ln1"]["g"], blk["ln1"]["b"])
+                  window=None, sinks=0, layer=0):
+    """One decoder block over a whole sequence — shared by the sequential
+    forward and the pipeline-parallel stage runner
+    (veles_tpu.parallel.pipeline).  ``n_heads`` is a head count with the
+    classic keywords beside it, or the model's record
+    (``model_config.ModelConfig``; ``layer`` then picks the layer's
+    kinds).  A block carrying ``moe`` params uses the routed expert FFN in
+    place of the dense one; ``with_aux=True`` returns (h,
+    moe_load_balancing_loss) (0 for dense blocks; ``token_mask`` keeps
+    padded rows out of the router statistics)."""
+    cfg = model_config.of(n_heads, rope, window, sinks)
     if attn_fn is not None:    # injected attention (ring SP)
-        if rope or window or sinks:
+        if cfg.rope or cfg.window or cfg.sinks \
+                or cfg.attn_kinds is not None:
             # the injected path never rotates q/k or masks the window —
             # running a RoPE model through it would silently drop ALL
             # positional signal (rope params have no pos table)
             raise ValueError("rope/window are not supported with an "
                              "injected attn_fn (ring attention)")
-        h = h + attn_fn(blk["attn"], hn)
+
+        def attend(p, hn):
+            return attn_fn(p, hn), None
     else:
-        h = h + mha_forward(blk["attn"], hn, n_heads, causal=True,
-                            block_size=block_size, rope=rope,
-                            window=window, sinks=sinks)
-    hn = _layernorm(h, blk["ln2"]["g"], blk["ln2"]["b"])
-    if "moe" in blk and with_aux:
-        from veles_tpu.ops.moe import moe_ffn
-        out, aux = moe_ffn(blk["moe"], hn, return_aux=True,
-                           token_mask=token_mask)
-        return h + out, aux
-    h = h + _block_ffn(blk, hn)
-    return (h, 0.0) if with_aux else h
+        def attend(p, hn):
+            return mha_forward(p, hn, cfg, causal=True,
+                               block_size=block_size, layer=layer), None
+    h, _, aux = _wire(blk, h, cfg, layer, attend, with_aux, token_mask)
+    return (h, aux) if with_aux else h
 
 
-def _block_ffn(blk, hn):
-    """The FFN half of a block (dense or routed-MoE), shared by the
-    training forward and the KV-cached decode step."""
+def _block_ffn(blk, hn, cfg, layer, router_in=None):
+    """The feed-forward half of a block, shared by the training forward
+    and the KV-cached decode step: (output, the expert layer's counts or
+    None).  ``pre_ln``: dense ReLU with biases, or the top-1 expert layer
+    of a tree that carries ``moe``.  ``sandwich``: gated SiLU, dense or
+    routed beside a shared expert, as the record says for the layer
+    (``router_in``: the float32 input the router scores, where it is not
+    ``hn`` itself)."""
     import jax.numpy as jnp
+    if cfg.block == "sandwich":
+        from veles_tpu.ops.moe import gated_ffn, routed_ffn
+        mm = lambda a, b: cfg_matmul(cfg, a, b)  # noqa: E731
+        if cfg.ffn_kind(layer, blk) == model_config.MOE:
+            return routed_ffn(blk["moe"], hn, cfg.moe, mm, router_in)
+        return gated_ffn(blk, hn, mm), None
     if "moe" in blk:
         from veles_tpu.ops.moe import moe_ffn
-        return moe_ffn(blk["moe"], hn)
+        return moe_ffn(blk["moe"], hn), None
     ff = jnp.maximum(F.matmul(hn, blk["w1"]) + blk["b1"], 0.0)
-    return F.matmul(ff, blk["w2"]) + blk["b2"]
+    return F.matmul(ff, blk["w2"]) + blk["b2"], None
 
 
-def embed_tokens(params, tokens):
+def _scaled_embed(params, tokens, cfg):
+    """Token rows; the ``sandwich`` block scales them by sqrt(d_model)
+    and carries them, the residual stream, in float32."""
+    import jax.numpy as jnp
+    h = jnp.take(params["embed"], tokens, axis=0)
+    scale = cfg.embed_scale(h.shape[-1]) if cfg is not None else None
+    if scale is None:
+        return h
+    return h.astype(jnp.float32) * scale
+
+
+def embed_tokens(params, tokens, cfg=None):
     """Token (+ learned positional, absent under RoPE) embedding — the
     pre-block-stack half, shared by the sequential forward and the
     pipeline-parallel path."""
-    import jax.numpy as jnp
     s = tokens.shape[1]
-    h = jnp.take(params["embed"], tokens, axis=0)
+    h = _scaled_embed(params, tokens, cfg)
     if "pos" in params:
         h = h + params["pos"][:s]
     return h
 
 
-def head_logits(params, h):
-    """Final LN + tied output head over block-stack activations."""
+def head_logits(params, h, cfg=None):
+    """Final norm and output head over block-stack activations: LayerNorm
+    and the tied head, or (``sandwich``) RMSNorm and the tree's own
+    ``head``, the logits in float32."""
+    import jax.numpy as jnp
+    if cfg is not None and cfg.block == "sandwich":
+        h = rms_norm(h, params["ln_f"], cfg.eps, cfg.dtype)
+        return jnp.matmul(h, params["head"],
+                          preferred_element_type=jnp.float32)
     h = _layernorm(h, params["ln_f"]["g"], params["ln_f"]["b"])
     return F.matmul(h, params["embed"].T)
 
 
-def nll_from_hidden(params, h, targets, mask):
+def nll_from_hidden(params, h, targets, mask, cfg=None):
     """Masked mean next-token cross-entropy from block-stack activations —
     the post-block half shared by lm_loss and pipeline_lm_loss."""
     import jax
     import jax.numpy as jnp
-    logp = jax.nn.log_softmax(head_logits(params, h), axis=-1)
+    logp = jax.nn.log_softmax(head_logits(params, h, cfg), axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     m = mask[:, None]
     denom = jnp.maximum(m.sum() * nll.shape[1], 1.0)
@@ -165,11 +231,11 @@ def transformer_forward(params, tokens, n_heads, block_size=None,
                         attn_fn=None, rope=False, window=None, sinks=0):
     """Logits (batch, seq, vocab); ``attn_fn(q_input)`` optionally replaces
     the attention call (ring attention injection point)."""
-    h = embed_tokens(params, tokens)
-    for blk in params["blocks"]:
-        h = block_forward(blk, h, n_heads, block_size, attn_fn,
-                          rope=rope, window=window, sinks=sinks)
-    return head_logits(params, h)
+    cfg = model_config.of(n_heads, rope, window, sinks)
+    h = embed_tokens(params, tokens, cfg)
+    for i, blk in enumerate(params["blocks"]):
+        h = block_forward(blk, h, cfg, block_size, attn_fn, layer=i)
+    return head_logits(params, h, cfg)
 
 
 def lm_loss(params, tokens, mask, n_heads, block_size=None,
@@ -189,7 +255,8 @@ def lm_loss(params, tokens, mask, n_heads, block_size=None,
     design note)."""
     import jax
     import jax.numpy as jnp
-    h = embed_tokens(params, tokens[:, :-1])
+    cfg = model_config.of(n_heads, rope, window, sinks)
+    h = embed_tokens(params, tokens[:, :-1], cfg)
     token_mask = jnp.broadcast_to(
         mask[:, None], (h.shape[0], h.shape[1])).reshape(-1)
     aux_total, n_moe = 0.0, 0
@@ -197,19 +264,17 @@ def lm_loss(params, tokens, mask, n_heads, block_size=None,
     def wrap(fn):
         return jax.checkpoint(fn) if remat else fn
 
-    for blk in params["blocks"]:
-        if moe_aux_coef and "moe" in blk:
-            h, aux = wrap(lambda b, x: block_forward(
-                b, x, n_heads, block_size, with_aux=True,
-                token_mask=token_mask, rope=rope, window=window,
-                sinks=sinks))(blk, h)
+    for i, blk in enumerate(params["blocks"]):
+        if moe_aux_coef and "moe" in blk and cfg.block == "pre_ln":
+            h, aux = wrap(lambda b, x, i=i: block_forward(
+                b, x, cfg, block_size, with_aux=True,
+                token_mask=token_mask, layer=i))(blk, h)
             aux_total = aux_total + aux
             n_moe += 1
         else:
-            h = wrap(lambda b, x: block_forward(
-                b, x, n_heads, block_size, rope=rope, window=window,
-                sinks=sinks))(blk, h)
-    loss = nll_from_hidden(params, h, tokens[:, 1:], mask)
+            h = wrap(lambda b, x, i=i: block_forward(
+                b, x, cfg, block_size, layer=i))(blk, h)
+    loss = nll_from_hidden(params, h, tokens[:, 1:], mask, cfg)
     if n_moe:
         loss = loss + moe_aux_coef * aux_total / n_moe
     return loss
@@ -230,59 +295,57 @@ def prefill(params, tokens, n_heads, max_len, rope=False, window=None,
     serving can never drift on block wiring.
     """
     import jax.numpy as jnp
-    h = embed_tokens(params, tokens)
+    cfg = model_config.of(n_heads, rope, window, sinks)
+    h = embed_tokens(params, tokens, cfg)
     s = h.shape[1]
     pad = [(0, 0), (0, 0), (0, max_len - s), (0, 0)]
     caches = []
-    for blk in params["blocks"]:
-        captured = {}
+    for i, blk in enumerate(params["blocks"]):
+        def attend(p, hn, i=i):
+            out, k, v = mha_forward(p, hn, cfg, causal=True,
+                                    return_kv=True, layer=i)
+            return out, (k, v)
 
-        def attn_capture(p, hn, captured=captured):
-            out, k, v = mha_forward(p, hn, n_heads, causal=True,
-                                    return_kv=True, rope=rope,
-                                    window=window, sinks=sinks)
-            captured["kv"] = (k, v)
-            return out
-
-        h = block_forward(blk, h, n_heads, attn_fn=attn_capture)
-        k, v = captured["kv"]
+        h, (k, v), _ = _wire(blk, h, cfg, i, attend)
         caches.append((jnp.pad(k, pad), jnp.pad(v, pad)))
     return h, caches
 
 
+def _cached_block(step, blk, h, k_cache, v_cache, cfg, layer, **kw):
+    """:func:`_wire` around one of the cached attention steps of
+    ``ops/attention.py`` (``step(attention params, normed, k, v, ...) ->
+    (out, k, v)``): (h, k, v, the expert layer's counts or None)."""
+    def attend(p, hn):
+        out, k, v = step(p, hn, k_cache, v_cache, layer=layer, **kw)
+        return out, (k, v)
+
+    h, (k, v), stats = _wire(blk, h, cfg, layer, attend)
+    return h, k, v, stats
+
+
 def block_decode_step(blk, h, k_cache, v_cache, pos, n_heads,
-                      rope=False, window=None, sinks=0):
+                      rope=False, window=None, sinks=0, layer=0):
     """One block over ONE position against its KV cache (decode path)."""
     from veles_tpu.ops.attention import mha_decode_step
-    hn = _layernorm(h, blk["ln1"]["g"], blk["ln1"]["b"])
-    attn, k_cache, v_cache = mha_decode_step(blk["attn"], hn, k_cache,
-                                             v_cache, pos, n_heads,
-                                             rope=rope, window=window,
-                                             sinks=sinks)
-    h = h + attn
-    hn = _layernorm(h, blk["ln2"]["g"], blk["ln2"]["b"])
-    return h + _block_ffn(blk, hn), k_cache, v_cache
+    cfg = model_config.of(n_heads, rope, window, sinks)
+    return _cached_block(mha_decode_step, blk, h, k_cache, v_cache, cfg,
+                         layer, pos=pos, n_heads=cfg)[:3]
 
 
 def block_chunk_step(blk, h, k_cache, v_cache, pos, n_heads,
-                     rope=False, window=None, sinks=0):
+                     rope=False, window=None, sinks=0, layer=0):
     """One block over ``c`` consecutive positions against its KV cache —
     the multi-token sibling of :func:`block_decode_step` (same wiring,
     ``attention.mha_chunk_step`` core).  Serves chunked prefill and
     speculative-draft verification; at c=1 it computes exactly what
     ``block_decode_step`` computes."""
     from veles_tpu.ops.attention import mha_chunk_step
-    hn = _layernorm(h, blk["ln1"]["g"], blk["ln1"]["b"])
-    attn, k_cache, v_cache = mha_chunk_step(blk["attn"], hn, k_cache,
-                                            v_cache, pos, n_heads,
-                                            rope=rope, window=window,
-                                            sinks=sinks)
-    h = h + attn
-    hn = _layernorm(h, blk["ln2"]["g"], blk["ln2"]["b"])
-    return h + _block_ffn(blk, hn), k_cache, v_cache
+    cfg = model_config.of(n_heads, rope, window, sinks)
+    return _cached_block(mha_chunk_step, blk, h, k_cache, v_cache, cfg,
+                         layer, pos=pos, n_heads=cfg)[:3]
 
 
-def chunk_embed(params, tokens, pos):
+def chunk_embed(params, tokens, pos, cfg=None):
     """Token (+ positional at [pos, pos+c), absent under RoPE) embedding
     for a mid-sequence chunk — :func:`embed_tokens` generalized to a
     traced start position (the chunked-prefill / speculative entry
@@ -290,7 +353,7 @@ def chunk_embed(params, tokens, pos):
     import jax
     import jax.numpy as jnp
     c = tokens.shape[1]
-    h = jnp.take(params["embed"], tokens, axis=0)
+    h = _scaled_embed(params, tokens, cfg)
     if "pos" in params:
         h = h + jax.lax.dynamic_slice_in_dim(params["pos"], pos, c,
                                              axis=0)[None]
@@ -307,19 +370,19 @@ def chunk_apply(params, tokens, caches, pos, n_heads, rope=False,
     (c = 1 + draft length).  Position j's hidden state equals the full
     ``prefill`` / step-by-step decode result for the same tokens, so
     everything downstream stays bit-identical to ``generate``."""
-    h = chunk_embed(params, tokens, pos)
+    cfg = model_config.of(n_heads, rope, window, sinks)
+    h = chunk_embed(params, tokens, pos, cfg)
     new_caches = []
-    for blk, (kc, vc) in zip(params["blocks"], caches):
-        h, kc, vc = block_chunk_step(blk, h, kc, vc, pos, n_heads,
-                                     rope=rope, window=window,
-                                     sinks=sinks)
+    for i, (blk, (kc, vc)) in enumerate(zip(params["blocks"], caches)):
+        h, kc, vc = block_chunk_step(blk, h, kc, vc, pos, cfg, layer=i)
         new_caches.append((kc, vc))
     return h, new_caches
 
 
 def block_paged_chunk_step(blk, h, k_pool, v_pool, ptab, pos, n_heads,
                            rope=False, window=None, sinks=0,
-                           attn_kernel=None, write_mask=None):
+                           attn_kernel=None, write_mask=None, layer=0,
+                           base=None):
     """One block over ``c`` positions per lane against the PAGED KV
     pool — :func:`block_chunk_step` with storage indirected through a
     per-lane page table (``attention.mha_paged_chunk_step`` core), and
@@ -329,19 +392,17 @@ def block_paged_chunk_step(blk, h, k_pool, v_pool, ptab, pos, n_heads,
     Pallas serving kernels (ISSUE 7); ``write_mask`` (traced (b,)
     bool; ISSUE 13) diverts masked lanes' K/V writes to the scratch
     page — the megastep's early-exit lanes stay in the program without
-    being able to touch an allocated page."""
+    being able to touch an allocated page.  Returns (h, k_pool, v_pool,
+    the expert layer's counts or None)."""
     from veles_tpu.ops.attention import mha_paged_chunk_step
-    hn = _layernorm(h, blk["ln1"]["g"], blk["ln1"]["b"])
-    attn, k_pool, v_pool = mha_paged_chunk_step(
-        blk["attn"], hn, k_pool, v_pool, ptab, pos, n_heads, rope=rope,
-        window=window, sinks=sinks, attn_kernel=attn_kernel,
-        write_mask=write_mask)
-    h = h + attn
-    hn = _layernorm(h, blk["ln2"]["g"], blk["ln2"]["b"])
-    return h + _block_ffn(blk, hn), k_pool, v_pool
+    cfg = model_config.of(n_heads, rope, window, sinks)
+    return _cached_block(
+        mha_paged_chunk_step, blk, h, k_pool, v_pool, cfg, layer,
+        ptab=ptab, pos=pos, n_heads=cfg, attn_kernel=attn_kernel,
+        write_mask=write_mask, base=base)
 
 
-def paged_chunk_embed(params, tokens, pos):
+def paged_chunk_embed(params, tokens, pos, cfg=None):
     """Token (+ positional, absent under RoPE) embedding for ``c``
     positions per lane starting at PER-LANE traced ``pos`` (b,) —
     :func:`chunk_embed` generalized to the batched paged step, where
@@ -350,7 +411,7 @@ def paged_chunk_embed(params, tokens, pos):
     exceed it, and their outputs are never read)."""
     import jax.numpy as jnp
     c = tokens.shape[1]
-    h = jnp.take(params["embed"], tokens, axis=0)
+    h = _scaled_embed(params, tokens, cfg)
     if "pos" in params:
         idx = jnp.asarray(pos)[:, None] + jnp.arange(c)      # (b, c)
         h = h + jnp.take(params["pos"], idx, axis=0)
@@ -359,7 +420,8 @@ def paged_chunk_embed(params, tokens, pos):
 
 def paged_chunk_apply(params, tokens, pools, ptab, pos, n_heads,
                       rope=False, window=None, sinks=0,
-                      attn_kernel=None, write_mask=None):
+                      attn_kernel=None, write_mask=None, base=None,
+                      with_stats=False):
     """Run ``c`` consecutive tokens PER LANE through the whole stack
     against the paged KV pools in one pass — :func:`chunk_apply` with
     (pools, page table) in place of per-lane contiguous caches.
@@ -376,17 +438,39 @@ def paged_chunk_apply(params, tokens, pools, ptab, pos, n_heads,
     serving kernel path (ISSUE 7) — same K/V writes, no materialized
     ``paged_view`` gather.  ``write_mask`` (traced (b,) bool; ISSUE
     13) redirects masked lanes' K/V writes to the scratch page — see
-    :func:`~veles_tpu.ops.attention.paged_write`."""
-    h = paged_chunk_embed(params, tokens, pos)
+    :func:`~veles_tpu.ops.attention.paged_write`.
+
+    A stack with two kinds of cache takes ``ptab`` and
+    ``base`` as dicts by kind (``model_config.FULL`` / ``SLIDING``): each
+    layer walks its own kind's table, a sliding layer's beginning at the
+    lane's first live page (``base``, see ``mha_paged_chunk_step``).
+    ``with_stats`` also returns the expert layers' counts, int32 ``[held,
+    elsewhere, experts hit, largest load]`` (``ops/moe.py::held_part``),
+    summed over the layers (the last: their maximum)."""
+    import jax.numpy as jnp
+    cfg = model_config.of(n_heads, rope, window, sinks)
+    h = paged_chunk_embed(params, tokens, pos, cfg)
+    by_kind = isinstance(ptab, dict)
     new_pools = []
-    for blk, (kp, vp) in zip(params["blocks"], pools):
-        h, kp, vp = block_paged_chunk_step(blk, h, kp, vp, ptab, pos,
-                                           n_heads, rope=rope,
-                                           window=window, sinks=sinks,
-                                           attn_kernel=attn_kernel,
-                                           write_mask=write_mask)
+    counts = []
+    for i, (blk, (kp, vp)) in enumerate(zip(params["blocks"], pools)):
+        kind = cfg.kind(i)
+        h, kp, vp, stats = block_paged_chunk_step(
+            blk, h, kp, vp, ptab[kind] if by_kind else ptab, pos, cfg,
+            attn_kernel=attn_kernel, write_mask=write_mask, layer=i,
+            base=base[kind] if by_kind else base)
         new_pools.append((kp, vp))
-    return h, new_pools
+        if stats is not None:
+            counts.append(stats)
+    if not with_stats:
+        return h, new_pools
+    if not counts:
+        return h, new_pools, jnp.zeros(4, jnp.int32)
+    counts = jnp.stack(counts)
+    # assignments held, elsewhere and experts hit add up over the expert
+    # layers; the largest expert load is the largest of any layer
+    return h, new_pools, jnp.concatenate(
+        [counts[:, :3].sum(0), counts[:, 3:].max(0)])
 
 
 def propose_draft_in_graph(hist, hlen, k, max_ngram=3):
@@ -537,12 +621,10 @@ def sample_token(key, logits, temperature, top_k=0):
 
 
 def _generate_impl(params, prompt, rng, temperature, true_len, n_new,
-                   n_heads, greedy, max_len, top_k, rope, window,
-                   sinks):
+                   cfg, greedy, max_len, top_k):
     import jax
     import jax.numpy as jnp
-    h, caches = prefill(params, prompt, n_heads, max_len, rope=rope,
-                        window=window, sinks=sinks)
+    h, caches = prefill(params, prompt, cfg, max_len)
     # ``true_len`` is TRACED: the prompt may be right-padded to a bucket
     # length so servers compile one program per bucket, not per exact
     # prompt length.  Under causal attention every position < true_len is
@@ -550,7 +632,7 @@ def _generate_impl(params, prompt, rng, temperature, true_len, n_new,
     # cache from position true_len on, and mha_decode_step masks cache
     # positions > pos — so bucketing is bit-exact, not approximate.
     logits = head_logits(params, jax.lax.dynamic_slice_in_dim(
-        h, true_len - 1, 1, axis=1))[:, 0, :]
+        h, true_len - 1, 1, axis=1), cfg)[:, 0, :]
     sample, next_key = _make_sampler(greedy, top_k, temperature)
 
     # the final sampled token never feeds the stack again, so the scan
@@ -561,17 +643,14 @@ def _generate_impl(params, prompt, rng, temperature, true_len, n_new,
         key, sub = next_key(key)
         tok = sample(logits, sub)
         pos = true_len + i
-        x = jnp.take(params["embed"], tok, axis=0)[:, None, :]
-        if "pos" in params:
-            x = x + jax.lax.dynamic_slice_in_dim(params["pos"], pos, 1,
-                                                 axis=0)[None]
+        x = chunk_embed(params, tok[:, None], pos, cfg)
         new_caches = []
-        for blk, (kc, vc) in zip(params["blocks"], caches):
-            x, kc, vc = block_decode_step(blk, x, kc, vc, pos, n_heads,
-                                          rope=rope, window=window,
-                                          sinks=sinks)
+        for i, (blk, (kc, vc)) in enumerate(zip(params["blocks"],
+                                                caches)):
+            x, kc, vc = block_decode_step(blk, x, kc, vc, pos, cfg,
+                                          layer=i)
             new_caches.append((kc, vc))
-        logits = head_logits(params, x)[:, 0, :]
+        logits = head_logits(params, x, cfg)[:, 0, :]
         return (new_caches, logits, key), tok
 
     key0 = None if greedy else rng
@@ -583,7 +662,7 @@ def _generate_impl(params, prompt, rng, temperature, true_len, n_new,
     return jnp.concatenate([prompt, toks.astype(jnp.int32)], axis=1)
 
 
-#: cached jit of _generate_impl (n_new/n_heads/greedy/max_len static,
+#: cached jit of _generate_impl (n_new/cfg/greedy/max_len static,
 #: temperature TRACED) — a fresh jax.jit wrapper per call would retrace
 #: every time
 _GENERATE_JIT = None
@@ -598,6 +677,8 @@ def generate(params, prompt, n_new, n_heads, rng=None, temperature=1.0,
     """Autoregressive sampling with a KV cache, fully under jit.
 
     prompt: (batch, s) int32; returns (batch, s + n_new) int32.
+    ``n_heads`` is a head count with the classic keywords beside it, or
+    the model's record (``model_config.ModelConfig``).
     One prefill pass captures the prompt's K/V; each new token then
     attends against the fixed-shape cache via ``dynamic_update_slice``
     (O(seq) per token instead of O(seq²) full recompute — the TPU
@@ -642,14 +723,15 @@ def generate(params, prompt, n_new, n_heads, rng=None, temperature=1.0,
     if _GENERATE_JIT is None:
         _GENERATE_JIT = jax.jit(
             _generate_impl,
-            static_argnames=("n_new", "n_heads", "greedy", "max_len",
-                             "top_k", "rope", "window", "sinks"))
+            static_argnames=("n_new", "cfg", "greedy", "max_len",
+                             "top_k"))
     return _GENERATE_JIT(params, prompt, None if greedy else rng,
                          jnp.asarray(temperature or 1.0, jnp.float32),
                          jnp.asarray(start, jnp.int32),
-                         n_new=n_new, n_heads=n_heads, greedy=greedy,
-                         max_len=max_len, rope=rope, window=window,
-                         sinks=sinks,
+                         n_new=n_new,
+                         cfg=model_config.of(n_heads, rope, window,
+                                             sinks),
+                         greedy=greedy, max_len=max_len,
                          # greedy never reads top_k — null it so distinct
                          # values cannot fork identical compiles
                          top_k=None if greedy else top_k)
@@ -666,12 +748,14 @@ def block_decode_step_rolling(blk, h, k_cache, v_cache, slot, live, pos,
     rolling sibling of :func:`block_decode_step` (same wiring, the
     precomputed slot/live from attention.rolling_slot_update)."""
     from veles_tpu.ops.attention import mha_decode_step_rolling
-    hn = _layernorm(h, blk["ln1"]["g"], blk["ln1"]["b"])
-    attn, k_cache, v_cache = mha_decode_step_rolling(
-        blk["attn"], hn, k_cache, v_cache, slot, live, pos, n_heads)
-    h = h + attn
-    hn = _layernorm(h, blk["ln2"]["g"], blk["ln2"]["b"])
-    return h + _block_ffn(blk, hn), k_cache, v_cache
+
+    def attend(p, hn):
+        out, k, v = mha_decode_step_rolling(p, hn, k_cache, v_cache, slot,
+                                            live, pos, n_heads)
+        return out, (k, v)
+
+    h, (k, v), _ = _wire(blk, h, model_config.classic(n_heads), 0, attend)
+    return h, k, v
 
 
 def _generate_rolling_impl(params, prompt, rng, temperature, n_new,
@@ -796,15 +880,10 @@ def trainer_sample_tokens(trainer, prompt, n_new=32, temperature=0.0,
     rng = jax.random.PRNGKey(seed) if temperature else None
     return numpy.asarray(generate(params,
                                   jnp.asarray(prompt, jnp.int32),
-                                  n_new, trainer.n_heads, rng=rng,
+                                  n_new, trainer.model_config, rng=rng,
                                   temperature=temperature,
                                   max_len=max_len, top_k=top_k,
-                                  true_len=true_len,
-                                  rope=getattr(trainer, "rope", False),
-                                  window=getattr(trainer, "window",
-                                                 None),
-                                  sinks=getattr(trainer, "attn_sinks",
-                                                0)))
+                                  true_len=true_len))
 
 
 def make_adam_train_step(loss_fn, learning_rate, beta1=0.9, beta2=0.999,
@@ -846,10 +925,25 @@ class TransformerTrainer(AcceleratedUnit):
                  block_size=None, beta1=0.9, beta2=0.999, eps=1e-8,
                  n_experts=0, moe_aux_coef=1e-2, pipeline_stages=0,
                  pipeline_microbatches=4, remat=False, n_kv_heads=None,
-                 rope=False, window=None, attn_sinks=0, **kwargs):
+                 rope=False, window=None, attn_sinks=0, config=None,
+                 **kwargs):
         super().__init__(workflow, **kwargs)
         self.vocab = vocab
         self.d_model = d_model
+        #: the model's record (model_config.py): given whole
+        #: (``config``), or made from the classic keywords — what
+        #: ``serve_lm`` and the loss read
+        self.model_config = model_config.of(
+            config if config is not None else n_heads, rope, window,
+            attn_sinks)
+        if config is not None:
+            n_heads, n_kv_heads = config.n_heads, config.n_kv_heads
+            rope, window = config.rope, config.window
+            if config.block != "pre_ln" and pipeline_stages > 0:
+                raise ValueError(
+                    "the pipeline stage scan (parallel/pipeline.py) runs "
+                    "the pre_ln block only: a %r model trains on the "
+                    "sequential path (pipeline_stages=0)" % config.block)
         self.n_heads = n_heads
         #: grouped-query attention: kv heads < query heads shrink the
         #: KV projections AND the decode cache by the group factor
@@ -962,9 +1056,8 @@ class TransformerTrainer(AcceleratedUnit):
         coef = (self.moe_aux_coef
                 if training and self.n_experts > 0 else 0.0)
         return lambda params, tokens, mask: lm_loss(
-            params, tokens, mask, self.n_heads, self.block_size,
-            moe_aux_coef=coef, remat=self.remat, rope=self.rope,
-            window=self.window, sinks=self.attn_sinks)
+            params, tokens, mask, self.model_config, self.block_size,
+            moe_aux_coef=coef, remat=self.remat)
 
     def initialize(self, device=None, **kwargs):
         import jax
@@ -982,6 +1075,11 @@ class TransformerTrainer(AcceleratedUnit):
                 "root.<name>.trainer.vocab to cover the data source"
                 % (loader_vocab, self.vocab))
         if self.params is None:
+            if self.model_config.block != "pre_ln":
+                raise ValueError(
+                    "init_transformer_params makes pre_ln trees only: a "
+                    "%r trainer is given its params"
+                    % self.model_config.block)
             host = init_transformer_params(
                 prng_mod.get("init"), self.vocab, self.d_model,
                 self.n_heads, self.n_layers, max_len=self.max_len,

@@ -740,10 +740,8 @@ def serve_lm(workflow, host="127.0.0.1", port=8180, max_new=256,
                 label = {"replica": str(i)}
                 eng_name = "lm_r%d" % i
             return LMEngine(
-                params, n_heads=trainer.n_heads, max_len=cache_len,
-                slots=slots, rope=getattr(trainer, "rope", False),
-                window=getattr(trainer, "window", None),
-                sinks=getattr(trainer, "attn_sinks", 0),
+                params, n_heads=trainer.model_config, max_len=cache_len,
+                slots=slots,
                 queue_depth=queue_depth, deadline_s=deadline_s,
                 prefix_cache=prefix_cache, prefill_chunk=prefill_chunk,
                 spec_k=spec_k, queue_tokens=queue_tokens,

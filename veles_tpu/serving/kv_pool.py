@@ -36,6 +36,8 @@ from __future__ import annotations
 
 import collections
 
+import numpy
+
 
 class KVPagePool:
     """Allocator over page ids ``1..num_pages`` (id 0 is the reserved
@@ -181,3 +183,140 @@ class KVPagePool:
 
     def pinned(self, page):
         return self._pins[page] > 0
+
+
+class WindowTables:
+    """Per-lane page tables of the SLIDING kind of layer: a lane holds only
+    the pages its window still reaches.
+
+    A full layer's table maps linear page ``j`` at entry ``j`` and keeps every
+    page until the request ends.  A sliding layer's keys stop being read once
+    they are ``window`` or more behind the lane's position, so its table is a
+    short row that SLIDES: entry ``j`` maps linear page ``base + j``, a page is
+    released when its last token has left the window of the lane's next query
+    (prefill chunks included), and the page the frontier enters is taken then.
+    The device programs take the row and ``base * page`` and work on positions
+    less that base (``ops/attention.py::mha_paged_chunk_step``).
+
+    Admission COMMITS a lane's largest holding (``min(pages of its span,
+    width)``) against the pool, so taking a page on the way can never fail
+    and decode can never deadlock on pages; ``width`` is the window's pages,
+    the frontier's page, and one taken before the oldest is released.
+    Host-side integers only, owned by the engine's worker thread like
+    :class:`KVPagePool`."""
+
+    _synchronized_externally = "LMEngine worker thread (single owner)"
+
+    def __init__(self, pool, slots, window):
+        self.pool = pool
+        self.page = pool.page_size
+        self.window = int(window)
+        self.width = -(-self.window // self.page) + 2
+        self.tables = numpy.zeros((slots, self.width), numpy.int32)
+        #: linear page index of each row's entry 0, and live entries
+        self.base = numpy.zeros(slots, numpy.int32)
+        self.count = numpy.zeros(slots, numpy.int32)
+        self._commit = [0] * slots
+        self.committed = 0
+        #: pages released because they left the window (not at finish)
+        self.released = 0
+
+    def holding(self, pages):
+        """The most pages a request of ``pages`` pages of span ever holds."""
+        return min(int(pages), self.width)
+
+    def can_admit(self, pages):
+        return self.committed + self.holding(pages) <= self.pool.num_pages
+
+    def admit(self, slot, pages):
+        if self._commit[slot] or self.count[slot]:
+            raise RuntimeError("slot %d admitted twice" % slot)
+        self._commit[slot] = self.holding(pages)
+        self.committed += self._commit[slot]
+
+    def first_live(self, pos):
+        """Linear index of the oldest page a query at ``pos`` still reads."""
+        return max(0, (int(pos) - self.window + 1) // self.page)
+
+    def advance(self, slot, lo, hi):
+        """Before the lane writes (and reads from) positions [lo, hi):
+        release the pages wholly behind the window of a query at ``lo``,
+        take the pages up to the one ``hi - 1`` lies in.  Returns the number
+        released."""
+        row, page = self.tables[slot], self.page
+        gone = min(self.first_live(lo) - int(self.base[slot]),
+                   int(self.count[slot]))
+        if gone > 0:
+            for p in row[:gone].tolist():
+                self.pool.release(p)
+            n = int(self.count[slot]) - gone
+            row[:n] = row[gone:gone + n]
+            row[n:] = KVPagePool.SCRATCH
+            self.count[slot] = n
+            self.base[slot] += gone
+            self.released += gone
+        if not self.count[slot]:
+            # nothing held: the row begins at the first page still read
+            self.base[slot] = self.first_live(lo)
+        want = (int(hi) - 1) // page - int(self.base[slot]) + 1
+        have = int(self.count[slot])
+        if want > have:
+            if want > self._commit[slot]:
+                raise RuntimeError(
+                    "slot %d needs %d window pages, committed %d"
+                    % (slot, want, self._commit[slot]))
+            fresh = self.pool.alloc(want - have)
+            if fresh is None:
+                raise RuntimeError("window pool exhausted under its own "
+                                   "commitments")
+            row[have:want] = fresh
+            self.count[slot] = want
+        return max(gone, 0)
+
+    def due(self, pos):
+        """Bool per slot: a query at ``pos[slot]`` needs :meth:`advance`
+        (its page is not held yet, or the oldest held has left the
+        window)."""
+        first = numpy.maximum(0, (pos - self.window + 1) // self.page)
+        return (pos // self.page >= self.base + self.count) \
+            | ((first > self.base) & (self.count > 0))
+
+    def vacate(self, slot):
+        n = int(self.count[slot])
+        for p in self.tables[slot, :n].tolist():
+            self.pool.release(p)
+        self.tables[slot, :] = KVPagePool.SCRATCH
+        self.base[slot] = 0
+        self.count[slot] = 0
+        self.committed -= self._commit[slot]
+        self._commit[slot] = 0
+
+    def verify(self):
+        """The pool's own audit, then: every held page has exactly one
+        referent (its lane), no lane holds more than ``width`` or than it
+        committed, and the commitments add up.  Raises RuntimeError; returns
+        a summary when sound."""
+        self.pool.verify()
+        held = []
+        for slot in range(len(self.count)):
+            n = int(self.count[slot])
+            if n > self.width or n > self._commit[slot]:
+                raise RuntimeError(
+                    "slot %d holds %d window pages (width %d, committed "
+                    "%d)" % (slot, n, self.width, self._commit[slot]))
+            held.extend(self.tables[slot, :n].tolist())
+            if (self.tables[slot, n:] != KVPagePool.SCRATCH).any():
+                raise RuntimeError("slot %d: entries past its %d live "
+                                   "pages are not scratch" % (slot, n))
+        if len(set(held)) != len(held) or KVPagePool.SCRATCH in held:
+            raise RuntimeError("a window page is held twice, or scratch "
+                               "is held")
+        if len(held) != self.pool.used_pages:
+            raise RuntimeError("lanes hold %d window pages, the pool says "
+                               "%d are used" % (len(held),
+                                                self.pool.used_pages))
+        if self.committed != sum(self._commit) \
+                or self.committed > self.pool.num_pages:
+            raise RuntimeError("window commitments do not add up")
+        return {"held": len(held), "committed": self.committed,
+                "released": self.released}

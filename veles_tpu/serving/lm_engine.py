@@ -210,10 +210,11 @@ from concurrent.futures import Future
 import numpy
 
 from veles_tpu.logger import Logger
+from veles_tpu import model_config
 from veles_tpu.serving import lockcheck, tracing, xfer
 from veles_tpu.serving.batcher import (DeadlineExceeded, Overloaded,
                                        PoolExhausted)
-from veles_tpu.serving.kv_pool import KVPagePool
+from veles_tpu.serving.kv_pool import KVPagePool, WindowTables
 from veles_tpu.serving.metrics import ServingMetrics
 
 
@@ -590,7 +591,10 @@ class LMEngine(Logger):
         #: unarmed discipline: every site is one is-None check
         self._tracer = tracer
         self.params = params
-        self.n_heads = int(n_heads)
+        #: the model's record (model_config.py): ``n_heads`` is one,
+        #: or a head count with the classic keywords beside it
+        self.cfg = model_config.of(n_heads, rope, window, sinks)
+        self.n_heads = self.cfg.n_heads
         self.max_len = int(max_len)
         # ---- sharded serving (ISSUE 8): ``tp >= 2`` runs EVERY engine
         # program under a one-axis ('tp',) mesh — weights head-/column-
@@ -622,9 +626,9 @@ class LMEngine(Logger):
         elif devices:
             self._device = devices[0]
         self.slots = int(slots)
-        self.rope = bool(rope)
-        self.window = window
-        self.sinks = int(sinks)
+        self.rope = self.cfg.rope
+        self.window = self.cfg.window
+        self.sinks = self.cfg.sinks
         self.queue_depth = int(queue_depth)
         self.deadline_s = float(deadline_s)
         self.queue_tokens = int(queue_tokens)
@@ -696,6 +700,36 @@ class LMEngine(Logger):
         if self.temperature < 0 or self.top_k < 0:
             raise ValueError("temperature and top_k must be >= 0")
         self._sampling = self.temperature > 0
+        if self.cfg.by_kind or self.cfg.block != "pre_ln":
+            # what was not widened to this family says so here, by
+            # mechanism — never a wrong answer
+            for on, what in (
+                    (prefix_cache, "prefix_cache (the radix trie shares "
+                     "pages of ONE table; a sliding layer's pages are "
+                     "released under it)"),
+                    (self.spec_k, "spec_k (the verify program writes k "
+                     "positions ahead through one table)"),
+                    (self.megastep, "megastep (the fused scan and while "
+                     "programs carry one table and no window release)"),
+                    (self.refill_ring, "refill_ring (standby lanes are "
+                     "published as rows of one table)"),
+                    (self.tp >= 2, "tp >= 2 (lm_param_specs shards the "
+                     "pre_ln tree only)"),
+                    (self._sampling, "temperature > 0 (the seeded sampler "
+                     "is wired into the pre_ln programs only)")):
+                if on:
+                    raise ValueError(
+                        "LMEngine: %s is not supported for a %r model%s"
+                        % (what, self.cfg.block,
+                           " with per-layer attention kinds"
+                           if self.cfg.by_kind else ""))
+        if not self._paged and any(
+                self.cfg.ffn_kind(i, blk) == model_config.MOE
+                for i, blk in enumerate(params["blocks"])):
+            raise ValueError(
+                "LMEngine: an expert layer needs paged_kv — the contiguous "
+                "layout's step is a vmap over lanes, and the grouped "
+                "matmul of ops/moe.py has no batching rule")
         if self._sampling and sample_seed is None:
             raise ValueError("temperature > 0 needs sample_seed — "
                              "seeded reproducibility is the contract")
@@ -734,8 +768,8 @@ class LMEngine(Logger):
 
         embed = params["embed"]
         d_model = embed.shape[1]
-        head_dim = d_model // self.n_heads
-        kv_heads = params["blocks"][0]["attn"]["wk"].shape[1] // head_dim
+        head_dim = self.cfg.head_size(d_model)
+        kv_heads = self.cfg.kv_heads(params["blocks"][0]["attn"], d_model)
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
             if kv_heads % self.tp:
@@ -798,6 +832,10 @@ class LMEngine(Logger):
         self._page_tables = None
         self._max_pages = 0
         self._width_ladder = []
+        #: the sliding layers' tables and allocator (kv_pool.WindowTables;
+        #: None for a stack of one kind)
+        self._wt = None
+        self._window_shape = None
         if self._paged:
             self._max_pages = self.max_len // self.prefill_chunk
             # decode/verify table-width ladder (ISSUE 7 satellite): a
@@ -833,6 +871,18 @@ class LMEngine(Logger):
             self._page_tables = numpy.zeros(
                 (self.slots, self._max_pages), numpy.int32)
             self.metrics.set_gauge("kv_pages_total", num_pages)
+            if model_config.SLIDING in self.cfg.kinds:
+                # two kinds of cache (ISSUE 28): a page table, an
+                # allocator and pools of their own for the sliding
+                # layers, where a lane never holds more than the
+                # window's pages
+                wpages = min(num_pages, self.slots
+                             * self.cfg.window_pages(self.prefill_chunk))
+                self._wt = WindowTables(
+                    KVPagePool(wpages, self.prefill_chunk), self.slots,
+                    self.cfg.window)
+                self._window_shape = (wpages + 1,) + self._storage_shape[1:]
+                self.metrics.set_gauge("kv_pages_total.window", wpages)
         else:
             self._storage_shape = (self.slots, kv_heads, self.max_len,
                                    head_dim)
@@ -949,11 +999,15 @@ class LMEngine(Logger):
         where = (self._kv_shard if self._mesh is not None
                  else self._device)
 
-        def zeros():
-            arr = jnp.zeros(self._storage_shape, self._storage_dtype)
+        def zeros(shape):
+            arr = jnp.zeros(shape, self._storage_dtype)
             return arr if where is None else jax.device_put(arr, where)
 
-        return [(zeros(), zeros()) for _ in self.params["blocks"]]
+        shapes = [self._window_shape if self._wt is not None
+                  and self.cfg.kind(i) == model_config.SLIDING
+                  else self._storage_shape
+                  for i in range(len(self.params["blocks"]))]
+        return [(zeros(shape), zeros(shape)) for shape in shapes]
 
     def _storage(self):
         return self._kv_pools if self._paged else self._caches
@@ -1050,10 +1104,9 @@ class LMEngine(Logger):
         import jax
         import jax.numpy as jnp
         from veles_tpu.ops.transformer import (block_decode_step,
-                                               chunk_apply, head_logits,
-                                               prefill)
-        n_heads, max_len = self.n_heads, self.max_len
-        rope, window, sinks = self.rope, self.window, self.sinks
+                                               chunk_apply, chunk_embed,
+                                               head_logits, prefill)
+        cfg, max_len = self.cfg, self.max_len
         C, k1 = self.prefill_chunk, self.spec_k + 1
         if self._paged:
             self._build_paged_jits()
@@ -1065,10 +1118,9 @@ class LMEngine(Logger):
             # < true_len are exact under causal attention regardless of
             # pad content (see transformer._generate_impl), so one
             # compile serves every prompt length in the bucket
-            h, caches = prefill(params, prompt, n_heads, max_len,
-                                rope=rope, window=window, sinks=sinks)
+            h, caches = prefill(params, prompt, cfg, max_len)
             logits = head_logits(params, jax.lax.dynamic_slice_in_dim(
-                h, true_len - 1, 1, axis=1))[:, 0, :]
+                h, true_len - 1, 1, axis=1), cfg)[:, 0, :]
             if pick1 is None:
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[0]
             else:
@@ -1085,17 +1137,14 @@ class LMEngine(Logger):
             # one lane, one token: feed ``tok`` at ``pos`` against this
             # lane's cache rows; vmapped below over the slot axis so
             # every lane advances in ONE dispatch at its own position
-            x = jnp.take(params["embed"], tok[None], axis=0)[None]
-            if "pos" in params:
-                x = x + jax.lax.dynamic_slice_in_dim(
-                    params["pos"], pos, 1, axis=0)[None]
+            x = chunk_embed(params, tok[None, None], pos, cfg)
             new_rows = []
-            for blk, (kc, vc) in zip(params["blocks"], cache_rows):
+            for i, (blk, (kc, vc)) in enumerate(zip(params["blocks"],
+                                                    cache_rows)):
                 x, kc, vc = block_decode_step(
-                    blk, x, kc[None], vc[None], pos, n_heads, rope=rope,
-                    window=window, sinks=sinks)
+                    blk, x, kc[None], vc[None], pos, cfg, layer=i)
                 new_rows.append((kc[0], vc[0]))
-            logits = head_logits(params, x)[0, 0, :]
+            logits = head_logits(params, x, cfg)[0, 0, :]
             if pick1 is None:
                 return new_rows, jnp.argmax(logits).astype(jnp.int32)
             return new_rows, pick1(logits, seed, pos + 1)
@@ -1131,8 +1180,7 @@ class LMEngine(Logger):
                          jax.lax.dynamic_slice_in_dim(vc, slot, 1, 0))
                         for kc, vc in caches]
                 h, rows = chunk_apply(params, tokens[None], rows, start,
-                                      n_heads, rope=rope, window=window,
-                                      sinks=sinks)
+                                      cfg)
                 caches = [
                     (jax.lax.dynamic_update_slice(kc, rk,
                                                   (slot, 0, 0, 0)),
@@ -1141,7 +1189,7 @@ class LMEngine(Logger):
                     for (kc, vc), (rk, rv) in zip(caches, rows)]
                 logits = head_logits(
                     params, jax.lax.dynamic_slice_in_dim(
-                        h, last_idx, 1, axis=1))[:, 0, :]
+                        h, last_idx, 1, axis=1), cfg)[:, 0, :]
                 if pick1 is None:
                     tok = jnp.argmax(logits,
                                      axis=-1).astype(jnp.int32)[0]
@@ -1190,10 +1238,8 @@ class LMEngine(Logger):
                 # draft prefix that matches the verifier's own pick, so
                 # output is exact by construction in both modes
                 rows = [(kc[None], vc[None]) for kc, vc in cache_rows]
-                h, rows = chunk_apply(params, toks[None], rows, pos,
-                                      n_heads, rope=rope, window=window,
-                                      sinks=sinks)
-                logits = head_logits(params, h)[0]      # (k+1, vocab)
+                h, rows = chunk_apply(params, toks[None], rows, pos, cfg)
+                logits = head_logits(params, h, cfg)[0]  # (k+1, vocab)
                 if pick1 is None:
                     out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 else:
@@ -1240,21 +1286,36 @@ class LMEngine(Logger):
         import jax.numpy as jnp
         from veles_tpu.ops.transformer import (head_logits,
                                                paged_chunk_apply)
-        n_heads = self.n_heads
-        rope, window, sinks = self.rope, self.window, self.sinks
+        cfg = self.cfg
         kern = self._kernel_active
         pick1 = self._make_pick()
+
+        full, sliding = model_config.FULL, model_config.SLIDING
+        # the step of a record with an expert layer also returns the
+        # layers' counts, fetched with the tokens
+        stats = cfg.moe is not None
+
+        def tables_of(ptab):
+            # (tables, where they begin) as ``paged_chunk_apply`` takes
+            # them: the plain table of a stack of one kind, or
+            # ``_table_args``' pair for two kinds of cache (a table per
+            # kind, the sliding kind's beginning in tokens per lane)
+            if not isinstance(ptab, tuple):
+                return ptab, None
+            tabs, wbase = ptab
+            return tabs, {full: None, sliding: wbase}
 
         def chunk_slot(params, pools, ptab, tokens, start, last_idx,
                        *sargs):
             # one lane's prompt chunk through its page table; returns
             # the pick after ``last_idx`` (read on the tail chunk)
+            tokens = tokens[None]
+            tabs, base = tables_of(jax.tree.map(lambda t: t[None], ptab))
             h, pools = paged_chunk_apply(
-                params, tokens[None], pools, ptab[None], start[None],
-                n_heads, rope=rope, window=window, sinks=sinks,
-                attn_kernel="prefill" if kern else None)
+                params, tokens, pools, tabs, start[None],
+                cfg, attn_kernel="prefill" if kern else None, base=base)
             logits = head_logits(params, jax.lax.dynamic_slice_in_dim(
-                h, last_idx, 1, axis=1))[:, 0, :]
+                h, last_idx, 1, axis=1), cfg)[:, 0, :]
             if pick1 is None:
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[0]
             else:
@@ -1264,15 +1325,17 @@ class LMEngine(Logger):
         def step_all(params, pools, ptabs, toks, pos, *sargs):
             # ONE dispatch advances every lane by one token at its own
             # position through its own page table
-            h, pools = paged_chunk_apply(
-                params, toks[:, None], pools, ptabs, pos, n_heads,
-                rope=rope, window=window, sinks=sinks,
-                attn_kernel="decode" if kern else None)
-            logits = head_logits(params, h)[:, 0, :]
+            tabs, base = tables_of(ptabs)
+            h, pools, *counts = paged_chunk_apply(
+                params, toks[:, None], pools, tabs, pos, cfg,
+                attn_kernel="decode" if kern else None, base=base,
+                with_stats=stats)
+            logits = head_logits(params, h, cfg)[:, 0, :]
             if pick1 is None:
-                return pools, jnp.argmax(logits,
-                                         axis=-1).astype(jnp.int32)
-            return pools, jax.vmap(pick1)(logits, sargs[0], pos + 1)
+                toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            else:
+                toks = jax.vmap(pick1)(logits, sargs[0], pos + 1)
+            return (pools, toks, *counts)
 
         def page_copy(pools, src, dst):
             # copy-on-write: duplicate one page across every block so
@@ -1300,8 +1363,7 @@ class LMEngine(Logger):
                 # returns the greedy argmax (or the seeded sample at
                 # each absolute position) AFTER each fed position
                 h, pools = paged_chunk_apply(
-                    params, toks, pools, ptabs, pos, n_heads, rope=rope,
-                    window=window, sinks=sinks,
+                    params, toks, pools, ptabs, pos, cfg,
                     attn_kernel="decode" if kern else None)
                 logits = head_logits(params, h)      # (slots, k+1, v)
                 if pick1 is None:
@@ -1391,8 +1453,7 @@ class LMEngine(Logger):
         import jax.numpy as jnp
         K, k = self.megastep, self.spec_k
         paged = self._paged
-        n_heads = self.n_heads
-        rope, window, sinks = self.rope, self.window, self.sinks
+        cfg = self.cfg
         kern = self._kernel_active
         L = self.max_len
         slots = self.slots
@@ -1422,8 +1483,7 @@ class LMEngine(Logger):
                 toks = jnp.concatenate([last[:, None], draft], axis=1)
                 if paged:
                     h, storage = paged_chunk_apply(
-                        params, toks, storage, ptabs, pos, n_heads,
-                        rope=rope, window=window, sinks=sinks,
+                        params, toks, storage, ptabs, pos, cfg,
                         attn_kernel="decode" if kern else None,
                         write_mask=active)
                     logits = head_logits(params, h)
@@ -1494,8 +1554,7 @@ class LMEngine(Logger):
                 active = left > 0
                 if paged:
                     h, storage = paged_chunk_apply(
-                        params, last[:, None], storage, ptabs, pos,
-                        n_heads, rope=rope, window=window, sinks=sinks,
+                        params, last[:, None], storage, ptabs, pos, cfg,
                         attn_kernel="decode" if kern else None,
                         write_mask=active)
                     logits = head_logits(params, h)[:, 0, :]
@@ -1690,7 +1749,8 @@ class LMEngine(Logger):
             ptabs = numpy.zeros((self.slots, self._max_pages),
                                 numpy.int32)
             self._kv_pools, _ = self._chunk_jit(
-                self.params, self._kv_pools, xfer.to_device(ptabs[0]),
+                self.params, self._kv_pools,
+                self._table_args(ptabs[0], 0),
                 xfer.to_device(numpy.zeros(self.prefill_chunk,
                                            numpy.int32)), zero, zero,
                 *s1)
@@ -1702,7 +1762,7 @@ class LMEngine(Logger):
             # or the first request to cross each width boundary pays
             # its compile inside the serving loop
             for w in self._width_ladder:
-                wtab = xfer.to_device(ptabs[:, :w])
+                wtab = self._table_args(ptabs[:, :w], slice(None))
                 fused = self._whilestep_jit or self._megastep_jit
                 if fused is not None:
                     args = [self.params, self._kv_pools, wtab,
@@ -1725,9 +1785,9 @@ class LMEngine(Logger):
                             (self.slots, self.spec_k + 1),
                             numpy.int32)), zeros, *sv)
                 went_in = self._kv_pools[0][0]
-                self._kv_pools, _ = self._step_jit(
+                self._kv_pools = self._step_jit(
                     self.params, self._kv_pools, wtab, zeros, zeros,
-                    *sv)
+                    *sv)[0]
         else:
             tok, rows = self._prefill_jit(
                 self.params,
@@ -2312,6 +2372,13 @@ class LMEngine(Logger):
         traffic drains and after restore."""
         if not self._paged:
             return {"paged": False}
+        if self._wt is not None:
+            self._wt.verify()
+            for slot, lane in enumerate(self._lanes):
+                if lane is None and self._wt.count[slot]:
+                    raise RuntimeError(
+                        "free slot %d holds %d window pages"
+                        % (slot, self._wt.count[slot]))
         self._pool.verify()
         n = self._pool.num_pages
         want_refs = [0] * (n + 1)
@@ -2530,11 +2597,15 @@ class LMEngine(Logger):
             keys = [tuple(int(t) for t in req.prompt[i * C:(i + 1) * C])
                     for i in range(n_full)]
             nodes = self._trie.match(keys)
+        if self._wt is not None and not self._wt.can_admit(req.pages):
+            return False         # the sliding layers' pool is committed
         fresh = self._alloc_pages(req.pages - len(nodes))
         if fresh is None:
             if nodes:            # nothing committed — undo the pins
                 self._trie.release(nodes)
             return False
+        if self._wt is not None:
+            self._wt.admit(slot, req.pages)
         lane.pinned.extend(nodes)
         lane.cursor = (nodes[-1] if nodes else
                        self._trie.root if self._trie is not None
@@ -2677,6 +2748,38 @@ class LMEngine(Logger):
         self.metrics.set_gauge("kv_pages_free", self._pool.free_pages)
         self.metrics.set_gauge("kv_pages_pinned",
                                self._pool.pinned_pages)
+        if self._wt is not None:
+            self.metrics.set_gauge("kv_pages_free.full",
+                                   self._pool.free_pages)
+            self.metrics.set_gauge("kv_pages_free.window",
+                                   self._wt.pool.free_pages)
+
+    def _slide_window(self, slot, lo, hi):   # hot-path
+        """The sliding layers' table of ``slot`` before it writes
+        positions [lo, hi): pages behind the window released, the
+        frontier's page taken (``kv_pool.WindowTables.advance``)."""
+        gone = self._wt.advance(slot, lo, hi)
+        if gone:
+            self.metrics.inc("kv_pages_released_window", gone)
+
+    def _table_args(self, full, rows):   # hot-path
+        """The programs' table argument after the table ``full`` of the
+        lanes ``rows`` (a slot, or a slice of all), already cut to its
+        width: that table on the device; for two kinds of cache the pair
+        (a table per kind, where the sliding kind's begins in tokens per
+        lane), the sliding kind's rows cut to the same width or to its
+        own, whichever is less."""
+        wt = self._wt
+        if wt is None:
+            return xfer.to_device(full)
+        width = min(full.shape[-1], wt.width)
+        # the sliding rows as an array of their own: a put may read host
+        # memory after it returns, and the rows shift in place
+        tables = {model_config.FULL: xfer.to_device(full),
+                  model_config.SLIDING: xfer.to_device(
+                      wt.tables[rows, :width].copy())}
+        return tables, xfer.to_device(wt.base[rows] * wt.page,
+                                      numpy.int32)
 
     def _live_width(self, span, floor=0):
         """Ladder-bucketed page-table width for a decode/verify step
@@ -2696,6 +2799,17 @@ class LMEngine(Logger):
             if w >= need:
                 return w
         return self._max_pages
+
+    def _note_moe(self, counts):   # hot-path
+        """The expert layers' counts of one decode step, fetched with its
+        tokens (``ops/moe.py::held_part``): the counters of
+        ``/metrics.json`` and the open turn of the loop recorder."""
+        held, away, hit, load = (int(c) for c in counts)
+        self.metrics.inc("moe_assignments_held", held)
+        self.metrics.inc("moe_assignments_elsewhere", away)
+        self.metrics.inc("moe_experts_hit", hit)
+        self.metrics.set_gauge_max("moe_max_expert_load", load)
+        self.recorder.moe(held, away, hit, load)
 
     def _note_attn_dispatch(self):
         """Per-dispatch kernel accounting (ISSUE 7): which path the
@@ -2866,7 +2980,9 @@ class LMEngine(Logger):
         try:
             self._fault("engine.chunk")
             self._cow_guard(slot, lane, start, start + C)
-            args = (xfer.to_device(self._page_tables[slot]),
+            if self._wt is not None:
+                self._slide_window(slot, start, start + C)
+            args = (self._table_args(self._page_tables[slot], slot),
                     xfer.to_device(tokens, numpy.int32),
                     xfer.to_device(start, numpy.int32),
                     xfer.to_device(last_idx, numpy.int32)) \
@@ -2966,6 +3082,8 @@ class LMEngine(Logger):
         requeue all funnel here so none can forget a step.  The step
         position parks at 0 (a free slot's garbage writes land where
         the next admission overwrites them)."""
+        if self._wt is not None:
+            self._wt.vacate(slot)
         self._release_lane(lane)
         self._lanes[slot] = None
         if slot not in self._free:
@@ -3114,13 +3232,22 @@ class LMEngine(Logger):
         try:
             self._fault("engine.step")
             args = ()
+            if self._wt is not None:
+                due = self._wt.due(self._pos)
+                for slot in active:
+                    if due[slot]:
+                        p = int(self._pos[slot])
+                        self._slide_window(slot, p, p + 1)
             if self._paged:
                 w = self._live_width(1)
-                args = (xfer.to_device(self._page_tables[:, :w]),)
+                args = (self._table_args(self._page_tables[:, :w],
+                                         slice(None)),)
             args += (xfer.to_device(self._last),
                      xfer.to_device(self._pos)) + self._seed_vec()
-            toks, = self._dispatch_decode(self._step_jit, args,
-                                          len(active), tctxs)
+            toks, *counts = self._dispatch_decode(self._step_jit, args,
+                                                  len(active), tctxs)
+            if counts:
+                self._note_moe(counts[0])
         except Exception as e:   # noqa: BLE001 — fails the lanes
             if self._tracer is not None:
                 self._tracer.add_many(

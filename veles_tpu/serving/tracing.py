@@ -748,7 +748,15 @@ COL_BUSY = COL_END + 3
 COL_ACTIVE = COL_END + 4
 COL_QUEUE = COL_END + 5
 COL_TOKENS = COL_END + 6
-TURN_WIDTH = COL_END + 7
+#: the expert layers' counts of the turn's decode step (0 for a model
+#: without any; ``ops/moe.py::held_part``): assignments to experts this
+#: chip holds and to experts held elsewhere, experts hit (summed over the
+#: expert layers), the largest expert load of any layer
+COL_MOE_HELD = COL_END + 7
+COL_MOE_ELSEWHERE = COL_END + 8
+COL_MOE_HIT = COL_END + 9
+COL_MOE_LOAD = COL_END + 10
+TURN_WIDTH = COL_END + 11
 #: the column that holds the program a dispatch phase called
 _PROGRAM_COL = {PREFILL_DISPATCH: COL_PREFILL_PROGRAM,
                 STEP_DISPATCH: COL_STEP_PROGRAM}
@@ -890,6 +898,13 @@ class LoopRecorder:
     def lanes(self, busy, queued):
         self._cur[COL_BUSY] = busy
         self._cur[COL_QUEUE] = queued
+
+    def moe(self, held, elsewhere, hit, load):
+        cur = self._cur
+        cur[COL_MOE_HELD] = held
+        cur[COL_MOE_ELSEWHERE] = elsewhere
+        cur[COL_MOE_HIT] = hit
+        cur[COL_MOE_LOAD] = load
 
     def emitted(self, request, n):
         """``n`` tokens of ``request`` reached the host now: the stamp of
