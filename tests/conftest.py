@@ -170,3 +170,61 @@ def _lock_order_witness(request):
         "lock-order witness recorded %d violation(s) during %s:\n\n%s"
         % (len(witness.violations), request.node.nodeid,
            "\n\n".join(witness.violations)))
+
+
+@pytest.fixture
+def page_step_census():
+    """``watch(engine)`` wraps the chunk and the decode program of a
+    STARTED engine (its warm-up has run) to note the table and the
+    positions every dispatch is handed, and returns ``count()``: the page
+    steps those dispatches gave the attention kernels and the live ones
+    among them, ``(given, live)``, by brute force over
+    ``attention.chunk_live_mask`` (the XLA twin's own mask) — what the
+    engine's ``attn_page_steps`` and ``attn_page_steps_live`` must read
+    (ISSUE 29)."""
+    import numpy
+    from veles_tpu import model_config
+    from veles_tpu.ops.attention import chunk_live_mask
+
+    def watch(engine):
+        calls = []
+
+        def spy(name, span):
+            real = getattr(engine, name)
+
+            @functools.wraps(real)
+            def noted(params, pools, table, tokens, pos, *rest):
+                calls.append((span, jax.tree.map(numpy.array, table),
+                              numpy.atleast_1d(numpy.array(pos))))
+                return real(params, pools, table, tokens, pos, *rest)
+            setattr(engine, name, noted)
+        spy("_chunk_jit", 0)       # the history below a chunk's frontier
+        spy("_step_jit", 1)
+
+        cfg, page = engine.cfg, engine.prefill_chunk
+
+        @functools.lru_cache(maxsize=None)
+        def live_pages(pos, span, width, window):
+            mask = numpy.asarray(chunk_live_mask(
+                pos, span or page, width * page, window, cfg.sinks))
+            if not span:
+                mask = mask & (numpy.arange(width * page) < pos)
+            return int(mask.any(0).reshape(width, page).any(1).sum())
+
+        def count():
+            given = live = 0
+            for span, table, pos in calls:
+                tables, base = table if isinstance(table, tuple) \
+                    else ({model_config.FULL: table}, 0)
+                for layer in range(len(engine.params["blocks"])):
+                    kind = cfg.kind(layer)
+                    width = tables[kind].shape[-1]
+                    rel = pos - (base if kind == model_config.SLIDING
+                                 else 0)
+                    given += len(rel) * width
+                    live += sum(live_pages(int(p), span, width,
+                                           cfg.layer_window(layer))
+                                for p in rel)
+            return given, live
+        return count
+    return watch

@@ -125,7 +125,8 @@ def test_paged_prefill_then_decode_matches_the_reference(weights, kernel):
 @pytest.mark.parametrize("features", [
     {"slots": 3}, {"slots": 16, "attn_kernel": "force", "prefill_chunk": 8}],
     ids=["xla", "kernels_row_write"])
-def test_engine_serves_the_references_tokens(weights, features):
+def test_engine_serves_the_references_tokens(weights, features,
+                                             page_step_census):
     """Through ``LMEngine`` (admission, chunked prefill interleaved with
     decode, the live-width ladder, window pages released per lane): every
     served token is the reference's choice, the allocators of both kinds
@@ -137,6 +138,7 @@ def test_engine_serves_the_references_tokens(weights, features):
     page = features.get("prefill_chunk", PAGE)
     eng = LMEngine(wf, record(), max_len=48, **dict(
         {"paged_kv": 48, "prefill_chunk": PAGE}, **features)).start()
+    count = page_step_census(eng)
     held = []
     if eng._wt is not None:
         real = eng._wt.advance
@@ -179,6 +181,16 @@ def test_engine_serves_the_references_tokens(weights, features):
                 == c["moe_experts_hit"]
             assert int(turns[:, tracing.COL_MOE_LOAD].max()) \
                 == snap["gauges"]["moe_max_expert_load"]
+            # ISSUE 29: the page steps handed to the kernels and the live
+            # ones, over both kinds of table (the sliding kind's relative
+            # to its base); nothing is counted without the kernels
+            steps = (c.get("attn_page_steps"), c.get("attn_page_steps_live"))
+            assert steps == (count() if eng._kernel_active
+                             else (None, None))
+            assert int(turns[:, tracing.COL_ATTN_STEPS].sum()) \
+                == (steps[0] or 0)
+            assert int(turns[:, tracing.COL_ATTN_LIVE].sum()) \
+                == (steps[1] or 0)
     finally:
         eng.stop()
 

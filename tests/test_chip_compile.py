@@ -150,6 +150,36 @@ def test_paged_flash_prefill_compiles_with_a_head_block_axis(one_chip):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+@pytest.mark.parametrize("width,window", [(32, None), (18, 4096)],
+                         ids=["full", "window_base"])
+def test_kernels_that_skip_dead_pages_compile(one_chip, kernel, width,
+                                              window):
+    """ISSUE 29: the index maps clamp the page index into the lane's live
+    range (scalar arithmetic on the prefetched positions) and the step's
+    body sits under ``pl.when``; interpret mode runs grid steps in order
+    and cannot show what the chip's pipelined grid makes of that, the
+    chip's compiler at least must take it: at ``trinity-large-ep8``'s
+    shapes, a full layer's table and a sliding layer's short one that
+    begins at a ``base`` (the caller hands the kernel ``pos - base``)."""
+    lanes = 32 if kernel == "decode" else 1
+    pool = ((lanes * width + 1, 8, 256, 128), BF16)
+    table, ints = ((lanes, width), I32), ((lanes,), I32)
+    if kernel == "decode":
+        def run(q, k, v, pt, ps, base):
+            return PK.paged_flash_decode(q, k, v, pt, ps - base,
+                                         window=window, interpret=False)
+        shapes = [((lanes, 48, 1, 128), BF16), pool, pool]
+    else:
+        def run(q, kn, vn, k, v, pt, ps, base):
+            return PK.paged_flash_prefill(q, kn, vn, k, v, pt, ps - base,
+                                          window=window, interpret=False)
+        new = ((lanes, 8, 256, 128), BF16)
+        shapes = [((lanes, 48, 256, 128), BF16), new, new, pool, pool]
+    text = compile_for(one_chip, run, *shapes, table, ints, ints)
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
 def test_paged_row_write_compiles(one_chip, dtype):
     text = compile_for(

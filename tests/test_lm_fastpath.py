@@ -750,6 +750,47 @@ class TestAttnKernelRouting:
         finally:
             engine.stop()
 
+    @pytest.mark.parametrize("band", [{}, {"window": 20, "sinks": 2}],
+                             ids=["full", "window_sinks"])
+    def test_page_steps_counted_as_dispatched(self, page_step_census,
+                                              band):
+        """ISSUE 29: the engine counts, per dispatch through the kernels,
+        the page steps it handed them (lanes x table width x layers) and
+        the live ones, with the kernels' own ``live_pages``: they equal
+        a brute-force count over the dispatches made, the recorder's two
+        columns sum to the counters, and the tokens are ``generate``'s."""
+        import jax.numpy as jnp
+        from veles_tpu.ops.transformer import generate
+        from veles_tpu.serving import LMEngine, tracing
+        params = _params()
+        engine = LMEngine(params, n_heads=2, max_len=96, slots=3,
+                          paged_kv=True, prefill_chunk=8,
+                          attn_kernel="force", name="ak_steps", **band)
+        engine.start()
+        count = page_step_census(engine)      # after the warm-up's calls
+        try:
+            rng = numpy.random.RandomState(29)
+            prompts = [rng.randint(1, 16, n).tolist()
+                       for n in (3, 20, 41, 9, 33)]
+            outs = [f.result(timeout=300)
+                    for f in [engine.submit(p, 7) for p in prompts]]
+            for p, o in zip(prompts, outs):
+                want = numpy.asarray(generate(
+                    params, jnp.asarray([p], jnp.int32), 7, 2,
+                    temperature=0.0, max_len=96, **band))[0]
+                numpy.testing.assert_array_equal(
+                    numpy.concatenate([p, o]), want)
+            c = engine.metrics.snapshot()["counters"]
+            given, live = count()
+            assert (c["attn_page_steps"], c["attn_page_steps_live"]) \
+                == (given, live)
+            assert 0 < live < given / 2        # most of a table is dead
+            turns = engine.recorder.turns()
+            assert int(turns[:, tracing.COL_ATTN_STEPS].sum()) == given
+            assert int(turns[:, tracing.COL_ATTN_LIVE].sum()) == live
+        finally:
+            engine.stop()
+
     def test_flash_serve_backend_default(self):
         """set_attention_backend('flash_serve') flips the DEFAULT for
         engines built while it is set (attn_kernel=None follows it;

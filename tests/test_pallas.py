@@ -245,6 +245,99 @@ class TestPallasLRN:
         assert numpy.isfinite(numpy.asarray(g)).all()
 
 
+def _all_pages_live(monkeypatch):
+    """The kernels as they were before ISSUE 29: every page of the table
+    fetched and given a softmax step, live or not."""
+    from veles_tpu.ops import pallas_kernels as PK
+
+    def everything(pos, c, page, m_pages, window=None, sinks=0, xp=jnp):
+        return xp.zeros_like(pos), xp.zeros_like(pos) + m_pages - 1, 0
+    monkeypatch.setattr(PK, "live_pages", everything)
+
+
+def _poisoned(pool, ptab, live, rng):
+    """``pool`` and ``ptab`` (numpy) with every table entry that ``live``
+    (lanes x entries, bool) calls dead pointed at a page of NaN, and a
+    clean twin whose dead entries point at a page of noise."""
+    nan_page = pool.shape[0]
+    bad = numpy.concatenate(
+        [pool, numpy.full((1,) + pool.shape[1:], numpy.nan, pool.dtype)])
+    clean = numpy.concatenate(
+        [pool, rng.randn(1, *pool.shape[1:]).astype(pool.dtype)])
+    return bad, clean, numpy.where(live, ptab, nan_page).astype(numpy.int32)
+
+
+def _band_pages(pos, c, page, m_pages, window, sinks):
+    """Brute force over every (query row, key) pair: which pages of the
+    table hold a key that ``_band`` lets some row see.  ``c == 0`` is
+    the prefill kernel's history (a chunk of ``page`` rows at ``pos``,
+    keys strictly below it)."""
+    from veles_tpu.ops import pallas_kernels as PK
+    k = numpy.arange(m_pages * page)[None, :]
+    q = pos + numpy.arange(c or page)[:, None]
+    base = numpy.broadcast_to((k <= q) if c else (k < pos),
+                              (len(q), k.shape[1])).copy()
+    return PK._band(k, q, window, sinks, base).any(0).reshape(
+        m_pages, page).any(1)
+
+
+class TestLivePages:
+    """ISSUE 29: the range of pages the serving kernels neither fetch nor
+    step over must be EXACTLY the pages on which ``_band`` is false for
+    every (query row, key) pair."""
+
+    PAGE, M = 8, 6
+
+    @pytest.mark.parametrize("window,sinks", [
+        (None, 0), (None, 3), (1, 0), (10, 0), (10, 2), (20, 9), (16, 17)])
+    @pytest.mark.parametrize("c", [0, 1, 3, 8])
+    def test_agrees_with_band(self, c, window, sinks):
+        from veles_tpu.ops import pallas_kernels as PK
+        page, m = self.PAGE, self.M
+        # 0, mid page, a page's last and first row, deep, the table's end
+        lanes = numpy.asarray([0, 3, 7, 8, 21, 31, 32, m * page - max(c, 1)])
+        if not c:
+            lanes = lanes // page * page       # a chunk is page-aligned
+        first, last, sink = PK.live_pages(lanes, c, page, m, window, sinks,
+                                          xp=numpy)
+        j = numpy.arange(m)[None, :]
+        got = (j <= last[:, None]) & ((j >= first[:, None]) | (j < sink))
+        want = numpy.stack([_band_pages(int(p), c, page, m, window, sinks)
+                            for p in lanes])
+        numpy.testing.assert_array_equal(got, want)
+        numpy.testing.assert_array_equal(
+            PK.live_page_count(first, last, sink), want.sum(1))
+        # the kernels' wrappers (jax) read what the host (numpy) reads
+        traced = PK.live_pages(jnp.asarray(lanes, jnp.int32), c, page, m,
+                               window, sinks)
+        numpy.testing.assert_array_equal(traced[0], first)
+        numpy.testing.assert_array_equal(traced[1], last)
+        assert traced[2] == sink
+        for i in range(len(lanes)):
+            named = []
+            for jj in range(m):
+                at = (jnp.int32(jj), traced[0][i], traced[1][i], sink)
+                live, entry = PK._is_live(*at), PK._live_entry(*at)
+                assert bool(live) == bool(want[i, jj])
+                assert 0 <= int(entry) < m
+                assert int(entry) == jj or not live
+                named.append(int(entry))
+            # a block is copied when its index changes: the live pages,
+            # and at most one more (a lane with an empty range)
+            copies = 1 + sum(a != b for a, b in zip(named, named[1:]))
+            assert copies <= max(int(want[i].sum()), 1) + (
+                first[i] > last[i])
+
+    def test_a_narrow_table_clips_the_range(self):
+        """A table cut narrower than the lane's frontier (never handed to
+        the kernels; the host's count may ask) ends the range at its last
+        entry."""
+        from veles_tpu.ops import pallas_kernels as PK
+        first, last, sink = PK.live_pages(numpy.asarray([40]), 1, 8, 4,
+                                          xp=numpy)
+        assert (int(first[0]), int(last[0]), sink) == (0, 3, 0)
+
+
 @pytest.mark.kernel_parity
 class TestPagedFlashDecode:
     """ISSUE 7: the flash-decode serving kernel (interpret mode = the
@@ -329,6 +422,97 @@ class TestPagedFlashDecode:
         numpy.testing.assert_allclose(numpy.asarray(got),
                                       numpy.asarray(ref),
                                       rtol=1e-5, atol=1e-6)
+
+    # lanes of mixed depth: parked at 0, mid page, a page's last row and
+    # first row, deep, the table's end
+    DEPTHS = [0, 3, 7, 8, 21, 40]
+
+    def _mixed(self, c, dh, pack, seed, page=8, m=6):
+        rng = numpy.random.RandomState(seed)
+        b, kv = len(self.DEPTHS), 2
+        n_pages = b * m
+        q = jnp.asarray(rng.randn(b, 2 * kv, c, dh), jnp.float32)
+        kp = rng.randn(n_pages, kv // pack, page, pack * dh) \
+            .astype(numpy.float32)
+        vp = rng.randn(n_pages, kv // pack, page, pack * dh) \
+            .astype(numpy.float32)
+        ptab = rng.permutation(n_pages).reshape(b, m).astype(numpy.int32)
+        pos = numpy.minimum(self.DEPTHS, m * page - c).astype(numpy.int32)
+        return rng, q, kp, vp, ptab, pos
+
+    @pytest.mark.parametrize("c,window,sinks", [
+        (1, None, 0), (3, None, 0), (1, 10, 0), (3, 10, 2), (8, 20, 9)])
+    @pytest.mark.parametrize("dh,pack", [(64, 2), (128, 1)])
+    def test_skipping_dead_pages_keeps_the_bits(self, monkeypatch, c,
+                                                window, sinks, dh, pack):
+        """ISSUE 29: a grid step whose page no query row can see does
+        nothing and fetches nothing; the outputs are the SAME BITS as
+        those of the kernel that stepped over every page (a fully masked
+        block contributed an exact 0.0), on lanes of mixed depth, on a
+        packed pool (two heads of 64 to a row) and a plain one (128)."""
+        from veles_tpu.ops import pallas_kernels as PK
+        _, q, kp, vp, ptab, pos = self._mixed(c, dh, pack, seed=c + dh)
+        got = PK.paged_flash_decode(q, kp, vp, ptab, pos, window=window,
+                                    sinks=sinks)
+        _all_pages_live(monkeypatch)
+        ref = PK.paged_flash_decode(q, kp, vp, ptab, pos, window=window,
+                                    sinks=sinks)
+        numpy.testing.assert_array_equal(numpy.asarray(got),
+                                         numpy.asarray(ref))
+
+    @pytest.mark.parametrize("c,window,sinks", [
+        (1, None, 0), (3, 10, 0), (3, 10, 2)])
+    @pytest.mark.parametrize("dh,pack", [(64, 2), (128, 1)])
+    def test_dead_pages_of_nan_are_never_read(self, c, window, sinks, dh,
+                                              pack):
+        """The poison case: every table entry outside a lane's live range
+        points at a pool page of NaN.  The outputs are finite and equal
+        the clean run's.  (The kernels before ISSUE 29 fail this: they
+        multiplied the NaN keys into scores BEFORE masking them, and NaN
+        plus the mask's constant is NaN.)"""
+        from veles_tpu.ops import pallas_kernels as PK
+        rng, q, kp, vp, ptab, pos = self._mixed(c, dh, pack, seed=7 * c)
+        m = ptab.shape[1]
+        live = numpy.stack([_band_pages(int(p), c, 8, m, window, sinks)
+                            for p in pos])
+        assert not live.all() and live.any(1).all()
+        k_bad, k_clean, table = _poisoned(kp, ptab, live, rng)
+        v_bad, v_clean, _ = _poisoned(vp, ptab, live, rng)
+        bad = numpy.asarray(PK.paged_flash_decode(
+            q, k_bad, v_bad, table, pos, window=window, sinks=sinks))
+        clean = PK.paged_flash_decode(
+            q, k_clean, v_clean, table, pos, window=window, sinks=sinks)
+        assert numpy.isfinite(bad).all()
+        numpy.testing.assert_array_equal(bad, numpy.asarray(clean))
+
+    def test_sliding_table_with_a_base_keeps_the_bits(self, monkeypatch):
+        """A sliding layer's short table begins at ``base``; the kernels
+        work on ``pos - base``, and so does the range they skip by."""
+        from veles_tpu import prng
+        from veles_tpu.ops.attention import (init_mha_params,
+                                             mha_paged_chunk_step)
+        rng = numpy.random.RandomState(11)
+        d_model, n_heads, page, m, b = 32, 4, 8, 5, 3
+        params = jax.tree.map(
+            jnp.asarray, init_mha_params(prng.get("init"), d_model,
+                                         n_heads, n_kv_heads=2))
+        x = jnp.asarray(rng.randn(b, 1, d_model), jnp.float32)
+        kp = jnp.asarray(rng.randn(b * m + 1, 2, page, 8), jnp.float32)
+        vp = jnp.asarray(rng.randn(b * m + 1, 2, page, 8), jnp.float32)
+        ptab = jnp.asarray(1 + rng.permutation(b * m).reshape(b, m),
+                           jnp.int32)
+        base = jnp.asarray([0, 16, 40], jnp.int32)
+        pos = jnp.asarray([5, 37, 63], jnp.int32)
+
+        def run():
+            return mha_paged_chunk_step(
+                params, x, kp, vp, ptab, pos, n_heads, rope=True,
+                window=20, attn_kernel="decode", base=base)
+        got = run()
+        _all_pages_live(monkeypatch)
+        for a, e in zip(got, run()):
+            numpy.testing.assert_array_equal(numpy.asarray(a),
+                                             numpy.asarray(e))
 
     @pytest.mark.parametrize("pack", [1, 2])
     def test_mha_paged_chunk_step_kernel_route(self, pack):
@@ -428,6 +612,79 @@ class TestPagedFlashPrefill:
         numpy.testing.assert_allclose(numpy.asarray(got_o),
                                       numpy.asarray(ref_o),
                                       rtol=1e-5, atol=1e-6)
+
+    def _mixed(self, dh, pack, seed, page=8, m=6, kv=2, g=2):
+        """Three lanes whose chunks begin at 0, 8 and 32 of a table of
+        ``m`` pages."""
+        rng = numpy.random.RandomState(seed)
+        pos = numpy.asarray([0, page, 4 * page], numpy.int32)
+        b = len(pos)
+        q = jnp.asarray(rng.randn(b, g * kv, page, dh), jnp.float32)
+        kn = jnp.asarray(rng.randn(b, kv, page, dh), jnp.float32)
+        vn = jnp.asarray(rng.randn(b, kv, page, dh), jnp.float32)
+        kp = rng.randn(b * m, kv // pack, page, pack * dh) \
+            .astype(numpy.float32)
+        vp = rng.randn(b * m, kv // pack, page, pack * dh) \
+            .astype(numpy.float32)
+        ptab = rng.permutation(b * m).reshape(b, m).astype(numpy.int32)
+        return rng, q, kn, vn, kp, vp, ptab, pos
+
+    @pytest.mark.parametrize("window,sinks", [(None, 0), (12, 0), (12, 3)])
+    @pytest.mark.parametrize("dh,pack,head_blocks", [
+        (64, 2, False), (128, 1, False), (128, 1, True)])
+    def test_skipping_dead_pages_keeps_the_bits(self, monkeypatch, window,
+                                                sinks, dh, pack,
+                                                head_blocks):
+        """ISSUE 29 in the prefill kernel: the history pages at and past
+        the chunk's frontier, and those behind a window, cost neither a
+        fetch nor a softmax step; outputs and installed pools are the
+        same bits as the kernel's that walked the whole table; with the
+        3-axis grid (a block of kv heads a step) too."""
+        from veles_tpu.ops import pallas_kernels as PK
+        if head_blocks:
+            monkeypatch.setattr(PK, "_SCORES_BYTES", 2 * 8 * 8 * 4)
+            assert PK._heads_per_step(2, 2 * 8, 8) == 1
+        _, q, kn, vn, kp, vp, ptab, pos = self._mixed(dh, pack, seed=dh)
+        got = PK.paged_flash_prefill(q, kn, vn, kp, vp, ptab, pos,
+                                     window=window, sinks=sinks)
+        _all_pages_live(monkeypatch)
+        ref = PK.paged_flash_prefill(q, kn, vn, kp, vp, ptab, pos,
+                                     window=window, sinks=sinks)
+        for a, e in zip(got, ref):
+            numpy.testing.assert_array_equal(numpy.asarray(a),
+                                             numpy.asarray(e))
+
+    @pytest.mark.parametrize("window,sinks", [(None, 0), (12, 0), (12, 3)])
+    @pytest.mark.parametrize("dh,pack", [(64, 2), (128, 1)])
+    def test_dead_pages_of_nan_are_never_read(self, window, sinks, dh,
+                                              pack):
+        """The poison case for the history walk: every entry but the live
+        history's and the chunk's own page points at a page of NaN (the
+        first chunk of a prompt has no live history at all: its steps all
+        name entry 0, fetched and never used).  Outputs and installed
+        pages are finite and equal the clean run's; the kernel before
+        ISSUE 29 fails this (NaN scores before the mask)."""
+        from veles_tpu.ops import pallas_kernels as PK
+        rng, q, kn, vn, kp, vp, ptab, pos = self._mixed(dh, pack, seed=5)
+        page, m = 8, ptab.shape[1]
+        live = numpy.stack([_band_pages(int(p), 0, page, m, window, sinks)
+                            for p in pos])
+        own = numpy.arange(m)[None, :] == (pos // page)[:, None]
+        # entry 0 of a lane with no live history is named, never read
+        keep = live | own
+        keep[:, 0] |= ~live.any(1)
+        k_bad, k_clean, table = _poisoned(kp, ptab, keep, rng)
+        v_bad, v_clean, _ = _poisoned(vp, ptab, keep, rng)
+        bad = PK.paged_flash_prefill(q, kn, vn, k_bad, v_bad, table, pos,
+                                     window=window, sinks=sinks)
+        clean = PK.paged_flash_prefill(q, kn, vn, k_clean, v_clean, table,
+                                       pos, window=window, sinks=sinks)
+        assert numpy.isfinite(numpy.asarray(bad[0])).all()
+        numpy.testing.assert_array_equal(numpy.asarray(bad[0]),
+                                         numpy.asarray(clean[0]))
+        for a, e in zip(bad[1:], clean[1:]):    # the NaN page is the last
+            numpy.testing.assert_array_equal(numpy.asarray(a)[:-1],
+                                             numpy.asarray(e)[:-1])
 
     def test_batched_lanes_install_their_own_pages(self):
         from veles_tpu.ops import pallas_kernels as PK

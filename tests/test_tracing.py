@@ -736,6 +736,49 @@ class TestLoopRecorder:
             assert 0 <= r.lane < engine.slots
         assert sum(r.tokens_out for r in reqs) == counters["tokens_out"]
 
+    @pytest.mark.parametrize("driver", sorted(DRIVERS))
+    def test_page_steps_of_every_driver_reach_the_reader(self, driver):
+        """ISSUE 29: every decode driver's dispatches through the kernels
+        count the page steps they were handed and the live ones (a fused
+        program's at the positions it entered with, once per step it
+        ran); the recorder's two columns sum to the counters, and the
+        benchmark's reader gives the dead share of the window's turns.
+        A program whose recorder has no such columns reads None."""
+        import types
+        from benchmark.lib.files import load_module
+        from veles_tpu.serving import tracing
+        engine, outs = self._serve(attn_kernel="force",
+                                   **self.DRIVERS[driver])
+        expect = greedy_rows(tiny_params(), self.PROMPTS, self.N_NEW)
+        for p, out, exp in zip(self.PROMPTS, outs, expect):
+            numpy.testing.assert_array_equal(
+                numpy.concatenate([p, out]), exp)
+        c = engine.metrics.snapshot()["counters"]
+        given, live = c["attn_page_steps"], c["attn_page_steps_live"]
+        assert 0 < live < given
+        assert c["attn_kernel_dispatches"] \
+            == c["decode_dispatches"] + c["prefill_dispatches"]
+        turns = engine.recorder.turns()
+        assert int(turns[:, tracing.COL_ATTN_STEPS].sum()) == given
+        assert int(turns[:, tracing.COL_ATTN_LIVE].sum()) == live
+        # only turns that dispatched a program hold any
+        quiet = (turns[:, tracing.COL_STEP_PROGRAM] == 0) \
+            & (turns[:, tracing.COL_PREFILL_PROGRAM] == 0)
+        assert not turns[quiet, tracing.COL_ATTN_STEPS].any()
+        first, last = turns[0, tracing.COL_STAMPS], turns[-1, tracing.COL_END]
+        art = {"t_open": (first - 1) / 1e9, "window_s": (last - first + 2) / 1e9,
+               "_spans_recorder": {"recorder": engine.recorder,
+                                   "turns": turns, "tracing": tracing}}
+        read = load_module(
+            "layer_metrics", "attn_dead_page_steps_share.serve").read
+        assert read(art, None) == pytest.approx(
+            100.0 * (1.0 - live / given))
+        older = types.SimpleNamespace(**{
+            k: v for k, v in vars(tracing).items()
+            if not k.startswith("COL_ATTN")})
+        art["_spans_recorder"]["tracing"] = older
+        assert read(art, None) is None
+
     def test_standby_ring_requests_are_recorded(self):
         """The while driver's refill ring admits outside the slot array:
         its requests still leave ordered records whose token stamps sum

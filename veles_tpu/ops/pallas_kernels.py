@@ -47,6 +47,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy
 
 # the XLA reference path's finite masking constant — the kernels MUST
 # share it exactly: the all-masked-block rescale argument in
@@ -430,6 +431,58 @@ def _band(k_pos, q_pos, window, sinks, base):
     return live
 
 
+def live_pages(pos, c, page, m_pages, window=None, sinks=0, xp=jnp):
+    """The pages of a lane's table that hold a key some query row may see:
+    ``(first, last, sink)``.  Page ``j`` of ``m_pages`` is LIVE if and only
+    if ``j <= last and (j >= first or j < sink)``; for every other page
+    ``_band`` is false on every (query row, key) pair, so the kernels
+    neither fetch it nor run a softmax step on it.
+
+    ``c`` query rows at ``pos .. pos + c - 1`` see keys ``k <= q`` (decode
+    and verify); ``c = 0`` is the prefill kernel's history, the keys
+    strictly below the chunk's frontier ``pos`` (no key at all at
+    ``pos == 0``: ``last`` is then -1).  Either way the last live page is
+    the one position ``pos + c - 1`` lies in, and with a window the first
+    is the page of ``pos - window + 1``, the lower edge of the FIRST query
+    row (later rows see no further back), clipped at 0.  The ``sink``
+    pages at the table's head (a static count) stay live through the
+    window's sinks.  ``last`` is clipped to the table, and a range may be
+    empty (``first > last``).
+
+    ``pos`` is table-relative like the kernels' own: the lanes' traced
+    positions in the kernels' wrappers, a numpy array on the host
+    (``xp=numpy``: the engine's count of page steps)."""
+    last = xp.minimum((pos + c + page - 1) // page, m_pages) - 1
+    if not window:
+        return xp.zeros_like(last), last, 0
+    first = xp.maximum(pos - window + 1, 0) // page
+    return first, last, -(-sinks // page)
+
+
+def live_page_count(first, last, sink):
+    """How many pages ``live_pages`` calls live, per lane (numpy: the
+    host's side of the rule ``_is_live`` applies in the kernels)."""
+    return (numpy.maximum(last - first + 1, 0)
+            + numpy.minimum(numpy.minimum(sink, first), last + 1))
+
+
+def _is_live(j, first, last, sink):
+    """Is page ``j`` of a lane's table live (``live_pages``; ``first`` and
+    ``last`` the lane's own, scalars)."""
+    live = (j >= first) & (j <= last)
+    return live | ((j < sink) & (j <= last)) if sink else live
+
+
+def _live_entry(j, first, last, sink):
+    """The table entry whose page grid step ``j`` of a lane names: its own
+    if the page is live, else the nearest live one's (entry 0 for a lane
+    with none).  A dead step so names the block a neighbouring live step
+    names, and the pipeline issues no copy for it."""
+    if sink:
+        first = jnp.where(j < sink, 0, first)
+    return jnp.maximum(jnp.minimum(jnp.maximum(j, first), last), 0)
+
+
 def paged_flash_decode(q, k_pool, v_pool, ptab, pos, window=None,
                        sinks=0, interpret=None):
     """Flash-decode over the paged KV pool: ``c`` query positions per
@@ -448,6 +501,14 @@ def paged_flash_decode(q, k_pool, v_pool, ptab, pos, window=None,
     XLA ``mha_paged_chunk_step`` path to fp32 roundoff (the greedy
     argmax downstream is what the serving parity matrix pins).
 
+    The grid is (lanes, table width) whatever the lanes hold; a step
+    whose page no query row of its lane can see (beyond the frontier,
+    behind the window: ``live_pages``) runs no softmax step and names the
+    block of the nearest live step, so it costs no copy either — what a
+    dead table entry holds never reaches the kernel.  The result is the
+    same bits: a fully masked block contributed an exact 0.0
+    (``_flash_step``).
+
     Returns (b, h, c, dh)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -460,8 +521,15 @@ def paged_flash_decode(q, k_pool, v_pool, ptab, pos, window=None,
     rows = r * g * c            # query rows per pool row
     qp = _pack_queries(q.reshape(b, kvp * r, g * c, dh), r)
 
-    def kernel(ptab_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-               acc_ref, l_ref, m_ref):
+    # the lanes' live ranges, computed once a call in the program around
+    # the kernel and prefetched: the two index maps and the body only
+    # compare against them (each is traced anew for every layer of every
+    # program, and that time is set-up time)
+    first, last, sink = live_pages(jnp.asarray(pos, jnp.int32), c, page,
+                                   m_pages, window, sinks)
+
+    def kernel(ptab_ref, pos_ref, first_ref, last_ref, q_ref, k_ref, v_ref,
+               o_ref, acc_ref, l_ref, m_ref):
         i, j = pl.program_id(0), pl.program_id(1)
 
         @pl.when(j == 0)
@@ -471,32 +539,37 @@ def paged_flash_decode(q, k_pool, v_pool, ptab, pos, window=None,
             m_ref[...] = jnp.full_like(m_ref, NEG_INF)
 
         pos = pos_ref[i]
-        k_pos = j * page + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page), 1)
-        q_pos = pos + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page), 0) % c
-        live = _band(k_pos, q_pos, window, sinks, k_pos <= q_pos)
-        _flash_step(q_ref[0], k_ref[0], v_ref[0], live, dh,
-                    acc_ref, l_ref, m_ref)
+
+        @pl.when(_is_live(j, first_ref[i], last_ref[i], sink))
+        def _():
+            k_pos = j * page + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, page), 1)
+            q_pos = pos + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, page), 0) % c
+            live = _band(k_pos, q_pos, window, sinks, k_pos <= q_pos)
+            _flash_step(q_ref[0], k_ref[0], v_ref[0], live, dh,
+                        acc_ref, l_ref, m_ref)
 
         @pl.when(j == m_pages - 1)
         def _():
             o_ref[0] = (acc_ref[...]
                         / l_ref[...][..., None]).astype(o_ref.dtype)
 
+    def lane(i, j, *_):
+        return (i, 0, 0, 0)
+
+    def history(i, j, pt, ps, fs, ls):
+        return (pt[i, _live_entry(j, fs[i], ls[i], sink)], 0, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=4,
         grid=(b, m_pages),
         in_specs=[
-            pl.BlockSpec((1, kvp, rows, lanes),
-                         lambda i, j, pt, ps: (i, 0, 0, 0)),
-            pl.BlockSpec((1, kvp, page, lanes),
-                         lambda i, j, pt, ps: (pt[i, j], 0, 0, 0)),
-            pl.BlockSpec((1, kvp, page, lanes),
-                         lambda i, j, pt, ps: (pt[i, j], 0, 0, 0)),
+            pl.BlockSpec((1, kvp, rows, lanes), lane),
+            pl.BlockSpec((1, kvp, page, lanes), history),
+            pl.BlockSpec((1, kvp, page, lanes), history),
         ],
-        out_specs=pl.BlockSpec((1, kvp, rows, lanes),
-                               lambda i, j, pt, ps: (i, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, kvp, rows, lanes), lane),
         scratch_shapes=[pltpu.VMEM((kvp, rows, lanes), jnp.float32),
                         pltpu.VMEM((kvp, rows), jnp.float32),
                         pltpu.VMEM((kvp, rows), jnp.float32)],
@@ -506,7 +579,7 @@ def paged_flash_decode(q, k_pool, v_pool, ptab, pos, window=None,
         out_shape=jax.ShapeDtypeStruct((b, kvp, rows, lanes), q.dtype),
         interpret=_interpret(interpret),
     )(jnp.asarray(ptab, jnp.int32), jnp.asarray(pos, jnp.int32),
-      qp, k_pool, v_pool)
+      first, last, qp, k_pool, v_pool)
     return _unpack_outputs(o, r).reshape(b, h, c, dh)
 
 
@@ -547,6 +620,12 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
     page's (6 query heads of 128 over a 256-token chunk are 1536 query
     rows per kv head, 1.5 MB of float32 scores a head and page).
 
+    Of the table's pages only the live history (``live_pages`` with no
+    query row: below the frontier, inside the window) is fetched and
+    stepped over, as in :func:`paged_flash_decode`; the first and the
+    last step of a lane (the accumulator's reset; the chunk's own block,
+    the output and the install) run whatever its depth.
+
     Caller contract (the engine's chunk program guarantees both):
     ``pos`` is page-aligned and the chunk occupies exactly the pool
     page ``ptab[i, pos // page]`` — a fresh, unshared page (COW has
@@ -568,8 +647,12 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
     hb = _heads_per_step(kvp, rows, page)
     grid = (b, m_pages) if hb == kvp else (b, kvp // hb, m_pages)
 
-    def kernel(ptab_ref, pos_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
-               o_ref, ko_ref, vo_ref, acc_ref, l_ref, m_ref):
+    first, last, sink = live_pages(jnp.asarray(pos, jnp.int32), 0, page,
+                                   m_pages, window, sinks)
+
+    def kernel(ptab_ref, pos_ref, first_ref, last_ref, q_ref, kn_ref,
+               vn_ref, k_ref, v_ref, o_ref, ko_ref, vo_ref, acc_ref, l_ref,
+               m_ref):
         i, j = pl.program_id(0), pl.program_id(len(grid) - 1)
 
         @pl.when(j == 0)
@@ -579,15 +662,19 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
             m_ref[...] = jnp.full_like(m_ref, NEG_INF)
 
         pos = pos_ref[i]
-        q_rows = jax.lax.broadcasted_iota(jnp.int32, (rows, page), 0) % c
+
         # history page j: live strictly below the chunk frontier (the
         # chunk's own page sits in the pool UNWRITTEN — its rows come
         # from the VMEM operands in the epilogue)
-        k_pos = j * page + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page), 1)
-        live = _band(k_pos, pos + q_rows, window, sinks, k_pos < pos)
-        _flash_step(q_ref[0], k_ref[0], v_ref[0], live, dh,
-                    acc_ref, l_ref, m_ref)
+        @pl.when(_is_live(j, first_ref[i], last_ref[i], sink))
+        def _():
+            q_rows = jax.lax.broadcasted_iota(
+                jnp.int32, (rows, page), 0) % c
+            k_pos = j * page + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, page), 1)
+            live = _band(k_pos, pos + q_rows, window, sinks, k_pos < pos)
+            _flash_step(q_ref[0], k_ref[0], v_ref[0], live, dh,
+                        acc_ref, l_ref, m_ref)
 
         @pl.when(j == m_pages - 1)
         def _():
@@ -606,7 +693,8 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
             ko_ref[0] = kn_ref[0]
             vo_ref[0] = vn_ref[0]
 
-    # index maps over (lane, [head block,] page, page table, positions)
+    # index maps over (lane, [head block,] page, page table, positions,
+    # first and last live page)
     def head_block(idx):
         return idx[1] if len(grid) == 3 else 0
 
@@ -614,15 +702,16 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
         return (idx[0], head_block(idx), 0, 0)
 
     def history(*idx):
-        (i, j), pt = (idx[0], idx[-3]), idx[-2]
-        return (pt[i, j], head_block(idx), 0, 0)
+        (i, j), (pt, _, fs, ls) = (idx[0], idx[-5]), idx[-4:]
+        return (pt[i, _live_entry(j, fs[i], ls[i], sink)],
+                head_block(idx), 0, 0)
 
     def tgt(*idx):
-        i, pt, ps = idx[0], idx[-2], idx[-1]
+        i, pt, ps = idx[0], idx[-4], idx[-3]
         return (pt[i, ps[i] // page], head_block(idx), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=4,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, hb, rows, lanes), lane),
@@ -645,12 +734,13 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
         out_shape=(jax.ShapeDtypeStruct((b, kvp, rows, lanes), q.dtype),
                    jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
                    jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)),
-        # aliased in-place pool update: operand indices INCLUDE the two
-        # scalar-prefetch args, so k_pool/v_pool are operands 5/6
-        input_output_aliases={5: 1, 6: 2},
+        # aliased in-place pool update: operand indices INCLUDE the four
+        # scalar-prefetch args, so k_pool/v_pool are operands 7/8
+        input_output_aliases={7: 1, 8: 2},
         interpret=_interpret(interpret),
     )(jnp.asarray(ptab, jnp.int32), jnp.asarray(pos, jnp.int32),
-      qp, pack_heads(k_new, r), pack_heads(v_new, r), k_pool, v_pool)
+      first, last, qp, pack_heads(k_new, r), pack_heads(v_new, r), k_pool,
+      v_pool)
     return _unpack_outputs(o, r).reshape(b, h, c, dh), k_out, v_out
 
 

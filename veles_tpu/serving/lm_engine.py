@@ -85,7 +85,11 @@ everywhere else (off-TPU, contiguous KV layout, unsupported geometry
 mode — the parity tests' end-to-end gear, far too slow for traffic).
 Decode/verify additionally slice the page table to the LIVE width
 ladder (``_live_width``): a step pays for the pages the batch actually
-occupies, one program per power-of-two ladder entry.
+occupies, one program per power-of-two ladder entry.  Inside the
+kernels a page no query of its lane can see costs neither a fetch nor a
+softmax step (ISSUE 29); the host counts what each dispatch handed them
+and what of it was live (``attn_page_steps`` / ``attn_page_steps_live``,
+:meth:`LMEngine._note_attn_dispatch`).
 
 SHARDED SERVING (ISSUE 8, ``tp=N``) runs every program above under a
 one-axis ``('tp',)`` mesh: weights are head-/column-sharded by
@@ -888,6 +892,11 @@ class LMEngine(Logger):
                                    head_dim)
         self._storage_dtype = embed.dtype
         self._set_storage(self._zero_storage())
+        #: (cache kind, its layers) for the count of the attention
+        #: kernels' page steps (:meth:`_note_attn_dispatch`)
+        self._layers_of_kind = sorted(collections.Counter(
+            self.cfg.kind(i) for i in range(len(self.params["blocks"]))
+        ).items())
         self._trie = (RadixPrefixCache(
             prefix_cache, self.prefill_chunk,
             on_evict=self._pool.release if self._paged else None)
@@ -2811,15 +2820,48 @@ class LMEngine(Logger):
         self.metrics.set_gauge_max("moe_max_expert_load", load)
         self.recorder.moe(held, away, hit, load)
 
-    def _note_attn_dispatch(self):
+    def _note_attn_dispatch(self, pos=None, width=0, span=0,
+                            rows=slice(None), calls=1):
         """Per-dispatch kernel accounting (ISSUE 7): which path the
         engine's attention actually took.  Only metered when the caller
         ASKED for kernels — an untouched engine carries no new
-        counters."""
-        if self.attn_kernel:
-            self.metrics.inc("attn_kernel_dispatches"
-                             if self._kernel_active
-                             else "attn_kernel_fallbacks")
+        counters.
+
+        A dispatch through the kernels also counts the page steps it
+        handed them and the live ones among them (ISSUE 29), the
+        counters ``attn_page_steps`` / ``attn_page_steps_live`` and the
+        recorder's open turn: ``pos`` the positions of the lanes
+        ``rows`` as the program got them, ``width`` its table's,
+        ``span`` the query rows a lane (0: a prefill chunk, whose kernel
+        walks the history below ``pos``), ``calls`` the steps of a
+        fused program (counted at the positions it entered with).  Host
+        integers over at most ``slots`` lanes, by the kernels' own
+        ``live_pages``."""
+        if not self.attn_kernel:
+            return
+        self.metrics.inc("attn_kernel_dispatches" if self._kernel_active
+                         else "attn_kernel_fallbacks")
+        if pos is None or not self._kernel_active:
+            return
+        from veles_tpu.ops.pallas_kernels import (live_page_count,
+                                                  live_pages)
+        pos = numpy.atleast_1d(pos).astype(numpy.int64)
+        given = live = 0
+        for kind, layers in self._layers_of_kind:
+            p, w = pos, width
+            if self._wt is not None and kind == model_config.SLIDING:
+                # the sliding kind's short table begins at its base
+                p = pos - self._wt.base[rows] * self._wt.page
+                w = min(width, self._wt.width)
+            window = (self.cfg.window if self._wt is None
+                      or kind == model_config.SLIDING else None)
+            live += layers * int(live_page_count(*live_pages(
+                p, span, self.prefill_chunk, w, window, self.sinks,
+                xp=numpy)).sum())
+            given += layers * p.size * w
+        self.metrics.inc("attn_page_steps", calls * given)
+        self.metrics.inc("attn_page_steps_live", calls * live)
+        self.recorder.attn_pages(calls * given, calls * live)
 
     def kv_bytes_resident(self):
         """Device bytes held for KV storage — the pool (paged) or the
@@ -3022,7 +3064,7 @@ class LMEngine(Logger):
             self._teardown_slot(slot, lane, e)
             return
         self.metrics.inc("prefill_dispatches")
-        self._note_attn_dispatch()
+        self._note_attn_dispatch(start, self._max_pages, rows=slot)
         self.metrics.inc("prefill_tokens",
                          (req.true_len - start) if is_tail
                          else len(tokens))
@@ -3259,7 +3301,7 @@ class LMEngine(Logger):
         self.metrics.record_dispatch(len(active))
         self.metrics.record_decode_step(time.monotonic() - t0)
         self.metrics.inc("decode_dispatches")
-        self._note_attn_dispatch()
+        self._note_attn_dispatch(self._pos, w, 1)
         if self._tracer is not None:
             self._tracer.add_many(
                 tctxs, "decode.step", "decode", t0, time.monotonic(),
@@ -3337,7 +3379,7 @@ class LMEngine(Logger):
         self.metrics.record_dispatch(len(active))
         self.metrics.record_decode_step(time.monotonic() - t0)
         self.metrics.inc("decode_dispatches")
-        self._note_attn_dispatch()
+        self._note_attn_dispatch(self._pos, w, k + 1)
         if self._tracer is not None:
             self._tracer.add_many(
                 tctxs, "decode.verify", "decode", t0, time.monotonic(),
@@ -3433,6 +3475,7 @@ class LMEngine(Logger):
             self._fail_active(active, e)
             return
         t1 = time.monotonic()
+        entered = self._pos
         # sync the host frontiers from the program's final carry
         # (frozen lanes returned their entry values, so this is a
         # wholesale assignment)
@@ -3465,7 +3508,7 @@ class LMEngine(Logger):
         self.metrics.record_decode_step(t1 - t0)
         self.metrics.inc("decode_dispatches")
         self.metrics.record_megastep(K, len(active), total, wasted)
-        self._note_attn_dispatch()
+        self._note_attn_dispatch(entered, w, k + 1, calls=K)
         if self._tracer is not None:
             # ONE decode.megastep span per dispatch, shared did so the
             # cost ledger counts the fused program once — never the
@@ -3616,6 +3659,7 @@ class LMEngine(Logger):
             return
         t1 = time.monotonic()
         iters = int(iters)
+        entered = self._pos
         self._pos = numpy.array(pos, numpy.int32)
         self._last = numpy.array(last, numpy.int32)
         armed = {}                      # slot -> entries, in arm order
@@ -3662,7 +3706,7 @@ class LMEngine(Logger):
         # what the early exit actually saved
         self.metrics.record_megastep(iters, len(participants), total,
                                      wasted)
-        self._note_attn_dispatch()
+        self._note_attn_dispatch(entered, w, k + 1, calls=iters)
         if self._tracer is not None:
             self._tracer.add_many(
                 tctxs, "decode.megastep", "decode", t0, t1,
@@ -3854,7 +3898,7 @@ class LMEngine(Logger):
             self._fail_standby(entry, e)
             return
         self.metrics.inc("prefill_dispatches")
-        self._note_attn_dispatch()
+        self._note_attn_dispatch(start, self._max_pages)
         self.metrics.inc("prefill_tokens",
                          (req.true_len - start) if is_tail
                          else len(tokens))

@@ -756,7 +756,13 @@ COL_MOE_HELD = COL_END + 7
 COL_MOE_ELSEWHERE = COL_END + 8
 COL_MOE_HIT = COL_END + 9
 COL_MOE_LOAD = COL_END + 10
-TURN_WIDTH = COL_END + 11
+#: the page steps the turn's dispatches handed the attention kernels
+#: (lanes x table width, summed over layers, chunk and decode program
+#: alike) and those of them whose page holds a key a query row may see
+#: (``ops/pallas_kernels.py::live_pages``); the others the kernels skip
+COL_ATTN_STEPS = COL_END + 11
+COL_ATTN_LIVE = COL_END + 12
+TURN_WIDTH = COL_END + 13
 #: the column that holds the program a dispatch phase called
 _PROGRAM_COL = {PREFILL_DISPATCH: COL_PREFILL_PROGRAM,
                 STEP_DISPATCH: COL_STEP_PROGRAM}
@@ -905,6 +911,13 @@ class LoopRecorder:
         cur[COL_MOE_ELSEWHERE] = elsewhere
         cur[COL_MOE_HIT] = hit
         cur[COL_MOE_LOAD] = load
+
+    def attn_pages(self, given, live):
+        """One more dispatch of the open turn through the attention
+        kernels (a turn may hold a chunk and a decode step)."""
+        cur = self._cur
+        cur[COL_ATTN_STEPS] += given
+        cur[COL_ATTN_LIVE] += live
 
     def emitted(self, request, n):
         """``n`` tokens of ``request`` reached the host now: the stamp of
