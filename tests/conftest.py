@@ -124,7 +124,8 @@ _WITNESSED_SUITES = frozenset((
 #: implicit transfers exactly where they matter: inside the serving
 #: loop and warmup, not in test-helper host math.
 _TRANSFER_GUARDED_SUITES = frozenset((
-    "test_serving", "test_lm_fastpath", "test_kv_pool",
+    "test_serving", "test_lm_fastpath", "test_lm_paged",
+    "test_lm_kernels", "test_lm_megastep", "test_kv_pool",
 ))
 
 

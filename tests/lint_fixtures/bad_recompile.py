@@ -25,20 +25,3 @@ class FakeEngine:
         self._decode_jit = self._jit(step)     # EXPECT-LINT recompile-hazard
         # programs: verify
         self._verify_jit = self._jit(step)     # EXPECT-LINT recompile-hazard
-
-    def _build_while(self):
-        import jax
-
-        def cond(c):
-            return c < 4
-
-        def body(c):
-            return c + 1
-
-        # An uncensused resident loop program (ISSUE 19): a while
-        # twin with no census family entry at all, then one whose
-        # named family no jit site installs.
-        loop = jax.lax.while_loop(cond, body, 0)   # EXPECT-LINT recompile-hazard
-        # programs: phantom
-        twin = jax.lax.while_loop(cond, body, 0)   # EXPECT-LINT recompile-hazard
-        return loop, twin

@@ -17,27 +17,11 @@ import jax
 import jax.numpy as jnp
 
 from benchmark.reference import afmoe
+from lm_cases import SMALL, record
 from veles_tpu import model_config
 from veles_tpu.serving.kv_pool import KVPagePool, WindowTables
 
-SMALL = {
-    "model_type": "afmoe", "hidden_size": 64, "num_attention_heads": 6,
-    "num_key_value_heads": 2, "head_dim": 16, "intermediate_size": 160,
-    "moe_intermediate_size": 48, "vocab_size": 96, "num_hidden_layers": 4,
-    "num_dense_layers": 1,
-    "layer_types": ["sliding_attention", "sliding_attention",
-                    "full_attention", "sliding_attention"],
-    "num_experts": 4, "router_width": 16, "held_experts": [4, 4],
-    "num_experts_per_tok": 3, "sliding_window": 8, "rope_theta": 10000,
-    "rms_norm_eps": 1e-5, "route_scale": 2.448, "route_norm": True,
-    "score_func": "sigmoid", "num_shared_experts": 1,
-    "initializer_std": 0.1, "max_position_embeddings": 64,
-}
 PAGE = 4
-
-
-def record(cfg=SMALL, dtype="float32"):
-    return model_config.from_published(dict(cfg, dtype=dtype))
 
 
 @pytest.fixture(scope="module")
@@ -318,9 +302,7 @@ def test_window_tables_accounting():
 @pytest.mark.parametrize("option,match", [
     ({"prefix_cache": 8}, "prefix_cache"), ({"spec_k": 2}, "spec_k"),
     ({"megastep": 4}, "megastep"),
-    ({"megastep": "while", "refill_ring": 2}, "megastep"),
-    ({"tp": 2}, "tp >= 2"), ({"paged_kv": 0}, "expert layer needs paged"),
-    ({"temperature": 0.7, "sample_seed": 1}, "temperature")])
+    ({"tp": 2}, "tp >= 2"), ({"paged_kv": 0}, "expert layer needs paged")])
 def test_what_was_not_widened_says_so(weights, option, match):
     from veles_tpu.serving import LMEngine
     with pytest.raises(ValueError, match=match):

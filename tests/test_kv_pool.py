@@ -17,18 +17,8 @@ import time
 import numpy
 import pytest
 
+from lm_cases import _params, assert_greedy, served_model
 from veles_tpu.serving.kv_pool import KVPagePool
-
-
-def _params(max_len=96, vocab=16, n_heads=2, n_layers=2, d_model=32):
-    import jax
-    import jax.numpy as jnp
-    from veles_tpu import prng
-    from veles_tpu.ops.transformer import init_transformer_params
-    host = init_transformer_params(prng.get("init"), vocab,
-                                   d_model=d_model, n_heads=n_heads,
-                                   n_layers=n_layers, max_len=max_len)
-    return jax.tree.map(jnp.asarray, host)
 
 
 class TestPoolUnit:
@@ -128,31 +118,66 @@ class TestTrieEvictionReleasesPages:
         assert pool.free_pages == 4
 
 
+def _paged_engine(caches, name, **kw):
+    """A paged engine over ``caches`` kinds of cache: ``one`` is the
+    ``pre_ln`` model; ``two`` is the small ``sandwich`` model (sliding
+    and full layers: a table and an allocator each)."""
+    from veles_tpu.serving import LMEngine
+    record, params, max_len = served_model(caches == "two")
+    return LMEngine(params, record, max_len=max_len, prefill_chunk=8,
+                    name=name, **kw)
+
+
+def _trie(caches, capacity):
+    """The prefix cache's keyword for ``one`` kind of cache; none for
+    ``two``: that engine refuses it, and its legs say what they check
+    in the trie's place."""
+    return {"prefix_cache": capacity} if caches == "one" else {}
+
+
+def _home_whole(engine):
+    """No lane holds anything: the cross-check is clean, no page is
+    pinned, and what the trie does not hold is free — in the sliding
+    layers' allocator every page."""
+    engine.verify_pool_invariants()
+    assert engine._pool.pinned_pages == 0
+    held = engine._trie.size if engine._trie is not None else 0
+    assert engine._pool.free_pages + held == engine._pool.num_pages
+    if engine._wt is not None:
+        window = engine._wt.pool
+        assert window.free_pages == window.num_pages
+
+
+CACHES = pytest.mark.parametrize("caches", ["one", "two"])
+
+
 class TestEngineLifecycle:
-    def test_refcount_release_on_lane_finish(self):
+    @CACHES
+    def test_refcount_release_on_lane_finish(self, caches):
         """Two shared-prefix requests through a paged engine: while the
         trie holds the shared chunks their pages stay allocated (refs
         from the trie), every lane-owned page returns to the free list
         at finish, and evicting the trie drains the pool back to
-        FULL — no page leaks across the request lifecycle."""
-        from veles_tpu.serving import LMEngine
-        params = _params()
+        FULL — no page leaks across the request lifecycle.  Two kinds
+        of cache (no trie): both allocators are whole at finish."""
         rng = numpy.random.RandomState(7)
         shared = rng.randint(0, 16, 16).tolist()     # 2 full chunks
         prompts = [shared + rng.randint(0, 16, 3).tolist()
                    for _ in range(2)]
-        engine = LMEngine(params, n_heads=2, max_len=96, slots=2,
-                          paged_kv=True, prefill_chunk=8,
-                          prefix_cache=16, name="kv_life").start()
+        engine = _paged_engine(caches, "kv_life", slots=2, paged_kv=True,
+                               **_trie(caches, 16)).start()
         try:
             for p in prompts:
                 engine.submit(p, 4).result(timeout=60)
+            _home_whole(engine)
             pool, trie = engine._pool, engine._trie
-            # only the trie's references remain
-            assert pool.used_pages == trie.size == 2
-            assert pool.pinned_pages == 0            # no active lane
-            while trie.evict_one():
-                pass
+            if caches == "one":
+                # only the trie's references remain
+                assert pool.used_pages == trie.size == 2
+                while trie.evict_one():
+                    pass
+            else:
+                assert trie is None
             assert pool.free_pages == pool.num_pages
         finally:
             engine.stop()
@@ -220,33 +245,29 @@ class TestEngineLifecycle:
         engine._cow_guard(0, lane, 1, 2)
         assert engine.metrics.counter("kv_cow_copies") == 1
 
-    @pytest.mark.slow
-    def test_sustained_pool_churn_no_leaks(self):
-        """SLOW: sustained pool-stress — 32 mixed-length requests
-        (some sharing a prefix, some unique) churn through a pool far
-        smaller than their total demand, with trie eviction reclaiming
-        pages throughout.  Every request completes exactly greedy, and
-        the pool drains back to FULL once the trie is emptied — no
-        page leaks under sustained pressure."""
-        import jax
-        import jax.numpy as jnp
-        from veles_tpu.ops.transformer import generate
-        from veles_tpu.serving import LMEngine
-        params = _params()
+    @pytest.mark.parametrize("caches,requests", [
+        pytest.param("one", 32, marks=pytest.mark.slow), ("two", 8)])
+    def test_sustained_pool_churn_no_leaks(self, caches, requests):
+        """Sustained pool-stress (SLOW for one kind of cache) — 32
+        mixed-length requests (8 of them for two kinds: that model's
+        step is the costlier), some sharing a prefix, some unique,
+        churn through a pool far smaller than their total demand, with
+        trie eviction reclaiming pages throughout.  Every request
+        completes exactly greedy, and the pool drains back to FULL once
+        the trie is emptied — no page leaks under sustained pressure.
+        Two kinds of cache: the sliding layers' pages come and go with
+        every window crossed, and both allocators end whole."""
         rng = numpy.random.RandomState(11)
         shared = rng.randint(0, 16, 16).tolist()
         prompts = []
-        for i in range(32):
+        for i in range(requests):
             tail = rng.randint(0, 16, rng.randint(1, 24)).tolist()
             prompts.append((shared + tail) if i % 2 else tail)
-        expected = [numpy.asarray(generate(
-            params, jnp.asarray([p], jnp.int32), 6, 2,
-            temperature=0.0, max_len=96))[0] for p in prompts]
         from veles_tpu.serving import PoolExhausted
-        engine = LMEngine(params, n_heads=2, max_len=96, slots=4,
-                          paged_kv=10, prefill_chunk=8, prefix_cache=4,
-                          queue_depth=64, deadline_s=120.0,
-                          name="kv_churn").start()
+        engine = _paged_engine(caches, "kv_churn", slots=4, paged_kv=10,
+                               queue_depth=64, deadline_s=120.0,
+                               **_trie(caches, 4))
+        engine.start()
         try:
             futures = []
             for p in prompts:
@@ -260,51 +281,49 @@ class TestEngineLifecycle:
                         time.sleep(min(e.retry_after, 0.05))
                 else:
                     raise AssertionError("submit never admitted")
-            for p, f, exp in zip(prompts, futures, expected):
-                got = numpy.concatenate([p, f.result(timeout=300)])
-                numpy.testing.assert_array_equal(got, exp)
+            for p, f in zip(prompts, futures):
+                assert_greedy(engine, p, f.result(timeout=300), 6)
+            _home_whole(engine)
             pool, trie = engine._pool, engine._trie
-            assert pool.pinned_pages == 0
-            assert pool.used_pages == trie.size <= 4
-            while trie.evict_one():
-                pass
+            if caches == "one":
+                assert pool.used_pages == trie.size <= 4
+                while trie.evict_one():
+                    pass
+            else:
+                assert trie is None
+                assert engine.metrics.counter(
+                    "kv_pages_released_window") > 0
             assert pool.free_pages == pool.num_pages
         finally:
             engine.stop()
 
-    def test_mid_prefill_faults_leak_no_pages(self):
+    @CACHES
+    def test_mid_prefill_faults_leak_no_pages(self, caches):
         """ISSUE 10 satellite: injected mid-prefill dispatch failures
         (the engine.chunk site) across several shared-prefix requests
         — every faulted request fails alone, the survivors stay
         exactly greedy, and afterwards the pool returns to baseline
-        with zero orphan trie pins and the allocator invariants
-        intact."""
-        import jax.numpy as jnp
-        from veles_tpu.ops.transformer import generate
-        from veles_tpu.serving import FaultPlan, InjectedFault, LMEngine
-        params = _params()
+        with zero orphan trie pins (one kind of cache: the engine with
+        the trie) and the allocator invariants intact (of both
+        allocators, for two kinds of cache)."""
+        from veles_tpu.serving import FaultPlan, InjectedFault
         rng = numpy.random.RandomState(3)
         shared = rng.randint(0, 16, 16).tolist()     # 2 full chunks
         prompts = [shared + rng.randint(0, 16, 1 + i).tolist()
                    for i in range(6)]
-        expected = [numpy.asarray(generate(
-            params, jnp.asarray([p], jnp.int32), 4, 2,
-            temperature=0.0, max_len=96))[0] for p in prompts]
         # every 3rd chunk dispatch faults — mid-prefill, because these
         # prompts are almost all prefill chunks
         plan = FaultPlan().arm("engine.chunk", every=3)
-        engine = LMEngine(params, n_heads=2, max_len=96, slots=2,
-                          paged_kv=True, prefill_chunk=8,
-                          prefix_cache=16, name="kv_fault",
-                          faults=plan).start()
+        engine = _paged_engine(caches, "kv_fault", slots=2,
+                               paged_kv=True, faults=plan,
+                               **_trie(caches, 16))
+        engine.start()
         try:
             futures = [engine.submit(p, 4) for p in prompts]
             failed = ok = 0
-            for p, f, exp in zip(prompts, futures, expected):
+            for p, f in zip(prompts, futures):
                 try:
-                    out = f.result(timeout=60)
-                    numpy.testing.assert_array_equal(
-                        numpy.concatenate([p, out]), exp)
+                    assert_greedy(engine, p, f.result(timeout=60), 4)
                     ok += 1
                 except InjectedFault:
                     failed += 1
@@ -312,11 +331,11 @@ class TestEngineLifecycle:
             assert plan.fired("engine.chunk") >= failed
             # leak-freedom: no lane active, no orphan pins, and once
             # the trie is pressed empty the pool refills WHOLE
-            assert engine._pool.pinned_pages == 0
-            assert engine._trie.live_pins() == 0
-            engine.verify_pool_invariants()
-            while engine._trie.evict_one():
-                pass
+            _home_whole(engine)
+            if caches == "one":
+                assert engine._trie.live_pins() == 0
+                while engine._trie.evict_one():
+                    pass
             assert engine._pool.free_pages == engine._pool.num_pages
         finally:
             engine.stop()
@@ -355,16 +374,15 @@ class TestEngineLifecycle:
         assert q != p and pool.refs(q) == 1 and pool.pinned(q)
         assert engine.metrics.counter("kv_cow_copies") == 1
 
-    def test_pool_exhaustion_sheds_503_never_hangs(self):
+    @CACHES
+    def test_pool_exhaustion_sheds_503_never_hangs(self, caches):
         """A request queued on pool pressure whose pages never free in
         time sheds DeadlineExceeded (503) at its deadline — it does not
         wedge the queue, and the lane holding the pool finishes
         normally."""
-        from veles_tpu.serving import DeadlineExceeded, LMEngine
-        params = _params()
-        engine = LMEngine(params, n_heads=2, max_len=96, slots=2,
-                          paged_kv=3, prefill_chunk=8, deadline_s=1.0,
-                          name="kv_shed").start()
+        from veles_tpu.serving import DeadlineExceeded
+        engine = _paged_engine(caches, "kv_shed", slots=2, paged_kv=3,
+                               deadline_s=1.0).start()
         real_step = engine._step_jit
 
         def slow_step(*a):
@@ -382,68 +400,10 @@ class TestEngineLifecycle:
             assert len(fut_a.result(timeout=60)) == 16
             assert engine.metrics.snapshot()["shed"] == 1
             assert engine._pool.free_pages == engine._pool.num_pages
+            _home_whole(engine)
         finally:
             engine._step_jit = real_step
             engine.stop()
-
-    def test_standby_ring_faults_and_cancel_leak_no_pages(self):
-        """ISSUE 19: standby-ring occupants hold pool pages exactly
-        like lanes — a faulted standby prefill (the engine.chunk site
-        firing on a ring entry) and a cancelled occupant both return
-        their pages immediately, and after the traffic drains the
-        pool refills whole with the engine's cross-check clean."""
-        from veles_tpu.serving import FaultPlan, InjectedFault, LMEngine
-        params = _params(max_len=128)
-        # armed only after fa's admission prefill is observed, so the
-        # one-shot rule deterministically lands on fb's standby-ring
-        # prefill no matter how the serve loop interleaves ticks
-        plan = FaultPlan()
-        engine = LMEngine(params, n_heads=2, max_len=128, slots=1,
-                          megastep=4, megastep_mode="while",
-                          paged_kv=True, prefill_chunk=8,
-                          refill_ring=2, faults=plan,
-                          name="kv_ring").start()
-        real = engine._whilestep_jit
-
-        def slow(*a):
-            time.sleep(0.05)
-            return real(*a)
-
-        engine._whilestep_jit = slow
-        try:
-            fa = engine.submit([1, 2, 3], 24)    # occupies the slot
-            deadline = time.monotonic() + 30.0
-            while plan.calls("engine.chunk") < 1:
-                assert time.monotonic() < deadline
-                time.sleep(0.01)
-            plan.arm("engine.chunk", kind="error", times=1)
-            fb = engine.submit([2, 4, 6], 6)     # standby prefill faults
-            with pytest.raises(InjectedFault):
-                fb.result(timeout=60)
-            engine.verify_pool_invariants()      # fb's pages came back
-            fc = engine.submit([4, 4, 4], 6)     # ring-prefilled, then
-            engine._cancel(fc.request)           # withdrawn in the ring
-            assert len(fa.result(timeout=120)) == 24
-            deadline = time.monotonic() + 30.0
-            while engine.metrics.snapshot()["gauges"].get(
-                    "standby_ring_occupancy", 0):
-                assert time.monotonic() < deadline
-                time.sleep(0.01)
-            assert fc.cancelled()
-            assert engine._pool.pinned_pages == 0
-            assert engine._pool.free_pages == engine._pool.num_pages
-            engine.verify_pool_invariants()
-        finally:
-            engine._whilestep_jit = real
-            engine.stop()
-
-
-def _greedy(params, prompt, n_new, max_len=96, n_heads=2):
-    import jax.numpy as jnp
-    from veles_tpu.ops.transformer import generate
-    return numpy.asarray(generate(
-        params, jnp.asarray([prompt], jnp.int32), n_new, n_heads,
-        temperature=0.0, max_len=max_len))[0]
 
 
 class TestStorageLost:
@@ -460,13 +420,17 @@ class TestStorageLost:
 
     @staticmethod
     def _engine(layout, **extra):
+        """The engine of ``layout``: ``paged`` and ``contiguous`` with
+        the prefix cache, ``kinds`` paged over two kinds of cache."""
+        if layout == "kinds":
+            return _paged_engine("two", "kv_lost", slots=2, paged_kv=True,
+                                 **extra)
         from veles_tpu.serving import LMEngine
         features = {"prefill_chunk": 8, "prefix_cache": 32}
         if layout == "paged":
             features["paged_kv"] = True
-        params = _params()
-        return params, LMEngine(params, n_heads=2, max_len=96, slots=2,
-                                name="kv_lost", **features, **extra)
+        return LMEngine(_params(), n_heads=2, max_len=96, slots=2,
+                        name="kv_lost", **features, **extra)
 
     @staticmethod
     def _consuming(engine, attr, ready):
@@ -489,12 +453,11 @@ class TestStorageLost:
         setattr(engine, attr, stub)
         return fired
 
-    @pytest.mark.parametrize("layout", ["paged", "contiguous"])
+    @pytest.mark.parametrize("layout", ["paged", "contiguous", "kinds"])
     @pytest.mark.parametrize("path", ["decode", "chunk"])
     def test_consumed_storage_fails_holders_and_rebuilds(self, layout,
                                                          path):
-        params, engine = self._engine(layout)
-        engine.start()
+        engine = self._engine(layout).start()
         lanes = engine._lanes
         if path == "decode":
             # the first decode dispatch that runs beside a prefilling lane
@@ -508,11 +471,9 @@ class TestStorageLost:
         try:
             # something for the trie to hold (and, paged, pages with it)
             seed_prompt = self.LONG[:20]
-            got = engine.submit(seed_prompt, 3).result(timeout=120)
-            numpy.testing.assert_array_equal(
-                numpy.concatenate([seed_prompt, got]),
-                _greedy(params, seed_prompt, 3))
-            assert engine._trie.size >= 2
+            assert_greedy(engine, seed_prompt,
+                          engine.submit(seed_prompt, 3).result(timeout=120), 3)
+            assert layout == "kinds" or engine._trie.size >= 2
             fa = engine.submit([1, 2, 3], 30)        # decodes
             fb = engine.submit(self.LONG[::-1], 4)   # prefills, 5 chunks
             fc = engine.submit([2, 4, 6, 8], 6)      # queued: no slot
@@ -521,37 +482,33 @@ class TestStorageLost:
                     f.result(timeout=120)
             assert fired
             # the queued request held nothing: served token for token
-            numpy.testing.assert_array_equal(
-                numpy.concatenate([[2, 4, 6, 8], fc.result(timeout=120)]),
-                _greedy(params, [2, 4, 6, 8], 6))
+            assert_greedy(engine, [2, 4, 6, 8], fc.result(timeout=120), 6)
             assert engine.metrics.counter("kv_storage_rebuilds") == 1
-            assert engine._trie.size == 0       # dropped with the rows
+            # dropped with the rows
+            assert layout == "kinds" or engine._trie.size == 0
             leaves = [a for pair in engine._storage() for a in pair]
             assert not any(a.is_deleted() for a in leaves)
             # and the next one, through the fresh storage, as well
-            got = engine.submit(self.LONG, 5).result(timeout=120)
-            numpy.testing.assert_array_equal(
-                numpy.concatenate([self.LONG, got]),
-                _greedy(params, self.LONG, 5))
+            assert_greedy(engine, self.LONG,
+                          engine.submit(self.LONG, 5).result(timeout=120), 5)
             assert engine.metrics.counter("kv_storage_rebuilds") == 1
         finally:
             engine.stop()
-        if layout == "paged":
+        if layout != "contiguous":
             # the allocator is whole: what is not free, the trie holds
             # for the prompts served since
-            engine.verify_pool_invariants()
-            assert engine._pool.pinned_pages == 0
-            assert engine._pool.free_pages + engine._trie.size \
-                == engine._pool.num_pages
+            _home_whole(engine)
+        if layout == "paged":
             engine._trie.clear()
             assert engine._pool.free_pages == engine._pool.num_pages
 
-    def test_allocator_whole_right_after_the_loss(self):
+    @pytest.mark.parametrize("layout", ["paged", "kinds"])
+    def test_allocator_whole_right_after_the_loss(self, layout):
         """Between the loss and the next admission nothing is held:
         ``kv_pages_free`` equals the total, no pin, no trie entry, every
-        table row on scratch."""
-        params, engine = self._engine("paged")
-        engine.start()
+        table row on scratch — of both allocators and both tables, for
+        two kinds of cache."""
+        engine = self._engine(layout).start()
         lanes = engine._lanes
         self._consuming(engine, "_step_jit", lambda: any(
             ln is not None and ln.pending for ln in lanes))
@@ -569,13 +526,18 @@ class TestStorageLost:
             g = engine.metrics.snapshot()["gauges"]
             assert g["kv_pages_free"] == g["kv_pages_total"]
             assert g["kv_pages_pinned"] == 0
-            assert g["prefix_cache_chunks"] == 0
             assert (engine._page_tables == KVPagePool.SCRATCH).all()
-            engine.verify_pool_invariants()
+            if layout == "kinds":
+                assert g["kv_pages_free.window"] \
+                    == g["kv_pages_total.window"]
+                assert (engine._wt.tables == KVPagePool.SCRATCH).all()
+            else:
+                assert g["prefix_cache_chunks"] == 0
+            _home_whole(engine)
         finally:
             engine.stop()
 
-    @pytest.mark.parametrize("layout", ["paged", "contiguous"])
+    @pytest.mark.parametrize("layout", ["paged", "contiguous", "kinds"])
     @pytest.mark.parametrize("site", ["engine.step", "engine.chunk"])
     def test_injected_fault_keeps_todays_behaviour(self, layout, site):
         """An injected fault fires BEFORE the program is called: the
@@ -589,22 +551,20 @@ class TestStorageLost:
         # while the short one decodes (ITS lane survives a chunk fault)
         plan = FaultPlan().arm(site, calls={1 if site == "engine.step"
                                             else 4})
-        params, engine = self._engine(layout, faults=plan)
-        engine.start()
+        engine = self._engine(layout, faults=plan).start()
         try:
             long_prompt = self.LONG[::-1]
             fa = engine.submit([1, 2, 3], 30)
             fb = engine.submit(long_prompt, 4)
-            failed, survivor, prompt, n_new = (
-                (fa, fb, long_prompt, 4) if site == "engine.step"
-                else (fb, fa, [1, 2, 3], 30))
+            failed, survivor, prompt = (
+                (fa, fb, long_prompt) if site == "engine.step"
+                else (fb, fa, [1, 2, 3]))
             with pytest.raises(InjectedFault):
                 failed.result(timeout=120)
-            numpy.testing.assert_array_equal(
-                numpy.concatenate([prompt, survivor.result(timeout=120)]),
-                _greedy(params, prompt, n_new))
+            assert_greedy(engine, prompt, survivor.result(timeout=120),
+                          30 if survivor is fa else 4)
             assert engine.metrics.counter("kv_storage_rebuilds") == 0
         finally:
             engine.stop()
-        if layout == "paged":
+        if layout != "contiguous":
             engine.verify_pool_invariants()

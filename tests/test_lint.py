@@ -150,11 +150,6 @@ class TestLintFixtures:
         assert "census" in msgs
         assert "silently-compiled twin" in msgs
         assert "fixture drift" in msgs
-        # ISSUE 19: while-loop-built programs join the census — an
-        # unmarked `lax.while_loop` and one naming an uninstalled
-        # family are both findings
-        assert "silently-compiled while-twin" in msgs
-        assert "no `self._phantom_jit" in msgs
 
     def test_hostsync_violations_caught_at_line(self):
         """ISSUE 17: implicit device→host coercions, jnp staging,

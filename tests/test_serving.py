@@ -16,6 +16,7 @@ import pytest
 sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools"))
 
+from lm_cases import assert_greedy, kinds_model  # noqa: E402
 from load_gen import run_load  # noqa: E402
 
 
@@ -514,11 +515,16 @@ class TestLMEngine:
 
 
 class TestServeLMContinuous:
-    def test_http_engine_matches_direct(self):
-        """serve_lm(slots=2) over a (briefly) trained char_lm: engine
-        replies are exactly the direct greedy continuation, n_new is
-        honored exactly (no tier overshoot), and sampling requests
-        still work (direct-path fallback)."""
+    @pytest.mark.parametrize("deployment", [
+        {}, {"paged_kv": 6, "prefill_chunk": 8, "attn_kernel": "force"}],
+        ids=["default", "paged_kernels"])
+    def test_http_engine_matches_direct(self, deployment):
+        """serve_lm(slots=2) over a (briefly) trained char_lm — the
+        default engine, and what the benchmark's cells deploy (paged,
+        chunked, through the kernels): engine replies are exactly the
+        direct greedy continuation, n_new is honored exactly (no tier
+        overshoot), and sampling requests still work (direct-path
+        fallback)."""
         import jax.numpy as jnp
         from veles_tpu import prng
         from veles_tpu.config import root
@@ -540,7 +546,7 @@ class TestServeLMContinuous:
         wf = char_lm.train()
         trainer = wf.trainer
         params = trainer._to_portable(trainer.params)
-        api = serve_lm(wf, port=0, max_new=8, slots=2)
+        api = serve_lm(wf, port=0, max_new=8, slots=2, **deployment)
         try:
             for p in ([1, 2, 3], [2, 4, 6, 8, 10]):
                 out = _post(api.port, {"input": [p], "n_new": 5})
@@ -1343,26 +1349,27 @@ class TestResilience:
         assert isinstance(err.value, Overloaded)
         assert err.value.retry_after > 0
 
-    def test_checkpoint_restore_after_simulated_crash(self):
+    @pytest.mark.parametrize("model", ["pre_ln", "kinds"])
+    def test_checkpoint_restore_after_simulated_crash(self, model):
         """Kill-and-restore: a paged engine freezes mid-traffic, its
         checkpoint re-admits the journaled work on a FRESH engine
         (allocator invariants verified first), resumed outputs are
         bit-identical to greedy generate, the pool ends leak-free,
-        and new traffic serves with unchanged parity."""
-        import jax.numpy as jnp
-        from veles_tpu.ops.transformer import generate
+        and new traffic serves with unchanged parity.  ``kinds``: two
+        kinds of cache (no prefix cache), both allocators verified."""
         from veles_tpu.serving import FaultPlan, LMEngine
-        params = _tiny_params(max_len=64)
+        if model == "kinds":
+            record, params = kinds_model()
+            features = {}
+        else:
+            record, params = 2, _tiny_params(max_len=64)
+            features = {"prefix_cache": 8}
         prompts = [[1, 2, 3], [2, 4, 6, 8, 10], [5, 1, 5, 1, 5]]
-        expected = [numpy.asarray(generate(
-            params, jnp.asarray([p], jnp.int32), 5, 2,
-            temperature=0.0, max_len=64))[0] for p in prompts]
         plan = FaultPlan().arm("engine.tick", kind="freeze", after=2,
                                duration_s=60.0)
-        crashed = LMEngine(params, n_heads=2, max_len=64, slots=2,
-                           paged_kv=8, prefill_chunk=8,
-                           prefix_cache=8, name="crash",
-                           faults=plan).start()
+        crashed = LMEngine(params, record, max_len=64, slots=2,
+                           paged_kv=8, prefill_chunk=8, name="crash",
+                           faults=plan, **features).start()
         try:
             for p in prompts:
                 crashed.submit(p, 5)
@@ -1370,26 +1377,29 @@ class TestResilience:
             state = crashed.checkpoint()
             assert len(state["requests"]) == 3
             json.dumps(state)                # JSON-safe by contract
-            fresh = LMEngine(params, n_heads=2, max_len=64, slots=2,
-                             paged_kv=8, prefill_chunk=8,
-                             prefix_cache=8, name="fresh").start()
+            fresh = LMEngine(params, record, max_len=64, slots=2,
+                             paged_kv=8, prefill_chunk=8, name="fresh",
+                             **features).start()
             try:
                 restored = fresh.restore(state)
                 assert len(restored) == 3
                 outs = [restored[e["rid"]].result(timeout=60)
                         for e in state["requests"]]
-                for p, out, exp in zip(prompts, outs, expected):
-                    numpy.testing.assert_array_equal(
-                        numpy.concatenate([p, out]), exp)
+                for p, out in zip(prompts, outs):
+                    assert_greedy(fresh, p, out, 5)
                 # leak-free: drain the trie, the pool refills whole
-                while fresh._trie.evict_one():
-                    pass
+                if model == "pre_ln":
+                    while fresh._trie.evict_one():
+                        pass
+                    assert fresh._trie.live_pins() == 0
                 inv = fresh.verify_pool_invariants()
                 assert inv["free_pages"] == fresh._pool.num_pages
-                assert fresh._trie.live_pins() == 0
+                if fresh._wt is not None:
+                    assert fresh._wt.pool.free_pages \
+                        == fresh._wt.pool.num_pages
                 # new traffic, unchanged parity
                 out = fresh.generate(numpy.asarray([prompts[0]]), 5)
-                numpy.testing.assert_array_equal(out[0], expected[0])
+                assert_greedy(fresh, prompts[0], out[0][3:], 5)
                 assert fresh.metrics.counter("engine_restores") == 1
             finally:
                 fresh.stop()
@@ -1484,73 +1494,80 @@ class TestWeightSwap:
             time.sleep(0.002)
         assert engine.metrics.gauge("slots_busy") >= n
 
-    def test_swap_parity_straddling_lanes(self):
+    @staticmethod
+    def _old_and_new(model):
+        """(the engine's model keywords, the tree it starts on, the one
+        swapped in: same shapes, other weights) — the ``pre_ln`` model,
+        or the two kinds of cache of ``lm_cases.kinds_model``, paged."""
+        if model == "kinds":
+            (record, pa), (_, pb) = kinds_model(3), kinds_model(4)
+            return {"n_heads": record, "paged_kv": True,
+                    "prefill_chunk": 8}, pa, pb
+        return {"n_heads": 2}, _tiny_params(), _tiny_params()
+
+    @pytest.mark.parametrize("model", ["pre_ln", "kinds"])
+    def test_swap_parity_straddling_lanes(self, model):
         """swap_weights mid-traffic: every request completes whole and
         exactly once, each delivered row is bit-identical to the
         weights version its future is stamped with (straddling lanes
         finish on the OLD weights — the default), and post-swap
         traffic serves the new weights."""
         from veles_tpu.serving import LMEngine
-        pa = _tiny_params()
-        pb = _tiny_params()       # fresh draws: same shapes, new weights
+        features, pa, pb = self._old_and_new(model)
         prompts = [[1, 2, 3], [2, 4, 6, 8], [5, 1, 5], [7, 7, 1]]
         n_new = 12
-        exp_a = self._expected(pa, prompts, n_new)
-        exp_b = self._expected(pb, prompts, n_new)
-        engine = LMEngine(pa, n_heads=2, max_len=48, slots=2,
-                          name="sw_par").start()
+        engine = LMEngine(pa, max_len=48, slots=2, name="sw_par",
+                          **features).start()
         try:
             futures = [engine.submit(p, n_new) for p in prompts]
             self._wait_busy(engine, 2)
             v = engine.swap_weights(pb, version=7)
             assert v == 7 and engine.weights_version == 7
             seen = set()
-            for p, f, ea, eb in zip(prompts, futures, exp_a, exp_b):
+            for p, f in zip(prompts, futures):
                 out = f.result(timeout=60)
                 assert len(out) == n_new      # whole, exactly once
                 seen.add(f.version)
-                numpy.testing.assert_array_equal(
-                    numpy.concatenate([p, out]),
-                    ea if f.version == 0 else eb)
+                assert_greedy(engine, p, out, n_new,
+                              pa if f.version == 0 else pb)
             assert seen <= {0, 7}
             assert 0 in seen        # the confirmed-busy lanes finished
             #                         on the old weights
             fut = engine.submit(prompts[0], n_new)
             out = fut.result(timeout=60)
             assert fut.version == 7
-            numpy.testing.assert_array_equal(
-                numpy.concatenate([prompts[0], out]), exp_b[0])
+            assert_greedy(engine, prompts[0], out, n_new, pb)
             assert engine.metrics.counter("weight_swaps") == 1
             assert engine.metrics.gauge("weights_version") == 7
         finally:
             engine.stop()
 
-    def test_swap_drain_requeues_on_new_weights_paged(self):
+    @pytest.mark.parametrize("model", ["pre_ln", "kinds"])
+    def test_swap_drain_requeues_on_new_weights_paged(self, model):
         """drain=True on a paged engine: in-flight lanes are withdrawn
         whole and re-decode from scratch on the NEW weights — futures
         resolve exactly once with the new stamp, and the page pool
-        survives the requeue leak-free (allocator invariants)."""
+        survives the requeue leak-free (allocator invariants; of both
+        allocators, for two kinds of cache)."""
         from veles_tpu.serving import FaultPlan, LMEngine
-        pa = _tiny_params()
-        pb = _tiny_params()
+        features, pa, pb = self._old_and_new(model)
         prompts = [[1, 2, 3], [2, 4, 6, 8]]
         n_new = 16
-        exp_b = self._expected(pb, prompts, n_new)
         # slow ticks so the swap provably lands mid-decode
         plan = FaultPlan().arm("engine.step", kind="latency",
                                latency_s=0.02)
-        engine = LMEngine(pa, n_heads=2, max_len=48, slots=2,
-                          paged_kv=True, prefill_chunk=8,
-                          name="sw_drain", faults=plan).start()
+        engine = LMEngine(pa, max_len=48, slots=2, name="sw_drain",
+                          faults=plan, **dict(
+                              features, paged_kv=True,
+                              prefill_chunk=8)).start()
         try:
             futures = [engine.submit(p, n_new) for p in prompts]
             self._wait_busy(engine, 2)
             engine.swap_weights(pb, version=3, drain=True)
-            for p, f, eb in zip(prompts, futures, exp_b):
+            for p, f in zip(prompts, futures):
                 out = f.result(timeout=60)
                 assert len(out) == n_new and f.version == 3
-                numpy.testing.assert_array_equal(
-                    numpy.concatenate([p, out]), eb)
+                assert_greedy(engine, p, out, n_new, pb)
             assert engine.metrics.counter(
                 "requests_requeued_for_swap") >= 1
             deadline = time.monotonic() + 15
@@ -1559,6 +1576,9 @@ class TestWeightSwap:
                 time.sleep(0.02)
             inv = engine.verify_pool_invariants()
             assert inv["free_pages"] == engine._pool.num_pages
+            if engine._wt is not None:
+                assert engine._wt.pool.free_pages \
+                    == engine._wt.pool.num_pages
         finally:
             plan.release()
             engine.stop()

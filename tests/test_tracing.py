@@ -13,6 +13,7 @@ import pytest
 
 import jax.numpy as jnp
 
+from lm_cases import assert_greedy, kinds_model
 from veles_tpu import prng
 from veles_tpu.ops.transformer import generate, init_transformer_params
 
@@ -612,18 +613,19 @@ class TestLoopRecorder:
     N_NEW = 8
     PROMPTS = [[1, 2, 3], [2, 4, 6, 8], [5, 1, 5, 1, 5, 1, 5, 1, 5, 1],
                [7, 7], [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5]]
-    #: the four decode drivers of LMEngine._serve_loop
+    #: the three decode drivers of LMEngine._serve_loop, and the plain
+    #: one over two kinds of cache (``kinds``: lm_cases.kinds_model)
     DRIVERS = {
         "plain": dict(prefill_chunk=8, paged_kv=True),
         "speculative": dict(prefill_chunk=8, paged_kv=True, spec_k=2),
         "megastep": dict(prefill_chunk=8, paged_kv=True, megastep=4),
-        "while": dict(prefill_chunk=8, paged_kv=True, megastep=4,
-                      megastep_mode="while"),
+        "plain_kinds": dict(prefill_chunk=8, paged_kv=True, kinds=True),
     }
 
-    def _engine(self, name="rec_t", **kw):
+    def _engine(self, name="rec_t", kinds=False, **kw):
         from veles_tpu.serving import LMEngine, ServingMetrics
-        return LMEngine(tiny_params(), n_heads=2, max_len=64, slots=2,
+        record, params = kinds_model() if kinds else (2, tiny_params())
+        return LMEngine(params, record, max_len=64, slots=2,
                         metrics=ServingMetrics(name), name=name, **kw)
 
     def _serve(self, prompts=None, **kw):
@@ -686,10 +688,8 @@ class TestLoopRecorder:
         to the tokens_out counter."""
         from veles_tpu.serving import tracing
         engine, outs = self._serve(**self.DRIVERS[driver])
-        expect = greedy_rows(tiny_params(), self.PROMPTS, self.N_NEW)
-        for p, out, exp in zip(self.PROMPTS, outs, expect):
-            numpy.testing.assert_array_equal(
-                numpy.concatenate([p, out]), exp)
+        for p, out in zip(self.PROMPTS, outs):
+            assert_greedy(engine, p, out, self.N_NEW)
         rec = engine.recorder
         assert rec is tracing.recorders()[-1]
         turns = rec.turns()
@@ -707,7 +707,7 @@ class TestLoopRecorder:
         assert int((step > 0).sum()) == counters["decode_dispatches"]
         assert int((chunk > 0).sum()) == counters["prefill_dispatches"]
         want = {"plain": "step_all", "speculative": "verify_all",
-                "megastep": "mega_plain", "while": "mega_while"}[driver]
+                "megastep": "mega_plain", "plain_kinds": "step_all"}[driver]
         assert {rec.programs[i] for i in set(step[step > 0].tolist())} \
             == {want}
         assert {rec.programs[i] for i in set(chunk[chunk > 0].tolist())} \
@@ -749,10 +749,8 @@ class TestLoopRecorder:
         from veles_tpu.serving import tracing
         engine, outs = self._serve(attn_kernel="force",
                                    **self.DRIVERS[driver])
-        expect = greedy_rows(tiny_params(), self.PROMPTS, self.N_NEW)
-        for p, out, exp in zip(self.PROMPTS, outs, expect):
-            numpy.testing.assert_array_equal(
-                numpy.concatenate([p, out]), exp)
+        for p, out in zip(self.PROMPTS, outs):
+            assert_greedy(engine, p, out, self.N_NEW)
         c = engine.metrics.snapshot()["counters"]
         given, live = c["attn_page_steps"], c["attn_page_steps_live"]
         assert 0 < live < given
@@ -779,22 +777,6 @@ class TestLoopRecorder:
         art["_spans_recorder"]["tracing"] = older
         assert read(art, None) is None
 
-    def test_standby_ring_requests_are_recorded(self):
-        """The while driver's refill ring admits outside the slot array:
-        its requests still leave ordered records whose token stamps sum
-        to the counter."""
-        engine, outs = self._serve(
-            prefill_chunk=8, paged_kv=True, megastep=4,
-            megastep_mode="while", refill_ring=2)
-        counters = engine.metrics.snapshot()["counters"]
-        reqs = engine.recorder.requests()
-        assert len(reqs) == len(self.PROMPTS)
-        for r in reqs:
-            assert r.outcome == "ok"
-            assert r.enqueue <= r.admit <= r.first_token <= r.done
-            assert r.tokens_out == self.N_NEW
-        assert sum(r.tokens_out for r in reqs) == counters["tokens_out"]
-
     def test_shed_request_leaves_its_outcome(self):
         from veles_tpu.serving import DeadlineExceeded
         engine = self._engine(deadline_s=0.0, prefill_chunk=8).start()
@@ -809,11 +791,14 @@ class TestLoopRecorder:
         assert r.admit == 0 and r.first_token == 0 and r.tokens_out == 0
         assert r.enqueue <= r.done and r.lane == -1
 
-    def test_failed_request_leaves_its_outcome(self):
+    @pytest.mark.parametrize("layout", [
+        {}, {"prefill_chunk": 8, "paged_kv": True}],
+        ids=["contiguous", "paged"])
+    def test_failed_request_leaves_its_outcome(self, layout):
         from veles_tpu.serving import FaultPlan, InjectedFault
         plan = FaultPlan(seed=0).arm("engine.step", kind="error",
                                      calls={1})
-        engine = self._engine(faults=plan).start()
+        engine = self._engine(faults=plan, **layout).start()
         try:
             fut = engine.submit([1, 2, 3], 4)
             with pytest.raises(InjectedFault):
@@ -823,8 +808,8 @@ class TestLoopRecorder:
             engine.stop()
         first, second = engine.recorder.requests()
         assert first.outcome == "failed" and second.outcome == "ok"
-        # the failed one was admitted and had its first token (whole-
-        # prompt prefill) before the decode step raised
+        # the failed one was admitted and had its first token (its
+        # prefill's) before the decode step raised
         assert first.enqueue <= first.admit <= first.first_token \
             <= first.done
         assert first.tokens_out == 1 and len(ok) == second.tokens_out

@@ -215,21 +215,6 @@ def _builtin_records():
                     {"metric": "",
                      "note": "megastep headline did not select the "
                              "lm_megastep_dispatches_per_token metric"}))
-    # the whilestep record path (ISSUE 19): the while-loop headline
-    # must WIN over the scan megastep column and conform to the shape
-    ws_record = lm_bench.summary_record({
-        "headline": {
-            "dispatches_per_token_megastep_single_lane": 0.062,
-            "dispatches_per_token_whilestep_single_lane": 0.058,
-            "whilestep_waste_frac_single_lane": 0.0}})[0]
-    out.append(("lm_bench.summary_record(whilestep headline)",
-                ws_record))
-    if ws_record.get("metric") != "lm_whilestep_dispatches_per_token":
-        out.append(("lm_bench.summary_record(whilestep headline)",
-                    {"metric": "",
-                     "note": "whilestep headline did not select the "
-                             "lm_whilestep_dispatches_per_token "
-                             "metric"}))
     out.append(("chaos_bench.summary_record({})",
                 chaos_bench.summary_record({})[0]))
     out.append(("trace_report.summary_record({})",

@@ -149,8 +149,7 @@ def _emulate_device_latency(engines, seconds):
 
     for engine in engines:
         for name in ("_step_jit", "_verify_jit", "_chunk_jit",
-                     "_prefill_jit", "_megastep_jit",
-                     "_whilestep_jit"):
+                     "_prefill_jit", "_megastep_jit"):
             fn = getattr(engine, name, None)
             if fn is not None:
                 setattr(engine, name, wrap(fn))
@@ -340,11 +339,6 @@ def run_leg(params, n_heads, max_len, prompts, n_new, expect,
                 # measured cost side of the K tradeoff
                 "megastep_waste_frac": waste_frac,
             }
-            if features.get("refill_ring"):
-                # ISSUE 19: in-graph re-arms from the standby ring —
-                # each one is a dispatch boundary the loop skipped
-                megastep_cols["megastep_refills"] = \
-                    c.get("megastep_refills", 0)
             if slots == 1 and n_new >= 32 \
                     and int(features["megastep"]) >= 8:
                 # THE acceptance criterion (ISSUE 13): single-lane
@@ -356,27 +350,6 @@ def run_leg(params, n_heads, max_len, prompts, n_new, expect,
                         "megastep leg measured %s dispatches/token "
                         "(acceptance bound < 0.1) under %r"
                         % (dpt, features))
-                if features.get("megastep_mode") == "while":
-                    # ISSUE 19 acceptance: the while loop's early exit
-                    # must RETIRE the scan waste tail (0.225 on the
-                    # spec K=8 single-lane record) ...
-                    if waste_frac is None or waste_frac >= 0.02:
-                        raise AssertionError(
-                            "whilestep leg measured waste_frac %s "
-                            "(acceptance bound < 0.02, scan record "
-                            "0.225) under %r" % (waste_frac, features))
-                    # ... while holding dispatches/token at or under
-                    # the K=16 scan megastep record (0.062 — itself
-                    # the rounded record column, so compare rounded;
-                    # the K=8 spec leg has a different dispatch
-                    # geometry and answers only to the < 0.1 bound)
-                    if int(features["megastep"]) >= 16 \
-                            and round(dpt, 3) > 0.062:
-                        raise AssertionError(
-                            "whilestep leg measured %s dispatches/"
-                            "token (acceptance bound <= 0.062, the "
-                            "K=16 scan record) under %r"
-                            % (dpt, features))
         tps = tokens / wall if wall else 0.0
         peak, peak_src = peak_flops_estimate()
         mfu = (tps * flops_per_token / peak
@@ -654,19 +627,6 @@ def run_bench(smoke=False, slots=4, chunk=16, cache=256, spec_k=4,
         "megastep_all": {"megastep": 8, "paged_kv": True,
                          "prefix_cache": cache, "prefill_chunk": chunk,
                          "spec_k": spec_k},
-        # ISSUE 19: the persistent while-loop megastep — same K caps
-        # as the scan legs above, but the loop EXITS at the realized
-        # iteration count; whilestep_all stacks the standby refill
-        # ring + cache + chunk + spec on the K=8 cap.  run_leg asserts
-        # the acceptance pair on the single-lane legs: waste_frac
-        # < 0.02 (vs the 0.225 scan K=8 spec record) and
-        # dispatches/token <= 0.062 (the K=16 scan record).
-        "whilestep": {"megastep": 16, "megastep_mode": "while",
-                      "paged_kv": True, "prefill_chunk": chunk},
-        "whilestep_all": {"megastep": 8, "megastep_mode": "while",
-                          "paged_kv": True, "prefix_cache": cache,
-                          "prefill_chunk": chunk, "spec_k": spec_k,
-                          "refill_ring": 2},
         # ISSUE 12: the TRACED legs — the full fast-path stack with the
         # span tracer armed.  Parity still asserted (tracing must not
         # perturb output), span-tree integrity asserted per request,
@@ -765,19 +725,6 @@ def run_bench(smoke=False, slots=4, chunk=16, cache=256, spec_k=4,
             lane1["megastep_all"]["dispatches_per_token"],
         "megastep_waste_frac_single_lane":
             lane1["megastep"]["megastep_waste_frac"],
-        # ISSUE 19: the while-loop megastep acceptance pair (run_leg
-        # already ASSERTED waste < 0.02 and dpt <= 0.062 on these
-        # legs) plus the in-graph refill count on the ring leg
-        "dispatches_per_token_whilestep_single_lane":
-            lane1["whilestep"]["dispatches_per_token"],
-        "dispatches_per_token_whilestep_all_single_lane":
-            lane1["whilestep_all"]["dispatches_per_token"],
-        "whilestep_waste_frac_single_lane":
-            lane1["whilestep"]["megastep_waste_frac"],
-        "whilestep_all_waste_frac_single_lane":
-            lane1["whilestep_all"]["megastep_waste_frac"],
-        "whilestep_all_refills_single_lane":
-            lane1["whilestep_all"].get("megastep_refills", 0),
         "prefill_tokens_baseline": sp_base["prefill_tokens"],
         "prefill_tokens_prefix_cache": sp_cache["prefill_tokens"],
         "prefix_hit_tokens": sp_cache["prefix_hit_tokens"],
@@ -868,19 +815,6 @@ def summary_record(results):
     reading."""
     mfu = _latest_mfu(results)
     headline = results.get("headline") or {}
-    if headline.get("dispatches_per_token_whilestep_single_lane") \
-            is not None:
-        # ISSUE 19 headline: the while-loop megastep's dispatches/
-        # token against the K=16 scan record it must not regress
-        return {
-            "metric": "lm_whilestep_dispatches_per_token",
-            "mfu": mfu,
-            "value":
-                headline["dispatches_per_token_whilestep_single_lane"],
-            "unit": "dispatches/token",
-            "vs_baseline": 0.062,
-            "configs": results,
-        }, 0
     if headline.get("dispatches_per_token_megastep_single_lane") \
             is not None:
         # ISSUE 13 headline: the fused-decode dispatches/token against

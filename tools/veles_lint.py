@@ -134,7 +134,7 @@ CENSUS_MODULES = ("veles_tpu/serving/lm_engine.py",)
 
 #: jit-guard fixture files: every family the census declares must be
 #: compile-count-asserted here, and vice versa
-JIT_GUARD_FIXTURES = ("tests/test_lm_fastpath.py",)
+JIT_GUARD_FIXTURES = ("tests/lm_cases.py",)
 
 CHECKS = ("lock-discipline", "traced-purity", "suppression",
           "recompile-hazard", "host-sync", "resource-lifecycle")
@@ -911,42 +911,6 @@ class _RecompilePass:
                     continue
                 declared.setdefault(family, []).append(
                     (mod.relpath, node.lineno))
-        # ISSUE 19: lax.while_loop-built resident programs — a while
-        # loop is a whole program family behind ONE call, so every
-        # call site in a census module must name its family, and that
-        # family must be INSTALLED at a declared `self._<family>_jit`
-        # site; a while program jitted under an undeclared attr is the
-        # silently-compiled-twin bug class with in-graph control flow
-        for relpath in census_modules:
-            mod = self.modules.get(relpath)
-            if mod is None:
-                continue
-            for node in ast.walk(mod.tree):
-                if not isinstance(node, ast.Call):
-                    continue
-                if not (_dotted(node.func) or "").endswith(
-                        "lax.while_loop"):
-                    continue
-                family = None
-                for line in (node.lineno - 1, node.lineno):
-                    m = PROGRAMS_RE.search(
-                        mod.comments.get(line, ""))
-                    if m:
-                        family = m.group("family")
-                if family is None:
-                    self._flag(mod.relpath, node,
-                               "lax.while_loop builds a resident loop "
-                               "program with no `# programs: <family>` "
-                               "census entry — a silently-compiled "
-                               "while-twin would go unnoticed")
-                    continue
-                if family not in declared:
-                    self._flag(mod.relpath, node,
-                               "while-loop census names family %r but "
-                               "no `self._%s_jit = self._jit(...)` "
-                               "site installs it — the while-twin "
-                               "would compile outside every jit-guard "
-                               "bound" % (family, family))
         asserted = {}        # family -> (fixture relpath, line)
         for relpath in jit_guard_fixtures:
             mod = self.modules.get(relpath)

@@ -14,6 +14,9 @@ request, this package amortizes dispatch across concurrent clients.
   cache (greedy path bit-identical to ``ops.transformer.generate``),
   plus the ISSUE 4 fast path: :class:`RadixPrefixCache` prompt-KV
   reuse, chunked prefill, and prompt-lookup speculative decoding.
+  A turn of its loop decodes through one of three drivers: one token
+  a lane, the speculative verify (``spec_k``), or the fused
+  ``lax.scan`` megastep (``megastep=K``, the one fused driver).
   ``attn_kernel=`` (ISSUE 7) routes the paged engine's attention
   through the Pallas serving kernels in ``ops/pallas_kernels.py``
   (flash-decode over the page table + fused chunked-prefill with

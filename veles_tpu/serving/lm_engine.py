@@ -162,7 +162,11 @@ bit-for-bit; any K is bit-identical to it anyway (the scan body IS the
 step program), which the parity matrix pins across the full
 {paged_kv, prefix_cache, prefill_chunk, spec_k, attn_kernel, tp}
 feature set.  With the Pallas ``paged_flash_decode`` kernel active the
-whole K-step loop never leaves the device.
+whole K-step loop never leaves the device.  The scan is the ONE fused
+driver: a turn of the loop decodes through exactly one of
+``_step_megastep`` (``megastep >= 2``), ``_step_speculative``
+(``spec_k``) or ``_step_plain``, all three through
+``_dispatch_decode``.
 
 The KV STORAGE IS UPDATED IN PLACE (ISSUE 27).  The pools (paged) or
 the caches (contiguous) are one tree of device arrays that every
@@ -225,7 +229,7 @@ from veles_tpu.serving.metrics import ServingMetrics
 class _Request:
     __slots__ = ("prompt", "true_len", "n_new", "future", "t_enq",
                  "deadline", "cancelled", "pages", "trace", "tspan",
-                 "seed", "t_enq_ns", "t_admit_ns", "token_ns", "lane")
+                 "t_enq_ns", "t_admit_ns", "token_ns", "lane")
 
     def __init__(self, prompt, n_new, deadline_s, pages=0):
         self.prompt = prompt          # (s,) int32, unpadded
@@ -235,7 +239,7 @@ class _Request:
         self.future.request = self    # cancellation handle
         #: the loop recorder's stamps (ISSUE 26), ``time.monotonic_ns()``:
         #: enqueue, lane assigned (0 until then), one per emitted token;
-        #: ``lane`` is the slot (-1: none yet, or a standby-ring entry)
+        #: ``lane`` is the slot (-1: none yet)
         self.t_enq_ns = time.monotonic_ns()
         self.t_admit_ns = 0
         self.token_ns = array.array("q")
@@ -250,10 +254,6 @@ class _Request:
         #: attributes its dispatch spans to the right request
         self.trace = None
         self.tspan = None
-        #: seeded-sampling lane seed (ISSUE 19): the admission id —
-        #: deterministic per submission order, so the same workload
-        #: samples identically whatever engine configuration serves it
-        self.seed = 0
 
 
 class _Slot:
@@ -276,27 +276,6 @@ class _Slot:
         #: paged mode: page ids backing this lane's table row, in
         #: lane-local order (owned AND referenced; released at finish)
         self.pages = []
-
-
-class _Standby:
-    """One standby-ring entry (ISSUE 19): a host-prefilled lane parked
-    OUTSIDE the slot array, waiting to be published into the while-loop
-    megastep's carry so a finishing slot can be re-armed in-graph.  It
-    owns its pages (reserved and pinned like a live lane's) and its
-    request's first token is already delivered — the entry is admitted
-    work, never deadline-shed."""
-
-    __slots__ = ("lane", "table", "pos", "last", "ready")
-
-    def __init__(self, lane, table):
-        self.lane = lane
-        #: (max_pages,) int32 page-table row backing this entry
-        self.table = table
-        #: decode frontier after the tail prefill chunk
-        self.pos = 0
-        self.last = 0
-        #: tail chunk done — publishable into the megastep carry
-        self.ready = False
 
 
 def prompt_bucket(true_len, max_len, floor=16):
@@ -582,9 +561,7 @@ class LMEngine(Logger):
                  prefix_cache=0, spec_k=0, spec_ngram=3,
                  queue_tokens=0, paged_kv=0, attn_kernel=None,
                  tp=0, devices=None, faults=None, version=0,
-                 tracer=None, megastep=0, megastep_mode=None,
-                 refill_ring=0, temperature=0.0, top_k=0,
-                 sample_seed=None):
+                 tracer=None, megastep=0):
         if slots < 1:
             raise ValueError("slots must be >= 1")
         self.name = name
@@ -666,44 +643,10 @@ class LMEngine(Logger):
         #: decode megastep (ISSUE 13): K >= 2 fuses K decode (or
         #: propose/verify) iterations into one lax.scan dispatch;
         #: 0/1 = the per-tick path, bit-identical and unchanged.
-        #: ISSUE 19: megastep='while' (or megastep_mode='while') swaps
-        #: the fixed-K scan for a lax.while_loop whose cond exits as
-        #: soon as every live lane finished its n_new — K stays the
-        #: HARD iteration cap, so termination stays provable and the
-        #: program family stays one per live-width ladder entry.
-        if megastep == "while":
-            megastep, megastep_mode = 16, "while"
-        if megastep_mode not in (None, "scan", "while"):
-            raise ValueError("megastep_mode must be 'scan' or 'while' "
-                             "(got %r)" % (megastep_mode,))
         self.megastep = int(megastep or 0)
-        self.megastep_mode = megastep_mode or "scan"
         if self.megastep < 0:
             raise ValueError("megastep must be >= 0 (got %d)"
                              % self.megastep)
-        if self.megastep_mode == "while" and self.megastep < 2:
-            raise ValueError("megastep_mode='while' needs megastep >= 2 "
-                             "(the iteration cap)")
-        #: standby refill ring (ISSUE 19): host-prefilled lanes the
-        #: while-loop re-arms finishing slots from, in-graph
-        self.refill_ring = int(refill_ring or 0)
-        if self.refill_ring < 0:
-            raise ValueError("refill_ring must be >= 0 (got %d)"
-                             % self.refill_ring)
-        if self.refill_ring and not (self._paged and
-                                     self.megastep_mode == "while"):
-            raise ValueError("refill_ring needs paged_kv and "
-                             "megastep_mode='while' (the ring is "
-                             "published into the while-loop carry as "
-                             "page-table rows)")
-        #: in-graph seeded sampling (ISSUE 19): temperature > 0 samples
-        #: with counter-based prng streams keyed by (lane seed,
-        #: position); 0 keeps greedy argmax and byte-identical programs
-        self.temperature = float(temperature or 0.0)
-        self.top_k = int(top_k or 0)
-        if self.temperature < 0 or self.top_k < 0:
-            raise ValueError("temperature and top_k must be >= 0")
-        self._sampling = self.temperature > 0
         if self.cfg.by_kind or self.cfg.block != "pre_ln":
             # what was not widened to this family says so here, by
             # mechanism — never a wrong answer
@@ -713,14 +656,10 @@ class LMEngine(Logger):
                      "released under it)"),
                     (self.spec_k, "spec_k (the verify program writes k "
                      "positions ahead through one table)"),
-                    (self.megastep, "megastep (the fused scan and while "
-                     "programs carry one table and no window release)"),
-                    (self.refill_ring, "refill_ring (standby lanes are "
-                     "published as rows of one table)"),
+                    (self.megastep, "megastep (the fused scan program "
+                     "carries one table and no window release)"),
                     (self.tp >= 2, "tp >= 2 (lm_param_specs shards the "
-                     "pre_ln tree only)"),
-                    (self._sampling, "temperature > 0 (the seeded sampler "
-                     "is wired into the pre_ln programs only)")):
+                     "pre_ln tree only)")):
                 if on:
                     raise ValueError(
                         "LMEngine: %s is not supported for a %r model%s"
@@ -734,20 +673,6 @@ class LMEngine(Logger):
                 "LMEngine: an expert layer needs paged_kv — the contiguous "
                 "layout's step is a vmap over lanes, and the grouped "
                 "matmul of ops/moe.py has no batching rule")
-        if self._sampling and sample_seed is None:
-            raise ValueError("temperature > 0 needs sample_seed — "
-                             "seeded reproducibility is the contract")
-        self.sample_seed = (None if sample_seed is None
-                            else int(sample_seed))
-        self._sample_key_host = None
-        if self._sampling:
-            from veles_tpu.prng import RandomGenerator
-            # FIXED stream name: the key derivation folds the stream
-            # name into the seed, and sampled outputs must depend on
-            # sample_seed alone — never on what the engine (or its
-            # replica twin on another host) happens to be called
-            self._sample_key_host = numpy.asarray(RandomGenerator(
-                "lm-sample", self.sample_seed).base_key())
         if self._paged and self.max_len % self.prefill_chunk:
             # the paged lane view must tile max_len exactly: a partial
             # tail page would either truncate placeable rows or attend
@@ -906,9 +831,6 @@ class LMEngine(Logger):
         self._last = numpy.zeros(self.slots, numpy.int32)
         self._lanes = [None] * self.slots
         self._free = list(range(self.slots))
-        #: standby refill ring (ISSUE 19): _Standby entries prefilled
-        #: between boundaries, published into the while-loop carry
-        self._ring = []
 
         self._queue = collections.deque()
         self._queued_tokens = 0
@@ -955,10 +877,9 @@ class LMEngine(Logger):
                 lockcheck._witness.dispatch("engine.fence")
             jax.block_until_ready(state)
 
-    def _trace_admitted(self, req, slot=-1):
-        """Lane assignment (``slot``; -1 for a standby-ring entry): the
-        recorder's ``admit`` stamp, and the request's queue-wait span
-        closes when it is traced."""
+    def _trace_admitted(self, req, slot):
+        """Lane assignment (``slot``): the recorder's ``admit`` stamp,
+        and the request's queue-wait span closes when it is traced."""
         req.t_admit_ns = time.monotonic_ns()
         req.lane = slot
         if req.tspan is not None:
@@ -1065,49 +986,6 @@ class LMEngine(Logger):
         kv_pair = (self._kv_shard, self._kv_shard)
         return [kv_pair] * len(self.params["blocks"]), self._repl_shard
 
-    def _make_pick(self):
-        """In-graph seeded sampler (ISSUE 19), or None when greedy —
-        the greedy programs keep their argmax bodies byte-identical to
-        the pre-sampling build.  ``pick1(logits, seed, p)`` draws ONE
-        token from a (vocab,) row with a counter-derived key folded
-        from (engine sample stream, lane seed, absolute position p):
-        the key depends on nothing else, so the tick, scan and while
-        decode paths — spec or not, chunked or not — sample the
-        identical token at the same position given the same seed."""
-        if not self._sampling:
-            return None
-        import jax
-        from veles_tpu.ops.transformer import sample_token
-        base = xfer.to_device(self._sample_key_host)
-        temp, topk = self.temperature, self.top_k
-
-        def pick1(logits, seed, p):
-            key = jax.random.fold_in(jax.random.fold_in(base, seed), p)
-            return sample_token(key, logits, temp, topk)
-
-        return pick1
-
-    def _seed_args(self, seed):
-        """Trailing scalar seed argument for a one-lane sampling
-        dispatch (prefill/chunk) — empty when greedy, so the greedy
-        program signatures stay exactly the pre-sampling ones."""
-        if not self._sampling:
-            return ()
-        return (xfer.to_device(seed, numpy.int32),)
-
-    def _seed_vec(self):
-        """Trailing (slots,) lane-seed vector for the batched decode
-        dispatches: each admitted lane's request seed, 0 for
-        free/prefilling slots (their sampled garbage lands in masked or
-        soon-overwritten writes, so the value never matters)."""
-        if not self._sampling:
-            return ()
-        seeds = numpy.zeros(self.slots, numpy.int32)
-        for slot, lane in enumerate(self._lanes):
-            if lane is not None:
-                seeds[slot] = lane.request.seed
-        return (xfer.to_device(seeds),)
-
     # ------------------------------------------------------------- jitted core
     def _build_jits(self):
         import jax
@@ -1116,13 +994,12 @@ class LMEngine(Logger):
                                                chunk_apply, chunk_embed,
                                                head_logits, prefill)
         cfg, max_len = self.cfg, self.max_len
-        C, k1 = self.prefill_chunk, self.spec_k + 1
+        C = self.prefill_chunk
         if self._paged:
             self._build_paged_jits()
             return
-        pick1 = self._make_pick()
 
-        def prefill_one(params, prompt, true_len, *sargs):
+        def prefill_one(params, prompt, true_len):
             # prompt (1, bucket) int32, true_len traced: positions
             # < true_len are exact under causal attention regardless of
             # pad content (see transformer._generate_impl), so one
@@ -1130,10 +1007,7 @@ class LMEngine(Logger):
             h, caches = prefill(params, prompt, cfg, max_len)
             logits = head_logits(params, jax.lax.dynamic_slice_in_dim(
                 h, true_len - 1, 1, axis=1), cfg)[:, 0, :]
-            if pick1 is None:
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[0]
-            else:
-                tok = pick1(logits[0], sargs[0], true_len)
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[0]
             return tok, caches
 
         def install(caches, rows, slot):
@@ -1142,7 +1016,7 @@ class LMEngine(Logger):
             return [(k.at[slot].set(rk[0]), v.at[slot].set(rv[0]))
                     for (k, v), (rk, rv) in zip(caches, rows)]
 
-        def step_one(params, cache_rows, tok, pos, seed=None):
+        def step_one(params, cache_rows, tok, pos):
             # one lane, one token: feed ``tok`` at ``pos`` against this
             # lane's cache rows; vmapped below over the slot axis so
             # every lane advances in ONE dispatch at its own position
@@ -1154,15 +1028,11 @@ class LMEngine(Logger):
                     blk, x, kc[None], vc[None], pos, cfg, layer=i)
                 new_rows.append((kc[0], vc[0]))
             logits = head_logits(params, x, cfg)[0, 0, :]
-            if pick1 is None:
-                return new_rows, jnp.argmax(logits).astype(jnp.int32)
-            return new_rows, pick1(logits, seed, pos + 1)
+            return new_rows, jnp.argmax(logits).astype(jnp.int32)
 
         kv_tree, repl = self._out_shard_trees()
         pair = (kv_tree, repl) if kv_tree is not None else None
-        step_all = jax.vmap(
-            step_one, in_axes=(None, 0, 0, 0) if pick1 is None
-            else (None, 0, 0, 0, 0))
+        step_all = jax.vmap(step_one, in_axes=(None, 0, 0, 0))
         # programs: prefill
         self._prefill_jit = self._jit(
             prefill_one, (repl, kv_tree) if kv_tree is not None else None)
@@ -1177,7 +1047,7 @@ class LMEngine(Logger):
         self._page_copy_jit = None
         if C:
             def chunk_slot(params, caches, tokens, slot, start,
-                           last_idx, *sargs):
+                           last_idx):
                 # one prompt chunk for ONE lane, straight into the
                 # shared caches at a TRACED (slot, start): positions
                 # [start, start+C) computed against everything already
@@ -1199,12 +1069,7 @@ class LMEngine(Logger):
                 logits = head_logits(
                     params, jax.lax.dynamic_slice_in_dim(
                         h, last_idx, 1, axis=1), cfg)[:, 0, :]
-                if pick1 is None:
-                    tok = jnp.argmax(logits,
-                                     axis=-1).astype(jnp.int32)[0]
-                else:
-                    tok = pick1(logits[0], sargs[0],
-                                start + last_idx + 1)
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[0]
                 return caches, tok
 
             def chunk_extract(caches, slot, start):
@@ -1239,26 +1104,19 @@ class LMEngine(Logger):
         self._verify_jit = None
         verify_all = None
         if self.spec_k:
-            def verify_one(params, cache_rows, toks, pos, seed=None):
+            def verify_one(params, cache_rows, toks, pos):
                 # toks (k+1,) = [last committed, draft…] fed at
                 # positions [pos, pos+k]; returns the greedy argmax
-                # (or the seeded sample at each absolute position)
                 # AFTER each fed token — the host accepts the longest
                 # draft prefix that matches the verifier's own pick, so
-                # output is exact by construction in both modes
+                # output is exact by construction
                 rows = [(kc[None], vc[None]) for kc, vc in cache_rows]
                 h, rows = chunk_apply(params, toks[None], rows, pos, cfg)
                 logits = head_logits(params, h, cfg)[0]  # (k+1, vocab)
-                if pick1 is None:
-                    out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                else:
-                    out = jax.vmap(pick1, in_axes=(0, None, 0))(
-                        logits, seed, pos + 1 + jnp.arange(k1))
+                out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 return [(kc[0], vc[0]) for kc, vc in rows], out
 
-            verify_all = jax.vmap(
-                verify_one, in_axes=(None, 0, 0, 0) if pick1 is None
-                else (None, 0, 0, 0, 0))
+            verify_all = jax.vmap(verify_one, in_axes=(None, 0, 0, 0))
             # programs: verify
             self._verify_jit = self._jit(verify_all, pair, storage=1)
 
@@ -1297,7 +1155,6 @@ class LMEngine(Logger):
                                                paged_chunk_apply)
         cfg = self.cfg
         kern = self._kernel_active
-        pick1 = self._make_pick()
 
         full, sliding = model_config.FULL, model_config.SLIDING
         # the step of a record with an expert layer also returns the
@@ -1314,10 +1171,9 @@ class LMEngine(Logger):
             tabs, wbase = ptab
             return tabs, {full: None, sliding: wbase}
 
-        def chunk_slot(params, pools, ptab, tokens, start, last_idx,
-                       *sargs):
+        def chunk_slot(params, pools, ptab, tokens, start, last_idx):
             # one lane's prompt chunk through its page table; returns
-            # the pick after ``last_idx`` (read on the tail chunk)
+            # the argmax after ``last_idx`` (read on the tail chunk)
             tokens = tokens[None]
             tabs, base = tables_of(jax.tree.map(lambda t: t[None], ptab))
             h, pools = paged_chunk_apply(
@@ -1325,13 +1181,10 @@ class LMEngine(Logger):
                 cfg, attn_kernel="prefill" if kern else None, base=base)
             logits = head_logits(params, jax.lax.dynamic_slice_in_dim(
                 h, last_idx, 1, axis=1), cfg)[:, 0, :]
-            if pick1 is None:
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[0]
-            else:
-                tok = pick1(logits[0], sargs[0], start + last_idx + 1)
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[0]
             return pools, tok
 
-        def step_all(params, pools, ptabs, toks, pos, *sargs):
+        def step_all(params, pools, ptabs, toks, pos):
             # ONE dispatch advances every lane by one token at its own
             # position through its own page table
             tabs, base = tables_of(ptabs)
@@ -1340,10 +1193,7 @@ class LMEngine(Logger):
                 attn_kernel="decode" if kern else None, base=base,
                 with_stats=stats)
             logits = head_logits(params, h, cfg)[:, 0, :]
-            if pick1 is None:
-                toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            else:
-                toks = jax.vmap(pick1)(logits, sargs[0], pos + 1)
+            toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return (pools, toks, *counts)
 
         def page_copy(pools, src, dst):
@@ -1367,22 +1217,15 @@ class LMEngine(Logger):
         self._chunk_extract_jit = None
         self._verify_jit = None
         if self.spec_k:
-            def verify_all(params, pools, ptabs, toks, pos, *sargs):
+            def verify_all(params, pools, ptabs, toks, pos):
                 # toks (slots, k+1) = [last committed, draft…] per lane;
-                # returns the greedy argmax (or the seeded sample at
-                # each absolute position) AFTER each fed position
+                # returns the greedy argmax AFTER each fed position
                 h, pools = paged_chunk_apply(
                     params, toks, pools, ptabs, pos, cfg,
                     attn_kernel="decode" if kern else None)
                 logits = head_logits(params, h)      # (slots, k+1, v)
-                if pick1 is None:
-                    return pools, jnp.argmax(
-                        logits, axis=-1).astype(jnp.int32)
-                pp = pos[:, None] + 1 \
-                    + jnp.arange(toks.shape[1])[None, :]
-                return pools, jax.vmap(
-                    jax.vmap(pick1, in_axes=(0, None, 0)))(
-                        logits, sargs[0], pp)
+                return pools, jnp.argmax(
+                    logits, axis=-1).astype(jnp.int32)
 
             # programs: verify
             self._verify_jit = self._jit(verify_all, pair, storage=1)
@@ -1399,25 +1242,12 @@ class LMEngine(Logger):
         """Build and jit the fused megastep program (or leave it None
         below K=2) — THE one wiring both layout builders share, so the
         output arity and the tp-mesh out_shardings pin (storage, last,
-        pos, emitted[, accs]) can never drift between them.  ISSUE 19:
-        megastep_mode='while' wires the early-exit lax.while_loop
-        variant into ``_whilestep_jit`` instead — its own jit-guard
-        census family (``whilestep``), its own output arity (storage,
-        last, pos, emitted, iters[, accs][, assign])."""
+        pos, emitted[, accs]) can never drift between them."""
         self._megastep_jit = None
-        self._whilestep_jit = None
         if self.megastep < 2:
             return
         mega = self._make_megastep_body(step_all=step_all,
                                         verify_all=verify_all)
-        if self.megastep_mode == "while":
-            n_out = 5 + (1 if self.spec_k else 0) \
-                + (1 if self.refill_ring else 0)
-            out_sh = ((kv_tree,) + (repl,) * (n_out - 1)
-                      if kv_tree is not None else None)
-            # programs: whilestep
-            self._whilestep_jit = self._jit(mega, out_sh, storage=1)
-            return
         n_out = 5 if self.spec_k else 4
         out_sh = ((kv_tree,) + (repl,) * (n_out - 1)
                   if kv_tree is not None else None)
@@ -1431,14 +1261,16 @@ class LMEngine(Logger):
         so any K is bit-identical to K repeated host ticks by
         construction.
 
-        Signature of the returned function: ``(params, storage[,
-        ptabs], last, pos, left[, hist, hlen]) -> (storage, last, pos,
-        emitted[, accs])`` where ``storage`` is the contiguous caches
-        or the paged pools, ``emitted`` is (K, slots) int32 — or
-        (K, slots, spec_k+1) speculative — with -1 marking positions a
-        frozen (early-exited or never-active) lane did not emit, and
-        ``accs`` (K, slots) carries each iteration's draft-acceptance
-        count (-1 when frozen) for the host's metering.
+        Signature of the returned function, one per (layout, spec_k):
+        ``(params, storage[, ptabs], last, pos, left[, hist, hlen]) ->
+        (storage, last, pos, emitted[, accs])`` — ``ptabs`` on the
+        paged layout, ``hist, hlen`` and ``accs`` with ``spec_k`` —
+        where ``storage`` is the contiguous caches or the paged pools,
+        ``emitted`` is (K, slots) int32 — or (K, slots, spec_k+1)
+        speculative — with -1 marking positions a frozen (early-exited
+        or never-active) lane did not emit, and ``accs`` (K, slots)
+        carries each iteration's draft-acceptance count (-1 when
+        frozen) for the host's metering.
 
         EARLY-EXIT MASKING: a lane whose ``left`` hits 0 freezes — its
         last token, position and history stop advancing, its emitted
@@ -1465,100 +1297,12 @@ class LMEngine(Logger):
         cfg = self.cfg
         kern = self._kernel_active
         L = self.max_len
-        slots = self.slots
-        pick1 = self._make_pick()
-        sampling = pick1 is not None
-        R = self.refill_ring if self.megastep_mode == "while" else 0
         if paged:
             from veles_tpu.ops.transformer import (head_logits,
                                                    paged_chunk_apply)
-        # frozen-lane feed clamp: an active lane's legitimate feed
-        # positions never reach it (admission reserves n_new + spec_k
-        # headroom), and a finished lane's garbage verify window
-        # [pos, pos+k] must stay inside [0, max_len)
-        cap = xfer.to_device(L - 1 - k, numpy.int32)
-
-        if k:
-            from veles_tpu.ops.transformer import propose_draft_in_graph
-            ngram = self.spec_ngram
-            propose_all = jax.vmap(
-                lambda h, hl: propose_draft_in_graph(h, hl, k, ngram))
-            cols = xfer.to_device(numpy.arange(k + 1)[None, :])
-
-            def spec_iter(params, storage, ptabs, seeds, carry):
-                last, pos, left, hist, hlen = carry
-                active = left > 0
-                draft, _found = propose_all(hist, hlen)
-                toks = jnp.concatenate([last[:, None], draft], axis=1)
-                if paged:
-                    h, storage = paged_chunk_apply(
-                        params, toks, storage, ptabs, pos, cfg,
-                        attn_kernel="decode" if kern else None,
-                        write_mask=active)
-                    logits = head_logits(params, h)
-                    if pick1 is None:
-                        out = jnp.argmax(logits,
-                                         axis=-1).astype(jnp.int32)
-                    else:
-                        out = jax.vmap(jax.vmap(
-                            pick1, in_axes=(0, None, 0)))(
-                            logits, seeds, pos[:, None] + 1 + cols)
-                elif pick1 is None:
-                    storage, out = verify_all(params, storage, toks,
-                                              pos)
-                else:
-                    storage, out = verify_all(params, storage, toks,
-                                              pos, seeds)
-                # leading draft/argmax matches; accepted tokens ARE
-                # out[:acc], so the emit window is simply out[:take]
-                matches = (draft == out[:, :k]).astype(jnp.int32)
-                acc = jnp.cumprod(matches, axis=1).sum(axis=1)
-                take = jnp.minimum(acc + 1, left)
-                emit = jnp.where(
-                    active[:, None] & (cols < take[:, None]), out, -1)
-                # history append: the full (k+1) window lands at hlen
-                # (start clamped so the update can never shift); rows
-                # past `take` are overwritten by the next append or
-                # never read — draft quality is speed-only
-                hist = jax.vmap(
-                    lambda h_, hl, row, act: jnp.where(
-                        act, jax.lax.dynamic_update_slice(
-                            h_, row,
-                            (jnp.minimum(hl, L - (k + 1)),)), h_))(
-                    hist, hlen, out, active)
-                hlen = jnp.where(active,
-                                 jnp.minimum(hlen + take, L), hlen)
-                last = jnp.where(active, jnp.take_along_axis(
-                    out, acc[:, None], axis=1)[:, 0], last)
-                pos = jnp.where(active,
-                                jnp.minimum(pos + acc + 1, cap), pos)
-                left = left - jnp.where(active, take, 0)
-                return storage, (last, pos, left, hist, hlen), \
-                    (emit, jnp.where(active, acc, -1))
-
-            if self.megastep_mode != "while":
-                def mega_spec(params, storage, ptabs, last, pos, left,
-                              hist, hlen, *sargs):
-                    seeds = sargs[0] if sampling else None
-
-                    def body(carry, _):
-                        storage, rest = carry
-                        storage, rest, out = spec_iter(
-                            params, storage, ptabs, seeds, rest)
-                        return (storage, rest), out
-
-                    (storage, rest), (emitted, accs) = jax.lax.scan(
-                        body, (storage, (last, pos, left, hist, hlen)),
-                        None, length=K)
-                    return storage, rest[0], rest[1], emitted, accs
-
-                if paged:
-                    return mega_spec
-                return lambda params, storage, *a: mega_spec(
-                    params, storage, None, *a)
 
         if not k:
-            def plain_iter(params, storage, ptabs, seeds, carry):
+            def plain_iter(params, storage, ptabs, carry):
                 last, pos, left = carry
                 active = left > 0
                 if paged:
@@ -1567,165 +1311,97 @@ class LMEngine(Logger):
                         attn_kernel="decode" if kern else None,
                         write_mask=active)
                     logits = head_logits(params, h)[:, 0, :]
-                    if pick1 is None:
-                        toks = jnp.argmax(logits,
-                                          axis=-1).astype(jnp.int32)
-                    else:
-                        toks = jax.vmap(pick1)(logits, seeds, pos + 1)
-                elif pick1 is None:
-                    storage, toks = step_all(params, storage, last,
-                                             pos)
+                    toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 else:
-                    storage, toks = step_all(params, storage, last,
-                                             pos, seeds)
+                    storage, toks = step_all(params, storage, last, pos)
                 emit = jnp.where(active, toks, -1)
                 last = jnp.where(active, toks, last)
                 pos = jnp.where(active, pos + 1, pos)
                 left = left - jnp.where(active, 1, 0)
                 return storage, (last, pos, left), emit
 
-            if self.megastep_mode != "while":
-                def mega_plain(params, storage, ptabs, last, pos, left,
-                               *sargs):
-                    seeds = sargs[0] if sampling else None
+            def mega_plain(params, storage, ptabs, last, pos, left):
+                def body(carry, _):
+                    storage, rest = carry
+                    storage, rest, emit = plain_iter(
+                        params, storage, ptabs, rest)
+                    return (storage, rest), emit
 
-                    def body(carry, _):
-                        storage, rest = carry
-                        storage, rest, emit = plain_iter(
-                            params, storage, ptabs, seeds, rest)
-                        return (storage, rest), emit
+                (storage, rest), emitted = jax.lax.scan(
+                    body, (storage, (last, pos, left)), None, length=K)
+                return storage, rest[0], rest[1], emitted
 
-                    (storage, rest), emitted = jax.lax.scan(
-                        body, (storage, (last, pos, left)), None,
-                        length=K)
-                    return storage, rest[0], rest[1], emitted
+            if paged:
+                return mega_plain
+            return lambda params, storage, last, pos, left: mega_plain(
+                params, storage, None, last, pos, left)
 
-                if paged:
-                    return mega_plain
-                return lambda params, storage, *a: mega_plain(
-                    params, storage, None, *a)
+        from veles_tpu.ops.transformer import propose_draft_in_graph
+        ngram = self.spec_ngram
+        propose_all = jax.vmap(
+            lambda h, hl: propose_draft_in_graph(h, hl, k, ngram))
+        cols = xfer.to_device(numpy.arange(k + 1)[None, :])
+        # frozen-lane feed clamp: an active lane's legitimate feed
+        # positions never reach it (admission reserves n_new + spec_k
+        # headroom), and a finished lane's garbage verify window
+        # [pos, pos+k] must stay inside [0, max_len)
+        cap = xfer.to_device(L - 1 - k, numpy.int32)
 
-        # ---- ISSUE 19: the persistent-loop variant — same iteration
-        # body, but driven by lax.while_loop so the program EXITS as
-        # soon as every live lane (and the published standby ring) is
-        # drained instead of burning masked iterations to the K
-        # boundary.  Stacked per-iteration outputs land in a fixed
-        # (K, ...) buffer via dynamic_update_slice (while_loop has no
-        # scan-style stacking), so the output shapes — and the program
-        # family — stay exactly the scan megastep's.  Idle slots enter
-        # with left = -1 so only a slot that DRAINED (left hit 0 from
-        # a positive value, or was published as re-armable) can take a
-        # standby entry.
-        def mega_while(params, storage, ptabs, last, pos, left, *rest):
-            rest = list(rest)
-            if k:
-                hist, hlen = rest.pop(0), rest.pop(0)
-            seeds = rest.pop(0) if sampling else None
-            if R:
-                ring_tabs, ring_last = rest.pop(0), rest.pop(0)
-                ring_pos, ring_left = rest.pop(0), rest.pop(0)
-                if k:
-                    ring_hist, ring_hlen = rest.pop(0), rest.pop(0)
-                if sampling:
-                    ring_seeds = rest.pop(0)
-                count = rest.pop(0)
-            c = {"storage": storage, "ptabs": ptabs, "last": last,
-                 "pos": pos, "left": left, "i": jnp.int32(0),
-                 "emitted": jnp.full((K, slots, k + 1) if k
-                                     else (K, slots), -1, jnp.int32)}
-            if k:
-                c["hist"], c["hlen"] = hist, hlen
-                c["accs"] = jnp.full((K, slots), -1, jnp.int32)
-            if sampling:
-                c["seeds"] = seeds
-            if R:
-                c["head"] = jnp.int32(0)
-                c["assign"] = jnp.full((R,), -1, jnp.int32)
+        def spec_iter(params, storage, ptabs, carry):
+            last, pos, left, hist, hlen = carry
+            active = left > 0
+            draft, _found = propose_all(hist, hlen)
+            toks = jnp.concatenate([last[:, None], draft], axis=1)
+            if paged:
+                h, storage = paged_chunk_apply(
+                    params, toks, storage, ptabs, pos, cfg,
+                    attn_kernel="decode" if kern else None,
+                    write_mask=active)
+                logits = head_logits(params, h)
+                out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            else:
+                storage, out = verify_all(params, storage, toks, pos)
+            # leading draft/argmax matches; accepted tokens ARE
+            # out[:acc], so the emit window is simply out[:take]
+            matches = (draft == out[:, :k]).astype(jnp.int32)
+            acc = jnp.cumprod(matches, axis=1).sum(axis=1)
+            take = jnp.minimum(acc + 1, left)
+            emit = jnp.where(
+                active[:, None] & (cols < take[:, None]), out, -1)
+            # history append: the full (k+1) window lands at hlen
+            # (start clamped so the update can never shift); rows
+            # past `take` are overwritten by the next append or
+            # never read — draft quality is speed-only
+            hist = jax.vmap(
+                lambda h_, hl, row, act: jnp.where(
+                    act, jax.lax.dynamic_update_slice(
+                        h_, row, (jnp.minimum(hl, L - (k + 1)),)), h_))(
+                hist, hlen, out, active)
+            hlen = jnp.where(active, jnp.minimum(hlen + take, L), hlen)
+            last = jnp.where(active, jnp.take_along_axis(
+                out, acc[:, None], axis=1)[:, 0], last)
+            pos = jnp.where(active, jnp.minimum(pos + acc + 1, cap), pos)
+            left = left - jnp.where(active, take, 0)
+            return storage, (last, pos, left, hist, hlen), \
+                (emit, jnp.where(active, acc, -1))
 
-            def cond(c):
-                live = jnp.any(c["left"] > 0)
-                if R:
-                    live = live | (c["head"] < count)
-                return (c["i"] < K) & live
+        def mega_spec(params, storage, ptabs, last, pos, left, hist,
+                      hlen):
+            def body(carry, _):
+                storage, rest = carry
+                storage, rest, out = spec_iter(params, storage, ptabs,
+                                               rest)
+                return (storage, rest), out
 
-            def body(c):
-                c = dict(c)
-                if R:
-                    # in-graph re-arm: each drained slot (left == 0)
-                    # takes the next unconsumed ring entry — frontier,
-                    # page-table row, history and seed all swap in one
-                    # masked select; ``assign`` records entry -> slot
-                    # so the host can attribute the emitted rows at
-                    # the boundary.  Unrolled over the small slot
-                    # count; at most one entry arms per slot per
-                    # iteration, which is exact (a slot drains at most
-                    # once per iteration).
-                    for s in range(slots):
-                        idx = jnp.minimum(c["head"], R - 1)
-                        take = (c["left"][s] == 0) & \
-                            (c["head"] < count)
-                        c["ptabs"] = jnp.where(
-                            take,
-                            c["ptabs"].at[s].set(ring_tabs[idx]),
-                            c["ptabs"])
-                        c["last"] = c["last"].at[s].set(jnp.where(
-                            take, ring_last[idx], c["last"][s]))
-                        c["pos"] = c["pos"].at[s].set(jnp.where(
-                            take, ring_pos[idx], c["pos"][s]))
-                        c["left"] = c["left"].at[s].set(jnp.where(
-                            take, ring_left[idx], c["left"][s]))
-                        if k:
-                            c["hist"] = jnp.where(
-                                take,
-                                c["hist"].at[s].set(ring_hist[idx]),
-                                c["hist"])
-                            c["hlen"] = c["hlen"].at[s].set(
-                                jnp.where(take, ring_hlen[idx],
-                                          c["hlen"][s]))
-                        if sampling:
-                            c["seeds"] = c["seeds"].at[s].set(
-                                jnp.where(take, ring_seeds[idx],
-                                          c["seeds"][s]))
-                        c["assign"] = c["assign"].at[idx].set(
-                            jnp.where(take, s, c["assign"][idx]))
-                        c["head"] = c["head"] + take.astype(jnp.int32)
-                if k:
-                    carry = (c["last"], c["pos"], c["left"],
-                             c["hist"], c["hlen"])
-                    c["storage"], carry, (emit, acc) = spec_iter(
-                        params, c["storage"], c["ptabs"],
-                        c.get("seeds"), carry)
-                    (c["last"], c["pos"], c["left"], c["hist"],
-                     c["hlen"]) = carry
-                    c["accs"] = jax.lax.dynamic_update_slice(
-                        c["accs"], acc[None], (c["i"], 0))
-                    c["emitted"] = jax.lax.dynamic_update_slice(
-                        c["emitted"], emit[None], (c["i"], 0, 0))
-                else:
-                    carry = (c["last"], c["pos"], c["left"])
-                    c["storage"], carry, emit = plain_iter(
-                        params, c["storage"], c["ptabs"],
-                        c.get("seeds"), carry)
-                    c["last"], c["pos"], c["left"] = carry
-                    c["emitted"] = jax.lax.dynamic_update_slice(
-                        c["emitted"], emit[None], (c["i"], 0))
-                c["i"] = c["i"] + 1
-                return c
-
-            # programs: whilestep
-            c = jax.lax.while_loop(cond, body, c)
-            res = [c["storage"], c["last"], c["pos"], c["emitted"],
-                   c["i"]]
-            if k:
-                res.append(c["accs"])
-            if R:
-                res.append(c["assign"])
-            return tuple(res)
+            (storage, rest), (emitted, accs) = jax.lax.scan(
+                body, (storage, (last, pos, left, hist, hlen)), None,
+                length=K)
+            return storage, rest[0], rest[1], emitted, accs
 
         if paged:
-            return mega_while
-        return lambda params, storage, *a: mega_while(
-            params, storage, None, *a)
+            return mega_spec
+        return lambda params, storage, last, pos, left, hist, hlen: \
+            mega_spec(params, storage, None, last, pos, left, hist, hlen)
 
     # --------------------------------------------------------------- lifecycle
     def _warmup(self):
@@ -1748,12 +1424,6 @@ class LMEngine(Logger):
         returns one storage leaf as it went into the last."""
         zero = xfer.to_device(0, numpy.int32)
         zeros = xfer.to_device(numpy.zeros(self.slots, numpy.int32))
-        # seeded sampling appends a trailing seed argument per program
-        # family (scalar for the one-lane programs, a lane vector for
-        # the batched ones) — warm with it or the first sampled
-        # dispatch compiles inside the serving loop
-        s1 = (zero,) if self._sampling else ()
-        sv = (zeros,) if self._sampling else ()
         if self._paged:
             ptabs = numpy.zeros((self.slots, self._max_pages),
                                 numpy.int32)
@@ -1761,82 +1431,73 @@ class LMEngine(Logger):
                 self.params, self._kv_pools,
                 self._table_args(ptabs[0], 0),
                 xfer.to_device(numpy.zeros(self.prefill_chunk,
-                                           numpy.int32)), zero, zero,
-                *s1)
+                                           numpy.int32)), zero, zero)
             self._kv_pools = self._page_copy_jit(self._kv_pools, zero,
                                                  zero)
-            # step/verify (or the fused megastep / whilestep, which
-            # REPLACES them on the decode loop) compile one program per
+            # step/verify (or the fused megastep, which REPLACES them
+            # on the decode loop) compile one program per
             # live-width ladder entry (ISSUE 7) — warm EVERY entry now,
             # or the first request to cross each width boundary pays
             # its compile inside the serving loop
             for w in self._width_ladder:
                 wtab = self._table_args(ptabs[:, :w], slice(None))
-                fused = self._whilestep_jit or self._megastep_jit
-                if fused is not None:
+                if self._megastep_jit is not None:
                     args = [self.params, self._kv_pools, wtab,
                             zeros, zeros, zeros]
                     if self.spec_k:
                         args += [xfer.to_device(numpy.zeros(
                             (self.slots, self.max_len), numpy.int32)),
                             zeros]
-                    args += sv
-                    if self._whilestep_jit is not None \
-                            and self.refill_ring:
-                        args += self._ring_zero_args(w)
                     went_in = self._kv_pools[0][0]
-                    self._kv_pools = fused(*args)[0]
+                    self._kv_pools = self._megastep_jit(*args)[0]
                     continue
                 if self._verify_jit is not None:
                     self._kv_pools, _ = self._verify_jit(
                         self.params, self._kv_pools, wtab,
                         xfer.to_device(numpy.zeros(
                             (self.slots, self.spec_k + 1),
-                            numpy.int32)), zeros, *sv)
+                            numpy.int32)), zeros)
                 went_in = self._kv_pools[0][0]
                 self._kv_pools = self._step_jit(
-                    self.params, self._kv_pools, wtab, zeros, zeros,
-                    *sv)[0]
+                    self.params, self._kv_pools, wtab, zeros, zeros)[0]
         else:
             tok, rows = self._prefill_jit(
                 self.params,
                 xfer.to_device(numpy.zeros(
                     (1, prompt_bucket(1, self.max_len)), numpy.int32)),
-                xfer.to_device(1, numpy.int32), *s1)
+                xfer.to_device(1, numpy.int32))
             self._caches = self._install_jit(self._caches, rows, zero)
             if self._chunk_jit is not None:
                 self._caches, _ = self._chunk_jit(
                     self.params, self._caches,
                     xfer.to_device(numpy.zeros(self.prefill_chunk,
                                                numpy.int32)), zero,
-                    zero, zero, *s1)
+                    zero, zero)
                 crows = self._chunk_extract_jit(self._caches, zero,
                                                 zero)
                 self._caches = self._chunk_install_jit(self._caches,
                                                        crows, zero,
                                                        zero)
-            fused = self._whilestep_jit or self._megastep_jit
-            if fused is not None:
+            if self._megastep_jit is not None:
                 args = [self.params, self._caches, zeros, zeros, zeros]
                 if self.spec_k:
                     args += [xfer.to_device(numpy.zeros(
                         (self.slots, self.max_len), numpy.int32)),
                         zeros]
-                args += sv
                 went_in = self._caches[0][0]
-                self._caches = fused(*args)[0]
+                self._caches = self._megastep_jit(*args)[0]
             else:
                 if self._verify_jit is not None:
                     self._caches, _ = self._verify_jit(
                         self.params, self._caches,
                         xfer.to_device(numpy.zeros(
                             (self.slots, self.spec_k + 1),
-                            numpy.int32)), zeros, *sv)
+                            numpy.int32)), zeros)
                 went_in = self._caches[0][0]
                 self._caches, _ = self._step_jit(
                     self.params, self._caches, zeros,
                     xfer.to_device(numpy.ones(self.slots,
-                                              numpy.int32)), *sv)
+                                              numpy.int32)))
         return went_in
 
     def start(self):
@@ -1964,9 +1625,9 @@ class LMEngine(Logger):
     def _peek_swap(self):
         """Racy worker peek at the pending weight swap.  Read-only:
         every consumer that acts on the result re-checks (and claims)
-        under ``_cond`` — ``_admit``/``_admit_ring``/``_advance_ring``/
-        ``_step_while`` only use it to hold work back for a tick, and
-        ``_maybe_apply_swap`` re-validates identity before claiming."""
+        under ``_cond`` — ``_admit`` only uses it to hold work back for
+        a tick, and ``_maybe_apply_swap`` re-validates identity before
+        claiming."""
         # lint: allow(lock-discipline): racy worker peek; claim re-checked under _cond
         return self._pending_swap
 
@@ -1994,10 +1655,6 @@ class LMEngine(Logger):
             self._pending_swap = None
         if active:
             self._requeue_active(active)
-        if self._ring:
-            # standby prefill ran on the OLD weights — stale KV the
-            # moment the new tree installs
-            self._requeue_ring()
         t0a = time.monotonic()
         try:
             self._fault("engine.swap")
@@ -2160,13 +1817,6 @@ class LMEngine(Logger):
             # checkpoint never iterates a mutating dict.
             self._rid += 1
             rid = self._rid
-            # seeded-sampling lane seed (ISSUE 19): the admission id is
-            # deterministic per submission order, so the same traffic
-            # replayed against any engine config (tick/scan/while,
-            # paged or contiguous, tp=1/2) folds the SAME (seed, pos)
-            # coordinates into the sampling stream — that is what the
-            # seeded-parity matrix asserts
-            req.seed = rid
             self._journal[rid] = req
             req.future.add_done_callback(
                 lambda f, rid=rid, req=req: self._settled(rid, req, f))
@@ -2398,12 +2048,6 @@ class LMEngine(Logger):
             for p in lane.pages:
                 want_refs[p] += 1
                 want_pins[p] += 1
-        for entry in self._ring:
-            # standby-ring occupants hold pages exactly like lanes
-            # (ISSUE 19) — a leaked ring page is a violation here too
-            for p in entry.lane.pages:
-                want_refs[p] += 1
-                want_pins[p] += 1
         if self._trie is not None:
             stack = list(self._trie.root.children.values())
             while stack:
@@ -2501,8 +2145,7 @@ class LMEngine(Logger):
                 tok, rows = self._prefill_jit(
                     self.params,
                     xfer.to_device(prompt[None], numpy.int32),
-                    xfer.to_device(req.true_len, numpy.int32),
-                    *self._seed_args(req.seed))
+                    xfer.to_device(req.true_len, numpy.int32))
                 with self._donating():
                     self._caches = self._install_jit(
                         self._caches, rows,
@@ -2790,20 +2433,15 @@ class LMEngine(Logger):
         return tables, xfer.to_device(wt.base[rows] * wt.page,
                                       numpy.int32)
 
-    def _live_width(self, span, floor=0):
+    def _live_width(self, span):
         """Ladder-bucketed page-table width for a decode/verify step
         writing ``span`` positions per lane: the smallest power-of-two
         (capped at max_pages) covering EVERY slot's frontier —
         ``_pos`` includes prefilling lanes' parked frontiers and the
         inactive lanes' 0, so the batched step's garbage writes always
         land inside the sliced table (take_along_axis would otherwise
-        CLAMP an out-of-range page lookup onto a live page).  ``floor``
-        raises the covered frontier past the slots' own — the while
-        megastep passes its published standby lanes' positions so a
-        ring entry armed mid-loop writes inside the sliced width
-        too."""
-        need = -(-(max(int(self._pos.max()), floor) + span)
-                 // self.prefill_chunk)
+        CLAMP an out-of-range page lookup onto a live page)."""
+        need = -(-(int(self._pos.max()) + span) // self.prefill_chunk)
         for w in self._width_ladder:
             if w >= need:
                 return w
@@ -2925,8 +2563,7 @@ class LMEngine(Logger):
             args = (xfer.to_device(tokens, numpy.int32),
                     xfer.to_device(slot, numpy.int32),
                     xfer.to_device(start, numpy.int32),
-                    xfer.to_device(last_idx, numpy.int32)) \
-                + self._seed_args(req.seed)
+                    xfer.to_device(last_idx, numpy.int32))
             self.recorder.dispatch(tracing.PREFILL_DISPATCH,
                                    self._chunk_jit)
             with self._donating():
@@ -3027,8 +2664,7 @@ class LMEngine(Logger):
             args = (self._table_args(self._page_tables[slot], slot),
                     xfer.to_device(tokens, numpy.int32),
                     xfer.to_device(start, numpy.int32),
-                    xfer.to_device(last_idx, numpy.int32)) \
-                + self._seed_args(req.seed)
+                    xfer.to_device(last_idx, numpy.int32))
             self.recorder.dispatch(tracing.PREFILL_DISPATCH,
                                    self._chunk_jit)
             with self._donating():
@@ -3173,7 +2809,7 @@ class LMEngine(Logger):
         consumed, :meth:`_storage_lost` fails every request that held
         rows in it and installs fresh storage BEFORE the exception
         reaches the handler, which then finds its own work already
-        failed (``_teardown_slot`` / ``_fail_standby`` tolerate that).
+        failed (``_teardown_slot`` tolerates that).
         ``_kv_pools`` / ``_caches`` never point at deleted or poisoned
         buffers when the loop takes its next turn."""
         leaf = self._storage()[0][0]
@@ -3187,9 +2823,9 @@ class LMEngine(Logger):
     def _storage_lost(self, exc):
         """The KV storage went down with a failed dispatch
         (:meth:`_donating`): every request that holds pages or a slot
-        row — decoding lanes, prefilling lanes, standby-ring entries —
-        fails with ``exc``; the prefix trie is dropped (its rows are
-        gone); the page allocator comes home whole through those
+        row — decoding lanes, prefilling lanes — fails with ``exc``;
+        the prefix trie is dropped (its rows are gone); the page
+        allocator comes home whole through those
         releases and every table row parks on scratch; fresh zero
         storage takes the place of the lost one; ``kv_storage_rebuilds``
         counts it.  Queued requests are untouched: they hold nothing
@@ -3198,12 +2834,9 @@ class LMEngine(Logger):
                 if lane is not None]
         self.warning(
             "KV storage consumed by a failed dispatch (%s): failing %d "
-            "lane(s) and %d standby entr(ies), rebuilding the storage",
-            exc, len(held), len(self._ring))
+            "lane(s), rebuilding the storage", exc, len(held))
         for slot in held:
             self._teardown_slot(slot, self._lanes[slot], exc)
-        for entry in list(self._ring):
-            self._fail_standby(entry, exc)
         if self._trie is not None:
             self._trie.clear()
             self.metrics.set_gauge("prefix_cache_chunks", 0)
@@ -3230,8 +2863,8 @@ class LMEngine(Logger):
                 self._teardown_slot(slot, self._lanes[slot], exc)
 
     def _dispatch_decode(self, decode_jit, args, lanes, tctxs):   # hot-path
-        """THE decode dispatch all four drivers share (tick, verify, scan
-        and while megastep): ``decode_jit`` over the parameters, the KV
+        """THE decode dispatch all three drivers share (tick, verify and
+        megastep): ``decode_jit`` over the parameters, the KV
         storage — DONATED: the program updates it in place and the tree
         passed in is dead when the call returns — and ``args``, already
         on the device (the puts belong to ``step.prepare``); then the
@@ -3285,7 +2918,7 @@ class LMEngine(Logger):
                 args = (self._table_args(self._page_tables[:, :w],
                                          slice(None)),)
             args += (xfer.to_device(self._last),
-                     xfer.to_device(self._pos)) + self._seed_vec()
+                     xfer.to_device(self._pos))
             toks, *counts = self._dispatch_decode(self._step_jit, args,
                                                   len(active), tctxs)
             if counts:
@@ -3365,7 +2998,7 @@ class LMEngine(Logger):
                 w = self._live_width(k + 1)
                 args = (xfer.to_device(self._page_tables[:, :w]),)
             args += (xfer.to_device(toks_in),
-                     xfer.to_device(self._pos)) + self._seed_vec()
+                     xfer.to_device(self._pos))
             out, = self._dispatch_decode(self._verify_jit, args,
                                          len(active), tctxs)
         except Exception as e:   # noqa: BLE001 — fails the lanes
@@ -3447,7 +3080,6 @@ class LMEngine(Logger):
                 hist[slot, :len(row)] = row
                 hlen[slot] = len(row)
             extra = (xfer.to_device(hist), xfer.to_device(hlen))
-        extra = extra + self._seed_vec()
         w = None
         tctxs = ()
         if self._tracer is not None:
@@ -3527,480 +3159,6 @@ class LMEngine(Logger):
             if lane.remaining == 0 or lane.request.cancelled:
                 self._finish(slot)
 
-    # ---------------------------------------------- ISSUE 19: while megastep
-    def _ring_args(self, pub, w):
-        """Device arguments publishing ``pub`` (the READY standby
-        entries) into the while-megastep carry, zero-padded to the
-        fixed ring size R — the program family depends on R and the
-        page-table width, never on occupancy (count=0 simply arms
-        nothing).  Padding table rows park on SCRATCH like a free
-        slot's."""
-        R = self.refill_ring
-        tabs = numpy.full((R, w), KVPagePool.SCRATCH, numpy.int32)
-        last = numpy.zeros(R, numpy.int32)
-        pos = numpy.zeros(R, numpy.int32)
-        left = numpy.zeros(R, numpy.int32)
-        if self.spec_k:
-            hist = numpy.zeros((R, self.max_len), numpy.int32)
-            hlen = numpy.zeros(R, numpy.int32)
-        seeds = numpy.zeros(R, numpy.int32)
-        for j, entry in enumerate(pub):
-            lane = entry.lane
-            tabs[j] = entry.table[:w]
-            last[j] = entry.last
-            pos[j] = entry.pos
-            left[j] = lane.remaining
-            if self.spec_k:
-                row = numpy.concatenate(
-                    [lane.request.prompt,
-                     numpy.asarray(lane.emitted, numpy.int32)])
-                hist[j, :len(row)] = row
-                hlen[j] = len(row)
-            seeds[j] = lane.request.seed
-        args = [xfer.to_device(tabs), xfer.to_device(last),
-                xfer.to_device(pos), xfer.to_device(left)]
-        if self.spec_k:
-            args += [xfer.to_device(hist), xfer.to_device(hlen)]
-        if self._sampling:
-            args.append(xfer.to_device(seeds))
-        args.append(xfer.to_device(len(pub), numpy.int32))
-        return args
-
-    def _ring_zero_args(self, w):
-        """Empty-ring dispatch arguments at width ``w`` (warmup)."""
-        return self._ring_args([], w)
-
-    def _step_while(self, active):   # hot-path
-        """ONE early-exit fused dispatch (ISSUE 19): the
-        ``lax.while_loop`` megastep advances every active lane until
-        ALL are drained — or the K-iteration cap lands — instead of
-        burning masked iterations to a fixed-K boundary, and arms
-        published standby-ring lanes into slots that drain mid-loop.
-        The host's boundary work mirrors :meth:`_step_megastep` plus:
-        read back the REALIZED iteration count (the span/ledger and
-        waste metering quote it, not the cap), split each slot's
-        emitted stream between the outgoing lane and its in-graph
-        replacements (sequential by construction: a lane only stops
-        emitting when drained, and ring entries arm in ring order),
-        resolve replacements that finished inside the loop, and
-        install the last unfinished replacement as the slot's lane."""
-        K, k = self.megastep, self.spec_k
-        span = K * (k + 1) + k if k else K
-        if self._paged:
-            active = self._cow_guard_active(active, span)
-            if not active:
-                return
-        left = numpy.full(self.slots, -1, numpy.int32)
-        for slot in active:
-            left[slot] = self._lanes[slot].remaining
-        pub = []
-        if self.refill_ring:
-            # a free slot enters at left=0: rearm-eligible from
-            # iteration 0 (a mid-loop drain is just the common case,
-            # not a precondition); prefilling slots stay at -1 so the
-            # in-graph arm can NEVER clobber a host-side prefill
-            for slot in self._free:
-                left[slot] = 0
-            if self._peek_swap() is None:
-                # quiescing swap: entries prefilled on the old weights
-                # must not arm now and decode past the apply
-                pub = [e for e in self._ring
-                       if e.ready and not e.lane.request.cancelled]
-        extra = ()
-        if k:
-            hist = numpy.zeros((self.slots, self.max_len), numpy.int32)
-            hlen = numpy.zeros(self.slots, numpy.int32)
-            for slot in active:
-                lane = self._lanes[slot]
-                row = numpy.concatenate(
-                    [lane.request.prompt,
-                     numpy.asarray(lane.emitted, numpy.int32)])
-                hist[slot, :len(row)] = row
-                hlen[slot] = len(row)
-            extra = (xfer.to_device(hist), xfer.to_device(hlen))
-        extra = extra + self._seed_vec()
-        w = None
-        tctxs = ()
-        if self._tracer is not None:
-            # standby occupants participate in this dispatch: the span
-            # lands in THEIR trace trees too (sound trees under chaos)
-            tctxs = [self._lanes[s].request.trace for s in active] \
-                + [e.lane.request.trace for e in pub]
-        t0 = time.monotonic()
-        try:
-            self._fault("engine.step")
-            args = []
-            if self._paged:
-                floor = max([e.pos for e in pub] or [0])
-                w = self._live_width(span, floor)
-                args = [xfer.to_device(self._page_tables[:, :w])]
-            args += [xfer.to_device(self._last),
-                     xfer.to_device(self._pos),
-                     xfer.to_device(left)] + list(extra)
-            if self.refill_ring:
-                args += self._ring_args(pub, w)
-            last, pos, emitted, iters, *rest = self._dispatch_decode(
-                self._whilestep_jit, args, len(active) + len(pub),
-                tctxs)
-            accs = rest[0] if k else None
-            assign = rest[-1] if self.refill_ring else None
-        except Exception as e:   # noqa: BLE001 — fails the lanes
-            if self._tracer is not None:
-                self._tracer.add_many(
-                    tctxs, "decode.megastep", "decode", t0,
-                    time.monotonic(),
-                    attrs={"batch": len(active) + len(pub), "K": K,
-                           "error": str(e)})
-            self._fail_active(active, e)
-            for entry in pub:
-                # a mid-loop fault fails exactly the participants —
-                # published ring occupants included, their pages home
-                self._fail_standby(entry, e)
-            return
-        t1 = time.monotonic()
-        iters = int(iters)
-        entered = self._pos
-        self._pos = numpy.array(pos, numpy.int32)
-        self._last = numpy.array(last, numpy.int32)
-        armed = {}                      # slot -> entries, in arm order
-        if assign is not None:
-            for j, entry in enumerate(pub):
-                s = int(assign[j])
-                if s >= 0:
-                    armed.setdefault(s, []).append(entry)
-                    self._ring.remove(entry)
-        participants = sorted(set(active) | set(armed))
-        lane_tokens = {}
-        wasted = 0
-        total = 0
-        for slot in participants:
-            rows = (emitted[:iters, slot, :] if k
-                    else emitted[:iters, slot][:, None])
-            toks = [int(t) for t in rows[rows >= 0]]
-            wasted += int((rows[:, 0] < 0).sum())
-            total += len(toks)
-            lane_tokens[slot] = len(toks)
-            owners = ([self._lanes[slot]] if slot in active else []) \
-                + [e.lane for e in armed.get(slot, ())]
-            for lane in owners:
-                take = min(lane.remaining, len(toks))
-                lane.emitted.extend(toks[:take])
-                lane.remaining -= take
-                toks = toks[take:]
-                if take:
-                    self._count_tokens(lane.request, take)
-        if accs is not None:
-            live_iters = int((accs[:iters] >= 0).sum())
-            self.metrics.inc("draft_tokens", k * live_iters)
-            self.metrics.inc("draft_accepted",
-                             int(numpy.clip(accs[:iters], 0, k).sum()))
-        n_armed = sum(len(v) for v in armed.values())
-        if n_armed:
-            self.metrics.inc("megastep_refills", n_armed)
-        self.metrics.set_gauge("standby_ring_occupancy",
-                               len(self._ring))
-        self.metrics.record_dispatch(len(participants))
-        self.metrics.record_decode_step(t1 - t0)
-        self.metrics.inc("decode_dispatches")
-        # REALIZED iterations, not the cap: the waste gauge must read
-        # what the early exit actually saved
-        self.metrics.record_megastep(iters, len(participants), total,
-                                     wasted)
-        self._note_attn_dispatch(entered, w, k + 1, calls=iters)
-        if self._tracer is not None:
-            self._tracer.add_many(
-                tctxs, "decode.megastep", "decode", t0, t1,
-                attrs={"batch": len(participants), "K": K,
-                       "iters": iters, "tokens": total,
-                       "bucket": "%sxK%d" % (w if w is not None
-                                             else self.slots, K),
-                       "backend": self._backend},
-                each_attrs=[{"lane_tokens": lane_tokens.get(s, 0)}
-                            for s in active]
-                + [{"standby": True} for _ in pub])
-        for slot in participants:
-            if slot in active:
-                lane = self._lanes[slot]
-                if lane.remaining == 0 or lane.request.cancelled:
-                    self._finish(slot)
-            for entry in armed.get(slot, ()):
-                lane = entry.lane
-                if lane.remaining == 0 or lane.request.cancelled:
-                    self._resolve_standby(entry)
-                else:
-                    # still decoding at the cap: the entry BECOMES the
-                    # slot's lane — restore the frontier that
-                    # _finish's vacate reset, and the full-width page
-                    # table row from the entry's own reservation
-                    self._lanes[slot] = lane
-                    lane.request.lane = slot
-                    if slot in self._free:
-                        self._free.remove(slot)
-                    self._page_tables[slot] = entry.table
-                    self._pos[slot] = int(pos[slot])
-                    self._last[slot] = int(last[slot])
-            if self._lanes[slot] is None:
-                # every owner drained: park the freed slot's frontier
-                # back at the garbage-write discipline's 0
-                self._pos[slot] = 0
-                self._last[slot] = 0
-                if self._paged:
-                    self._page_tables[slot, :] = KVPagePool.SCRATCH
-
-    # --------------------------------------------- ISSUE 19: standby ring
-    def _admit_ring(self):   # hot-path
-        """Install READY standby lanes into free slots HOST-side: the
-        ring's fast path is the in-graph arm, but when a slot frees at
-        a boundary (or lanes drained while the ring was still
-        prefilling) the entry must not wait for a mid-loop drain that
-        can never come."""
-        if not self.refill_ring or self._peek_swap() is not None:
-            return
-        while self._free and self._ring:
-            entry = next((e for e in self._ring if e.ready), None)
-            if entry is None:
-                return
-            self._ring.remove(entry)
-            lane = entry.lane
-            if lane.request.cancelled:
-                self._drop_standby(entry)
-                continue
-            slot = self._free.pop()
-            self._lanes[slot] = lane
-            lane.request.lane = slot
-            self._page_tables[slot] = entry.table
-            self._pos[slot] = entry.pos
-            self._last[slot] = entry.last
-            self.metrics.set_gauge("standby_ring_occupancy",
-                                   len(self._ring))
-
-    def _advance_ring(self):   # hot-path
-        """One tick of standby-ring work (ISSUE 19): advance ONE
-        pending standby prefill chunk, or — when every slot is busy,
-        the ring has room and no swap is quiescing — pull the queue
-        head into a fresh standby entry.  Pages are reserved
-        all-or-nothing exactly like :meth:`_admit_paged`, but with NO
-        prefix-cache interaction: a standby page is never shared, so
-        the in-graph arm needs no COW guard."""
-        if not self.refill_ring:
-            return
-        for entry in list(self._ring):
-            # withdrawn entries give their pages home NOW, not at some
-            # future boundary
-            if entry.lane.request.cancelled:
-                self._drop_standby(entry)
-        if self._peek_swap() is not None:
-            return
-        entry = next((e for e in self._ring if not e.ready), None)
-        if entry is not None:
-            self._advance_standby_chunk(entry)
-            return
-        if self._free or len(self._ring) >= self.refill_ring:
-            return
-        with self._cond:
-            req = self._queue.popleft() if self._queue else None
-            if req is not None:
-                self._queued_tokens -= req.true_len
-                self._queued_pages -= req.pages
-                self.metrics.set_gauge("queue_depth", len(self._queue))
-                self.metrics.set_gauge("queue_tokens",
-                                       self._queued_tokens)
-                self.metrics.set_gauge("queue_pages",
-                                       self._queued_pages)
-        if req is None:
-            return
-        if req.cancelled:
-            self._trace_queue_end(req, "cancelled")
-            req.future.cancel()
-            return
-        if time.monotonic() > req.deadline:
-            self.metrics.record_shed()
-            self._trace_queue_end(req, "shed")
-            req.future.set_exception(DeadlineExceeded(
-                "prompt shed after %.3fs in queue" % (
-                    time.monotonic() - req.t_enq)))
-            return
-        pages = self._alloc_pages(req.pages)
-        if pages is None:
-            # pool pressure: back to the HEAD, exactly like _admit
-            with self._cond:
-                self._queue.appendleft(req)
-                self._queued_tokens += req.true_len
-                self._queued_pages += req.pages
-                self.metrics.set_gauge("queue_depth", len(self._queue))
-                self.metrics.set_gauge("queue_tokens",
-                                       self._queued_tokens)
-                self.metrics.set_gauge("queue_pages",
-                                       self._queued_pages)
-            return
-        lane = _Slot(req)
-        for p in pages:
-            self._pool.pin(p)
-        lane.pages.extend(pages)
-        table = numpy.full(self._max_pages, KVPagePool.SCRATCH,
-                           numpy.int32)
-        table[:len(pages)] = pages
-        C = self.prefill_chunk
-        n_full = (req.true_len - 1) // C
-        for i in range(n_full):
-            lane.pending.append((req.prompt[i * C:(i + 1) * C], i * C,
-                                 False))
-        tail = req.prompt[n_full * C:]
-        if len(tail) < C:
-            tail = numpy.pad(tail, (0, C - len(tail)))
-        lane.pending.append((tail, n_full * C, True))
-        self.metrics.record_queue_wait(time.monotonic() - req.t_enq)
-        self._trace_admitted(req)
-        entry = _Standby(lane, table)
-        self._ring.append(entry)
-        self._update_pool_gauges()
-        self.metrics.set_gauge("standby_ring_occupancy",
-                               len(self._ring))
-        self.metrics.set_gauge_max("standby_ring_peak",
-                                   len(self._ring))
-        # the creation tick does its first chunk of prefill work too —
-        # otherwise a C-chunk prompt takes C+1 boundaries to become
-        # publishable and a one-boundary handoff window is always
-        # missed by exactly the creation tick
-        self._advance_standby_chunk(entry)
-
-    def _advance_standby_chunk(self, entry):   # hot-path
-        """One prompt chunk for a standby lane, into its own reserved
-        pages; the tail chunk yields the entry's first token and marks
-        it ready for publication."""
-        lane = entry.lane
-        req = lane.request
-        tokens, start, is_tail = lane.pending.pop(0)
-        last_idx = (req.true_len - 1 - start) if is_tail else 0
-        t0 = time.monotonic()
-        try:
-            self._fault("engine.chunk")
-            with self._donating():
-                self._kv_pools, tok = self._chunk_jit(
-                    self.params, self._kv_pools,
-                    xfer.to_device(entry.table),
-                    xfer.to_device(tokens, numpy.int32),
-                    xfer.to_device(start, numpy.int32),
-                    xfer.to_device(last_idx, numpy.int32),
-                    *self._seed_args(req.seed))
-                self._tfence(self._kv_pools, req.trace is not None)
-                if is_tail:      # the first token crosses in here too
-                    tok = int(xfer.to_host(tok))
-        except Exception as e:   # noqa: BLE001 — fails THIS request
-            self.metrics.record_error()
-            self.warning("standby prefill failed: %s", e)
-            if req.trace is not None:
-                req.trace.tracer.add(
-                    req.trace, "prefill.chunk", "prefill", t0,
-                    time.monotonic(),
-                    attrs={"start": start, "standby": True,
-                           "error": str(e)})
-            self._fail_standby(entry, e)
-            return
-        self.metrics.inc("prefill_dispatches")
-        self._note_attn_dispatch(start, self._max_pages)
-        self.metrics.inc("prefill_tokens",
-                         (req.true_len - start) if is_tail
-                         else len(tokens))
-        # enqueue time by design (a tail chunk's includes the wait for
-        # its token); device wall rides traced spans (_tfence)
-        self.metrics.record_decode_step(time.monotonic() - t0)
-        if req.trace is not None:
-            req.trace.tracer.add(
-                req.trace, "prefill.chunk", "prefill", t0,
-                time.monotonic(),
-                attrs={"start": start, "tail": is_tail,
-                       "standby": True,
-                       "bucket": self.prefill_chunk, "paged": True,
-                       "backend": self._backend})
-        if not is_tail:
-            entry.pos = lane.pending[0][1]
-            return
-        lane.emitted.append(tok)
-        lane.remaining -= 1
-        self._count_tokens(req)
-        self.metrics.record_ttft(time.monotonic() - req.t_enq)
-        entry.pos = req.true_len
-        entry.last = tok
-        if lane.remaining == 0 or req.cancelled:
-            self._ring.remove(entry)
-            self._resolve_standby(entry)
-            self.metrics.set_gauge("standby_ring_occupancy",
-                                   len(self._ring))
-            return
-        entry.ready = True
-
-    def _resolve_standby(self, entry):
-        """A standby lane that FINISHED while never holding a slot
-        (n_new=1 at the prefill tail, or armed and drained between two
-        boundaries): pages home, future resolved — the ring twin of
-        :meth:`_finish`."""
-        self._release_lane(entry.lane)
-        fut = entry.lane.request.future
-        if not fut.cancelled():
-            fut.version = self.weights_version
-            fut.set_result(numpy.asarray(entry.lane.emitted,
-                                         numpy.int32))
-
-    def _drop_standby(self, entry):
-        """Withdrawn standby entry: pages home, future cancelled."""
-        if entry in self._ring:
-            self._ring.remove(entry)
-        self._release_lane(entry.lane)
-        entry.lane.request.future.cancel()
-        self.metrics.set_gauge("standby_ring_occupancy",
-                               len(self._ring))
-
-    def _fail_standby(self, entry, exc):
-        """Fail one standby entry to its client: pages back to the
-        pool leak-free, future resolved — ring occupants participate
-        in a faulted dispatch exactly like lanes (the chaos
-        fault-isolation discipline)."""
-        if entry in self._ring:
-            self._ring.remove(entry)
-        self._release_lane(entry.lane)
-        fut = entry.lane.request.future
-        if not fut.done():       # cancelled, or failed by _storage_lost
-            fut.set_exception(exc)
-        self.metrics.set_gauge("standby_ring_occupancy",
-                               len(self._ring))
-
-    def _requeue_ring(self):
-        """Swap application: standby entries were prefilled on the OLD
-        weights — their KV is stale the moment the new tree installs,
-        so they go back to the queue head WHOLE (fresh deadline, like
-        :meth:`_requeue_active`: the wait was spent on work the deploy
-        threw away — a pre-prefilled request must never 503 for it)
-        and re-prefill on the new weights."""
-        reqs = []
-        fresh_deadline = time.monotonic() + self.deadline_s
-        for entry in self._ring:
-            lane = entry.lane
-            self._release_lane(lane)
-            req = lane.request
-            if req.cancelled:
-                req.future.cancel()
-                continue
-            req.deadline = max(req.deadline, fresh_deadline)
-            if req.trace is not None:
-                req.trace.tracer.instant(
-                    req.trace, "swap.requeue", cat="engine")
-                req.tspan = req.trace.tracer.begin(
-                    req.trace, "queue.wait", cat="queue",
-                    attrs={"engine": self.name, "requeued": True})
-            reqs.append(req)
-        self._ring = []
-        self.metrics.set_gauge("standby_ring_occupancy", 0)
-        with self._cond:
-            for req in reversed(reqs):
-                self._queue.appendleft(req)
-                self._queued_tokens += req.true_len
-                self._queued_pages += req.pages
-            self.metrics.set_gauge("queue_depth", len(self._queue))
-            self.metrics.set_gauge("queue_tokens", self._queued_tokens)
-            self.metrics.set_gauge("queue_pages", self._queued_pages)
-        self.metrics.inc("requests_requeued_for_swap", len(reqs))
-
     def _boundary_shed(self):
         """Deadline shedding at the MEGASTEP BOUNDARY (ISSUE 13
         satellite): one sweep of the whole queue per boundary, instead
@@ -4011,21 +3169,10 @@ class LMEngine(Logger):
         deadline only ever governed queue wait), and a request whose
         tokens completed inside the megastep resolves its future before
         this sweep can ever see it.  Queue-token/page gauges re-read
-        once per sweep, at the boundary, not per pop.
-
-        ISSUE 19 window semantics: the worst-case shed LATENCY is one
-        dispatch window, quoted from the megastep iteration CAP — the
-        while mode realizes fewer iterations and exits early, so the
-        cap bounds both modes (a fixed-K scan simply realizes the cap).
-        The sweep also covers the standby ring: a pre-prefilled entry
-        is ADMITTED work whose deadline only ever governed queue wait,
-        so sitting in the ring past it must never 503 — its deadline is
-        bumped forward (idempotent) so even a later swap requeue cannot
-        shed work the engine already paid to prefill."""
+        once per sweep, at the boundary, not per pop.  The worst-case
+        shed LATENCY is one dispatch window: the megastep's K
+        iterations."""
         now = time.monotonic()
-        for entry in self._ring:
-            entry.lane.request.deadline = max(
-                entry.lane.request.deadline, now + self.deadline_s)
         shed = []
         with self._cond:
             if not self._queue:
@@ -4090,9 +3237,7 @@ class LMEngine(Logger):
             # queued request now, not just those the admission loop
             # happens to pop
             self._boundary_shed()
-            self._admit_ring()
             self._admit()
-            self._advance_ring()
             busy = [i for i, lane in enumerate(self._lanes)
                     if lane is not None]
             self.metrics.set_gauge("slots_busy", len(busy))
@@ -4104,11 +3249,7 @@ class LMEngine(Logger):
                 with self._cond:
                     if self._stop:
                         break
-                    if self._ring:
-                        # standby prefill still has host work — keep
-                        # ticking so the ring drains/installs promptly
-                        pass
-                    elif not self._queue:
+                    if not self._queue:
                         self._cond.wait(0.5)
                     elif self._pool_blocked:
                         # head request waiting on pages with no lane
@@ -4132,9 +3273,7 @@ class LMEngine(Logger):
                       if lane is not None and not lane.pending]
             if not active:
                 continue
-            if self._whilestep_jit is not None:
-                self._step_while(active)
-            elif self._megastep_jit is not None:
+            if self._megastep_jit is not None:
                 self._step_megastep(active)
             elif self._verify_jit is not None:
                 self._step_speculative(active)
@@ -4157,9 +3296,6 @@ class LMEngine(Logger):
         for req in pending:
             self._trace_queue_end(req, "engine stopped")
             req.future.set_exception(RuntimeError("LM engine stopped"))
-        for entry in list(self._ring):
-            self._fail_standby(entry,
-                               RuntimeError("LM engine stopped"))
         for slot, lane in enumerate(self._lanes):
             if lane is not None:
                 lane.request.future.set_exception(
