@@ -244,7 +244,42 @@ def kernel_engine(one_chip):
         monkeypatch.undo()
 
 
-@pytest.mark.parametrize("program", ["chunk", "decode_w1", "decode_w8"])
+PROGRAMS = ["chunk", "decode_w1", "decode_w8"]
+_TEXTS = {}
+
+
+def program_text(fixture, program):
+    """The compiled text of one of ``PROGRAMS`` of a fixture's engine for
+    the described chip, compiled once a module: the chunk program, or the
+    decode program at a page table 1 or 8 wide."""
+    engine, params, pools, ints = fixture
+    if (engine.name, program) in _TEXTS:
+        return _TEXTS[engine.name, program]
+    wide = engine._wt.width if engine._wt is not None else None
+
+    def tables(*lead):
+        """``LMEngine._table_args`` at width ``lead[-1]``."""
+        if wide is None:
+            return ints(*lead)
+        return ({"full": ints(*lead),
+                 "sliding": ints(*lead[:-1], min(lead[-1], wide))},
+                ints(*lead[:-1]))
+
+    if program == "chunk":
+        lowered = engine._chunk_jit.lower(
+            params, pools, tables(engine._max_pages),
+            ints(engine.prefill_chunk), ints(), ints())
+    else:
+        width = int(program.rsplit("w", 1)[1])
+        lowered = engine._step_jit.lower(
+            params, pools, tables(engine.slots, width), ints(engine.slots),
+            ints(engine.slots))
+    text = _TEXTS[engine.name, program] = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
 def test_engine_programs_update_the_pool_in_place(kernel_engine, program):
     """ISSUE 27: compiled for the chip, the decode and the chunk program
     hold no copy with a pool's shape — not of the arguments (they are
@@ -253,18 +288,8 @@ def test_engine_programs_update_the_pool_in_place(kernel_engine, program):
     the chip's 128 lanes, so the pool lies the way the kernel reads it)
     — and list every pool leaf under ``input_output_alias``."""
     from veles_tpu.serving.lm_engine import compiled_storage_report
-    engine, params, pools, ints = kernel_engine
-    if program == "chunk":
-        lowered = engine._chunk_jit.lower(
-            params, pools, ints(engine._max_pages),
-            ints(engine.prefill_chunk), ints(), ints())
-    else:
-        width = int(program.rsplit("w", 1)[1])
-        lowered = engine._step_jit.lower(
-            params, pools, ints(engine.slots, width), ints(engine.slots),
-            ints(engine.slots))
-    text = lowered.compile().as_text()
-    assert "tpu_custom_call" in text
+    engine = kernel_engine[0]
+    text = program_text(kernel_engine, program)
     leaves = jax.tree.leaves(engine._kv_pools)
     copies, aliased = compiled_storage_report(text, leaves[0])
     assert copies == 0, "%d whole-pool copies in %s" % (copies, program)
@@ -310,7 +335,7 @@ def kinds_engine(one_chip):
         monkeypatch.undo()
 
 
-@pytest.mark.parametrize("program", ["chunk", "decode_w1", "decode_w8"])
+@pytest.mark.parametrize("program", PROGRAMS)
 def test_programs_of_two_kinds_update_both_pools_in_place(kinds_engine,
                                                           program):
     """ISSUE 28: the check of ISSUE 27 for the new block: compiled for
@@ -318,24 +343,8 @@ def test_programs_of_two_kinds_update_both_pools_in_place(kinds_engine,
     of layer hold no copy with either pool's shape and list every leaf of
     both kinds under ``input_output_alias``."""
     from veles_tpu.serving.lm_engine import compiled_storage_report
-    engine, params, pools, ints = kinds_engine
-    wide = engine._wt.width
-    if program == "chunk":
-        lowered = engine._chunk_jit.lower(
-            params, pools,
-            ({"full": ints(engine._max_pages),
-              "sliding": ints(min(engine._max_pages, wide))}, ints()),
-            ints(engine.prefill_chunk), ints(), ints())
-    else:
-        width = int(program.rsplit("w", 1)[1])
-        lowered = engine._step_jit.lower(
-            params, pools,
-            ({"full": ints(engine.slots, width),
-              "sliding": ints(engine.slots, min(width, wide))},
-             ints(engine.slots)),
-            ints(engine.slots), ints(engine.slots))
-    text = lowered.compile().as_text()
-    assert "tpu_custom_call" in text
+    engine = kinds_engine[0]
+    text = program_text(kinds_engine, program)
     leaves = jax.tree.leaves(engine._kv_pools)
     kinds = {leaf.shape: leaf for leaf in leaves}
     assert len(kinds) == 2
@@ -343,3 +352,19 @@ def test_programs_of_two_kinds_update_both_pools_in_place(kinds_engine,
         copies, aliased = compiled_storage_report(text, leaf)
         assert copies == 0, "%d whole-pool copies in %s" % (copies, program)
     assert aliased == len(leaves) == 6
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+@pytest.mark.parametrize("fixture", ["kernel_engine", "kinds_engine"])
+def test_engine_programs_read_the_weights_where_they_lie(request, fixture,
+                                                         program):
+    """ISSUE 31: compiled for the chip, no engine program holds a copy
+    with the shape of a weight matrix: the head split and the pool's head
+    packing are applied to a projection's small output, never folded into
+    ``wq``, ``wk``, ``wv`` (three weight-sized transpositions a layer and
+    dispatch before ``ops/attention.py::_qkv_cached`` barred the fold)."""
+    from veles_tpu.serving.lm_engine import compiled_param_copies
+    fixture = request.getfixturevalue(fixture)
+    copies = compiled_param_copies(program_text(fixture, program),
+                                   fixture[0].params)
+    assert copies == 0, "%d weight-shaped copies in %s" % (copies, program)

@@ -21,8 +21,9 @@ by hand::
   of pool (``chip_smoke.KINDS_LM``: heads of 128 in bfloat16, 32 lanes,
   pages of 256, expert layers through the grouped matmul).  For each engine
   program it prints the copies of a whole KV pool and the pool leaves
-  updated in place (``compiled_storage_report``), and exits non-zero when
-  a copy is there or a leaf is not aliased;
+  updated in place (``compiled_storage_report``) and the copies with a
+  weight matrix's shape (``compiled_param_copies``), and exits non-zero
+  when a copy of either kind is there or a leaf is not aliased;
 - ``mesh``: the ``ShardedTrainer`` AlexNet step on a data 2 x model 2 mesh
   (checks for an all-reduce);
 - ``tp``: the ``LMEngine(tp=4)`` decode program over four chips.
@@ -164,18 +165,25 @@ def kernel_engine(params, n_heads, **kwargs):
 
 
 def storage_in_place(name, text, eng):
-    """Print what the compiled program does to the KV storage; exit
-    non-zero on a whole-pool copy or a pool leaf not updated in place."""
-    from veles_tpu.serving.lm_engine import compiled_storage_report
+    """Print what the compiled program does to the KV storage and to its
+    weights; exit non-zero on a whole-pool copy, a pool leaf not updated
+    in place or a copy with a weight matrix's shape."""
+    from veles_tpu.serving.lm_engine import (compiled_param_copies,
+                                             compiled_storage_report)
     leaves = jax.tree.leaves(eng._kv_pools)
     # one report per kind of pool (a stack of two kinds has two shapes)
     kinds = {leaf.shape: leaf for leaf in leaves}.values()
     copies = sum(compiled_storage_report(text, leaf)[0] for leaf in kinds)
     _, aliased = compiled_storage_report(text, leaves[0])
-    print("%-34s pool copies x%d  pool leaves in place %d of %d"
-          % (name, copies, aliased, len(leaves)), flush=True)
+    weights = compiled_param_copies(text, eng.params)
+    print("%-34s pool copies x%d  pool leaves in place %d of %d  "
+          "weight-shaped copies x%d"
+          % (name, copies, aliased, len(leaves), weights), flush=True)
     if copies or aliased < len(leaves):
         raise SystemExit("%s: the KV storage is not updated in place"
+                         % name)
+    if weights:
+        raise SystemExit("%s: a weight is copied whole before it is read"
                          % name)
 
 

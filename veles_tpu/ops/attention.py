@@ -357,26 +357,55 @@ def cfg_matmul(cfg, a, b):
         .astype(a.dtype)
 
 
-def _qkv(params, x, cfg):
-    """q (b, h, s, dh), k and v (b, kv, s, dh) and the output gate
-    (b, s, h·dh; None where the block has none) of ``x`` (b, s, d), not
-    yet rotated.  The ``sandwich`` block norms q and k per head."""
+def _flat_qkv(params, x, cfg):
+    """The q, k and v projections of ``x`` (b, s, d), heads not yet
+    split: plain ``[rows, d] @ [d, n]`` dots on the weights as they lie."""
+    return tuple(cfg_matmul(cfg, x, params[w]) for w in ("wq", "wk", "wv"))
+
+
+def _heads(params, x, flat, cfg):
+    """q (b, h, s, dh), k and v (b, kv, s, dh) from their ``flat``
+    projections, and the output gate (b, s, h·dh; None where the block has
+    none) of ``x`` (b, s, d), not yet rotated.  The ``sandwich`` block
+    norms q and k per head."""
     b, s, d = x.shape
     dh = cfg.head_size(d)
     kv = cfg.kv_heads(params, d)
 
-    def split(w, heads):
-        return cfg_matmul(cfg, x, w).reshape(b, s, heads, dh) \
-            .transpose(0, 2, 1, 3)
+    def split(y, heads):
+        return y.reshape(b, s, heads, dh).transpose(0, 2, 1, 3)
 
-    q = split(params["wq"], cfg.n_heads)
-    k = split(params["wk"], kv)
-    v = split(params["wv"], kv)
+    q, k, v = flat
+    q, k, v = split(q, cfg.n_heads), split(k, kv), split(v, kv)
     if cfg.block != "sandwich":
         return q, k, v, None
     q = rms_norm(q, params["q_norm"], cfg.eps)
     k = rms_norm(k, params["k_norm"], cfg.eps)
     return q, k, v, cfg_matmul(cfg, x, params["wg"])
+
+
+def _qkv(params, x, cfg):
+    """``_heads`` of ``x``'s projections where rows are many (training,
+    a whole prompt): the compiler lays the dots' outputs out as the
+    attention reads them."""
+    return _heads(params, x, _flat_qkv(params, x, cfg), cfg)
+
+
+def _qkv_cached(params, x, cfg):
+    """``_qkv`` for the cached paths, where rows are few and a weight is
+    the larger operand.  The barrier keeps the head split (and the pool's
+    packing of two heads to a 128-lane row) on each projection's small
+    OUTPUT.  Without it the chip's compiler folds them into the WEIGHT
+    operand and transposes the whole matrix before the dot, in every
+    dispatch (weights are arguments: nothing folds once):
+      %copy.21 = f32[2048,2048]{0,1:T(8,128)S(1)} copy(wq)
+      %copy.34 = f32[2048,2048]{1,0:T(8,128)S(1)} copy(bitcast(wk))
+      %copy.33 = f32[2048,2048]{1,0:T(8,128)S(1)} copy(bitcast(wv))
+    72 a dispatch, 12 % of the device's time on OPT-1.3B (ISSUE 31).
+    ``tests/test_chip_compile.py`` holds the engine's programs to none."""
+    flat = [jax.lax.optimization_barrier(y)
+            for y in _flat_qkv(params, x, cfg)]
+    return _heads(params, x, flat, cfg)
 
 
 def _merge(params, o, gate, cfg):
@@ -457,7 +486,7 @@ def _decode_attend(params, x, k_cache, v_cache, write_idx, live,
     at cache index ``write_idx``, attend over the cache under the
     precomputed ``live`` mask (cache_len,), and project out."""
     cfg = model_config.of(cfg)
-    q, k_new, v_new, gate = _qkv(params, x, cfg)     # (b, h, 1, dh)
+    q, k_new, v_new, gate = _qkv_cached(params, x, cfg)     # (b, h, 1, dh)
     if rope_pos is not None:
         pos_arr = jnp.asarray(rope_pos)[None]
         q = rope_rotate(q, pos_arr, cfg.rope_theta)
@@ -509,7 +538,7 @@ def mha_chunk_step(params, x, k_cache, v_cache, pos, n_heads,
     cfg = model_config.of(n_heads, rope, window, sinks)
     rope, window = cfg.layer_rope(layer), cfg.layer_window(layer)
     c = x.shape[1]
-    q, k_new, v_new, gate = _qkv(params, x, cfg)    # (b, h, c, dh)
+    q, k_new, v_new, gate = _qkv_cached(params, x, cfg)    # (b, h, c, dh)
     if rope:
         pos_arr = pos + jnp.arange(c)
         q = rope_rotate(q, pos_arr, cfg.rope_theta)
@@ -723,7 +752,7 @@ def mha_paged_chunk_step(params, x, k_pool, v_pool, ptab, pos, n_heads,
     rope, window = cfg.layer_rope(layer), cfg.layer_window(layer)
     n_heads, sinks = cfg.n_heads, cfg.sinks
     c = x.shape[1]
-    q, k_new, v_new, gate = _qkv(params, x, cfg)    # (b, h, c, dh)
+    q, k_new, v_new, gate = _qkv_cached(params, x, cfg)    # (b, h, c, dh)
     if rope:
         positions = jnp.asarray(pos)[:, None] + jnp.arange(c)   # (b, c)
         q = rope_rotate_batched(q, positions, cfg.rope_theta)

@@ -329,15 +329,40 @@ def compiled_storage_report(text, leaf):
     ``tools/aot_compile.py`` and ``tests/test_chip_compile.py`` make for
     a described chip, no chip attached: no copy, every leaf aliased."""
     import re
-    name = {"float32": "f32", "bfloat16": "bf16", "float16": "f16"}[
-        str(leaf.dtype)]
-    shape = "%s[%s]" % (name, ",".join(str(n) for n in leaf.shape))
     copies = re.findall(
-        r"= %s(?:\{[^}]*\})? copy(?:-start)?\(" % re.escape(shape), text)
+        r"= %s(?:\{[^}]*\})? copy(?:-start)?\(" % re.escape(_hlo_shape(leaf)),
+        text)
     header = text.split("\n", 1)[0]
     aliased = re.findall(r"\{\d+\}: \(\d+, \{\}, (?:may|must)-alias\)",
                          header)
     return len(copies), len(aliased)
+
+
+def compiled_param_copies(text, params):
+    """The number of ``copy`` instructions in one COMPILED engine
+    program's text whose result has the shape and dtype of a leaf of
+    ``params`` with two or more dimensions, in any layout: a weight
+    rewritten (transposed, re-tiled) in every dispatch before the dot
+    that reads it — the compiler folding a reshape or a transposition of
+    a projection's small output into its weight operand (ISSUE 31: three
+    a layer through ``ops/attention.py::_qkv_cached``).  The asynchronous
+    ``copy-start`` of a parameter (the prefetch of ``wo`` into fast
+    memory across programs) is not one.  Checked beside
+    :func:`compiled_storage_report`: none."""
+    import re
+    import jax
+    shapes = {_hlo_shape(leaf) for leaf in jax.tree.leaves(params)
+              if len(leaf.shape) >= 2}
+    return sum(len(re.findall(
+        r"= %s(?:\{[^}]*\})? copy\(" % re.escape(shape), text))
+        for shape in shapes)
+
+
+def _hlo_shape(leaf):
+    """``leaf``'s shape and dtype as compiled text writes them."""
+    name = {"float32": "f32", "bfloat16": "bf16", "float16": "f16"}[
+        str(leaf.dtype)]
+    return "%s[%s]" % (name, ",".join(str(n) for n in leaf.shape))
 
 
 class _PrefixNode:
