@@ -834,6 +834,114 @@ def phase_kinds(seed, lm=KINDS_LM, slots=16, page=256, prompt_len=700,
         "tokens", same, n_new)
 
 
+#: the latent kind at the benchmark configuration's published widths
+#: (benchmark/configs/xing4.0-29b-a4b.json), cut in depth, experts and
+#: vocabulary so that the phase is quick: a dense and two expert layers
+LATENT_LM = {
+    "model_type": "xing4_0", "hidden_size": 3584, "num_attention_heads": 32,
+    "q_lora_rank": 768, "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+    "qk_rope_head_dim": 64, "v_head_dim": 128, "intermediate_size": 9216,
+    "moe_intermediate_size": 1024, "vocab_size": 8192,
+    "num_hidden_layers": 3, "first_k_dense_replace": 1,
+    "n_routed_experts": 16, "num_experts_per_tok": 4, "n_shared_experts": 1,
+    "routed_scaling_factor": 2, "norm_topk_prob": True,
+    "scoring_func": "sigmoid", "topk_method": "noaux_tc", "n_group": 1,
+    "topk_group": 1, "rope_theta": 10000, "rms_norm_eps": 1e-6,
+    "rope_scaling": {"type": "yarn", "factor": 64, "beta_fast": 32,
+                     "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                     "original_max_position_embeddings": 4096},
+    "hc_mult": 4, "hc_sinkhorn_iters": 20, "hc_eps": 1e-6,
+    "mhc_h_res_clamp_min": -30, "mhc_h_res_clamp_max": 30,
+    "initializer_std": 0.02, "max_position_embeddings": 8192,
+}
+
+
+def phase_latent(seed, lm=LATENT_LM, slots=16, page=1024, prompt_len=5500,
+                 n_new=120, gap_limit=1.5, kernel="auto"):
+    """Latent attention under the n-stream residual on the serving path:
+    one request through ``LMEngine`` (on the chip the expanded prefill
+    kernel over five whole pages and a part of a sixth, the absorbed decode
+    kernel through the table, the one-call row write of 16 lanes into the
+    ONE pool a layer; expert layers through the grouped matmul) against the
+    benchmark's plain reference (``benchmark/reference/xing4.py``, float32,
+    expanded attention only), and against an engine on the XLA path, which
+    serves the same request first.  The served tokens are the reference's
+    own but for bfloat16 roundoff (``gap_limit`` on the reference's logit
+    scale)."""
+    import jax
+    from benchmark.reference import xing4
+    from veles_tpu import model_config
+    from veles_tpu.serving import LMEngine
+    record = model_config.from_published(lm)
+    say("latent", "%d layers, ranks %d / %d, %d streams, %d experts top-%d, "
+        "page %d, %s; %d lanes", lm["num_hidden_layers"],
+        lm["q_lora_rank"], lm["kv_lora_rank"], record.streams,
+        lm["n_routed_experts"], lm["num_experts_per_tok"], page,
+        record.dtype, slots)
+    weights = jax.tree.map(lambda a: a.astype(record.dtype),
+                           xing4.make_weights(seed, lm))
+    prompt = numpy.random.RandomState(seed).randint(
+        0, lm["vocab_size"], prompt_len)
+
+    def serve(name, attn_kernel):
+        engine = LMEngine(weights, record,
+                          max_len=lm["max_position_embeddings"],
+                          slots=slots, prefill_chunk=page, paged_kv=True,
+                          attn_kernel=attn_kernel, deadline_s=600.0,
+                          name=name)
+        with timed("latent", "%s: engine start (every program and table "
+                   "width)" % name):
+            engine.start()
+        with timed("latent", "%s: one request: prompt %d, n_new %d"
+                   % (name, prompt_len, n_new)):
+            return engine, engine.submit(prompt, n_new).result(timeout=600)
+
+    twin, want = serve("latent_xla", 0)
+    twin.stop()
+    engine, out = serve("latent", kernel)
+    try:
+        snap = engine.metrics.snapshot()
+        gauges, counters = snap["gauges"], snap["counters"]
+        say("latent", "attn_kernel_active %d, kv_storage_in_place %d, "
+            "kv_storage_rebuilds %d, kv_bytes_per_token %d, experts hit a "
+            "step %.2f", gauges["attn_kernel_active"],
+            gauges["kv_storage_in_place"],
+            counters.get("kv_storage_rebuilds", 0),
+            gauges["kv_bytes_per_token"],
+            counters["moe_experts_hit"] / counters["decode_dispatches"])
+        if on_tpu():
+            check(gauges["attn_kernel_active"] == 1
+                  and counters.get("attn_kernel_fallbacks", 0) == 0,
+                  "attn_kernel='auto' fell back to the XLA path on the "
+                  "TPU: %s", engine._kernel_fallback_reason)
+        check(gauges["kv_storage_in_place"] == 1
+              and counters.get("kv_storage_rebuilds", 0) == 0,
+              "the latent pools are not updated in place")
+        check(len(engine._storage()[0]) == 1, "more than one pool a layer")
+        _say_page_steps("latent", engine, gauges["attn_kernel_active"])
+        check(engine.verify_pool_invariants()["used_pages"] == 0,
+              "pages still held after the request")
+    finally:
+        engine.stop()
+    rows = numpy.arange(prompt_len - 1, prompt_len + n_new - 1)
+    for name, row in (("kernels", out), ("XLA twin", want)):
+        with timed("latent", "%s: reference over %d tokens"
+                   % (name, prompt_len + n_new)):
+            ref = numpy.asarray(xing4.logits(
+                weights, numpy.concatenate([prompt, row]), rows, lm))
+        gap = ref.max(-1) - ref[numpy.arange(n_new), row]
+        say("latent", "%s: served tokens that are the reference's choice "
+            "%d of %d (off at %s); widest gap below its best %.4f (limit "
+            "%.3f)", name, int((gap == 0).sum()), n_new,
+            numpy.nonzero(gap > 0)[0].tolist(), float(gap.max()), gap_limit)
+        check(float(gap.max()) <= gap_limit,
+              "%s: served tokens lie %.4f below the reference's best",
+              name, float(gap.max()))
+    same = int((numpy.cumsum(out != want) == 0).sum())
+    say("latent", "kernels and XLA twin serve the same first %d of %d "
+        "tokens", same, n_new)
+
+
 def _sharding_line(name, arr):
     return "%s %s on %d device(s), shard %s" % (
         name, tuple(arr.shape), len(arr.sharding.device_set),
@@ -1006,6 +1114,8 @@ def main(argv=None):
     parser.add_argument("--chips", type=int, choices=(1, 4), default=1,
                         help="4 runs only the four-chip phase")
     parser.add_argument("--seed", type=int, default=22)
+    parser.add_argument("--only", default=None, metavar="PHASE",
+                        help="run one phase of the one-chip list")
     args = parser.parse_args(argv)
 
     import jax
@@ -1035,7 +1145,10 @@ def main(argv=None):
                   ("train", lambda: phase_train(args.seed, workdir)),
                   ("kernels", lambda: phase_kernels(args.seed)),
                   ("serve", lambda: phase_serve(args.seed)),
-                  ("kinds", lambda: phase_kinds(args.seed))]
+                  ("kinds", lambda: phase_kinds(args.seed)),
+                  ("latent", lambda: phase_latent(args.seed))]
+    if args.only:
+        phases = [(name, run) for name, run in phases if name == args.only]
     failed = []
     begin = time.perf_counter()
     try:

@@ -199,10 +199,11 @@ def page_step_census():
                               numpy.atleast_1d(numpy.array(pos))))
                 return real(params, pools, table, tokens, pos, *rest)
             setattr(engine, name, noted)
-        spy("_chunk_jit", 0)       # the history below a chunk's frontier
-        spy("_step_jit", 1)
-
         cfg, page = engine.cfg, engine.prefill_chunk
+        # the history below a chunk's frontier; the latent kind's chunk
+        # kernel also walks the chunk's own page, written before it
+        spy("_chunk_jit", page if cfg.latent is not None else 0)
+        spy("_step_jit", 1)
 
         @functools.lru_cache(maxsize=None)
         def live_pages(pos, span, width, window):

@@ -212,6 +212,18 @@ def test_flash_attention_tpu_traces_at_policy_precision(one_chip,
     assert text.count("tpu_custom_call") == 3      # forward, dkv, dq
 
 
+def described(engine, one_chip):
+    """(engine, its params' and its pools' shapes on the DESCRIBED chip,
+    a maker of int32 shapes there): what ``program_text`` lowers with."""
+    import numpy
+    shapes = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip), tree)
+    return (engine, shapes(engine.params), shapes(engine._kv_pools),
+            lambda *shape: jax.ShapeDtypeStruct(shape, numpy.int32,
+                                                sharding=one_chip))
+
+
 @pytest.fixture(scope="module")
 def kernel_engine(one_chip):
     """A tiny ``LMEngine`` with the Pallas serving kernels active and
@@ -219,7 +231,6 @@ def kernel_engine(one_chip):
     64, under the chip's 128 lanes, where the chip's own layout for a
     pool of such rows is not the kernels' (the geometry of the
     benchmark's OPT-1.3B)."""
-    import numpy
     from veles_tpu import prng
     from veles_tpu.ops.transformer import init_transformer_params
     from veles_tpu.serving import LMEngine
@@ -233,13 +244,7 @@ def kernel_engine(one_chip):
                           prefill_chunk=32, paged_kv=16,
                           attn_kernel="auto", name="aot_tiny")
         assert engine._kernel_active
-        shapes = lambda tree, where: jax.tree.map(  # noqa: E731
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=where), tree)
-        yield (engine, shapes(engine.params, one_chip),
-               shapes(engine._kv_pools, one_chip),
-               lambda *shape: jax.ShapeDtypeStruct(shape, numpy.int32,
-                                                   sharding=one_chip))
+        yield described(engine, one_chip)
     finally:
         monkeypatch.undo()
 
@@ -301,7 +306,6 @@ def kinds_engine(one_chip):
     """A tiny ``LMEngine`` for the sandwich block with two kinds of layer
     (and so two kinds of pool), the Pallas serving kernels active, 16
     lanes (the one-call row write) of heads of 128 in bfloat16."""
-    import numpy
     from benchmark.reference import afmoe
     from veles_tpu import model_config
     from veles_tpu.serving import LMEngine
@@ -325,12 +329,7 @@ def kinds_engine(one_chip):
                           max_len=256, slots=16, prefill_chunk=32,
                           paged_kv=96, attn_kernel="auto", name="aot_kinds")
         assert engine._kernel_active and engine._wt is not None
-        shapes = lambda tree: jax.tree.map(  # noqa: E731
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip), tree)
-        yield (engine, shapes(engine.params), shapes(engine._kv_pools),
-               lambda *shape: jax.ShapeDtypeStruct(shape, numpy.int32,
-                                                   sharding=one_chip))
+        yield described(engine, one_chip)
     finally:
         monkeypatch.undo()
 
@@ -354,8 +353,95 @@ def test_programs_of_two_kinds_update_both_pools_in_place(kinds_engine,
     assert aliased == len(leaves) == 6
 
 
+@pytest.mark.parametrize("page", [1024, 512])
+def test_latent_kernels_compile_at_the_cells_widths(one_chip, page):
+    """ISSUE 34: the absorbed decode kernel (16 lanes, 32 heads, rows of
+    640 lanes, a table over 33,792 positions) and the expanded prefill
+    kernel (a chunk of one page, 4 heads a grid step, keys and values
+    rebuilt in fast memory) at ``xing4.0-29b-a4b``'s published widths, for
+    both page sizes the configuration may take."""
+    m = 33792 // page
+    pool = ((16 * m + 1, 1, page, 640), BF16)
+    text = compile_for(
+        one_chip,
+        lambda q, k, pt, ps: PK.paged_latent_decode(
+            q, k, pt, ps, 0.1447, interpret=False),
+        ((16, 32, 1, 640), BF16), pool, ((16, m), I32), ((16,), I32))
+    assert "tpu_custom_call" in text
+    w = ((32, 512, 128), BF16)
+    text = compile_for(
+        one_chip,
+        lambda qn, qr, wk, wv, k, pt, ps: PK.paged_latent_prefill(
+            qn, qr, wk, wv, k, pt, ps, 0.1447, 512, interpret=False),
+        ((1, 32, page, 128), BF16), ((1, 32, page, 64), BF16), w, w, pool,
+        ((1, m), I32), ((1,), I32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.fixture(scope="module")
+def latent_engine(one_chip):
+    """A small ``LMEngine`` for the latent kind (ONE pool a layer), the
+    Pallas serving kernels active, 16 lanes (the one-call row write), the
+    published head sizes and ranks in bfloat16 under a 4-stream residual.
+    (No weight's leading size is the chunk's 256 rows: the census of
+    weight-shaped copies goes by shape, and an activation of chunk x
+    width must not pass for one.)"""
+    from benchmark.reference import xing4
+    from veles_tpu import model_config
+    from veles_tpu.serving import LMEngine
+    cfg = {
+        "model_type": "xing4_0", "hidden_size": 384,
+        "num_attention_heads": 4, "q_lora_rank": 128, "kv_lora_rank": 512,
+        "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,
+        "intermediate_size": 512, "moe_intermediate_size": 128,
+        "vocab_size": 512, "num_hidden_layers": 2,
+        "first_k_dense_replace": 1, "n_routed_experts": 4,
+        "num_experts_per_tok": 2, "n_shared_experts": 1,
+        "routed_scaling_factor": 2, "norm_topk_prob": True,
+        "scoring_func": "sigmoid", "rope_theta": 10000,
+        "rms_norm_eps": 1e-6, "hc_mult": 4, "hc_sinkhorn_iters": 20,
+        "hc_eps": 1e-6, "mhc_h_res_clamp_min": -30,
+        "mhc_h_res_clamp_max": 30,
+        "rope_scaling": {"type": "yarn", "factor": 64, "beta_fast": 32,
+                         "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                         "original_max_position_embeddings": 4096},
+        "initializer_std": 0.02}
+    params = xing4.make_weights(1, cfg)
+    monkeypatch = pytest.MonkeyPatch()
+    monkeypatch.setattr(PK, "on_tpu", lambda: True)
+    try:
+        engine = LMEngine(params, model_config.from_published(cfg),
+                          max_len=2048, slots=16, prefill_chunk=256,
+                          paged_kv=128, attn_kernel="auto",
+                          name="aot_latent")
+        assert engine._kernel_active and engine.cfg.latent.row == 640
+        yield described(engine, one_chip)
+    finally:
+        monkeypatch.undo()
+
+
 @pytest.mark.parametrize("program", PROGRAMS)
-@pytest.mark.parametrize("fixture", ["kernel_engine", "kinds_engine"])
+def test_latent_programs_update_the_one_pool_in_place(latent_engine,
+                                                      program):
+    """ISSUE 34: the check of ISSUE 27 for the latent kind: compiled for
+    the chip, the chunk program (the chunk's page written with one update
+    slice, then the expanded kernel) and the decode program (the one-call
+    row write, then the absorbed kernel) hold no copy with the pool's
+    shape and list the ONE pool of every layer under
+    ``input_output_alias``."""
+    from veles_tpu.serving.lm_engine import compiled_storage_report
+    engine = latent_engine[0]
+    text = program_text(latent_engine, program)
+    leaves = jax.tree.leaves(engine._kv_pools)
+    assert len(leaves) == 2 and leaves[0].shape == (129, 1, 256, 640)
+    copies, aliased = compiled_storage_report(text, leaves[0])
+    assert copies == 0, "%d whole-pool copies in %s" % (copies, program)
+    assert aliased == len(leaves)
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+@pytest.mark.parametrize("fixture", ["kernel_engine", "kinds_engine",
+                                     "latent_engine"])
 def test_engine_programs_read_the_weights_where_they_lie(request, fixture,
                                                          program):
     """ISSUE 31: compiled for the chip, no engine program holds a copy

@@ -95,6 +95,24 @@ def test_kinds_phase_serves_the_references_tokens():
                            n_new=30, gap_limit=1e-4, kernel="force")
 
 
+def test_latent_phase_serves_the_references_tokens():
+    """ISSUE 34: latent attention under the 4-stream residual, kernels in
+    interpret mode (expanded prefill over whole pages and a part of one,
+    absorbed decode, the one-call row write of 16 lanes), float32 so that
+    the served tokens are the reference's own."""
+    tiny = dict(chip_smoke.LATENT_LM, hidden_size=64, num_attention_heads=4,
+                q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=16,
+                qk_rope_head_dim=8, v_head_dim=16, intermediate_size=96,
+                moe_intermediate_size=32, vocab_size=96, n_routed_experts=8,
+                num_experts_per_tok=2, initializer_std=0.1,
+                max_position_embeddings=64, dtype="float32",
+                rope_scaling=dict(chip_smoke.LATENT_LM["rope_scaling"],
+                                  factor=4,
+                                  original_max_position_embeddings=16))
+    chip_smoke.phase_latent(3, lm=tiny, slots=16, page=8, prompt_len=21,
+                            n_new=30, gap_limit=1e-4, kernel="force")
+
+
 def test_serve_phase_treats_a_fallback_as_failure(monkeypatch):
     """On the chip attn_kernel='auto' must select the kernels.  The
     engine here is on the CPU and falls back; tell the phase it is on

@@ -8,7 +8,7 @@ script holds the whole-program ones, which take up to minutes and are run
 by hand::
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \\
-        python tools/aot_compile.py [alexnet] [lm] [mesh] [tp]
+        python tools/aot_compile.py [alexnet] [lm] [latent] [mesh] [tp]
 
 - ``alexnet``: the graph loop's train step and the epoch-scan window
   program at minibatch 128, 227x227 crops, 1000 classes, fp32 and bf16;
@@ -19,7 +19,11 @@ by hand::
   default layout for the pool is not the kernels'; then the same two
   programs for the sandwich block with two kinds of layer and two kinds
   of pool (``chip_smoke.KINDS_LM``: heads of 128 in bfloat16, 32 lanes,
-  pages of 256, expert layers through the grouped matmul).  For each engine
+  pages of 256, expert layers through the grouped matmul); then (``latent``,
+  also on its own) the same two programs for the latent kind at the
+  benchmark cell's own configuration and geometry
+  (``benchmark/configs/xing4.0-29b-a4b.json``: 6 layers, 16 lanes, pages of
+  1024, a table of 33; one pool a layer).  For each engine
   program it prints the copies of a whole KV pool and the pool leaves
   updated in place (``compiled_storage_report``) and the copies with a
   weight matrix's shape (``compiled_param_copies``), and exits non-zero
@@ -250,6 +254,30 @@ def lm(one_chip):
                         max_len=kinds["max_position_embeddings"], slots=32,
                         prefill_chunk=256, paged_kv=True)
     engine_programs("engine kinds", eng, one_chip, (1, 8))
+    latent(one_chip)
+
+
+def latent(one_chip):
+    """ISSUE 34: the latent kind at the benchmark cell's configuration and
+    geometry: the chunk program (expanded attention) and the decode program
+    (absorbed) at the narrowest and the widest table."""
+    import json
+    from benchmark.reference import xing4
+    from veles_tpu import model_config
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "xing4.0-29b-a4b.json")) as f:
+        cfg = json.load(f)
+    dep = cfg["deployment"]
+    params = jax.tree.map(
+        lambda a: jnp.zeros(a.shape, a.dtype),
+        jax.eval_shape(lambda: xing4.make_weights(1, cfg)))
+    eng = kernel_engine(params, model_config.from_published(cfg),
+                        max_len=cfg["max_position_embeddings"],
+                        slots=dep["slots"],
+                        prefill_chunk=dep["prefill_chunk"],
+                        paged_kv=dep["paged_kv"])
+    engine_programs("engine latent", eng, one_chip, (1, eng._max_pages))
 
 
 def tp(topo):
@@ -298,6 +326,8 @@ def main(argv):
         alexnet(one_chip)
     if "lm" in want:
         lm(one_chip)
+    elif "latent" in want:
+        latent(one_chip)
     if "mesh" in want:
         mesh(topo)
     if "tp" in want:

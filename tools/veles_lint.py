@@ -1444,6 +1444,11 @@ def run_check(root=REPO, modules=SERVING_MODULES,
     purity = _PurityPass(mset, sups_by_file, findings)
     purity.run(purity_modules, registry)
     stats["traced_functions"] = purity.traced_functions
+    # ... and so does every file the purity pass FOLLOWED a call into
+    # (``ops/latent.py`` calls ``ops/attention.py::paged_write``): the
+    # recompile pass flags lines there, so an allow() there must count
+    for mod, _ in purity.analyzed:
+        _adopt(mod.relpath)
     recompile = _RecompilePass(mset, sups_by_file, findings)
     recompile.run_bodies(purity.analyzed)
     recompile.run_census(census_modules, jit_guard_fixtures)

@@ -24,9 +24,20 @@ Two blocks:
   gated-SiLU feed forward (dense, or sigmoid-routed experts beside a shared
   expert), embedding scaled by sqrt(d_model), untied head.
 
+- ``pre_rms`` — RMSNorm without bias before attention and before a
+  gated-SiLU feed forward (dense in the leading layers, then sigmoid-routed
+  experts beside a shared expert), untied head, and LATENT attention
+  (``latent``): queries through a low-rank bottleneck, keys and values
+  re-expanded from one cached latent row a token (``kv_rank`` numbers and
+  ``rope`` rotated ones shared by all heads), rotary positions scaled by
+  YaRN (``yarn``).  Under ``hyper`` the residual is ``streams`` parallel
+  streams mixed around every sublayer by input-dependent coefficients
+  (manifold-constrained hyper-connections): see ``ops/hyper.py``.
+
 A record with ``attn_kinds`` naming both ``sliding`` and ``full`` layers has
 two kinds of KV cache (``kinds``): the engine keeps a page table and an
-allocator for each.
+allocator for each.  A latent record has one kind (``full``): one table,
+and ONE pool array a layer whose rows are the latents.
 """
 
 from __future__ import annotations
@@ -58,9 +69,58 @@ class MoEConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentConfig:
+    """Latent attention's five sizes: the queries' bottleneck, the cached
+    latent, and per head the unrotated and rotated parts of a query or key
+    and a value's width."""
+    q_rank: int
+    kv_rank: int
+    nope: int
+    rope: int
+    v: int
+
+    @property
+    def width(self):
+        """Numbers a cached token holds a layer: the latent and the one
+        rotated key all heads share."""
+        return self.kv_rank + self.rope
+
+    @property
+    def row(self):
+        """Lanes of one pool row: ``width`` rounded up to the chip's 128
+        (576 -> 640).  The chip's tiled layout pads an array's minor axis
+        to 128 lanes whatever is asked for, so the padding costs no byte
+        that a 576-wide array would not; stating it keeps the pool the
+        shape the kernels read and no dispatch converts it."""
+        return -(-self.width // 128) * 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN's scaling of the rotary frequencies (``rope_scaling`` of type
+    ``yarn``)."""
+    factor: float
+    original: int                     # original_max_position_embeddings
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
+class HyperConfig:
+    """The residual of ``streams`` streams: Sinkhorn iterations and their
+    epsilon, and the clamp on the mixing matrix's logits."""
+    streams: int
+    iters: int = 20
+    eps: float = 1e-6
+    clamp: Tuple[float, float] = (-30.0, 30.0)
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     n_heads: int
-    block: str = "pre_ln"             # | "sandwich"
+    block: str = "pre_ln"             # | "sandwich" | "pre_rms"
     #: None: read off ``wk``'s width (the classic tree states it)
     n_kv_heads: Optional[int] = None
     #: None: ``d_model // n_heads``
@@ -79,10 +139,21 @@ class ModelConfig:
     #: matmul accumulation, router scores and the logits are float32
     dtype: str = "float32"
     eps: float = 1e-5
+    #: latent attention (``pre_rms`` only); None: heads project their own
+    #: keys and values
+    latent: Optional[LatentConfig] = None
+    yarn: Optional[YarnConfig] = None
+    #: the n-stream residual (``pre_rms`` only); None: one plain stream
+    hyper: Optional[HyperConfig] = None
 
     def __post_init__(self):
-        if self.block not in ("pre_ln", "sandwich"):
+        if self.block not in ("pre_ln", "sandwich", "pre_rms"):
             raise ValueError("unknown block %r" % (self.block,))
+        if (self.block == "pre_rms") != (self.latent is not None):
+            raise ValueError("the pre_rms block is the latent-attention "
+                             "block: both or neither")
+        if self.block != "pre_rms" and (self.yarn or self.hyper):
+            raise ValueError("yarn and hyper belong to the pre_rms block")
         if self.attn_kinds is not None:
             bad = set(self.attn_kinds) - {SLIDING, FULL}
             if bad:
@@ -147,6 +218,15 @@ class ModelConfig:
     def embed_scale(self, d_model):
         return math.sqrt(d_model) if self.block == "sandwich" else None
 
+    @property
+    def wide(self):
+        """The residual stream is float32 whatever the model's dtype."""
+        return self.block in ("sandwich", "pre_rms")
+
+    @property
+    def streams(self):
+        return self.hyper.streams if self.hyper is not None else 1
+
 
 def classic(n_heads, rope=False, window=None, sinks=0):
     """The record of the repo's own block from the keyword arguments its
@@ -173,6 +253,8 @@ def from_published(cfg):
     deployment's share where they are given: ``held_experts`` ``[lo, n]``
     (this tree's experts, of ``router_width`` that the router scores)."""
     family = cfg.get("model_type")
+    if family == "xing4_0":
+        return _xing4(cfg)
     if family != "afmoe":
         raise ValueError("no record for model_type %r (the pre_ln block "
                          "is made by model_config.classic)" % (family,))
@@ -196,4 +278,51 @@ def from_published(cfg):
                       route_norm=bool(cfg["route_norm"]),
                       route_scale=float(cfg["route_scale"]), held=held,
                       shared=cfg["num_shared_experts"] > 0),
+        dtype=cfg.get("dtype", "bfloat16"), eps=cfg["rms_norm_eps"])
+
+
+def _xing4(cfg):
+    """``model_type: xing4_0``: latent attention under YaRN, leading dense
+    layers then sigmoid-routed experts (``noaux_tc``: a selection bias,
+    one group) beside shared ones, an ``hc_mult``-stream residual.  The
+    multi-token-prediction module is not part of the record: a tree that
+    carries one is served without it."""
+    if cfg.get("topk_method", "noaux_tc") != "noaux_tc" \
+            or cfg.get("n_group", 1) != 1 or cfg.get("topk_group", 1) != 1:
+        raise ValueError("xing4_0: only noaux_tc selection over one group")
+    if cfg.get("n_shared_experts", 0) > 1:
+        raise ValueError("xing4_0: one shared expert or none")
+    scaling = cfg.get("rope_scaling") or None
+    if scaling is not None and scaling.get("type") != "yarn":
+        raise ValueError("xing4_0: rope_scaling of type %r"
+                         % (scaling.get("type"),))
+    n = cfg["num_hidden_layers"]
+    return ModelConfig(
+        n_heads=cfg["num_attention_heads"], block="pre_rms",
+        rope=True, rope_theta=float(cfg["rope_theta"]),
+        latent=LatentConfig(
+            q_rank=cfg["q_lora_rank"], kv_rank=cfg["kv_lora_rank"],
+            nope=cfg["qk_nope_head_dim"], rope=cfg["qk_rope_head_dim"],
+            v=cfg["v_head_dim"]),
+        yarn=None if scaling is None else YarnConfig(
+            factor=float(scaling["factor"]),
+            original=int(scaling["original_max_position_embeddings"]),
+            beta_fast=float(scaling.get("beta_fast", 32)),
+            beta_slow=float(scaling.get("beta_slow", 1)),
+            mscale=float(scaling.get("mscale", 1)),
+            mscale_all_dim=float(scaling.get("mscale_all_dim", 0))),
+        hyper=None if "hc_mult" not in cfg else HyperConfig(
+            streams=int(cfg["hc_mult"]),
+            iters=int(cfg.get("hc_sinkhorn_iters", 20)),
+            eps=float(cfg.get("hc_eps", 1e-6)),
+            clamp=(float(cfg.get("mhc_h_res_clamp_min", -30)),
+                   float(cfg.get("mhc_h_res_clamp_max", 30)))),
+        ffn_kinds=tuple(DENSE if i < cfg["first_k_dense_replace"] else MOE
+                        for i in range(n)),
+        moe=MoEConfig(router_width=cfg["n_routed_experts"],
+                      top_k=cfg["num_experts_per_tok"],
+                      score=cfg["scoring_func"],
+                      route_norm=bool(cfg["norm_topk_prob"]),
+                      route_scale=float(cfg["routed_scaling_factor"]),
+                      shared=cfg.get("n_shared_experts", 0) > 0),
         dtype=cfg.get("dtype", "bfloat16"), eps=cfg["rms_norm_eps"])

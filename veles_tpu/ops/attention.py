@@ -451,6 +451,12 @@ def mha_forward(params, x, n_heads, causal=True, block_size=None,
     ``rope`` rotates q/k (``positions`` defaults to 0..s-1); ``window``
     restricts attention to the last W positions."""
     cfg = model_config.of(n_heads, rope, window, sinks)
+    if cfg.latent is not None:
+        from veles_tpu.ops.latent import latent_forward
+        if return_kv or not causal:
+            raise ValueError("latent attention is causal and has no "
+                             "contiguous cache")
+        return latent_forward(params, x, cfg, positions)
     rope, window = cfg.layer_rope(layer), cfg.layer_window(layer)
     n_heads, sinks = cfg.n_heads, cfg.sinks
     s = x.shape[1]
@@ -666,6 +672,7 @@ def paged_write(pool, ptab, pos, rows, write_mask=None, kernel=False):
     # the ids
     new = jnp.moveaxis(rows, -3, -2).reshape(
         -1, pool.shape[1], 1, pool.shape[3])
+    # lint: allow(recompile-hazard): the rows of a write are the engine's lanes x c, fixed per program family
     if kernel and c == 1 and new.shape[0] >= ROW_KERNEL_MIN \
             and page % (32 // pool.dtype.itemsize) == 0:
         # (the kernel moves whole tiles of 32 / itemsize pool rows)

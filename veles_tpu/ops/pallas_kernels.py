@@ -390,10 +390,11 @@ def _unpack_outputs(o, r):
                      axis=2).reshape(b, kvp * r, rows // r, lanes // r)
 
 
-def _flash_step(q, k, v, live, dh, acc_ref, l_ref, m_ref):
+def _flash_step(q, k, v, live, dh, acc_ref, l_ref, m_ref, scale=None):
     """One online-softmax accumulation against a K/V block — the
     ``attention._online_update`` recurrence on kernel refs; ``dh`` is
-    the head size the scores scale by (a packed row is wider).  NEG_INF
+    the head size the scores scale by (a packed row is wider), unless
+    ``scale`` gives the factor itself (latent attention's).  NEG_INF
     masking (finite) keeps fully-masked blocks harmless: their
     transient terms rescale to exactly 0.0 (fp32 exp underflow) once a
     live block arrives, the same argument ``blockwise_attention``
@@ -405,7 +406,7 @@ def _flash_step(q, k, v, live, dh, acc_ref, l_ref, m_ref):
     s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                             precision=precision,
                             preferred_element_type=jnp.float32)
-    s = s / jnp.sqrt(jnp.float32(dh))
+    s = s / jnp.sqrt(jnp.float32(dh)) if scale is None else s * scale
     s = s + jnp.where(live, 0.0, NEG_INF)[None]
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, s.max(axis=-1))
@@ -742,6 +743,260 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
       first, last, qp, pack_heads(k_new, r), pack_heads(v_new, r), k_pool,
       v_pool)
     return _unpack_outputs(o, r).reshape(b, h, c, dh), k_out, v_out
+
+
+# ------------------------------------------------ paged latent attention
+# Latent attention (ops/latent.py) keeps ONE row a token a layer, shared by
+# every head: pool (n_pages, 1, page, row), row = [c_kv (kv_rank) | k_rope |
+# zeros] padded to whole 128-lane tiles.  The two kernels are the two orders
+# of the same sums: the decode kernel attends in the ABSORBED form (the
+# query rows arrive ``row`` wide and meet a cached row lane for lane; the
+# values ARE the cached rows, so a page is fetched once and serves scores
+# and accumulation alike), the prefill kernel in the EXPANDED form (it
+# rebuilds a page's keys and values through ``W_kvb`` in fast memory, a
+# block of heads at a time).  Both walk the page table and skip dead pages
+# as the kernels above do (``live_pages``).
+
+
+def paged_latent_decode(q, pool, ptab, pos, scale, interpret=None):
+    """Absorbed latent attention over the paged latent pool: ``c`` query
+    rows a head a lane, q (b, h, c, row) = ``[q_nope W_kvb[K,h]^T | q_rope
+    | 0]``, against the lane's cached rows through its table.  ``s = q .
+    row * scale`` (the zero lanes meet zeros), causal online softmax in
+    float32, ``o = sum p row``: the pool must already hold the rows of
+    positions [0, pos + c).  One pool page per grid step, fetched ONCE for
+    scores and values and all ``h`` heads (:func:`paged_flash_decode` with
+    the pool as K and as V would fetch it twice).  Dead pages cost neither
+    a fetch nor a step.  Returns (b, h, c, row): lanes [:kv_rank] are
+    ``o_lat``, the rest is of no use."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, c, row = q.shape
+    page = pool.shape[2]
+    m_pages = ptab.shape[1]
+    rows = h * c
+    qp = q.reshape(b, 1, rows, row)
+    first, last, sink = live_pages(jnp.asarray(pos, jnp.int32), c, page,
+                                   m_pages)
+
+    def kernel(ptab_ref, pos_ref, first_ref, last_ref, q_ref, k_ref, o_ref,
+               acc_ref, l_ref, m_ref):
+        i, j = pl.program_id(0), pl.program_id(1)
+
+        @pl.when(j == 0)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+
+        @pl.when(_is_live(j, first_ref[i], last_ref[i], sink))
+        def _():
+            k_pos = j * page + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, page), 1)
+            q_pos = pos_ref[i] + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, page), 0) % c
+            rows_k = k_ref[0]
+            _flash_step(q_ref[0], rows_k, rows_k, k_pos <= q_pos, row,
+                        acc_ref, l_ref, m_ref, scale=scale)
+
+        @pl.when(j == m_pages - 1)
+        def _():
+            o_ref[0] = (acc_ref[...]
+                        / l_ref[...][..., None]).astype(o_ref.dtype)
+
+    def lane(i, j, *_):
+        return (i, 0, 0, 0)
+
+    def history(i, j, pt, ps, fs, ls):
+        return (pt[i, _live_entry(j, fs[i], ls[i], sink)], 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(b, m_pages),
+        in_specs=[pl.BlockSpec((1, 1, rows, row), lane),
+                  pl.BlockSpec((1, 1, page, row), history)],
+        out_specs=pl.BlockSpec((1, 1, rows, row), lane),
+        scratch_shapes=[pltpu.VMEM((1, rows, row), jnp.float32),
+                        pltpu.VMEM((1, rows), jnp.float32),
+                        pltpu.VMEM((1, rows), jnp.float32)],
+    )
+    o = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, 1, rows, row), q.dtype),
+        interpret=_interpret(interpret),
+    )(jnp.asarray(ptab, jnp.int32), jnp.asarray(pos, jnp.int32),
+      first, last, qp, pool)
+    return o.reshape(b, h, c, row)
+
+
+#: heads one grid step of the latent prefill kernel takes (their
+#: accumulators, queries and outputs stay in fast memory while the lane's
+#: pages stream past: a page is fetched heads / this many times), and the
+#: query rows one softmax update takes (float32 scores of rows x page)
+_LATENT_HEADS = 4
+_LATENT_Q_ROWS = 256
+#: fast memory the latent prefill kernel may take (the chip's default
+#: gives a kernel 16 MiB of 128)
+_LATENT_VMEM = 48 << 20
+
+
+def paged_latent_prefill(q_nope, q_rope, wk, wv, pool, ptab, pos, scale,
+                         kv_rank, interpret=None):
+    """Expanded latent attention of one page-aligned chunk of ``c == page``
+    positions a lane over the paged latent pool, the chunk's own rows
+    included: THE POOL ALREADY HOLDS THEM (the caller writes the chunk's
+    page first; the table's entry ``pos // page`` is that page).
+
+    q_nope (b, h, c, nope), q_rope (b, h, c, rope) rotated; wk (h, kv_rank,
+    nope), wv (h, kv_rank, v): ``W_kvb`` by head; pool (n_pages, 1, page,
+    row) with ``row - kv_rank`` a whole number of lane tiles that hold
+    ``[k_rope | zeros]``.  The grid is (lane, head block, page).  A step
+    on a live page rebuilds the block's keys and values from the page's
+    latents (``c_kv W_k[h]``, ``c_kv W_v[h]``: the re-expansion, once a
+    cached token a head a chunk) and runs the online softmax, ``s =
+    (q_nope . k_nope + q_rope . k_rope) * scale`` in float32, a block of
+    query rows at a time.  History pages lie wholly below the chunk's
+    frontier and need no mask; the chunk's own page (the last live one) is
+    causal, and a block of query rows there takes only the keys up to its
+    own diagonal (``pos`` is page-aligned, so which those are is static).
+    Dead pages (behind the chunk) cost neither a fetch nor a step.
+    Returns (b, h, c, v)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, c, nope = q_nope.shape
+    rope = q_rope.shape[-1]
+    vdim = wv.shape[-1]
+    page, row = pool.shape[2:]
+    if c != page:
+        raise ValueError("prefill kernel needs chunk (%d) == page (%d)"
+                         % (c, page))
+    tail = row - kv_rank
+    m_pages = ptab.shape[1]
+    hb = math.gcd(h, _LATENT_HEADS)
+    qb = math.gcd(c, _LATENT_Q_ROWS)
+    dtype = q_nope.dtype
+    precision = F._PRECISION if dtype == jnp.float32 else None
+    # the queries' rotated part beside zeros, as wide as the row's tail
+    q = jnp.concatenate(
+        [q_nope, q_rope,
+         jnp.zeros((b, h, c, tail - rope), dtype)], axis=-1)
+    _, last, _ = live_pages(jnp.asarray(pos, jnp.int32), c, page, m_pages)
+
+    def dot_nt(x, y):      # x (m, k) . y (n, k)^T
+        return jax.lax.dot_general(
+            x, y, (((1,), (1,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32)
+
+    def dot_nn(x, y):
+        return jax.lax.dot_general(
+            x, y, (((1,), (0,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32)
+
+    def update(e, rows, s, v, acc_ref, l_ref, m_ref):
+        """The online-softmax recurrence on the query rows ``rows`` (a
+        slice) of head ``e`` against scores ``s`` (qb, keys) and values
+        ``v``."""
+        m_prev = m_ref[e, rows, :]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[e, rows, :] = l_ref[e, rows, :] * alpha \
+            + p.sum(axis=-1, keepdims=True)
+        acc_ref[e, rows, :] = acc_ref[e, rows, :] * alpha \
+            + dot_nn(p.astype(v.dtype), v)
+        m_ref[e, rows, :] = m_new
+
+    def kernel(ptab_ref, pos_ref, last_ref, q_ref, wk_ref, wv_ref, pool_ref,
+               o_ref, acc_ref, l_ref, m_ref):
+        i, j = pl.program_id(0), pl.program_id(2)
+
+        @pl.when(j == 0)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+
+        def expand(e):
+            lat = pool_ref[0, 0]
+            c_kv = lat[:, :kv_rank]
+            k_nope = dot_nn(c_kv, wk_ref[e]).astype(dtype)
+            v = dot_nn(c_kv, wv_ref[e]).astype(dtype)
+            return k_nope, lat[:, kv_rank:], v
+
+        def scores(e, rows, k_nope, k_tail):
+            qn = q_ref[0, e, rows, :nope]
+            qt = q_ref[0, e, rows, nope:]
+            return (dot_nt(qn, k_nope) + dot_nt(qt, k_tail)) * scale
+
+        # the heads of a block, and on a history page the blocks of query
+        # rows, are loops and not unrolled: every unrolled body is compiled
+        # again for every layer of the chunk program
+
+        @pl.when(j < last_ref[i])
+        def _():           # a history page: every key visible to every row
+            def head(e, _):
+                k_nope, k_tail, v = expand(e)
+
+                def block(t, _):
+                    rows = pl.ds(pl.multiple_of(t * qb, qb), qb)
+                    update(e, rows, scores(e, rows, k_nope, k_tail), v,
+                           acc_ref, l_ref, m_ref)
+                    return 0
+                return jax.lax.fori_loop(0, c // qb, block, 0)
+            jax.lax.fori_loop(0, hb, head, 0)
+
+        @pl.when(j == last_ref[i])
+        def _():           # the chunk's own page: causal
+            def head(e, _):
+                k_nope, k_tail, v = expand(e)
+                for lo in range(0, c, qb):
+                    keys = lo + qb          # up to this block's diagonal
+                    rows = pl.ds(lo, qb)
+                    s = scores(e, rows, k_nope[:keys], k_tail[:keys])
+                    k_pos = jax.lax.broadcasted_iota(
+                        jnp.int32, (qb, keys), 1)
+                    q_pos = lo + jax.lax.broadcasted_iota(
+                        jnp.int32, (qb, keys), 0)
+                    s = jnp.where(k_pos <= q_pos, s, NEG_INF)
+                    update(e, rows, s, v[:keys], acc_ref, l_ref, m_ref)
+                return 0
+            jax.lax.fori_loop(0, hb, head, 0)
+
+        @pl.when(j == m_pages - 1)
+        def _():
+            o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+    def lane(i, g, j, *_):
+        return (i, g, 0, 0)
+
+    def weights(i, g, j, *_):
+        return (g, 0, 0)
+
+    def history(i, g, j, pt, ps, ls):
+        return (pt[i, jnp.maximum(jnp.minimum(j, ls[i]), 0)], 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, h // hb, m_pages),
+        in_specs=[pl.BlockSpec((1, hb, c, nope + tail), lane),
+                  pl.BlockSpec((hb, kv_rank, nope), weights),
+                  pl.BlockSpec((hb, kv_rank, vdim), weights),
+                  pl.BlockSpec((1, 1, page, row), history)],
+        out_specs=pl.BlockSpec((1, hb, c, vdim), lane),
+        scratch_shapes=[pltpu.VMEM((hb, c, vdim), jnp.float32),
+                        pltpu.VMEM((hb, c, 1), jnp.float32),
+                        pltpu.VMEM((hb, c, 1), jnp.float32)],
+    )
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, c, vdim), dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_LATENT_VMEM),
+        interpret=_interpret(interpret),
+    )(jnp.asarray(ptab, jnp.int32), jnp.asarray(pos, jnp.int32), last,
+      q, wk, wv, pool)
 
 
 def serving_kernels_supported(paged, n_heads, kv_heads, head_dim,

@@ -99,7 +99,29 @@ def _wire(blk, h, cfg, layer, attend, with_aux=False, token_mask=None):
     before AND after each half, the second norm inside the residual.
     Returns (h, state, extra) with ``extra`` the expert layer's load-
     balancing loss (``with_aux``, pre_ln) or its counts (sandwich; None
-    for a dense layer)."""
+    for a dense layer).  ``pre_rms``: RMSNorm, latent attention, residual;
+    RMSNorm, gated feed forward, residual: over one plain stream, or with
+    each half inside the n-stream residual's mix (``ops/hyper.py``)."""
+    if cfg.block == "pre_rms":
+        from veles_tpu.ops import hyper
+
+        def attn_half(u):
+            return attend(blk["attn"], rms_norm(u, blk["ln_attn"], cfg.eps,
+                                                cfg.dtype))
+
+        def ffn_half(u):
+            normed = rms_norm(u, blk["ln_mlp"], cfg.eps)
+            return _block_ffn(blk, normed.astype(cfg.dtype), cfg, layer,
+                              normed)
+
+        if cfg.hyper is None:
+            out, state = attn_half(h)
+            h = h + out.astype(h.dtype)
+            ff, stats = ffn_half(h)
+            return h + ff.astype(h.dtype), state, stats
+        h, state = hyper.around(blk["hc_attn"], h, cfg, attn_half)
+        h, stats = hyper.around(blk["hc_mlp"], h, cfg, ffn_half)
+        return h, state, stats
     if cfg.block == "sandwich":
         # the residual stream ``h`` is float32 whatever the model's dtype
         # (``_scaled_embed``); what a sublayer reads and returns is the
@@ -167,7 +189,7 @@ def _block_ffn(blk, hn, cfg, layer, router_in=None):
     (``router_in``: the float32 input the router scores, where it is not
     ``hn`` itself)."""
     import jax.numpy as jnp
-    if cfg.block == "sandwich":
+    if cfg.wide:
         from veles_tpu.ops.moe import gated_ffn, routed_ffn
         mm = lambda a, b: cfg_matmul(cfg, a, b)  # noqa: E731
         if cfg.ffn_kind(layer, blk) == model_config.MOE:
@@ -185,6 +207,13 @@ def _scaled_embed(params, tokens, cfg):
     and carries them, the residual stream, in float32."""
     import jax.numpy as jnp
     h = jnp.take(params["embed"], tokens, axis=0)
+    if cfg is not None and cfg.block == "pre_rms":
+        # float32 too, unscaled; copied into the residual's streams
+        h = h.astype(jnp.float32)
+        if cfg.hyper is None:
+            return h
+        from veles_tpu.ops import hyper
+        return hyper.spread(h, cfg)
     scale = cfg.embed_scale(h.shape[-1]) if cfg is not None else None
     if scale is None:
         return h
@@ -207,7 +236,10 @@ def head_logits(params, h, cfg=None):
     and the tied head, or (``sandwich``) RMSNorm and the tree's own
     ``head``, the logits in float32."""
     import jax.numpy as jnp
-    if cfg is not None and cfg.block == "sandwich":
+    if cfg is not None and cfg.hyper is not None:
+        from veles_tpu.ops import hyper
+        h = hyper.gather(h)           # the streams' sum
+    if cfg is not None and cfg.wide:
         h = rms_norm(h, params["ln_f"], cfg.eps, cfg.dtype)
         return jnp.matmul(h, params["head"],
                           preferred_element_type=jnp.float32)
@@ -296,6 +328,10 @@ def prefill(params, tokens, n_heads, max_len, rope=False, window=None,
     """
     import jax.numpy as jnp
     cfg = model_config.of(n_heads, rope, window, sinks)
+    if cfg.latent is not None:
+        raise ValueError("latent attention has no contiguous cache: its "
+                         "cached path is the paged latent pool "
+                         "(paged_chunk_apply, LMEngine(paged_kv=...))")
     h = embed_tokens(params, tokens, cfg)
     s = h.shape[1]
     pad = [(0, 0), (0, 0), (0, max_len - s), (0, 0)]
@@ -402,6 +438,21 @@ def block_paged_chunk_step(blk, h, k_pool, v_pool, ptab, pos, n_heads,
         write_mask=write_mask, base=base)
 
 
+def block_latent_chunk_step(blk, h, pool, ptab, pos, cfg,
+                            attn_kernel=None, write_mask=None, layer=0):
+    """:func:`block_paged_chunk_step` for the latent kind: one pool a
+    layer (``ops/latent.py::latent_paged_chunk_step``).  Returns (h, pool,
+    the expert layer's counts or None)."""
+    from veles_tpu.ops.latent import latent_paged_chunk_step
+
+    def attend(p, hn):
+        return latent_paged_chunk_step(p, hn, pool, ptab, pos, cfg,
+                                       attn_kernel=attn_kernel,
+                                       write_mask=write_mask)
+
+    return _wire(blk, h, cfg, layer, attend)
+
+
 def paged_chunk_embed(params, tokens, pos, cfg=None):
     """Token (+ positional, absent under RoPE) embedding for ``c``
     positions per lane starting at PER-LANE traced ``pos`` (b,) —
@@ -453,13 +504,20 @@ def paged_chunk_apply(params, tokens, pools, ptab, pos, n_heads,
     by_kind = isinstance(ptab, dict)
     new_pools = []
     counts = []
-    for i, (blk, (kp, vp)) in enumerate(zip(params["blocks"], pools)):
+    for i, (blk, pool) in enumerate(zip(params["blocks"], pools)):
         kind = cfg.kind(i)
-        h, kp, vp, stats = block_paged_chunk_step(
-            blk, h, kp, vp, ptab[kind] if by_kind else ptab, pos, cfg,
-            attn_kernel=attn_kernel, write_mask=write_mask, layer=i,
-            base=base[kind] if by_kind else base)
-        new_pools.append((kp, vp))
+        if cfg.latent is not None:
+            # one pool a layer, its rows the latents
+            h, pool, stats = block_latent_chunk_step(
+                blk, h, pool[0], ptab, pos, cfg, attn_kernel=attn_kernel,
+                write_mask=write_mask, layer=i)
+            new_pools.append((pool,))
+        else:
+            h, kp, vp, stats = block_paged_chunk_step(
+                blk, h, *pool, ptab[kind] if by_kind else ptab, pos, cfg,
+                attn_kernel=attn_kernel, write_mask=write_mask, layer=i,
+                base=base[kind] if by_kind else base)
+            new_pools.append((kp, vp))
         if stats is not None:
             counts.append(stats)
     if not with_stats:

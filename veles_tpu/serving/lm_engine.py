@@ -691,6 +691,11 @@ class LMEngine(Logger):
                         % (what, self.cfg.block,
                            " with per-layer attention kinds"
                            if self.cfg.by_kind else ""))
+        if not self._paged and self.cfg.latent is not None:
+            raise ValueError(
+                "LMEngine: latent attention needs paged_kv — its cache is "
+                "the paged pool of latent rows, and no contiguous layout "
+                "holds them")
         if not self._paged and any(
                 self.cfg.ffn_kind(i, blk) == model_config.MOE
                 for i, blk in enumerate(params["blocks"])):
@@ -722,8 +727,14 @@ class LMEngine(Logger):
 
         embed = params["embed"]
         d_model = embed.shape[1]
-        head_dim = self.cfg.head_size(d_model)
-        kv_heads = self.cfg.kv_heads(params["blocks"][0]["attn"], d_model)
+        if self.cfg.latent is not None:
+            # one row a token a layer, shared by every head: the latent
+            # and the rotated key, padded to whole lane tiles
+            head_dim, kv_heads = self.cfg.latent.row, 1
+        else:
+            head_dim = self.cfg.head_size(d_model)
+            kv_heads = self.cfg.kv_heads(params["blocks"][0]["attn"],
+                                         d_model)
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
             if kv_heads % self.tp:
@@ -825,6 +836,13 @@ class LMEngine(Logger):
             self._page_tables = numpy.zeros(
                 (self.slots, self._max_pages), numpy.int32)
             self.metrics.set_gauge("kv_pages_total", num_pages)
+            if self.cfg.latent is not None:
+                # the pool's real bytes a token over all layers, padding
+                # included (width x 2 x layers if nothing were padded)
+                self.metrics.set_gauge(
+                    "kv_bytes_per_token",
+                    head_dim * embed.dtype.itemsize
+                    * len(params["blocks"]))
             if model_config.SLIDING in self.cfg.kinds:
                 # two kinds of cache (ISSUE 28): a page table, an
                 # allocator and pools of their own for the sliding
@@ -962,6 +980,9 @@ class LMEngine(Logger):
                   and self.cfg.kind(i) == model_config.SLIDING
                   else self._storage_shape
                   for i in range(len(self.params["blocks"]))]
+        if self.cfg.latent is not None:
+            # ONE pool a layer: its rows are (c_kv, k_rope)
+            return [(zeros(shape),) for shape in shapes]
         return [(zeros(shape), zeros(shape)) for shape in shapes]
 
     def _storage(self):
@@ -1225,8 +1246,8 @@ class LMEngine(Logger):
             # copy-on-write: duplicate one page across every block so
             # the writer owns ``dst`` exclusively and the other
             # referents of ``src`` keep bit-identical rows
-            return [(kp.at[dst].set(kp[src]), vp.at[dst].set(vp[src]))
-                    for kp, vp in pools]
+            return [tuple(p.at[dst].set(p[src]) for p in layer)
+                    for layer in pools]
 
         kv_tree, repl = self._out_shard_trees()
         pair = (kv_tree, repl) if kv_tree is not None else None
@@ -2725,7 +2746,11 @@ class LMEngine(Logger):
             self._teardown_slot(slot, lane, e)
             return
         self.metrics.inc("prefill_dispatches")
-        self._note_attn_dispatch(start, self._max_pages, rows=slot)
+        # (the latent kind's chunk kernel also walks the chunk's own page,
+        # written before it: its query rows count like a decode's)
+        self._note_attn_dispatch(
+            start, self._max_pages, rows=slot,
+            span=C if self.cfg.latent is not None else 0)
         self.metrics.inc("prefill_tokens",
                          (req.true_len - start) if is_tail
                          else len(tokens))
