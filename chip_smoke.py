@@ -966,6 +966,162 @@ def phase_latent(seed, lm=LATENT_LM, slots=16, page=1024, prompt_len=5500,
         "tokens", same, n_new)
 
 
+#: a stack of linear and full layers at the benchmark configuration's
+#: published widths (benchmark/configs/qwen3-next-80b-a3b-ep4.json), cut in
+#: depth, experts and vocabulary so that the phase is quick: one period
+LINEAR_LM = {
+    "model_type": "qwen3_next", "hidden_size": 2048,
+    "num_attention_heads": 16, "num_key_value_heads": 2, "head_dim": 256,
+    "partial_rotary_factor": 0.25, "rope_theta": 10000000,
+    "rope_scaling": None, "rms_norm_eps": 1e-6,
+    "linear_num_key_heads": 16, "linear_num_value_heads": 32,
+    "linear_key_head_dim": 128, "linear_value_head_dim": 128,
+    "linear_conv_kernel_dim": 4, "moe_intermediate_size": 512,
+    "shared_expert_intermediate_size": 512, "num_experts": 16,
+    "router_width": 64, "held_experts": [0, 16], "num_experts_per_tok": 10,
+    "norm_topk_prob": True, "decoder_sparse_step": 1, "mlp_only_layers": [],
+    "vocab_size": 8192, "num_hidden_layers": 4, "full_attention_interval": 4,
+    "initializer_std": 0.02, "max_position_embeddings": 4096,
+}
+
+
+def phase_linear(seed, lm=LINEAR_LM, slots=16, page=1024, prompt_len=2500,
+                 n_new=60, rows=1024, gap_limit=1.5, kernel="auto",
+                 interpret=False):
+    """The gated delta rule (``ops/linear_attn.py``) at the published head
+    sizes: the CHUNKED order (``chunk_terms`` and the sequential pass, on
+    the chip ``pallas_kernels.gdn_chunk``) against the recurrent rule row
+    by row over ``rows`` rows from a state that is not zero; the recurrent
+    STEP (``gdn_decode``) with half the lanes masked, whose states must
+    come back bit for bit; then one request through ``LMEngine`` (state
+    slots beside pages, the prefill kernel at a head of 256 in query
+    blocks, the row-tiled grouped matmul in the decode step) against the
+    benchmark's plain reference (``benchmark/reference/qwen3_next.py``,
+    float32, the recurrent rule token by token)."""
+    import jax
+    import jax.numpy as jnp
+    from benchmark.reference import qwen3_next
+    from veles_tpu import model_config
+    from veles_tpu.ops import linear_attn
+    from veles_tpu.ops import pallas_kernels as PK
+    from veles_tpu.serving import LMEngine
+    record = model_config.from_published(lm)
+    lin = record.linear
+    h, dk, dv = lin.v_heads, lin.k_dim, lin.v_dim
+    say("linear", "%d layers %s, %d value heads of %d x %d, %d experts held "
+        "of %d top-%d, page %d, %s; %d lanes", lm["num_hidden_layers"],
+        "".join(k[0] for k in record.attn_kinds), h, dk, dv,
+        lm["num_experts"], lm["router_width"], lm["num_experts_per_tok"],
+        page, record.dtype, slots)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q, k = (jax.random.normal(key, (1, rows, h, dk)) for key in keys[:2])
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * dk ** -0.5
+    v = jax.random.normal(keys[2], (1, rows, h, dv))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[3], (1, rows, h)))
+    g = -jnp.exp(jax.random.uniform(keys[4], (h,), minval=-6.0, maxval=2.0)) \
+        * jax.nn.softplus(jax.random.normal(keys[3], (1, rows, h)) + 1.0)
+    state = jax.random.normal(keys[5], (slots, h, dk, dv))
+    use_kernel = interpret or on_tpu()
+
+    def rel(got, want):
+        err, largest = _max_err(got, want)
+        return err / max(largest, 1e-30)
+
+    @jax.jit
+    def chunked(state):
+        terms = linear_attn.chunk_terms(q, k, v, beta, g)
+        if use_kernel:
+            o, s = PK.gdn_chunk(state, jnp.asarray([1]), jnp.asarray([False]),
+                                *terms, interpret=interpret)
+            return o, s[1:2]
+        return linear_attn.chunk_pass(state[1:2], terms)
+
+    @jax.jit
+    def by_row(state):
+        def row(s, t):
+            o, s = linear_attn.recurrent_step(s, *t)
+            return s, o
+        s, o = jax.lax.scan(row, state[1:2], tuple(
+            jnp.moveaxis(y, 1, 0) for y in (q, k, v, beta, g)))
+        return jnp.moveaxis(o, 0, 1), s
+
+    with timed("linear", "chunked rule over %d rows (%s)"
+               % (rows, "kernel" if use_kernel else "scan")):
+        o, s = jax.block_until_ready(chunked(state))
+    with timed("linear", "recurrent rule over %d rows" % rows):
+        o2, s2 = jax.block_until_ready(by_row(state))
+    o = jnp.moveaxis(o, 1, 3).reshape(o2.shape)
+    err = (rel(o, o2), rel(s, s2))
+    say("linear", "chunked against recurrent: outputs %.3g, state %.3g "
+        "(largest error over largest entry)", *err)
+    check(max(err) < 2e-3, "the chunked rule differs from the recurrent "
+          "one: %r", err)
+    active = jnp.arange(slots) % 2 == 0
+    # (the first rows as one row of each lane)
+    row = [y[0, :slots] for y in (q, k, v, beta, g)]
+    if use_kernel:
+        step = jax.jit(lambda s: PK.gdn_decode(s, *row, active,
+                                               interpret=interpret))
+        with timed("linear", "the step with half of %d lanes masked"
+                   % slots):
+            o, s = jax.block_until_ready(step(state))
+        o2, s2 = linear_attn.recurrent_step(state, *row)
+        err = (rel(o[active], o2[active]), rel(s[active], s2[active]))
+        kept = bool((s[~active] == state[~active]).all())
+        say("linear", "step kernel against the rule: outputs %.3g, state "
+            "%.3g; masked lanes bit for bit: %s", *err, kept)
+        check(max(err) < 1e-4 and kept, "the step kernel: %r, masked lanes "
+              "kept %s", err, kept)
+    weights = jax.tree.map(lambda a: a.astype(record.dtype),
+                           qwen3_next.make_weights(seed, lm))
+    prompt = numpy.random.RandomState(seed).randint(
+        0, lm["vocab_size"], prompt_len)
+    engine = LMEngine(weights, record, max_len=lm["max_position_embeddings"],
+                      slots=slots, prefill_chunk=page, paged_kv=True,
+                      attn_kernel=kernel, deadline_s=600.0, name="linear")
+    with timed("linear", "engine start (every program and table width)"):
+        engine.start()
+    try:
+        with timed("linear", "one request: prompt %d, n_new %d"
+                   % (prompt_len, n_new)):
+            out = engine.submit(prompt, n_new).result(timeout=600)
+        snap = engine.metrics.snapshot()
+        gauges, counters = snap["gauges"], snap["counters"]
+        say("linear", "attn_kernel_active %d, kv_storage_in_place %d, "
+            "kv_storage_rebuilds %d, state_bytes_per_lane %d, "
+            "kv_bytes_per_token %d, state_resets %d",
+            gauges["attn_kernel_active"], gauges["kv_storage_in_place"],
+            counters.get("kv_storage_rebuilds", 0),
+            gauges["state_bytes_per_lane"], gauges["kv_bytes_per_token"],
+            counters["state_resets"])
+        if on_tpu():
+            check(gauges["attn_kernel_active"] == 1
+                  and counters.get("attn_kernel_fallbacks", 0) == 0,
+                  "attn_kernel='auto' fell back to the XLA path on the "
+                  "TPU: %s", engine._kernel_fallback_reason)
+        check(gauges["kv_storage_in_place"] == 1
+              and counters.get("kv_storage_rebuilds", 0) == 0,
+              "state and pools are not updated in place")
+        check(engine.verify_pool_invariants()["used_pages"] == 0
+              and gauges["state_slots_free"] == slots,
+              "pages or state slots still held after the request")
+    finally:
+        engine.stop()
+    steps = numpy.arange(prompt_len - 1, prompt_len + n_new - 1)
+    with timed("linear", "reference over %d tokens" % (prompt_len + n_new)):
+        ref = numpy.asarray(qwen3_next.logits(
+            weights, numpy.concatenate([prompt, out]), steps, lm))
+    gap = ref.max(-1) - ref[numpy.arange(n_new), out]
+    say("linear", "served tokens that are the reference's choice %d of %d "
+        "(off at %s); widest gap below its best %.4f (limit %.3f)",
+        int((gap == 0).sum()), n_new, numpy.nonzero(gap > 0)[0].tolist(),
+        float(gap.max()), gap_limit)
+    check(float(gap.max()) <= gap_limit,
+          "served tokens lie %.4f below the reference's best",
+          float(gap.max()))
+
+
 def _sharding_line(name, arr):
     return "%s %s on %d device(s), shard %s" % (
         name, tuple(arr.shape), len(arr.sharding.device_set),
@@ -1170,7 +1326,8 @@ def main(argv=None):
                   ("kernels", lambda: phase_kernels(args.seed)),
                   ("serve", lambda: phase_serve(args.seed)),
                   ("kinds", lambda: phase_kinds(args.seed)),
-                  ("latent", lambda: phase_latent(args.seed))]
+                  ("latent", lambda: phase_latent(args.seed)),
+                  ("linear", lambda: phase_linear(args.seed))]
     if args.only:
         phases = [(name, run) for name, run in phases if name == args.only]
     failed = []

@@ -264,6 +264,13 @@ def program_text(fixture, program):
 
     def tables(*lead):
         """``LMEngine._table_args`` at width ``lead[-1]``."""
+        if engine._state_shapes is not None:
+            # linear layers beside one table: the chunk's lane's slot, or
+            # the lanes that decode
+            return ints(*lead), (ints() if len(lead) == 1 else
+                                 jax.ShapeDtypeStruct(
+                                     lead[:-1], jnp.bool_,
+                                     sharding=ints().sharding))
         if wide is None:
             return ints(*lead)
         return ({"full": ints(*lead),
@@ -439,9 +446,137 @@ def test_latent_programs_update_the_one_pool_in_place(latent_engine,
     assert aliased == len(leaves)
 
 
+def test_delta_rule_kernels_compile_at_the_cells_widths(one_chip):
+    """ISSUE 36: the recurrent step over 64 lanes' states (32 heads of 128
+    x 128 float32, 2 MiB a lane in fast memory twice each way) and the
+    chunked rule's sequential pass over the 16 inner chunks of a 1024-row
+    chunk, at the published head sizes."""
+    lanes, h, d = 64, 32, 128
+    state = ((lanes, h, d, d), F32)
+    vec, one = ((lanes, h, d), F32), ((lanes, h), F32)
+    text = compile_for(
+        one_chip,
+        lambda s, q, k, v, b, g, a: PK.gdn_decode(s, q, k, v, b, g, a,
+                                                  interpret=False),
+        state, vec, vec, vec, one, one, ((lanes,), jnp.bool_))
+    assert "tpu_custom_call" in text
+    rows = lambda *tail: ((1, h, 16) + tail, F32)  # noqa: E731
+    text = compile_for(
+        one_chip,
+        lambda s, sl, fr, *terms: PK.gdn_chunk(s, sl, fr, *terms,
+                                               interpret=False),
+        state, ((1,), I32), ((1,), jnp.bool_), rows(64, d), rows(64, d),
+        rows(64, d), rows(64, 64), rows(d, 64), rows())
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("page", [512, 1024, 2048])
+def test_flash_kernels_compile_at_a_head_of_256(one_chip, page):
+    """ISSUE 36: 16 query heads of 256 on 2 KV heads: a chunk's 8 x page
+    query rows a KV head overrun the prefill kernel's memory at any page
+    worth serving, so it takes them in blocks (``_query_rows``: a whole
+    number of blocks, scores under the limit); the decode kernel at 64
+    lanes."""
+    slots, m = 64, 17408 // page
+    pool = ((slots * m + 1, 2, page, 256), BF16)
+    text = compile_for(
+        one_chip,
+        lambda q, kn, vn, k, v, pt, ps: PK.paged_flash_prefill(
+            q, kn, vn, k, v, pt, ps, interpret=False),
+        ((1, 16, page, 256), BF16), ((1, 2, page, 256), BF16),
+        ((1, 2, page, 256), BF16), pool, pool, ((1, m), I32), ((1,), I32))
+    assert "tpu_custom_call" in text
+    qr = PK._query_rows(8 * page, page)
+    assert (8 * page) % qr == 0 and qr * page * 4 <= PK._Q_BLOCK_BYTES
+    text = compile_for(
+        one_chip,
+        lambda q, k, v, pt, ps: PK.paged_flash_decode(
+            q, k, v, pt, ps, interpret=False),
+        ((slots, 16, 1, 256), BF16), pool, pool, ((slots, m), I32),
+        ((slots,), I32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.fixture(scope="module")
+def linear_engine(one_chip):
+    """A small ``LMEngine`` for a stack of linear and full layers, the
+    Pallas serving kernels active, 64 lanes (10 of 64 routed: 640
+    assignment rows a decode step), the published head sizes (128 x 128
+    states; full heads of 256) in bfloat16.  (No weight's leading size is
+    the chunk's 256 rows or the 64 lanes.)"""
+    from benchmark.reference import qwen3_next
+    from veles_tpu import model_config
+    from veles_tpu.serving import LMEngine
+    cfg = {
+        "model_type": "qwen3_next", "hidden_size": 384,
+        "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 256,
+        "partial_rotary_factor": 0.25, "rope_theta": 10000000,
+        "rope_scaling": None, "rms_norm_eps": 1e-6,
+        "linear_num_key_heads": 2, "linear_num_value_heads": 4,
+        "linear_key_head_dim": 128, "linear_value_head_dim": 128,
+        "linear_conv_kernel_dim": 4, "moe_intermediate_size": 128,
+        "shared_expert_intermediate_size": 128, "num_experts": 16,
+        "router_width": 64, "held_experts": [0, 16],
+        "num_experts_per_tok": 10, "norm_topk_prob": True,
+        "decoder_sparse_step": 1, "mlp_only_layers": [], "vocab_size": 512,
+        "num_hidden_layers": 4, "full_attention_interval": 4,
+        "initializer_std": 0.02}
+    params = qwen3_next.make_weights(1, cfg)
+    monkeypatch = pytest.MonkeyPatch()
+    monkeypatch.setattr(PK, "on_tpu", lambda: True)
+    try:
+        engine = LMEngine(params, model_config.from_published(cfg),
+                          max_len=2048, slots=64, prefill_chunk=256,
+                          paged_kv=128, attn_kernel="auto",
+                          name="aot_linear")
+        assert engine._kernel_active
+        yield described(engine, one_chip)
+    finally:
+        monkeypatch.undo()
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_linear_programs_update_state_and_pools_in_place(linear_engine,
+                                                         program):
+    """ISSUE 36: the check of ISSUE 27 for two kinds of cache in one
+    manager: compiled for the chip, the chunk program (the chunked rule on
+    one lane's slot, the prefill kernel) and the decode program (the
+    recurrent rule on the decoding lanes' slots, the row write and the
+    decode kernel) hold no copy with the shape of a state, of a
+    convolution tail or of a pool, and list every leaf of all three under
+    ``input_output_alias``."""
+    from veles_tpu.serving.lm_engine import compiled_storage_report
+    engine = linear_engine[0]
+    text = program_text(linear_engine, program)
+    leaves = jax.tree.leaves(engine._kv_pools)
+    kinds = {leaf.shape: leaf for leaf in leaves}
+    assert set(kinds) == {(64, 4, 128, 128), (64, 3, 1024),
+                          (129, 2, 256, 256)}
+    for leaf in kinds.values():
+        copies, aliased = compiled_storage_report(text, leaf)
+        assert copies == 0, "%d copies of %s in %s" % (copies, leaf.shape,
+                                                       program)
+    assert aliased == len(leaves) == 8
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_a_decode_step_of_640_rows_takes_the_row_kernel(linear_engine,
+                                                        program):
+    """ISSUE 36: 64 lanes x 10 experts a token are 640 assignment rows, at
+    or above ``ROW_KERNEL_MIN``: the first configuration whose DECODE step
+    runs the row-tiled grouped matmul, three calls an expert layer, as its
+    chunk does; no ``ragged-dot`` in either."""
+    from veles_tpu.ops import moe
+    from veles_tpu.serving.lm_engine import compiled_grouped_matmuls
+    engine = linear_engine[0]
+    assert engine.slots * engine.cfg.moe.top_k >= moe.ROW_KERNEL_MIN
+    text = program_text(linear_engine, program)
+    assert compiled_grouped_matmuls(text) == (0, 3 * 4)
+
+
 @pytest.mark.parametrize("program", PROGRAMS)
 @pytest.mark.parametrize("fixture", ["kernel_engine", "kinds_engine",
-                                     "latent_engine"])
+                                     "latent_engine", "linear_engine"])
 def test_engine_programs_read_the_weights_where_they_lie(request, fixture,
                                                          program):
     """ISSUE 31: compiled for the chip, no engine program holds a copy

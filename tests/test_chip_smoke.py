@@ -114,6 +114,24 @@ def test_latent_phase_serves_the_references_tokens():
                             n_new=30, gap_limit=1e-4, kernel="force")
 
 
+def test_linear_phase_serves_the_references_tokens():
+    """ISSUE 36: the gated delta rule's two kernels in interpret mode (the
+    chunked rule against the recurrent one, the step with half the lanes
+    masked), then a request through state slots and pages, float32 so that
+    the served tokens are the reference's own."""
+    tiny = dict(chip_smoke.LINEAR_LM, hidden_size=64, num_attention_heads=4,
+                num_key_value_heads=2, head_dim=16, linear_num_key_heads=2,
+                linear_num_value_heads=4, linear_key_head_dim=16,
+                linear_value_head_dim=16, moe_intermediate_size=32,
+                shared_expert_intermediate_size=32, num_experts=4,
+                router_width=16, held_experts=[4, 4], num_experts_per_tok=3,
+                vocab_size=96, initializer_std=0.1,
+                max_position_embeddings=64, dtype="float32")
+    chip_smoke.phase_linear(3, lm=tiny, slots=16, page=8, prompt_len=21,
+                            n_new=30, rows=128, gap_limit=1e-4,
+                            kernel="force", interpret=True)
+
+
 def test_serve_phase_treats_a_fallback_as_failure(monkeypatch):
     """On the chip attn_kernel='auto' must select the kernels.  The
     engine here is on the CPU and falls back; tell the phase it is on

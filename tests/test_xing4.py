@@ -310,7 +310,7 @@ def test_record_from_the_published_keys():
     assert cfg.moe.shared and cfg.moe.route_scale == 2.0
     with pytest.raises(ValueError, match="model_type"):
         model_config.from_published(dict(SMALL, model_type="xing9"))
-    with pytest.raises(ValueError, match="both or neither"):
+    with pytest.raises(ValueError, match="attn_kinds"):
         model_config.ModelConfig(n_heads=4, block="pre_rms")
 
 

@@ -8,7 +8,7 @@ script holds the whole-program ones, which take up to minutes and are run
 by hand::
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \\
-        python tools/aot_compile.py [alexnet] [lm] [latent] [mesh] [tp]
+        python tools/aot_compile.py [alexnet] [lm] [latent] [linear] [mesh] [tp]
 
 - ``alexnet``: the graph loop's train step and the epoch-scan window
   program at minibatch 128, 227x227 crops, 1000 classes, fp32 and bf16;
@@ -231,6 +231,11 @@ def engine_programs(tag, eng, one_chip, widths):
         """The page-table argument at width ``lead[-1]``
         (``LMEngine._table_args``): one table, or for a stack of two
         kinds of layer one per kind and where the sliding kind's begins."""
+        if eng._state_shapes is not None:
+            # linear layers beside one table: the chunk's lane's slot, or
+            # the lanes that decode
+            return s(lead), (s(()) if len(lead) == 1
+                             else s(lead[:-1], jnp.bool_))
         if eng._wt is None:
             return s(lead)
         narrow = lead[:-1] + (min(lead[-1], eng._wt.width),)
@@ -308,6 +313,32 @@ def latent(one_chip):
     engine_programs("engine latent", eng, one_chip, (1, eng._max_pages))
 
 
+def linear(one_chip):
+    """ISSUE 36: a stack of linear (gated delta rule) and full layers at
+    the benchmark cell's configuration and geometry: the chunk program (the
+    chunked rule, the prefill kernel at a head of 256) and the decode
+    program (the recurrent rule on the decoding lanes' state, the row-tiled
+    grouped matmul at 640 assignment rows) at the narrowest and the widest
+    table."""
+    import json
+    from benchmark.reference import qwen3_next
+    from veles_tpu import model_config
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "qwen3-next-80b-a3b-ep4.json")) as f:
+        cfg = json.load(f)
+    dep = cfg["deployment"]
+    params = jax.tree.map(
+        lambda a: jnp.zeros(a.shape, a.dtype),
+        jax.eval_shape(lambda: qwen3_next.make_weights(1, cfg)))
+    eng = kernel_engine(params, model_config.from_published(cfg),
+                        max_len=cfg["max_position_embeddings"],
+                        slots=dep["slots"],
+                        prefill_chunk=dep["prefill_chunk"],
+                        paged_kv=dep["paged_kv"])
+    engine_programs("engine linear", eng, one_chip, (1, eng._max_pages))
+
+
 def tp(topo):
     from veles_tpu.ops.transformer import lm_param_specs
     from veles_tpu.parallel import make_tp_mesh
@@ -356,6 +387,8 @@ def main(argv):
         lm(one_chip)
     elif "latent" in want:
         latent(one_chip)
+    if "linear" in want:
+        linear(one_chip)
     if "mesh" in want:
         mesh(topo)
     if "tp" in want:

@@ -34,10 +34,21 @@ Two blocks:
   streams mixed around every sublayer by input-dependent coefficients
   (manifold-constrained hyper-connections): see ``ops/hyper.py``.
 
+  Without ``latent`` the same block runs over layers of two kinds
+  (``attn_kinds``): ``linear`` layers mix tokens through a gated delta rule
+  (``linear``, ``ops/linear_attn.py``) and keep a recurrent state and a
+  convolution tail a sequence, whatever its length; ``full`` layers are
+  grouped-query softmax attention whose sigmoid output gate is the second
+  half of ``wq``'s output, with per-head q/k norms and rotary positions on
+  the first ``partial_rotary`` of a head.  ``norm_centred``: every RMSNorm
+  gain is stored about zero (``1 + w``).
+
 A record with ``attn_kinds`` naming both ``sliding`` and ``full`` layers has
 two kinds of KV cache (``kinds``): the engine keeps a page table and an
 allocator for each.  A latent record has one kind (``full``): one table,
-and ONE pool array a layer whose rows are the latents.
+and ONE pool array a layer whose rows are the latents.  ``linear`` layers
+hold no pages (``kinds`` leaves them out): the engine keeps a slot of state
+a lane for each beside the full layers' one table (``state_layers``).
 """
 
 from __future__ import annotations
@@ -46,7 +57,7 @@ import dataclasses
 import math
 from typing import Optional, Tuple
 
-SLIDING, FULL = "sliding", "full"
+SLIDING, FULL, LINEAR = "sliding", "full", "linear"
 DENSE, MOE = "dense", "moe"
 
 
@@ -63,6 +74,8 @@ class MoEConfig:
     route_scale: float = 1.0
     held: Optional[Tuple[int, int]] = None    # None: all of them
     shared: bool = False              # a shared expert beside the routed
+    #: the shared expert's output times ``sigmoid(x w_gate)``, one per token
+    shared_gate: bool = False
 
     def held_range(self):
         return self.held if self.held is not None else (0, self.router_width)
@@ -93,6 +106,41 @@ class LatentConfig:
         that a 576-wide array would not; stating it keeps the pool the
         shape the kernels read and no dispatch converts it."""
         return -(-self.width // 128) * 128
+
+
+@dataclasses.dataclass(frozen=True)
+class LinearConfig:
+    """A gated-delta-rule (linear attention) layer: ``k_heads`` query/key
+    heads of ``k_dim``, ``v_heads`` value heads of ``v_dim`` (a multiple of
+    the key heads: each key head serves ``v_heads / k_heads`` value heads),
+    a causal depthwise convolution of ``conv`` positions over ``[q, k,
+    v]``."""
+    k_heads: int
+    v_heads: int
+    k_dim: int
+    v_dim: int
+    conv: int = 4
+
+    @property
+    def key_width(self):
+        return self.k_heads * self.k_dim
+
+    @property
+    def value_width(self):
+        return self.v_heads * self.v_dim
+
+    @property
+    def conv_width(self):
+        """Channels the convolution runs over: ``[q | k | v]``."""
+        return 2 * self.key_width + self.value_width
+
+    def state_shapes(self, slots):
+        """(recurrent state, convolution tail) of ``slots`` sequences: ``S``
+        (slots, v_heads, k_dim, v_dim), always float32, and the last
+        ``conv - 1`` pre-convolution rows (slots, conv - 1, conv_width) in
+        the model's dtype."""
+        return ((slots, self.v_heads, self.k_dim, self.v_dim),
+                (slots, self.conv - 1, self.conv_width))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -145,19 +193,38 @@ class ModelConfig:
     yarn: Optional[YarnConfig] = None
     #: the n-stream residual (``pre_rms`` only); None: one plain stream
     hyper: Optional[HyperConfig] = None
+    #: the ``linear`` layers' sizes (``pre_rms`` without ``latent``)
+    linear: Optional[LinearConfig] = None
+    #: share of a head's dimensions, from the first, that rotary positions
+    #: rotate (``pre_rms`` without ``latent``)
+    partial_rotary: float = 1.0
+    #: RMSNorm gains are stored about zero: the norm multiplies by ``1 + w``
+    norm_centred: bool = False
 
     def __post_init__(self):
         if self.block not in ("pre_ln", "sandwich", "pre_rms"):
             raise ValueError("unknown block %r" % (self.block,))
-        if (self.block == "pre_rms") != (self.latent is not None):
-            raise ValueError("the pre_rms block is the latent-attention "
-                             "block: both or neither")
-        if self.block != "pre_rms" and (self.yarn or self.hyper):
-            raise ValueError("yarn and hyper belong to the pre_rms block")
+        if self.block != "pre_rms" and (
+                self.latent or self.yarn or self.hyper or self.linear):
+            raise ValueError("latent, yarn, hyper and linear belong to the "
+                             "pre_rms block")
+        if self.block == "pre_rms" and self.latent is None \
+                and self.attn_kinds is None:
+            raise ValueError("the pre_rms block is latent attention, or "
+                             "names its layers' kinds (attn_kinds)")
+        if self.latent is not None and self.linear is not None:
+            raise ValueError("latent attention and linear layers do not "
+                             "share a stack")
         if self.attn_kinds is not None:
-            bad = set(self.attn_kinds) - {SLIDING, FULL}
+            bad = set(self.attn_kinds) - {SLIDING, FULL, LINEAR}
             if bad:
                 raise ValueError("unknown attention kind(s) %r" % (bad,))
+            if (LINEAR in self.attn_kinds) != (self.linear is not None):
+                raise ValueError("linear layers and their sizes (linear=) "
+                                 "come together")
+            if self.linear is not None and SLIDING in self.attn_kinds:
+                raise ValueError("linear layers stand beside full layers "
+                                 "only")
             if SLIDING in self.attn_kinds and not self.window:
                 raise ValueError("sliding layers need window=W")
             if self.sinks:
@@ -175,12 +242,21 @@ class ModelConfig:
         return tuple(k for k in (FULL, SLIDING) if k in self.attn_kinds)
 
     @property
+    def state_layers(self):
+        """The layers that hold a recurrent state and no pages."""
+        if self.linear is None:
+            return ()
+        return tuple(i for i, k in enumerate(self.attn_kinds)
+                     if k == LINEAR)
+
+    @property
     def by_kind(self):
         """The stack names its layers' attention kinds."""
         return self.attn_kinds is not None
 
     def kind(self, layer):
-        """Which cache kind layer ``layer`` reads and writes."""
+        """Which cache kind layer ``layer`` reads and writes (``linear``:
+        a state slot, no pages)."""
         if self.attn_kinds is None:
             return FULL
         return self.attn_kinds[layer]
@@ -188,7 +264,13 @@ class ModelConfig:
     def layer_rope(self, layer):
         if self.attn_kinds is None:
             return self.rope
+        if self.block == "pre_rms":
+            return self.rope and self.attn_kinds[layer] == FULL
         return self.attn_kinds[layer] == SLIDING
+
+    def rotary_dims(self, head_dim):
+        """How many of a head's dimensions, from the first, are rotated."""
+        return int(head_dim * self.partial_rotary)
 
     def layer_window(self, layer):
         if self.attn_kinds is None:
@@ -249,12 +331,15 @@ def of(cfg_or_heads, rope=False, window=None, sinks=0):
 
 def from_published(cfg):
     """The record of a published ``config.json`` (a dict under its own
-    keys), by ``model_type``.  ``afmoe`` also reads two keys of a
-    deployment's share where they are given: ``held_experts`` ``[lo, n]``
-    (this tree's experts, of ``router_width`` that the router scores)."""
+    keys), by ``model_type``.  ``afmoe`` and ``qwen3_next`` also read two
+    keys of a deployment's share where they are given: ``held_experts``
+    ``[lo, n]`` (this tree's experts, of ``router_width`` that the router
+    scores)."""
     family = cfg.get("model_type")
     if family == "xing4_0":
         return _xing4(cfg)
+    if family == "qwen3_next":
+        return _qwen3_next(cfg)
     if family != "afmoe":
         raise ValueError("no record for model_type %r (the pre_ln block "
                          "is made by model_config.classic)" % (family,))
@@ -325,4 +410,50 @@ def _xing4(cfg):
                       route_norm=bool(cfg["norm_topk_prob"]),
                       route_scale=float(cfg["routed_scaling_factor"]),
                       shared=cfg.get("n_shared_experts", 0) > 0),
+        dtype=cfg.get("dtype", "bfloat16"), eps=cfg["rms_norm_eps"])
+
+
+def _qwen3_next(cfg):
+    """``model_type: qwen3_next``: three gated-delta-rule layers to one
+    gated softmax-attention layer (``full_attention_interval``, or
+    ``layer_types`` written out), every layer with softmax-routed experts
+    beside a sigmoid-gated shared expert, zero-centred RMSNorm gains,
+    rotary positions on part of a head.  The multi-token-prediction module
+    is not part of the record."""
+    n = cfg["num_hidden_layers"]
+    if cfg.get("decoder_sparse_step", 1) != 1 or cfg.get("mlp_only_layers"):
+        raise ValueError("qwen3_next: every layer routed "
+                         "(decoder_sparse_step 1, no mlp_only_layers)")
+    if cfg.get("rope_scaling"):
+        raise ValueError("qwen3_next: no rope_scaling")
+    types = cfg.get("layer_types")
+    if types is None:
+        every = cfg["full_attention_interval"]
+        types = ["full_attention" if (i + 1) % every == 0
+                 else "linear_attention" for i in range(n)]
+    if len(types) != n:
+        raise ValueError("layer_types names %d layers of %d"
+                         % (len(types), n))
+    names = {"linear_attention": LINEAR, "full_attention": FULL}
+    width = cfg.get("router_width", cfg["num_experts"])
+    held = tuple(cfg.get("held_experts") or (0, cfg["num_experts"]))
+    return ModelConfig(
+        n_heads=cfg["num_attention_heads"], block="pre_rms",
+        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+        rope=True, rope_theta=float(cfg["rope_theta"]),
+        partial_rotary=float(cfg.get("partial_rotary_factor", 1.0)),
+        attn_kinds=tuple(names[t] for t in types),
+        linear=LinearConfig(
+            k_heads=cfg["linear_num_key_heads"],
+            v_heads=cfg["linear_num_value_heads"],
+            k_dim=cfg["linear_key_head_dim"],
+            v_dim=cfg["linear_value_head_dim"],
+            conv=cfg["linear_conv_kernel_dim"]),
+        norm_centred=True,
+        ffn_kinds=(MOE,) * n,
+        moe=MoEConfig(router_width=width,
+                      top_k=cfg["num_experts_per_tok"], score="softmax",
+                      route_norm=bool(cfg["norm_topk_prob"]), held=held,
+                      shared=cfg["shared_expert_intermediate_size"] > 0,
+                      shared_gate=True),
         dtype=cfg.get("dtype", "bfloat16"), eps=cfg["rms_norm_eps"])

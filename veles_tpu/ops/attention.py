@@ -97,6 +97,21 @@ def rope_rotate_batched(x, positions, theta=10000.0):
         x, positions)
 
 
+def cfg_rotate(x, positions, cfg, batched=False):
+    """A layer's rotary positions by the record: ``rope_rotate`` (per-lane
+    positions: ``rope_rotate_batched``) over the whole head, or over its
+    first ``cfg.rotary_dims`` dimensions (half-split within those) with the
+    rest left as they are."""
+    rotate = rope_rotate_batched if batched else rope_rotate
+    dh = x.shape[-1]
+    rot = cfg.rotary_dims(dh)
+    if rot == dh:
+        return rotate(x, positions, cfg.rope_theta)
+    return jnp.concatenate(
+        [rotate(x[..., :rot], positions, cfg.rope_theta), x[..., rot:]],
+        axis=-1)
+
+
 def band_bias(q_pos, k_pos, causal, window, dtype, sinks=0):
     """Additive score bias for the global-position causal/sliding-window
     band — THE shared mask the dense, blockwise and ring decompositions
@@ -339,12 +354,15 @@ def _repeat_kv(k, n_heads):
     return k if reps == 1 else jnp.repeat(k, reps, axis=-3)
 
 
-def rms_norm(x, g, eps, dtype=None):
+def rms_norm(x, g, eps, dtype=None, centred=False):
     """``x / sqrt(mean(x^2) + eps) * g`` over the last axis, computed in
-    float32 whatever ``x`` is; returns ``dtype`` (default ``x``'s)."""
+    float32 whatever ``x`` is; returns ``dtype`` (default ``x``'s).
+    ``centred``: the gain is stored about zero and multiplies as ``1 + g``
+    (``ModelConfig.norm_centred``)."""
     xf = x.astype(jnp.float32)
     y = xf * jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + eps)
-    return (y * g.astype(jnp.float32)).astype(dtype or x.dtype)
+    gain = g.astype(jnp.float32)
+    return (y * (1.0 + gain if centred else gain)).astype(dtype or x.dtype)
 
 
 def cfg_matmul(cfg, a, b):
@@ -376,6 +394,15 @@ def _heads(params, x, flat, cfg):
         return y.reshape(b, s, heads, dh).transpose(0, 2, 1, 3)
 
     q, k, v = flat
+    if cfg.block == "pre_rms":
+        # the output gate is the second half of each head's ``wq`` columns
+        q = q.reshape(b, s, cfg.n_heads, 2 * dh)
+        gate = q[..., dh:].reshape(b, s, cfg.n_heads * dh)
+        q = q[..., :dh].transpose(0, 2, 1, 3)
+        q = rms_norm(q, params["q_norm"], cfg.eps, centred=cfg.norm_centred)
+        k = rms_norm(split(k, kv), params["k_norm"], cfg.eps,
+                     centred=cfg.norm_centred)
+        return q, k, split(v, kv), gate
     q, k, v = split(q, cfg.n_heads), split(k, kv), split(v, kv)
     if cfg.block != "sandwich":
         return q, k, v, None
@@ -457,18 +484,23 @@ def mha_forward(params, x, n_heads, causal=True, block_size=None,
             raise ValueError("latent attention is causal and has no "
                              "contiguous cache")
         return latent_forward(params, x, cfg, positions)
+    if cfg.kind(layer) == model_config.LINEAR:
+        from veles_tpu.ops.linear_attn import linear_forward
+        if return_kv or not causal:
+            raise ValueError("a linear layer is causal and has no KV cache")
+        return linear_forward(params, x, cfg)
     rope, window = cfg.layer_rope(layer), cfg.layer_window(layer)
     n_heads, sinks = cfg.n_heads, cfg.sinks
     s = x.shape[1]
     q, k, v, gate = _qkv(params, x, cfg)
     if rope:
         pos = positions if positions is not None else jnp.arange(s)
-        q = rope_rotate(q, pos, cfg.rope_theta)
-        k = rope_rotate(k, pos, cfg.rope_theta)
+        q = cfg_rotate(q, pos, cfg)
+        k = cfg_rotate(k, pos, cfg)
     kr, vr = _repeat_kv(k, n_heads), _repeat_kv(v, n_heads)
-    if cfg.block == "sandwich":
+    if cfg.block != "pre_ln":
         if not causal:
-            raise ValueError("the sandwich block is causal")
+            raise ValueError("the %s block is causal" % cfg.block)
         with jax.named_scope("attn.window" if window else "attn.full"):
             o = _attend(q, kr, vr,
                         chunk_live_mask(0, s, s, window)[None, None])
@@ -495,8 +527,8 @@ def _decode_attend(params, x, k_cache, v_cache, write_idx, live,
     q, k_new, v_new, gate = _qkv_cached(params, x, cfg)     # (b, h, 1, dh)
     if rope_pos is not None:
         pos_arr = jnp.asarray(rope_pos)[None]
-        q = rope_rotate(q, pos_arr, cfg.rope_theta)
-        k_new = rope_rotate(k_new, pos_arr, cfg.rope_theta)
+        q = cfg_rotate(q, pos_arr, cfg)
+        k_new = cfg_rotate(k_new, pos_arr, cfg)
     k_cache = jax.lax.dynamic_update_slice(
         k_cache, k_new, (0, 0, write_idx, 0))
     v_cache = jax.lax.dynamic_update_slice(
@@ -547,8 +579,8 @@ def mha_chunk_step(params, x, k_cache, v_cache, pos, n_heads,
     q, k_new, v_new, gate = _qkv_cached(params, x, cfg)    # (b, h, c, dh)
     if rope:
         pos_arr = pos + jnp.arange(c)
-        q = rope_rotate(q, pos_arr, cfg.rope_theta)
-        k_new = rope_rotate(k_new, pos_arr, cfg.rope_theta)
+        q = cfg_rotate(q, pos_arr, cfg)
+        k_new = cfg_rotate(k_new, pos_arr, cfg)
     k_cache = jax.lax.dynamic_update_slice(
         k_cache, k_new, (0, 0, pos, 0))
     v_cache = jax.lax.dynamic_update_slice(
@@ -762,8 +794,8 @@ def mha_paged_chunk_step(params, x, k_pool, v_pool, ptab, pos, n_heads,
     q, k_new, v_new, gate = _qkv_cached(params, x, cfg)    # (b, h, c, dh)
     if rope:
         positions = jnp.asarray(pos)[:, None] + jnp.arange(c)   # (b, c)
-        q = rope_rotate_batched(q, positions, cfg.rope_theta)
-        k_new = rope_rotate_batched(k_new, positions, cfg.rope_theta)
+        q = cfg_rotate(q, positions, cfg, batched=True)
+        k_new = cfg_rotate(k_new, positions, cfg, batched=True)
     if base is not None:
         if sinks:
             raise ValueError("attention sinks need absolute positions: "

@@ -1,0 +1,297 @@
+"""Gated DeltaNet: the ``linear`` layers of the ``pre_rms`` block
+(``model_config.LinearConfig``; arXiv:2412.06464).
+
+Per token ``x``: ``[q | k | v] = x W_qkv`` (``k_heads`` heads of ``k_dim``
+twice, ``v_heads`` of ``v_dim``), ``z = x W_z`` (an output gate the values'
+width), ``[b | a] = x W_ba`` (one of each a value head).  A causal depthwise
+convolution of ``conv`` positions, without bias, runs over the channels of
+``[q | k | v]`` (each channel sees its own last ``conv`` positions), then
+SiLU.  ``beta = sigmoid(b)``; ``g = -exp(A_log) * softplus(a + dt_bias)``; q
+and k are L2-normalised over their head (``x * rsqrt(sum x^2 + 1e-6)``), q
+times ``k_dim^-0.5``, and each key head's q and k serve ``v_heads / k_heads``
+value heads.  Per value head, with the state ``S`` (k_dim, v_dim), zero where
+a sequence starts::
+
+    S <- exp(g_t) S;  d = beta_t (v_t - S^T k_t);  S <- S + k_t d^T;
+    o_t = S^T q_t
+
+and the layer's output is ``(rms(o_t) * w_n * silu(z_t)) W_o``.  WHAT A
+SEQUENCE KEEPS is ``S`` of every value head (float32) and the last ``conv -
+1`` rows of ``[q | k | v]`` BEFORE the convolution, whatever its length.
+
+Two orders of the same sums, by phase:
+
+- RECURRENT (a decode step: one row a lane): the lines above as they stand.
+  With the serving kernels it is ``pallas_kernels.gdn_decode``: one call
+  that reads and writes the states of the lanes that decode and of no
+  other.
+- CHUNKED (a prompt chunk, the whole-sequence forward): rows in inner
+  chunks of ``CHUNK``; with ``gam_i`` the sum of ``g`` up to row ``i`` of
+  its inner chunk, ``A_ij = beta_i (k_i . k_j) exp(gam_i - gam_j)`` below
+  the diagonal, ``W = (I + A)^-1 (beta exp(gam) K)``, ``U = (I + A)^-1
+  (beta V)``, all inner chunks at once as plain dots and a triangular
+  solve by blocks (``solve_unit_lower``); then ONE sequential pass over the inner chunks carries the state:
+  ``V' = U - W S``, ``O = (Q exp(gam)) S + tril((Q K^T) exp(gam_i -
+  gam_j)) V'``, ``S <- exp(gam_C) S + (K exp(gam_C - gam))^T V'``.  The
+  pass is a ``lax.scan``, or with the serving kernels
+  ``pallas_kernels.gdn_chunk`` (the state of a head in fast memory from
+  the first inner chunk to the last).
+
+A row behind a lane's ``rows`` (the padding of a prompt's last chunk; the
+one row of a lane that does not decode) has ``beta = 0`` and ``g = 0``: it
+moves no state, and the convolution tail is taken at the true length.
+
+``g``, ``beta``, ``gam``, ``S``, the L2 norms, the convolution's sums and
+every accumulator are float32 whatever the model's dtype; the dots of the
+rule itself run at ``HIGHEST`` (they are small: the state is the cost).
+Everything here runs under the scope ``attn.linear``.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from veles_tpu.ops.attention import cfg_matmul
+
+#: rows of one inner chunk of the chunked rule
+CHUNK = 64
+L2_EPS = 1e-6
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _f32(x):
+    return x.astype(jnp.float32)
+
+
+# ------------------------------------------------------------ projections
+def _inputs(p, x, cfg, cached):
+    """(qkv (b, c, conv_width) before the convolution, z (b, c, value
+    width), beta and g (b, c, v_heads) float32) of ``x`` (b, c, d)."""
+    hold = jax.lax.optimization_barrier if cached else (lambda y: y)
+    qkv = hold(cfg_matmul(cfg, x, p["w_qkv"]))
+    z = hold(cfg_matmul(cfg, x, p["w_z"]))
+    ba = jnp.matmul(x, p["w_ba"], preferred_element_type=jnp.float32,
+                    precision=_HI if x.dtype == jnp.float32 else None)
+    h = cfg.linear.v_heads
+    beta = jax.nn.sigmoid(ba[..., :h])
+    g = -jnp.exp(_f32(p["A_log"])) * jax.nn.softplus(
+        ba[..., h:] + _f32(p["dt_bias"]))
+    return qkv, z, beta, g
+
+
+def _convolve(tail, qkv, w, rows):
+    """The causal depthwise convolution and SiLU of ``qkv`` (b, c, ch)
+    behind the sequence's last rows ``tail`` (b, conv - 1, ch): (float32
+    (b, c, ch), the new tail: the ``conv - 1`` rows that end at row
+    ``rows`` of the chunk; ``rows = 0`` hands the old tail back)."""
+    k = w.shape[0]
+    c = qkv.shape[1]
+    seq = jnp.concatenate([tail, qkv], axis=1)           # (b, c + k - 1, ch)
+    acc = sum(_f32(seq[:, j:j + c]) * _f32(w[j]) for j in range(k))
+    new_tail = jax.vmap(lambda s, r: jax.lax.dynamic_slice_in_dim(
+        s, r, k - 1, axis=0))(seq, rows)
+    return jax.nn.silu(acc), new_tail
+
+
+def _heads(act, cfg):
+    """q, k (b, c, v_heads, k_dim) normalised (q scaled), each key head
+    repeated for its value heads, and v (b, c, v_heads, v_dim), float32,
+    from the convolved ``act`` (b, c, conv_width)."""
+    lin = cfg.linear
+    b, c, _ = act.shape
+    kw = lin.key_width
+
+    def unit(y):
+        y = y.reshape(b, c, lin.k_heads, lin.k_dim)
+        y = y * jax.lax.rsqrt((y * y).sum(-1, keepdims=True) + L2_EPS)
+        return jnp.repeat(y, lin.v_heads // lin.k_heads, axis=2)
+
+    q = unit(act[..., :kw]) * lin.k_dim ** -0.5
+    k = unit(act[..., kw:2 * kw])
+    v = act[..., 2 * kw:].reshape(b, c, lin.v_heads, lin.v_dim)
+    return q, k, v
+
+
+def _output(p, o, z, cfg):
+    """``(rms(o) * w_n * silu(z)) W_o``: o (b, c, v_heads, v_dim) float32,
+    z (b, c, value width)."""
+    lin = cfg.linear
+    b, c = o.shape[:2]
+    o = o * jax.lax.rsqrt((o * o).mean(-1, keepdims=True) + cfg.eps) \
+        * _f32(p["norm"])
+    o = o * jax.nn.silu(_f32(z).reshape(b, c, lin.v_heads, lin.v_dim))
+    return cfg_matmul(cfg, o.reshape(b, c, -1).astype(z.dtype), p["wo"])
+
+
+# -------------------------------------------------------------- two orders
+def recurrent_step(state, q, k, v, beta, g):
+    """One row a lane by the rule as written: state (b, h, dk, dv); q, k
+    (b, h, dk); v (b, h, dv); beta, g (b, h).  Returns (o (b, h, dv), the
+    new state)."""
+    state = state * jnp.exp(g)[..., None, None]
+    kv = jnp.einsum("bhkv,bhk->bhv", state, k, precision=_HI)
+    d = beta[..., None] * (v - kv)
+    state = state + k[..., :, None] * d[..., None, :]
+    return jnp.einsum("bhkv,bhk->bhv", state, q, precision=_HI), state
+
+
+def solve_unit_lower(a, rhs, block=16):
+    """``(I + a)^-1 rhs`` for ``a`` (..., C, C) strictly lower triangular,
+    by blocks of ``block``: a diagonal block's inverse is the finite series
+    ``sum (-N)^p = (I - N)(I + N^2)(I + N^4)(I + N^8)`` (``N^16 = 0``; with
+    entries of at most 1 its powers stay under C(15, p), where the same
+    series over all 64 rows would reach 1e18 and cancel to nothing), then
+    forward substitution over the blocks: 14 small batched dots in place of
+    the compiler's triangular solve, which took 1.37 ms a layer and chunk
+    on the chip against the sequential pass's 0.38 (PERF.md section 6, PR
+    36)."""
+    c = a.shape[-1]
+    nb = c // block
+    lead = a.shape[:-2]
+    ab = a.reshape(lead + (nb, block, nb, block))
+    eye = jnp.eye(block, dtype=a.dtype)
+    n = jnp.stack([ab[..., i, :, i, :] for i in range(nb)], axis=-3)
+    inv, power = eye - n, n
+    for _ in range(block.bit_length() - 2):
+        power = jnp.matmul(power, power, precision=_HI)
+        inv = jnp.matmul(inv, eye + power, precision=_HI)
+    rb = rhs.reshape(lead + (nb, block, rhs.shape[-1]))
+    out = []
+    for i in range(nb):
+        r = rb[..., i, :, :]
+        for j in range(i):
+            r = r - jnp.matmul(ab[..., i, :, j, :], out[j], precision=_HI)
+        out.append(jnp.matmul(inv[..., i, :, :], r, precision=_HI))
+    return jnp.concatenate(out, axis=-2)
+
+
+def chunk_terms(q, k, v, beta, g):
+    """What the sequential pass of the chunked rule reads, for all inner
+    chunks at once: q, k (b, L, h, dk), v (b, L, h, dv), beta, g (b, L, h),
+    L a multiple of ``CHUNK``.  Returns float32 ``(W, U, Qg (b, h, n, C,
+    .), Att (b, h, n, C, C), KdT (b, h, n, dk, C), decay (b, h, n))``:
+    ``V' = U - W S``; ``O = Qg S + Att V'``; ``S <- decay S + KdT V'``."""
+    b, length, h, _ = q.shape
+    n = length // CHUNK
+
+    def split(y):                       # (b, L, h, ...) -> (b, h, n, C, ...)
+        y = y.reshape((b, n, CHUNK, h) + y.shape[3:])
+        return jnp.moveaxis(y, 3, 1)
+
+    q, k, v, beta, g = (split(y) for y in (q, k, v, beta, g))
+    gam = jnp.cumsum(g, axis=-1)                            # (b, h, n, C)
+    diff = gam[..., :, None] - gam[..., None, :]            # gam_i - gam_j
+    low = jnp.tril(jnp.ones((CHUNK, CHUNK), bool))
+    decay = jnp.exp(jnp.where(low, diff, -jnp.inf))         # 0 above diag
+    kk = jnp.einsum("bhnik,bhnjk->bhnij", k, k, precision=_HI)
+    a = beta[..., None] * kk * jnp.where(jnp.eye(CHUNK, dtype=bool), 0.0,
+                                         decay)
+    rhs = jnp.concatenate([(beta * jnp.exp(gam))[..., None] * k,
+                           beta[..., None] * v], axis=-1)
+    solved = solve_unit_lower(a, rhs)
+    dk = k.shape[-1]
+    w, u = solved[..., :dk], solved[..., dk:]
+    qg = q * jnp.exp(gam)[..., None]
+    att = jnp.einsum("bhnik,bhnjk->bhnij", q, k, precision=_HI) * decay
+    last = gam[..., -1:]
+    kdt = jnp.swapaxes(k * jnp.exp(last - gam)[..., None], -1, -2)
+    return w, u, qg, att, kdt, jnp.exp(last[..., 0])
+
+
+def chunk_pass(state, terms):
+    """The sequential pass over the inner chunks as a ``lax.scan``: state
+    (b, h, dk, dv) -> (O (b, h, n, C, dv), the state after the last)."""
+    def body(s, t):
+        w, u, qg, att, kdt, decay = t
+        vp = u - jnp.einsum("bhck,bhkv->bhcv", w, s, precision=_HI)
+        o = jnp.einsum("bhck,bhkv->bhcv", qg, s, precision=_HI) \
+            + jnp.einsum("bhij,bhjv->bhiv", att, vp, precision=_HI)
+        s = decay[..., None, None] * s \
+            + jnp.einsum("bhkc,bhcv->bhkv", kdt, vp, precision=_HI)
+        return s, o
+
+    state, o = jax.lax.scan(
+        body, state, tuple(jnp.moveaxis(t, 2, 0) for t in terms))
+    return jnp.moveaxis(o, 0, 2), state
+
+
+# ------------------------------------------------------------ entry points
+def linear_paged_chunk_step(p, x, state, tail, cfg, rows, slots=None,
+                            fresh=None, attn_kernel=None):
+    """``c`` positions per lane through one linear layer against the lanes'
+    state: ``attention.mha_paged_chunk_step`` for the kind that holds no
+    pages.
+
+    x: (b, c, d); state: (slots, v_heads, k_dim, v_dim) float32 and tail:
+    (slots, conv - 1, conv_width), one slot a lane of the engine; ``rows``
+    (b,) int32: how many of a lane's ``c`` rows are real (the others move
+    nothing; 0: the lane's slot comes back bit for bit); ``slots`` (b,)
+    int32: each lane's slot, or None where lane ``i`` IS slot ``i``.  One
+    row a lane runs the recurrent rule, more the chunked one.  ``fresh``
+    (b,) bool: the lane's sequence starts with this chunk, so whatever its
+    slot held is read as zeros (how the engine resets a lane at admission
+    without a dispatch of its own; the chunked order only).
+    ``attn_kernel`` ('decode' | 'prefill' | None) takes the Pallas kernels.
+    Returns (out (b, c, d), state, tail)."""
+    lin = cfg.linear
+    b, c, _ = x.shape
+    rows = jnp.asarray(rows, jnp.int32)
+    with jax.named_scope("attn.linear"):
+        qkv, z, beta, g = _inputs(p, x, cfg, cached=True)
+        real = jnp.arange(c)[None, :] < rows[:, None]          # (b, c)
+        beta = jnp.where(real[..., None], beta, 0.0)
+        g = jnp.where(real[..., None], g, 0.0)
+        mine = tail if slots is None else tail[slots]
+        if fresh is not None:
+            mine = jnp.where(fresh[:, None, None], 0, mine).astype(tail.dtype)
+        act, new_tail = _convolve(mine, qkv, p["conv"], rows)
+        tail = new_tail if slots is None else tail.at[slots].set(new_tail)
+        q, k, v = _heads(act, cfg)
+        if c == 1:
+            if attn_kernel:
+                from veles_tpu.ops import pallas_kernels as PK
+                if slots is not None:
+                    raise ValueError("the decode kernel steps every slot")
+                o, state = PK.gdn_decode(state, q[:, 0], k[:, 0], v[:, 0],
+                                         beta[:, 0], g[:, 0], rows > 0)
+            else:
+                s0 = state if slots is None else state[slots]
+                o, s1 = recurrent_step(s0, q[:, 0], k[:, 0], v[:, 0],
+                                       beta[:, 0], g[:, 0])
+                s1 = jnp.where((rows > 0)[:, None, None, None], s1, s0)
+                state = s1 if slots is None else state.at[slots].set(s1)
+            o = o[:, None]
+        else:
+            pad = -c % CHUNK
+            if pad:
+                q, k, v, beta, g = (jnp.pad(
+                    y, [(0, 0), (0, pad)] + [(0, 0)] * (y.ndim - 2))
+                    for y in (q, k, v, beta, g))
+            terms = chunk_terms(q, k, v, beta, g)
+            ids = jnp.arange(b) if slots is None else slots
+            if fresh is None:
+                fresh = jnp.zeros((b,), bool)
+            if attn_kernel:
+                from veles_tpu.ops import pallas_kernels as PK
+                o, state = PK.gdn_chunk(state, ids, fresh, *terms)
+            else:
+                s0 = jnp.where(fresh[:, None, None, None], 0.0, state[ids])
+                o, s1 = chunk_pass(s0, terms)
+                state = state.at[ids].set(s1)
+            # (b, h, n, C, dv) -> (b, c, h, dv)
+            o = jnp.moveaxis(o, 1, 3).reshape(
+                b, c + pad, lin.v_heads, lin.v_dim)[:, :c]
+        return _output(p, o, z, cfg), state, tail
+
+
+def linear_forward(p, x, cfg):
+    """One linear layer over whole sequences ``x`` (b, s, d) from empty
+    states, in the chunked order."""
+    b, s, _ = x.shape
+    state_shape, tail_shape = cfg.linear.state_shapes(b)
+    out, _, _ = linear_paged_chunk_step(
+        p, x, jnp.zeros(state_shape, jnp.float32),
+        jnp.zeros(tail_shape, x.dtype), cfg,
+        jnp.full((b,), s, jnp.int32))
+    return out

@@ -244,7 +244,17 @@ def routed_ffn(params, x, moe, matmul=None, router_in=None):
                            _n_held(params))
     if moe.shared:
         with jax.named_scope("moe.shared"):
-            out = out + gated_ffn(params["shared"], flat, matmul)
+            shared = gated_ffn(params["shared"], flat, matmul)
+            if moe.shared_gate:
+                # one sigmoid a token, in float32 (``shared_gate``: (d, 1))
+                gate = jax.nn.sigmoid(jnp.matmul(
+                    flat, params["shared_gate"],
+                    preferred_element_type=jnp.float32,
+                    precision=F._PRECISION
+                    if flat.dtype == jnp.float32 else None))
+                shared = (shared.astype(jnp.float32) * gate) \
+                    .astype(shared.dtype)
+            out = out + shared
     return out.reshape(shape), stats
 
 

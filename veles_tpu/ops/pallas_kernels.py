@@ -591,6 +591,26 @@ def paged_flash_decode(q, k_pool, v_pool, ptab, pos, window=None,
 _SCORES_BYTES = 2 << 20
 
 
+#: float32 scores (query rows x page) of one grid step where ONE kv head's
+#: query rows are cut into blocks (``_query_rows``), and the memory such a
+#: kernel is given
+_Q_BLOCK_BYTES = 4 << 20
+_Q_BLOCK_VMEM = 64 << 20
+
+
+def _query_rows(rows, page):
+    """The query rows one grid step takes of a kv head that alone overruns
+    ``_SCORES_BYTES``: the largest divisor of ``rows`` in whole sublane
+    tiles whose scores stay under ``_Q_BLOCK_BYTES`` (16 query heads of 256
+    on 2 kv heads over a 1024-token chunk are 8192 query rows a kv head, 32
+    MiB of scores a page: taken 1024 rows, one query head, at a time)."""
+    for qr in range(rows, 7, -1):
+        if rows % qr == 0 and qr % 8 == 0 \
+                and qr * page * 4 <= _Q_BLOCK_BYTES:
+            return qr
+    return rows
+
+
 def _heads_per_step(kvp, rows, page):
     """The (packed) kv heads one grid step of the prefill kernel takes:
     all of them while their scores stay under ``_SCORES_BYTES``, else the
@@ -619,7 +639,10 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
     fit the kernel's memory (``_heads_per_step``), else a block of them:
     the grid then has a head-block axis between the lane's and the
     page's (6 query heads of 128 over a 256-token chunk are 1536 query
-    rows per kv head, 1.5 MB of float32 scores a head and page).
+    rows per kv head, 1.5 MB of float32 scores a head and page).  Where one
+    kv head's query rows alone are too many, a query-block axis stands
+    before the page's (``_query_rows``): each block streams the history for
+    itself, and all of them install the same chunk rows.
 
     Of the table's pages only the live history (``live_pages`` with no
     query row: below the frontier, inside the window) is fetched and
@@ -647,6 +670,12 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
     qp = _pack_queries(q.reshape(b, kvp * r, g * c, dh), r)
     hb = _heads_per_step(kvp, rows, page)
     grid = (b, m_pages) if hb == kvp else (b, kvp // hb, m_pages)
+    # one kv head's rows may still be too many: a query-block axis then
+    # stands before the page's, and a block's history is streamed anew
+    qr = (_query_rows(rows, page)
+          if hb == 1 and rows * page * 4 > _SCORES_BYTES else rows)
+    if qr != rows:
+        grid = grid[:-1] + (rows // qr, m_pages)
 
     first, last, sink = live_pages(jnp.asarray(pos, jnp.int32), 0, page,
                                    m_pages, window, sinks)
@@ -667,12 +696,18 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
         # history page j: live strictly below the chunk frontier (the
         # chunk's own page sits in the pool UNWRITTEN — its rows come
         # from the VMEM operands in the epilogue)
+        first_row = pl.program_id(len(grid) - 2) * qr if qr != rows else 0
+
+        def chunk_rows(width):
+            # the chunk offset each query row of this step serves
+            at = jax.lax.broadcasted_iota(jnp.int32, (qr, width), 0)
+            return (at + first_row if qr != rows else at) % c
+
         @pl.when(_is_live(j, first_ref[i], last_ref[i], sink))
         def _():
-            q_rows = jax.lax.broadcasted_iota(
-                jnp.int32, (rows, page), 0) % c
+            q_rows = chunk_rows(page)
             k_pos = j * page + jax.lax.broadcasted_iota(
-                jnp.int32, (rows, page), 1)
+                jnp.int32, (qr, page), 1)
             live = _band(k_pos, pos + q_rows, window, sinks, k_pos < pos)
             _flash_step(q_ref[0], k_ref[0], v_ref[0], live, dh,
                         acc_ref, l_ref, m_ref)
@@ -681,9 +716,8 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
         def _():
             # the chunk block: intra-chunk causal over the VMEM K/V
             k_pos_new = pos + jax.lax.broadcasted_iota(
-                jnp.int32, (rows, c), 1)
-            q_pos = pos + jax.lax.broadcasted_iota(
-                jnp.int32, (rows, c), 0) % c
+                jnp.int32, (qr, c), 1)
+            q_pos = pos + chunk_rows(c)
             live_new = _band(k_pos_new, q_pos, window, sinks,
                              k_pos_new <= q_pos)
             _flash_step(q_ref[0], kn_ref[0], vn_ref[0], live_new, dh,
@@ -697,10 +731,14 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
     # index maps over (lane, [head block,] page, page table, positions,
     # first and last live page)
     def head_block(idx):
-        return idx[1] if len(grid) == 3 else 0
+        return idx[1] if hb != kvp else 0
 
     def lane(*idx):
         return (idx[0], head_block(idx), 0, 0)
+
+    def queries(*idx):
+        return (idx[0], head_block(idx),
+                idx[len(grid) - 2] if qr != rows else 0, 0)
 
     def history(*idx):
         (i, j), (pt, _, fs, ls) = (idx[0], idx[-5]), idx[-4:]
@@ -715,23 +753,25 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
         num_scalar_prefetch=4,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, hb, rows, lanes), lane),
+            pl.BlockSpec((1, hb, qr, lanes), queries),
             pl.BlockSpec((1, hb, c, lanes), lane),
             pl.BlockSpec((1, hb, c, lanes), lane),
             pl.BlockSpec((1, hb, page, lanes), history),
             pl.BlockSpec((1, hb, page, lanes), history),
         ],
         out_specs=(
-            pl.BlockSpec((1, hb, rows, lanes), lane),
+            pl.BlockSpec((1, hb, qr, lanes), queries),
             pl.BlockSpec((1, hb, page, lanes), tgt),
             pl.BlockSpec((1, hb, page, lanes), tgt),
         ),
-        scratch_shapes=[pltpu.VMEM((hb, rows, lanes), jnp.float32),
-                        pltpu.VMEM((hb, rows), jnp.float32),
-                        pltpu.VMEM((hb, rows), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hb, qr, lanes), jnp.float32),
+                        pltpu.VMEM((hb, qr), jnp.float32),
+                        pltpu.VMEM((hb, qr), jnp.float32)],
     )
+    more = ({} if qr == rows else {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=_Q_BLOCK_VMEM)})
     o, k_out, v_out = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
+        kernel, grid_spec=grid_spec, **more,
         out_shape=(jax.ShapeDtypeStruct((b, kvp, rows, lanes), q.dtype),
                    jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
                    jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)),
@@ -1191,3 +1231,164 @@ def grouped_matmul(xs, w, sizes, tm=_GMM_ROWS, interpret=None):
             vmem_limit_bytes=_GMM_VMEM),
         interpret=_interpret(interpret),
     )(group, tile, offsets, total, xs, w)
+
+
+# ------------------------------------------------------ the gated delta rule
+# ``ops/linear_attn.py``'s two orders on the lanes' recurrent state
+# ``(slots, heads, k_dim, v_dim)`` float32, taken aliased in and out: a call
+# touches the slots it is told to and no other, and what it does not touch
+# keeps its bytes.
+
+_GDN_VMEM = 48 << 20
+
+
+def gdn_decode(state, q, k, v, beta, g, active, interpret=None):
+    """The recurrent rule for ONE row a lane, on the lanes that decode:
+    state (b, h, dk, dv); q, k (b, h, dk); v (b, h, dv); beta, g (b, h),
+    all float32; ``active`` (b,) bool.  Per lane and head ``S <- exp(g) S;
+    d = beta (v - S^T k); S <- S + k d^T; o = S^T q``
+    (``linear_attn.recurrent_step``, sum for sum).  Returns (o (b, h, dv),
+    zeros for a lane that is not active; the state).
+
+    The grid walks the ACTIVE lanes first (their indices prefetched, in
+    order) and a step takes one lane's whole state, 2 MiB at 32 heads of
+    128 x 128, through fast memory once; the steps behind the last active
+    lane name its blocks again and compute nothing, so a lane that is
+    prefilling or empty costs neither a copy nor a write, and its state
+    keeps its bits.  With no lane active the first step hands lane 0's
+    state back as it came."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, dk, dv = state.shape
+    active = jnp.asarray(active, bool)
+    n = active.sum().astype(jnp.int32)
+    # active lanes first, in order; behind them the last active lane again
+    order = jnp.argsort(~active, stable=True).astype(jnp.int32)
+    ids = order[jnp.minimum(jnp.arange(b, dtype=jnp.int32),
+                            jnp.maximum(n - 1, 0))]
+    # k and q as columns (k_dim on sublanes, a head a lane), v and the two
+    # scalars of a head as rows
+    cols = jnp.stack([jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2)], axis=1)
+    rows = jnp.stack([v, jnp.broadcast_to(jnp.exp(g)[..., None], v.shape),
+                      jnp.broadcast_to(beta[..., None], v.shape)], axis=1)
+
+    def kernel(ids_ref, n_ref, s_ref, c_ref, r_ref, o_ref, so_ref):
+        i = pl.program_id(0)
+
+        @pl.when(i < n_ref[0])
+        def _():
+            for e in range(h):
+                qc = c_ref[0, 0, :, e:e + 1]                  # (dk, 1)
+                kc = c_ref[0, 1, :, e:e + 1]
+                ve = r_ref[0, 0, e:e + 1, :]                  # (1, dv)
+                s = s_ref[0, e] * r_ref[0, 1, e:e + 1, :]
+                d = r_ref[0, 2, e:e + 1, :] * (
+                    ve - (s * kc).sum(axis=0, keepdims=True))
+                s = s + kc * d
+                so_ref[0, e] = s
+                o_ref[0, e:e + 1, :] = (s * qc).sum(axis=0, keepdims=True)
+
+        @pl.when((n_ref[0] == 0) & (i == 0))
+        def _():
+            so_ref[...] = s_ref[...]
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+    def lane4(i, ids, n):
+        return (ids[i], 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, h, dk, dv), lane4),
+                  pl.BlockSpec((1, 2, dk, h), lane4),
+                  pl.BlockSpec((1, 3, h, dv), lane4)],
+        out_specs=(pl.BlockSpec((1, h, dv), lambda i, ids, n: (ids[i], 0, 0)),
+                   pl.BlockSpec((1, h, dk, dv), lane4)),
+    )
+    o, state = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=(jax.ShapeDtypeStruct((b, h, dv), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)),
+        # operand indices include the two scalar-prefetch arguments
+        input_output_aliases={2: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_GDN_VMEM),
+        interpret=_interpret(interpret),
+    )(ids, n.reshape(1), state, cols, rows)
+    return jnp.where(active[:, None, None], o, 0.0), state
+
+
+def gdn_chunk(state, slots, fresh, w, u, qg, att, kdt, decay,
+              interpret=None):
+    """The sequential pass of the chunked rule over the inner chunks
+    (``linear_attn.chunk_pass``): per lane and head, from the lane's state
+    ``S`` (zeros where ``fresh``), for each inner chunk in order ``V' = U -
+    W S; O = Qg S + Att V'; S <- decay S + KdT V'``, the state (k_dim x
+    v_dim, float32) in fast memory from the first inner chunk to the last.
+
+    state (slots, h, dk, dv); ``slots`` (b,) int32 the lanes' slots;
+    ``fresh`` (b,) bool; w, qg (b, h, n, C, dk); u (b, h, n, C, dv); att
+    (b, h, n, C, C); kdt (b, h, n, dk, C); decay (b, h, n): what
+    ``linear_attn.chunk_terms`` returns.  Returns (O (b, h, n, C, dv), the
+    state with the lanes' slots rewritten, in place)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, n, c, dk = w.shape
+    dv = u.shape[-1]
+    decay = jnp.broadcast_to(decay[..., None, None], (b, h, n, 1, dv))
+
+    def dot(x, y):
+        return jnp.dot(x, y, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+
+    def kernel(slot_ref, fresh_ref, s_ref, w_ref, u_ref, q_ref, a_ref,
+               k_ref, d_ref, o_ref, so_ref, acc_ref):
+        t = pl.program_id(2)
+        old = fresh_ref[pl.program_id(0)] == 0
+
+        @pl.when(t == 0)
+        def _():
+            acc_ref[...] = jnp.where(old, s_ref[0, 0], 0.0)
+
+        s = acc_ref[...]
+        vp = u_ref[0, 0, 0] - dot(w_ref[0, 0, 0], s)
+        o_ref[0, 0, 0] = dot(q_ref[0, 0, 0], s) + dot(a_ref[0, 0, 0], vp)
+        s = d_ref[0, 0, 0] * s + dot(k_ref[0, 0, 0], vp)
+        acc_ref[...] = s
+
+        @pl.when(t == n - 1)
+        def _():
+            so_ref[0, 0] = s
+
+    def slot(i, j, t, sl, fr):
+        return (sl[i], j, 0, 0)
+
+    def inner(i, j, t, *_):
+        return (i, j, t, 0, 0)
+
+    def block(*shape):
+        return pl.BlockSpec((1, 1, 1) + shape, inner)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, h, n),
+        in_specs=[pl.BlockSpec((1, 1, dk, dv), slot),
+                  block(c, dk), block(c, dv), block(c, dk), block(c, c),
+                  block(dk, c), block(1, dv)],
+        out_specs=(block(c, dv), pl.BlockSpec((1, 1, dk, dv), slot)),
+        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+    )
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=(jax.ShapeDtypeStruct((b, h, n, c, dv), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)),
+        input_output_aliases={2: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_GDN_VMEM),
+        interpret=_interpret(interpret),
+    )(jnp.asarray(slots, jnp.int32), jnp.asarray(fresh, jnp.int32),
+      state, w, u, qg, att, kdt, decay)

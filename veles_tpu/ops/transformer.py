@@ -99,7 +99,8 @@ def _wire(blk, h, cfg, layer, attend, with_aux=False, token_mask=None):
     before AND after each half, the second norm inside the residual.
     Returns (h, state, extra) with ``extra`` the expert layer's load-
     balancing loss (``with_aux``, pre_ln) or its counts (sandwich; None
-    for a dense layer).  ``pre_rms``: RMSNorm, latent attention, residual;
+    for a dense layer).  ``pre_rms``: RMSNorm, the layer's mixer (latent
+    attention; or by kind a linear layer or gated attention), residual;
     RMSNorm, gated feed forward, residual: over one plain stream, or with
     each half inside the n-stream residual's mix (``ops/hyper.py``)."""
     if cfg.block == "pre_rms":
@@ -107,10 +108,11 @@ def _wire(blk, h, cfg, layer, attend, with_aux=False, token_mask=None):
 
         def attn_half(u):
             return attend(blk["attn"], rms_norm(u, blk["ln_attn"], cfg.eps,
-                                                cfg.dtype))
+                                                cfg.dtype, cfg.norm_centred))
 
         def ffn_half(u):
-            normed = rms_norm(u, blk["ln_mlp"], cfg.eps)
+            normed = rms_norm(u, blk["ln_mlp"], cfg.eps,
+                              centred=cfg.norm_centred)
             return _block_ffn(blk, normed.astype(cfg.dtype), cfg, layer,
                               normed)
 
@@ -240,7 +242,8 @@ def head_logits(params, h, cfg=None):
         from veles_tpu.ops import hyper
         h = hyper.gather(h)           # the streams' sum
     if cfg is not None and cfg.wide:
-        h = rms_norm(h, params["ln_f"], cfg.eps, cfg.dtype)
+        h = rms_norm(h, params["ln_f"], cfg.eps, cfg.dtype,
+                     cfg.norm_centred)
         return jnp.matmul(h, params["head"],
                           preferred_element_type=jnp.float32)
     h = _layernorm(h, params["ln_f"]["g"], params["ln_f"]["b"])
@@ -332,6 +335,11 @@ def prefill(params, tokens, n_heads, max_len, rope=False, window=None,
         raise ValueError("latent attention has no contiguous cache: its "
                          "cached path is the paged latent pool "
                          "(paged_chunk_apply, LMEngine(paged_kv=...))")
+    if cfg.linear is not None:
+        raise ValueError("a stack with linear layers has no contiguous "
+                         "cache: its cached path keeps a state slot a lane "
+                         "beside the paged pool (paged_chunk_apply, "
+                         "LMEngine(paged_kv=...))")
     h = embed_tokens(params, tokens, cfg)
     s = h.shape[1]
     pad = [(0, 0), (0, 0), (0, max_len - s), (0, 0)]
@@ -453,6 +461,24 @@ def block_latent_chunk_step(blk, h, pool, ptab, pos, cfg,
     return _wire(blk, h, cfg, layer, attend)
 
 
+def block_linear_chunk_step(blk, h, state, tail, cfg, rows, slots=None,
+                            fresh=None, attn_kernel=None, layer=0):
+    """:func:`block_paged_chunk_step` for a linear layer: no pages, the
+    lanes' recurrent state and convolution tail in their place
+    (``ops/linear_attn.py::linear_paged_chunk_step``).  Returns (h, state,
+    tail, the expert layer's counts or None)."""
+    from veles_tpu.ops.linear_attn import linear_paged_chunk_step
+
+    def attend(p, hn):
+        out, s, t = linear_paged_chunk_step(
+            p, hn, state, tail, cfg, rows, slots=slots, fresh=fresh,
+            attn_kernel=attn_kernel)
+        return out, (s, t)
+
+    h, (state, tail), stats = _wire(blk, h, cfg, layer, attend)
+    return h, state, tail, stats
+
+
 def paged_chunk_embed(params, tokens, pos, cfg=None):
     """Token (+ positional, absent under RoPE) embedding for ``c``
     positions per lane starting at PER-LANE traced ``pos`` (b,) —
@@ -472,7 +498,7 @@ def paged_chunk_embed(params, tokens, pos, cfg=None):
 def paged_chunk_apply(params, tokens, pools, ptab, pos, n_heads,
                       rope=False, window=None, sinks=0,
                       attn_kernel=None, write_mask=None, base=None,
-                      with_stats=False):
+                      with_stats=False, rows=None, slots=None):
     """Run ``c`` consecutive tokens PER LANE through the whole stack
     against the paged KV pools in one pass — :func:`chunk_apply` with
     (pools, page table) in place of per-lane contiguous caches.
@@ -497,7 +523,16 @@ def paged_chunk_apply(params, tokens, pools, ptab, pos, n_heads,
     lane's first live page (``base``, see ``mha_paged_chunk_step``).
     ``with_stats`` also returns the expert layers' counts, int32 ``[held,
     elsewhere, experts hit, largest load]`` (``ops/moe.py::held_part``),
-    summed over the layers (the last: their maximum)."""
+    summed over the layers (the last: their maximum).
+
+    A stack with ``linear`` layers holds for each of them, in ``pools``'
+    place of a (k, v) pair, the pair (recurrent state, convolution tail) of
+    every lane's slot, and needs ``rows`` (b,) int32: how many of each
+    lane's ``c`` rows are real (0: a lane that rides the step without
+    decoding; its state and tail come back bit for bit).  ``slots`` (b,)
+    names the lanes' slots where lane ``i`` is not slot ``i`` (the one-lane
+    chunk program); a lane at ``pos`` 0 with several rows starts its
+    sequence, and what its slot held is read as zeros."""
     import jax.numpy as jnp
     cfg = model_config.of(n_heads, rope, window, sinks)
     h = paged_chunk_embed(params, tokens, pos, cfg)
@@ -506,7 +541,14 @@ def paged_chunk_apply(params, tokens, pools, ptab, pos, n_heads,
     counts = []
     for i, (blk, pool) in enumerate(zip(params["blocks"], pools)):
         kind = cfg.kind(i)
-        if cfg.latent is not None:
+        if kind == model_config.LINEAR:
+            # lint: allow(recompile-hazard): rows a lane are fixed per program family (one: the step; a chunk: the prefill)
+            fresh = jnp.asarray(pos) == 0 if tokens.shape[1] > 1 else None
+            h, state, tail, stats = block_linear_chunk_step(
+                blk, h, *pool, cfg, rows, slots=slots, fresh=fresh,
+                attn_kernel=attn_kernel, layer=i)
+            new_pools.append((state, tail))
+        elif cfg.latent is not None:
             # one pool a layer, its rows the latents
             h, pool, stats = block_latent_chunk_step(
                 blk, h, pool[0], ptab, pos, cfg, attn_kernel=attn_kernel,

@@ -710,6 +710,11 @@ class LMEngine(Logger):
                 "LMEngine: latent attention needs paged_kv — its cache is "
                 "the paged pool of latent rows, and no contiguous layout "
                 "holds them")
+        if not self._paged and self.cfg.linear is not None:
+            raise ValueError(
+                "LMEngine: linear layers need paged_kv — their cached path "
+                "keeps a state slot a lane beside the full layers' paged "
+                "pool, and the contiguous layout has no place for it")
         if not self._paged and any(
                 self.cfg.ffn_kind(i, blk) == model_config.MOE
                 for i, blk in enumerate(params["blocks"])):
@@ -815,6 +820,11 @@ class LMEngine(Logger):
         #: None for a stack of one kind)
         self._wt = None
         self._window_shape = None
+        #: shapes of a linear layer's (recurrent state, convolution tail),
+        #: one slot a lane (None: the stack has no such layer), and which
+        #: lanes decode in the step being dispatched
+        self._state_shapes = None
+        self._decoding = numpy.zeros(self.slots, bool)
         if self._paged:
             self._max_pages = self.max_len // self.prefill_chunk
             # decode/verify table-width ladder (ISSUE 7 satellite): a
@@ -857,6 +867,26 @@ class LMEngine(Logger):
                     "kv_bytes_per_token",
                     head_dim * embed.dtype.itemsize
                     * len(params["blocks"]))
+            if self.cfg.linear is not None:
+                # two kinds of cache in one manager (ISSUE 36): a slot of
+                # recurrent state and convolution tail a lane for every
+                # linear layer, of a fixed size whatever the lane holds,
+                # beside ONE page table for the full layers.  A lane's
+                # slot IS its lane: taken at admission, reset by the
+                # chunk that starts at 0, freed with the lane's pages
+                self._state_shapes = self.cfg.linear.state_shapes(
+                    self.slots)
+                n_state = len(self.cfg.state_layers)
+                n_full = len(params["blocks"]) - n_state
+                state, tail = self._state_shapes
+                self.metrics.set_gauge("state_slots_total", self.slots)
+                self.metrics.set_gauge(
+                    "state_bytes_per_lane", n_state * (
+                        4 * int(numpy.prod(state[1:]))
+                        + embed.dtype.itemsize * int(numpy.prod(tail[1:]))))
+                self.metrics.set_gauge(
+                    "kv_bytes_per_token",
+                    2 * kv_heads * head_dim * embed.dtype.itemsize * n_full)
             if model_config.SLIDING in self.cfg.kinds:
                 # two kinds of cache (ISSUE 28): a page table, an
                 # allocator and pools of their own for the sliding
@@ -878,7 +908,7 @@ class LMEngine(Logger):
         #: kernels' page steps (:meth:`_note_attn_dispatch`)
         self._layers_of_kind = sorted(collections.Counter(
             self.cfg.kind(i) for i in range(len(self.params["blocks"]))
-        ).items())
+            if self.cfg.kind(i) != model_config.LINEAR).items())
         self._trie = (RadixPrefixCache(
             prefix_cache, self.prefill_chunk,
             on_evict=self._pool.release if self._paged else None)
@@ -986,10 +1016,19 @@ class LMEngine(Logger):
         where = (self._kv_shard if self._mesh is not None
                  else self._device)
 
-        def zeros(shape):
-            arr = jnp.zeros(shape, self._storage_dtype)
+        def zeros(shape, dtype=None):
+            arr = jnp.zeros(shape, dtype or self._storage_dtype)
             return arr if where is None else jax.device_put(arr, where)
 
+        if self._state_shapes is not None:
+            # a linear layer's pair is (state, float32; tail), a full
+            # layer's (k pool, v pool)
+            state, tail = self._state_shapes
+            return [(zeros(state, jnp.float32), zeros(tail))
+                    if self.cfg.kind(i) == model_config.LINEAR
+                    else (zeros(self._storage_shape),
+                          zeros(self._storage_shape))
+                    for i in range(len(self.params["blocks"]))]
         shapes = [self._window_shape if self._wt is not None
                   and self.cfg.kind(i) == model_config.SLIDING
                   else self._storage_shape
@@ -1222,23 +1261,33 @@ class LMEngine(Logger):
         stats = cfg.moe is not None
 
         def tables_of(ptab):
-            # (tables, where they begin) as ``paged_chunk_apply`` takes
-            # them: the plain table of a stack of one kind, or
-            # ``_table_args``' pair for two kinds of cache (a table per
-            # kind, the sliding kind's beginning in tokens per lane)
+            # (tables, where they begin, the lanes' state) as
+            # ``paged_chunk_apply`` takes them: the plain table of a stack
+            # of one kind, or ``_table_args``' pair: for two kinds of
+            # paged cache (a table per kind, the sliding kind's beginning
+            # in tokens per lane), for linear layers beside ONE table
+            # (the table, and the lane's slot or the lanes that decode)
             if not isinstance(ptab, tuple):
-                return ptab, None
+                return ptab, None, None
+            if cfg.linear is not None:
+                return ptab[0], None, ptab[1]
             tabs, wbase = ptab
-            return tabs, {full: None, sliding: wbase}
+            return tabs, {full: None, sliding: wbase}, None
 
         def chunk_slot(params, pools, ptab, tokens, start, last_idx):
             # one lane's prompt chunk through its page table; returns
-            # the argmax after ``last_idx`` (read on the tail chunk)
+            # the argmax after ``last_idx`` (read on the tail chunk; the
+            # chunk's last real row: behind it lies padding, which a
+            # linear layer must be told of)
             tokens = tokens[None]
-            tabs, base = tables_of(jax.tree.map(lambda t: t[None], ptab))
+            tabs, base, slot = tables_of(
+                jax.tree.map(lambda t: t[None], ptab))
+            state = ({} if slot is None else
+                     {"slots": slot, "rows": (last_idx + 1)[None]})
             h, pools = paged_chunk_apply(
                 params, tokens, pools, tabs, start[None],
-                cfg, attn_kernel="prefill" if kern else None, base=base)
+                cfg, attn_kernel="prefill" if kern else None, base=base,
+                **state)
             logits = head_logits(params, jax.lax.dynamic_slice_in_dim(
                 h, last_idx, 1, axis=1), cfg)[:, 0, :]
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[0]
@@ -1247,11 +1296,13 @@ class LMEngine(Logger):
         def step_all(params, pools, ptabs, toks, pos):
             # ONE dispatch advances every lane by one token at its own
             # position through its own page table
-            tabs, base = tables_of(ptabs)
+            tabs, base, decoding = tables_of(ptabs)
+            state = ({} if decoding is None else
+                     {"rows": decoding.astype(jnp.int32)})
             h, pools, *counts = paged_chunk_apply(
                 params, toks[:, None], pools, tabs, pos, cfg,
                 attn_kernel="decode" if kern else None, base=base,
-                with_stats=stats)
+                with_stats=stats, **state)
             logits = head_logits(params, h, cfg)[:, 0, :]
             toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return (pools, toks, *counts)
@@ -1260,8 +1311,10 @@ class LMEngine(Logger):
             # copy-on-write: duplicate one page across every block so
             # the writer owns ``dst`` exclusively and the other
             # referents of ``src`` keep bit-identical rows
-            return [tuple(p.at[dst].set(p[src]) for p in layer)
-                    for layer in pools]
+            # (a linear layer's pair is slots of state, not pages)
+            return [layer if cfg.kind(i) == model_config.LINEAR
+                    else tuple(p.at[dst].set(p[src]) for p in layer)
+                    for i, layer in enumerate(pools)]
 
         kv_tree, repl = self._out_shard_trees()
         pair = (kv_tree, repl) if kv_tree is not None else None
@@ -2098,6 +2151,20 @@ class LMEngine(Logger):
                     raise RuntimeError(
                         "free slot %d holds %d window pages"
                         % (slot, self._wt.count[slot]))
+        if self._state_shapes is not None:
+            # a lane's state slot is its lane: held exactly while the
+            # lane is, and a free slot's step position parks at 0 (the
+            # chunk that starts there ignores what the slot holds)
+            for slot, lane in enumerate(self._lanes):
+                if (lane is None) != (slot in self._free):
+                    raise RuntimeError(
+                        "state slot %d is %s but its lane is %s"
+                        % (slot, "free" if slot in self._free else "held",
+                           "empty" if lane is None else "occupied"))
+                if lane is None and self._pos[slot]:
+                    raise RuntimeError(
+                        "free state slot %d parks at position %d"
+                        % (slot, self._pos[slot]))
         self._pool.verify()
         n = self._pool.num_pages
         want_refs = [0] * (n + 1)
@@ -2465,6 +2532,10 @@ class LMEngine(Logger):
                                    self._pool.free_pages)
             self.metrics.set_gauge("kv_pages_free.window",
                                    self._wt.pool.free_pages)
+        if self._state_shapes is not None:
+            self.metrics.set_gauge(
+                "state_slots_free",
+                sum(lane is None for lane in self._lanes))
 
     def _slide_window(self, slot, lo, hi):   # hot-path
         """The sliding layers' table of ``slot`` before it writes
@@ -2482,6 +2553,14 @@ class LMEngine(Logger):
         lane), the sliding kind's rows cut to the same width or to its
         own, whichever is less."""
         wt = self._wt
+        if self._state_shapes is not None:
+            # the table, and whose state the program touches: the chunk
+            # program's one lane's slot, or the lanes that decode in this
+            # step (``_step_plain`` marks them; none while warming up)
+            return xfer.to_device(full), (
+                xfer.to_device(self._decoding.copy())
+                if isinstance(rows, slice)
+                else xfer.to_device(rows, numpy.int32))
         if wt is None:
             return xfer.to_device(full)
         width = min(full.shape[-1], wt.width)
@@ -2714,7 +2793,9 @@ class LMEngine(Logger):
                 self._update_pool_gauges()
                 self._pos[slot] = lane.pending[0][1]
                 return
-        last_idx = (req.true_len - 1 - start) if is_tail else 0
+        # (the chunk's last real row: a whole chunk's own last, whose
+        # token nobody reads)
+        last_idx = (req.true_len - 1 - start) if is_tail else C - 1
         t0 = time.monotonic()
         try:
             self._fault("engine.chunk")
@@ -2760,6 +2841,8 @@ class LMEngine(Logger):
             self._teardown_slot(slot, lane, e)
             return
         self.metrics.inc("prefill_dispatches")
+        if self._state_shapes is not None and start == 0:
+            self.metrics.inc("state_resets")
         # (the latent kind's chunk kernel also walks the chunk's own page,
         # written before it: its query rows count like a decode's)
         self._note_attn_dispatch(
@@ -2834,6 +2917,8 @@ class LMEngine(Logger):
         self._last[slot] = 0
         if self._paged:
             self._page_tables[slot, :] = KVPagePool.SCRATCH
+        if self._state_shapes is not None:
+            self._update_pool_gauges()
 
     def _teardown_slot(self, slot, lane, exc=None):
         """THE failure/cancellation teardown: vacate the slot and fail
@@ -2979,6 +3064,9 @@ class LMEngine(Logger):
                         self._slide_window(slot, p, p + 1)
             if self._paged:
                 w = self._live_width(1)
+                if self._state_shapes is not None:
+                    self._decoding[:] = False
+                    self._decoding[active] = True
                 args = (self._table_args(self._page_tables[:, :w],
                                          slice(None)),)
             args += (xfer.to_device(self._last),
