@@ -1,0 +1,419 @@
+"""ISSUE 37: the part of a turn that needs no token runs while the device
+runs the step.  ``LMEngine._serve_loop``'s paged plain driver delivers the
+tokens of the step before, sheds and admits, and prepares the next turn's
+chunk and step BETWEEN a decode step's jit call and the wait for its tokens
+(``_under_step``, the ``ahead.*`` phases).  Held here: the served tokens are
+the references' own through the reordered loop, for the four kinds of
+engine the cells run; the turn's row keeps the shape the benchmark's readers
+rely on; an argument put ahead never shares memory with what the loop
+changes in place; a fault, a cancel or a weight swap that lands between
+preparation and dispatch drops what was prepared and fails the right lanes
+only; and the two counters count what they say.
+
+No case asserts a duration: only order, counts, identities and tokens."""
+
+import functools
+import threading
+
+import numpy
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from lm_cases import _params, assert_greedy, kinds_model
+from veles_tpu import model_config
+from veles_tpu.serving import tracing
+
+#: (prompt length, n_new) of a round: more requests than lanes, prompts of
+#: one to four chunks of 8, answers that end while others prefill — and an
+#: answer of ONE token (its tail chunk's first token is its last) and of two
+#: (freed by count under its only step)
+ROUND = [(5, 9), (19, 6), (3, 1), (26, 12), (9, 2), (12, 7), (30, 5)]
+
+
+def tokens(n, seed, vocab):
+    return numpy.random.default_rng(seed).integers(0, vocab, n)
+
+
+@functools.lru_cache(maxsize=None)
+def _model(kind):
+    """(record, the engine's float32 weights, checker(prompt, out))."""
+    if kind == "pre_ln":
+        params = _params(max_len=64)
+        return 2, params, None
+    if kind == "window":
+        record, params = kinds_model()
+        return record, params, None
+    if kind == "latent":
+        import test_xing4 as small
+        from benchmark.reference import xing4 as reference
+    else:
+        import test_qwen3_next as small
+        from benchmark.reference import qwen3_next as reference
+    w = reference.make_weights(3, small.SMALL)
+
+    def check(prompt, out):
+        seq = numpy.concatenate([prompt, out])
+        ref = reference.logits(
+            w, seq, numpy.arange(len(prompt) - 1, len(seq) - 1), small.SMALL)
+        gap = ref.max(-1) - ref[numpy.arange(len(out)), out]
+        assert float(gap.max()) <= 1e-4, gap
+    return (small.record(), jax.tree.map(lambda a: a.astype(jnp.float32), w),
+            check)
+
+
+def make_engine(kind="pre_ln", name="ahead", **over):
+    from veles_tpu.serving import LMEngine, ServingMetrics
+    record, params, _ = _model(kind)
+    kw = dict(max_len=64, slots=3, paged_kv=True, prefill_chunk=8,
+              metrics=ServingMetrics(name), name=name)
+    kw.update(over)
+    return LMEngine(params, record, **kw)
+
+
+def check_tokens(kind, engine, prompt, out, n_new):
+    check = _model(kind)[2]
+    out = numpy.asarray(out)
+    assert out.shape == (n_new,)
+    if check is None:
+        assert_greedy(engine, prompt, out, n_new)
+    else:
+        check(numpy.asarray(prompt), out)
+
+
+def vocab_of(engine):
+    return int(engine.params["embed"].shape[0])
+
+
+def serve(engine, round_=ROUND, seed=40):
+    """Start, serve one round (all submitted at once), stop: the prompts
+    and the served continuations."""
+    engine.start()
+    try:
+        prompts = [tokens(n, seed + i, vocab_of(engine))
+                   for i, (n, _) in enumerate(round_)]
+        futures = [engine.submit(p, n_new)
+                   for p, (_, n_new) in zip(prompts, round_)]
+        outs = [f.result(timeout=300) for f in futures]
+    finally:
+        engine.stop()
+    return prompts, outs
+
+
+def counters(engine):
+    return engine.metrics.snapshot()["counters"]
+
+
+def stamps_of(turns):
+    return turns[:, tracing.COL_STAMPS:tracing.COL_END + 1]
+
+
+def follows_a_step(turns):
+    """Bool per turn: the turn before dispatched a decode program (its step
+    was in flight while this one was prepared)."""
+    step = turns[:, tracing.COL_STEP_PROGRAM] > 0
+    return numpy.concatenate([[False], step[:-1]])
+
+
+# ------------------------------------------------------- (a) the tokens
+@pytest.mark.parametrize("kind", ["pre_ln", "window", "latent", "linear"])
+def test_the_reordered_loop_serves_the_references_tokens(kind):
+    """Seven requests on three lanes, so prompts end in the turns in which
+    others start, lanes are freed by count under their last step and taken
+    again under the same step: every token is the reference's, every page
+    and slot comes home, and most decode steps were prepared ahead."""
+    engine = make_engine(kind, name="ahead_" + kind)
+    prompts, outs = serve(engine)
+    for p, o, (_, n_new) in zip(prompts, outs, ROUND):
+        check_tokens(kind, engine, p, o, n_new)
+    assert engine.verify_pool_invariants()["used_pages"] == 0
+    assert engine._ahead is None and not engine._undelivered
+    c = counters(engine)
+    assert c["tokens_out"] == sum(n for _, n in ROUND)
+    assert c.get("kv_storage_rebuilds", 0) == 0
+    assert c["turns_prepared_ahead"] > c["decode_dispatches"] // 2
+    # the one-token answer ends at its tail chunk, after the step that took
+    # its lane for a decoding one was prepared: that one preparation goes
+    assert c.get("ahead_discarded", 0) <= 1
+
+
+# ------------------------------------------------------ (b) a turn's row
+DRIVERS = {
+    "plain": dict(),
+    "plain_window": dict(kind="window"),
+    "speculative": dict(spec_k=2),
+    "megastep": dict(megastep=4),
+    "contiguous": dict(paged_kv=0),
+}
+
+
+@pytest.mark.parametrize("driver", sorted(DRIVERS))
+def test_a_turns_row_keeps_its_shape(driver):
+    """What ``benchmark/lib/spans.py`` relies on, per driver: stamps never
+    go back, turns leave no hole, a row holds at most one chunk and one
+    step with ``prefill.dispatch`` <= ``step.dispatch`` <= ``step.emit``,
+    every phase has its name in ``tracing.PHASES``; the ``ahead.*`` phases
+    are empty where no step is in flight and for every driver that cannot
+    split its turn; token stamps number ``n_new`` a request and sum to
+    ``tokens_out``."""
+    kw = dict(DRIVERS[driver])
+    kind = kw.pop("kind", "pre_ln")
+    round_ = [(n, max(n_new, 2)) for n, n_new in ROUND]
+    engine = make_engine(kind, name="row_" + driver, **kw)
+    prompts, outs = serve(engine, round_)
+    for p, o, (_, n_new) in zip(prompts, outs, round_):
+        check_tokens(kind, engine, p, o, n_new)
+    rec, c = engine.recorder, counters(engine)
+    turns = rec.turns()
+    s = stamps_of(turns)
+    assert (numpy.diff(s, axis=1) >= 0).all()
+    assert (s[1:, 0] == s[:-1, -1]).all()
+    assert turns[:, tracing.COL_SEQ].tolist() == list(range(1, len(turns) + 1))
+    assert len(tracing.PHASES) == s.shape[1] - 1
+    assert tracing.PHASES.index("step.dispatch") \
+        < tracing.PHASES.index("ahead.emit") \
+        < tracing.PHASES.index("ahead.admit") \
+        < tracing.PHASES.index("ahead.prepare") \
+        < tracing.PHASES.index("step.fetch") \
+        < tracing.PHASES.index("step.emit")
+    assert {e["name"] for e in rec.chrome_events(1, last=len(turns))
+            if e["ph"] == "X"} <= set(tracing.PHASES)
+    step = turns[:, tracing.COL_STEP_PROGRAM] > 0
+    chunk = turns[:, tracing.COL_PREFILL_PROGRAM] > 0
+    assert int(step.sum()) == c["decode_dispatches"]
+    assert int(chunk.sum()) == c.get("prefill_dispatches", 0)
+    assert (s[:, tracing.PREFILL_DISPATCH] <= s[:, tracing.STEP_DISPATCH]).all()
+    assert (s[:, tracing.STEP_DISPATCH] <= s[:, tracing.STEP_EMIT]).all()
+    ahead = s[:, tracing.STEP_FETCH] - s[:, tracing.AHEAD_EMIT]
+    assert not ahead[~step].any()
+    splits = driver.startswith("plain")
+    if splits:
+        assert (ahead[step] > 0).all()
+        # a turn prepared under the step before skips admission and the
+        # chunk's preparation: the tick runs into the first dispatch
+        made = follows_a_step(turns) & step
+        assert int(made.sum()) == c["turns_prepared_ahead"] > 0
+        assert (s[made, tracing.ADMIT]
+                == s[made, tracing.PREFILL_DISPATCH]).all()
+        assert (s[made & ~chunk, tracing.ADMIT]
+                == s[made & ~chunk, tracing.STEP_PREPARE]).all()
+    else:
+        assert not ahead.any()
+        assert "turns_prepared_ahead" not in c
+    assert c.get("ahead_discarded", 0) == 0
+    reqs = rec.requests()
+    assert len(reqs) == len(round_)
+    for r, (_, n_new) in zip(sorted(reqs, key=lambda r: r.enqueue), round_):
+        assert r.outcome == "ok"
+        assert r.tokens_out == len(r.token_ns) == r.n_new == n_new
+        assert list(r.token_ns) == sorted(r.token_ns)
+        assert r.enqueue <= r.admit <= r.first_token <= r.done
+    assert sum(r.tokens_out for r in reqs) == c["tokens_out"] \
+        == int(turns[:, tracing.COL_TOKENS].sum())
+
+
+# ------------------------------------- (e) what the first counter counts
+@pytest.mark.parametrize("kind", ["pre_ln", "window"])
+def test_turns_prepared_ahead_counts_the_steps_that_follow_a_step(kind):
+    """With nothing dropped, ``turns_prepared_ahead`` is ``decode_dispatches``
+    less the decode turns that began with no step in flight (the first, and
+    those behind a turn that only prefilled or waited)."""
+    round_ = [(n, max(n_new, 2)) for n, n_new in ROUND]
+    engine = make_engine(kind, name="count_" + kind, slots=2)
+    serve(engine, round_)
+    c = counters(engine)
+    turns = engine.recorder.turns()
+    step = turns[:, tracing.COL_STEP_PROGRAM] > 0
+    cold = int((step & ~follows_a_step(turns)).sum())
+    assert cold >= 1
+    assert c.get("ahead_discarded", 0) == 0
+    assert c["turns_prepared_ahead"] == c["decode_dispatches"] - cold
+
+
+# --------------------------------------- (c) arguments put ahead are copies
+@pytest.mark.parametrize("kind", ["window", "linear"])
+def test_an_argument_put_ahead_is_a_copy_and_keeps_its_value(kind,
+                                                             monkeypatch):
+    """A put may read host memory after it returns, and the loop now changes
+    ``_pos``, ``_last`` and the tables in place while the arguments it put
+    wait for their dispatch: nothing handed to ``xfer.to_device`` shares
+    memory with them, and every argument of every prepared step still holds,
+    after all traffic, what the host held when it was put."""
+    from veles_tpu.serving import lm_engine
+    engine = make_engine(kind, name="copies_" + kind)
+    live = [engine._pos, engine._last, engine._page_tables, engine._decoding]
+    if engine._wt is not None:
+        live += [engine._wt.tables, engine._wt.base, engine._wt.count]
+    real_put = lm_engine.xfer.to_device
+    shared = []
+
+    def put(x, dtype=None, device=None):
+        if isinstance(x, numpy.ndarray) and any(
+                numpy.shares_memory(x, a) for a in live):
+            shared.append(x.shape)
+        return real_put(x, dtype, device)
+    monkeypatch.setattr(lm_engine.xfer, "to_device", put)
+    kept = []
+    real_prepare = engine._prepare_step
+
+    def prepare(active):
+        step = real_prepare(active)
+        if step is not None:
+            kept.append((step, engine._pos.copy(),
+                         engine._page_tables[:, :step.width].copy(),
+                         engine._decoding.copy()))
+        return step
+    engine._prepare_step = prepare
+    serve(engine)
+    assert not shared
+    assert len(kept) > 10
+    for step, pos, table, decoding in kept:
+        numpy.testing.assert_array_equal(numpy.asarray(step.pos_dev), pos)
+        arg = step.tables[0]
+        if engine._state_shapes is not None:
+            numpy.testing.assert_array_equal(numpy.asarray(arg[1]), decoding)
+            arg = arg[0]
+        elif engine._wt is not None:
+            arg = arg[0][model_config.FULL]
+        numpy.testing.assert_array_equal(numpy.asarray(arg), table)
+    # and the lanes' state DID move under them
+    assert any((pos != kept[0][1]).any() for _, pos, _, _ in kept[1:])
+
+
+# ------------------- (d) what lands between preparation and dispatch
+def gated(engine):
+    """Hold the worker's first admission until the test has queued its
+    requests, so that the turns are the same on every run."""
+    gate = threading.Event()
+    real = engine._admit_turn
+
+    def admit_turn():
+        assert gate.wait(60)
+        return real()
+    engine._admit_turn = admit_turn
+    return gate
+
+
+def after_stretch(engine, n, act):
+    """Run ``act()`` at the end of the engine's ``n``-th early stretch: what
+    it does lands between a preparation and its dispatch."""
+    real, calls = engine._under_step, []
+
+    def under_step(step, made_ahead):
+        real(step, made_ahead)
+        calls.append(1)
+        if len(calls) == n:
+            assert engine._ahead is not None
+            act()
+    engine._under_step = under_step
+
+
+LANDINGS = ["step", "chunk", "tick", "cancel", "swap"]
+
+
+@pytest.mark.parametrize("what", LANDINGS)
+def test_what_lands_between_preparation_and_dispatch(what):
+    """Two lanes: A decodes (a one-chunk prompt, 14 tokens), B prefills four
+    chunks behind it, C waits in the queue.  A fault at ``engine.step``, at
+    ``engine.chunk`` or at ``engine.tick``, a cancel of A or a weight swap
+    that drains lands when a turn has been prepared under A's step: the
+    preparation is dropped and counted, the lanes the event names fail (or
+    are withdrawn, or decoded anew), the others' tokens are the greedy ones
+    bit for bit, and the pool's books balance."""
+    from veles_tpu.serving import FaultPlan, InjectedFault
+    plan = FaultPlan(seed=0)
+    if what == "step":
+        plan.arm("engine.step", calls={3})
+    elif what == "chunk":
+        # turn 1 A's tail chunk, turn 2 B's first chunk, turn 3 B's second:
+        # both of B's prepared under a step of A
+        plan.arm("engine.chunk", calls={3})
+    elif what == "tick":
+        plan.arm("engine.tick", calls={4})
+    engine = make_engine(name="lands_" + what, slots=2, faults=plan)
+    gate = gated(engine)
+    vocab = vocab_of(engine)
+    a, b, c = (tokens(n, 70 + n, vocab) for n in (6, 29, 11))
+    engine.start()
+    try:
+        fa, fb = engine.submit(a, 14), engine.submit(b, 5)
+        fc = engine.submit(c, 6)
+        swapped = []
+        if what == "cancel":
+            after_stretch(engine, 2, lambda: engine._cancel(fa.request))
+        elif what == "swap":
+            def swap():
+                thread = threading.Thread(
+                    target=lambda: swapped.append(engine.swap_weights(
+                        jax.tree.map(jnp.array, engine.params), drain=True)))
+                thread.start()
+                swapped.append(thread)
+                while engine._peek_swap() is None:
+                    assert thread.is_alive()
+            after_stretch(engine, 2, swap)
+        gate.set()
+        failed = {"step": [fa], "chunk": [fb], "tick": [fa, fb],
+                  "cancel": [], "swap": []}[what]
+        for name, f, prompt, n_new in (("a", fa, a, 14), ("b", fb, b, 5),
+                                       ("c", fc, c, 6)):
+            if f in failed:
+                with pytest.raises(InjectedFault):
+                    f.result(timeout=120)
+            elif what == "cancel" and f is fa:
+                # withdrawn in its slot: it leaves with the tokens it had
+                assert 1 <= len(f.result(timeout=120)) < 14
+            else:
+                assert_greedy(engine, prompt, f.result(timeout=120), n_new)
+        if what == "swap":
+            swapped[0].join(60)
+            assert not swapped[0].is_alive() and swapped[1] == 1
+            assert fa.version == fb.version == fc.version == 1
+    finally:
+        engine.stop()
+    assert engine.verify_pool_invariants()["used_pages"] == 0
+    assert engine._ahead is None and not engine._undelivered
+    cn = counters(engine)
+    assert cn["ahead_discarded"] >= 1
+    assert cn.get("kv_storage_rebuilds", 0) == 0
+    outcomes = sorted(r.outcome for r in engine.recorder.requests())
+    assert outcomes == sorted(["failed"] * len(failed)
+                              + ["ok"] * (3 - len(failed)))
+    # the recorder's stamps and the counter still agree, whatever failed
+    assert sum(r.tokens_out for r in engine.recorder.requests()) \
+        == cn["tokens_out"]
+
+
+def test_a_failed_fetch_fails_the_lane_it_had_freed_by_count():
+    """The step's program fails on the device: the lanes it advanced fail,
+    the one that was freed by count when the step went out (its last) too,
+    and a request admitted into that lane's slot under the step is served."""
+    engine = make_engine(name="fetch", slots=1)
+    gate = gated(engine)
+    vocab = vocab_of(engine)
+    a, b = tokens(5, 1, vocab), tokens(7, 2, vocab)
+    boom = RuntimeError("the step failed on the device")
+    real, calls = engine._under_step, []
+
+    def under_step(step, made_ahead):
+        real(step, made_ahead)
+        calls.append(1)
+        if len(calls) == 2:     # A's second and last step
+            raise boom
+    engine._under_step = under_step
+    engine.start()
+    try:
+        fa, fb = engine.submit(a, 3), engine.submit(b, 4)
+        gate.set()
+        with pytest.raises(RuntimeError, match="failed on the device"):
+            fa.result(timeout=120)
+        # B took A's slot under A's last step; the storage went down with
+        # the failed dispatch and took B's rows with it
+        with pytest.raises(RuntimeError, match="failed on the device"):
+            fb.result(timeout=120)
+        again = engine.submit(b, 4).result(timeout=120)
+        assert_greedy(engine, b, again, 4)
+    finally:
+        engine.stop()
+    assert engine.verify_pool_invariants()["used_pages"] == 0
+    assert counters(engine)["kv_storage_rebuilds"] == 1

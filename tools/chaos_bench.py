@@ -467,7 +467,7 @@ def scenario_traced_flight_recorder(params, n_heads, max_len, prompts,
 def recorder_turn_cost(slots, turns=20000):
     """Seconds the always-on loop recorder (ISSUE 26) costs per turn of
     the engine loop: every call a decode turn with one prefill chunk
-    makes (turn, seven marks, two dispatches, the lane counts, one token
+    makes (turn, ten marks, two dispatches, the lane counts, one token
     stamp per lane), timed over ``turns`` turns on this host."""
     from veles_tpu.serving import tracing
 
@@ -489,10 +489,13 @@ def recorder_turn_cost(slots, turns=20000):
         rec.dispatch(tracing.PREFILL_DISPATCH, program)
         rec.mark(tracing.STEP_PREPARE)
         rec.dispatch(tracing.STEP_DISPATCH, program, slots)
-        rec.mark(tracing.STEP_FETCH)
-        rec.mark(tracing.STEP_EMIT)
+        rec.mark(tracing.AHEAD_EMIT)
         for _lane in range(slots):
             rec.emitted(req, 1)
+        rec.mark(tracing.AHEAD_ADMIT)
+        rec.mark(tracing.AHEAD_PREPARE)
+        rec.mark(tracing.STEP_FETCH)
+        rec.mark(tracing.STEP_EMIT)
     rec.close()
     return (time.perf_counter() - t0) / turns
 

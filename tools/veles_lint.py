@@ -113,8 +113,12 @@ HOT_PATH_REGISTRY = (
     ("veles_tpu/serving/lm_engine.py", "_admit_paged"),
     ("veles_tpu/serving/lm_engine.py", "_cow_guard"),
     ("veles_tpu/serving/lm_engine.py", "_advance_prefill"),
-    ("veles_tpu/serving/lm_engine.py", "_advance_prefill_paged"),
+    ("veles_tpu/serving/lm_engine.py", "_prepare_chunk_paged"),
+    ("veles_tpu/serving/lm_engine.py", "_dispatch_chunk_paged"),
     ("veles_tpu/serving/lm_engine.py", "_dispatch_decode"),
+    ("veles_tpu/serving/lm_engine.py", "_prepare_step"),
+    ("veles_tpu/serving/lm_engine.py", "_under_step"),
+    ("veles_tpu/serving/lm_engine.py", "_deliver"),
     ("veles_tpu/serving/lm_engine.py", "_step_plain"),
     ("veles_tpu/serving/lm_engine.py", "_step_speculative"),
     ("veles_tpu/serving/lm_engine.py", "_step_megastep"),

@@ -190,6 +190,33 @@ pool into and out of every kernel dispatch.  The gauge
 consumed its storage) and the counter ``kv_storage_rebuilds`` are the
 witnesses in ``/metrics.json``.
 
+THE ORDER OF A TURN (ISSUE 37).  One turn of ``_serve_loop`` dispatches
+at most one prompt chunk and one decode program, the chunk first.  Of
+everything a turn does, ONE value needs the tokens the host waits for:
+the ``last`` argument of the next step.  The engine has no stop token —
+a lane ends by count — so positions, pages, windows, widths and tables
+all follow from what the host already knows when a step goes out.  The
+paged plain driver therefore does the rest of its turn between the
+step's jit call and the wait for its tokens, while the device runs the
+step (:meth:`LMEngine._under_step`; the recorder's ``ahead.emit``,
+``ahead.admit``, ``ahead.prepare``): the step is counted into its lanes
+(a lane that owes no further token is freed at once, its reply made
+when the token lands), the tokens of the step BEFORE go to their lanes,
+the queue is shed and admitted, and the next turn's chunk and step get
+their guards, slides, width and every put but ``last``.  The turn that
+begins when the tokens arrive is then: tick, the prepared chunk's jit
+call, the put of ``last``, the step's jit call.  Arguments made ahead
+are put from copies (the loop changes ``_pos`` and the tables in place
+under them), and are dropped — the turn falls back to the old order,
+tick -> deliver -> admit -> chunk -> step — when a lane left its slot
+meanwhile (a failed fetch, a fault site, a teardown), a lane was
+withdrawn or a weight swap waits; ``turns_prepared_ahead`` and
+``ahead_discarded`` in ``/metrics.json`` count both.  The speculative
+driver (it drafts from the emitted tokens), the megastep (its tokens
+stay inside the scan) and the contiguous layout (its admission
+dispatches programs) cannot split their turn: they leave nothing
+prepared and the same loop runs them in the old order.
+
 Decoding is GREEDY (temperature 0) — bit-identical to
 ``ops/transformer.py::generate`` for the same prompt WHATEVER fast-path
 combination is enabled, which is the serving contract (sampled
@@ -276,6 +303,38 @@ class _Slot:
         #: paged mode: page ids backing this lane's table row, in
         #: lane-local order (owned AND referenced; released at finish)
         self.pages = []
+
+
+#: one prompt chunk with its arguments on the device and its page steps
+#: counted (:meth:`LMEngine._prepare_chunk_paged`), ready for its jit call
+_Chunk = collections.namedtuple(
+    "_Chunk", "slot lane tokens start is_tail args steps")
+
+#: one plain decode step's arguments, all but ``last`` (the one that needs
+#: the tokens of the step before), on the device
+#: (:meth:`LMEngine._prepare_step`): the lanes it advances as ``(slot,
+#: lane)`` pairs, the table width, the table argument as a tuple (empty for
+#: the contiguous layout), the positions on the device, and the page steps
+#: it hands the attention kernels (:meth:`LMEngine._attn_page_steps`)
+_Step = collections.namedtuple(
+    "_Step", "pairs width tables pos_dev steps")
+
+
+class _Ahead:
+    """The next turn as :meth:`LMEngine._under_step` prepared it while the
+    device ran a decode step (ISSUE 37): its prompt chunk (None: no lane
+    prefills, or the chunk was a late hit), its decode step (None: no lane
+    will decode), the lanes' generation both were made for and what
+    admission left for the recorder's row."""
+
+    __slots__ = ("chunk", "step", "gen", "busy", "queued")
+
+    def __init__(self, chunk, step, gen, busy, queued):
+        self.chunk = chunk
+        self.step = step
+        self.gen = gen
+        self.busy = busy
+        self.queued = queued
 
 
 def prompt_bucket(true_len, max_len, floor=16):
@@ -918,6 +977,17 @@ class LMEngine(Logger):
         self._last = numpy.zeros(self.slots, numpy.int32)
         self._lanes = [None] * self.slots
         self._free = list(range(self.slots))
+        #: the turn's early stretch (ISSUE 37), the worker thread's own:
+        #: what was prepared for the next turn under the step in flight;
+        #: the tokens fetched and not yet given to their lanes, as
+        #: ``(slot, lane, token, whether it is the request's last)``; a
+        #: count of the lanes that left a slot, by which a preparation
+        #: knows the lanes are still those it was made for; the
+        #: prefilling lanes' round robin
+        self._ahead = None
+        self._undelivered = []
+        self._lanes_gen = 0
+        self._rr = 0
 
         self._queue = collections.deque()
         self._queued_tokens = 0
@@ -1754,6 +1824,12 @@ class LMEngine(Logger):
         swap = self._peek_swap()
         if swap is None:
             return
+        # the turn runs in the old order while a swap waits, and every
+        # reply owed is made before it may apply: a lane the plain step
+        # freed by count still owes its last token's delivery, on the
+        # weights that made it
+        self._drop_ahead()
+        self._deliver()
         active = [i for i, lane in enumerate(self._lanes)
                   if lane is not None]
         if active and not swap["drain"]:
@@ -2551,8 +2627,12 @@ class LMEngine(Logger):
         width: that table on the device; for two kinds of cache the pair
         (a table per kind, where the sliding kind's begins in tokens per
         lane), the sliding kind's rows cut to the same width or to its
-        own, whichever is less."""
+        own, whichever is less.  Every argument is put from a COPY: a put
+        may read host memory after it returns, and the loop changes the
+        tables and ``_pos`` in place while the step it dispatched runs
+        (ISSUE 37)."""
         wt = self._wt
+        full = full.copy()
         if self._state_shapes is not None:
             # the table, and whose state the program touches: the chunk
             # program's one lane's slot, or the lanes that decode in this
@@ -2564,8 +2644,6 @@ class LMEngine(Logger):
         if wt is None:
             return xfer.to_device(full)
         width = min(full.shape[-1], wt.width)
-        # the sliding rows as an array of their own: a put may read host
-        # memory after it returns, and the rows shift in place
         tables = {model_config.FULL: xfer.to_device(full),
                   model_config.SLIDING: xfer.to_device(
                       wt.tables[rows, :width].copy())}
@@ -2597,29 +2675,18 @@ class LMEngine(Logger):
         self.metrics.set_gauge_max("moe_max_expert_load", load)
         self.recorder.moe(held, away, hit, load)
 
-    def _note_attn_dispatch(self, pos=None, width=0, span=0,
-                            rows=slice(None), calls=1):
-        """Per-dispatch kernel accounting (ISSUE 7): which path the
-        engine's attention actually took.  Only metered when the caller
-        ASKED for kernels — an untouched engine carries no new
-        counters.
-
-        A dispatch through the kernels also counts the page steps it
-        handed them and the live ones among them (ISSUE 29), the
-        counters ``attn_page_steps`` / ``attn_page_steps_live`` and the
-        recorder's open turn: ``pos`` the positions of the lanes
-        ``rows`` as the program got them, ``width`` its table's,
+    def _attn_page_steps(self, pos, width, span=0, rows=slice(None)):
+        """``(given, live)``: the page steps a dispatch hands the
+        attention kernels and the live ones among them (ISSUE 29), or None
+        where the kernels are not active: ``pos`` the positions of the
+        lanes ``rows`` as the program gets them, ``width`` its table's,
         ``span`` the query rows a lane (0: a prefill chunk, whose kernel
-        walks the history below ``pos``), ``calls`` the steps of a
-        fused program (counted at the positions it entered with).  Host
-        integers over at most ``slots`` lanes, by the kernels' own
-        ``live_pages``."""
-        if not self.attn_kernel:
-            return
-        self.metrics.inc("attn_kernel_dispatches" if self._kernel_active
-                         else "attn_kernel_fallbacks")
-        if pos is None or not self._kernel_active:
-            return
+        walks the history below ``pos``).  Read where the dispatch's
+        tables are made (the sliding kind's short table begins at its
+        base THEN).  Host integers over at most ``slots`` lanes, by the
+        kernels' own ``live_pages``."""
+        if not self._kernel_active:
+            return None
         from veles_tpu.ops.pallas_kernels import (live_page_count,
                                                   live_pages)
         pos = numpy.atleast_1d(pos).astype(numpy.int64)
@@ -2636,6 +2703,26 @@ class LMEngine(Logger):
                 p, span, self.prefill_chunk, w, window, self.sinks,
                 xp=numpy)).sum())
             given += layers * p.size * w
+        return given, live
+
+    def _note_attn_dispatch(self, steps=None, calls=1):
+        """Per-dispatch kernel accounting (ISSUE 7): which path the
+        engine's attention actually took.  Only metered when the caller
+        ASKED for kernels — an untouched engine carries no new
+        counters.
+
+        A dispatch through the kernels also counts its page steps
+        (``steps``, :meth:`_attn_page_steps`): the counters
+        ``attn_page_steps`` / ``attn_page_steps_live`` and the recorder's
+        open turn; ``calls`` the steps of a fused program (counted at the
+        positions it entered with)."""
+        if not self.attn_kernel:
+            return
+        self.metrics.inc("attn_kernel_dispatches" if self._kernel_active
+                         else "attn_kernel_fallbacks")
+        if steps is None:
+            return
+        given, live = steps
         self.metrics.inc("attn_page_steps", calls * given)
         self.metrics.inc("attn_page_steps_live", calls * live)
         self.recorder.attn_pages(calls * given, calls * live)
@@ -2646,21 +2733,30 @@ class LMEngine(Logger):
         return sum(a.size * a.dtype.itemsize
                    for pair in self._storage() for a in pair)
 
-    def _advance_prefill(self, slot):   # hot-path
+    def _pick_prefill(self, prefilling):   # hot-path
+        """The lane whose prompt chunk goes next, as ``(slot, lane)``: at
+        most ONE chunk a turn, round-robin across the prefilling lanes.
+        None where that lane was withdrawn (``generate()``'s sibling
+        cancellation) mid-prefill: its slot is freed now instead of
+        finishing the prompt for a result nobody will read."""
+        self._rr += 1
+        slot = prefilling[self._rr % len(prefilling)]
+        lane = self._lanes[slot]
+        if lane.request.cancelled:
+            self._teardown_slot(slot, lane)
+            return None
+        return slot, lane
+
+    def _advance_prefill(self, slot, lane):   # hot-path
         """Run ONE pending prompt chunk for this lane (a tick's worth of
         prefill — decode lanes step in between, so a long prompt never
         head-of-line-blocks them).  Computed full chunks feed the prefix
         cache; the tail chunk yields the first generated token."""
-        lane = self._lanes[slot]
         req = lane.request
-        if req.cancelled:
-            # withdrawn (generate() sibling cancellation) mid-prefill:
-            # free the slot now instead of finishing the prompt for a
-            # result nobody will read
-            self._teardown_slot(slot, lane)
-            return
         if self._paged:
-            self._advance_prefill_paged(slot, lane, req)
+            chunk = self._prepare_chunk_paged(slot, lane, req)
+            if chunk is not None:
+                self._dispatch_chunk_paged(chunk)
             return
         tokens, start, is_tail = lane.pending.pop(0)
         if not is_tail and self._trie is not None \
@@ -2757,12 +2853,16 @@ class LMEngine(Logger):
         else:
             self._pos[slot] = lane.pending[0][1]
 
-    def _advance_prefill_paged(self, slot, lane, req):   # hot-path
-        """One pending prompt chunk, paged: a LATE HIT swaps the lane's
-        reserved page for a REFERENCE to the sibling's page (release
-        one, retain the other — still zero device work); a computed
-        full chunk SHARES the lane's own page with the trie (retain —
-        the insert itself copies nothing)."""
+    def _prepare_chunk_paged(self, slot, lane, req):   # hot-path
+        """The lane's next pending prompt chunk with everything but its
+        jit call done, as a :class:`_Chunk`, or None where nothing is
+        left to dispatch: a LATE HIT swaps the lane's reserved page for a
+        REFERENCE to the sibling's page (release one, retain the other —
+        zero device work), and a guard that fails tears the lane down.
+        The lane's step position moves to where the chunk will leave it
+        (the next chunk's start; ``true_len`` behind a tail chunk), so a
+        decode step prepared before the chunk goes out (ISSUE 37) parks
+        the lane, or takes it in, as it would have after."""
         C = self.prefill_chunk
         tokens, start, is_tail = lane.pending.pop(0)
         page_idx = start // C
@@ -2792,13 +2892,11 @@ class LMEngine(Logger):
                                "paged": True})
                 self._update_pool_gauges()
                 self._pos[slot] = lane.pending[0][1]
-                return
+                return None
         # (the chunk's last real row: a whole chunk's own last, whose
         # token nobody reads)
         last_idx = (req.true_len - 1 - start) if is_tail else C - 1
-        t0 = time.monotonic()
         try:
-            self._fault("engine.chunk")
             self._cow_guard(slot, lane, start, start + C)
             if self._wt is not None:
                 self._slide_window(slot, start, start + C)
@@ -2806,12 +2904,45 @@ class LMEngine(Logger):
                     xfer.to_device(tokens, numpy.int32),
                     xfer.to_device(start, numpy.int32),
                     xfer.to_device(last_idx, numpy.int32))
+            # (the latent kind's chunk kernel also walks the chunk's own
+            # page, written before it: its query rows count like a
+            # decode's)
+            steps = self._attn_page_steps(
+                start, self._max_pages, rows=slot,
+                span=C if self.cfg.latent is not None else 0)
+        except Exception as e:   # noqa: BLE001 — fails THIS request
+            self.metrics.record_error()
+            self.warning("paged chunk prefill failed: %s", e)
+            self._teardown_slot(slot, lane, e)
+            return None
+        self._pos[slot] = req.true_len if is_tail else lane.pending[0][1]
+        return _Chunk(slot, lane, tokens, start, is_tail, args, steps)
+
+    def _dispatch_chunk_paged(self, chunk):   # hot-path
+        """The jit call of a prepared chunk and what follows it: a
+        computed full chunk SHARES the lane's own page with the trie
+        (retain — the insert itself copies nothing); a tail chunk's first
+        token is fetched here and the lane becomes a decode lane."""
+        slot, lane, tokens, start, is_tail, args, steps = chunk
+        req = lane.request
+        if req.cancelled:
+            # withdrawn since its arguments were made
+            self._teardown_slot(slot, lane)
+            return
+        page_idx = start // self.prefill_chunk
+        t0 = time.monotonic()
+        try:
+            self._fault("engine.chunk")
             self.recorder.dispatch(tracing.PREFILL_DISPATCH,
                                    self._chunk_jit)
             with self._donating():
                 self._kv_pools, tok = self._chunk_jit(
                     self.params, self._kv_pools, *args)
                 self._tfence(self._kv_pools, req.trace is not None)
+                # the device has the chunk to run: the tokens the last
+                # fetch brought reach their lanes now, not behind the
+                # wait for a tail chunk's token
+                self._deliver()
                 if is_tail:      # the first token crosses in here too
                     tok = int(xfer.to_host(tok))
             if not is_tail and self._trie is not None \
@@ -2843,11 +2974,7 @@ class LMEngine(Logger):
         self.metrics.inc("prefill_dispatches")
         if self._state_shapes is not None and start == 0:
             self.metrics.inc("state_resets")
-        # (the latent kind's chunk kernel also walks the chunk's own page,
-        # written before it: its query rows count like a decode's)
-        self._note_attn_dispatch(
-            start, self._max_pages, rows=slot,
-            span=C if self.cfg.latent is not None else 0)
+        self._note_attn_dispatch(steps)
         self.metrics.inc("prefill_tokens",
                          (req.true_len - start) if is_tail
                          else len(tokens))
@@ -2863,8 +2990,6 @@ class LMEngine(Logger):
                        "backend": self._backend})
         if is_tail:
             self._emit_first(slot, lane, tok)
-        else:
-            self._pos[slot] = lane.pending[0][1]
 
     def _count_tokens(self, req, n=1):
         """THE emit site: ``n`` tokens of ``req`` reached the host.  The
@@ -2911,6 +3036,7 @@ class LMEngine(Logger):
             self._wt.vacate(slot)
         self._release_lane(lane)
         self._lanes[slot] = None
+        self._lanes_gen += 1
         if slot not in self._free:
             self._free.append(slot)
         self._pos[slot] = 0
@@ -2933,6 +3059,14 @@ class LMEngine(Logger):
     def _finish(self, slot):
         lane = self._lanes[slot]
         self._vacate_slot(slot, lane)
+        self._reply(lane)
+
+    def _reply(self, lane):
+        """The request's result, once its lane has left the slot: at once
+        (:meth:`_finish`), or — a lane the plain step freed by count when
+        its last step went out — when that step's token is delivered.  A
+        weight swap applies only after every reply owed is made
+        (``_serve_loop``), so the stamp is the tokens' own generation."""
         fut = lane.request.future
         if not fut.cancelled():          # withdrawn mid-decode
             # stamped with the generation that produced these tokens —
@@ -2979,6 +3113,7 @@ class LMEngine(Logger):
         storage takes the place of the lost one; ``kv_storage_rebuilds``
         counts it.  Queued requests are untouched: they hold nothing
         yet, and are served from the fresh storage."""
+        self._deliver()
         held = [i for i, lane in enumerate(self._lanes)
                 if lane is not None]
         self.warning(
@@ -3004,58 +3139,66 @@ class LMEngine(Logger):
         simply abandoned: the storage itself is intact here (the fault
         fired before the program took it), or :meth:`_donating` has
         already replaced it and failed these lanes with the rest, in
-        which case there is nothing left to do for them."""
+        which case there is nothing left to do for them.  The tokens
+        that reached the host before the fault are the lanes' own: they
+        are delivered first (a lane they complete has its result)."""
+        self._deliver()
         self.metrics.record_error()
         self.warning("decode step failed: %s", exc)
         for slot in active:
             if self._lanes[slot] is not None:
                 self._teardown_slot(slot, self._lanes[slot], exc)
 
-    def _dispatch_decode(self, decode_jit, args, lanes, tctxs):   # hot-path
+    def _dispatch_decode(self, decode_jit, args, lanes, tctxs, under=None):   # hot-path
         """THE decode dispatch all three drivers share (tick, verify and
         megastep): ``decode_jit`` over the parameters, the KV
         storage — DONATED: the program updates it in place and the tree
         passed in is dead when the call returns — and ``args``, already
         on the device (the puts belong to ``step.prepare``); then the
-        storage rebound to the first output and the others fetched to
-        the host.  Call and fetch run under :meth:`_donating`, the rule
-        for a dispatch that raises once its storage is consumed; the
-        drivers' own ``except`` follows it.  The recorder's
-        ``step.dispatch`` spans the jit call until it returns,
-        ``step.fetch`` the wait for the device and the copy out (an armed
-        tracer's fence too, when a sampled lane rides the dispatch), and
-        ``step.emit`` opens as this returns."""
+        storage rebound to the first output, ``under()`` if the driver
+        gave one — the part of its turn that needs no token, run while
+        the device runs the program (the ``ahead.*`` phases, ISSUE 37) —
+        and the other outputs fetched to the host.  Call, ``under`` and
+        fetch run under :meth:`_donating`, the rule for a dispatch that
+        raises once its storage is consumed; the drivers' own ``except``
+        follows it.  The recorder's ``step.dispatch`` spans the jit call
+        until it returns, ``step.fetch`` the wait for the device and the
+        copy out (an armed tracer's fence too, when a sampled lane rides
+        the dispatch), and ``step.emit`` opens as this returns."""
         rec = self.recorder
         rec.dispatch(tracing.STEP_DISPATCH, decode_jit, lanes)
         with self._donating():
             out = decode_jit(self.params, self._storage(), *args)
             self._set_storage(out[0])
+            if under is not None:
+                under()
             rec.mark(tracing.STEP_FETCH)
             host = xfer.to_host(tuple(out[1:]))
-            self._tfence(out[0], any(c is not None for c in tctxs))
+            # (the storage as it is NOW: a copy-on-write made under the
+            # step has donated ``out[0]`` on)
+            self._tfence(self._storage(),
+                         any(c is not None for c in tctxs))
         rec.mark(tracing.STEP_EMIT)
         return host
 
-    def _step_plain(self, active):   # hot-path
-        """ONE dispatch advances every active lane by one token;
-        inactive lanes step too (their writes land at a frozen position
-        that the next prefill/chunk overwrites before attending — see
-        the module docstring), so the step program never respecializes
-        on the active set."""
+    def _prepare_step(self, active):   # hot-path
+        """Everything a plain decode step over the lanes ``active`` needs
+        but the tokens of the step before (ISSUE 37), as a
+        :class:`_Step`: the copy-on-write guard and the sliding layers'
+        tables at the positions the step will write, the live table
+        width, and the table, ``_decoding`` and position arguments put on
+        the device from copies.  It reads the lanes' positions as they
+        are, so it serves a turn of the old order and, once the step in
+        flight is counted in (:meth:`_advance_by_count`), the turn after
+        alike.  None where no lane is left to step: a guard that fails
+        tears its lane down, a slide that fails fails them all."""
         if self._paged:
             active = self._cow_guard_active(active, 1)
             if not active:
-                return
+                return None
         w = None
-        tctxs = ()
-        if self._tracer is not None:
-            # only the SAMPLED lanes carry a context — an all-None
-            # batch records nothing and (sample:P) skips the fence
-            tctxs = [self._lanes[s].request.trace for s in active]
-        t0 = time.monotonic()
+        tables = ()
         try:
-            self._fault("engine.step")
-            args = ()
             if self._wt is not None:
                 due = self._wt.due(self._pos)
                 for slot in active:
@@ -3067,12 +3210,56 @@ class LMEngine(Logger):
                 if self._state_shapes is not None:
                     self._decoding[:] = False
                     self._decoding[active] = True
-                args = (self._table_args(self._page_tables[:, :w],
-                                         slice(None)),)
-            args += (xfer.to_device(self._last),
-                     xfer.to_device(self._pos))
-            toks, *counts = self._dispatch_decode(self._step_jit, args,
-                                                  len(active), tctxs)
+                tables = (self._table_args(self._page_tables[:, :w],
+                                           slice(None)),)
+            pos = self._pos.copy()
+            return _Step([(slot, self._lanes[slot]) for slot in active],
+                         w, tables, xfer.to_device(pos),
+                         self._attn_page_steps(pos, w, 1))
+        except Exception as e:   # noqa: BLE001 — fails the lanes
+            self._fail_active(active, e)
+            return None
+
+    def _step_plain(self, active, step=None):   # hot-path
+        """ONE dispatch advances every active lane by one token;
+        inactive lanes step too (their writes land at a frozen position
+        that the next prefill/chunk overwrites before attending — see
+        the module docstring), so the step program never respecializes
+        on the active set.
+
+        ``step`` holds the arguments made under the step before
+        (:meth:`_under_step`; None: they are made here, the old order),
+        so what is left between the tokens' arrival and the jit call is
+        the put of ``last``.  The paged layout then does the rest of its
+        turn WHILE the device runs the step (:meth:`_under_step`, the
+        ``ahead.*`` phases); after the wait only ``_last`` moves, and the
+        tokens wait for the next stretch (:meth:`_deliver`).  The
+        contiguous layout, whose admission dispatches programs of its
+        own, keeps the old order: count, commit and deliver after the
+        fetch."""
+        made_ahead = step is not None
+        if step is None:
+            step = self._prepare_step(active)
+            if step is None:
+                return
+        pairs = step.pairs
+        tctxs = ()
+        if self._tracer is not None:
+            # only the SAMPLED lanes carry a context — an all-None
+            # batch records nothing and (sample:P) skips the fence
+            tctxs = [lane.request.trace for _, lane in pairs]
+        under, went = None, []
+        if self._paged:
+            def under():
+                went.append(True)
+                self._under_step(step, made_ahead)
+        t0 = time.monotonic()
+        try:
+            self._fault("engine.step")
+            args = step.tables + (xfer.to_device(self._last.copy()),
+                                  step.pos_dev)
+            toks, *counts = self._dispatch_decode(
+                self._step_jit, args, len(pairs), tctxs, under)
             if counts:
                 self._note_moe(counts[0])
         except Exception as e:   # noqa: BLE001 — fails the lanes
@@ -3080,28 +3267,125 @@ class LMEngine(Logger):
                 self._tracer.add_many(
                     tctxs, "decode.step", "decode", t0,
                     time.monotonic(),
-                    attrs={"batch": len(active), "error": str(e)})
-            self._fail_active(active, e)
+                    attrs={"batch": len(pairs), "error": str(e)})
+            self._fail_step(step, e, made_ahead and not went)
             return
-        self.metrics.record_dispatch(len(active))
         self.metrics.record_decode_step(time.monotonic() - t0)
-        self.metrics.inc("decode_dispatches")
-        self._note_attn_dispatch(self._pos, w, 1)
         if self._tracer is not None:
             self._tracer.add_many(
                 tctxs, "decode.step", "decode", t0, time.monotonic(),
-                attrs={"batch": len(active),
-                       "bucket": w if w is not None else self.slots,
+                attrs={"batch": len(pairs),
+                       "bucket": (step.width if step.width is not None
+                                  else self.slots),
                        "backend": self._backend})
-        for slot in active:
-            lane = self._lanes[slot]
-            lane.emitted.append(int(toks[slot]))
-            lane.remaining -= 1
-            self._count_tokens(lane.request)
+        if under is None:
+            self._note_step(step)
+            self._advance_by_count(pairs)
+        toks = toks.tolist()
+        for slot, lane in pairs:
+            tok = toks[slot]
+            if self._lanes[slot] is lane:
+                self._last[slot] = tok
+            # (its last token, if the count says so NOW: the next
+            # stretch counts the next step in before it delivers)
+            self._undelivered.append((slot, lane, tok,
+                                      lane.remaining == 0))
+        if under is None:
+            self._deliver()
+
+    def _note_step(self, step, made_ahead=False):
+        """The counters of one plain decode dispatch."""
+        self.metrics.record_dispatch(len(step.pairs))
+        self.metrics.inc("decode_dispatches")
+        if made_ahead:
+            self.metrics.inc("turns_prepared_ahead")
+        self._note_attn_dispatch(step.steps)
+
+    def _advance_by_count(self, pairs):   # hot-path
+        """What a plain decode step does to its lanes is known without its
+        tokens (the engine has no stop token: a lane ends by count): each
+        moves one position on and owes one token fewer.  A lane that owes
+        none now is FREED here — pages, tables, slot — so the next
+        admission finds it; its request is answered when the step's token
+        is delivered (:meth:`_deliver`).  The writes of the step in flight
+        land in pages the host has released: the device runs dispatches
+        in order, and whoever takes such a page writes a row before
+        attending it, the rule every free slot's garbage write already
+        lives by."""
+        for slot, lane in pairs:
             self._pos[slot] += 1
-            self._last[slot] = int(toks[slot])
-            if lane.remaining == 0 or lane.request.cancelled:
+            lane.remaining -= 1
+            if lane.remaining == 0:
+                self._vacate_slot(slot, lane)
+
+    def _deliver(self):   # hot-path
+        """The tokens the last fetch brought go to their lanes: the
+        reply's list, THE emit site (:meth:`_count_tokens`), and the result
+        of a request whose last token this is; a lane withdrawn meanwhile
+        leaves its slot as it always did, with the tokens it had.  Runs
+        where the device is busy or nothing is in flight: behind the next
+        turn's chunk call, else under the next step (``ahead.emit``), or
+        first thing in a turn of the old order; and before anything that
+        fails lanes."""
+        if not self._undelivered:
+            return
+        pending, self._undelivered = self._undelivered, []
+        for slot, lane, tok, last in pending:
+            lane.emitted.append(tok)
+            self._count_tokens(lane.request)
+            if last:
+                self._reply(lane)
+            elif lane.request.cancelled and self._lanes[slot] is lane:
                 self._finish(slot)
+
+    def _fail_step(self, step, exc, unused):
+        """A plain step's dispatch or fetch raised: its lanes fail
+        (:meth:`_fail_active`), those it had already freed by count with
+        them, and what was prepared under it goes (``unused``: so did its
+        own arguments, made ahead, before the program took them)."""
+        if unused:
+            self.metrics.inc("ahead_discarded")
+        self._drop_ahead()
+        self._fail_active([slot for slot, lane in step.pairs
+                           if self._lanes[slot] is lane], exc)
+        for _, lane in step.pairs:
+            if not lane.request.future.done():
+                lane.request.future.set_exception(exc)
+
+    def _under_step(self, step, made_ahead):   # hot-path
+        """The part of a turn that needs no token, run between the decode
+        step's jit call and the wait for its tokens (ISSUE 37; the
+        ``ahead.*`` phases): the step's own counters and its effect on
+        the lanes by count; the tokens of the step BEFORE to their lanes;
+        the queue shed and admitted; then the next turn's prompt chunk
+        (the round robin as it is) and decode step with every argument
+        but ``last`` on the device, kept in ``_ahead`` for the turn that
+        begins when this step's tokens arrive."""
+        rec = self.recorder
+        rec.mark(tracing.AHEAD_EMIT)
+        self._note_step(step, made_ahead)
+        self._advance_by_count(step.pairs)
+        self._deliver()
+        rec.mark(tracing.AHEAD_ADMIT)
+        busy = self._admit_turn()
+        rec.mark(tracing.AHEAD_PREPARE)
+        if not busy:
+            return
+        chunk = None
+        prefilling = [i for i in busy if self._lanes[i].pending]
+        picked = self._pick_prefill(prefilling) if prefilling else None
+        if picked is not None:
+            slot, lane = picked
+            chunk = self._prepare_chunk_paged(slot, lane, lane.request)
+        active = [i for i, lane in enumerate(self._lanes)
+                  if lane is not None and not lane.pending
+                  # (a tail chunk's first token may be its lane's last)
+                  and (chunk is None or lane is not chunk.lane
+                       or lane.remaining > 1)]
+        nxt = self._prepare_step(active) if active else None
+        with self._cond:
+            queued = len(self._queue)
+        self._ahead = _Ahead(chunk, nxt, self._lanes_gen, len(busy), queued)
 
     def _step_speculative(self, active):   # hot-path
         """ONE verify dispatch advances every active lane by 1..k+1
@@ -3164,7 +3448,7 @@ class LMEngine(Logger):
         self.metrics.record_dispatch(len(active))
         self.metrics.record_decode_step(time.monotonic() - t0)
         self.metrics.inc("decode_dispatches")
-        self._note_attn_dispatch(self._pos, w, k + 1)
+        self._note_attn_dispatch(self._attn_page_steps(self._pos, w, k + 1))
         if self._tracer is not None:
             self._tracer.add_many(
                 tctxs, "decode.verify", "decode", t0, time.monotonic(),
@@ -3292,7 +3576,8 @@ class LMEngine(Logger):
         self.metrics.record_decode_step(t1 - t0)
         self.metrics.inc("decode_dispatches")
         self.metrics.record_megastep(K, len(active), total, wasted)
-        self._note_attn_dispatch(entered, w, k + 1, calls=K)
+        self._note_attn_dispatch(self._attn_page_steps(entered, w, k + 1),
+                                 calls=K)
         if self._tracer is not None:
             # ONE decode.megastep span per dispatch, shared did so the
             # cost ledger counts the fused program once — never the
@@ -3361,8 +3646,87 @@ class LMEngine(Logger):
         with xfer.guard():
             self._serve_loop()
 
+    def _admit_turn(self):   # hot-path
+        """A turn's admission, wherever the turn does it (``loop.admit``,
+        or ``ahead.admit`` under the step in flight): the boundary sweep
+        (one pass per loop turn = per megastep when fused decode is on)
+        sheds EVERY expired queued request now, not just those the
+        admission loop happens to pop; then the free slots fill.  Returns
+        the slots that hold a request."""
+        self._boundary_shed()
+        self._admit()
+        busy = [i for i, lane in enumerate(self._lanes)
+                if lane is not None]
+        self.metrics.set_gauge("slots_busy", len(busy))
+        self.metrics.set_gauge_max("slots_busy_peak", len(busy))
+        return busy
+
+    def _drop_ahead(self):
+        """What was prepared for the next turn is not used (counted in
+        ``ahead_discarded``).  A chunk not yet dispatched goes back to the
+        head of its lane's pending list, if the lane is still there; its
+        guards and slides stand (both are idempotent), its arguments are
+        made again."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is None:
+            return
+        self.metrics.inc("ahead_discarded")
+        chunk = ahead.chunk
+        if chunk is not None and self._lanes[chunk.slot] is chunk.lane:
+            chunk.lane.pending.insert(
+                0, (chunk.tokens, chunk.start, chunk.is_tail))
+            self._pos[chunk.slot] = chunk.start
+
+    def _take_ahead(self):   # hot-path
+        """The turn prepared under the step before, if it still holds:
+        no lane has left its slot since (a failed fetch, a tick fault) and
+        none was withdrawn (a pending weight swap has dropped it already,
+        :meth:`_maybe_apply_swap`).  Else it is dropped and the turn runs
+        in the old order, from the lanes as they are."""
+        ahead = self._ahead
+        if ahead is None:
+            return None
+        if ahead.gen != self._lanes_gen \
+                or any(lane is not None and lane.request.cancelled
+                       for lane in self._lanes):
+            self._drop_ahead()
+            return None
+        return ahead
+
+    def _take_step(self, active):   # hot-path
+        """The decode step prepared ahead for exactly the lanes ``active``
+        (the lanes that decode NOW), or None: none was prepared, or the
+        lanes moved after it was (the turn's chunk failed, or its first
+        token was its request's last)."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is None or ahead.step is None:
+            return None
+        if ahead.gen != self._lanes_gen \
+                or [slot for slot, _ in ahead.step.pairs] != active:
+            self.metrics.inc("ahead_discarded")
+            return None
+        return ahead.step
+
     def _serve_loop(self):   # hot-path
-        rr = 0
+        """The engine's worker loop: one turn dispatches at most one
+        prompt chunk and one decode program.
+
+        A turn begins when the step before has its tokens on the host
+        (``rec.turn()``).  The old order, which every driver takes when
+        no step was in flight (an idle engine, only prefilling lanes, the
+        first turn) and the speculative driver, the megastep and the
+        contiguous layout take always: tick (fault site, weight swap) ->
+        the tokens owed to the lanes -> shed and admit -> one prompt
+        chunk's arguments and jit call -> the decode step's arguments,
+        jit call, wait, emit.  The paged plain driver does everything of
+        that which needs no token UNDER its step (:meth:`_under_step`,
+        between the jit call's return and the wait), so the turn after is
+        tick -> the prepared chunk's jit call -> the put of ``last`` ->
+        the step's jit call -> [under the step: deliver, shed and admit,
+        prepare] -> wait -> ``_last``.  Which it is, the loop reads off
+        ``_ahead``: whatever a driver left there is used if it still
+        holds (:meth:`_take_ahead`), and a driver that cannot split its
+        turn leaves nothing."""
         rec = self.recorder
         while True:
             # the recorder's turn (ISSUE 26): the phases marked below
@@ -3379,50 +3743,54 @@ class LMEngine(Logger):
                     # a raised tick fault poisons the whole engine
                     # loop's turn: fail the in-flight lanes (the
                     # fault-isolation discipline) and keep ticking
+                    self._drop_ahead()
                     self._fail_active(
                         [i for i, ln in enumerate(self._lanes)
                          if ln is not None], e)
             self._maybe_apply_swap()
-            rec.mark(tracing.ADMIT)
-            # the boundary sweep (one pass per loop turn = per
-            # megastep when fused decode is on): sheds EVERY expired
-            # queued request now, not just those the admission loop
-            # happens to pop
-            self._boundary_shed()
-            self._admit()
-            busy = [i for i, lane in enumerate(self._lanes)
-                    if lane is not None]
-            self.metrics.set_gauge("slots_busy", len(busy))
-            self.metrics.set_gauge_max("slots_busy_peak", len(busy))
-            # lint: allow(lock-discipline): the recorder takes no lock; len() of a deque is one atomic read
-            rec.lanes(len(busy), len(self._queue))
-            if not busy:
-                rec.mark(tracing.WAIT)
-                with self._cond:
-                    if self._stop:
-                        break
-                    if not self._queue:
-                        self._cond.wait(0.5)
-                    elif self._pool_blocked:
-                        # head request waiting on pages with no lane
-                        # running to free any: only trie eviction or
-                        # its deadline can resolve it — poll briefly so
-                        # the shed fires on time without a hot spin
-                        self._cond.wait(0.05)
-                continue
-            # chunked prefill interleaving: at most ONE prompt chunk per
-            # tick (round-robin across prefilling lanes), then one
-            # decode dispatch for the lanes that are past prefill — a
-            # long prompt costs the decode lanes one chunk of latency
-            # per token, never its whole prefill
-            prefilling = [i for i in busy if self._lanes[i].pending]
-            if prefilling:
-                rec.mark(tracing.PREFILL_PREPARE)
-                rr += 1
-                self._advance_prefill(prefilling[rr % len(prefilling)])
+            ahead = self._take_ahead()
+            if ahead is None:
+                self._deliver()
+                rec.mark(tracing.ADMIT)
+                busy = self._admit_turn()
+                # lint: allow(lock-discipline): the recorder takes no lock; len() of a deque is one atomic read
+                rec.lanes(len(busy), len(self._queue))
+                if not busy:
+                    rec.mark(tracing.WAIT)
+                    with self._cond:
+                        if self._stop:
+                            break
+                        if not self._queue:
+                            self._cond.wait(0.5)
+                        elif self._pool_blocked:
+                            # head request waiting on pages with no lane
+                            # running to free any: only trie eviction or
+                            # its deadline can resolve it — poll briefly so
+                            # the shed fires on time without a hot spin
+                            self._cond.wait(0.05)
+                    continue
+                # chunked prefill interleaving: at most ONE prompt chunk
+                # per tick (round-robin across prefilling lanes), then one
+                # decode dispatch for the lanes that are past prefill — a
+                # long prompt costs the decode lanes one chunk of latency
+                # per token, never its whole prefill
+                prefilling = [i for i in busy if self._lanes[i].pending]
+                if prefilling:
+                    rec.mark(tracing.PREFILL_PREPARE)
+                    picked = self._pick_prefill(prefilling)
+                    if picked is not None:
+                        self._advance_prefill(*picked)
+            else:
+                # admitted, chosen and prepared under the step before:
+                # the chunk goes out at once
+                rec.lanes(ahead.busy, ahead.queued)
+                chunk, ahead.chunk = ahead.chunk, None
+                if chunk is not None:
+                    self._dispatch_chunk_paged(chunk)
             rec.mark(tracing.STEP_PREPARE)
             active = [i for i, lane in enumerate(self._lanes)
                       if lane is not None and not lane.pending]
+            step = self._take_step(active)
             if not active:
                 continue
             if self._megastep_jit is not None:
@@ -3430,7 +3798,7 @@ class LMEngine(Logger):
             elif self._verify_jit is not None:
                 self._step_speculative(active)
             else:
-                self._step_plain(active)
+                self._step_plain(active, step)
         rec.close()
         # drain: engine stopping fails whatever is still queued
         with self._cond:

@@ -727,11 +727,28 @@ def format_waterfall(record, width=40):
 #: to stamp ``i + 1`` of the turn's record, a phase the turn skipped has
 #: no length, and a turn ends at the instant the next one begins.  A phase
 #: includes any wait for the interpreter lock inside it.
+#:
+#: A turn begins when the step before has its tokens on the host.  The
+#: ``loop.*``, ``prefill.*`` and ``step.*`` phases are what the device may
+#: wait for: the tick, admission, a prompt chunk's arguments and its jit
+#: call, the decode step's arguments, its jit call, the wait for its tokens
+#: and what the next dispatch needs of them.  The ``ahead.*`` phases
+#: (ISSUE 37) lie between the step's jit call and the wait, so the device
+#: runs the step under them: the tokens of the step BEFORE go to their
+#: lanes (``ahead.emit``), the queue is shed and admitted
+#: (``ahead.admit``), and the next turn's chunk and step get every
+#: argument that needs no token (``ahead.prepare``).  A turn that follows
+#: such a stretch skips ``loop.admit`` and ``prefill.prepare`` and its
+#: ``step.prepare`` is one put; a driver that cannot split its turn (the
+#: speculative one, the megastep, the contiguous layout) leaves the three
+#: empty and every turn in the old order.
 PHASES = ("loop.tick", "loop.admit", "loop.wait", "prefill.prepare",
           "prefill.dispatch", "step.prepare", "step.dispatch",
+          "ahead.emit", "ahead.admit", "ahead.prepare",
           "step.fetch", "step.emit")
 (TICK, ADMIT, WAIT, PREFILL_PREPARE, PREFILL_DISPATCH, STEP_PREPARE,
- STEP_DISPATCH, STEP_FETCH, STEP_EMIT) = range(len(PHASES))
+ STEP_DISPATCH, AHEAD_EMIT, AHEAD_ADMIT, AHEAD_PREPARE, STEP_FETCH,
+ STEP_EMIT) = range(len(PHASES))
 
 #: columns of a turn record (one int64 row of ``LoopRecorder.turns()``):
 #: the sequence number (from 1), the ``len(PHASES) + 1`` stamps
