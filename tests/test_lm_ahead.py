@@ -178,7 +178,7 @@ def test_a_turns_row_keeps_its_shape(driver):
         < tracing.PHASES.index("step.fetch") \
         < tracing.PHASES.index("step.emit")
     assert {e["name"] for e in rec.chrome_events(1, last=len(turns))
-            if e["ph"] == "X"} <= set(tracing.PHASES)
+            if e["ph"] == "X" and e["tid"] == 1} <= set(tracing.PHASES)
     step = turns[:, tracing.COL_STEP_PROGRAM] > 0
     chunk = turns[:, tracing.COL_PREFILL_PROGRAM] > 0
     assert int(step.sum()) == c["decode_dispatches"]
@@ -211,6 +211,41 @@ def test_a_turns_row_keeps_its_shape(driver):
         assert r.enqueue <= r.admit <= r.first_token <= r.done
     assert sum(r.tokens_out for r in reqs) == c["tokens_out"] \
         == int(turns[:, tracing.COL_TOKENS].sum())
+
+
+@pytest.mark.parametrize("kind", ["pre_ln", "window", "latent", "linear"])
+def test_every_dispatch_has_one_record_under_prepared_turns(kind):
+    """ISSUE 38: through stretches of turns prepared under the step before
+    (most of them: ``turns_prepared_ahead``), every call of a jitted program
+    still has exactly one dispatch record, in call order; each was fetched,
+    if at all, in the turn that called it; and the step's record lies over
+    the ``ahead.*`` phases of its turn (called before them, waited for
+    behind them)."""
+    t = tracing
+    round_ = [(n, max(n_new, 2)) for n, n_new in ROUND]
+    engine = make_engine(kind, name="rows_" + kind)
+    serve(engine, round_)
+    rec, c = engine.recorder, counters(engine)
+    assert c["turns_prepared_ahead"] > c["decode_dispatches"] // 2
+    rows, turns = rec.dispatches(), rec.turns()
+    assert len(rows) == c["decode_dispatches"] + c["prefill_dispatches"]
+    assert rows[:, t.DCOL_SEQ].tolist() == list(range(1, len(rows) + 1))
+    assert (numpy.diff(rows[:, t.DCOL_CALL]) > 0).all()
+    fetched = rows[:, t.DCOL_FETCHED] > 0
+    assert (rows[fetched, t.DCOL_FETCH_TURN] == rows[fetched, t.DCOL_TURN]).all()
+    step = rows[:, t.DCOL_PHASE] == t.STEP_DISPATCH
+    assert fetched[step].all()
+    assert int((~step & fetched).sum()) == len(round_)      # the tails
+    # one chunk and one step a turn at most, the chunk first
+    for of in (step, ~step):
+        assert len(set(rows[of, t.DCOL_TURN].tolist())) == int(of.sum())
+    assert (numpy.diff(rows[:, t.DCOL_TURN]) >= 0).all()
+    s = stamps_of(turns[rows[step, t.DCOL_TURN] - 1])
+    assert (rows[step, t.DCOL_CALL] == s[:, t.STEP_DISPATCH]).all()
+    assert (rows[step, t.DCOL_RETURNED] <= s[:, t.AHEAD_EMIT]).all()
+    assert (rows[step, t.DCOL_WAIT] == s[:, t.STEP_FETCH]).all()
+    assert (rows[step, t.DCOL_FETCHED] == s[:, t.STEP_EMIT]).all()
+    assert (s[:, t.STEP_FETCH] > s[:, t.AHEAD_EMIT]).all()
 
 
 # ------------------------------------- (e) what the first counter counts

@@ -654,6 +654,84 @@ class TestLoopRecorder:
         with pytest.raises(ValueError, match="power of two"):
             tracing.LoopRecorder("odd", capacity=12)
 
+    def test_dispatch_ring_wraps_and_keeps_the_newest(self):
+        """ISSUE 38: the dispatch ring holds twice the turns' capacity (a
+        turn makes at most two dispatches), wraps like the turn ring, and
+        a handle writes into the row it names."""
+        from veles_tpu.serving import tracing
+        rec = tracing.LoopRecorder("dwrap", capacity=4)
+        handles = []
+        for n in range(21):
+            rec.turn()
+            handles.append(rec.dispatch(
+                tracing.STEP_DISPATCH, _program_stub, lanes=n + 1))
+            rec.returned(handles[-1])
+        rec.fetched(handles[-2])            # the fetch one dispatch late
+        rec.close()
+        assert handles == list(range(1, 22)) and rec.dispatch_head == 21
+        rows = rec.dispatches()
+        assert rows.shape[1] == tracing.DISPATCH_WIDTH
+        # the newest, less the one place the writer may be filling now
+        assert rows[:, tracing.DCOL_SEQ].tolist() == list(range(15, 22))
+        assert rows[:, tracing.DCOL_LANES].tolist() == list(range(15, 22))
+        assert rows[:, tracing.DCOL_TURN].tolist() == list(range(15, 22))
+        assert (rows[:, tracing.DCOL_RETURNED]
+                >= rows[:, tracing.DCOL_CALL]).all()
+        assert rec.dispatches(last=2)[:, tracing.DCOL_SEQ].tolist() \
+            == [20, 21]
+        late, newest = rec.dispatches(last=2)
+        assert late[tracing.DCOL_FETCHED] >= newest[tracing.DCOL_RETURNED]
+        assert late[tracing.DCOL_FETCH_TURN] == 21 \
+            and late[tracing.DCOL_TURN] == 20
+        assert newest[tracing.DCOL_FETCHED] == 0 \
+            == newest[tracing.DCOL_FETCH_TURN]
+
+    @pytest.mark.parametrize("ring", ["turns", "dispatches"])
+    @pytest.mark.parametrize("when", ["during", "before"])
+    def test_a_copy_taken_under_the_writer_drops_replaced_rows(self, ring,
+                                                                when):
+        """The reader's rule, for both rings: a row the writer replaced
+        while the copy ran (``during``: it wrote on behind the copy;
+        ``before``: it had committed rows the first reading of the head
+        did not count yet) is dropped, never handed out under the
+        sequence number of the row it replaced."""
+        from veles_tpu.serving import tracing
+        rec = tracing.LoopRecorder("torn", capacity=8)
+
+        def write(n):
+            for _ in range(n):
+                rec.turn()
+                rec.dispatch(tracing.STEP_DISPATCH, _program_stub, lanes=1)
+                rec.dispatch(tracing.STEP_DISPATCH, _program_stub, lanes=1)
+            rec.close()
+
+        write(10)
+        store, head = {"turns": (rec._ring, lambda: rec.head),
+                       "dispatches": (rec._dring,
+                                      lambda: rec.dispatch_head)}[ring]
+        size = len(store)
+        h0 = head()
+        reads = []
+
+        def moving_head():
+            reads.append(1)
+            if len(reads) == 1:
+                if when == "before":
+                    write(3)
+                return h0
+            if when == "during":
+                write(3)
+            return head()
+
+        got = tracing._ring_copy(store, moving_head, None)[:, 0].tolist()
+        h1 = head()
+        assert h1 > h0
+        # what the writer replaced, or may be replacing now, is gone; what
+        # it had not committed when the copy began is not handed out
+        assert got == list(range(h1 + 2 - size, h0 + 1))
+        whole = tracing._ring_copy(store, head, None)
+        assert whole[:, 0].tolist() == list(range(h1 + 2 - size, h1 + 1))
+
     def test_skipped_phases_have_no_length(self):
         """A turn that went tick -> admit -> wait: every later phase
         starts and ends at the turn's end; the next turn starts there."""
@@ -735,6 +813,147 @@ class TestLoopRecorder:
             assert r.first_token == r.token_ns[0]
             assert 0 <= r.lane < engine.slots
         assert sum(r.tokens_out for r in reqs) == counters["tokens_out"]
+
+    @pytest.mark.parametrize("driver", sorted(DRIVERS) + ["contiguous"])
+    def test_every_dispatch_has_a_record_of_its_own(self, driver):
+        """ISSUE 38, per decode driver and for the chunked contiguous
+        layout: one dispatch record per call of a jitted program (they
+        number the two dispatch counters; the contiguous layout's
+        whole-prompt prefill at admission has no phase and no record),
+        numbered in call order; its stamps never go back; a decode
+        dispatch and a tail chunk were waited for and fetched in the turn
+        that called them, a chunk that is no tail never; and each names
+        the turn whose row holds its program and its call's stamp."""
+        from veles_tpu.serving import tracing as t
+        kw = self.DRIVERS.get(driver, dict(prefill_chunk=8))
+        engine, outs = self._serve(**kw)
+        for p, out in zip(self.PROMPTS, outs):
+            assert_greedy(engine, p, out, self.N_NEW)
+        rec = engine.recorder
+        counters = engine.metrics.snapshot()["counters"]
+        rows, turns = rec.dispatches(), rec.turns()
+        assert len(rows) == rec.dispatch_head \
+            == counters["decode_dispatches"] + counters["prefill_dispatches"]
+        assert rows[:, t.DCOL_SEQ].tolist() == list(range(1, len(rows) + 1))
+        call, back, wait, got = (rows[:, c] for c in (
+            t.DCOL_CALL, t.DCOL_RETURNED, t.DCOL_WAIT, t.DCOL_FETCHED))
+        assert (numpy.diff(call) > 0).all()
+        assert (call <= back).all()
+        waited = wait > 0
+        assert ((got > 0) == waited).all()
+        assert (back[waited] <= wait[waited]).all() \
+            and (wait[waited] <= got[waited]).all()
+        # a dispatch is over before the next is called: today's order
+        assert (numpy.maximum(back, got)[:-1] <= call[1:]).all()
+        step = rows[:, t.DCOL_PHASE] == t.STEP_DISPATCH
+        chunk = rows[:, t.DCOL_PHASE] == t.PREFILL_DISPATCH
+        assert (step | chunk).all()
+        assert int(step.sum()) == counters["decode_dispatches"]
+        assert waited[step].all() and (rows[step, t.DCOL_LANES] >= 1).all()
+        assert not rows[chunk, t.DCOL_LANES].any()
+        # every request has one tail chunk, whose token is its first: it
+        # is stamped between that chunk's fetch and the next call
+        tails = numpy.flatnonzero(chunk & waited)
+        assert len(tails) == len(self.PROMPTS) < int(chunk.sum())
+        firsts = sorted(r.first_token for r in rec.requests())
+        for i, first in zip(tails, firsts):
+            assert got[i] <= first
+            assert i + 1 == len(rows) or first < call[i + 1]
+        assert (rows[waited, t.DCOL_FETCH_TURN]
+                == rows[waited, t.DCOL_TURN]).all()
+        assert not rows[~waited, t.DCOL_FETCH_TURN].any()
+        # the span that caused it: the turn's row
+        assert turns[:, t.COL_SEQ].tolist() == list(range(1, len(turns) + 1))
+        mine = turns[rows[:, t.DCOL_TURN] - 1]
+        for phase, col in ((t.PREFILL_DISPATCH, t.COL_PREFILL_PROGRAM),
+                           (t.STEP_DISPATCH, t.COL_STEP_PROGRAM)):
+            of = rows[:, t.DCOL_PHASE] == phase
+            assert (mine[of, col] == rows[of, t.DCOL_PROGRAM]).all()
+            assert (mine[of, t.COL_STAMPS + phase] == call[of]).all()
+            # at most one of a kind a turn
+            assert len(set(rows[of, t.DCOL_TURN].tolist())) == int(of.sum())
+        assert (mine[step, t.COL_STAMPS + t.STEP_FETCH] == wait[step]).all()
+        assert (mine[step, t.COL_STAMPS + t.STEP_EMIT] == got[step]).all()
+        assert (mine[step, t.COL_ACTIVE] == rows[step, t.DCOL_LANES]).all()
+        assert {rec.programs[i] for i in rows[chunk, t.DCOL_PROGRAM]} \
+            == {"chunk_slot"}
+
+    @pytest.mark.parametrize("where", ["engine.step", "engine.chunk",
+                                       "call", "fetch"])
+    def test_a_dispatch_that_raises_leaves_its_record_as_it_was(
+            self, where, monkeypatch):
+        """A fault point fires before the jit call, so the dispatch it
+        stops has no record and the records still number the counters; a
+        program that raises in its call leaves a record with the call's
+        stamp alone, one whose fetch raises leaves ``fetched`` 0.  No
+        handle leaks: the next dispatch takes the next row, and it is
+        sound."""
+        from veles_tpu.serving import FaultPlan, lm_engine, tracing as t
+        kw = dict(prefill_chunk=8, paged_kv=True)
+        if where.startswith("engine."):
+            kw["faults"] = FaultPlan(seed=0).arm(where, kind="error",
+                                                 calls={2})
+        engine = self._engine(name="rec_raise", **kw).start()
+        boom = RuntimeError("raised in the %s" % where)
+        if where == "call":
+            real, calls = engine._step_jit, []
+
+            def step_all(*args):
+                calls.append(1)
+                if len(calls) == 2:
+                    raise boom
+                return real(*args)
+            step_all.__name__ = real.__name__
+            engine._step_jit = step_all
+        elif where == "fetch":
+            real_fetch, fetches = lm_engine.xfer.to_host, []
+
+            def to_host(x):
+                if isinstance(x, tuple):        # a decode step's outputs
+                    fetches.append(1)
+                    if len(fetches) == 2:
+                        raise boom
+                return real_fetch(x)
+            monkeypatch.setattr(lm_engine.xfer, "to_host", to_host)
+        try:
+            # (twelve tokens: two chunks, so engine.chunk's second call
+            # is this request's)
+            fut = engine.submit(list(range(1, 13)), 6)
+            with pytest.raises(Exception, match="raised|injected"):
+                fut.result(timeout=60)
+            ok = engine.submit([1, 2, 3], 4).result(timeout=60)
+        finally:
+            engine.stop()
+        assert_greedy(engine, [1, 2, 3], ok, 4)
+        c = engine.metrics.snapshot()["counters"]
+        rows = engine.recorder.dispatches()
+        assert rows[:, t.DCOL_SEQ].tolist() == list(range(1, len(rows) + 1))
+        done = c["decode_dispatches"] + c["prefill_dispatches"]
+        stamps = rows[:, t.DCOL_CALL:t.DCOL_FETCHED + 1]
+        open_ = numpy.flatnonzero(
+            (rows[:, t.DCOL_PHASE] == t.STEP_DISPATCH) & (stamps[:, 3] == 0))
+        if where.startswith("engine."):
+            assert len(rows) == done and not len(open_)
+        else:
+            # (the paged driver counts a step when its call is back,
+            # under the step: the one whose fetch raised is counted)
+            assert len(rows) == done + (where == "call")
+            (i,) = open_.tolist()
+            assert i + 1 < len(rows)
+            call, back, wait, got = stamps[i].tolist()
+            assert call > 0 and got == 0 == rows[i, t.DCOL_FETCH_TURN]
+            assert (back, wait) == (0, 0) if where == "call" \
+                else call <= back <= wait
+        # every other row is sound, the one behind the failure too
+        sound = numpy.ones(len(rows), bool)
+        sound[open_] = False
+        s = stamps[sound]
+        assert (s[:, 0] > 0).all() and (s[:, 0] <= s[:, 1]).all()
+        fetched = s[:, 3] > 0
+        assert (s[fetched, 1] <= s[fetched, 2]).all() \
+            and (s[fetched, 2] <= s[fetched, 3]).all()
+        assert (s[~fetched, 2] == 0).all()
+        assert (numpy.diff(rows[:, t.DCOL_CALL]) > 0).all()
 
     @pytest.mark.parametrize("driver", sorted(DRIVERS))
     def test_page_steps_of_every_driver_reach_the_reader(self, driver):
@@ -944,6 +1163,30 @@ class TestLoopRecorder:
         assert {e["name"] for e in loop} <= set(tracing.PHASES)
         assert any(e["name"] == "step.dispatch"
                    and e["args"]["program"] == "step_one" for e in loop)
+        # ISSUE 38: the dispatches of those turns on a track of their own,
+        # a slice each from the call to the fetch, named by program
+        sent = [e for e in trace["traceEvents"] if e["ph"] == "X"
+                and e["tid"] == tracks["engine loop rec_http dispatches"]]
+        assert tracks["engine loop rec_http dispatches"] \
+            == tracks["engine loop rec_http"] + 1
+        assert {e["name"] for e in sent} <= set(engine.recorder.programs[1:])
+        assert any(e["name"] == "step_one" for e in sent)
+        shown = {e["args"]["turn"] for e in loop}
+        for e in sent:
+            assert set(e["args"]) == {"dispatch", "turn", "fetch_turn",
+                                      "lanes"}
+            assert e["args"]["turn"] in shown and e["dur"] > 0
+            if e["name"] == "step_one":
+                assert e["args"]["fetch_turn"] == e["args"]["turn"]
+                assert e["args"]["lanes"] == 1
+                # over the phases of its turn from step.dispatch on
+                inside = [p for p in loop
+                          if p["args"]["turn"] == e["args"]["turn"]
+                          and p["name"] in ("step.dispatch", "step.fetch")]
+                assert inside and all(
+                    e["ts"] - 1 <= p["ts"]
+                    and p["ts"] + p["dur"] <= e["ts"] + e["dur"] + 1
+                    for p in inside)
         # a request's decode.step spans lie inside the loop track's span
         steps = [e for e in trace["traceEvents"] if e["ph"] == "X"
                  and e["name"] == "decode.step"]

@@ -467,8 +467,9 @@ def scenario_traced_flight_recorder(params, n_heads, max_len, prompts,
 def recorder_turn_cost(slots, turns=20000):
     """Seconds the always-on loop recorder (ISSUE 26) costs per turn of
     the engine loop: every call a decode turn with one prefill chunk
-    makes (turn, ten marks, two dispatches, the lane counts, one token
-    stamp per lane), timed over ``turns`` turns on this host."""
+    makes (turn, six marks, two dispatches with the stamps of their
+    own records (ISSUE 38: returned, waiting, fetched), the lane counts,
+    one token stamp per lane), timed over ``turns`` turns on this host."""
     from veles_tpu.serving import tracing
 
     class _Req:
@@ -486,16 +487,20 @@ def recorder_turn_cost(slots, turns=20000):
         rec.mark(tracing.ADMIT)
         rec.lanes(slots, 0)
         rec.mark(tracing.PREFILL_PREPARE)
-        rec.dispatch(tracing.PREFILL_DISPATCH, program)
+        chunk = rec.dispatch(tracing.PREFILL_DISPATCH, program)
+        rec.returned(chunk)
+        rec.waiting(chunk)          # (a tail chunk: the dearest kind)
+        rec.fetched(chunk)
         rec.mark(tracing.STEP_PREPARE)
-        rec.dispatch(tracing.STEP_DISPATCH, program, slots)
+        step = rec.dispatch(tracing.STEP_DISPATCH, program, slots)
+        rec.returned(step)
         rec.mark(tracing.AHEAD_EMIT)
         for _lane in range(slots):
             rec.emitted(req, 1)
         rec.mark(tracing.AHEAD_ADMIT)
         rec.mark(tracing.AHEAD_PREPARE)
-        rec.mark(tracing.STEP_FETCH)
-        rec.mark(tracing.STEP_EMIT)
+        rec.waiting(step, tracing.STEP_FETCH)
+        rec.fetched(step, tracing.STEP_EMIT)
     rec.close()
     return (time.perf_counter() - t0) / turns
 

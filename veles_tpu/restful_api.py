@@ -249,7 +249,8 @@ class RESTfulAPI(Logger):
                     # the flight recorder as Chrome-trace/Perfetto JSON
                     # (ISSUE 12): ?last=N trims to the newest N
                     # requests; the served engines' loop recorders ride
-                    # along as `engine loop` tracks (ISSUE 26); load at
+                    # along as `engine loop` tracks (ISSUE 26), each
+                    # with its dispatches' track (ISSUE 38); load at
                     # ui.perfetto.dev
                     query = urllib.parse.parse_qs(split.query)
                     last = None
@@ -635,8 +636,9 @@ def serve_lm(workflow, host="127.0.0.1", port=8180, max_new=256,
     THE LOOP RECORDER (ISSUE 26) is on in every engine whatever
     ``trace`` says: ``serving/tracing.py::LoopRecorder`` keeps one
     record per turn of the engine loop (phases that partition the
-    turn), one per request (enqueue, admit, first token, done, a stamp
-    per emitted token) and one per HTTP POST, all on
+    turn), one per device dispatch (ISSUE 38), one per request
+    (enqueue, admit, first token, done, a stamp per emitted token) and
+    one per HTTP POST, all on
     ``time.monotonic_ns()``, with no lock, fence or transfer.
     ``tracing.recorders()`` returns them, after ``stop()`` too; the
     benchmark's per-layer readers are their consumer.
@@ -652,7 +654,8 @@ def serve_lm(workflow, host="127.0.0.1", port=8180, max_new=256,
     post-mortem of one request, with the engine loop's newest turns as
     an ``engine loop`` track beside them so that a request's
     ``decode.step`` spans stand above the loop phases that produced
-    them.
+    them, and those turns' dispatches on a track under it (a slice
+    each, from the jit call to the outputs' arrival on the host).
 
     CONTINUOUS TELEMETRY + SLOs (ISSUE 14, engine path only):
     ``telemetry=S`` starts a

@@ -78,11 +78,13 @@ request, this package amortizes dispatch across concurrent clients.
   attribute-is-None check per site (the ``faults.py`` discipline).
   The same module holds :class:`~tracing.LoopRecorder` (ISSUE 26),
   ON in every engine whatever ``--serve-trace`` says: one record per
-  turn of the engine loop (nine phases that partition it), one per
-  request with the stamp of every emitted token, one per HTTP POST,
-  all on ``time.monotonic_ns()``; no lock, no fence, no transfer.
-  ``tracing.recorders()`` keeps the newest four, stopped engines'
-  included; ``benchmark/lib/spans.py`` puts them on the device
+  turn of the engine loop (twelve phases that partition it), one per
+  device dispatch (ISSUE 38: program, turn, call, return, wait,
+  outputs on the host), one per request with the stamp of every
+  emitted token, one per HTTP POST, all on ``time.monotonic_ns()``;
+  no lock, no fence, no transfer.  ``tracing.recorders()`` keeps the
+  newest four, stopped engines' included; ``benchmark/lib/spans.py``
+  and ``benchmark/lib/dispatch_log.py`` put them on the device
   trace's clock.
 - :mod:`veles_tpu.serving.metrics` — :class:`ServingMetrics`:
   lock-cheap counters/histograms (queue wait, batch size, latency

@@ -2673,7 +2673,7 @@ class LMEngine(Logger):
         self.metrics.inc("moe_assignments_elsewhere", away)
         self.metrics.inc("moe_experts_hit", hit)
         self.metrics.set_gauge_max("moe_max_expert_load", load)
-        self.recorder.moe(held, away, hit, load)
+        self.recorder.moe(held, hit)
 
     def _attn_page_steps(self, pos, width, span=0, rows=slice(None)):
         """``(given, live)``: the page steps a dispatch hands the
@@ -2799,11 +2799,12 @@ class LMEngine(Logger):
                     xfer.to_device(slot, numpy.int32),
                     xfer.to_device(start, numpy.int32),
                     xfer.to_device(last_idx, numpy.int32))
-            self.recorder.dispatch(tracing.PREFILL_DISPATCH,
-                                   self._chunk_jit)
+            rec = self.recorder
+            sent = rec.dispatch(tracing.PREFILL_DISPATCH, self._chunk_jit)
             with self._donating():
                 self._caches, tok = self._chunk_jit(
                     self.params, self._caches, *args)
+                rec.returned(sent)
                 if not is_tail and self._trie is not None \
                         and lane.cursor is not None:
                     rows = self._chunk_extract_jit(
@@ -2819,7 +2820,9 @@ class LMEngine(Logger):
                                            self._trie.size)
                 self._tfence(self._caches, req.trace is not None)
                 if is_tail:      # the first token crosses in here too
+                    rec.waiting(sent)
                     tok = int(xfer.to_host(tok))
+                    rec.fetched(sent)
         except Exception as e:   # noqa: BLE001 — fails THIS request
             self.metrics.record_error()
             self.warning("chunk prefill failed: %s", e)
@@ -2933,18 +2936,21 @@ class LMEngine(Logger):
         t0 = time.monotonic()
         try:
             self._fault("engine.chunk")
-            self.recorder.dispatch(tracing.PREFILL_DISPATCH,
-                                   self._chunk_jit)
+            rec = self.recorder
+            sent = rec.dispatch(tracing.PREFILL_DISPATCH, self._chunk_jit)
             with self._donating():
                 self._kv_pools, tok = self._chunk_jit(
                     self.params, self._kv_pools, *args)
+                rec.returned(sent)
                 self._tfence(self._kv_pools, req.trace is not None)
                 # the device has the chunk to run: the tokens the last
                 # fetch brought reach their lanes now, not behind the
                 # wait for a tail chunk's token
                 self._deliver()
                 if is_tail:      # the first token crosses in here too
+                    rec.waiting(sent)
                     tok = int(xfer.to_host(tok))
+                    rec.fetched(sent)
             if not is_tail and self._trie is not None \
                     and lane.cursor is not None:
                 page = lane.pages[page_idx]
@@ -3164,21 +3170,24 @@ class LMEngine(Logger):
         follows it.  The recorder's ``step.dispatch`` spans the jit call
         until it returns, ``step.fetch`` the wait for the device and the
         copy out (an armed tracer's fence too, when a sampled lane rides
-        the dispatch), and ``step.emit`` opens as this returns."""
+        the dispatch), and ``step.emit`` opens as this returns; the
+        dispatch's own record (ISSUE 38) takes the same stamps, and the
+        jit call's return, under the handle ``sent``."""
         rec = self.recorder
-        rec.dispatch(tracing.STEP_DISPATCH, decode_jit, lanes)
+        sent = rec.dispatch(tracing.STEP_DISPATCH, decode_jit, lanes)
         with self._donating():
             out = decode_jit(self.params, self._storage(), *args)
+            rec.returned(sent)
             self._set_storage(out[0])
             if under is not None:
                 under()
-            rec.mark(tracing.STEP_FETCH)
+            rec.waiting(sent, tracing.STEP_FETCH)
             host = xfer.to_host(tuple(out[1:]))
             # (the storage as it is NOW: a copy-on-write made under the
             # step has donated ``out[0]`` on)
             self._tfence(self._storage(),
                          any(c is not None for c in tctxs))
-        rec.mark(tracing.STEP_EMIT)
+        rec.fetched(sent, tracing.STEP_EMIT)
         return host
 
     def _prepare_step(self, active):   # hot-path

@@ -69,7 +69,23 @@ benchmark's window and (through ``benchmark/lib/spans.py``'s fit) of
 the device trace.  One record per turn of ``LMEngine._serve_loop``
 (:data:`PHASES` partition the turn), one per finished request (with
 the stamp of every emitted token), one per HTTP POST
-(:func:`note_http`).  :func:`recorders` keeps the newest four of the
+(:func:`note_http`), and (ISSUE 38) one per DEVICE DISPATCH: every call
+of a jitted program by the engine's worker thread is a thing of its
+own there (a second ring, ``DCOL_*``), with the program, the turn whose
+code made the call, the lanes, and four stamps: the call, its return
+to the host, the moment the host began to wait for the outputs, the
+moment it had them, and the turn that was open then.  The engine holds
+a dispatch's handle with its outputs for as long as their fetch is
+outstanding, so the record says the same whether the outputs are
+fetched in the turn of the call, one dispatch late, or behind a
+program called in between; the turn row (two program columns, one
+``step.emit``) cannot.  Readers: ``GET /trace.json`` (a track of
+dispatch slices under each loop track),
+``benchmark/lib/dispatch_log.py`` (pairs the device trace's executions
+with the records by program and order, and splits the device's idle
+time by what the host was doing: waiting for outputs already made,
+inside or behind the next call, or at its own work).
+:func:`recorders` keeps the newest four of the
 process, stopped engines' included, so a reader that runs after
 ``api.stop()`` still finds them.  All span times of this module — the
 tracer's too — are on that one clock, against the process origin
@@ -516,10 +532,12 @@ class SpanTracer(Logger):
         Chrome-trace/Perfetto JSON object — one track (tid) per
         request, engine events on tid 0, ts/dur in microseconds.
         ``loops`` (:class:`LoopRecorder` objects — ``GET /trace.json``
-        passes the served engines') adds one ``engine loop`` track each
-        with the newest turns' phases, below the request tracks and on
-        the same clock: a request's ``decode.step`` spans stand above
-        the loop phases that produced them.  Load at
+        passes the served engines') adds two tracks each, ``engine loop
+        <name>`` with the newest turns' phases and ``engine loop <name>
+        dispatches`` with one slice per dispatch of those turns, below
+        the request tracks and on the same clock: a request's
+        ``decode.step`` spans stand above the loop phases that produced
+        them.  Load at
         https://ui.perfetto.dev or chrome://tracing."""
         recs = self.requests(last)
         with self._lock:
@@ -557,8 +575,8 @@ class SpanTracer(Logger):
                             "dur": round(max(0.0, (sp["t1"] or sp["t0"])
                                          - sp["t0"]) * 1e6, 1),
                             "args": args})
-        for i, loop in enumerate(loops):
-            out.extend(loop.chrome_events(len(recs) + 1 + i))
+        for i, loop in enumerate(loops):     # two tracks a loop
+            out.extend(loop.chrome_events(len(recs) + 1 + 2 * i))
         return {"traceEvents": out, "displayTimeUnit": "ms",
                 "otherData": {"tracer": self.name, "mode": self.mode,
                               "stats": self.stats()}}
@@ -767,22 +785,40 @@ COL_QUEUE = COL_END + 5
 COL_TOKENS = COL_END + 6
 #: the expert layers' counts of the turn's decode step (0 for a model
 #: without any; ``ops/moe.py::held_part``): assignments to experts this
-#: chip holds and to experts held elsewhere, experts hit (summed over the
-#: expert layers), the largest expert load of any layer
+#: chip holds, and experts hit (summed over the expert layers)
 COL_MOE_HELD = COL_END + 7
-COL_MOE_ELSEWHERE = COL_END + 8
-COL_MOE_HIT = COL_END + 9
-COL_MOE_LOAD = COL_END + 10
+COL_MOE_HIT = COL_END + 8
 #: the page steps the turn's dispatches handed the attention kernels
 #: (lanes x table width, summed over layers, chunk and decode program
 #: alike) and those of them whose page holds a key a query row may see
 #: (``ops/pallas_kernels.py::live_pages``); the others the kernels skip
-COL_ATTN_STEPS = COL_END + 11
-COL_ATTN_LIVE = COL_END + 12
-TURN_WIDTH = COL_END + 13
+COL_ATTN_STEPS = COL_END + 9
+COL_ATTN_LIVE = COL_END + 10
+TURN_WIDTH = COL_END + 11
 #: the column that holds the program a dispatch phase called
 _PROGRAM_COL = {PREFILL_DISPATCH: COL_PREFILL_PROGRAM,
                 STEP_DISPATCH: COL_STEP_PROGRAM}
+
+#: columns of a dispatch record (one int64 row of
+#: ``LoopRecorder.dispatches()``): one per call of a jitted program by the
+#: engine's worker thread, whatever turn its outputs are fetched in.
+#: ``DCOL_SEQ`` the dispatch's number, from 1, in call order (the order the
+#: device runs them); ``DCOL_TURN`` the ``COL_SEQ`` of the turn whose code
+#: made the call; ``DCOL_PROGRAM`` an index into ``LoopRecorder.programs``;
+#: ``DCOL_PHASE`` the dispatch phase it was called from (``PREFILL_DISPATCH``:
+#: a prompt chunk, ``STEP_DISPATCH``: a decode program, whatever its name);
+#: ``DCOL_LANES`` the lanes a decode dispatch advances, 0 for a chunk; then
+#: four stamps (``time.monotonic_ns()``, 0 where the dispatch never got
+#: that far): ``DCOL_CALL`` just before the jit call (the turn row's phase
+#: stamp: one clock read), ``DCOL_RETURNED`` the jit call back on the host,
+#: ``DCOL_WAIT`` the host began to wait for the dispatch's outputs (never,
+#: for a chunk that is no tail), ``DCOL_FETCHED`` the host had them (never,
+#: where it did not wait or the dispatch raised); ``DCOL_FETCH_TURN`` the
+#: ``COL_SEQ`` of the turn open at ``DCOL_FETCHED`` (``DCOL_TURN`` while
+#: every fetch falls in the turn of its call)
+(DCOL_SEQ, DCOL_TURN, DCOL_PROGRAM, DCOL_PHASE, DCOL_LANES, DCOL_CALL,
+ DCOL_RETURNED, DCOL_WAIT, DCOL_FETCHED, DCOL_FETCH_TURN) = range(10)
+DISPATCH_WIDTH = 10
 
 #: one finished (or failed, shed, cancelled) request: stamps in
 #: nanoseconds on the monotonic clock, 0 where the request never got
@@ -832,13 +868,38 @@ def http_records():
     return list(_http)
 
 
+def _ring_copy(ring, head, last):
+    """The rows a ring of sequence-numbered records (column 0, from 1;
+    ``head()`` the newest committed) holds, oldest first, as a copy that
+    the ring's one writer cannot tear: see :meth:`LoopRecorder.turns`."""
+    capacity = len(ring)
+    h0 = head()
+    used = min(h0, capacity)            # a ring not yet full: its head
+    rows = ring[:used].copy()
+    h1 = head()
+    seq = rows[:, 0]
+    # the writer replaced (h0, h1] while the copy ran and may be
+    # committing h1 + 1 now: what those slots held before is dropped
+    keep = (seq > 0) & (seq <= h0) & (seq > h1 + 1 - capacity) \
+        & (((seq - 1) & (capacity - 1)) == numpy.arange(used))
+    out = rows[keep]
+    out = out[numpy.argsort(out[:, 0], kind="stable")]
+    if last is not None:
+        out = out[len(out) - min(int(last), len(out)):]
+    return out
+
+
 class LoopRecorder:
     """The engine loop's always-on recorder; see the module docstring.
 
     ONE writer, the engine's worker thread, fills the turn ring: it
     builds the open turn in a scratch list and commits it to the ring as
     one row assignment, so a reader's copy never holds a half-written
-    turn.  Request records go to a bounded deque when the request's
+    turn.  The same thread fills the dispatch ring (``DCOL_*``): a row
+    goes in whole at the jit call, and each later stamp of it is one
+    aligned store into the row its handle names, so a reader finds a
+    dispatch in flight with the stamps it has and never a mix of two.
+    Request records go to a bounded deque when the request's
     future settles (the worker thread but for a client's own cancel of
     a queued request; a deque append needs no lock).  Nothing here takes
     a lock, touches the device, or depends on a :class:`SpanTracer`."""
@@ -864,6 +925,13 @@ class LoopRecorder:
         self._blank = (0,) * TURN_WIDTH
         #: turns committed so far (the newest record's sequence number)
         self.head = 0
+        #: the dispatch ring outlasts the turn ring: a turn makes at most
+        #: two dispatches
+        self._dmask = 2 * capacity - 1
+        self._dring = numpy.zeros((2 * capacity, DISPATCH_WIDTH),
+                                  numpy.int64)
+        #: dispatches recorded so far (the newest row's ``DCOL_SEQ``)
+        self.dispatch_head = 0
         #: program names by id; id 0 is "none dispatched"
         self.programs = [""]
         self._program_ids = {}
@@ -903,10 +971,14 @@ class LoopRecorder:
         self._cur[COL_STAMPS + phase] = time.monotonic_ns()
 
     def dispatch(self, phase, fn, lanes=0):
-        """:meth:`mark` for a dispatch phase: ``fn`` is the jitted
-        program about to be called (recorded by its name, the one the
-        device trace shows with ``jit_`` before it); ``lanes`` the lanes
-        a decode dispatch advances."""
+        """:meth:`mark` for a dispatch phase, and the dispatch's own
+        record: ``fn`` is the jitted program about to be called (recorded
+        by its name, the one the device trace shows with ``jit_`` before
+        it); ``lanes`` the lanes a decode dispatch advances.  Returns the
+        record's handle (its ``DCOL_SEQ``) for :meth:`returned`,
+        :meth:`waiting` and :meth:`fetched`; the engine may keep it with
+        the program's outputs for as long as their fetch is outstanding.
+        A dispatch that raises leaves its record with the stamps it had."""
         name = fn.__name__
         pid = self._program_ids.get(name)
         if pid is None:
@@ -916,18 +988,45 @@ class LoopRecorder:
         cur[_PROGRAM_COL[phase]] = pid
         if lanes:
             cur[COL_ACTIVE] = lanes
-        cur[COL_STAMPS + phase] = time.monotonic_ns()
+        t = cur[COL_STAMPS + phase] = time.monotonic_ns()
+        seq = self.dispatch_head + 1
+        self._dring[(seq - 1) & self._dmask] = (
+            seq, self.head + 1, pid, phase, lanes, t, 0, 0, 0, 0)
+        self.dispatch_head = seq
+        return seq
+
+    def returned(self, seq):
+        """The jit call of dispatch ``seq`` is back on the host."""
+        self._dring[(seq - 1) & self._dmask, DCOL_RETURNED] = \
+            time.monotonic_ns()
+
+    def waiting(self, seq, phase=None):
+        """The host begins to wait for the outputs of dispatch ``seq``;
+        with ``phase``, that phase of the open turn begins at the same
+        clock reading (:meth:`mark`)."""
+        t = time.monotonic_ns()
+        self._dring[(seq - 1) & self._dmask, DCOL_WAIT] = t
+        if phase is not None:
+            self._cur[COL_STAMPS + phase] = t
+
+    def fetched(self, seq, phase=None):
+        """The host has the outputs of dispatch ``seq``; ``phase`` as for
+        :meth:`waiting`."""
+        t = time.monotonic_ns()
+        row = self._dring[(seq - 1) & self._dmask]
+        row[DCOL_FETCH_TURN] = self.head + 1
+        row[DCOL_FETCHED] = t
+        if phase is not None:
+            self._cur[COL_STAMPS + phase] = t
 
     def lanes(self, busy, queued):
         self._cur[COL_BUSY] = busy
         self._cur[COL_QUEUE] = queued
 
-    def moe(self, held, elsewhere, hit, load):
+    def moe(self, held, hit):
         cur = self._cur
         cur[COL_MOE_HELD] = held
-        cur[COL_MOE_ELSEWHERE] = elsewhere
         cur[COL_MOE_HIT] = hit
-        cur[COL_MOE_LOAD] = load
 
     def attn_pages(self, given, live):
         """One more dispatch of the open turn through the attention
@@ -961,20 +1060,14 @@ class LoopRecorder:
         ``TURN_WIDTH`` columns (``COL_*``).  A record the writer replaced
         while the copy was taken, or whose sequence number is not the one
         its place in the ring calls for, is dropped."""
-        h0 = self.head
-        used = min(h0, self.capacity)       # a ring not yet full: its head
-        ring = self._ring[:used].copy()
-        h1 = self.head
-        seq = ring[:, COL_SEQ]
-        # the writer replaced (h0, h1] while the copy ran and may be
-        # committing h1 + 1 now: what those slots held before is dropped
-        keep = (seq > 0) & (seq <= h0) & (seq > h1 + 1 - self.capacity) \
-            & (((seq - 1) & self._mask) == numpy.arange(used))
-        out = ring[keep]
-        out = out[numpy.argsort(out[:, COL_SEQ], kind="stable")]
-        if last is not None:
-            out = out[len(out) - min(int(last), len(out)):]
-        return out
+        return _ring_copy(self._ring, lambda: self.head, last)
+
+    def dispatches(self, last=None):
+        """:meth:`turns`' twin over the dispatch ring: a copy of the
+        dispatch records kept, oldest first, ``DISPATCH_WIDTH`` columns
+        (``DCOL_*``), replaced and misplaced rows dropped alike.  The
+        newest rows may be dispatches in flight: later stamps still 0."""
+        return _ring_copy(self._dring, lambda: self.dispatch_head, last)
 
     def requests(self):
         """The request records kept, oldest first."""
@@ -982,12 +1075,19 @@ class LoopRecorder:
 
     def chrome_events(self, tid, last=256):
         """The newest ``last`` turns as Chrome-trace events on track
-        ``tid`` (one slice per phase that took time), microseconds
+        ``tid`` (one slice per phase that took time), and on track
+        ``tid + 1`` their dispatches (one slice each, named by program,
+        from its call to the moment the host had its outputs, or to the
+        jit call's return where it never fetched them: a dispatch in
+        flight lies over the ``ahead.*`` phases it covers); microseconds
         against the process origin like the tracer's spans."""
         origin = int(_ORIGIN * 1e9)
         out = [{"ph": "M", "pid": 1, "tid": tid, "name": "thread_name",
-                "args": {"name": "engine loop %s" % self.name}}]
-        for row in self.turns(last).tolist():
+                "args": {"name": "engine loop %s" % self.name}},
+               {"ph": "M", "pid": 1, "tid": tid + 1, "name": "thread_name",
+                "args": {"name": "engine loop %s dispatches" % self.name}}]
+        turns = self.turns(last).tolist()
+        for row in turns:
             args = {"turn": row[COL_SEQ], "busy": row[COL_BUSY],
                     "active": row[COL_ACTIVE], "queue": row[COL_QUEUE],
                     "tokens": row[COL_TOKENS]}
@@ -1002,4 +1102,18 @@ class LoopRecorder:
                 out.append({"ph": "X", "pid": 1, "tid": tid, "name": phase,
                             "cat": "loop", "ts": (t0 - origin) / 1e3,
                             "dur": (t1 - t0) / 1e3, "args": named})
+        first = turns[0][COL_SEQ] if turns else self.head + 1
+        for row in self.dispatches(2 * len(turns) + 2).tolist():
+            t0 = row[DCOL_CALL]
+            t1 = row[DCOL_FETCHED] or row[DCOL_RETURNED]
+            if row[DCOL_TURN] < first or t1 <= t0:
+                continue
+            out.append({"ph": "X", "pid": 1, "tid": tid + 1,
+                        "name": self.programs[row[DCOL_PROGRAM]],
+                        "cat": "dispatch", "ts": (t0 - origin) / 1e3,
+                        "dur": (t1 - t0) / 1e3,
+                        "args": {"dispatch": row[DCOL_SEQ],
+                                 "turn": row[DCOL_TURN],
+                                 "fetch_turn": row[DCOL_FETCH_TURN],
+                                 "lanes": row[DCOL_LANES]}})
         return out
