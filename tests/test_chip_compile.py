@@ -280,12 +280,14 @@ def program_text(fixture, program):
     if program == "chunk":
         lowered = engine._chunk_jit.lower(
             params, pools, tables(engine._max_pages),
-            ints(engine.prefill_chunk), ints(), ints())
+            ints(engine.prefill_chunk), ints(), ints(), ints(),
+            ints(engine.slots))
     else:
         width = int(program.rsplit("w", 1)[1])
         lowered = engine._step_jit.lower(
             params, pools, tables(engine.slots, width), ints(engine.slots),
-            ints(engine.slots))
+            ints(engine.slots), jax.ShapeDtypeStruct(
+                (engine.slots,), jnp.bool_, sharding=ints().sharding))
     text = _TEXTS[engine.name, program] = lowered.compile().as_text()
     assert "tpu_custom_call" in text
     return text

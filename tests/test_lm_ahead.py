@@ -10,6 +10,14 @@ changes in place; a fault, a cancel or a weight swap that lands between
 preparation and dispatch drops what was prepared and fails the right lanes
 only; and the two counters count what they say.
 
+ISSUE 39: two dispatches in flight.  The same driver keeps the lanes' last
+tokens on the device and waits for the tokens of step N only after it has
+called turn N+1's chunk and step.  Held here, as more cases of the same
+tests: the tokens through the pipelined loop, a tail chunk whose first token
+is its request's last, what lands with two dispatches in flight (a cancel, a
+swap either way, a checkpoint, faults), a fetch that raises with a younger
+dispatch in flight, and the two counters of the mechanism.
+
 No case asserts a duration: only order, counts, identities and tokens."""
 
 import functools
@@ -105,6 +113,16 @@ def counters(engine):
     return engine.metrics.snapshot()["counters"]
 
 
+def pipeline_balances(engine):
+    """Idle, nothing is left in flight, and every decode dispatch was either
+    followed by one sent ahead of its fetch or drained (ISSUE 39)."""
+    c = counters(engine)
+    assert not engine._flights and engine._older == 0
+    assert c.get("dispatches_sent_ahead", 0) + c.get("pipeline_drains", 0) \
+        == c["decode_dispatches"]
+    return c
+
+
 def stamps_of(turns):
     return turns[:, tracing.COL_STAMPS:tracing.COL_END + 1]
 
@@ -129,10 +147,13 @@ def test_the_reordered_loop_serves_the_references_tokens(kind):
         check_tokens(kind, engine, p, o, n_new)
     assert engine.verify_pool_invariants()["used_pages"] == 0
     assert engine._ahead is None and not engine._undelivered
-    c = counters(engine)
+    c = pipeline_balances(engine)
     assert c["tokens_out"] == sum(n for _, n in ROUND)
     assert c.get("kv_storage_rebuilds", 0) == 0
     assert c["turns_prepared_ahead"] > c["decode_dispatches"] // 2
+    assert c["dispatches_sent_ahead"] > c["decode_dispatches"] // 2
+    # every request's first token reached the host once, late or not
+    assert engine.metrics.snapshot()["ttft"]["count"] == len(ROUND)
     # the one-token answer ends at its tail chunk, after the step that took
     # its lane for a decoding one was prepared: that one preparation goes
     assert c.get("ahead_discarded", 0) <= 1
@@ -188,6 +209,23 @@ def test_a_turns_row_keeps_its_shape(driver):
     ahead = s[:, tracing.STEP_FETCH] - s[:, tracing.AHEAD_EMIT]
     assert not ahead[~step].any()
     splits = driver.startswith("plain")
+    rows = rec.dispatches()
+    of_step = rows[:, tracing.DCOL_PHASE] == tracing.STEP_DISPATCH
+    late = rows[:, tracing.DCOL_FETCH_TURN] - rows[:, tracing.DCOL_TURN]
+    assert (rows[:, tracing.DCOL_FETCHED] > 0)[of_step].all()
+    if splits:
+        # ISSUE 39: a step whose follower was sent ahead of its fetch is
+        # fetched in the follower's turn, a step that was drained in its own
+        c = pipeline_balances(engine)
+        assert int((late[of_step] == 1).sum()) \
+            == c["dispatches_sent_ahead"] > c["decode_dispatches"] // 2
+        assert int((late[of_step] == 0).sum()) == c["pipeline_drains"]
+        # and a tail chunk's token rides with the step behind it
+        assert set(late[~of_step & (rows[:, tracing.DCOL_FETCHED] > 0)]
+                   .tolist()) <= {0, 1}
+    else:
+        assert not late[rows[:, tracing.DCOL_FETCHED] > 0].any()
+        assert "dispatches_sent_ahead" not in c and "pipeline_drains" not in c
     if splits:
         assert (ahead[step] > 0).all()
         # a turn prepared under the step before skips admission and the
@@ -217,25 +255,35 @@ def test_a_turns_row_keeps_its_shape(driver):
 def test_every_dispatch_has_one_record_under_prepared_turns(kind):
     """ISSUE 38: through stretches of turns prepared under the step before
     (most of them: ``turns_prepared_ahead``), every call of a jitted program
-    still has exactly one dispatch record, in call order; each was fetched,
-    if at all, in the turn that called it; and the step's record lies over
-    the ``ahead.*`` phases of its turn (called before them, waited for
-    behind them)."""
+    still has exactly one dispatch record, in call order.  ISSUE 39: each
+    step was fetched in the turn that called it (a drain) or in the next
+    (its follower went out first), inside that turn's ``step.fetch``; a tail
+    chunk with the step behind it; and the step's record lies over the
+    ``ahead.*`` phases of its own turn (called before them)."""
     t = tracing
     round_ = [(n, max(n_new, 2)) for n, n_new in ROUND]
     engine = make_engine(kind, name="rows_" + kind)
     serve(engine, round_)
-    rec, c = engine.recorder, counters(engine)
+    rec, c = engine.recorder, pipeline_balances(engine)
     assert c["turns_prepared_ahead"] > c["decode_dispatches"] // 2
     rows, turns = rec.dispatches(), rec.turns()
     assert len(rows) == c["decode_dispatches"] + c["prefill_dispatches"]
     assert rows[:, t.DCOL_SEQ].tolist() == list(range(1, len(rows) + 1))
     assert (numpy.diff(rows[:, t.DCOL_CALL]) > 0).all()
     fetched = rows[:, t.DCOL_FETCHED] > 0
-    assert (rows[fetched, t.DCOL_FETCH_TURN] == rows[fetched, t.DCOL_TURN]).all()
+    late = rows[:, t.DCOL_FETCH_TURN] - rows[:, t.DCOL_TURN]
+    assert set(late[fetched].tolist()) == {0, 1}
     step = rows[:, t.DCOL_PHASE] == t.STEP_DISPATCH
     assert fetched[step].all()
+    assert int((late[step] == 1).sum()) == c["dispatches_sent_ahead"]
     assert int((~step & fetched).sum()) == len(round_)      # the tails
+    # fetched in call order, the order the device ran them
+    assert (numpy.diff(rows[fetched, t.DCOL_FETCHED]) > 0).all()
+    # a tail chunk is fetched with its own turn's step, or before it where
+    # the turn was not prepared (the old order begins with a drain)
+    for i in numpy.flatnonzero(~step & fetched).tolist():
+        assert step[i + 1] and rows[i + 1, t.DCOL_TURN] == rows[i, t.DCOL_TURN]
+        assert rows[i, t.DCOL_FETCH_TURN] <= rows[i + 1, t.DCOL_FETCH_TURN]
     # one chunk and one step a turn at most, the chunk first
     for of in (step, ~step):
         assert len(set(rows[of, t.DCOL_TURN].tolist())) == int(of.sum())
@@ -243,9 +291,19 @@ def test_every_dispatch_has_one_record_under_prepared_turns(kind):
     s = stamps_of(turns[rows[step, t.DCOL_TURN] - 1])
     assert (rows[step, t.DCOL_CALL] == s[:, t.STEP_DISPATCH]).all()
     assert (rows[step, t.DCOL_RETURNED] <= s[:, t.AHEAD_EMIT]).all()
-    assert (rows[step, t.DCOL_WAIT] == s[:, t.STEP_FETCH]).all()
-    assert (rows[step, t.DCOL_FETCHED] == s[:, t.STEP_EMIT]).all()
     assert (s[:, t.STEP_FETCH] > s[:, t.AHEAD_EMIT]).all()
+    # a step's wait lies inside the ``step.fetch`` of the turn that made it
+    # (a tail's too, but in a turn of the old order, which drains first)
+    f = stamps_of(turns[rows[fetched, t.DCOL_FETCH_TURN] - 1])
+    assert (f[step[fetched], t.STEP_FETCH]
+            <= rows[fetched & step, t.DCOL_WAIT]).all()
+    assert (rows[fetched, t.DCOL_WAIT] <= rows[fetched, t.DCOL_FETCHED]).all()
+    assert (rows[fetched, t.DCOL_FETCHED] <= f[:, t.STEP_EMIT]).all()
+    # a step sent ahead was called before the step before it was waited for
+    ahead_of = numpy.flatnonzero(step & (late == 1))
+    nxt = numpy.array([numpy.flatnonzero(step[i + 1:])[0] + i + 1
+                       for i in ahead_of.tolist()])
+    assert (rows[nxt, t.DCOL_RETURNED] <= rows[ahead_of, t.DCOL_WAIT]).all()
 
 
 # ------------------------------------- (e) what the first counter counts
@@ -264,6 +322,11 @@ def test_turns_prepared_ahead_counts_the_steps_that_follow_a_step(kind):
     assert cold >= 1
     assert c.get("ahead_discarded", 0) == 0
     assert c["turns_prepared_ahead"] == c["decode_dispatches"] - cold
+    # ISSUE 39: a step that follows a step goes out ahead of that one's
+    # fetch; the steps no step follows are the drains
+    assert pipeline_balances(engine)["dispatches_sent_ahead"] \
+        == c["turns_prepared_ahead"]
+    assert c["pipeline_drains"] == cold
 
 
 # --------------------------------------- (c) arguments put ahead are copies
@@ -340,11 +403,14 @@ def after_stretch(engine, n, act):
         calls.append(1)
         if len(calls) == n:
             assert engine._ahead is not None
+            # ISSUE 39: this step and the one before it are both unfetched
+            assert sum(not f.first for f in engine._flights) == min(n, 2)
             act()
     engine._under_step = under_step
 
 
-LANDINGS = ["step", "chunk", "tick", "cancel", "swap"]
+LANDINGS = ["step", "chunk", "tick", "cancel", "swap", "swap_finish",
+            "checkpoint"]
 
 
 @pytest.mark.parametrize("what", LANDINGS)
@@ -355,7 +421,11 @@ def test_what_lands_between_preparation_and_dispatch(what):
     that drains lands when a turn has been prepared under A's step: the
     preparation is dropped and counted, the lanes the event names fail (or
     are withdrawn, or decoded anew), the others' tokens are the greedy ones
-    bit for bit, and the pool's books balance."""
+    bit for bit, and the pool's books balance.  ISSUE 39: A's step before
+    is unfetched then too (two dispatches in flight): its tokens are
+    fetched first and reach their lanes; a swap that lets the lanes finish
+    stamps them with the weights that made them; a checkpoint taken there
+    holds what a fresh engine needs to serve the same tokens."""
     from veles_tpu.serving import FaultPlan, InjectedFault
     plan = FaultPlan(seed=0)
     if what == "step":
@@ -374,22 +444,26 @@ def test_what_lands_between_preparation_and_dispatch(what):
     try:
         fa, fb = engine.submit(a, 14), engine.submit(b, 5)
         fc = engine.submit(c, 6)
-        swapped = []
+        swapped, states = [], []
         if what == "cancel":
             after_stretch(engine, 2, lambda: engine._cancel(fa.request))
-        elif what == "swap":
+        elif what == "checkpoint":
+            after_stretch(engine, 2,
+                          lambda: states.append(engine.checkpoint()))
+        elif what.startswith("swap"):
             def swap():
                 thread = threading.Thread(
                     target=lambda: swapped.append(engine.swap_weights(
-                        jax.tree.map(jnp.array, engine.params), drain=True)))
+                        jax.tree.map(jnp.array, engine.params),
+                        drain=what == "swap")))
                 thread.start()
                 swapped.append(thread)
                 while engine._peek_swap() is None:
                     assert thread.is_alive()
             after_stretch(engine, 2, swap)
         gate.set()
-        failed = {"step": [fa], "chunk": [fb], "tick": [fa, fb],
-                  "cancel": [], "swap": []}[what]
+        failed = {"step": [fa], "chunk": [fb], "tick": [fa, fb]}.get(
+            what, [])
         for name, f, prompt, n_new in (("a", fa, a, 14), ("b", fb, b, 5),
                                        ("c", fc, c, 6)):
             if f in failed:
@@ -400,17 +474,34 @@ def test_what_lands_between_preparation_and_dispatch(what):
                 assert 1 <= len(f.result(timeout=120)) < 14
             else:
                 assert_greedy(engine, prompt, f.result(timeout=120), n_new)
-        if what == "swap":
+        if what.startswith("swap"):
             swapped[0].join(60)
             assert not swapped[0].is_alive() and swapped[1] == 1
-            assert fa.version == fb.version == fc.version == 1
+            # drained, all three decode anew on the new weights; left to
+            # finish, A and B are the old weights' and C, held back, the new
+            assert fc.version == 1
+            assert fa.version == fb.version == (what == "swap")
     finally:
         engine.stop()
     assert engine.verify_pool_invariants()["used_pages"] == 0
     assert engine._ahead is None and not engine._undelivered
-    cn = counters(engine)
-    assert cn["ahead_discarded"] >= 1
+    cn = pipeline_balances(engine)
+    assert cn.get("ahead_discarded", 0) >= (what != "checkpoint")
     assert cn.get("kv_storage_rebuilds", 0) == 0
+    if what == "checkpoint":
+        # taken with two dispatches in flight: the three requests, none
+        # resolved; a fresh engine serves them the same tokens
+        (state,) = states
+        assert [len(r["prompt"]) for r in state["requests"]] == [6, 29, 11]
+        fresh = make_engine(name="restored", slots=2).start()
+        try:
+            again = fresh.restore(state)
+            for f, rid in zip((fa, fb, fc), sorted(again)):
+                numpy.testing.assert_array_equal(
+                    again[rid].result(timeout=120), f.result())
+        finally:
+            fresh.stop()
+        assert fresh.verify_pool_invariants()["used_pages"] == 0
     outcomes = sorted(r.outcome for r in engine.recorder.requests())
     assert outcomes == sorted(["failed"] * len(failed)
                               + ["ok"] * (3 - len(failed)))
@@ -452,3 +543,76 @@ def test_a_failed_fetch_fails_the_lane_it_had_freed_by_count():
         engine.stop()
     assert engine.verify_pool_invariants()["used_pages"] == 0
     assert counters(engine)["kv_storage_rebuilds"] == 1
+
+
+# ----------------------------------------- ISSUE 39: two dispatches in flight
+@pytest.mark.parametrize("n_new", [1, 2])
+@pytest.mark.parametrize("kind", ["pre_ln", "window", "latent", "linear"])
+def test_a_tail_chunks_first_token_may_be_its_requests_last(kind, n_new):
+    """Short answers beside a long one: with ``n_new`` 1 the tail chunk's
+    token, which never leaves the device before the next step reads it, is
+    the whole answer and the lane is freed by count at the chunk's call;
+    with 2 the lane is freed under its only step.  Every token is the
+    reference's, each request's first token is stamped once, when it is
+    fetched, and nothing is left in flight."""
+    round_ = [(5, 12), (19, n_new), (3, n_new), (26, n_new), (9, n_new)]
+    engine = make_engine(kind, name="short%d_%s" % (n_new, kind), slots=2)
+    prompts, outs = serve(engine, round_)
+    for p, o, (_, n) in zip(prompts, outs, round_):
+        check_tokens(kind, engine, p, o, n)
+    assert engine.verify_pool_invariants()["used_pages"] == 0
+    assert engine._ahead is None and not engine._undelivered
+    c = pipeline_balances(engine)
+    assert c["tokens_out"] == sum(n for _, n in round_)
+    assert c.get("kv_storage_rebuilds", 0) == 0
+    assert engine.metrics.snapshot()["ttft"]["count"] == len(round_)
+    reqs = engine.recorder.requests()
+    assert sorted(r.tokens_out for r in reqs) == sorted(n for _, n in round_)
+    assert all(r.outcome == "ok" and r.first_token == r.token_ns[0]
+               for r in reqs)
+
+
+def test_a_fetch_that_raises_fails_the_younger_dispatch_too(monkeypatch):
+    """Two lanes: A decodes ten tokens, B three.  The fetch of the step that
+    is B's last raises while A's next step is already in flight: that one
+    read what the failed one wrote, so the lanes of BOTH fail (A in its
+    slot, B freed by count under the failed step), C, admitted into B's slot
+    under it, with them; the storage is rebuilt, the books balance, nothing
+    stays in flight and the engine serves the next request."""
+    from veles_tpu.serving import lm_engine
+    engine = make_engine(name="late_fetch", slots=2)
+    gate = gated(engine)
+    vocab = vocab_of(engine)
+    a, b, c = (tokens(n, 90 + n, vocab) for n in (5, 7, 6))
+    boom = RuntimeError("the step failed on the device")
+    real, fired = lm_engine.xfer.to_host, []
+
+    def to_host(x):
+        flights = engine._flights
+        if not fired and flights and x is flights[0].outs \
+                and not flights[0].first and any(flights[0].lasts):
+            # B's last step, and a younger one behind it
+            assert sum(not f.first for f in flights) == 2
+            fired.append(1)
+            raise boom
+        return real(x)
+    monkeypatch.setattr(lm_engine.xfer, "to_host", to_host)
+    engine.start()
+    try:
+        fa, fb = engine.submit(a, 10), engine.submit(b, 3)
+        fc = engine.submit(c, 4)
+        gate.set()
+        for f in (fa, fb, fc):
+            with pytest.raises(RuntimeError, match="failed on the device"):
+                f.result(timeout=120)
+        again = engine.submit(c, 4).result(timeout=120)
+        assert_greedy(engine, c, again, 4)
+    finally:
+        engine.stop()
+    assert fired
+    assert engine.verify_pool_invariants()["used_pages"] == 0
+    assert not engine._flights and engine._ahead is None \
+        and not engine._undelivered
+    assert counters(engine)["kv_storage_rebuilds"] == 1
+    outcomes = sorted(r.outcome for r in engine.recorder.requests())
+    assert outcomes == ["failed"] * 3 + ["ok"]

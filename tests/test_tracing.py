@@ -823,7 +823,12 @@ class TestLoopRecorder:
         numbered in call order; its stamps never go back; a decode
         dispatch and a tail chunk were waited for and fetched in the turn
         that called them, a chunk that is no tail never; and each names
-        the turn whose row holds its program and its call's stamp."""
+        the turn whose row holds its program and its call's stamp.
+        ISSUE 39: the paged plain driver fetches one dispatch late, so
+        its records say the turn after (``DCOL_FETCH_TURN``) wherever the
+        next step was called first, and a dispatch is no longer over
+        before the next is called; the waits are still made in call
+        order, inside the ``step.fetch`` of the turn that made them."""
         from veles_tpu.serving import tracing as t
         kw = self.DRIVERS.get(driver, dict(prefill_chunk=8))
         engine, outs = self._serve(**kw)
@@ -843,8 +848,20 @@ class TestLoopRecorder:
         assert ((got > 0) == waited).all()
         assert (back[waited] <= wait[waited]).all() \
             and (wait[waited] <= got[waited]).all()
-        # a dispatch is over before the next is called: today's order
-        assert (numpy.maximum(back, got)[:-1] <= call[1:]).all()
+        late = driver.startswith("plain")
+        if late:
+            # two dispatches in flight: the jit call is back before the
+            # next call, its outputs are fetched behind that one's
+            assert (back[:-1] <= call[1:]).all()
+            assert (numpy.diff(got[waited]) > 0).all()
+            c = counters
+            assert c["dispatches_sent_ahead"] + c["pipeline_drains"] \
+                == c["decode_dispatches"]
+            assert c["dispatches_sent_ahead"] > c["decode_dispatches"] // 2
+        else:
+            # a dispatch is over before the next is called: the old order
+            assert (numpy.maximum(back, got)[:-1] <= call[1:]).all()
+            assert "dispatches_sent_ahead" not in counters
         step = rows[:, t.DCOL_PHASE] == t.STEP_DISPATCH
         chunk = rows[:, t.DCOL_PHASE] == t.PREFILL_DISPATCH
         assert (step | chunk).all()
@@ -852,15 +869,21 @@ class TestLoopRecorder:
         assert waited[step].all() and (rows[step, t.DCOL_LANES] >= 1).all()
         assert not rows[chunk, t.DCOL_LANES].any()
         # every request has one tail chunk, whose token is its first: it
-        # is stamped between that chunk's fetch and the next call
+        # is stamped behind that chunk's fetch (and, where the chunk
+        # itself waits for it, before the next call)
         tails = numpy.flatnonzero(chunk & waited)
         assert len(tails) == len(self.PROMPTS) < int(chunk.sum())
         firsts = sorted(r.first_token for r in rec.requests())
         for i, first in zip(tails, firsts):
             assert got[i] <= first
-            assert i + 1 == len(rows) or first < call[i + 1]
-        assert (rows[waited, t.DCOL_FETCH_TURN]
-                == rows[waited, t.DCOL_TURN]).all()
+            assert late or i + 1 == len(rows) or first < call[i + 1]
+        behind = rows[:, t.DCOL_FETCH_TURN] - rows[:, t.DCOL_TURN]
+        if late:
+            assert set(behind[waited].tolist()) == {0, 1}
+            assert int((behind[step] == 1).sum()) \
+                == counters["dispatches_sent_ahead"]
+        else:
+            assert not behind[waited].any()
         assert not rows[~waited, t.DCOL_FETCH_TURN].any()
         # the span that caused it: the turn's row
         assert turns[:, t.COL_SEQ].tolist() == list(range(1, len(turns) + 1))
@@ -872,8 +895,18 @@ class TestLoopRecorder:
             assert (mine[of, t.COL_STAMPS + phase] == call[of]).all()
             # at most one of a kind a turn
             assert len(set(rows[of, t.DCOL_TURN].tolist())) == int(of.sum())
-        assert (mine[step, t.COL_STAMPS + t.STEP_FETCH] == wait[step]).all()
-        assert (mine[step, t.COL_STAMPS + t.STEP_EMIT] == got[step]).all()
+        # the waits lie in the ``step.fetch`` of the turn that made them
+        # (the step's own turn in the old order, and then they ARE it)
+        theirs = turns[rows[waited & step, t.DCOL_FETCH_TURN] - 1]
+        assert (theirs[:, t.COL_STAMPS + t.STEP_FETCH]
+                <= wait[waited & step]).all()
+        assert (got[waited & step]
+                <= theirs[:, t.COL_STAMPS + t.STEP_EMIT]).all()
+        if not late:
+            assert (mine[step, t.COL_STAMPS + t.STEP_FETCH]
+                    == wait[step]).all()
+            assert (mine[step, t.COL_STAMPS + t.STEP_EMIT]
+                    == got[step]).all()
         assert (mine[step, t.COL_ACTIVE] == rows[step, t.DCOL_LANES]).all()
         assert {rec.programs[i] for i in rows[chunk, t.DCOL_PROGRAM]} \
             == {"chunk_slot"}
@@ -885,9 +918,10 @@ class TestLoopRecorder:
         """A fault point fires before the jit call, so the dispatch it
         stops has no record and the records still number the counters; a
         program that raises in its call leaves a record with the call's
-        stamp alone, one whose fetch raises leaves ``fetched`` 0.  No
-        handle leaks: the next dispatch takes the next row, and it is
-        sound."""
+        stamp alone, one whose fetch raises leaves ``fetched`` 0, and so
+        does the younger step that was in flight behind it (ISSUE 39:
+        never waited for).  No handle leaks: the next dispatch takes the
+        next row, and it is sound."""
         from veles_tpu.serving import FaultPlan, lm_engine, tracing as t
         kw = dict(prefill_chunk=8, paged_kv=True)
         if where.startswith("engine."):
@@ -938,12 +972,18 @@ class TestLoopRecorder:
             # (the paged driver counts a step when its call is back,
             # under the step: the one whose fetch raised is counted)
             assert len(rows) == done + (where == "call")
-            (i,) = open_.tolist()
-            assert i + 1 < len(rows)
+            i, *younger = open_.tolist()
+            assert i + 1 + len(younger) < len(rows)
             call, back, wait, got = stamps[i].tolist()
             assert call > 0 and got == 0 == rows[i, t.DCOL_FETCH_TURN]
             assert (back, wait) == (0, 0) if where == "call" \
                 else call <= back <= wait
+            # the fetch one dispatch late: the step behind the one whose
+            # fetch raised was called and never waited for
+            assert younger == ([i + 1] if where == "fetch" else [])
+            for j in younger:
+                call, back, wait, got = stamps[j].tolist()
+                assert 0 < call <= back and (wait, got) == (0, 0)
         # every other row is sound, the one behind the failure too
         sound = numpy.ones(len(rows), bool)
         sound[open_] = False
@@ -954,6 +994,82 @@ class TestLoopRecorder:
             and (s[fetched, 2] <= s[fetched, 3]).all()
         assert (s[~fetched, 2] == 0).all()
         assert (numpy.diff(rows[:, t.DCOL_CALL]) > 0).all()
+
+    def test_the_benchmarks_readers_on_the_fetch_one_dispatch_late(
+            self, tmp_path):
+        """ISSUE 39, on a CPU run of the real engine under its new order
+        (the profiler's host events stand in for the device, as in a
+        rehearsal of the benchmark): ``benchmark/lib/dispatch_log.py``'s
+        fit pairs every dispatch recorded while the trace was on with its
+        execution (``dispatches_matched_share.serve`` 100) and ``parts()``
+        splits the idle gaps into its three parts.  ``lib/spans.py``'s
+        fit, which rebuilds the calls from "a turn's chunk, then its step,
+        fetched in the same row", finds no alignment and gives None, and
+        the seven readers on it are left out of the result line: the
+        documented state until a ``benchmark`` issue retires them."""
+        import jax
+        from benchmark.lib import dispatch_log, spans, trace as trace_lib
+        from benchmark.lib.files import load_module
+        from veles_tpu.serving import tracing as t
+        engine = self._engine(name="rec_late", **self.DRIVERS["plain"])
+        engine.start()
+        t_open = time.monotonic()
+        try:
+            for f in [engine.submit(p, 4) for p in self.PROMPTS[:2]]:
+                f.result(timeout=120)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            options.host_tracer_level = 1
+            jax.profiler.start_trace(str(tmp_path),
+                                     profiler_options=options)
+            begin = time.monotonic()
+            try:
+                futures = [engine.submit(p, 24) for p in self.PROMPTS]
+                outs = [f.result(timeout=120) for f in futures]
+            finally:
+                jax.profiler.stop_trace()
+            end = time.monotonic()
+        finally:
+            engine.stop()
+        for p, out in zip(self.PROMPTS, outs):
+            assert_greedy(engine, p, out, 24)
+        c = engine.metrics.snapshot()["counters"]
+        assert c["dispatches_sent_ahead"] > c["decode_dispatches"] // 2
+        rec = engine.recorder
+        art = {"t_open": t_open, "window_s": time.monotonic() - t_open,
+               "trace_host_window": (begin, end),
+               "trace": trace_lib.read(str(tmp_path)), "counters": {},
+               "_spans_recorder": {"recorder": rec, "turns": rec.turns(),
+                                   "tracing": t}}
+        assert spans.fit(art) is None
+        fitted = dispatch_log.fit(art)
+        assert fitted is not None and fitted["slack"] > 0
+        # every dispatch called since the trace began has its execution
+        rows = rec.dispatches()
+        traced = rows[rows[:, t.DCOL_CALL] >= int(begin * 1e9)]
+        assert len(traced) > 50
+        assert fitted["rows"][:, t.DCOL_SEQ].tolist() \
+            == traced[:, t.DCOL_SEQ].tolist()
+        assert [name for name, _, _ in fitted["execs"]] \
+            == [rec.programs[i] for i in traced[:, t.DCOL_PROGRAM]]
+        assert dispatch_log.matched_share(art) == 100.0
+        parts = dispatch_log.parts(art)
+        assert set(parts["ns"]) == set(dispatch_log.PARTS)
+        assert min(parts["ns"].values()) >= 0 < sum(parts["ns"].values())
+        assert parts["steps"] == int(
+            (traced[:, t.DCOL_PHASE] == t.STEP_DISPATCH).sum())
+
+        def read(name):
+            return load_module("layer_metrics", name).read(art, None)
+        for name in ("dispatches_matched_share.serve", "gap_return_ms.serve",
+                     "gap_launch_ms.serve", "gap_host_ms.serve",
+                     "token_return_ms.serve", "jit_call_ms.serve"):
+            assert read(name) is not None, name
+        for name in ("idle_admit_ms.serve", "idle_prefill_ms.serve",
+                     "idle_prepare_ms.serve", "idle_dispatch_ms.serve",
+                     "idle_fetch_ms.serve", "idle_emit_ms.serve",
+                     "idle_attributed_share.serve"):
+            assert read(name) is None, name
 
     @pytest.mark.parametrize("driver", sorted(DRIVERS))
     def test_page_steps_of_every_driver_reach_the_reader(self, driver):

@@ -243,13 +243,15 @@ def engine_programs(tag, eng, one_chip, widths):
 
     name = "%s prefill chunk (kernel)" % tag
     text = compile_(name, eng._chunk_jit, a_params, pools,
-                    tables(eng._max_pages), s((page,)), s(()), s(()))
+                    tables(eng._max_pages), s((page,)), s(()), s(()), s(()),
+                    s((slots,)))
     storage_in_place(name, text, eng)
     grouped_matmuls(name, text, eng, page)
     for width in widths:
         name = "%s decode step width %d" % (tag, width)
         text = compile_(name, eng._step_jit, a_params, pools,
-                        tables(slots, width), s((slots,)), s((slots,)))
+                        tables(slots, width), s((slots,)), s((slots,)),
+                        s((slots,), jnp.bool_))
         if "tpu_custom_call" not in text:
             raise SystemExit("no Pallas kernel in the decode program")
         storage_in_place(name, text, eng)
@@ -363,7 +365,7 @@ def tp(topo):
                                   on(P())))
     text = compile_("engine decode step tp=4", step, a_params, pools,
                     s((slots, 8), jnp.int32), s((slots,), jnp.int32),
-                    s((slots,), jnp.int32))
+                    s((slots,), jnp.int32), s((slots,), jnp.bool_))
     from veles_tpu.serving.lm_engine import compiled_storage_report
     leaves = jax.tree.leaves(pools)
     _, aliased = compiled_storage_report(text, leaves[0])

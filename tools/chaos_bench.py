@@ -469,7 +469,9 @@ def recorder_turn_cost(slots, turns=20000):
     the engine loop: every call a decode turn with one prefill chunk
     makes (turn, six marks, two dispatches with the stamps of their
     own records (ISSUE 38: returned, waiting, fetched), the lane counts,
-    one token stamp per lane), timed over ``turns`` turns on this host."""
+    one token stamp per lane), timed over ``turns`` turns on this host.
+    The fetches are those of the turn BEFORE (ISSUE 39: the same calls,
+    on the handles kept from it)."""
     from veles_tpu.serving import tracing
 
     class _Req:
@@ -481,6 +483,7 @@ def recorder_turn_cost(slots, turns=20000):
     rec = tracing.LoopRecorder("cost")
     req = _Req()
     req.token_ns = array.array("q")
+    before = ()
     t0 = time.perf_counter()
     for _ in range(turns):
         rec.turn()
@@ -489,8 +492,6 @@ def recorder_turn_cost(slots, turns=20000):
         rec.mark(tracing.PREFILL_PREPARE)
         chunk = rec.dispatch(tracing.PREFILL_DISPATCH, program)
         rec.returned(chunk)
-        rec.waiting(chunk)          # (a tail chunk: the dearest kind)
-        rec.fetched(chunk)
         rec.mark(tracing.STEP_PREPARE)
         step = rec.dispatch(tracing.STEP_DISPATCH, program, slots)
         rec.returned(step)
@@ -499,8 +500,11 @@ def recorder_turn_cost(slots, turns=20000):
             rec.emitted(req, 1)
         rec.mark(tracing.AHEAD_ADMIT)
         rec.mark(tracing.AHEAD_PREPARE)
-        rec.waiting(step, tracing.STEP_FETCH)
-        rec.fetched(step, tracing.STEP_EMIT)
+        # (a tail chunk and the step behind it: the dearest kind)
+        for i, sent in enumerate(before):
+            rec.waiting(sent, None if i else tracing.STEP_FETCH)
+            rec.fetched(sent, tracing.STEP_EMIT if i else None)
+        before = (chunk, step)
     rec.close()
     return (time.perf_counter() - t0) / turns
 

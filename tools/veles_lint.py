@@ -116,6 +116,7 @@ HOT_PATH_REGISTRY = (
     ("veles_tpu/serving/lm_engine.py", "_prepare_chunk_paged"),
     ("veles_tpu/serving/lm_engine.py", "_dispatch_chunk_paged"),
     ("veles_tpu/serving/lm_engine.py", "_dispatch_decode"),
+    ("veles_tpu/serving/lm_engine.py", "_fetch_flights"),
     ("veles_tpu/serving/lm_engine.py", "_prepare_step"),
     ("veles_tpu/serving/lm_engine.py", "_under_step"),
     ("veles_tpu/serving/lm_engine.py", "_deliver"),

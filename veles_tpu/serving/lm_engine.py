@@ -217,6 +217,36 @@ stay inside the scan) and the contiguous layout (its admission
 dispatches programs) cannot split their turn: they leave nothing
 prepared and the same loop runs them in the old order.
 
+TWO DISPATCHES IN FLIGHT (ISSUE 39).  That one value never leaves the
+device either: the tokens are the step program's second output, and the
+paged programs take the lanes' last tokens as the dispatch before left
+them there (``LMEngine._last_dev``: a step returns every lane's token, a
+tail chunk writes its first token at its lane's slot, a lane that does
+not decode is masked to token 0 inside the step).  The host needs the
+tokens only to answer requests, so the paged plain driver waits for the
+tokens of step N only AFTER it has called turn N+1's chunk and step:
+tick -> the prepared chunk's jit call -> the step's jit call -> [under
+the step: count it in, deliver, shed and admit, prepare] -> wait for the
+tokens of the step BEFORE (and of the tail chunk before it) -> they go
+to ``_undelivered``.  The device always has its next program queued
+while the host fetches, delivers, admits and prepares.  What is in
+flight is a queue of :class:`_Flight` records; a tail chunk's bookkeeping
+that needs no token (the lane decodes, owes one fewer, is freed if that
+was all) happens at its call, the token, its stamp and the time to it
+when it is fetched, up to one decode step later than the old order gave
+it.  The pipeline DRAINS — the outstanding fetches are made first, then
+the turn as ever — wherever the old order is taken: nothing was prepared
+(or it was dropped: a lane left, was withdrawn, a weight swap waits, a
+tick fault), no step follows (no lane decodes next turn), the loop ends.
+A fetch that raises fails the lanes of every dispatch in flight: the
+younger ones read what the failed one wrote
+(:meth:`LMEngine._storage_lost`).  ``dispatches_sent_ahead`` (decode
+dispatches called while the step before was unfetched) and
+``pipeline_drains`` (steps whose tokens were fetched in the old order)
+in ``/metrics.json`` add up to ``decode_dispatches`` on this driver.
+``checkpoint()`` reads the host's books only, which the count keeps
+exact whatever is in flight.
+
 Decoding is GREEDY (temperature 0) — bit-identical to
 ``ops/transformer.py::generate`` for the same prompt WHATEVER fast-path
 combination is enabled, which is the serving contract (sampled
@@ -310,14 +340,35 @@ class _Slot:
 _Chunk = collections.namedtuple(
     "_Chunk", "slot lane tokens start is_tail args steps")
 
-#: one plain decode step's arguments, all but ``last`` (the one that needs
-#: the tokens of the step before), on the device
+#: one plain decode step's arguments, all but ``last`` (the tokens of the
+#: step before: the paged layout passes them on the device from output to
+#: argument, the contiguous one puts them), on the device
 #: (:meth:`LMEngine._prepare_step`): the lanes it advances as ``(slot,
 #: lane)`` pairs, the table width, the table argument as a tuple (empty for
-#: the contiguous layout), the positions on the device, and the page steps
-#: it hands the attention kernels (:meth:`LMEngine._attn_page_steps`)
+#: the contiguous layout), the positions and (paged) the mask of those
+#: lanes on the device, and the page steps it hands the attention kernels
+#: (:meth:`LMEngine._attn_page_steps`)
 _Step = collections.namedtuple(
-    "_Step", "pairs width tables pos_dev steps")
+    "_Step", "pairs width tables pos_dev live_dev steps")
+
+
+class _Flight:
+    """One dispatch of the paged plain driver whose tokens the host has
+    not fetched yet (ISSUE 39): its recorder handle, its outputs but the
+    storage as they lie on the device (the lanes' tokens first, indexed
+    by slot; a step's expert counts behind them), the lanes whose tokens
+    it makes as ``(slot, lane)`` pairs, for each whether the token is its
+    request's last (known by count when the dispatch goes out), and
+    whether it is a tail chunk (the token is its lane's first)."""
+
+    __slots__ = ("sent", "outs", "pairs", "lasts", "first")
+
+    def __init__(self, sent, outs, pairs, lasts=(), first=False):
+        self.sent = sent
+        self.outs = outs
+        self.pairs = pairs
+        self.lasts = lasts
+        self.first = first
 
 
 class _Ahead:
@@ -980,14 +1031,23 @@ class LMEngine(Logger):
         #: the turn's early stretch (ISSUE 37), the worker thread's own:
         #: what was prepared for the next turn under the step in flight;
         #: the tokens fetched and not yet given to their lanes, as
-        #: ``(slot, lane, token, whether it is the request's last)``; a
-        #: count of the lanes that left a slot, by which a preparation
-        #: knows the lanes are still those it was made for; the
-        #: prefilling lanes' round robin
+        #: ``(slot, lane, token, whether it is the request's last,
+        #: whether its first)``; a count of the lanes that left a slot, by
+        #: which a preparation knows the lanes are still those it was made
+        #: for; the prefilling lanes' round robin
         self._ahead = None
         self._undelivered = []
         self._lanes_gen = 0
         self._rr = 0
+        #: two dispatches in flight (ISSUE 39), the worker thread's own:
+        #: the paged programs' ``last`` argument as the dispatch before
+        #: left it on the device (warm-up makes the first); the
+        #: dispatches whose tokens are not fetched yet, oldest first
+        #: (:class:`_Flight`; only the paged plain driver leaves any),
+        #: and how many of them were there when the turn began
+        self._last_dev = None
+        self._flights = collections.deque()
+        self._older = 0
 
         self._queue = collections.deque()
         self._queued_tokens = 0
@@ -1005,6 +1065,13 @@ class LMEngine(Logger):
         #: made in start(), kept after stop() for whoever reads later
         self.recorder = None
         self._build_jits()
+        #: whether a decode step's tokens are fetched one dispatch late
+        #: (ISSUE 39): the paged plain driver's order, read off what was
+        #: built (the speculative driver drafts from the tokens, the
+        #: megastep fetches once in K, the contiguous layout dispatches
+        #: programs at admission)
+        self._late_fetch = bool(self._paged and self._verify_jit is None
+                                and self._megastep_jit is None)
         if self._paged:
             self._update_pool_gauges()
 
@@ -1107,6 +1174,17 @@ class LMEngine(Logger):
             # ONE pool a layer: its rows are (c_kv, k_rope)
             return [(zeros(shape),) for shape in shapes]
         return [(zeros(shape), zeros(shape)) for shape in shapes]
+
+    def _zero_last(self):
+        """The paged programs' ``last`` argument before any dispatch has
+        made one (ISSUE 39): zeros, placed where the programs return it
+        (replicated over the tp mesh, committed to the replica's device,
+        or left uncommitted), so the first call compiles the program every
+        later call finds."""
+        import jax
+        where = (self._repl_shard if self._mesh is not None
+                 else self._device)
+        return jax.device_put(numpy.zeros(self.slots, numpy.int32), where)
 
     def _storage(self):
         return self._kv_pools if self._paged else self._caches
@@ -1344,11 +1422,15 @@ class LMEngine(Logger):
             tabs, wbase = ptab
             return tabs, {full: None, sliding: wbase}, None
 
-        def chunk_slot(params, pools, ptab, tokens, start, last_idx):
-            # one lane's prompt chunk through its page table; returns
-            # the argmax after ``last_idx`` (read on the tail chunk; the
+        def chunk_slot(params, pools, ptab, tokens, start, last_idx,
+                       first_at, last):
+            # one lane's prompt chunk through its page table; takes the
+            # lanes' last tokens ``last`` as they lie on the device and
+            # returns them with the argmax after ``last_idx`` (the
             # chunk's last real row: behind it lies padding, which a
-            # linear layer must be told of)
+            # linear layer must be told of) written at slot ``first_at``:
+            # a tail chunk's lane decodes from it (ISSUE 39); a chunk
+            # that is no tail gives -1 and ``last`` back as it came
             tokens = tokens[None]
             tabs, base, slot = tables_of(
                 jax.tree.map(lambda t: t[None], ptab))
@@ -1361,11 +1443,17 @@ class LMEngine(Logger):
             logits = head_logits(params, jax.lax.dynamic_slice_in_dim(
                 h, last_idx, 1, axis=1), cfg)[:, 0, :]
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[0]
-            return pools, tok
+            return pools, jnp.where(
+                jnp.arange(last.shape[0]) == first_at, tok, last)
 
-        def step_all(params, pools, ptabs, toks, pos):
+        def step_all(params, pools, ptabs, toks, pos, live):
             # ONE dispatch advances every lane by one token at its own
-            # position through its own page table
+            # position through its own page table.  ``toks`` is the
+            # output of the dispatch before (the step's own, or the tail
+            # chunk's that wrote into it), never fetched in between; a
+            # lane that does not decode (``live`` false: free, or in its
+            # prompt) steps on token 0 whatever that output left there
+            toks = jnp.where(live, toks, 0)
             tabs, base, decoding = tables_of(ptabs)
             state = ({} if decoding is None else
                      {"rows": decoding.astype(jnp.int32)})
@@ -1610,13 +1698,17 @@ class LMEngine(Logger):
         if self._paged:
             ptabs = numpy.zeros((self.slots, self._max_pages),
                                 numpy.int32)
-            self._kv_pools, _ = self._chunk_jit(
+            # (the lanes' last tokens pass from one program's output to
+            # the next one's argument, as they will in traffic)
+            self._kv_pools, self._last_dev = self._chunk_jit(
                 self.params, self._kv_pools,
                 self._table_args(ptabs[0], 0),
                 xfer.to_device(numpy.zeros(self.prefill_chunk,
-                                           numpy.int32)), zero, zero)
+                                           numpy.int32)), zero, zero,
+                xfer.to_device(-1, numpy.int32), self._zero_last())
             self._kv_pools = self._page_copy_jit(self._kv_pools, zero,
                                                  zero)
+            none = xfer.to_device(numpy.zeros(self.slots, bool))
             # step/verify (or the fused megastep, which REPLACES them
             # on the decode loop) compile one program per
             # live-width ladder entry (ISSUE 7) — warm EVERY entry now,
@@ -1641,8 +1733,9 @@ class LMEngine(Logger):
                             (self.slots, self.spec_k + 1),
                             numpy.int32)), zeros)
                 went_in = self._kv_pools[0][0]
-                self._kv_pools = self._step_jit(
-                    self.params, self._kv_pools, wtab, zeros, zeros)[0]
+                self._kv_pools, self._last_dev = self._step_jit(
+                    self.params, self._kv_pools, wtab, self._last_dev,
+                    zeros, none)[:2]
         else:
             tok, rows = self._prefill_jit(
                 self.params,
@@ -1829,6 +1922,7 @@ class LMEngine(Logger):
         # freed by count still owes its last token's delivery, on the
         # weights that made it
         self._drop_ahead()
+        self._drain()
         self._deliver()
         active = [i for i, lane in enumerate(self._lanes)
                   if lane is not None]
@@ -2906,7 +3000,10 @@ class LMEngine(Logger):
             args = (self._table_args(self._page_tables[slot], slot),
                     xfer.to_device(tokens, numpy.int32),
                     xfer.to_device(start, numpy.int32),
-                    xfer.to_device(last_idx, numpy.int32))
+                    xfer.to_device(last_idx, numpy.int32),
+                    # (where the program writes its token among the
+                    # lanes' last ones: a tail chunk's own slot)
+                    xfer.to_device(slot if is_tail else -1, numpy.int32))
             # (the latent kind's chunk kernel also walks the chunk's own
             # page, written before it: its query rows count like a
             # decode's)
@@ -2924,8 +3021,12 @@ class LMEngine(Logger):
     def _dispatch_chunk_paged(self, chunk):   # hot-path
         """The jit call of a prepared chunk and what follows it: a
         computed full chunk SHARES the lane's own page with the trie
-        (retain — the insert itself copies nothing); a tail chunk's first
-        token is fetched here and the lane becomes a decode lane."""
+        (retain — the insert itself copies nothing); a tail chunk's lane
+        becomes a decode lane.  Its first token the program writes among
+        the lanes' last tokens on the device, where the next step reads
+        it; the plain driver fetches it one dispatch late with that
+        step's (:meth:`_sent_first`, ISSUE 39), the drivers that need it
+        on the host at once wait for it here."""
         slot, lane, tokens, start, is_tail, args, steps = chunk
         req = lane.request
         if req.cancelled:
@@ -2939,17 +3040,20 @@ class LMEngine(Logger):
             rec = self.recorder
             sent = rec.dispatch(tracing.PREFILL_DISPATCH, self._chunk_jit)
             with self._donating():
-                self._kv_pools, tok = self._chunk_jit(
-                    self.params, self._kv_pools, *args)
+                self._kv_pools, last = self._chunk_jit(
+                    self.params, self._kv_pools, *args, self._last_dev)
                 rec.returned(sent)
+                if self._late_fetch:
+                    self._last_dev = last
+                    if is_tail:
+                        xfer.start_to_host(last)
                 self._tfence(self._kv_pools, req.trace is not None)
                 # the device has the chunk to run: the tokens the last
-                # fetch brought reach their lanes now, not behind the
-                # wait for a tail chunk's token
+                # fetch brought reach their lanes now
                 self._deliver()
-                if is_tail:      # the first token crosses in here too
+                if is_tail and not self._late_fetch:
                     rec.waiting(sent)
-                    tok = int(xfer.to_host(tok))
+                    tok = xfer.to_host(last).tolist()[slot]
                     rec.fetched(sent)
             if not is_tail and self._trie is not None \
                     and lane.cursor is not None:
@@ -2984,8 +3088,9 @@ class LMEngine(Logger):
         self.metrics.inc("prefill_tokens",
                          (req.true_len - start) if is_tail
                          else len(tokens))
-        # enqueue time by design (a tail chunk's includes the wait for
-        # its token); device wall rides traced spans (_tfence)
+        # enqueue time by design (with the wait for a tail chunk's token
+        # where a driver waits for it here); device wall rides traced
+        # spans (_tfence)
         self.metrics.record_decode_step(time.monotonic() - t0)
         if req.trace is not None:
             req.trace.tracer.add(
@@ -2994,7 +3099,11 @@ class LMEngine(Logger):
                 attrs={"start": start, "tail": is_tail,
                        "bucket": self.prefill_chunk, "paged": True,
                        "backend": self._backend})
-        if is_tail:
+        if not is_tail:
+            return
+        if self._late_fetch:
+            self._sent_first(slot, lane, sent, last)
+        else:
             self._emit_first(slot, lane, tok)
 
     def _count_tokens(self, req, n=1):
@@ -3017,6 +3126,20 @@ class LMEngine(Logger):
         self._lanes[slot] = lane
         if lane.remaining == 0 or req.cancelled:
             self._finish(slot)
+
+    def _sent_first(self, slot, lane, sent, last):
+        """:meth:`_emit_first` by count, for a tail chunk whose token
+        stays on the device (ISSUE 39; ``sent`` the chunk's dispatch
+        record, ``last`` its output): the lane owes one token fewer and
+        decodes from here on (its position is the prompt's length since
+        the chunk was prepared); where that token is its last the lane is
+        freed now, as :meth:`_advance_by_count` frees one.  The token
+        itself, its stamp and the time to it wait for the fetch."""
+        lane.remaining -= 1
+        self._flights.append(_Flight(
+            sent, (last,), [(slot, lane)], (lane.remaining == 0,), True))
+        if lane.remaining == 0:
+            self._vacate_slot(slot, lane)
 
     def _release_lane(self, lane):
         if self._trie is not None and lane.pinned:
@@ -3118,8 +3241,14 @@ class LMEngine(Logger):
         releases and every table row parks on scratch; fresh zero
         storage takes the place of the lost one; ``kv_storage_rebuilds``
         counts it.  Queued requests are untouched: they hold nothing
-        yet, and are served from the fresh storage."""
+        yet, and are served from the fresh storage.  The dispatches whose
+        tokens are still on the device (ISSUE 39) go too: the oldest of
+        them failed or a younger one read what it wrote, so the lanes
+        they had freed by count fail with the rest, and ``last`` on the
+        device starts from zeros again."""
         self._deliver()
+        flights, self._flights = self._flights, collections.deque()
+        self._older = 0
         held = [i for i, lane in enumerate(self._lanes)
                 if lane is not None]
         self.warning(
@@ -3133,9 +3262,15 @@ class LMEngine(Logger):
         if self._paged:
             self._page_tables[:] = KVPagePool.SCRATCH
             self._update_pool_gauges()
+        for flight in flights:
+            for _, lane in flight.pairs:
+                if not lane.request.future.done():
+                    lane.request.future.set_exception(exc)
         # a declared boundary: making the zeros is no hot-path transfer
         with xfer.boundary():
             self._set_storage(self._zero_storage())
+            if self._last_dev is not None:
+                self._last_dev = self._zero_last()
         self.metrics.inc("kv_storage_rebuilds")
 
     def _fail_active(self, active, exc):
@@ -3155,7 +3290,7 @@ class LMEngine(Logger):
             if self._lanes[slot] is not None:
                 self._teardown_slot(slot, self._lanes[slot], exc)
 
-    def _dispatch_decode(self, decode_jit, args, lanes, tctxs, under=None):   # hot-path
+    def _dispatch_decode(self, decode_jit, args, lanes, tctxs, under=None, pairs=None):   # hot-path
         """THE decode dispatch all three drivers share (tick, verify and
         megastep): ``decode_jit`` over the parameters, the KV
         storage — DONATED: the program updates it in place and the tree
@@ -3172,13 +3307,40 @@ class LMEngine(Logger):
         copy out (an armed tracer's fence too, when a sampled lane rides
         the dispatch), and ``step.emit`` opens as this returns; the
         dispatch's own record (ISSUE 38) takes the same stamps, and the
-        jit call's return, under the handle ``sent``."""
+        jit call's return, under the handle ``sent``.
+
+        With ``pairs`` (the paged plain driver: the lanes the step
+        advances) the fetch is ONE DISPATCH LATE (ISSUE 39): the outputs
+        stay on the device as a :class:`_Flight`, the tokens as the next
+        dispatch's ``last`` argument, and what the host waits for behind
+        ``under()`` are the dispatches of the turn BEFORE, whose handles
+        take the stamps (``DCOL_FETCH_TURN`` says the turn).  The device
+        has this step queued while the host fetches, delivers, admits and
+        prepares.  Where ``under()`` prepared no step to follow, this
+        one's tokens are fetched too, the old order (a drain).  Nothing
+        is returned: :meth:`_fetch_flights` hands over what it brings."""
         rec = self.recorder
         sent = rec.dispatch(tracing.STEP_DISPATCH, decode_jit, lanes)
         with self._donating():
             out = decode_jit(self.params, self._storage(), *args)
             rec.returned(sent)
             self._set_storage(out[0])
+            if pairs is not None:
+                if any(not flight.first for flight in self._flights):
+                    # (the step before is still unfetched)
+                    self.metrics.inc("dispatches_sent_ahead")
+                self._last_dev = out[1]
+                xfer.start_to_host(out[1:])
+                self._flights.append(_Flight(sent, out[1:], pairs))
+                under()
+                n = self._older
+                if self._ahead is None or self._ahead.step is None:
+                    self.metrics.inc("pipeline_drains")
+                    n = len(self._flights)
+                self._fetch_flights(n, True)
+                self._tfence(self._storage(),
+                             any(c is not None for c in tctxs))
+                return None
             if under is not None:
                 under()
             rec.waiting(sent, tracing.STEP_FETCH)
@@ -3189,6 +3351,60 @@ class LMEngine(Logger):
                          any(c is not None for c in tctxs))
         rec.fetched(sent, tracing.STEP_EMIT)
         return host
+
+    def _fetch_flights(self, n, in_step=False):   # hot-path
+        """Wait for the ``n`` oldest dispatches whose tokens are still on
+        the device (ISSUE 39) and hand over what they bring: a step's
+        expert counts to :meth:`_note_moe`, every token to
+        ``_undelivered`` with what the count said of it when its dispatch
+        went out, and to the host's ``_last``.  Each wait is stamped on
+        its own dispatch record; ``in_step`` (the late fetch behind a
+        step's ``under()``) makes the waits the turn's ``step.fetch``,
+        empty where nothing is owed.  A fetch that raises leaves its
+        flight and the younger ones where they are, for
+        :meth:`_storage_lost`: they read what the failed one wrote."""
+        rec = self.recorder
+        if in_step and not n:
+            rec.mark(tracing.STEP_FETCH)
+            rec.mark(tracing.STEP_EMIT)
+        for i in range(n):
+            flight = self._flights[0]
+            rec.waiting(flight.sent,
+                        tracing.STEP_FETCH if in_step and not i else None)
+            toks, *counts = xfer.to_host(flight.outs)
+            rec.fetched(flight.sent,
+                        tracing.STEP_EMIT if in_step and i == n - 1
+                        else None)
+            self._flights.popleft()
+            if counts:
+                self._note_moe(counts[0])
+            toks = toks.tolist()
+            for (slot, lane), last in zip(flight.pairs, flight.lasts):
+                if self._lanes[slot] is lane:
+                    self._last[slot] = toks[slot]
+                self._undelivered.append((slot, lane, toks[slot], last,
+                                          flight.first))
+        self._older = max(0, self._older - n)
+
+    def _drain(self):
+        """The outstanding fetches made first (ISSUE 39), wherever the
+        old order is taken: no turn was prepared, no lane decodes, a
+        weight swap waits, a fault fails lanes, the loop ends.  Counted
+        in ``pipeline_drains`` where a step was among them.  A fetch that
+        raises here has no dispatch around it to say so: the program
+        failed on the device, so the storage it returned goes
+        (:meth:`_storage_lost`)."""
+        if not self._flights:
+            return
+        steps = sum(not flight.first for flight in self._flights)
+        if steps:
+            self.metrics.inc("pipeline_drains", steps)
+        try:
+            self._fetch_flights(len(self._flights))
+        except Exception as e:   # noqa: BLE001 — fails the lanes
+            self.metrics.record_error()
+            self.warning("fetch of a dispatch in flight failed: %s", e)
+            self._storage_lost(e)
 
     def _prepare_step(self, active):   # hot-path
         """Everything a plain decode step over the lanes ``active`` needs
@@ -3207,6 +3423,7 @@ class LMEngine(Logger):
                 return None
         w = None
         tables = ()
+        live = None
         try:
             if self._wt is not None:
                 due = self._wt.due(self._pos)
@@ -3221,9 +3438,18 @@ class LMEngine(Logger):
                     self._decoding[active] = True
                 tables = (self._table_args(self._page_tables[:, :w],
                                            slice(None)),)
+                # the lanes that decode, for the program to know whose
+                # token on the device is one (ISSUE 39): the linear
+                # kind's table argument carries the same mask
+                if self._state_shapes is not None:
+                    live = tables[0][1]
+                else:
+                    live = numpy.zeros(self.slots, bool)
+                    live[active] = True
+                    live = xfer.to_device(live)
             pos = self._pos.copy()
             return _Step([(slot, self._lanes[slot]) for slot in active],
-                         w, tables, xfer.to_device(pos),
+                         w, tables, xfer.to_device(pos), live,
                          self._attn_page_steps(pos, w, 1))
         except Exception as e:   # noqa: BLE001 — fails the lanes
             self._fail_active(active, e)
@@ -3237,17 +3463,18 @@ class LMEngine(Logger):
         on the active set.
 
         ``step`` holds the arguments made under the step before
-        (:meth:`_under_step`; None: they are made here, the old order),
-        so what is left between the tokens' arrival and the jit call is
-        the put of ``last``.  The paged layout then does the rest of its
-        turn WHILE the device runs the step (:meth:`_under_step`, the
-        ``ahead.*`` phases); after the wait only ``_last`` moves, and the
-        tokens wait for the next stretch (:meth:`_deliver`).  The
-        contiguous layout, whose admission dispatches programs of its
-        own, keeps the old order: count, commit and deliver after the
-        fetch."""
+        (:meth:`_under_step`; None: they are made here, the old order,
+        behind the outstanding fetches).  The paged layout takes ``last``
+        from the device as the dispatch before left it, does the rest of
+        its turn WHILE the device runs the step (:meth:`_under_step`, the
+        ``ahead.*`` phases), and then waits for the tokens of the step
+        BEFORE this one (:meth:`_dispatch_decode`, ISSUE 39); they wait
+        for the next stretch (:meth:`_deliver`).  The contiguous layout,
+        whose admission dispatches programs of its own, keeps the old
+        order: put ``last``, call, wait, count, commit and deliver."""
         made_ahead = step is not None
         if step is None:
+            self._drain()
             step = self._prepare_step(active)
             if step is None:
                 return
@@ -3265,12 +3492,15 @@ class LMEngine(Logger):
         t0 = time.monotonic()
         try:
             self._fault("engine.step")
-            args = step.tables + (xfer.to_device(self._last.copy()),
-                                  step.pos_dev)
-            toks, *counts = self._dispatch_decode(
-                self._step_jit, args, len(pairs), tctxs, under)
-            if counts:
-                self._note_moe(counts[0])
+            if self._paged:
+                self._dispatch_decode(
+                    self._step_jit, step.tables + (
+                        self._last_dev, step.pos_dev, step.live_dev),
+                    len(pairs), tctxs, under, pairs)
+            else:
+                toks = self._dispatch_decode(
+                    self._step_jit, (xfer.to_device(self._last.copy()),
+                                     step.pos_dev), len(pairs), tctxs)[0]
         except Exception as e:   # noqa: BLE001 — fails the lanes
             if self._tracer is not None:
                 self._tracer.add_many(
@@ -3287,20 +3517,17 @@ class LMEngine(Logger):
                        "bucket": (step.width if step.width is not None
                                   else self.slots),
                        "backend": self._backend})
-        if under is None:
-            self._note_step(step)
-            self._advance_by_count(pairs)
+        if self._paged:
+            return
+        self._note_step(step)
+        self._advance_by_count(pairs)
         toks = toks.tolist()
         for slot, lane in pairs:
-            tok = toks[slot]
             if self._lanes[slot] is lane:
-                self._last[slot] = tok
-            # (its last token, if the count says so NOW: the next
-            # stretch counts the next step in before it delivers)
-            self._undelivered.append((slot, lane, tok,
-                                      lane.remaining == 0))
-        if under is None:
-            self._deliver()
+                self._last[slot] = toks[slot]
+            self._undelivered.append((slot, lane, toks[slot],
+                                      lane.remaining == 0, False))
+        self._deliver()
 
     def _note_step(self, step, made_ahead=False):
         """The counters of one plain decode dispatch."""
@@ -3316,16 +3543,22 @@ class LMEngine(Logger):
         moves one position on and owes one token fewer.  A lane that owes
         none now is FREED here — pages, tables, slot — so the next
         admission finds it; its request is answered when the step's token
-        is delivered (:meth:`_deliver`).  The writes of the step in flight
-        land in pages the host has released: the device runs dispatches
-        in order, and whoever takes such a page writes a row before
-        attending it, the rule every free slot's garbage write already
-        lives by."""
+        is delivered (:meth:`_deliver`).  Returns, per lane, whether this
+        step's token is its request's last.  The writes of the steps in
+        flight (two at most, ISSUE 39: the one counted here and the one
+        before it, unfetched) land in pages the host has released: the
+        device runs dispatches in order, and whoever takes such a page
+        writes a row before attending it, the rule every free slot's
+        garbage write already lives by; a dispatch that takes the page
+        is called after both and so runs after both."""
+        lasts = []
         for slot, lane in pairs:
             self._pos[slot] += 1
             lane.remaining -= 1
+            lasts.append(lane.remaining == 0)
             if lane.remaining == 0:
                 self._vacate_slot(slot, lane)
+        return lasts
 
     def _deliver(self):   # hot-path
         """The tokens the last fetch brought go to their lanes: the
@@ -3339,9 +3572,16 @@ class LMEngine(Logger):
         if not self._undelivered:
             return
         pending, self._undelivered = self._undelivered, []
-        for slot, lane, tok, last in pending:
+        for slot, lane, tok, last, first in pending:
+            if lane.request.future.done():
+                # (two steps' tokens may land at once, ISSUE 39: the first
+                # of them found the lane withdrawn, and its reply is made)
+                continue
             lane.emitted.append(tok)
             self._count_tokens(lane.request)
+            if first:
+                self.metrics.record_ttft(time.monotonic()
+                                         - lane.request.t_enq)
             if last:
                 self._reply(lane)
             elif lane.request.cancelled and self._lanes[slot] is lane:
@@ -3351,10 +3591,17 @@ class LMEngine(Logger):
         """A plain step's dispatch or fetch raised: its lanes fail
         (:meth:`_fail_active`), those it had already freed by count with
         them, and what was prepared under it goes (``unused``: so did its
-        own arguments, made ahead, before the program took them)."""
+        own arguments, made ahead, before the program took them).  Where
+        the storage stands (the fault fired before the program took it)
+        the dispatches still in flight are older and sound: their tokens
+        are fetched and delivered first.  Where it went down
+        (:meth:`_storage_lost`) they went with it, lanes and all."""
         if unused:
             self.metrics.inc("ahead_discarded")
         self._drop_ahead()
+        if self._flights and self._flights[-1].pairs is step.pairs:
+            self._flights.pop()          # its own, if it got that far
+        self._drain()
         self._fail_active([slot for slot, lane in step.pairs
                            if self._lanes[slot] is lane], exc)
         for _, lane in step.pairs:
@@ -3373,7 +3620,9 @@ class LMEngine(Logger):
         rec = self.recorder
         rec.mark(tracing.AHEAD_EMIT)
         self._note_step(step, made_ahead)
-        self._advance_by_count(step.pairs)
+        # (the step's own flight is the newest: what the count says of
+        # each token now is read when the token is fetched, a turn on)
+        self._flights[-1].lasts = self._advance_by_count(step.pairs)
         self._deliver()
         rec.mark(tracing.AHEAD_ADMIT)
         busy = self._admit_turn()
@@ -3730,17 +3979,20 @@ class LMEngine(Logger):
         jit call, wait, emit.  The paged plain driver does everything of
         that which needs no token UNDER its step (:meth:`_under_step`,
         between the jit call's return and the wait), so the turn after is
-        tick -> the prepared chunk's jit call -> the put of ``last`` ->
-        the step's jit call -> [under the step: deliver, shed and admit,
-        prepare] -> wait -> ``_last``.  Which it is, the loop reads off
+        tick -> the prepared chunk's jit call -> the step's jit call
+        (``last`` is on the device) -> [under the step: deliver, shed and
+        admit, prepare] -> wait for the tokens of the step BEFORE (ISSUE
+        39: two dispatches in flight).  Which it is, the loop reads off
         ``_ahead``: whatever a driver left there is used if it still
         holds (:meth:`_take_ahead`), and a driver that cannot split its
-        turn leaves nothing."""
+        turn leaves nothing; the old order begins with the outstanding
+        fetches (:meth:`_drain`)."""
         rec = self.recorder
         while True:
             # the recorder's turn (ISSUE 26): the phases marked below
             # partition it; no lock, no fence, no transfer
             rec.turn()
+            self._older = len(self._flights)
             # per-tick fault site (latency spikes / replica freezes —
             # a freeze here wedges the worker exactly like a hung
             # device call, the shape the health prober must catch);
@@ -3753,12 +4005,14 @@ class LMEngine(Logger):
                     # loop's turn: fail the in-flight lanes (the
                     # fault-isolation discipline) and keep ticking
                     self._drop_ahead()
+                    self._drain()
                     self._fail_active(
                         [i for i, ln in enumerate(self._lanes)
                          if ln is not None], e)
             self._maybe_apply_swap()
             ahead = self._take_ahead()
             if ahead is None:
+                self._drain()
                 self._deliver()
                 rec.mark(tracing.ADMIT)
                 busy = self._admit_turn()
@@ -3801,6 +4055,8 @@ class LMEngine(Logger):
                       if lane is not None and not lane.pending]
             step = self._take_step(active)
             if not active:
+                # (a tail chunk's token that was its request's only one)
+                self._drain()
                 continue
             if self._megastep_jit is not None:
                 self._step_megastep(active)
@@ -3808,6 +4064,8 @@ class LMEngine(Logger):
                 self._step_speculative(active)
             else:
                 self._step_plain(active, step)
+        self._drain()
+        self._deliver()
         rec.close()
         # drain: engine stopping fails whatever is still queued
         with self._cond:

@@ -1024,9 +1024,11 @@ class LoopRecorder:
         self._cur[COL_QUEUE] = queued
 
     def moe(self, held, hit):
+        """One more decode step's expert counts reached the host in the
+        open turn (a turn that drains fetches two steps' outputs)."""
         cur = self._cur
-        cur[COL_MOE_HELD] = held
-        cur[COL_MOE_HIT] = hit
+        cur[COL_MOE_HELD] += held
+        cur[COL_MOE_HIT] += hit
 
     def attn_pages(self, given, live):
         """One more dispatch of the open turn through the attention

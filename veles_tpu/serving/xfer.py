@@ -19,6 +19,10 @@ loudly:
   hot-path method reads a jit output.  Also the static host-sync
   pass's taint sink: a value routed through ``to_host`` is host data,
   so a following ``int()`` / ``numpy.asarray`` is not a finding.
+- :func:`start_to_host` — the same read begun and not waited for: the
+  copy is queued behind the program that makes the value, NOW, so a
+  program dispatched later cannot come between it and the host; a
+  following :func:`to_host` waits for it and transfers nothing twice.
 - :func:`arm` / :func:`disarm` / :func:`guard` — the RUNTIME WITNESS
   (same discipline as ``lockcheck``'s lock-order witness): the serving
   test suites arm a ``jax.transfer_guard`` mode via
@@ -118,3 +122,18 @@ def to_host(x):
     unfenced-timing rule credits."""
     import jax
     return jax.device_get(x)
+
+
+def start_to_host(x):
+    """Begin the EXPLICIT device→host transfer of a jit output (array
+    or tree of arrays) without waiting for it: the copy takes its place
+    behind the dispatch that produces the value, ahead of whatever is
+    dispatched after this call.  :func:`to_host` of the same value
+    later waits for this copy and returns it."""
+    import jax
+    # (explicit like ``to_host``'s: allowed under an armed witness; one
+    # None-check unarmed)
+    with (contextlib.nullcontext() if _mode is None
+          else jax.transfer_guard_device_to_host("allow")):
+        for leaf in jax.tree.leaves(x):
+            leaf.copy_to_host_async()
