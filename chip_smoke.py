@@ -966,6 +966,118 @@ def phase_latent(seed, lm=LATENT_LM, slots=16, page=1024, prompt_len=5500,
         "tokens", same, n_new)
 
 
+#: a latent stack that drafts with its own module, at the benchmark
+#: configuration's published widths (``joyai-llm-flash-ep8``), cut in depth,
+#: vocabulary and experts so that two engines and the reference fit
+MTP_LM = {
+    "model_type": "joyai_llm_flash", "hidden_size": 2048,
+    "num_attention_heads": 32, "q_lora_rank": 1536, "kv_lora_rank": 512,
+    "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,
+    "intermediate_size": 7168, "moe_intermediate_size": 768,
+    "vocab_size": 8192, "num_hidden_layers": 3, "first_k_dense_replace": 1,
+    "n_routed_experts": 8, "router_width": 64, "held_experts": [0, 8],
+    "num_experts_per_tok": 8, "n_shared_experts": 1,
+    "routed_scaling_factor": 2.5, "norm_topk_prob": True,
+    "scoring_func": "sigmoid", "topk_method": "noaux_tc", "n_group": 1,
+    "topk_group": 1, "rope_theta": 32000000, "rope_scaling": None,
+    "rms_norm_eps": 1e-6, "num_nextn_predict_layers": 1,
+    "initializer_std": 0.02,
+    "mtp_init": {"residual_std": 2e-06, "h_mix": 0.01},
+    "max_position_embeddings": 8192,
+}
+
+
+def phase_mtp(seed, lm=MTP_LM, slots=16, page=1024, prompt_len=2500,
+              n_new=161, gap_limit=1.5, kernel="auto"):
+    """Self-speculation on the serving path (ISSUE 40): requests through an
+    ``LMEngine`` whose model drafts with its own multi-token-prediction
+    module (the chunk program with the module's rows behind the stack's; one
+    decode dispatch a turn that verifies two rows a lane through the
+    absorbed kernel, decides acceptance and makes the next draft in the
+    graph; two dispatches in flight) against the SAME engine without
+    ``spec_k``, which must serve the same tokens up to bfloat16 near-ties
+    (each engine is held to the plain reference over its own tokens), and
+    the program's acceptance against the reference's module's."""
+    import jax
+    from benchmark.reference import joyai
+    from veles_tpu import model_config
+    from veles_tpu.serving import LMEngine
+    record = model_config.from_published(lm)
+    say("mtp", "%d layers and the module's, ranks %d / %d, %d of %d experts "
+        "held top-%d, page %d, %s; %d lanes", lm["num_hidden_layers"],
+        lm["q_lora_rank"], lm["kv_lora_rank"], lm["n_routed_experts"],
+        lm["router_width"], lm["num_experts_per_tok"], page, record.dtype,
+        slots)
+    weights = jax.tree.map(lambda a: a.astype(record.dtype),
+                           joyai.make_weights(seed, lm))
+    rng = numpy.random.RandomState(seed)
+    prompts = [rng.randint(0, lm["vocab_size"], n)
+               for n in (prompt_len, prompt_len // 3, prompt_len // 35 + 5)]
+
+    def serve(name, spec_k):
+        engine = LMEngine(weights, record,
+                          max_len=lm["max_position_embeddings"],
+                          slots=slots, prefill_chunk=page, paged_kv=True,
+                          attn_kernel=kernel, spec_k=spec_k,
+                          deadline_s=600.0, name=name)
+        with timed("mtp", "%s: engine start (every program and table "
+                   "width)" % name):
+            engine.start()
+        try:
+            with timed("mtp", "%s: %d requests, n_new %d"
+                       % (name, len(prompts), n_new)):
+                outs = [f.result(timeout=600) for f in
+                        [engine.submit(p, n_new) for p in prompts]]
+            snap = engine.metrics.snapshot()
+            check(engine.verify_pool_invariants()["used_pages"] == 0,
+                  "%s: pages still held after the requests", name)
+            check(snap["gauges"]["kv_storage_in_place"] == 1
+                  and snap["counters"].get("kv_storage_rebuilds", 0) == 0,
+                  "%s: the latent pools are not updated in place", name)
+            if on_tpu():
+                check(snap["gauges"]["attn_kernel_active"] == 1,
+                      "%s: attn_kernel='auto' fell back to the XLA path on "
+                      "the TPU: %s", name, engine._kernel_fallback_reason)
+            return outs, snap["counters"]
+        finally:
+            engine.stop()
+
+    plain, c0 = serve("mtp_plain", 0)
+    spec, c1 = serve("mtp_spec", 1)
+    drafts, accepted = c1["draft_tokens"], c1.get("draft_accepted", 0)
+    say("mtp", "decode dispatches %d -> %d; drafts accepted %d of %d; tokens "
+        "discarded %d; sent ahead %d, drains %d", c0["decode_dispatches"],
+        c1["decode_dispatches"], accepted, drafts,
+        c1.get("spec_tokens_discarded", 0),
+        c1.get("dispatches_sent_ahead", 0), c1.get("pipeline_drains", 0))
+    check(c1["spec_dispatches"] == c1["decode_dispatches"],
+          "decode dispatches that verified no draft")
+    check(c1["tokens_out"] == len(prompts) * n_new == c0["tokens_out"],
+          "tokens_out %d", c1["tokens_out"])
+    hits = positions = 0
+    for prompt, a, b in zip(prompts, plain, spec):
+        rows = numpy.arange(len(prompt) - 1, len(prompt) + n_new - 1)
+        for name, row in (("with the module", b), ("without", a)):
+            seq = numpy.concatenate([prompt, row])
+            ref = numpy.asarray(joyai.logits(weights, seq, rows, lm))
+            gap = ref.max(-1) - ref[numpy.arange(n_new), row]
+            check(float(gap.max()) <= gap_limit,
+                  "%s: served tokens lie %.4f below the reference's best",
+                  name, float(gap.max()))
+            if name == "with the module":
+                got = joyai.draft_hits(weights, seq, len(prompt), lm)
+                hits, positions = hits + got[0], positions + got[1]
+        same = int((numpy.cumsum(a != b) == 0).sum())
+        say("mtp", "prompt %d: with and without the module the same first "
+            "%d of %d tokens; widest gap below the reference's best %.4f",
+            len(prompt), same, n_new, float(gap.max()))
+    say("mtp", "acceptance %.1f %% (the reference's module, at every "
+        "position: %.1f %%)", 100.0 * accepted / max(drafts, 1),
+        100.0 * hits / max(positions, 1))
+    check(abs(accepted / max(drafts, 1) - hits / max(positions, 1)) <= 0.25,
+          "the program's acceptance is not the reference's module's")
+
+
 #: a stack of linear and full layers at the benchmark configuration's
 #: published widths (benchmark/configs/qwen3-next-80b-a3b-ep4.json), cut in
 #: depth, experts and vocabulary so that the phase is quick: one period
@@ -1327,7 +1439,8 @@ def main(argv=None):
                   ("serve", lambda: phase_serve(args.seed)),
                   ("kinds", lambda: phase_kinds(args.seed)),
                   ("latent", lambda: phase_latent(args.seed)),
-                  ("linear", lambda: phase_linear(args.seed))]
+                  ("linear", lambda: phase_linear(args.seed)),
+                  ("mtp", lambda: phase_mtp(args.seed))]
     if args.only:
         phases = [(name, run) for name, run in phases if name == args.only]
     failed = []

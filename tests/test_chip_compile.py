@@ -277,16 +277,20 @@ def program_text(fixture, program):
                  "sliding": ints(*lead[:-1], min(lead[-1], wide))},
                 ints(*lead[:-1]))
 
+    # the lanes' state between dispatches: their last tokens, or (last,
+    # draft, position) where the model's own module drafts (ISSUE 40)
+    state = (ints(engine.slots),) * 3 if engine._mtp else ints(engine.slots)
     if program == "chunk":
         lowered = engine._chunk_jit.lower(
             params, pools, tables(engine._max_pages),
-            ints(engine.prefill_chunk), ints(), ints(), ints(),
-            ints(engine.slots))
+            ints(engine.prefill_chunk + int(engine._mtp)), ints(), ints(),
+            ints(), state)
     else:
         width = int(program.rsplit("w", 1)[1])
         lowered = engine._step_jit.lower(
-            params, pools, tables(engine.slots, width), ints(engine.slots),
-            ints(engine.slots), jax.ShapeDtypeStruct(
+            params, pools, tables(engine.slots, width), state,
+            *(() if engine._mtp else (ints(engine.slots),)),
+            jax.ShapeDtypeStruct(
                 (engine.slots,), jnp.bool_, sharding=ints().sharding))
     text = _TEXTS[engine.name, program] = lowered.compile().as_text()
     assert "tpu_custom_call" in text
@@ -578,7 +582,8 @@ def test_a_decode_step_of_640_rows_takes_the_row_kernel(linear_engine,
 
 @pytest.mark.parametrize("program", PROGRAMS)
 @pytest.mark.parametrize("fixture", ["kernel_engine", "kinds_engine",
-                                     "latent_engine", "linear_engine"])
+                                     "latent_engine", "linear_engine",
+                                     "mtp_engine"])
 def test_engine_programs_read_the_weights_where_they_lie(request, fixture,
                                                          program):
     """ISSUE 31: compiled for the chip, no engine program holds a copy
@@ -591,6 +596,84 @@ def test_engine_programs_read_the_weights_where_they_lie(request, fixture,
     copies = compiled_param_copies(program_text(fixture, program),
                                    fixture[0].params)
     assert copies == 0, "%d weight-shaped copies in %s" % (copies, program)
+
+
+@pytest.fixture(scope="module")
+def mtp_engine(one_chip):
+    """A small ``LMEngine`` for a latent stack that DRAFTS WITH ITS OWN
+    MODULE (ISSUE 40; ``joyai_llm_flash``): the published head sizes and
+    ranks in bfloat16, a dense and an expert layer and the module's layer
+    behind them (three pools), 16 lanes, 4 held of 8 routed experts, top-4:
+    a verify step carries 16 x 2 x 4 = 128 assignment rows."""
+    from benchmark.reference import joyai
+    from veles_tpu import model_config
+    from veles_tpu.serving import LMEngine
+    cfg = {
+        "model_type": "joyai_llm_flash", "hidden_size": 384,
+        "num_attention_heads": 4, "q_lora_rank": 128, "kv_lora_rank": 512,
+        "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,
+        "intermediate_size": 512, "moe_intermediate_size": 128,
+        "vocab_size": 512, "num_hidden_layers": 2,
+        "first_k_dense_replace": 1, "n_routed_experts": 4,
+        "router_width": 8, "held_experts": [0, 4],
+        "num_experts_per_tok": 4, "n_shared_experts": 1,
+        "routed_scaling_factor": 2.5, "norm_topk_prob": True,
+        "scoring_func": "sigmoid", "rope_theta": 32000000,
+        "rope_scaling": None, "rms_norm_eps": 1e-6,
+        "num_nextn_predict_layers": 1, "initializer_std": 0.02}
+    params = joyai.make_weights(1, cfg)
+    monkeypatch = pytest.MonkeyPatch()
+    monkeypatch.setattr(PK, "on_tpu", lambda: True)
+    try:
+        engine = LMEngine(params, model_config.from_published(cfg),
+                          max_len=2048, slots=16, prefill_chunk=256,
+                          paged_kv=128, attn_kernel="auto", spec_k=1,
+                          name="aot_mtp")
+        assert engine._kernel_active and engine._mtp
+        yield described(engine, one_chip)
+    finally:
+        monkeypatch.undo()
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_programs_that_draft_update_every_pool_in_place(mtp_engine, program):
+    """ISSUE 40: the chunk program with the module's rows of the prompt
+    behind the stack's, and the verify-and-draft step (two rows a lane: a
+    row-kernel call a row, then the absorbed kernel at two query rows a
+    head; acceptance and the next draft in the graph) compile for the chip,
+    hold no copy with the pool's shape and list the stack's pools AND the
+    module's under ``input_output_alias``."""
+    from veles_tpu.serving.lm_engine import (compiled_grouped_matmuls,
+                                             compiled_storage_report)
+    engine = mtp_engine[0]
+    text = program_text(mtp_engine, program)
+    leaves = jax.tree.leaves(engine._kv_pools)
+    assert len(leaves) == 3 and leaves[0].shape == (129, 1, 256, 640)
+    copies, aliased = compiled_storage_report(text, leaves[0])
+    assert copies == 0, "%d whole-pool copies in %s" % (copies, program)
+    assert aliased == len(leaves)
+    # the module's attention's kernels are named by its own scope, so that
+    # the trace can tell where the module's part of a program begins
+    assert "%mtp.draft" in text and "%attn.latent" in text
+    # two expert layers (the stack's one and the module's): the chunk's 1024
+    # assignment rows take the row kernel, a step's 128 the compiler's op
+    assert compiled_grouped_matmuls(text) \
+        == ((0, 6) if program == "chunk" else (6, 0))
+
+
+@pytest.mark.parametrize("rows", [1, 2], ids=["decode", "verify"])
+def test_latent_decode_kernel_compiles_at_the_verify_cells_width(one_chip,
+                                                                 rows):
+    """The absorbed kernel at ``joyai-llm-flash-ep8.reason``'s shapes: 32
+    lanes, 32 heads, pages of 1024 over a table of 9, one query row a head
+    (a plain step) and two (a verify step)."""
+    text = compile_for(
+        one_chip,
+        lambda q, k, pt, ps: PK.paged_latent_decode(
+            q, k, pt, ps, 192 ** -0.5, interpret=False),
+        ((32, 32, rows, 640), BF16), ((289, 1, 1024, 640), BF16),
+        ((32, 9), I32), ((32,), I32))
+    assert "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("rows,k,n,groups", [

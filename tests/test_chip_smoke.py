@@ -132,6 +132,24 @@ def test_linear_phase_serves_the_references_tokens():
                             kernel="force", interpret=True)
 
 
+def test_mtp_phase_serves_the_same_tokens_with_the_module_drafting():
+    """ISSUE 40: a latent stack that drafts with its own module, kernels in
+    interpret mode (the verify step's two rows a lane through the row
+    kernel, a call a row, and the absorbed kernel at two query rows a head),
+    float32 so that the tokens with the module and without are the
+    reference's own and the same."""
+    tiny = dict(chip_smoke.MTP_LM, hidden_size=64, num_attention_heads=4,
+                q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=16,
+                qk_rope_head_dim=8, v_head_dim=16, intermediate_size=96,
+                moe_intermediate_size=32, vocab_size=96, n_routed_experts=4,
+                router_width=8, held_experts=[4, 4], num_experts_per_tok=2,
+                initializer_std=0.1, max_position_embeddings=64,
+                mtp_init={"residual_std": 0.002, "h_mix": 0.2},
+                dtype="float32")
+    chip_smoke.phase_mtp(3, lm=tiny, slots=16, page=8, prompt_len=21,
+                         n_new=31, gap_limit=1e-4, kernel="force")
+
+
 def test_serve_phase_treats_a_fallback_as_failure(monkeypatch):
     """On the chip attn_kernel='auto' must select the kernels.  The
     engine here is on the CPU and falls back; tell the phase it is on

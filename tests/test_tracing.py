@@ -1290,7 +1290,7 @@ class TestLoopRecorder:
         shown = {e["args"]["turn"] for e in loop}
         for e in sent:
             assert set(e["args"]) == {"dispatch", "turn", "fetch_turn",
-                                      "lanes"}
+                                      "lanes", "tokens"}
             assert e["args"]["turn"] in shown and e["dur"] > 0
             if e["name"] == "step_one":
                 assert e["args"]["fetch_turn"] == e["args"]["turn"]

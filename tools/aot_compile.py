@@ -8,7 +8,7 @@ script holds the whole-program ones, which take up to minutes and are run
 by hand::
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \\
-        python tools/aot_compile.py [alexnet] [lm] [latent] [linear] [mesh] [tp]
+        python tools/aot_compile.py [alexnet] [lm] [latent] [linear] [mtp] [mesh] [tp]
 
 - ``alexnet``: the graph loop's train step and the epoch-scan window
   program at minibatch 128, 227x227 crops, 1000 classes, fp32 and bf16;
@@ -204,7 +204,8 @@ def grouped_matmuls(name, text, eng, tokens):
     from veles_tpu.ops import moe
     from veles_tpu.serving.lm_engine import compiled_grouped_matmuls
     layers = sum(eng.cfg.ffn_kind(i, blk) == model_config.MOE
-                 for i, blk in enumerate(eng.params["blocks"]))
+                 for i, blk in enumerate(eng.params["blocks"])) \
+        + (eng.cfg.nextn if eng._mtp else 0)
     if not layers:
         return
     rows = tokens * eng.cfg.moe.top_k
@@ -241,21 +242,26 @@ def engine_programs(tag, eng, one_chip, widths):
         narrow = lead[:-1] + (min(lead[-1], eng._wt.width),)
         return {"full": s(lead), "sliding": s(narrow)}, s(lead[:-1])
 
+    # the lanes' state as it passes from dispatch to dispatch: their last
+    # tokens, or (last, draft, position) where the model's module drafts
+    state = (s((slots,)),) * 3 if eng._mtp else s((slots,))
     name = "%s prefill chunk (kernel)" % tag
     text = compile_(name, eng._chunk_jit, a_params, pools,
-                    tables(eng._max_pages), s((page,)), s(()), s(()), s(()),
-                    s((slots,)))
+                    tables(eng._max_pages), s((page + int(eng._mtp),)),
+                    s(()), s(()), s(()), state)
     storage_in_place(name, text, eng)
     grouped_matmuls(name, text, eng, page)
     for width in widths:
         name = "%s decode step width %d" % (tag, width)
         text = compile_(name, eng._step_jit, a_params, pools,
-                        tables(slots, width), s((slots,)), s((slots,)),
+                        tables(slots, width), state,
+                        *(() if eng._mtp else (s((slots,)),)),
                         s((slots,), jnp.bool_))
         if "tpu_custom_call" not in text:
             raise SystemExit("no Pallas kernel in the decode program")
         storage_in_place(name, text, eng)
-        grouped_matmuls(name, text, eng, slots)
+        grouped_matmuls(name, text, eng, slots * (eng.spec_k + 1
+                                                  if eng._mtp else 1))
 
 
 def lm(one_chip):
@@ -313,6 +319,32 @@ def latent(one_chip):
                         prefill_chunk=dep["prefill_chunk"],
                         paged_kv=dep["paged_kv"])
     engine_programs("engine latent", eng, one_chip, (1, eng._max_pages))
+
+
+def mtp(one_chip):
+    """ISSUE 40: a latent stack that drafts with its own module, at the
+    benchmark cell's configuration and geometry: the chunk program with the
+    module's rows of the prompt behind the stack's, and the verify-and-draft
+    step (two rows a lane through the absorbed kernel, the row-tiled grouped
+    matmul at 512 assignment rows, acceptance and the next draft in the
+    graph) at the narrowest and the widest table."""
+    import json
+    from benchmark.reference import joyai
+    from veles_tpu import model_config
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "joyai-llm-flash-ep8.json")) as f:
+        cfg = json.load(f)
+    dep = cfg["deployment"]
+    params = jax.tree.map(
+        lambda a: jnp.zeros(a.shape, a.dtype),
+        jax.eval_shape(lambda: joyai.make_weights(1, cfg)))
+    eng = kernel_engine(params, model_config.from_published(cfg),
+                        max_len=cfg["max_position_embeddings"],
+                        slots=dep["slots"],
+                        prefill_chunk=dep["prefill_chunk"],
+                        paged_kv=dep["paged_kv"], spec_k=dep["spec_k"])
+    engine_programs("engine mtp", eng, one_chip, (1, eng._max_pages))
 
 
 def linear(one_chip):
@@ -391,6 +423,8 @@ def main(argv):
         latent(one_chip)
     if "linear" in want:
         linear(one_chip)
+    if "mtp" in want:
+        mtp(one_chip)
     if "mesh" in want:
         mesh(topo)
     if "tp" in want:

@@ -201,8 +201,12 @@ def build_argparser():
                              "from the sequence's own n-grams and "
                              "verify them in one dispatch (multiple "
                              "tokens/dispatch on repetitive text, "
-                             "output bit-identical to greedy); 0 = "
-                             "one token per dispatch")
+                             "output bit-identical to greedy); a "
+                             "model that carries a multi-token-"
+                             "prediction module drafts with it "
+                             "instead (K = 1: the step verifies the "
+                             "draft and makes the next in the graph); "
+                             "0 = one token per dispatch")
     parser.add_argument("--serve-paged-kv", type=int, default=0,
                         metavar="PAGES",
                         help="with --serve-slots: paged KV cache — "

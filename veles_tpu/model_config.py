@@ -200,6 +200,12 @@ class ModelConfig:
     partial_rotary: float = 1.0
     #: RMSNorm gains are stored about zero: the norm multiplies by ``1 + w``
     norm_centred: bool = False
+    #: multi-token-prediction modules the tree carries under ``"mtp"``
+    #: (``pre_rms`` with ``latent``): each one more expert layer behind the
+    #: stack, which drafts the token after next from the last block's
+    #: output and the next token's embedding (``ops/transformer.py::
+    #: mtp_forward``); ``ffn_kinds`` names its layer behind the stack's
+    nextn: int = 0
 
     def __post_init__(self):
         if self.block not in ("pre_ln", "sandwich", "pre_rms"):
@@ -215,6 +221,10 @@ class ModelConfig:
         if self.latent is not None and self.linear is not None:
             raise ValueError("latent attention and linear layers do not "
                              "share a stack")
+        if self.nextn not in (0, 1) or (self.nextn and (
+                self.latent is None or self.hyper is not None)):
+            raise ValueError("one multi-token-prediction module at most, "
+                             "behind a latent stack of one residual stream")
         if self.attn_kinds is not None:
             bad = set(self.attn_kinds) - {SLIDING, FULL, LINEAR}
             if bad:
@@ -331,13 +341,15 @@ def of(cfg_or_heads, rope=False, window=None, sinks=0):
 
 def from_published(cfg):
     """The record of a published ``config.json`` (a dict under its own
-    keys), by ``model_type``.  ``afmoe`` and ``qwen3_next`` also read two
-    keys of a deployment's share where they are given: ``held_experts``
-    ``[lo, n]`` (this tree's experts, of ``router_width`` that the router
-    scores)."""
+    keys), by ``model_type``.  ``afmoe``, ``qwen3_next`` and
+    ``joyai_llm_flash`` also read two keys of a deployment's share where
+    they are given: ``held_experts`` ``[lo, n]`` (this tree's experts, of
+    ``router_width`` that the router scores)."""
     family = cfg.get("model_type")
     if family == "xing4_0":
         return _xing4(cfg)
+    if family == "joyai_llm_flash":
+        return _joyai(cfg)
     if family == "qwen3_next":
         return _qwen3_next(cfg)
     if family != "afmoe":
@@ -411,6 +423,30 @@ def _xing4(cfg):
                       route_scale=float(cfg["routed_scaling_factor"]),
                       shared=cfg.get("n_shared_experts", 0) > 0),
         dtype=cfg.get("dtype", "bfloat16"), eps=cfg["rms_norm_eps"])
+
+
+def _joyai(cfg):
+    """``model_type: joyai_llm_flash``: the latent part of ``xing4_0``
+    without YaRN (``rope_scaling`` null) over one residual stream, leading
+    dense layers then sigmoid-routed experts of which this tree holds a
+    share (``held_experts`` ``[lo, n]`` of ``n_routed_experts`` that the
+    router scores, as ``afmoe`` states it), and the multi-token-prediction
+    module LOADED: ``num_nextn_predict_layers`` modules (one at most) under
+    the tree's ``"mtp"``."""
+    if cfg.get("rope_scaling"):
+        raise ValueError("joyai_llm_flash: no rope_scaling")
+    if "hc_mult" in cfg:
+        raise ValueError("joyai_llm_flash: one residual stream")
+    record = _xing4(cfg)
+    nextn = int(cfg.get("num_nextn_predict_layers", 0))
+    held = cfg.get("held_experts")
+    return dataclasses.replace(
+        record, nextn=nextn,
+        ffn_kinds=record.ffn_kinds + (MOE,) * nextn,
+        moe=dataclasses.replace(
+            record.moe,
+            router_width=cfg.get("router_width", cfg["n_routed_experts"]),
+            held=None if held is None else tuple(held)))
 
 
 def _qwen3_next(cfg):

@@ -238,25 +238,28 @@ def _write_chunk_pages(pool, ptab, pos, rows):
 
 
 def latent_paged_chunk_step(p, x, pool, ptab, pos, cfg, attn_kernel=None,
-                            write_mask=None):
+                            write_mask=None, scope="attn.latent"):
     """``c`` positions per lane against the PAGED LATENT POOL:
     ``attention.mha_paged_chunk_step`` for the latent kind.
 
     x: (b, c, d); pool: (n_pages, 1, page, latent.row), one array a
     layer; ptab (b, m); pos (b,) traced.  The new rows are written through
     the table first (decode: ``paged_write``, one kernel call for 16 lanes
-    or more; a chunk under the prefill kernel: its whole page with one
-    update slice), then attention reads the pool: ABSORBED for a decode
+    or more, and one a row for a verify step's ``c`` rows a lane; a chunk
+    under the prefill kernel: its whole page with one update slice), then attention reads the pool: ABSORBED for a decode
     step (``attn_kernel='decode'``, or one query row a lane without
     kernels), EXPANDED for a chunk (``'prefill'``, or several rows).
-    Returns (out (b, c, d), pool)."""
+    ``scope`` names the Pallas calls in the device trace (the innermost
+    scope is the one a call takes): the stack's layers' ``attn.latent``, the
+    multi-token-prediction module's its own.  Returns (out (b, c, d),
+    pool)."""
     lat = cfg.latent
     b, c, _ = x.shape
     pos = jnp.asarray(pos)
     positions = pos[:, None] + jnp.arange(c)
     cos, sin = rotary(cfg, positions)
     absorbed = attn_kernel == "decode" if attn_kernel else c == 1
-    with jax.named_scope("attn.latent"):
+    with jax.named_scope(scope):
         q_nope, q_rope = queries(p, x, cfg, cos, sin, cached=True)
         rows = latent_rows(p, x, cfg, cos, sin, cached=True)  # (b, c, row)
         if attn_kernel == "prefill":
@@ -266,6 +269,15 @@ def latent_paged_chunk_step(p, x, pool, ptab, pos, cfg, attn_kernel=None,
             # (chunk == page and a page-aligned ``pos``: the kernel's
             # contract, which it checks)
             pool = _write_chunk_pages(pool, ptab, pos, rows)
+        elif attn_kernel == "decode" and c > 1:
+            # a verify step's c rows a lane: the row kernel takes ONE row a
+            # lane a call (two rows of a lane may share a tile), so the
+            # rows go a column at a time, each call on the pool the last
+            # one left
+            for j in range(c):
+                pool = paged_write(pool, ptab, pos + j,
+                                   rows[:, None, j:j + 1], write_mask,
+                                   kernel=True)
         else:
             pool = paged_write(pool, ptab, pos, rows[:, None], write_mask,
                                kernel=attn_kernel == "decode")

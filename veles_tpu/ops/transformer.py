@@ -447,16 +447,18 @@ def block_paged_chunk_step(blk, h, k_pool, v_pool, ptab, pos, n_heads,
 
 
 def block_latent_chunk_step(blk, h, pool, ptab, pos, cfg,
-                            attn_kernel=None, write_mask=None, layer=0):
+                            attn_kernel=None, write_mask=None, layer=0,
+                            scope="attn.latent"):
     """:func:`block_paged_chunk_step` for the latent kind: one pool a
-    layer (``ops/latent.py::latent_paged_chunk_step``).  Returns (h, pool,
-    the expert layer's counts or None)."""
+    layer (``ops/latent.py::latent_paged_chunk_step``; ``scope`` names its
+    Pallas calls in the device trace).  Returns (h, pool, the expert
+    layer's counts or None)."""
     from veles_tpu.ops.latent import latent_paged_chunk_step
 
     def attend(p, hn):
         return latent_paged_chunk_step(p, hn, pool, ptab, pos, cfg,
                                        attn_kernel=attn_kernel,
-                                       write_mask=write_mask)
+                                       write_mask=write_mask, scope=scope)
 
     return _wire(blk, h, cfg, layer, attend)
 
@@ -566,11 +568,135 @@ def paged_chunk_apply(params, tokens, pools, ptab, pos, n_heads,
         return h, new_pools
     if not counts:
         return h, new_pools, jnp.zeros(4, jnp.int32)
+    return h, new_pools, _sum_counts(counts)
+
+
+# ------------------------------------------- multi-token prediction (MTP)
+def mtp_forward(params, h, nxt, pool, ptab, pos, cfg, attn_kernel=None,
+                write_mask=None):
+    """The multi-token-prediction module (DeepSeek-V3, arXiv:2412.19437
+    section 2.2; ``cfg.nextn``) over ``c`` rows a lane: row ``i`` takes
+    the last main block's output ``h_i`` (b, c, d; BEFORE the final norm)
+    and the token that follows, ``nxt`` (b, c): ``x'_i = W_eh
+    [rms_e(Emb(t_{i+1})) ; rms_h(h_i)]``, then one whole expert layer with
+    latent attention over its OWN pool of latent rows (``pool``, the
+    module's, at the rotary position of row ``i``).  Returns (y (b, c, d)
+    before the module's final norm, the pool, the expert layer's counts).
+    Runs under the scope ``mtp.draft``, which its attention's Pallas calls
+    take for their own (a call is named by the innermost scope around it,
+    and the stack's layers' are ``attn.latent``): the first of them marks,
+    in the device trace, where the module's part of a program begins."""
+    import jax
+    with jax.named_scope("mtp.draft"):
+        return block_latent_chunk_step(
+            params["mtp"][0]["block"], mtp_inputs(params, h, nxt, cfg), pool,
+            ptab, pos, cfg, attn_kernel=attn_kernel, write_mask=write_mask,
+            layer=len(params["blocks"]), scope="mtp.draft")
+
+
+def mtp_inputs(params, h, nxt, cfg):
+    """``x'_i = W_eh [rms_e(Emb(t_{i+1})) ; rms_h(h_i)]``, the embedding
+    half first: what the module's block reads, as a float32 residual
+    stream."""
+    import jax.numpy as jnp
+    mod = params["mtp"][0]
+    e = jnp.take(params["embed"], nxt, axis=0).astype(jnp.float32)
+    x = jnp.concatenate(
+        [rms_norm(e, mod["enorm"], cfg.eps, cfg.dtype),
+         rms_norm(h, mod["hnorm"], cfg.eps, cfg.dtype)], axis=-1)
+    return cfg_matmul(cfg, x, mod["eh_proj"]).astype(jnp.float32)
+
+
+def mtp_logits(params, y, cfg):
+    """The module's own final norm, then the MAIN model's head: float32
+    logits of the token two places on."""
+    import jax.numpy as jnp
+    y = rms_norm(y, params["mtp"][0]["norm"], cfg.eps, cfg.dtype)
+    return jnp.matmul(y, params["head"], preferred_element_type=jnp.float32)
+
+
+def _sum_counts(counts):
+    """Expert layers' counts in one: assignments held, elsewhere and
+    experts hit add up over the layers; the largest expert load is the
+    largest of any layer."""
+    import jax.numpy as jnp
     counts = jnp.stack(counts)
-    # assignments held, elsewhere and experts hit add up over the expert
-    # layers; the largest expert load is the largest of any layer
-    return h, new_pools, jnp.concatenate(
-        [counts[:, :3].sum(0), counts[:, 3:].max(0)])
+    return jnp.concatenate([counts[:, :3].sum(0), counts[:, 3:].max(0)])
+
+
+def mtp_chunk_apply(params, tokens, nxt, pools, ptab, pos, cfg, last_idx,
+                    tail, attn_kernel=None):
+    """A prompt chunk of ONE lane through the stack and then through the
+    module, so that the module's pool holds its rows of the prompt too:
+    :func:`paged_chunk_apply` over ``pools[:-1]``, the greedy token after
+    row ``last_idx``, and the module over rows ``(h_i, t_{i+1})`` with
+    ``nxt`` (1, c) the chunk's tokens one place on (the next chunk's first
+    behind the last; in a ``tail`` chunk the token just picked takes its
+    place at ``last_idx``: it is what the prompt's last row is followed by).
+    Returns
+    (pools, that token, the module's draft of the one after it): the first
+    draft comes with the first token."""
+    import jax
+    import jax.numpy as jnp
+    h, main = paged_chunk_apply(params, tokens, pools[:-1], ptab, pos, cfg,
+                                attn_kernel=attn_kernel)
+    row = jax.lax.dynamic_slice_in_dim(h, last_idx, 1, axis=1)
+    tok = jnp.argmax(head_logits(params, row, cfg)[:, 0, :],
+                     axis=-1).astype(jnp.int32)
+    nxt = jnp.where(tail & (jnp.arange(nxt.shape[1])[None] == last_idx),
+                    tok[:, None], nxt)
+    y, pool, _ = mtp_forward(params, h, nxt, pools[-1][0], ptab, pos, cfg,
+                             attn_kernel=attn_kernel)
+    y = jax.lax.dynamic_slice_in_dim(y, last_idx, 1, axis=1)
+    draft = jnp.argmax(mtp_logits(params, y, cfg)[:, 0, :],
+                       axis=-1).astype(jnp.int32)
+    return main + [(pool,)], tok[0], draft[0]
+
+
+def mtp_verify_step(params, pools, ptab, last, draft, pos, live, cfg,
+                    attn_kernel=None):
+    """ONE decode dispatch of a model that drafts with its own module
+    (k = 1): every live lane at position ``p`` feeds ``[last, draft]`` at
+    ``p, p + 1`` through the stack (their latent rows written), the float32
+    argmaxes after the two rows are ``g_{p+1}, g_{p+2}``; the draft is
+    ACCEPTED where it equals ``g_{p+1}`` (scope ``spec.verify``), and the
+    lane then yields both, else ``g_{p+1}`` alone: exactly what plain
+    greedy decoding yields.  The module runs rows ``(h_p, g_{p+1})`` and
+    ``(h_{p+1}, g_{p+2})`` (the second is of use only where the draft was
+    accepted; a rejected draft's rows, the stack's and the module's alike,
+    are dead and the next step overwrites them) and drafts from the last
+    valid one.  A lane that is not live feeds token 0 at position 0 and its
+    writes go to the scratch page.
+
+    Returns (pools, (last, draft, pos) as the next dispatch takes them,
+    tokens (b, 3): the two tokens and behind them the draft just made,
+    count (b,) of the two that are real (0 for a lane that is not live),
+    the expert layers' counts)."""
+    import jax
+    import jax.numpy as jnp
+    at = jnp.where(live, pos, 0)
+    toks = jnp.where(live[:, None], jnp.stack([last, draft], axis=1), 0)
+    h, main, counts = paged_chunk_apply(
+        params, toks, pools[:-1], ptab, at, cfg, attn_kernel=attn_kernel,
+        write_mask=live, with_stats=True)
+    picked = jnp.argmax(head_logits(params, h, cfg),
+                        axis=-1).astype(jnp.int32)            # (b, 2)
+    with jax.named_scope("spec.verify"):
+        accepted = live & (toks[:, 1] == picked[:, 0])
+        count = jnp.where(live, 1 + accepted.astype(jnp.int32), 0)
+    y, pool, stats = mtp_forward(
+        params, h, picked, pools[-1][0], ptab, at, cfg,
+        attn_kernel=attn_kernel, write_mask=live)
+    y = jnp.where(accepted[:, None], y[:, 1], y[:, 0])
+    new_draft = jnp.argmax(mtp_logits(params, y, cfg),
+                           axis=-1).astype(jnp.int32)
+    state = (jnp.where(live, jnp.where(accepted, picked[:, 1],
+                                       picked[:, 0]), last),
+             jnp.where(live, new_draft, draft),
+             pos + count)
+    return (main + [(pool,)], state,
+            jnp.concatenate([picked, new_draft[:, None]], axis=1), count,
+            _sum_counts([counts, stats]))
 
 
 def propose_draft_in_graph(hist, hlen, k, max_ngram=3):

@@ -530,7 +530,9 @@ def serve_lm(workflow, host="127.0.0.1", port=8180, max_new=256,
     autoregressive continuation: POST ``{"input": [[tok, ...]],
     "n_new": N, "temperature": T, "top_k": K, "seed": S}`` to
     ``/predict`` returns ``{"tokens": [[...]]}`` — prompt plus
-    continuation per row.
+    continuation per row (with ``"drafts": true`` and an engine whose
+    model drafts with its own module also ``"drafts"``: per row, ``[n,
+    token]`` where the module put ``token`` for the n-th new token).
 
     ``slots > 0`` starts a :class:`veles_tpu.serving.LMEngine` and
     routes GREEDY requests (temperature 0, the default) through
@@ -822,7 +824,7 @@ def serve_lm(workflow, host="127.0.0.1", port=8180, max_new=256,
         # speculative decoding needs spec_k cache positions of write
         # headroom; a prompt too close to the cache cap falls back to
         # the direct path instead of being refused
-        eng_headroom = headroom - (engine.spec_k if engine is not None
+        eng_headroom = headroom - (engine.headroom if engine is not None
                                    else 0)
         if engine is not None and temperature == 0.0 \
                 and eng_headroom >= 1:
@@ -837,9 +839,14 @@ def serve_lm(workflow, host="127.0.0.1", port=8180, max_new=256,
                 # load_gen --lm aggregates
                 return {"tokens": toks.tolist(), "replicas": reps,
                         "weights_version": vers}
-            toks, vers = engine.generate(
-                prompt, min(want, eng_headroom), return_versions=True)
-            return {"tokens": toks.tolist(), "weights_version": vers}
+            toks, vers, drafts = engine.generate(
+                prompt, min(want, eng_headroom), return_versions=True,
+                return_drafts=True)
+            reply = {"tokens": toks.tolist(), "weights_version": vers}
+            if request.get("drafts"):
+                # what the model's own module drafted, accepted or not
+                reply["drafts"] = drafts
+            return reply
         # decode length: round the request UP to a tier; near the cache
         # cap fall back to the largest tier that fits (or the exact
         # headroom when even the smallest doesn't — rare, self-limiting)

@@ -247,6 +247,40 @@ in ``/metrics.json`` add up to ``decode_dispatches`` on this driver.
 ``checkpoint()`` reads the host's books only, which the count keeps
 exact whatever is in flight.
 
+A MODEL THAT DRAFTS WITH ITS OWN MODULE (ISSUE 40).  A record with
+``nextn`` (``joyai_llm_flash``: latent attention and one multi-token-
+prediction module, ``ops/transformer.py::mtp_forward``) under ``spec_k=1``
+takes that same order, and what breaks its one assumption (a step yields
+one token a lane) is carried through it.  The step program, still
+``step_all``, feeds every live lane ``[last, draft]`` at its position and
+the next, picks the greedy token after each row, ACCEPTS the draft where it
+is the first pick (so what is emitted is plain greedy decoding's, whatever
+was drafted), runs the module over both rows and drafts from the last valid
+one; the chunk program runs the module over the prompt too and hands the
+first draft with the first token.  ``(last, draft, position)`` stay on the
+device from dispatch to dispatch (``_last_dev`` is that triple); a lane
+that does not decode is masked to position 0 and writes to the scratch
+page.  The host fetches, late as ever, ``(tokens (slots, 2), count
+(slots,))`` and settles there what it could not know at the call
+(:meth:`LMEngine._settle_counts`): the confirmed position ``_pos`` and
+``remaining`` move by the count, a second token past ``n_new`` is dropped
+and counted.  Before that it knows a BOUND: every step in flight yields one
+token at least and ``spec_k + 1`` at most, so tables, widths and guards
+cover ``_pos + _unseen`` (:attr:`headroom` positions are reserved past
+``prompt + n_new``), and a lane that is owed no more than it has steps in
+flight is freed by count when its last step goes out
+(:meth:`_advance_by_count`).  A lane whose drafts were accepted has all its
+tokens a fetch or two earlier than the bound says: it rides the steps in
+flight behind its last token, what they make is dropped
+(``spec_tokens_discarded``), and it leaves with the next step's count; no
+preparation is dropped for it.  Counters: ``draft_tokens`` /
+``draft_accepted`` (one draft a live lane a step, counted at the fetch),
+``spec_dispatches``, ``spec_lane_steps``, ``spec_tokens_kept``,
+``spec_tokens_discarded``.  A record without a module keeps the host-side
+n-gram drafts of ``_step_speculative``, a synchronous driver; per-layer
+kinds (a sliding layer's released pages, a linear layer's state) refuse
+``spec_k``: a rejected draft there needs a snapshot to go back to.
+
 Decoding is GREEDY (temperature 0) — bit-identical to
 ``ops/transformer.py::generate`` for the same prompt WHATEVER fast-path
 combination is enabled, which is the serving contract (sampled
@@ -317,7 +351,7 @@ class _Slot:
     """Host-side lane state; device state lives in the shared caches."""
 
     __slots__ = ("request", "emitted", "remaining", "pending", "pinned",
-                 "cursor", "pages")
+                 "cursor", "pages", "inflight", "drafts")
 
     def __init__(self, request):
         self.request = request
@@ -333,6 +367,15 @@ class _Slot:
         #: paged mode: page ids backing this lane's table row, in
         #: lane-local order (owned AND referenced; released at finish)
         self.pages = []
+        #: a lane that drafts with the model's own module (ISSUE 40): its
+        #: decode steps called and not fetched yet, each of which yields
+        #: one token or two, the host cannot know which
+        self.inflight = 0
+        #: and what its steps drafted: (n, token) where the module put
+        #: ``token`` for the request's n-th new token (from 0), whether or
+        #: not the next step accepted it; handed over with the reply
+        #: (``future.drafts``)
+        self.drafts = []
 
 
 #: one prompt chunk with its arguments on the device and its page steps
@@ -803,8 +846,11 @@ class LMEngine(Logger):
                     (prefix_cache, "prefix_cache (the radix trie shares "
                      "pages of ONE table; a sliding layer's pages are "
                      "released under it)"),
-                    (self.spec_k, "spec_k (the verify program writes k "
-                     "positions ahead through one table)"),
+                    (self.spec_k and self.cfg.by_kind, "spec_k (a "
+                     "rejected draft's rows are dead until the next step "
+                     "overwrites them, which pages of ONE table allow: a "
+                     "sliding layer's released pages and a linear layer's "
+                     "recurrent state would need a snapshot to go back to)"),
                     (self.megastep, "megastep (the fused scan program "
                      "carries one table and no window release)"),
                     (self.tp >= 2, "tp >= 2 (lm_param_specs shards the "
@@ -815,6 +861,20 @@ class LMEngine(Logger):
                         % (what, self.cfg.block,
                            " with per-layer attention kinds"
                            if self.cfg.by_kind else ""))
+        #: the model drafts with its own multi-token-prediction module
+        #: (ISSUE 40): the decode step verifies the draft and makes the
+        #: next one in the graph, and yields one token or two a lane
+        self._mtp = bool(self.spec_k and self.cfg.nextn)
+        if self._mtp and self.spec_k != 1:
+            raise ValueError(
+                "LMEngine: a model with one multi-token-prediction module "
+                "drafts one token a step: spec_k must be 1 (got %d)"
+                % self.spec_k)
+        #: cache positions a lane may write past ``prompt + n_new``: the
+        #: drafts of one verify step; with the module drafting, two steps
+        #: may be in flight that the host has not seen the counts of
+        self.headroom = (2 * (self.spec_k + 1) if self._mtp
+                         else self.spec_k)
         if not self._paged and self.cfg.latent is not None:
             raise ValueError(
                 "LMEngine: latent attention needs paged_kv — its cache is "
@@ -975,8 +1035,7 @@ class LMEngine(Logger):
                 # included (width x 2 x layers if nothing were padded)
                 self.metrics.set_gauge(
                     "kv_bytes_per_token",
-                    head_dim * embed.dtype.itemsize
-                    * len(params["blocks"]))
+                    head_dim * embed.dtype.itemsize * self._n_pools())
             if self.cfg.linear is not None:
                 # two kinds of cache in one manager (ISSUE 36): a slot of
                 # recurrent state and convolution tail a lane for every
@@ -1017,7 +1076,7 @@ class LMEngine(Logger):
         #: (cache kind, its layers) for the count of the attention
         #: kernels' page steps (:meth:`_note_attn_dispatch`)
         self._layers_of_kind = sorted(collections.Counter(
-            self.cfg.kind(i) for i in range(len(self.params["blocks"]))
+            self.cfg.kind(i) for i in range(self._n_pools())
             if self.cfg.kind(i) != model_config.LINEAR).items())
         self._trie = (RadixPrefixCache(
             prefix_cache, self.prefill_chunk,
@@ -1026,6 +1085,11 @@ class LMEngine(Logger):
         #: per-slot device-facing scalars, host-owned between ticks
         self._pos = numpy.zeros(self.slots, numpy.int32)
         self._last = numpy.zeros(self.slots, numpy.int32)
+        #: positions a lane may be ahead of ``_pos`` (ISSUE 40): with the
+        #: module drafting ``_pos`` is what the fetched counts add up to,
+        #: and every step in flight moves its lane ``spec_k + 1`` on at
+        #: most; all zeros on every other driver
+        self._unseen = numpy.zeros(self.slots, numpy.int32)
         self._lanes = [None] * self.slots
         self._free = list(range(self.slots))
         #: the turn's early stretch (ISSUE 37), the worker thread's own:
@@ -1142,6 +1206,12 @@ class LMEngine(Logger):
         # every dispatch (and trip the armed transfer guard)
         return jax.device_put(params)
 
+    def _n_pools(self):
+        """Layers that hold a cache: the stack's, and the module's own
+        where it drafts (ISSUE 40)."""
+        return len(self.params["blocks"]) + (self.cfg.nextn if self._mtp
+                                             else 0)
+
     def _zero_storage(self):
         """Fresh zero KV storage — one (k, v) pair per block of the
         pool's (paged) or the caches' (contiguous) shape — placed per
@@ -1169,7 +1239,7 @@ class LMEngine(Logger):
         shapes = [self._window_shape if self._wt is not None
                   and self.cfg.kind(i) == model_config.SLIDING
                   else self._storage_shape
-                  for i in range(len(self.params["blocks"]))]
+                  for i in range(self._n_pools())]
         if self.cfg.latent is not None:
             # ONE pool a layer: its rows are (c_kv, k_rope)
             return [(zeros(shape),) for shape in shapes]
@@ -1184,7 +1254,11 @@ class LMEngine(Logger):
         import jax
         where = (self._repl_shard if self._mesh is not None
                  else self._device)
-        return jax.device_put(numpy.zeros(self.slots, numpy.int32), where)
+        zeros = numpy.zeros(self.slots, numpy.int32)
+        if self._mtp:
+            # (last, draft, position): all three stay on the device
+            return tuple(jax.device_put(zeros, where) for _ in range(3))
+        return jax.device_put(zeros, where)
 
     def _storage(self):
         return self._kv_pools if self._paged else self._caches
@@ -1465,6 +1539,42 @@ class LMEngine(Logger):
             toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return (pools, toks, *counts)
 
+        if self._mtp:
+            # the model drafts with its own module (ISSUE 40): the same
+            # two programs under the same names, the chunk with the
+            # module's rows of the prompt behind the stack's, the step
+            # verifying a draft a lane and making the next
+            from veles_tpu.ops.transformer import (mtp_chunk_apply,
+                                                   mtp_verify_step)
+            slots = self.slots
+
+            def chunk_slot(params, pools, ptab, tokens, start, last_idx,
+                           first_at, state):
+                # ``tokens``: the chunk's and the one behind them (the
+                # module's row i takes token i + 1); ``state``: the lanes'
+                # (last, draft, position) as they lie on the device, given
+                # back with the chunk's first token, the module's draft of
+                # the one after it and the prompt's length written at slot
+                # ``first_at`` (a tail chunk's; -1: as they came)
+                pools, tok, draft = mtp_chunk_apply(
+                    params, tokens[None, :-1], tokens[None, 1:], pools,
+                    ptab[None], start[None], cfg, last_idx, first_at >= 0,
+                    attn_kernel="prefill" if kern else None)
+                here = jnp.arange(slots) == first_at
+                last, drafts, pos = state
+                return pools, (jnp.where(here, tok, last),
+                               jnp.where(here, draft, drafts),
+                               jnp.where(here, start + last_idx + 1, pos))
+
+            def step_all(params, pools, ptabs, state, live):
+                # ONE dispatch verifies every live lane's draft and makes
+                # its next: (pools, the state the next dispatch takes,
+                # tokens (slots, 2), how many of them are real, the expert
+                # layers' counts); the host fetches the last three, late
+                return mtp_verify_step(
+                    params, pools, ptabs, *state, live, cfg,
+                    attn_kernel="decode" if kern else None)
+
         def page_copy(pools, src, dst):
             # copy-on-write: duplicate one page across every block so
             # the writer owns ``dst`` exclusively and the other
@@ -1487,14 +1597,14 @@ class LMEngine(Logger):
         self._chunk_install_jit = None
         self._chunk_extract_jit = None
         self._verify_jit = None
-        if self.spec_k:
+        if self.spec_k and not self._mtp:
             def verify_all(params, pools, ptabs, toks, pos):
                 # toks (slots, k+1) = [last committed, draft…] per lane;
                 # returns the greedy argmax AFTER each fed position
                 h, pools = paged_chunk_apply(
                     params, toks, pools, ptabs, pos, cfg,
                     attn_kernel="decode" if kern else None)
-                logits = head_logits(params, h)      # (slots, k+1, v)
+                logits = head_logits(params, h, cfg)  # (slots, k+1, v)
                 return pools, jnp.argmax(
                     logits, axis=-1).astype(jnp.int32)
 
@@ -1703,8 +1813,9 @@ class LMEngine(Logger):
             self._kv_pools, self._last_dev = self._chunk_jit(
                 self.params, self._kv_pools,
                 self._table_args(ptabs[0], 0),
-                xfer.to_device(numpy.zeros(self.prefill_chunk,
-                                           numpy.int32)), zero, zero,
+                xfer.to_device(numpy.zeros(
+                    self.prefill_chunk + int(self._mtp), numpy.int32)),
+                zero, zero,
                 xfer.to_device(-1, numpy.int32), self._zero_last())
             self._kv_pools = self._page_copy_jit(self._kv_pools, zero,
                                                  zero)
@@ -1735,7 +1846,7 @@ class LMEngine(Logger):
                 went_in = self._kv_pools[0][0]
                 self._kv_pools, self._last_dev = self._step_jit(
                     self.params, self._kv_pools, wtab, self._last_dev,
-                    zeros, none)[:2]
+                    *(() if self._mtp else (zeros,)), none)[:2]
         else:
             tok, rows = self._prefill_jit(
                 self.params,
@@ -2018,15 +2129,15 @@ class LMEngine(Logger):
             raise ValueError("empty prompt")
         if n_new < 1:
             raise ValueError("n_new must be >= 1")
-        if len(prompt) + n_new + self.spec_k > self.max_len:
-            extra = (" (+%d speculative headroom, spec_k)" % self.spec_k
+        if len(prompt) + n_new + self.headroom > self.max_len:
+            extra = (" (+%d speculative headroom, spec_k)" % self.headroom
                      if self.spec_k else "")
             raise ValueError("prompt %d + n_new %d%s exceeds the engine "
                              "cache length %d"
                              % (len(prompt), n_new, extra, self.max_len))
         demand = 0
         if self._paged:
-            span = len(prompt) + n_new + self.spec_k
+            span = len(prompt) + n_new + self.headroom
             demand = -(-span // self.prefill_chunk)
             if demand > self._pool.num_pages:
                 raise ValueError(
@@ -2120,12 +2231,16 @@ class LMEngine(Logger):
             self._cond.notify()
         return req.future
 
-    def generate(self, prompts, n_new, return_versions=False):
+    def generate(self, prompts, n_new, return_versions=False,
+                 return_drafts=False):
         """Decode a whole (b, s) prompt batch; returns (b, s + n_new)
         int32 — prompt plus greedy continuation per row (rows decode
         concurrently across slots; with ``return_versions`` also the
         ``weights_version`` that served each row — rows straddling a
-        hot swap carry different stamps).  All-or-nothing: if a later
+        hot swap carry different stamps; with ``return_drafts`` also, per
+        row, what the model's own module drafted: ``[n, token]`` where it
+        put ``token`` for the row's n-th new token, accepted or not;
+        empty where nothing drafts).  All-or-nothing: if a later
         row is refused (Overloaded/...), the rows already queued are
         CANCELLED instead of decoding to discarded results — a rejected
         batch must not keep consuming slots exactly when the engine is
@@ -2143,10 +2258,13 @@ class LMEngine(Logger):
             for f in futures:
                 self._cancel(f.request)
             raise
-        out = numpy.concatenate([prompts, news], axis=1)
+        out = (numpy.concatenate([prompts, news], axis=1),)
         if return_versions:
-            return out, [getattr(f, "version", None) for f in futures]
-        return out
+            out += ([getattr(f, "version", None) for f in futures],)
+        if return_drafts:
+            out += ([[list(d) for d in getattr(f, "drafts", ())]
+                     for f in futures],)
+        return out if len(out) > 1 else out[0]
 
     def _cancel(self, req):
         """Withdraw a request: dequeue it if still queued; if already in
@@ -2263,7 +2381,7 @@ class LMEngine(Logger):
         # that strands already-re-admitted futures
         for entry in entries:
             span = len(entry["prompt"]) + int(entry["n_new"]) \
-                + self.spec_k
+                + self.headroom
             if span > self.max_len:
                 raise ValueError(
                     "journaled request rid=%s needs %d cache positions "
@@ -2749,10 +2867,12 @@ class LMEngine(Logger):
         writing ``span`` positions per lane: the smallest power-of-two
         (capped at max_pages) covering EVERY slot's frontier —
         ``_pos`` includes prefilling lanes' parked frontiers and the
-        inactive lanes' 0, so the batched step's garbage writes always
-        land inside the sliced table (take_along_axis would otherwise
-        CLAMP an out-of-range page lookup onto a live page)."""
-        need = -(-(int(self._pos.max()) + span) // self.prefill_chunk)
+        inactive lanes' 0 (and ``_unseen`` what the steps in flight may
+        have added to a lane that drafts, ISSUE 40), so the batched step's
+        garbage writes always land inside the sliced table
+        (take_along_axis would otherwise CLAMP an out-of-range page lookup onto a live page)."""
+        need = -(-(int((self._pos + self._unseen).max()) + span)
+                 // self.prefill_chunk)
         for w in self._width_ladder:
             if w >= need:
                 return w
@@ -2993,12 +3113,19 @@ class LMEngine(Logger):
         # (the chunk's last real row: a whole chunk's own last, whose
         # token nobody reads)
         last_idx = (req.true_len - 1 - start) if is_tail else C - 1
+        fed = tokens
+        if self._mtp:
+            # the module's row i takes token i + 1: the chunk's tokens and
+            # the one behind them (a tail chunk's is made in the graph)
+            fed = numpy.zeros(C + 1, numpy.int32)
+            upto = min(start + C + 1, req.true_len)
+            fed[:upto - start] = req.prompt[start:upto]
         try:
             self._cow_guard(slot, lane, start, start + C)
             if self._wt is not None:
                 self._slide_window(slot, start, start + C)
             args = (self._table_args(self._page_tables[slot], slot),
-                    xfer.to_device(tokens, numpy.int32),
+                    xfer.to_device(fed, numpy.int32),
                     xfer.to_device(start, numpy.int32),
                     xfer.to_device(last_idx, numpy.int32),
                     # (where the program writes its token among the
@@ -3045,6 +3172,8 @@ class LMEngine(Logger):
                 rec.returned(sent)
                 if self._late_fetch:
                     self._last_dev = last
+                    if self._mtp:
+                        last = last[0]       # (last, draft, position)
                     if is_tail:
                         xfer.start_to_host(last)
                 self._tfence(self._kv_pools, req.trace is not None)
@@ -3170,6 +3299,7 @@ class LMEngine(Logger):
             self._free.append(slot)
         self._pos[slot] = 0
         self._last[slot] = 0
+        self._unseen[slot] = 0
         if self._paged:
             self._page_tables[slot, :] = KVPagePool.SCRATCH
         if self._state_shapes is not None:
@@ -3201,6 +3331,7 @@ class LMEngine(Logger):
             # stamped with the generation that produced these tokens —
             # the mixed-fleet attribution a rolling deploy needs
             fut.version = self.weights_version
+            fut.drafts = lane.drafts
             fut.set_result(numpy.asarray(lane.emitted, numpy.int32))
 
     @contextlib.contextmanager
@@ -3330,8 +3461,13 @@ class LMEngine(Logger):
                     # (the step before is still unfetched)
                     self.metrics.inc("dispatches_sent_ahead")
                 self._last_dev = out[1]
-                xfer.start_to_host(out[1:])
-                self._flights.append(_Flight(sent, out[1:], pairs))
+                # (what the host fetches: the tokens, which are the state
+                # itself but where the module drafts: there the state is
+                # (last, draft, position) and the tokens and their count
+                # come behind it)
+                outs = out[2:] if self._mtp else out[1:]
+                xfer.start_to_host(outs)
+                self._flights.append(_Flight(sent, outs, pairs))
                 under()
                 n = self._older
                 if self._ahead is None or self._ahead.step is None:
@@ -3372,10 +3508,18 @@ class LMEngine(Logger):
             rec.waiting(flight.sent,
                         tracing.STEP_FETCH if in_step and not i else None)
             toks, *counts = xfer.to_host(flight.outs)
+            drafted = self._mtp and not flight.first
             rec.fetched(flight.sent,
                         tracing.STEP_EMIT if in_step and i == n - 1
-                        else None)
+                        else None,
+                        int(counts[0].sum()) if drafted
+                        else len(flight.pairs))
             self._flights.popleft()
+            if drafted:
+                self._note_moe(counts[1])
+                self._settle_counts(flight.pairs, toks.tolist(),
+                                    counts[0].tolist())
+                continue
             if counts:
                 self._note_moe(counts[0])
             toks = toks.tolist()
@@ -3385,6 +3529,43 @@ class LMEngine(Logger):
                 self._undelivered.append((slot, lane, toks[slot], last,
                                           flight.first))
         self._older = max(0, self._older - n)
+
+    def _settle_counts(self, pairs, toks, counts):   # hot-path
+        """One fetched step of a model that drafts with its own module
+        (ISSUE 40): what the host could not know when the step went out is
+        settled here.  ``counts[slot]`` of the two tokens ``toks[slot][:2]`` are
+        real (the third is the draft made behind them): the lane's confirmed position moves by that, the request is
+        owed that many fewer, and what it is not owed is dropped and
+        counted (``spec_tokens_discarded``: a second token past ``n_new``,
+        and every token of a lane that had all its tokens already and rode
+        the steps in flight behind its last).  The kept ones go to
+        ``_undelivered``; the last of a request is marked so."""
+        made = kept = drafts = accepted = 0
+        for slot, lane in pairs:
+            n = counts[slot]
+            made += n
+            lane.inflight -= 1
+            if self._lanes[slot] is lane:
+                self._pos[slot] += n
+                self._unseen[slot] -= self.spec_k + 1
+            if not lane.remaining:
+                continue                 # it rode behind its last token
+            drafts += 1
+            accepted += n - 1
+            take = min(n, lane.remaining)
+            kept += take
+            lane.remaining -= take
+            for j in range(take):
+                self._undelivered.append((
+                    slot, lane, toks[slot][j],
+                    j == take - 1 and not lane.remaining, False))
+            if lane.remaining:
+                lane.drafts.append((lane.request.n_new - lane.remaining,
+                                    toks[slot][2]))
+        self.metrics.inc("draft_tokens", drafts)
+        self.metrics.inc("draft_accepted", accepted)
+        self.metrics.inc("spec_tokens_kept", kept)
+        self.metrics.inc("spec_tokens_discarded", made - kept)
 
     def _drain(self):
         """The outstanding fetches made first (ISSUE 39), wherever the
@@ -3417,8 +3598,11 @@ class LMEngine(Logger):
         flight is counted in (:meth:`_advance_by_count`), the turn after
         alike.  None where no lane is left to step: a guard that fails
         tears its lane down, a slide that fails fails them all."""
+        # (rows a lane writes, and how far a lane that drafts may be
+        # ahead of what the host has seen of it: ISSUE 40)
+        rows = self.spec_k + 1 if self._mtp else 1
         if self._paged:
-            active = self._cow_guard_active(active, 1)
+            active = self._cow_guard_active(active, self.headroom + rows)
             if not active:
                 return None
         w = None
@@ -3432,7 +3616,7 @@ class LMEngine(Logger):
                         p = int(self._pos[slot])
                         self._slide_window(slot, p, p + 1)
             if self._paged:
-                w = self._live_width(1)
+                w = self._live_width(rows)
                 if self._state_shapes is not None:
                     self._decoding[:] = False
                     self._decoding[active] = True
@@ -3447,10 +3631,14 @@ class LMEngine(Logger):
                     live = numpy.zeros(self.slots, bool)
                     live[active] = True
                     live = xfer.to_device(live)
-            pos = self._pos.copy()
+            pos = self._pos + self._unseen
             return _Step([(slot, self._lanes[slot]) for slot in active],
-                         w, tables, xfer.to_device(pos), live,
-                         self._attn_page_steps(pos, w, 1))
+                         w, tables,
+                         # (the positions of lanes that draft stay on the
+                         # device; the page steps are counted at the
+                         # farthest they may be)
+                         None if self._mtp else xfer.to_device(pos), live,
+                         self._attn_page_steps(pos, w, rows))
         except Exception as e:   # noqa: BLE001 — fails the lanes
             self._fail_active(active, e)
             return None
@@ -3484,6 +3672,8 @@ class LMEngine(Logger):
             # only the SAMPLED lanes carry a context — an all-None
             # batch records nothing and (sample:P) skips the fence
             tctxs = [lane.request.trace for _, lane in pairs]
+        # (a step that verifies a draft keeps the verify span's name)
+        span = "decode.verify" if self._mtp else "decode.step"
         under, went = None, []
         if self._paged:
             def under():
@@ -3495,7 +3685,8 @@ class LMEngine(Logger):
             if self._paged:
                 self._dispatch_decode(
                     self._step_jit, step.tables + (
-                        self._last_dev, step.pos_dev, step.live_dev),
+                        (self._last_dev, step.live_dev) if self._mtp else
+                        (self._last_dev, step.pos_dev, step.live_dev)),
                     len(pairs), tctxs, under, pairs)
             else:
                 toks = self._dispatch_decode(
@@ -3504,19 +3695,20 @@ class LMEngine(Logger):
         except Exception as e:   # noqa: BLE001 — fails the lanes
             if self._tracer is not None:
                 self._tracer.add_many(
-                    tctxs, "decode.step", "decode", t0,
-                    time.monotonic(),
+                    tctxs, span, "decode", t0, time.monotonic(),
                     attrs={"batch": len(pairs), "error": str(e)})
             self._fail_step(step, e, made_ahead and not went)
             return
         self.metrics.record_decode_step(time.monotonic() - t0)
         if self._tracer is not None:
-            self._tracer.add_many(
-                tctxs, "decode.step", "decode", t0, time.monotonic(),
-                attrs={"batch": len(pairs),
-                       "bucket": (step.width if step.width is not None
-                                  else self.slots),
-                       "backend": self._backend})
+            attrs = {"batch": len(pairs),
+                     "bucket": (step.width if step.width is not None
+                                else self.slots),
+                     "backend": self._backend}
+            if self._mtp:
+                attrs["k"] = self.spec_k
+            self._tracer.add_many(tctxs, span, "decode", t0,
+                                  time.monotonic(), attrs=attrs)
         if self._paged:
             return
         self._note_step(step)
@@ -3535,6 +3727,9 @@ class LMEngine(Logger):
         self.metrics.inc("decode_dispatches")
         if made_ahead:
             self.metrics.inc("turns_prepared_ahead")
+        if self._mtp:
+            self.metrics.inc("spec_dispatches")
+            self.metrics.inc("spec_lane_steps", len(step.pairs))
         self._note_attn_dispatch(step.steps)
 
     def _advance_by_count(self, pairs):   # hot-path
@@ -3552,6 +3747,21 @@ class LMEngine(Logger):
         garbage write already lives by; a dispatch that takes the page
         is called after both and so runs after both."""
         lasts = []
+        if self._mtp:
+            # (ISSUE 40) a step yields one token or two a lane and the
+            # host learns which when it fetches it: what is owed is
+            # settled there (:meth:`_settle_counts`).  Known now: every
+            # step in flight yields one at least, so a lane that is owed
+            # no more than it has steps in flight ends with this one (a
+            # lane whose drafts were accepted has ended before: it rode
+            # this step and the one before, and what they made is
+            # dropped), and its slot is free for the next admission
+            for slot, lane in pairs:
+                lane.inflight += 1
+                self._unseen[slot] += self.spec_k + 1
+                if lane.remaining <= lane.inflight:
+                    self._vacate_slot(slot, lane)
+            return lasts
         for slot, lane in pairs:
             self._pos[slot] += 1
             lane.remaining -= 1
@@ -3584,6 +3794,11 @@ class LMEngine(Logger):
                                          - lane.request.t_enq)
             if last:
                 self._reply(lane)
+                if self._lanes[slot] is lane and not lane.inflight:
+                    # (a lane that drafts is freed by count when its last
+                    # step goes out; where none is in flight, the old
+                    # order, it leaves here)
+                    self._vacate_slot(slot, lane)
             elif lane.request.cancelled and self._lanes[slot] is lane:
                 self._finish(slot)
 
@@ -3706,6 +3921,7 @@ class LMEngine(Logger):
         self.metrics.record_dispatch(len(active))
         self.metrics.record_decode_step(time.monotonic() - t0)
         self.metrics.inc("decode_dispatches")
+        self.metrics.inc("spec_dispatches")
         self._note_attn_dispatch(self._attn_page_steps(self._pos, w, k + 1))
         if self._tracer is not None:
             self._tracer.add_many(
@@ -4085,6 +4301,7 @@ class LMEngine(Logger):
             req.future.set_exception(RuntimeError("LM engine stopped"))
         for slot, lane in enumerate(self._lanes):
             if lane is not None:
-                lane.request.future.set_exception(
-                    RuntimeError("LM engine stopped"))
+                if not lane.request.future.done():
+                    lane.request.future.set_exception(
+                        RuntimeError("LM engine stopped"))
                 self._lanes[slot] = None

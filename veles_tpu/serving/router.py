@@ -311,6 +311,13 @@ class Router(Logger):
         return max(e.spec_k for e in self.replicas)
 
     @property
+    def headroom(self):
+        """Cache positions past ``prompt + n_new`` that a placement may
+        write (``LMEngine.headroom``: ``spec_k``, or two steps' worth where
+        the model drafts with its own module)."""
+        return max(e.headroom for e in self.replicas)
+
+    @property
     def max_len(self):
         return min(e.max_len for e in self.replicas)
 

@@ -815,10 +815,14 @@ _PROGRAM_COL = {PREFILL_DISPATCH: COL_PREFILL_PROGRAM,
 #: for a chunk that is no tail), ``DCOL_FETCHED`` the host had them (never,
 #: where it did not wait or the dispatch raised); ``DCOL_FETCH_TURN`` the
 #: ``COL_SEQ`` of the turn open at ``DCOL_FETCHED`` (``DCOL_TURN`` while
-#: every fetch falls in the turn of its call)
+#: every fetch falls in the turn of its call); ``DCOL_TOKENS`` the tokens
+#: the dispatch made, summed over its lanes, as its fetch found them (a
+#: step that verifies a draft makes one or two a lane; 0: a driver that
+#: does not say, or never fetched)
 (DCOL_SEQ, DCOL_TURN, DCOL_PROGRAM, DCOL_PHASE, DCOL_LANES, DCOL_CALL,
- DCOL_RETURNED, DCOL_WAIT, DCOL_FETCHED, DCOL_FETCH_TURN) = range(10)
-DISPATCH_WIDTH = 10
+ DCOL_RETURNED, DCOL_WAIT, DCOL_FETCHED, DCOL_FETCH_TURN,
+ DCOL_TOKENS) = range(11)
+DISPATCH_WIDTH = 11
 
 #: one finished (or failed, shed, cancelled) request: stamps in
 #: nanoseconds on the monotonic clock, 0 where the request never got
@@ -991,7 +995,7 @@ class LoopRecorder:
         t = cur[COL_STAMPS + phase] = time.monotonic_ns()
         seq = self.dispatch_head + 1
         self._dring[(seq - 1) & self._dmask] = (
-            seq, self.head + 1, pid, phase, lanes, t, 0, 0, 0, 0)
+            seq, self.head + 1, pid, phase, lanes, t, 0, 0, 0, 0, 0)
         self.dispatch_head = seq
         return seq
 
@@ -1009,13 +1013,15 @@ class LoopRecorder:
         if phase is not None:
             self._cur[COL_STAMPS + phase] = t
 
-    def fetched(self, seq, phase=None):
+    def fetched(self, seq, phase=None, tokens=0):
         """The host has the outputs of dispatch ``seq``; ``phase`` as for
-        :meth:`waiting`."""
+        :meth:`waiting`; ``tokens`` how many tokens they hold, over all
+        lanes, where the driver says."""
         t = time.monotonic_ns()
         row = self._dring[(seq - 1) & self._dmask]
         row[DCOL_FETCH_TURN] = self.head + 1
         row[DCOL_FETCHED] = t
+        row[DCOL_TOKENS] = tokens
         if phase is not None:
             self._cur[COL_STAMPS + phase] = t
 
@@ -1117,5 +1123,6 @@ class LoopRecorder:
                         "args": {"dispatch": row[DCOL_SEQ],
                                  "turn": row[DCOL_TURN],
                                  "fetch_turn": row[DCOL_FETCH_TURN],
-                                 "lanes": row[DCOL_LANES]}})
+                                 "lanes": row[DCOL_LANES],
+                                 "tokens": row[DCOL_TOKENS]}})
         return out
