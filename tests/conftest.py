@@ -190,12 +190,13 @@ def page_step_census():
     def watch(engine):
         calls = []
 
-        def spy(name, span):
+        def spy(name, span, walked=False):
             real = getattr(engine, name)
 
             @functools.wraps(real)
             def noted(params, pools, table, tokens, pos, *rest):
-                calls.append((span, jax.tree.map(numpy.array, table),
+                calls.append((span, walked,
+                              jax.tree.map(numpy.array, table),
                               numpy.atleast_1d(numpy.array(pos))))
                 return real(params, pools, table, tokens, pos, *rest)
             setattr(engine, name, noted)
@@ -203,7 +204,9 @@ def page_step_census():
         # the history below a chunk's frontier; the latent kind's chunk
         # kernel also walks the chunk's own page, written before it
         spy("_chunk_jit", page if cfg.latent is not None else 0)
-        spy("_step_jit", 1)
+        # the latent kind's decode kernel walks a lane's own pages and is
+        # handed no other (ISSUE 41): the live ones are all it is given
+        spy("_step_jit", 1, walked=cfg.latent is not None)
 
         @functools.lru_cache(maxsize=None)
         def live_pages(pos, span, width, window):
@@ -215,7 +218,7 @@ def page_step_census():
 
         def count():
             given = live = 0
-            for span, table, pos in calls:
+            for span, walked, table, pos in calls:
                 tables, base = table if isinstance(table, tuple) \
                     else ({model_config.FULL: table}, 0)
                 for layer in range(len(engine.params["blocks"])):
@@ -223,10 +226,11 @@ def page_step_census():
                     width = tables[kind].shape[-1]
                     rel = pos - (base if kind == model_config.SLIDING
                                  else 0)
-                    given += len(rel) * width
-                    live += sum(live_pages(int(p), span, width,
-                                           cfg.layer_window(layer))
-                                for p in rel)
+                    seen = sum(live_pages(int(p), span, width,
+                                          cfg.layer_window(layer))
+                               for p in rel)
+                    live += seen
+                    given += seen if walked else len(rel) * width
             return given, live
         return count
     return watch

@@ -366,6 +366,37 @@ def test_programs_of_two_kinds_update_both_pools_in_place(kinds_engine,
     assert aliased == len(leaves) == 6
 
 
+#: what the chip's compiler gives a kernel that asks for no more
+KERNEL_VMEM = 16 << 20
+
+
+def latent_decode_vmem(q, pool, table):
+    """Bytes of fast memory ``paged_latent_decode`` asks for at these
+    shapes, read off its traced call: the scratch it names (three slots of
+    a block, the float32 accumulators), its blocked operands twice (the
+    pipeline double-buffers the queries and the outputs; the pool stays
+    where it lies and costs nothing) and one block's float32 scores with
+    their exponentials.  The call must ask for no limit of its own: the
+    compiler then holds it to ``KERNEL_VMEM``."""
+    import math
+    jaxpr = jax.make_jaxpr(lambda q, k, pt, ps: PK.paged_latent_decode(
+        q, k, pt, ps, 0.1447, interpret=False))(
+            *(jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in
+              (q, pool, table, (table[0][:1], I32))))
+    call, = (e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call")
+    assert not call.params["compiler_params"]
+    total = 0
+    for ref in (v.aval for v in call.params["jaxpr"].invars):
+        space = str(getattr(ref, "memory_space", None) or "blocked")
+        if space in ("blocked", "vmem"):
+            total += (math.prod(ref.shape) * ref.dtype.itemsize
+                      * (2 if space == "blocked" else 1))
+        else:
+            assert space in ("smem", "any", "semaphore_mem"), space
+    rows = q[0][1] * q[0][2]
+    return total + 2 * rows * PK._latent_block(pool[0][2]) * 4
+
+
 @pytest.mark.parametrize("page", [1024, 512])
 def test_latent_kernels_compile_at_the_cells_widths(one_chip, page):
     """ISSUE 34: the absorbed decode kernel (16 lanes, 32 heads, rows of
@@ -381,6 +412,8 @@ def test_latent_kernels_compile_at_the_cells_widths(one_chip, page):
             q, k, pt, ps, 0.1447, interpret=False),
         ((16, 32, 1, 640), BF16), pool, ((16, m), I32), ((16,), I32))
     assert "tpu_custom_call" in text
+    assert latent_decode_vmem(((16, 32, 1, 640), BF16), pool,
+                              ((16, m), I32)) < KERNEL_VMEM // 2
     w = ((32, 512, 128), BF16)
     text = compile_for(
         one_chip,
@@ -674,6 +707,9 @@ def test_latent_decode_kernel_compiles_at_the_verify_cells_width(one_chip,
         ((32, 32, rows, 640), BF16), ((289, 1, 1024, 640), BF16),
         ((32, 9), I32), ((32,), I32))
     assert "tpu_custom_call" in text
+    assert latent_decode_vmem(((32, 32, rows, 640), BF16),
+                              ((289, 1, 1024, 640), BF16),
+                              ((32, 9), I32)) < KERNEL_VMEM // 2
 
 
 @pytest.mark.parametrize("rows,k,n,groups", [

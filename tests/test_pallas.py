@@ -741,6 +741,231 @@ class TestPagedFlashPrefill:
                                       rtol=1e-4, atol=1e-5)
 
 
+def _gridded_latent_decode(q, pool, ptab, pos, scale):
+    """``paged_latent_decode`` as it stood before ISSUE 41 (a grid step a
+    table entry, a whole page a live step), kept here as the yardstick:
+    the walk at a block of one page must give its bits."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    b, h, c, row = q.shape
+    page = pool.shape[2]
+    m_pages = ptab.shape[1]
+    rows = h * c
+    first, last, sink = PK.live_pages(jnp.asarray(pos, jnp.int32), c, page,
+                                      m_pages)
+
+    def kernel(ptab_ref, pos_ref, first_ref, last_ref, q_ref, k_ref, o_ref,
+               acc_ref, l_ref, m_ref):
+        i, j = pl.program_id(0), pl.program_id(1)
+
+        @pl.when(j == 0)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            m_ref[...] = jnp.full_like(m_ref, PK.NEG_INF)
+
+        @pl.when(PK._is_live(j, first_ref[i], last_ref[i], sink))
+        def _():
+            k_pos = j * page + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, page), 1)
+            q_pos = pos_ref[i] + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, page), 0) % c
+            rows_k = k_ref[0]
+            PK._flash_step(q_ref[0], rows_k, rows_k, k_pos <= q_pos, row,
+                           acc_ref, l_ref, m_ref, scale=scale)
+
+        @pl.when(j == m_pages - 1)
+        def _():
+            o_ref[0] = (acc_ref[...]
+                        / l_ref[...][..., None]).astype(o_ref.dtype)
+
+    def lane(i, j, *_):
+        return (i, 0, 0, 0)
+
+    def history(i, j, pt, ps, fs, ls):
+        return (pt[i, PK._live_entry(j, fs[i], ls[i], sink)], 0, 0, 0)
+
+    o = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(b, m_pages),
+            in_specs=[pl.BlockSpec((1, 1, rows, row), lane),
+                      pl.BlockSpec((1, 1, page, row), history)],
+            out_specs=pl.BlockSpec((1, 1, rows, row), lane),
+            scratch_shapes=[pltpu.VMEM((1, rows, row), jnp.float32),
+                            pltpu.VMEM((1, rows), jnp.float32),
+                            pltpu.VMEM((1, rows), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, 1, rows, row), q.dtype),
+        interpret=True,
+    )(jnp.asarray(ptab, jnp.int32), jnp.asarray(pos, jnp.int32), first,
+      last, q.reshape(b, 1, rows, row), pool)
+    return o.reshape(b, h, c, row)
+
+
+@pytest.mark.kernel_parity
+class TestPagedLatentDecode:
+    """ISSUE 41: the absorbed latent-attention kernel walks each lane's
+    cached rows itself (a loop of the lane's own length over blocks it
+    copies by hand): against the absorbed XLA form over the gathered view,
+    against the gridded kernel it replaces, and with everything it must
+    not touch poisoned."""
+
+    PAGE, M, HEADS, SCALE = 8, 6, 3, 0.17
+
+    def _record(self):
+        """As much of a model record as ``ops/latent.py`` reads: rows of
+        112 numbers in 128 lanes."""
+        import types
+        from veles_tpu.model_config import LatentConfig
+        return types.SimpleNamespace(
+            latent=LatentConfig(q_rank=8, kv_rank=96, nope=16, rope=16, v=16),
+            yarn=None, dtype="float32", n_heads=self.HEADS)
+
+    def _depths(self, c, block):
+        """Lanes of mixed depth whose LAST query row lies: on position 0
+        (a lane that rides the step in prefill), on a block's last and
+        first row, on a page's last and first row, deep, and on the
+        table's last row (a lane that fills its whole table)."""
+        page, m = self.PAGE, self.M
+        frontier = numpy.asarray([c - 1, block - 1, block, page - 1, page,
+                                  2 * page + block, 21, m * page - 1])
+        return numpy.maximum(frontier - (c - 1), 0).astype(numpy.int32)
+
+    def _setup(self, c, pos, seed, m=None, page=None):
+        cfg = self._record()
+        lat, page, m = cfg.latent, page or self.PAGE, m or self.M
+        rng = numpy.random.RandomState(seed)
+        b = len(pos)
+        p = {"wk_b": jnp.asarray(rng.randn(self.HEADS, lat.kv_rank,
+                                           lat.nope) * 0.2, jnp.float32),
+             "wv_b": jnp.asarray(rng.randn(self.HEADS, lat.kv_rank,
+                                           lat.v) * 0.2, jnp.float32)}
+        q_nope = jnp.asarray(rng.randn(b, self.HEADS, c, lat.nope),
+                             jnp.float32)
+        q_rope = jnp.asarray(rng.randn(b, self.HEADS, c, lat.rope),
+                             jnp.float32)
+        pool = rng.randn(b * m, 1, page, lat.row).astype(numpy.float32)
+        pool[..., lat.width:] = 0.0
+        ptab = rng.permutation(b * m).reshape(b, m).astype(numpy.int32)
+        return cfg, p, q_nope, q_rope, pool, ptab
+
+    def _kernel(self, cfg, p, q_nope, q_rope, pool, ptab, pos, **how):
+        from veles_tpu.ops import latent
+        qa = latent.absorbed_queries(p, q_nope, q_rope, cfg)
+        return PK.paged_latent_decode(qa, jnp.asarray(pool),
+                                      jnp.asarray(ptab), jnp.asarray(pos),
+                                      latent.softmax_scale(cfg), **how)
+
+    def _xla(self, cfg, p, q_nope, q_rope, pool, ptab, pos):
+        from veles_tpu.ops import attention as A, latent
+        c = q_nope.shape[2]
+        view = A.paged_view(jnp.asarray(pool), jnp.asarray(ptab))[:, 0]
+        live = jax.vmap(lambda at: A.chunk_live_mask(
+            at, c, view.shape[1]))(jnp.asarray(pos))[:, None]
+        return latent.attend_absorbed(p, q_nope, q_rope, view, live, cfg)
+
+    def _outputs(self, cfg, p, o_lat):
+        from veles_tpu.ops import latent
+        return latent.absorbed_outputs(p, o_lat[..., :cfg.latent.kv_rank],
+                                       cfg)
+
+    @pytest.mark.parametrize("block", [8, 4, 2], ids=["page", "half",
+                                                      "quarter"])
+    @pytest.mark.parametrize("c", [1, 2])
+    def test_matches_the_absorbed_xla_form(self, monkeypatch, c, block):
+        """One call over lanes of every depth, at one and at two query
+        rows a head, the block a page and whole fractions of it: the
+        absorbed form's outputs (``attend_absorbed`` over
+        ``paged_view``)."""
+        monkeypatch.setattr(PK, "_LATENT_BLOCK", block)
+        pos = self._depths(c, block)
+        args = self._setup(c, pos, seed=10 * c + block)
+        got = self._outputs(args[0], args[1], self._kernel(*args, pos))
+        ref = self._xla(*args, pos)
+        numpy.testing.assert_allclose(numpy.asarray(got), numpy.asarray(ref),
+                                      rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("c", [1, 2])
+    def test_a_table_wider_than_any_lane_needs(self, monkeypatch, c):
+        """The table's width is no part of the walk: twelve entries over
+        lanes of at most three pages give what four entries give, bit for
+        bit."""
+        monkeypatch.setattr(PK, "_LATENT_BLOCK", 4)
+        pos = numpy.asarray([0, 5, 17, 23 - c], numpy.int32)
+        args = self._setup(c, pos, seed=c, m=12)
+        wide = self._kernel(*args, pos)
+        narrow = self._kernel(*args[:5], args[5][:, :4], pos)
+        numpy.testing.assert_array_equal(numpy.asarray(wide),
+                                         numpy.asarray(narrow))
+        numpy.testing.assert_allclose(
+            numpy.asarray(self._outputs(args[0], args[1], wide)),
+            numpy.asarray(self._xla(*args, pos)), rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("block", [8, 4, 2], ids=["page", "half",
+                                                      "quarter"])
+    @pytest.mark.parametrize("c", [1, 2])
+    def test_what_no_query_sees_is_never_read(self, monkeypatch, c, block):
+        """Dead table entries point at a page of NaN and, in the
+        frontier's page, the rows behind the frontier's block ARE NaN:
+        the outputs are finite and those of the clean run, bit for bit."""
+        monkeypatch.setattr(PK, "_LATENT_BLOCK", block)
+        page, m = self.PAGE, self.M
+        pos = self._depths(c, block)
+        cfg, p, q_nope, q_rope, pool, ptab = self._setup(c, pos,
+                                                         seed=c + block)
+        clean = self._kernel(cfg, p, q_nope, q_rope, pool, ptab, pos)
+        frontier = pos + c - 1
+        bad = numpy.concatenate(
+            [pool, numpy.full((1,) + pool.shape[1:], numpy.nan,
+                              pool.dtype)])
+        for i, at in enumerate(frontier):
+            walked = (at // block + 1) * block      # rows the lane walks
+            bad[ptab[i, at // page], 0, walked % page or page:] = numpy.nan
+        dead = numpy.arange(m)[None, :] > (frontier // page)[:, None]
+        assert dead.any() and not dead.all(1).any()
+        nan_tab = numpy.where(dead, len(pool), ptab).astype(numpy.int32)
+        got = self._kernel(cfg, p, q_nope, q_rope, bad, nan_tab, pos)
+        assert numpy.isfinite(numpy.asarray(got)).all()
+        numpy.testing.assert_array_equal(numpy.asarray(got),
+                                         numpy.asarray(clean))
+
+    @pytest.mark.parametrize("c", [1, 2])
+    def test_a_block_of_one_page_keeps_the_gridded_kernels_bits(
+            self, monkeypatch, c):
+        """With the block a whole page the walk sums what the gridded
+        kernel summed, in its order: the same bits."""
+        from veles_tpu.ops import latent
+        monkeypatch.setattr(PK, "_LATENT_BLOCK", self.PAGE)
+        pos = self._depths(c, self.PAGE)
+        cfg, p, q_nope, q_rope, pool, ptab = self._setup(c, pos, seed=3 + c)
+        got = self._kernel(cfg, p, q_nope, q_rope, pool, ptab, pos)
+        ref = _gridded_latent_decode(
+            latent.absorbed_queries(p, q_nope, q_rope, cfg),
+            jnp.asarray(pool), jnp.asarray(ptab), jnp.asarray(pos),
+            latent.softmax_scale(cfg))
+        numpy.testing.assert_array_equal(numpy.asarray(got),
+                                         numpy.asarray(ref))
+
+    @pytest.mark.parametrize("page,block", [(8, 8), (24, 24), (1024, None),
+                                            (2048, None)],
+                             ids=["smaller", "no_multiple", "the_cells",
+                                  "twice_the_cells"])
+    def test_the_block_for_every_page_size(self, page, block):
+        """The one constant holds for every page the kernel is given: a
+        page smaller than it, or no multiple of it, is walked whole; the
+        cells' page of 1024, and one twice as long, in blocks of the
+        constant.  The kernel as it ships (nothing patched) gives the
+        absorbed form's outputs."""
+        assert PK._latent_block(page) == (block or PK._LATENT_BLOCK)
+        assert page % PK._latent_block(page) == 0
+        pos = numpy.asarray([0, page - 1, page, 2 * page - 2], numpy.int32)
+        args = self._setup(1, pos, seed=page, m=2, page=page)
+        got = self._outputs(args[0], args[1], self._kernel(*args, pos))
+        numpy.testing.assert_allclose(
+            numpy.asarray(got), numpy.asarray(self._xla(*args, pos)),
+            rtol=1e-5, atol=1e-5)
+
+
 class TestServingKernelSupport:
     def test_structural_checks(self):
         from veles_tpu.ops import pallas_kernels as PK

@@ -25,8 +25,9 @@ Two orders of the same sums, chosen by phase:
 Both run under the scope ``attn.latent``; scores, softmax and accumulators
 are float32 whatever the model's dtype.  With the serving kernels active
 (``attn_kernel``) the absorbed form is ``pallas_kernels.paged_latent_decode``
-and the expanded form ``pallas_kernels.paged_latent_prefill``, which expands
-a page of latents at a time in fast memory; both skip the table's dead pages
+(a loop of each lane's own length over blocks of its cached rows) and the
+expanded form ``pallas_kernels.paged_latent_prefill``, which expands a page
+of latents at a time in fast memory and skips the table's dead pages
 (``live_pages``).  Without them the lane's rows are gathered and the same
 sums are dense XLA.
 """

@@ -794,8 +794,33 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
 # values ARE the cached rows, so a page is fetched once and serves scores
 # and accumulation alike), the prefill kernel in the EXPANDED form (it
 # rebuilds a page's keys and values through ``W_kvb`` in fast memory, a
-# block of heads at a time).  Both walk the page table and skip dead pages
-# as the kernels above do (``live_pages``).
+# block of heads at a time).  The prefill kernel walks the page table on
+# its grid and skips dead pages as the kernels above do (``live_pages``);
+# the decode kernel's grid is the lanes alone and the walk is a loop inside
+# it, as long as the lane is deep, over blocks it copies itself (ISSUE 41:
+# on the grid every table entry paid a grid step, 0.06 us a dead one and
+# 2.4 us a live one whose copy takes 1.8; the loop's copies follow one
+# another through the whole call and the call takes what they take).
+
+
+#: cached rows one step of the absorbed kernel's walk copies and multiplies:
+#: a page, or a whole fraction of one (a page that is no multiple of it is
+#: walked whole: ``_latent_block``).  Chosen on the chip at both cells'
+#: shapes (``tools/latent_decode_sweep.py``; PERF.md section 6, PR 41; us a
+#: call at 1024 | 512 | 256 rows): 32 lanes x 64 query rows over 110,611
+#: cached rows 212 | 254 | 417, 16 lanes x 32 rows over 337,025 rows 600 |
+#: 599 | 1054.  At 1024 the call takes what its copies alone take (211 |
+#: 598 us: 745 GB/s); under it ``_flash_step``'s fixed part a block (0.4-0.6
+#: us: the running maximum and sum change layout between rows and lanes)
+#: costs as much as (16 x 32 query rows) or more than (32 x 64) the rows of
+#: the frontier's page that are then neither copied nor multiplied
+_LATENT_BLOCK = 1024
+
+
+def _latent_block(page):
+    """The rows a step of :func:`paged_latent_decode`'s walk takes of a
+    ``page``-row page."""
+    return _LATENT_BLOCK if page % _LATENT_BLOCK == 0 else page
 
 
 def paged_latent_decode(q, pool, ptab, pos, scale, interpret=None):
@@ -804,60 +829,117 @@ def paged_latent_decode(q, pool, ptab, pos, scale, interpret=None):
     | 0]``, against the lane's cached rows through its table.  ``s = q .
     row * scale`` (the zero lanes meet zeros), causal online softmax in
     float32, ``o = sum p row``: the pool must already hold the rows of
-    positions [0, pos + c).  One pool page per grid step, fetched ONCE for
-    scores and values and all ``h`` heads (:func:`paged_flash_decode` with
-    the pool as K and as V would fetch it twice).  Dead pages cost neither
-    a fetch nor a step.  Returns (b, h, c, row): lanes [:kv_rank] are
+    positions [0, pos + c).
+
+    The grid is the lanes; the kernel WALKS a lane's rows itself: the pool
+    stays where it lies, and a loop of the lane's own length,
+    ``ceil((pos + c) / block)`` blocks (``_latent_block``), copies block
+    after block into one of three slots of fast memory, fetched ONCE for
+    scores and values and all ``h`` heads.  The copies run two blocks ahead
+    of the block that is multiplied, across the lanes' edges: a lane's last
+    steps start the NEXT lanes' first blocks, so the copies follow one
+    another without a gap while a lane ends and the next begins (with one
+    block ahead the call took a tenth longer: the copies bound it), and
+    only the call's first copy is waited for with nothing to do.  A table
+    entry no query row of the lane sees, and the rows of the frontier's
+    page behind the frontier's block, cost nothing: no grid step, no copy,
+    no scalar read.  Returns (b, h, c, row): lanes [:kv_rank] are
     ``o_lat``, the rest is of no use."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, c, row = q.shape
     page = pool.shape[2]
-    m_pages = ptab.shape[1]
+    block = _latent_block(page)
+    per_page = page // block
+    ahead = 2                   # blocks in flight before the one multiplied
+    slots = ahead + 1
     rows = h * c
     qp = q.reshape(b, 1, rows, row)
-    first, last, sink = live_pages(jnp.asarray(pos, jnp.int32), c, page,
-                                   m_pages)
+    pos = jnp.asarray(pos, jnp.int32)
+    # the blocks each lane walks (``live_pages``' last page, in blocks:
+    # at least one, at most its table) and where its first stands in the
+    # call's walk, computed once a call in the program around the kernel
+    # and prefetched (``paged_flash_decode`` says why)
+    walk = jnp.minimum((pos + c + block - 1) // block,
+                       ptab.shape[1] * per_page)
+    first = jnp.cumsum(walk) - walk
 
-    def kernel(ptab_ref, pos_ref, first_ref, last_ref, q_ref, k_ref, o_ref,
-               acc_ref, l_ref, m_ref):
-        i, j = pl.program_id(0), pl.program_id(1)
+    def kernel(ptab_ref, pos_ref, walk_ref, first_ref, q_ref, pool_ref,
+               o_ref, buf_ref, sem_ref, acc_ref, l_ref, m_ref):
+        i = pl.program_id(0)
 
-        @pl.when(j == 0)
+        # (``jax.lax`` on the scalars, not ``jax.numpy``: every operator of
+        # the latter is a jitted function traced and lowered anew for every
+        # layer of every program, and that time is set-up time)
+        rem, select = jax.lax.rem, jax.lax.select
+
+        def copy(lane, t, slot):
+            """Block ``t`` of ``lane``'s walk into ``slot``."""
+            entry, at = (t, 0) if per_page == 1 else (
+                jax.lax.div(t, per_page), rem(t, per_page) * block)
+            return pltpu.make_async_copy(
+                pool_ref.at[ptab_ref[lane, entry], 0, pl.ds(at, block)],
+                buf_ref.at[slot], sem_ref.at[slot])
+
+        def after(lane, t):
+            """The block behind block ``t`` of ``lane`` in the call's walk:
+            the lane's next, or the next lane's first (lane ``b``: none)."""
+            more = t + 1 < walk_ref[jax.lax.min(lane, b - 1)]
+            return select(more, lane, lane + 1), select(more, t + 1, 0 * t)
+
+        def start(lane, t, slot):
+            @pl.when(lane < b)
+            def _():
+                copy(lane, t, slot).start()
+
+        # the call's blocks take the slots in turn: block g of the whole
+        # walk lies in slot g % slots
+        @pl.when(i == 0)
         def _():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            copy(0, 0, 0).start()
+            lane, t = 0, 0
+            for slot in range(1, ahead):
+                lane, t = after(lane, t)
+                start(lane, t, slot)
 
-        @pl.when(_is_live(j, first_ref[i], last_ref[i], sink))
-        def _():
-            k_pos = j * page + jax.lax.broadcasted_iota(
-                jnp.int32, (rows, page), 1)
-            q_pos = pos_ref[i] + jax.lax.broadcasted_iota(
-                jnp.int32, (rows, page), 0) % c
-            rows_k = k_ref[0]
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+
+        def step(t, carry):
+            g = first_ref[i] + t
+            lane, far = i, t
+            for _ in range(ahead):
+                lane, far = after(lane, far)
+            # (into the slot the step before this one read)
+            start(lane, far, rem(g + ahead, slots))
+            slot = rem(g, slots)
+            copy(i, t, slot).wait()
+            k_pos = t * block + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, block), 1)
+            q_pos = pos_ref[i] + rem(jax.lax.broadcasted_iota(
+                jnp.int32, (rows, block), 0), c)
+            rows_k = buf_ref[slot][None]
             _flash_step(q_ref[0], rows_k, rows_k, k_pos <= q_pos, row,
                         acc_ref, l_ref, m_ref, scale=scale)
+            return carry
 
-        @pl.when(j == m_pages - 1)
-        def _():
-            o_ref[0] = (acc_ref[...]
-                        / l_ref[...][..., None]).astype(o_ref.dtype)
+        jax.lax.fori_loop(0, walk_ref[i], step, 0)
+        o_ref[0] = (acc_ref[...] / l_ref[...][..., None]).astype(o_ref.dtype)
 
-    def lane(i, j, *_):
+    def lane(i, *_):
         return (i, 0, 0, 0)
-
-    def history(i, j, pt, ps, fs, ls):
-        return (pt[i, _live_entry(j, fs[i], ls[i], sink)], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(b, m_pages),
+        grid=(b,),
         in_specs=[pl.BlockSpec((1, 1, rows, row), lane),
-                  pl.BlockSpec((1, 1, page, row), history)],
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, 1, rows, row), lane),
-        scratch_shapes=[pltpu.VMEM((1, rows, row), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((slots, block, row), pool.dtype),
+                        pltpu.SemaphoreType.DMA((slots,)),
+                        pltpu.VMEM((1, rows, row), jnp.float32),
                         pltpu.VMEM((1, rows), jnp.float32),
                         pltpu.VMEM((1, rows), jnp.float32)],
     )
@@ -865,8 +947,7 @@ def paged_latent_decode(q, pool, ptab, pos, scale, interpret=None):
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, rows, row), q.dtype),
         interpret=_interpret(interpret),
-    )(jnp.asarray(ptab, jnp.int32), jnp.asarray(pos, jnp.int32),
-      first, last, qp, pool)
+    )(jnp.asarray(ptab, jnp.int32), pos, walk, first, qp, pool)
     return o.reshape(b, h, c, row)
 
 

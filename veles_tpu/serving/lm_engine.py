@@ -2895,15 +2895,23 @@ class LMEngine(Logger):
         where the kernels are not active: ``pos`` the positions of the
         lanes ``rows`` as the program gets them, ``width`` its table's,
         ``span`` the query rows a lane (0: a prefill chunk, whose kernel
-        walks the history below ``pos``).  Read where the dispatch's
-        tables are made (the sliding kind's short table begins at its
-        base THEN).  Host integers over at most ``slots`` lanes, by the
-        kernels' own ``live_pages``."""
+        walks the history below ``pos``; the latent kind's also walks the
+        chunk's own page, written before it: its query rows count like a
+        decode's).  A decode or verify step of the latent kind is handed
+        only what it walks (``paged_latent_decode`` loops over a lane's own
+        pages, whatever the table's width, ISSUE 41): ``given`` is ``live``
+        there.  Read where the dispatch's tables are made (the sliding
+        kind's short table begins at its base THEN).  Host integers over at
+        most ``slots`` lanes, by the kernels' own ``live_pages``."""
         if not self._kernel_active:
             return None
         from veles_tpu.ops.pallas_kernels import (live_page_count,
                                                   live_pages)
         pos = numpy.atleast_1d(pos).astype(numpy.int64)
+        latent = self.cfg.latent is not None
+        walked = latent and span > 0
+        if latent and not span:
+            span = self.prefill_chunk
         given = live = 0
         for kind, layers in self._layers_of_kind:
             p, w = pos, width
@@ -2913,10 +2921,11 @@ class LMEngine(Logger):
                 w = min(width, self._wt.width)
             window = (self.cfg.window if self._wt is None
                       or kind == model_config.SLIDING else None)
-            live += layers * int(live_page_count(*live_pages(
+            seen = layers * int(live_page_count(*live_pages(
                 p, span, self.prefill_chunk, w, window, self.sinks,
                 xp=numpy)).sum())
-            given += layers * p.size * w
+            live += seen
+            given += seen if walked else layers * p.size * w
         return given, live
 
     def _note_attn_dispatch(self, steps=None, calls=1):
@@ -3131,12 +3140,7 @@ class LMEngine(Logger):
                     # (where the program writes its token among the
                     # lanes' last ones: a tail chunk's own slot)
                     xfer.to_device(slot if is_tail else -1, numpy.int32))
-            # (the latent kind's chunk kernel also walks the chunk's own
-            # page, written before it: its query rows count like a
-            # decode's)
-            steps = self._attn_page_steps(
-                start, self._max_pages, rows=slot,
-                span=C if self.cfg.latent is not None else 0)
+            steps = self._attn_page_steps(start, self._max_pages, rows=slot)
         except Exception as e:   # noqa: BLE001 — fails THIS request
             self.metrics.record_error()
             self.warning("paged chunk prefill failed: %s", e)
