@@ -613,10 +613,98 @@ def test_a_decode_step_of_640_rows_takes_the_row_kernel(linear_engine,
     assert compiled_grouped_matmuls(text) == (0, 3 * 4)
 
 
+def test_kda_kernels_compile_at_the_cells_widths(one_chip):
+    """ISSUE 42: the delta rule's two kernels with a decay a KEY CHANNEL
+    (the decay a column beside q and k in the recurrent step; a row turned
+    into a column in the sequential pass), at 64 lanes and 32 heads of 128
+    x 128; and the chunked rule's terms over a 1024-row chunk (the pairwise
+    decays about reference rows)."""
+    from veles_tpu.ops import linear_attn
+    lanes, h, d = 64, 32, 128
+    state = ((lanes, h, d, d), F32)
+    vec, one = ((lanes, h, d), F32), ((lanes, h), F32)
+    text = compile_for(
+        one_chip,
+        lambda s, q, k, v, b, g, a: PK.gdn_decode(s, q, k, v, b, g, a,
+                                                  interpret=False),
+        state, vec, vec, vec, one, vec, ((lanes,), jnp.bool_))
+    assert "tpu_custom_call" in text
+    rows = lambda *tail: ((1, h, 16) + tail, F32)  # noqa: E731
+    text = compile_for(
+        one_chip,
+        lambda s, sl, fr, *terms: PK.gdn_chunk(s, sl, fr, *terms,
+                                               interpret=False),
+        state, ((1,), I32), ((1,), jnp.bool_), rows(64, d), rows(64, d),
+        rows(64, d), rows(64, 64), rows(d, 64), rows(d))
+    assert "tpu_custom_call" in text
+    row = lambda *tail: ((1, 1024, h) + tail, F32)  # noqa: E731
+    compile_for(one_chip, linear_attn.chunk_terms, row(d), row(d), row(d),
+                row(), row(d))
+
+
+@pytest.fixture(scope="module")
+def kda_engine(one_chip):
+    """A small ``LMEngine`` for a stack of Kimi-delta-attention layers and
+    one latent layer, the Pallas serving kernels active, 64 lanes (8 of 64
+    routed in 8 groups: 512 assignment rows a decode step), the published
+    head sizes (128 x 128 states; latent rows of 640 lanes) in bfloat16."""
+    from benchmark.reference import ling3
+    from veles_tpu import model_config
+    from veles_tpu.serving import LMEngine
+    cfg = {
+        "model_type": "ling3_flash", "hidden_size": 384,
+        "num_attention_heads": 4, "head_dim": 128, "q_lora_rank": None,
+        "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+        "qk_rope_head_dim": 64, "v_head_dim": 128, "rope_theta": 6000000,
+        "rms_norm_eps": 1e-6, "intermediate_size": 256,
+        "moe_intermediate_size": 128,
+        "moe_shared_expert_intermediate_size": 128, "num_experts": 16,
+        "router_width": 64, "held_experts": [0, 16],
+        "num_experts_per_tok": 8, "n_group": 8, "topk_group": 4,
+        "norm_topk_prob": True, "routed_scaling_factor": 2.5,
+        "first_k_dense_replace": 1, "short_conv_kernel_size": 4,
+        "kda_lower_bound": -5, "layer_group_size": 3,
+        "num_hidden_layers": 3, "vocab_size": 512, "initializer_std": 0.02}
+    params = ling3.make_weights(1, cfg)
+    monkeypatch = pytest.MonkeyPatch()
+    monkeypatch.setattr(PK, "on_tpu", lambda: True)
+    try:
+        engine = LMEngine(params, model_config.from_published(cfg),
+                          max_len=2048, slots=64, prefill_chunk=256,
+                          paged_kv=128, attn_kernel="auto", name="aot_kda")
+        assert engine._kernel_active
+        yield described(engine, one_chip)
+    finally:
+        monkeypatch.undo()
+
+
+@pytest.mark.parametrize("program", ["chunk", "decode_w8"])
+def test_kda_programs_update_state_and_pool_in_place(kda_engine, program):
+    """ISSUE 42: two kinds of cache in one lane, one of them latent rows:
+    compiled for the chip, neither program copies a state, a convolution
+    tail or the pool, every leaf is listed under ``input_output_alias``,
+    and the expert layers' grouped matmuls are the row kernel's (512
+    assignment rows a decode step)."""
+    from veles_tpu.serving.lm_engine import (compiled_grouped_matmuls,
+                                             compiled_storage_report)
+    engine = kda_engine[0]
+    text = program_text(kda_engine, program)
+    leaves = jax.tree.leaves(engine._kv_pools)
+    kinds = {leaf.shape: leaf for leaf in leaves}
+    assert set(kinds) == {(64, 4, 128, 128), (64, 3, 1536),
+                          (129, 1, 256, 640)}
+    for leaf in kinds.values():
+        copies, aliased = compiled_storage_report(text, leaf)
+        assert copies == 0, "%d copies of %s in %s" % (copies, leaf.shape,
+                                                       program)
+    assert aliased == len(leaves) == 5
+    assert compiled_grouped_matmuls(text) == (0, 3 * 2)
+
+
 @pytest.mark.parametrize("program", PROGRAMS)
 @pytest.mark.parametrize("fixture", ["kernel_engine", "kinds_engine",
                                      "latent_engine", "linear_engine",
-                                     "mtp_engine"])
+                                     "mtp_engine", "kda_engine"])
 def test_engine_programs_read_the_weights_where_they_lie(request, fixture,
                                                          program):
     """ISSUE 31: compiled for the chip, no engine program holds a copy

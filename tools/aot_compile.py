@@ -8,7 +8,7 @@ script holds the whole-program ones, which take up to minutes and are run
 by hand::
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \\
-        python tools/aot_compile.py [alexnet] [lm] [latent] [linear] [mtp] [mesh] [tp]
+        python tools/aot_compile.py [alexnet] [lm] [latent] [linear] [kda] [mtp] [mesh] [tp]
 
 - ``alexnet``: the graph loop's train step and the epoch-scan window
   program at minibatch 128, 227x227 crops, 1000 classes, fp32 and bf16;
@@ -347,30 +347,34 @@ def mtp(one_chip):
     engine_programs("engine mtp", eng, one_chip, (1, eng._max_pages))
 
 
-def linear(one_chip):
+def linear(one_chip, config="qwen3-next-80b-a3b-ep4", tag="engine linear"):
     """ISSUE 36: a stack of linear (gated delta rule) and full layers at
     the benchmark cell's configuration and geometry: the chunk program (the
     chunked rule, the prefill kernel at a head of 256) and the decode
     program (the recurrent rule on the decoding lanes' state, the row-tiled
     grouped matmul at 640 assignment rows) at the narrowest and the widest
-    table."""
+    table.  ``kda`` (ISSUE 42) is the same for ``ling-3.0-flash-vl-ep4``:
+    a decay a key channel, the full layer latent (one pool), 512 assignment
+    rows a decode step."""
+    import importlib
     import json
-    from benchmark.reference import qwen3_next
     from veles_tpu import model_config
     with open(os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "benchmark", "configs",
-            "qwen3-next-80b-a3b-ep4.json")) as f:
+            config + ".json")) as f:
         cfg = json.load(f)
+    reference = importlib.import_module(
+        "benchmark.reference." + cfg["reference"])
     dep = cfg["deployment"]
     params = jax.tree.map(
         lambda a: jnp.zeros(a.shape, a.dtype),
-        jax.eval_shape(lambda: qwen3_next.make_weights(1, cfg)))
+        jax.eval_shape(lambda: reference.make_weights(1, cfg)))
     eng = kernel_engine(params, model_config.from_published(cfg),
                         max_len=cfg["max_position_embeddings"],
                         slots=dep["slots"],
                         prefill_chunk=dep["prefill_chunk"],
                         paged_kv=dep["paged_kv"])
-    engine_programs("engine linear", eng, one_chip, (1, eng._max_pages))
+    engine_programs(tag, eng, one_chip, (1, eng._max_pages))
 
 
 def tp(topo):
@@ -423,6 +427,8 @@ def main(argv):
         latent(one_chip)
     if "linear" in want:
         linear(one_chip)
+    if "kda" in want:
+        linear(one_chip, "ling-3.0-flash-vl-ep4", "engine kda")
     if "mtp" in want:
         mtp(one_chip)
     if "mesh" in want:

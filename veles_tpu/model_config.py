@@ -34,10 +34,12 @@ Two blocks:
   streams mixed around every sublayer by input-dependent coefficients
   (manifold-constrained hyper-connections): see ``ops/hyper.py``.
 
-  Without ``latent`` the same block runs over layers of two kinds
+  With ``linear`` the same block runs over layers of two kinds
   (``attn_kinds``): ``linear`` layers mix tokens through a gated delta rule
   (``linear``, ``ops/linear_attn.py``) and keep a recurrent state and a
   convolution tail a sequence, whatever its length; ``full`` layers are
+  the latent attention above where the record has ``latent`` (one pool of
+  latent rows a ``full`` layer beside the linear layers' state), else
   grouped-query softmax attention whose sigmoid output gate is the second
   half of ``wq``'s output, with per-head q/k norms and rotary positions on
   the first ``partial_rotary`` of a head.  ``norm_centred``: every RMSNorm
@@ -66,7 +68,10 @@ class MoEConfig:
     """The routed feed forward of one model: ``router_width`` experts are
     scored, ``top_k`` chosen per token, and ``held = (lo, n)`` says which
     of them this param tree carries (an expert-parallel share; the others'
-    part of the sum is another chip's)."""
+    part of the sum is another chip's).  With ``n_group`` > 1 the choice is
+    group-limited (``ops/moe.py::route``): the experts lie in ``n_group``
+    equal groups in order, and a token chooses among the experts of its
+    ``topk_group`` best groups."""
     router_width: int
     top_k: int = 1
     score: str = "softmax"            # | "sigmoid"
@@ -76,6 +81,23 @@ class MoEConfig:
     shared: bool = False              # a shared expert beside the routed
     #: the shared expert's output times ``sigmoid(x w_gate)``, one per token
     shared_gate: bool = False
+    n_group: int = 1
+    topk_group: int = 1
+
+    def __post_init__(self):
+        if self.n_group < 1 or self.router_width % self.n_group \
+                or not 1 <= self.topk_group <= self.n_group:
+            raise ValueError("n_group %d must divide router_width %d, and "
+                             "topk_group %d lie in 1..n_group"
+                             % (self.n_group, self.router_width,
+                                self.topk_group))
+        if self.n_group > 1 and (
+                self.router_width // self.n_group < 2
+                or self.topk_group * (self.router_width // self.n_group)
+                < self.top_k):
+            raise ValueError("group-limited routing scores a group by its "
+                             "two best experts and needs top_k experts in "
+                             "the groups kept")
 
     def held_range(self):
         return self.held if self.held is not None else (0, self.router_width)
@@ -83,14 +105,17 @@ class MoEConfig:
 
 @dataclasses.dataclass(frozen=True)
 class LatentConfig:
-    """Latent attention's five sizes: the queries' bottleneck, the cached
-    latent, and per head the unrotated and rotated parts of a query or key
-    and a value's width."""
-    q_rank: int
+    """Latent attention's five sizes: the queries' bottleneck (None: no
+    bottleneck, one matrix ``wq``), the cached latent, and per head the
+    unrotated and rotated parts of a query or key and a value's width.
+    ``head_gate``: each head's output times ``sigmoid(x w_gate)_h`` before
+    ``W_o`` (``w_gate``: (d, heads))."""
+    q_rank: Optional[int]
     kv_rank: int
     nope: int
     rope: int
     v: int
+    head_gate: bool = False
 
     @property
     def width(self):
@@ -114,12 +139,36 @@ class LinearConfig:
     heads of ``k_dim``, ``v_heads`` value heads of ``v_dim`` (a multiple of
     the key heads: each key head serves ``v_heads / k_heads`` value heads),
     a causal depthwise convolution of ``conv`` positions over ``[q, k,
-    v]``."""
+    v]``.  ``decay``: the state decays by one factor a ``head`` each token
+    (Gated DeltaNet: ``g = -exp(A_log) softplus(a + dt_bias)``) or by one a
+    key ``channel`` (Kimi Delta Attention: ``g = lower_bound sigmoid(
+    exp(A_log) (x W_f + dt_bias))``, in ``(lower_bound, 0)``); ``gate``: the
+    output gate is ``silu`` or ``sigmoid`` of ``x W_z``."""
     k_heads: int
     v_heads: int
     k_dim: int
     v_dim: int
     conv: int = 4
+    decay: str = "head"               # | "channel"
+    gate: str = "silu"                # | "sigmoid"
+    #: the least log decay a token (``channel`` only): the chunked rule's
+    #: exponents about a block's first row stay under 16 x this
+    lower_bound: Optional[float] = None
+
+    def __post_init__(self):
+        if self.decay not in ("head", "channel") \
+                or self.gate not in ("silu", "sigmoid"):
+            raise ValueError("unknown decay %r or gate %r"
+                             % (self.decay, self.gate))
+        if (self.decay == "channel") != (self.lower_bound is not None):
+            raise ValueError("a decay per channel and its lower_bound come "
+                             "together")
+        if self.lower_bound is not None \
+                and not -5.0 <= self.lower_bound < 0.0:
+            raise ValueError(
+                "lower_bound %r: the chunked rule holds 16 rows of decay in "
+                "one float32 exponent (16 x 5 = 80 < 88)"
+                % (self.lower_bound,))
 
     @property
     def key_width(self):
@@ -193,7 +242,8 @@ class ModelConfig:
     yarn: Optional[YarnConfig] = None
     #: the n-stream residual (``pre_rms`` only); None: one plain stream
     hyper: Optional[HyperConfig] = None
-    #: the ``linear`` layers' sizes (``pre_rms`` without ``latent``)
+    #: the ``linear`` layers' sizes (``pre_rms``); beside ``latent`` the
+    #: ``full`` layers are the latent ones
     linear: Optional[LinearConfig] = None
     #: share of a head's dimensions, from the first, that rotary positions
     #: rotate (``pre_rms`` without ``latent``)
@@ -218,11 +268,13 @@ class ModelConfig:
                 and self.attn_kinds is None:
             raise ValueError("the pre_rms block is latent attention, or "
                              "names its layers' kinds (attn_kinds)")
-        if self.latent is not None and self.linear is not None:
-            raise ValueError("latent attention and linear layers do not "
-                             "share a stack")
+        if self.latent is not None and self.attn_kinds is not None \
+                and self.linear is None:
+            raise ValueError("a latent stack names its layers' kinds only "
+                             "beside linear layers")
         if self.nextn not in (0, 1) or (self.nextn and (
-                self.latent is None or self.hyper is not None)):
+                self.latent is None or self.hyper is not None
+                or self.linear is not None)):
             raise ValueError("one multi-token-prediction module at most, "
                              "behind a latent stack of one residual stream")
         if self.attn_kinds is not None:
@@ -341,11 +393,13 @@ def of(cfg_or_heads, rope=False, window=None, sinks=0):
 
 def from_published(cfg):
     """The record of a published ``config.json`` (a dict under its own
-    keys), by ``model_type``.  ``afmoe``, ``qwen3_next`` and
-    ``joyai_llm_flash`` also read two keys of a deployment's share where
-    they are given: ``held_experts`` ``[lo, n]`` (this tree's experts, of
-    ``router_width`` that the router scores)."""
+    keys), by ``model_type``.  ``afmoe``, ``qwen3_next``,
+    ``joyai_llm_flash`` and ``ling3_flash`` also read two keys of a
+    deployment's share where they are given: ``held_experts`` ``[lo, n]``
+    (this tree's experts, of ``router_width`` that the router scores)."""
     family = cfg.get("model_type")
+    if family == "ling3_flash":
+        return _ling3(cfg)
     if family == "xing4_0":
         return _xing4(cfg)
     if family == "joyai_llm_flash":
@@ -381,12 +435,12 @@ def from_published(cfg):
 def _xing4(cfg):
     """``model_type: xing4_0``: latent attention under YaRN, leading dense
     layers then sigmoid-routed experts (``noaux_tc``: a selection bias,
-    one group) beside shared ones, an ``hc_mult``-stream residual.  The
+    the choice limited to ``topk_group`` of ``n_group`` groups) beside
+    shared ones, an ``hc_mult``-stream residual.  The
     multi-token-prediction module is not part of the record: a tree that
     carries one is served without it."""
-    if cfg.get("topk_method", "noaux_tc") != "noaux_tc" \
-            or cfg.get("n_group", 1) != 1 or cfg.get("topk_group", 1) != 1:
-        raise ValueError("xing4_0: only noaux_tc selection over one group")
+    if cfg.get("topk_method", "noaux_tc") != "noaux_tc":
+        raise ValueError("xing4_0: only noaux_tc selection")
     if cfg.get("n_shared_experts", 0) > 1:
         raise ValueError("xing4_0: one shared expert or none")
     scaling = cfg.get("rope_scaling") or None
@@ -421,7 +475,9 @@ def _xing4(cfg):
                       score=cfg["scoring_func"],
                       route_norm=bool(cfg["norm_topk_prob"]),
                       route_scale=float(cfg["routed_scaling_factor"]),
-                      shared=cfg.get("n_shared_experts", 0) > 0),
+                      shared=cfg.get("n_shared_experts", 0) > 0,
+                      n_group=cfg.get("n_group", 1),
+                      topk_group=cfg.get("topk_group", 1)),
         dtype=cfg.get("dtype", "bfloat16"), eps=cfg["rms_norm_eps"])
 
 
@@ -449,6 +505,21 @@ def _joyai(cfg):
             held=None if held is None else tuple(held)))
 
 
+def _linear_or_full(cfg, period):
+    """``attn_kinds`` of a stack of linear and full layers: ``layer_types``
+    as written out, else the last of every ``period`` layers full."""
+    n = cfg["num_hidden_layers"]
+    types = cfg.get("layer_types")
+    if types is None:
+        types = ["full_attention" if (i + 1) % period == 0
+                 else "linear_attention" for i in range(n)]
+    if len(types) != n:
+        raise ValueError("layer_types names %d layers of %d"
+                         % (len(types), n))
+    names = {"linear_attention": LINEAR, "full_attention": FULL}
+    return tuple(names[t] for t in types)
+
+
 def _qwen3_next(cfg):
     """``model_type: qwen3_next``: three gated-delta-rule layers to one
     gated softmax-attention layer (``full_attention_interval``, or
@@ -462,15 +533,7 @@ def _qwen3_next(cfg):
                          "(decoder_sparse_step 1, no mlp_only_layers)")
     if cfg.get("rope_scaling"):
         raise ValueError("qwen3_next: no rope_scaling")
-    types = cfg.get("layer_types")
-    if types is None:
-        every = cfg["full_attention_interval"]
-        types = ["full_attention" if (i + 1) % every == 0
-                 else "linear_attention" for i in range(n)]
-    if len(types) != n:
-        raise ValueError("layer_types names %d layers of %d"
-                         % (len(types), n))
-    names = {"linear_attention": LINEAR, "full_attention": FULL}
+    kinds = _linear_or_full(cfg, cfg.get("full_attention_interval"))
     width = cfg.get("router_width", cfg["num_experts"])
     held = tuple(cfg.get("held_experts") or (0, cfg["num_experts"]))
     return ModelConfig(
@@ -478,7 +541,7 @@ def _qwen3_next(cfg):
         n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
         rope=True, rope_theta=float(cfg["rope_theta"]),
         partial_rotary=float(cfg.get("partial_rotary_factor", 1.0)),
-        attn_kinds=tuple(names[t] for t in types),
+        attn_kinds=kinds,
         linear=LinearConfig(
             k_heads=cfg["linear_num_key_heads"],
             v_heads=cfg["linear_num_value_heads"],
@@ -492,4 +555,65 @@ def _qwen3_next(cfg):
                       route_norm=bool(cfg["norm_topk_prob"]), held=held,
                       shared=cfg["shared_expert_intermediate_size"] > 0,
                       shared_gate=True),
+        dtype=cfg.get("dtype", "bfloat16"), eps=cfg["rms_norm_eps"])
+
+
+def _ling3(cfg):
+    """``model_type: ling3_flash`` (the language model of
+    ``inclusionAI/Ling-3.0-flash-VL``; the name is ours, the published
+    row states none): of every ``layer_group_size`` layers the last is
+    latent attention WITHOUT a query bottleneck (``q_lora_rank`` null) under
+    a head-wise sigmoid output gate, the others Kimi Delta Attention (a
+    delta rule whose state decays by a vector over the key dimension,
+    ``kda_safe_gate``); leading dense layers, then sigmoid-routed experts
+    chosen within ``topk_group`` of ``n_group`` groups beside one shared
+    expert.  No vision tower, no multi-token-prediction module: what the
+    record cannot compute it refuses by key."""
+    def refuse(key, why):
+        raise ValueError("ling3_flash: %s %r: %s" % (key, cfg.get(key), why))
+
+    for key in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list"):
+        if any(cfg.get(key) or ()):
+            refuse(key, "a nonzero SwiGLU limit clamps the experts' hidden "
+                   "activations in a form no source here gives; only layers "
+                   "whose limit is 0 are served")
+    for key in ("use_nGPT", "scale_router_input", "value_norm",
+                "up_proj_norm", "use_mla_nope", "use_kda_lora",
+                "rope_scaling", "num_nextn_predict_layers",
+                "num_kv_heads_for_linear_attn"):
+        if cfg.get(key):
+            refuse(key, "not computed here")
+    for key, want in (("kda_safe_gate", True), ("no_kda_lora", True),
+                      ("linear_silu", True), ("group_norm_size", 1),
+                      ("score_function", "sigmoid"),
+                      ("moe_router_enable_expert_bias", True),
+                      ("gated_attention_proj_granularity_type", "head_wise"),
+                      ("rotary_dim", cfg["qk_rope_head_dim"])):
+        if cfg.get(key, want) != want:
+            refuse(key, "only %r is computed here" % (want,))
+    n = cfg["num_hidden_layers"]
+    heads, dim = cfg["num_attention_heads"], cfg["head_dim"]
+    held = cfg.get("held_experts")
+    return ModelConfig(
+        n_heads=heads, block="pre_rms", rope=True,
+        rope_theta=float(cfg["rope_theta"]),
+        attn_kinds=_linear_or_full(cfg, cfg.get("layer_group_size")),
+        latent=LatentConfig(
+            q_rank=cfg.get("q_lora_rank"), kv_rank=cfg["kv_lora_rank"],
+            nope=cfg["qk_nope_head_dim"], rope=cfg["qk_rope_head_dim"],
+            v=cfg["v_head_dim"], head_gate=True),
+        linear=LinearConfig(
+            k_heads=heads, v_heads=heads, k_dim=dim, v_dim=dim,
+            conv=cfg["short_conv_kernel_size"], decay="channel",
+            gate="sigmoid", lower_bound=float(cfg["kda_lower_bound"])),
+        ffn_kinds=tuple(DENSE if i < cfg["first_k_dense_replace"] else MOE
+                        for i in range(n)),
+        moe=MoEConfig(router_width=cfg.get("router_width",
+                                           cfg["num_experts"]),
+                      top_k=cfg["num_experts_per_tok"], score="sigmoid",
+                      route_norm=bool(cfg["norm_topk_prob"]),
+                      route_scale=float(cfg["routed_scaling_factor"]),
+                      held=None if held is None else tuple(held),
+                      shared=cfg["moe_shared_expert_intermediate_size"] > 0,
+                      n_group=cfg["n_group"], topk_group=cfg["topk_group"]),
         dtype=cfg.get("dtype", "bfloat16"), eps=cfg["rms_norm_eps"])

@@ -478,17 +478,17 @@ def mha_forward(params, x, n_heads, causal=True, block_size=None,
     ``rope`` rotates q/k (``positions`` defaults to 0..s-1); ``window``
     restricts attention to the last W positions."""
     cfg = model_config.of(n_heads, rope, window, sinks)
+    if cfg.kind(layer) == model_config.LINEAR:
+        from veles_tpu.ops.linear_attn import linear_forward
+        if return_kv or not causal:
+            raise ValueError("a linear layer is causal and has no KV cache")
+        return linear_forward(params, x, cfg)
     if cfg.latent is not None:
         from veles_tpu.ops.latent import latent_forward
         if return_kv or not causal:
             raise ValueError("latent attention is causal and has no "
                              "contiguous cache")
         return latent_forward(params, x, cfg, positions)
-    if cfg.kind(layer) == model_config.LINEAR:
-        from veles_tpu.ops.linear_attn import linear_forward
-        if return_kv or not causal:
-            raise ValueError("a linear layer is causal and has no KV cache")
-        return linear_forward(params, x, cfg)
     rope, window = cfg.layer_rope(layer), cfg.layer_window(layer)
     n_heads, sinks = cfg.n_heads, cfg.sinks
     s = x.shape[1]

@@ -1,13 +1,15 @@
 """Latent attention (MLA): the attention of the ``pre_rms`` block
 (``model_config.LatentConfig``).
 
-Per token ``x``: ``c_q = rms(x W_qa)``, ``q = c_q W_qb`` and per head
+Per token ``x``: ``c_q = rms(x W_qa)``, ``q = c_q W_qb`` (or ``q = x W_q``
+where the record has no bottleneck, ``q_rank`` None) and per head
 ``[q_nope | q_rope]``; ``[c_kv | k_rope] = x W_kva``, ``c_kv = rms(c_kv)``;
 ``q_rope`` and ``k_rope`` rotated (YaRN), ``k_rope`` one vector for all
 heads.  THE CACHE HOLDS ``(c_kv, k_rope)``: one row of ``latent.row`` lanes
 a token a layer (``latent.width`` numbers, zeros behind them).  Per head
 ``[k_nope | v] = c_kv W_kvb``; ``s = (q_nope . k_nope + q_rope . k_rope)
-* scale``, causal softmax, ``o = sum p v``, output ``concat(o) W_o``.
+* scale``, causal softmax, ``o = sum p v``, output ``concat(o) W_o`` (each
+head's ``o`` first times ``sigmoid(x w_gate)_h`` under ``head_gate``).
 
 Two orders of the same sums, chosen by phase:
 
@@ -128,8 +130,12 @@ def queries(p, x, cfg, cos, sin, cached=False):
     (b, s, d); ``cos``/``sin`` (b, s, rope/2) or (s, rope/2)."""
     lat = cfg.latent
     b, s, _ = x.shape
-    cq = rms_norm(cfg_matmul(cfg, x, p["wq_a"]), p["q_norm"], cfg.eps)
-    q = _held(cfg_matmul(cfg, cq, p["wq_b"]), cached).reshape(
+    if lat.q_rank is None:
+        q = cfg_matmul(cfg, x, p["wq"])
+    else:
+        cq = rms_norm(cfg_matmul(cfg, x, p["wq_a"]), p["q_norm"], cfg.eps)
+        q = cfg_matmul(cfg, cq, p["wq_b"])
+    q = _held(q, cached).reshape(
         b, s, cfg.n_heads, lat.nope + lat.rope).transpose(0, 2, 1, 3)
     cos, sin = (t[..., None, :, :] for t in (cos, sin))
     return q[..., :lat.nope], rotate(q[..., lat.nope:], cos, sin)
@@ -154,9 +160,17 @@ def kvb_heads(p):
     return p["wk_b"], p["wv_b"]
 
 
-def _merge(p, o, cfg):
-    """Heads' outputs (b, h, s, v) through ``W_o``."""
+def _merge(p, o, cfg, x):
+    """Heads' outputs (b, h, s, v) through ``W_o``; under ``head_gate``
+    each head's first times its sigmoid gate of the layer's input ``x``
+    (b, s, d), one number a token and head, in float32."""
     b, h, s, v = o.shape
+    if cfg.latent.head_gate:
+        gate = jax.nn.sigmoid(jnp.matmul(
+            x, p["w_gate"], preferred_element_type=jnp.float32,
+            precision=F._PRECISION if x.dtype == jnp.float32 else None))
+        o = (o.astype(jnp.float32)
+             * gate.transpose(0, 2, 1)[..., None]).astype(o.dtype)
     return cfg_matmul(cfg, o.transpose(0, 2, 1, 3).reshape(b, s, h * v),
                       p["wo"])
 
@@ -223,7 +237,7 @@ def latent_forward(p, x, cfg, positions=None):
         rows = latent_rows(p, x, cfg, cos, sin)
         live = chunk_live_mask(0, s, s)[None, None]
         o = attend_expanded(p, q_nope, q_rope, rows, live, cfg)
-    return _merge(p, o, cfg)
+    return _merge(p, o, cfg, x)
 
 
 def _write_chunk_pages(pool, ptab, pos, rows):
@@ -300,4 +314,4 @@ def latent_paged_chunk_step(p, x, pool, ptab, pos, cfg, attn_kernel=None,
                 q, c, view.shape[1]))(pos)[:, None]
             attend = attend_absorbed if absorbed else attend_expanded
             o = attend(p, q_nope, q_rope, view, live, cfg)
-    return _merge(p, o, cfg), pool
+    return _merge(p, o, cfg, x), pool

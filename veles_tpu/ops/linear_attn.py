@@ -1,5 +1,7 @@
-"""Gated DeltaNet: the ``linear`` layers of the ``pre_rms`` block
-(``model_config.LinearConfig``; arXiv:2412.06464).
+"""The gated delta rule: the ``linear`` layers of the ``pre_rms`` block
+(``model_config.LinearConfig``), with ONE decay a head (Gated DeltaNet,
+arXiv:2412.06464) or one a key channel (Kimi Delta Attention,
+arXiv:2510.26692).
 
 Per token ``x``: ``[q | k | v] = x W_qkv`` (``k_heads`` heads of ``k_dim``
 twice, ``v_heads`` of ``v_dim``), ``z = x W_z`` (an output gate the values'
@@ -15,8 +17,17 @@ a sequence starts::
     S <- exp(g_t) S;  d = beta_t (v_t - S^T k_t);  S <- S + k_t d^T;
     o_t = S^T q_t
 
-and the layer's output is ``(rms(o_t) * w_n * silu(z_t)) W_o``.  WHAT A
-SEQUENCE KEEPS is ``S`` of every value head (float32) and the last ``conv -
+and the layer's output is ``(rms(o_t) * w_n * silu(z_t)) W_o``.
+
+With a decay per CHANNEL (``linear.decay == "channel"``) the tree has ``w_b``
+(one ``beta`` a head) and ``w_f`` (the keys' width) in ``w_ba``'s place: ``g =
+lower_bound * sigmoid(exp(A_log) (x W_f + dt_bias))``, one number a head and
+key channel in ``(lower_bound, 0)`` (``A_log`` a head, ``dt_bias`` a
+channel), the rule's first line becomes ``S <- diag(exp(g_t)) S``, and the
+output gate is ``sigmoid(z_t)`` (``linear.gate``).  Everywhere below ``g`` is
+``(.., h)`` or ``(.., h, k_dim)`` and the same functions take both.
+
+WHAT A SEQUENCE KEEPS is ``S`` of every value head (float32) and the last ``conv -
 1`` rows of ``[q | k | v]`` BEFORE the convolution, whatever its length.
 
 Two orders of the same sums, by phase:
@@ -35,7 +46,15 @@ Two orders of the same sums, by phase:
   gam_j)) V'``, ``S <- exp(gam_C) S + (K exp(gam_C - gam))^T V'``.  The
   pass is a ``lax.scan``, or with the serving kernels
   ``pallas_kernels.gdn_chunk`` (the state of a head in fast memory from
-  the first inner chunk to the last).
+  the first inner chunk to the last).  With a decay per channel
+  ``exp(gam_i - gam_j)`` sits INSIDE the sum over the key dimension and is
+  no one matrix a head: a block of ``SUB`` rows ``I`` computes ``(k_i
+  exp(gam_i - ref_I)) . (k_j exp(ref_I - gam_j))`` about its first row's
+  ``gam`` (``ref_I``).  Decays only shrink, so the left exponent lies in
+  ``[(SUB - 1) lower_bound, 0]`` and the right one under ``(SUB - 1)
+  |lower_bound|`` (75 at -5: ``exp`` stays inside float32), and under 0 for
+  every row of an earlier block: a factor may underflow to 0, as the product
+  it stands for would, and none overflows.
 
 A row behind a lane's ``rows`` (the padding of a prompt's last chunk; the
 one row of a lane that does not decode) has ``beta = 0`` and ``g = 0``: it
@@ -44,10 +63,15 @@ moves no state, and the convolution tail is taken at the true length.
 ``g``, ``beta``, ``gam``, ``S``, the L2 norms, the convolution's sums and
 every accumulator are float32 whatever the model's dtype; the dots of the
 rule itself run at ``HIGHEST`` (they are small: the state is the cost).
-Everything here runs under the scope ``attn.linear``.
+Everything here runs under the scope ``attn.linear``; the Pallas calls of a
+decay per channel under ``kda.decode`` / ``kda.chunk`` inside it (a call
+takes its innermost scope's name, and the benchmark's readers tell the two
+rules' kernels apart by it).
 """
 
 from __future__ import annotations
+
+import contextlib
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +80,9 @@ from veles_tpu.ops.attention import cfg_matmul
 
 #: rows of one inner chunk of the chunked rule
 CHUNK = 64
+#: rows of one block of the pairwise decays of a decay per channel (the block
+#: of ``solve_unit_lower``)
+SUB = 16
 L2_EPS = 1e-6
 _HI = jax.lax.Precision.HIGHEST
 
@@ -67,13 +94,25 @@ def _f32(x):
 # ------------------------------------------------------------ projections
 def _inputs(p, x, cfg, cached):
     """(qkv (b, c, conv_width) before the convolution, z (b, c, value
-    width), beta and g (b, c, v_heads) float32) of ``x`` (b, c, d)."""
+    width), beta (b, c, v_heads) and g (b, c, v_heads) or (b, c, v_heads,
+    k_dim) float32) of ``x`` (b, c, d)."""
     hold = jax.lax.optimization_barrier if cached else (lambda y: y)
     qkv = hold(cfg_matmul(cfg, x, p["w_qkv"]))
     z = hold(cfg_matmul(cfg, x, p["w_z"]))
-    ba = jnp.matmul(x, p["w_ba"], preferred_element_type=jnp.float32,
-                    precision=_HI if x.dtype == jnp.float32 else None)
-    h = cfg.linear.v_heads
+
+    def wide(w):
+        return jnp.matmul(x, w, preferred_element_type=jnp.float32,
+                          precision=_HI if x.dtype == jnp.float32 else None)
+
+    lin = cfg.linear
+    h = lin.v_heads
+    if lin.decay == "channel":
+        f = hold(wide(p["w_f"])).reshape(x.shape[:2] + (h, lin.k_dim))
+        g = lin.lower_bound * jax.nn.sigmoid(
+            jnp.exp(_f32(p["A_log"]))[:, None]
+            * (f + _f32(p["dt_bias"]).reshape(h, lin.k_dim)))
+        return qkv, z, jax.nn.sigmoid(wide(p["w_b"])), g
+    ba = wide(p["w_ba"])
     beta = jax.nn.sigmoid(ba[..., :h])
     g = -jnp.exp(_f32(p["A_log"])) * jax.nn.softplus(
         ba[..., h:] + _f32(p["dt_bias"]))
@@ -87,6 +126,17 @@ def _convolve(tail, qkv, w, rows):
     ``rows`` of the chunk; ``rows = 0`` hands the old tail back)."""
     k = w.shape[0]
     c = qkv.shape[1]
+    if c == 1:
+        # a decode step: the tail moves on by its one row or stays, and no
+        # array of ``conv`` rows is built.  (Slices of the two put together,
+        # one at a traced row of every lane, made the chip's compiler
+        # convert the whole tail's layout on the way in and out at 12288
+        # channels: 12 copies a step, ISSUE 42.)
+        acc = sum(_f32(tail[:, j:j + 1]) * _f32(w[j]) for j in range(k - 1)) \
+            + _f32(qkv) * _f32(w[k - 1])
+        moved = jnp.concatenate([tail[:, 1:], qkv], axis=1)
+        return jax.nn.silu(acc), jnp.where((rows > 0)[:, None, None], moved,
+                                           tail)
     seq = jnp.concatenate([tail, qkv], axis=1)           # (b, c + k - 1, ch)
     acc = sum(_f32(seq[:, j:j + c]) * _f32(w[j]) for j in range(k))
     new_tail = jax.vmap(lambda s, r: jax.lax.dynamic_slice_in_dim(
@@ -114,22 +164,25 @@ def _heads(act, cfg):
 
 
 def _output(p, o, z, cfg):
-    """``(rms(o) * w_n * silu(z)) W_o``: o (b, c, v_heads, v_dim) float32,
-    z (b, c, value width)."""
+    """``(rms(o) * w_n * gate(z)) W_o``: o (b, c, v_heads, v_dim) float32,
+    z (b, c, value width); the gate ``silu`` or ``sigmoid``."""
     lin = cfg.linear
     b, c = o.shape[:2]
     o = o * jax.lax.rsqrt((o * o).mean(-1, keepdims=True) + cfg.eps) \
         * _f32(p["norm"])
-    o = o * jax.nn.silu(_f32(z).reshape(b, c, lin.v_heads, lin.v_dim))
+    gate = jax.nn.silu if lin.gate == "silu" else jax.nn.sigmoid
+    o = o * gate(_f32(z).reshape(b, c, lin.v_heads, lin.v_dim))
     return cfg_matmul(cfg, o.reshape(b, c, -1).astype(z.dtype), p["wo"])
 
 
 # -------------------------------------------------------------- two orders
 def recurrent_step(state, q, k, v, beta, g):
     """One row a lane by the rule as written: state (b, h, dk, dv); q, k
-    (b, h, dk); v (b, h, dv); beta, g (b, h).  Returns (o (b, h, dv), the
-    new state)."""
-    state = state * jnp.exp(g)[..., None, None]
+    (b, h, dk); v (b, h, dv); beta (b, h); g (b, h) or (b, h, dk).  Returns
+    (o (b, h, dv), the new state)."""
+    decay = jnp.exp(g)
+    state = state * (decay[..., None] if g.ndim == 3
+                     else decay[..., None, None])
     kv = jnp.einsum("bhkv,bhk->bhv", state, k, precision=_HI)
     d = beta[..., None] * (v - kv)
     state = state + k[..., :, None] * d[..., None, :]
@@ -166,12 +219,37 @@ def solve_unit_lower(a, rhs, block=16):
     return jnp.concatenate(out, axis=-2)
 
 
+def _pair_products(q, k, gam):
+    """``(sum_c k_i k_j exp(gam_i - gam_j), sum_c q_i k_j exp(gam_i -
+    gam_j))`` for ``j <= i`` (what lies above the diagonal is finite and
+    means nothing), each (..., C, C), for a decay per channel: q, k, gam
+    (..., C, dk).  Block ``I`` of ``SUB`` rows meets the rows up to its
+    own end about its first row's ``gam``."""
+    kk, qk = [], []
+    for lo in range(0, CHUNK, SUB):
+        hi = lo + SUB
+        ref = gam[..., lo:lo + 1, :]
+        left = jnp.exp(gam[..., lo:hi, :] - ref)
+        right = k[..., :hi, :] * jnp.exp(ref - gam[..., :hi, :])
+        both = jnp.einsum(
+            "...ik,...jk->...ij",
+            jnp.concatenate([k[..., lo:hi, :] * left,
+                             q[..., lo:hi, :] * left], axis=-2),
+            right, precision=_HI)
+        both = jnp.pad(both, [(0, 0)] * (both.ndim - 1) + [(0, CHUNK - hi)])
+        kk.append(both[..., :SUB, :])
+        qk.append(both[..., SUB:, :])
+    return jnp.concatenate(kk, axis=-2), jnp.concatenate(qk, axis=-2)
+
+
 def chunk_terms(q, k, v, beta, g):
     """What the sequential pass of the chunked rule reads, for all inner
-    chunks at once: q, k (b, L, h, dk), v (b, L, h, dv), beta, g (b, L, h),
-    L a multiple of ``CHUNK``.  Returns float32 ``(W, U, Qg (b, h, n, C,
-    .), Att (b, h, n, C, C), KdT (b, h, n, dk, C), decay (b, h, n))``:
-    ``V' = U - W S``; ``O = Qg S + Att V'``; ``S <- decay S + KdT V'``."""
+    chunks at once: q, k (b, L, h, dk), v (b, L, h, dv), beta (b, L, h), g
+    (b, L, h) or (b, L, h, dk), L a multiple of ``CHUNK``.  Returns float32
+    ``(W, U, Qg (b, h, n, C, .), Att (b, h, n, C, C), KdT (b, h, n, dk, C),
+    decay (b, h, n) or (b, h, n, dk))``: ``V' = U - W S``; ``O = Qg S + Att
+    V'``; ``S <- decay S + KdT V'`` (``decay`` by rows of ``S`` where it is
+    one a channel)."""
     b, length, h, _ = q.shape
     n = length // CHUNK
 
@@ -180,23 +258,32 @@ def chunk_terms(q, k, v, beta, g):
         return jnp.moveaxis(y, 3, 1)
 
     q, k, v, beta, g = (split(y) for y in (q, k, v, beta, g))
-    gam = jnp.cumsum(g, axis=-1)                            # (b, h, n, C)
-    diff = gam[..., :, None] - gam[..., None, :]            # gam_i - gam_j
     low = jnp.tril(jnp.ones((CHUNK, CHUNK), bool))
-    decay = jnp.exp(jnp.where(low, diff, -jnp.inf))         # 0 above diag
-    kk = jnp.einsum("bhnik,bhnjk->bhnij", k, k, precision=_HI)
-    a = beta[..., None] * kk * jnp.where(jnp.eye(CHUNK, dtype=bool), 0.0,
-                                         decay)
-    rhs = jnp.concatenate([(beta * jnp.exp(gam))[..., None] * k,
+    eye = jnp.eye(CHUNK, dtype=bool)
+    if g.ndim == 5:
+        gam = jnp.cumsum(g, axis=-2)                        # (b, h, n, C, dk)
+        kk, qk = _pair_products(q, k, gam)
+        a = beta[..., None] * jnp.where(low & ~eye, kk, 0.0)
+        att = jnp.where(low, qk, 0.0)
+        scale = jnp.exp(gam)
+        last = gam[..., -1:, :]
+        to_last, decay = jnp.exp(last - gam), jnp.exp(last[..., 0, :])
+    else:
+        gam = jnp.cumsum(g, axis=-1)                        # (b, h, n, C)
+        diff = gam[..., :, None] - gam[..., None, :]        # gam_i - gam_j
+        pair = jnp.exp(jnp.where(low, diff, -jnp.inf))      # 0 above diag
+        kk = jnp.einsum("bhnik,bhnjk->bhnij", k, k, precision=_HI)
+        a = beta[..., None] * kk * jnp.where(eye, 0.0, pair)
+        att = jnp.einsum("bhnik,bhnjk->bhnij", q, k, precision=_HI) * pair
+        scale = jnp.exp(gam)[..., None]
+        last = gam[..., -1:]
+        to_last, decay = jnp.exp(last - gam)[..., None], jnp.exp(last[..., 0])
+    rhs = jnp.concatenate([beta[..., None] * scale * k,
                            beta[..., None] * v], axis=-1)
     solved = solve_unit_lower(a, rhs)
     dk = k.shape[-1]
     w, u = solved[..., :dk], solved[..., dk:]
-    qg = q * jnp.exp(gam)[..., None]
-    att = jnp.einsum("bhnik,bhnjk->bhnij", q, k, precision=_HI) * decay
-    last = gam[..., -1:]
-    kdt = jnp.swapaxes(k * jnp.exp(last - gam)[..., None], -1, -2)
-    return w, u, qg, att, kdt, jnp.exp(last[..., 0])
+    return (w, u, q * scale, att, jnp.swapaxes(k * to_last, -1, -2), decay)
 
 
 def chunk_pass(state, terms):
@@ -207,7 +294,8 @@ def chunk_pass(state, terms):
         vp = u - jnp.einsum("bhck,bhkv->bhcv", w, s, precision=_HI)
         o = jnp.einsum("bhck,bhkv->bhcv", qg, s, precision=_HI) \
             + jnp.einsum("bhij,bhjv->bhiv", att, vp, precision=_HI)
-        s = decay[..., None, None] * s \
+        s = (decay[..., None] if decay.ndim == 3
+             else decay[..., None, None]) * s \
             + jnp.einsum("bhkc,bhcv->bhkv", kdt, vp, precision=_HI)
         return s, o
 
@@ -217,6 +305,15 @@ def chunk_pass(state, terms):
 
 
 # ------------------------------------------------------------ entry points
+def _kernel_scope(lin, order):
+    """The scope a Pallas call of the rule runs under: the layer's own
+    (``attn.linear``) for one decay a head, ``kda.<order>`` for one a
+    channel."""
+    if lin.decay == "channel":
+        return jax.named_scope("kda." + order)
+    return contextlib.nullcontext()
+
+
 def linear_paged_chunk_step(p, x, state, tail, cfg, rows, slots=None,
                             fresh=None, attn_kernel=None):
     """``c`` positions per lane through one linear layer against the lanes'
@@ -240,8 +337,9 @@ def linear_paged_chunk_step(p, x, state, tail, cfg, rows, slots=None,
     with jax.named_scope("attn.linear"):
         qkv, z, beta, g = _inputs(p, x, cfg, cached=True)
         real = jnp.arange(c)[None, :] < rows[:, None]          # (b, c)
-        beta = jnp.where(real[..., None], beta, 0.0)
-        g = jnp.where(real[..., None], g, 0.0)
+        real = real[..., None]
+        beta = jnp.where(real, beta, 0.0)
+        g = jnp.where(real if g.ndim == 3 else real[..., None], g, 0.0)
         mine = tail if slots is None else tail[slots]
         if fresh is not None:
             mine = jnp.where(fresh[:, None, None], 0, mine).astype(tail.dtype)
@@ -253,8 +351,10 @@ def linear_paged_chunk_step(p, x, state, tail, cfg, rows, slots=None,
                 from veles_tpu.ops import pallas_kernels as PK
                 if slots is not None:
                     raise ValueError("the decode kernel steps every slot")
-                o, state = PK.gdn_decode(state, q[:, 0], k[:, 0], v[:, 0],
-                                         beta[:, 0], g[:, 0], rows > 0)
+                with _kernel_scope(lin, "decode"):
+                    o, state = PK.gdn_decode(
+                        state, q[:, 0], k[:, 0], v[:, 0], beta[:, 0],
+                        g[:, 0], rows > 0)
             else:
                 s0 = state if slots is None else state[slots]
                 o, s1 = recurrent_step(s0, q[:, 0], k[:, 0], v[:, 0],
@@ -274,7 +374,8 @@ def linear_paged_chunk_step(p, x, state, tail, cfg, rows, slots=None,
                 fresh = jnp.zeros((b,), bool)
             if attn_kernel:
                 from veles_tpu.ops import pallas_kernels as PK
-                o, state = PK.gdn_chunk(state, ids, fresh, *terms)
+                with _kernel_scope(lin, "chunk"):
+                    o, state = PK.gdn_chunk(state, ids, fresh, *terms)
             else:
                 s0 = jnp.where(fresh[:, None, None, None], 0.0, state[ids])
                 o, s1 = chunk_pass(s0, terms)

@@ -100,11 +100,27 @@ def router_probs(params, x):
 def route(params, flat, moe):
     """(scores (T, E) float32, chosen experts (T, k) int32, their weights
     (T, k) float32).  The selection bias (``params["bias"]``, where the
-    tree has one) enters the choice and not the weight."""
+    tree has one) enters the choice and not the weight.  Group-limited
+    (``moe.n_group`` > 1; DeepSeek-V3's ``noaux_tc``): the experts lie in
+    ``n_group`` equal groups in order, a group's score is the sum of its two
+    largest choice scores, and the ``top_k`` are taken among the experts of
+    the ``topk_group`` best groups."""
     scores = router_scores(params, flat, moe)
     choice = scores
     if "bias" in params:
         choice = scores + params["bias"].astype(jnp.float32)
+    if moe.n_group > 1:
+        grouped = choice.reshape(choice.shape[0], moe.n_group, -1)
+        # a group's two best as two reductions (``top_k`` over the groups'
+        # 64 is a sort on the chip: 43 us a layer and decode step of the
+        # cell, ISSUE 42)
+        first = grouped.argmax(-1)
+        second = jnp.where(first[..., None] == jnp.arange(grouped.shape[-1]),
+                           -jnp.inf, grouped).max(-1)
+        _, kept = jax.lax.top_k(grouped.max(-1) + second, moe.topk_group)
+        keep = (kept[:, :, None] == jnp.arange(moe.n_group)).any(1)
+        choice = jnp.where(keep[:, :, None], grouped,
+                           -jnp.inf).reshape(choice.shape)
     _, idx = jax.lax.top_k(choice, moe.top_k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if moe.route_norm:

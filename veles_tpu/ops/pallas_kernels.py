@@ -1325,7 +1325,8 @@ _GDN_VMEM = 48 << 20
 
 def gdn_decode(state, q, k, v, beta, g, active, interpret=None):
     """The recurrent rule for ONE row a lane, on the lanes that decode:
-    state (b, h, dk, dv); q, k (b, h, dk); v (b, h, dv); beta, g (b, h),
+    state (b, h, dk, dv); q, k (b, h, dk); v (b, h, dv); beta (b, h); g (b,
+    h), one decay a head, or (b, h, dk), one a key channel (a row of ``S``),
     all float32; ``active`` (b,) bool.  Per lane and head ``S <- exp(g) S;
     d = beta (v - S^T k); S <- S + k d^T; o = S^T q``
     (``linear_attn.recurrent_step``, sum for sum).  Returns (o (b, h, dv),
@@ -1348,11 +1349,17 @@ def gdn_decode(state, q, k, v, beta, g, active, interpret=None):
     order = jnp.argsort(~active, stable=True).astype(jnp.int32)
     ids = order[jnp.minimum(jnp.arange(b, dtype=jnp.int32),
                             jnp.maximum(n - 1, 0))]
-    # k and q as columns (k_dim on sublanes, a head a lane), v and the two
-    # scalars of a head as rows
-    cols = jnp.stack([jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2)], axis=1)
-    rows = jnp.stack([v, jnp.broadcast_to(jnp.exp(g)[..., None], v.shape),
-                      jnp.broadcast_to(beta[..., None], v.shape)], axis=1)
+    # k and q as columns (k_dim on sublanes, a head a lane), v and the
+    # scalars of a head as rows; a decay per channel is a column too
+    per_channel = g.ndim == 3
+    cols = [jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2)]
+    rows = [v, jnp.broadcast_to(beta[..., None], v.shape)]
+    if per_channel:
+        cols.append(jnp.swapaxes(jnp.exp(g), 1, 2))
+    else:
+        rows.insert(1, jnp.broadcast_to(jnp.exp(g)[..., None], v.shape))
+    cols, rows = jnp.stack(cols, axis=1), jnp.stack(rows, axis=1)
+    n_cols, n_rows = cols.shape[1], rows.shape[1]
 
     def kernel(ids_ref, n_ref, s_ref, c_ref, r_ref, o_ref, so_ref):
         i = pl.program_id(0)
@@ -1363,8 +1370,9 @@ def gdn_decode(state, q, k, v, beta, g, active, interpret=None):
                 qc = c_ref[0, 0, :, e:e + 1]                  # (dk, 1)
                 kc = c_ref[0, 1, :, e:e + 1]
                 ve = r_ref[0, 0, e:e + 1, :]                  # (1, dv)
-                s = s_ref[0, e] * r_ref[0, 1, e:e + 1, :]
-                d = r_ref[0, 2, e:e + 1, :] * (
+                s = s_ref[0, e] * (c_ref[0, 2, :, e:e + 1] if per_channel
+                                   else r_ref[0, 1, e:e + 1, :])
+                d = r_ref[0, n_rows - 1, e:e + 1, :] * (
                     ve - (s * kc).sum(axis=0, keepdims=True))
                 s = s + kc * d
                 so_ref[0, e] = s
@@ -1382,8 +1390,8 @@ def gdn_decode(state, q, k, v, beta, g, active, interpret=None):
         num_scalar_prefetch=2,
         grid=(b,),
         in_specs=[pl.BlockSpec((1, h, dk, dv), lane4),
-                  pl.BlockSpec((1, 2, dk, h), lane4),
-                  pl.BlockSpec((1, 3, h, dv), lane4)],
+                  pl.BlockSpec((1, n_cols, dk, h), lane4),
+                  pl.BlockSpec((1, n_rows, h, dv), lane4)],
         out_specs=(pl.BlockSpec((1, h, dv), lambda i, ids, n: (ids[i], 0, 0)),
                    pl.BlockSpec((1, h, dk, dv), lane4)),
     )
@@ -1411,7 +1419,8 @@ def gdn_chunk(state, slots, fresh, w, u, qg, att, kdt, decay,
 
     state (slots, h, dk, dv); ``slots`` (b,) int32 the lanes' slots;
     ``fresh`` (b,) bool; w, qg (b, h, n, C, dk); u (b, h, n, C, dv); att
-    (b, h, n, C, C); kdt (b, h, n, dk, C); decay (b, h, n): what
+    (b, h, n, C, C); kdt (b, h, n, dk, C); decay (b, h, n), or (b, h, n, dk)
+    where it is one a key channel (a row of ``S``): what
     ``linear_attn.chunk_terms`` returns.  Returns (O (b, h, n, C, dv), the
     state with the lanes' slots rewritten, in place)."""
     from jax.experimental import pallas as pl
@@ -1419,7 +1428,11 @@ def gdn_chunk(state, slots, fresh, w, u, qg, att, kdt, decay,
 
     b, h, n, c, dk = w.shape
     dv = u.shape[-1]
-    decay = jnp.broadcast_to(decay[..., None, None], (b, h, n, 1, dv))
+    per_channel = decay.ndim == 4
+    if per_channel:
+        decay = decay[..., None, :]                       # (b, h, n, 1, dk)
+    else:
+        decay = jnp.broadcast_to(decay[..., None, None], (b, h, n, 1, dv))
 
     def dot(x, y):
         return jnp.dot(x, y, precision=jax.lax.Precision.HIGHEST,
@@ -1437,7 +1450,13 @@ def gdn_chunk(state, slots, fresh, w, u, qg, att, kdt, decay,
         s = acc_ref[...]
         vp = u_ref[0, 0, 0] - dot(w_ref[0, 0, 0], s)
         o_ref[0, 0, 0] = dot(q_ref[0, 0, 0], s) + dot(a_ref[0, 0, 0], vp)
-        s = d_ref[0, 0, 0] * s + dot(k_ref[0, 0, 0], vp)
+        d = d_ref[0, 0, 0]
+        if per_channel:
+            # the row (1, dk) as a column (dk, 1): its diagonal's row sums
+            at = [jax.lax.broadcasted_iota(jnp.int32, (dk, dk), axis)
+                  for axis in (0, 1)]
+            d = jnp.where(at[0] == at[1], d, 0.0).sum(axis=1, keepdims=True)
+        s = d * s + dot(k_ref[0, 0, 0], vp)
         acc_ref[...] = s
 
         @pl.when(t == n - 1)
@@ -1458,7 +1477,7 @@ def gdn_chunk(state, slots, fresh, w, u, qg, att, kdt, decay,
         grid=(b, h, n),
         in_specs=[pl.BlockSpec((1, 1, dk, dv), slot),
                   block(c, dk), block(c, dv), block(c, dk), block(c, c),
-                  block(dk, c), block(1, dv)],
+                  block(dk, c), block(1, decay.shape[-1])],
         out_specs=(block(c, dv), pl.BlockSpec((1, 1, dk, dv), slot)),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
     )

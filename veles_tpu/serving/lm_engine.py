@@ -1030,32 +1030,37 @@ class LMEngine(Logger):
             self._page_tables = numpy.zeros(
                 (self.slots, self._max_pages), numpy.int32)
             self.metrics.set_gauge("kv_pages_total", num_pages)
+            #: the layers that hold pages (a linear layer holds a slot of
+            #: state), the module's own among them where it drafts
+            n_paged = sum(self.cfg.kind(i) != model_config.LINEAR
+                          for i in range(self._n_pools()))
             if self.cfg.latent is not None:
-                # the pool's real bytes a token over all layers, padding
-                # included (width x 2 x layers if nothing were padded)
+                # the pool's real bytes a token over the latent layers,
+                # padding included (width x 2 x layers if nothing were
+                # padded)
                 self.metrics.set_gauge(
                     "kv_bytes_per_token",
-                    head_dim * embed.dtype.itemsize * self._n_pools())
+                    head_dim * embed.dtype.itemsize * n_paged)
             if self.cfg.linear is not None:
                 # two kinds of cache in one manager (ISSUE 36): a slot of
                 # recurrent state and convolution tail a lane for every
                 # linear layer, of a fixed size whatever the lane holds,
-                # beside ONE page table for the full layers.  A lane's
+                # beside ONE page table for the full layers (k and v
+                # pools, or one pool of latent rows, ISSUE 42).  A lane's
                 # slot IS its lane: taken at admission, reset by the
                 # chunk that starts at 0, freed with the lane's pages
                 self._state_shapes = self.cfg.linear.state_shapes(
                     self.slots)
-                n_state = len(self.cfg.state_layers)
-                n_full = len(params["blocks"]) - n_state
                 state, tail = self._state_shapes
                 self.metrics.set_gauge("state_slots_total", self.slots)
                 self.metrics.set_gauge(
-                    "state_bytes_per_lane", n_state * (
+                    "state_bytes_per_lane", len(self.cfg.state_layers) * (
                         4 * int(numpy.prod(state[1:]))
                         + embed.dtype.itemsize * int(numpy.prod(tail[1:]))))
-                self.metrics.set_gauge(
-                    "kv_bytes_per_token",
-                    2 * kv_heads * head_dim * embed.dtype.itemsize * n_full)
+                if self.cfg.latent is None:
+                    self.metrics.set_gauge(
+                        "kv_bytes_per_token", 2 * kv_heads * head_dim
+                        * embed.dtype.itemsize * n_paged)
             if model_config.SLIDING in self.cfg.kinds:
                 # two kinds of cache (ISSUE 28): a page table, an
                 # allocator and pools of their own for the sliding
@@ -1229,12 +1234,13 @@ class LMEngine(Logger):
 
         if self._state_shapes is not None:
             # a linear layer's pair is (state, float32; tail), a full
-            # layer's (k pool, v pool)
+            # layer's (k pool, v pool), or the one pool of latent rows
             state, tail = self._state_shapes
+            pools = 1 if self.cfg.latent is not None else 2
             return [(zeros(state, jnp.float32), zeros(tail))
                     if self.cfg.kind(i) == model_config.LINEAR
-                    else (zeros(self._storage_shape),
-                          zeros(self._storage_shape))
+                    else tuple(zeros(self._storage_shape)
+                               for _ in range(pools))
                     for i in range(len(self.params["blocks"]))]
         shapes = [self._window_shape if self._wt is not None
                   and self.cfg.kind(i) == model_config.SLIDING
