@@ -204,9 +204,9 @@ def page_step_census():
         # the history below a chunk's frontier; the latent kind's chunk
         # kernel also walks the chunk's own page, written before it
         spy("_chunk_jit", page if cfg.latent is not None else 0)
-        # the latent kind's decode kernel walks a lane's own pages and is
-        # handed no other (ISSUE 41): the live ones are all it is given
-        spy("_step_jit", 1, walked=cfg.latent is not None)
+        # the decode kernels walk a lane's own live pages and are handed
+        # no other (ISSUES 41, 43): the live ones are all they are given
+        spy("_step_jit", 1, walked=True)
 
         @functools.lru_cache(maxsize=None)
         def live_pages(pos, span, width, window):
@@ -216,9 +216,16 @@ def page_step_census():
                 mask = mask & (numpy.arange(width * page) < pos)
             return int(mask.any(0).reshape(width, page).any(1).sum())
 
-        def count():
-            given = live = 0
+        def count(per=None):
+            """``(given, live)`` over the dispatches made; with ``per``,
+            the pages a block of the flash-decode kernel's walk,
+            ``(given, live, blocks)`` over the DECODE dispatches alone: a
+            lane's live pages in whole blocks, at least one, at most a
+            table's width to a block (ISSUE 43)."""
+            given = live = blocks = 0
             for span, walked, table, pos in calls:
+                if per and not walked:
+                    continue
                 tables, base = table if isinstance(table, tuple) \
                     else ({model_config.FULL: table}, 0)
                 for layer in range(len(engine.params["blocks"])):
@@ -226,11 +233,14 @@ def page_step_census():
                     width = tables[kind].shape[-1]
                     rel = pos - (base if kind == model_config.SLIDING
                                  else 0)
-                    seen = sum(live_pages(int(p), span, width,
-                                          cfg.layer_window(layer))
-                               for p in rel)
-                    live += seen
-                    given += seen if walked else len(rel) * width
-            return given, live
+                    seen = [live_pages(int(p), span, width,
+                                       cfg.layer_window(layer))
+                            for p in rel]
+                    live += sum(seen)
+                    given += sum(seen) if walked else len(rel) * width
+                    if per:
+                        blocks += sum(max(-(-n // min(per, width)), 1)
+                                      for n in seen)
+            return (given, live, blocks) if per else (given, live)
         return count
     return watch

@@ -187,6 +187,15 @@ def test_engine_serves_the_references_tokens(weights, features,
                 == (steps[0] or 0)
             assert int(turns[:, tracing.COL_ATTN_LIVE].sum()) \
                 == (steps[1] or 0)
+            if eng._kernel_active:
+                # ISSUE 43: a decode step is handed its live pages alone,
+                # and these pools' pages are so small that a lane's walk
+                # is ONE block a layer, whatever it holds
+                given, live, blocks = count(per=1 << 20)
+                assert given == live
+                assert c["attn_walk_blocks"] == blocks \
+                    == c["decode_dispatches"] * eng.slots \
+                    * len(eng.params["blocks"])
     finally:
         eng.stop()
 

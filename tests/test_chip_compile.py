@@ -96,38 +96,51 @@ def test_dropout_compiles(one_chip):
 
 
 def paged_shapes(heads, kv, page, dtype, chunk, slots=8, dh=128,
-                 max_len=2048):
+                 max_len=2048, window=None):
+    """The serving kernels' operands, the pool packed as an engine with
+    the kernels active packs it (``pool_pack``: two heads of 64 to a
+    row)."""
     m = max_len // page
-    pool = ((slots * m + 1, kv, page, dh), dtype)
+    r = PK.pool_pack(kv, dh)
+    pool = ((slots * m + 1, kv // r, page, r * dh), dtype)
     return dict(q=((slots, heads, chunk, dh), dtype),
                 new=((slots, kv, chunk, dh), dtype), pool=pool,
-                ptab=((slots, m), I32), pos=((slots,), I32))
+                ptab=((slots, m), I32), pos=((slots,), I32), window=window)
 
 
+# (heads, kv heads, page, dtype[, what else ``paged_shapes`` takes]); the
+# last three are the benchmark cells' own shapes (ISSUE 43): OPT-1.3B's 32
+# heads of 64 packed by two over 8 lanes and a table of 40,
+# ``trinity-large-ep8``'s 48 on 8 of 128 behind its window over 32 lanes,
+# ``qwen3-next-80b-a3b-ep4``'s 16 on 2 of 256 over 64 lanes and 17 pages
 PAGED = [(16, 16, 16, F32), (16, 16, 32, F32), (16, 16, 128, F32),
-         (16, 16, 16, BF16), (16, 16, 32, BF16), (32, 4, 16, F32)]
+         (16, 16, 16, BF16), (16, 16, 32, BF16), (32, 4, 16, F32),
+         (32, 32, 32, F32, dict(dh=64, max_len=1280)),
+         (48, 8, 256, BF16, dict(slots=32, max_len=8192, window=4096)),
+         (16, 2, 1024, BF16, dict(slots=64, dh=256, max_len=17408))]
 PAGED_IDS = ["mha_p16_f32", "mha_p32_f32", "mha_p128_f32", "mha_p16_bf16",
-             "mha_p32_bf16", "gqa32x4_p16_f32"]
+             "mha_p32_bf16", "gqa32x4_p16_f32", "chat", "longmix",
+             "longchat"]
 
 
-@pytest.mark.parametrize("heads,kv,page,dtype", PAGED, ids=PAGED_IDS)
-def test_paged_flash_decode_compiles(one_chip, heads, kv, page, dtype):
-    s = paged_shapes(heads, kv, page, dtype, chunk=1)
+@pytest.mark.parametrize("case", PAGED, ids=PAGED_IDS)
+def test_paged_flash_decode_compiles(one_chip, case):
+    s = paged_shapes(*case[:4], chunk=1, **dict(*case[4:]))
     text = compile_for(
         one_chip,
         lambda q, k, v, pt, ps: PK.paged_flash_decode(
-            q, k, v, pt, ps, interpret=False),
+            q, k, v, pt, ps, window=s["window"], interpret=False),
         s["q"], s["pool"], s["pool"], s["ptab"], s["pos"])
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("heads,kv,page,dtype", PAGED, ids=PAGED_IDS)
-def test_paged_flash_prefill_compiles(one_chip, heads, kv, page, dtype):
-    s = paged_shapes(heads, kv, page, dtype, chunk=page)
+@pytest.mark.parametrize("case", PAGED, ids=PAGED_IDS)
+def test_paged_flash_prefill_compiles(one_chip, case):
+    s = paged_shapes(*case[:4], chunk=case[2], **dict(*case[4:], slots=8))
     text = compile_for(
         one_chip,
         lambda q, kn, vn, k, v, pt, ps: PK.paged_flash_prefill(
-            q, kn, vn, k, v, pt, ps, interpret=False),
+            q, kn, vn, k, v, pt, ps, window=s["window"], interpret=False),
         s["q"], s["new"], s["new"], s["pool"], s["pool"], s["ptab"],
         s["pos"])
     assert "tpu_custom_call" in text

@@ -94,16 +94,22 @@ class TestAttnKernelRouting:
     @pytest.mark.parametrize("band", [{}, {"window": 20, "sinks": 2}],
                              ids=["full", "window_sinks"])
     def test_page_steps_counted_as_dispatched(self, page_step_census,
-                                              band):
+                                              band, monkeypatch):
         """ISSUE 29: the engine counts, per dispatch through the kernels,
-        the page steps it handed them (lanes x table width x layers) and
-        the live ones, with the kernels' own ``live_pages``: they equal
-        a brute-force count over the dispatches made, the recorder's two
-        columns sum to the counters, and the tokens are ``generate``'s."""
+        the page steps it handed them and the live ones, with the kernels'
+        own ``live_pages``: a chunk's are lanes x table width x layers, a
+        decode step's the live ones alone (ISSUE 43: the flash-decode
+        kernel walks them itself), in ``attn_walk_blocks`` blocks (here of
+        two pages, by the kernel's own rule for a page of 2 KB against 4
+        KB a block).  They equal a brute-force count over the dispatches
+        made, the recorder's two columns sum to the counters, and the
+        tokens are ``generate``'s."""
         import jax.numpy as jnp
+        from veles_tpu.ops import pallas_kernels as PK
         from veles_tpu.ops.transformer import generate
         from veles_tpu.serving import LMEngine, tracing
         params = _params()
+        monkeypatch.setattr(PK, "_FLASH_BLOCK_BYTES", 4096)
         engine = LMEngine(params, n_heads=2, max_len=96, slots=3,
                           paged_kv=True, prefill_chunk=8,
                           attn_kernel="force", name="ak_steps", **band)
@@ -125,7 +131,12 @@ class TestAttnKernelRouting:
             given, live = count()
             assert (c["attn_page_steps"], c["attn_page_steps_live"]) \
                 == (given, live)
-            assert 0 < live < given / 2        # most of a table is dead
+            assert 0 < live < given     # what is dead is the chunks'
+            walked = count(per=2)
+            assert walked[0] == walked[1]     # a decode step's are live
+            assert PK.flash_block_pages(engine._storage_shape, 4, 12) == 2
+            assert c["attn_walk_blocks"] == walked[2]
+            assert walked[1] / 2 <= walked[2] < walked[1]
             turns = engine.recorder.turns()
             assert int(turns[:, tracing.COL_ATTN_STEPS].sum()) == given
             assert int(turns[:, tracing.COL_ATTN_LIVE].sum()) == live

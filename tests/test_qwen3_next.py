@@ -397,6 +397,14 @@ def test_engine_serves_the_references_tokens(weights, features):
             assert 0 < pages[1] < pages[0]
             assert int(turns[:, tracing.COL_ATTN_STEPS].sum()) == pages[0]
             assert int(turns[:, tracing.COL_ATTN_LIVE].sum()) == pages[1]
+            # ISSUE 43: a decode step of the full layers is handed their
+            # live pages alone, one block a lane a layer at these sizes
+            alone = (turns[:, tracing.COL_STEP_PROGRAM] != 0) \
+                & (turns[:, tracing.COL_PREFILL_PROGRAM] == 0)
+            assert alone.any() and (turns[alone, tracing.COL_ATTN_STEPS]
+                                    == turns[alone, tracing.COL_ATTN_LIVE]
+                                    ).all()
+            assert c["attn_walk_blocks"] == steps * eng.slots   # one layer
     finally:
         eng.stop()
 

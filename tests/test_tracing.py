@@ -1098,6 +1098,15 @@ class TestLoopRecorder:
         quiet = (turns[:, tracing.COL_STEP_PROGRAM] == 0) \
             & (turns[:, tracing.COL_PREFILL_PROGRAM] == 0)
         assert not turns[quiet, tracing.COL_ATTN_STEPS].any()
+        # ISSUE 43: a decode or verify dispatch is handed the live pages
+        # alone (what is dead is the chunks'), in blocks that are counted
+        steps = (turns[:, tracing.COL_STEP_PROGRAM] != 0) \
+            & (turns[:, tracing.COL_PREFILL_PROGRAM] == 0)
+        assert steps.any()
+        numpy.testing.assert_array_equal(
+            turns[steps, tracing.COL_ATTN_STEPS],
+            turns[steps, tracing.COL_ATTN_LIVE])
+        assert 0 < c["attn_walk_blocks"] <= live
         first, last = turns[0, tracing.COL_STAMPS], turns[-1, tracing.COL_END]
         art = {"t_open": (first - 1) / 1e9, "window_s": (last - first + 2) / 1e9,
                "_spans_recorder": {"recorder": engine.recorder,

@@ -8,7 +8,7 @@ script holds the whole-program ones, which take up to minutes and are run
 by hand::
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \\
-        python tools/aot_compile.py [alexnet] [lm] [latent] [linear] [kda] [mtp] [mesh] [tp]
+        python tools/aot_compile.py [alexnet] [lm] [flash] [latent] [linear] [kda] [mtp] [mesh] [tp]
 
 - ``alexnet``: the graph loop's train step and the epoch-scan window
   program at minibatch 128, 227x227 crops, 1000 classes, fp32 and bf16;
@@ -31,6 +31,9 @@ by hand::
   program with expert layers also how their grouped matmuls are carried
   out (``compiled_grouped_matmuls``: the row-tiled kernel from
   ``ops/moe.py::ROW_KERNEL_MIN`` assignment rows on, else ``ragged-dot``);
+- ``flash``: the flash-decode kernel alone at the three benchmark cells'
+  own shapes (``tools/flash_decode_sweep.py::CELLS``: the pages a block its
+  own rule gives each, one query row a lane and two);
 - ``mesh``: the ``ShardedTrainer`` AlexNet step on a data 2 x model 2 mesh
   (checks for an all-reduce);
 - ``tp``: the ``LMEngine(tp=4)`` decode program over four chips.
@@ -298,6 +301,25 @@ def lm(one_chip):
     latent(one_chip)
 
 
+def flash(one_chip):
+    """The flash-decode kernel at the cells' shapes (ISSUE 43): a decode
+    step's one query row a lane and a verify step's two."""
+    from tools.flash_decode_sweep import CELLS, shapes
+    from veles_tpu.ops import pallas_kernels as PK
+    for name, cell in CELLS.items():
+        for c in (1, 2):
+            args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                    for shape, dtype in shapes(cell, c=c)]
+            pool = args[1]
+            compile_(
+                "flash decode %s, %d row(s) a lane, %d pages a block"
+                % (name, c, PK.flash_block_pages(
+                    pool.shape, pool.dtype.itemsize, cell["width"])),
+                jax.jit(lambda q, k, tab, pos, w=cell["window"]:
+                        PK.paged_flash_decode(q, k, k, tab, pos, window=w,
+                                              interpret=False)), *args)
+
+
 def latent(one_chip):
     """ISSUE 34: the latent kind at the benchmark cell's configuration and
     geometry: the chunk program (expanded attention) and the decode program
@@ -425,6 +447,8 @@ def main(argv):
         lm(one_chip)
     elif "latent" in want:
         latent(one_chip)
+    if "flash" in want:
+        flash(one_chip)
     if "linear" in want:
         linear(one_chip)
     if "kda" in want:

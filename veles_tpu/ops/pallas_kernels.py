@@ -22,11 +22,12 @@ query reads a fraction of it.  Two kernels walk the page table INSIDE
 the kernel instead, so no densified view ever exists:
 
 - :func:`paged_flash_decode` — flash-decode over the paged KV pool: the
-  grid is (lane, page), each step streams ONE pool page through VMEM
-  into an online-softmax accumulator (the ``attention._online_update``
-  recurrence), with the ``chunk_live_mask`` causal/window/sink band
-  applied in-kernel.  Serves the single-token decode step AND the
-  (k+1)-token speculative verify (queries are (c,) per lane).
+  grid is the lanes, and a loop of the lane's own length copies its live
+  pages, several to a block, through VMEM into an online-softmax
+  accumulator (the ``attention._online_update`` recurrence), with the
+  ``chunk_live_mask`` causal/window/sink band applied in-kernel.  Serves
+  the single-token decode step AND the (k+1)-token speculative verify
+  (queries are (c,) per lane).
 - :func:`paged_flash_prefill` — fused chunked prefill: the chunk's new
   K/V enter as VMEM operands (never read back from HBM), history pages
   stream like decode, and the kernel's EPILOGUE installs the chunk's
@@ -460,11 +461,17 @@ def live_pages(pos, c, page, m_pages, window=None, sinks=0, xp=jnp):
     return first, last, -(-sinks // page)
 
 
-def live_page_count(first, last, sink):
+def sink_pages_apart(first, last, sink, xp=numpy):
+    """How many of a lane's live pages are sink pages that lie before
+    ``first`` (``live_pages``), per lane."""
+    return xp.minimum(xp.minimum(sink, first), last + 1)
+
+
+def live_page_count(first, last, sink, xp=numpy):
     """How many pages ``live_pages`` calls live, per lane (numpy: the
     host's side of the rule ``_is_live`` applies in the kernels)."""
-    return (numpy.maximum(last - first + 1, 0)
-            + numpy.minimum(numpy.minimum(sink, first), last + 1))
+    return (xp.maximum(last - first + 1, 0)
+            + sink_pages_apart(first, last, sink, xp))
 
 
 def _is_live(j, first, last, sink):
@@ -484,12 +491,57 @@ def _live_entry(j, first, last, sink):
     return jnp.maximum(jnp.minimum(jnp.maximum(j, first), last), 0)
 
 
+#: bytes of keys and values one step of the flash-decode kernel's walk
+#: copies and multiplies: as many whole pages as fit (``flash_block_pages``).
+#: Chosen on the chip at the three cells' shapes (``tools/
+#: flash_decode_sweep.py``; PERF.md section 6, PR 43; us a call, the gridded
+#: parent, then the walk at 1 | 2 | 4 | 8 | 16 pages a block, then its
+#: copies alone): ``opt-1.3b.chat`` (8 lanes x 16 packed heads x 2 rows in
+#: float32, pages of 0.5 MB, 92 live) 125, 78 | 67 | 69 | 71 | 84, 65;
+#: ``trinity-large-ep8``'s short table (32 lanes, pages of 1 MB, 289 live)
+#: 470, 402 | 405 | 407 | 412, 402 and its full one (332 live) 581, 462 |
+#: 464 | 467 | 473, 461; ``qwen3-next-80b-a3b-ep4`` (64 lanes, pages of 2
+#: MB, 315 live) 1079, 876 | 879 | 882, 875.  The bfloat16 shapes run at
+#: what their copies take at any block (755 GB/s), and a larger block only
+#: adds the call's first copy and the slots' zeroing, which nothing hides.
+#: On the float32 shape the matmuls cost nearly what the copies do (two
+#: query rows a packed head at HIGHEST: 0.5-0.7 us a 32-token page, its
+#: copy 0.64), a step costs 0.25 us whatever it holds and a lane's short
+#: last block multiplies its stale rows too: two to four pages run at the
+#: copies' time + 2-4 us (1 MB would do as well there; 4 MB read 64 where
+#: 2 MB read 57 on shallower lanes)
+_FLASH_BLOCK_BYTES = 2 << 20
+#: blocks whose copies are in flight before the block that is multiplied
+#: (with one, ``opt-1.3b.chat``'s call took 72.8 us where two took 68.6)
+_FLASH_AHEAD = 2
+_FLASH_VMEM = 48 << 20
+
+
+def flash_block_pages(pool_shape, itemsize, m_pages):
+    """The pages a step of :func:`paged_flash_decode`'s walk takes: what
+    ``_FLASH_BLOCK_BYTES`` holds of one page's keys and values (a pool
+    ``(n_pages, kv/r, page, r·dh)`` of ``itemsize``-byte numbers), at least
+    one, at most the table's ``m_pages``.  The kernel and the host's count
+    of its blocks (``flash_walk_blocks``) read the same rule."""
+    page_bytes = 2 * math.prod(pool_shape[1:]) * itemsize
+    return max(1, min(_FLASH_BLOCK_BYTES // page_bytes, m_pages))
+
+
+def flash_walk_blocks(live, block_pages):
+    """The blocks :func:`paged_flash_decode` walks for lanes of ``live``
+    live pages each (``live_page_count``): whole blocks of ``block_pages``,
+    the last may be short, and a lane with no live page still takes one
+    (integer arrays, ``jax.numpy`` in the kernel's wrapper and ``numpy`` on
+    the host)."""
+    return ((live + block_pages - 1) // block_pages).clip(1)
+
+
 def paged_flash_decode(q, k_pool, v_pool, ptab, pos, window=None,
                        sinks=0, interpret=None):
     """Flash-decode over the paged KV pool: ``c`` query positions per
     lane (already projected, rotated and GQA-shaped — (b, h, c, dh))
-    attend their lane's linear cache view THROUGH the page table, one
-    pool page per grid step, masked by the ``chunk_live_mask`` band.
+    attend their lane's linear cache view THROUGH the page table, masked
+    by the ``chunk_live_mask`` band.
 
     The pool must already hold the lane's rows for positions
     [0, pos+c) — the caller ``paged_write``s the c new rows first (c
@@ -502,18 +554,22 @@ def paged_flash_decode(q, k_pool, v_pool, ptab, pos, window=None,
     XLA ``mha_paged_chunk_step`` path to fp32 roundoff (the greedy
     argmax downstream is what the serving parity matrix pins).
 
-    The grid is (lanes, table width) whatever the lanes hold; a step
-    whose page no query row of its lane can see (beyond the frontier,
-    behind the window: ``live_pages``) runs no softmax step and names the
-    block of the nearest live step, so it costs no copy either — what a
-    dead table entry holds never reaches the kernel.  The result is the
-    same bits: a fully masked block contributed an exact 0.0
-    (``_flash_step``).
+    The grid is the lanes; the kernel WALKS a lane's live pages itself
+    (ISSUE 43, as :func:`paged_latent_decode` walks its rows): the pools
+    stay where they lie, and a loop of the lane's own length goes over its
+    live table entries in order (``live_pages``: the sink pages before
+    ``first``, then ``first .. last``), ``flash_block_pages`` of them a
+    block: one softmax step a block, one copy a page and pool into one of
+    three slots of fast memory, the copies two blocks ahead of the block
+    that is multiplied and across the lanes' edges (a lane's last steps
+    start the next lanes' first blocks).  A table entry no query row of
+    its lane can see (beyond the frontier, behind the window) costs
+    nothing: no grid step, no scalar read, no copy; what it holds never
+    reaches the kernel.  A lane's last block may be short: the rest of its
+    slot holds an earlier block's rows (the slots are zeroed once a call,
+    for a masked NaN would still poison the sums) and is masked.
 
     Returns (b, h, c, dh)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     b, h, c, dh = q.shape
     kvp, page, lanes = k_pool.shape[1:]
     r = lanes // dh
@@ -521,67 +577,162 @@ def paged_flash_decode(q, k_pool, v_pool, ptab, pos, window=None,
     g = h // (kvp * r)
     rows = r * g * c            # query rows per pool row
     qp = _pack_queries(q.reshape(b, kvp * r, g * c, dh), r)
+    per = flash_block_pages(k_pool.shape, k_pool.dtype.itemsize, m_pages)
 
-    # the lanes' live ranges, computed once a call in the program around
-    # the kernel and prefetched: the two index maps and the body only
-    # compare against them (each is traced anew for every layer of every
-    # program, and that time is set-up time)
-    first, last, sink = live_pages(jnp.asarray(pos, jnp.int32), c, page,
-                                   m_pages, window, sinks)
+    # the lanes' live pages, computed once a call in the program around
+    # the kernel and prefetched: how many, how many of them are sink pages
+    # that lie apart from the rest and how far, the blocks they make, and
+    # where a lane's first block stands in the call's walk
+    pos = jnp.asarray(pos, jnp.int32)
+    first, last, sink = live_pages(pos, c, page, m_pages, window, sinks)
+    apart = sink_pages_apart(first, last, sink, jnp)
+    count = live_page_count(first, last, sink, jnp)
+    walk = flash_walk_blocks(count, per)
+    begin = jnp.cumsum(walk) - walk
+    call = _flash_walk_call(
+        b, kvp, rows, lanes, page, per, c, dh, window, sinks, _FLASH_AHEAD,
+        q.dtype, k_pool.dtype, _interpret(interpret), _flash_step,
+        F._PRECISION)
+    o = call(jnp.asarray(ptab, jnp.int32), pos, count, walk, begin, apart,
+             first - apart, qp, k_pool, v_pool)
+    return _unpack_outputs(o, r).reshape(b, h, c, dh)
 
-    def kernel(ptab_ref, pos_ref, first_ref, last_ref, q_ref, k_ref, v_ref,
-               o_ref, acc_ref, l_ref, m_ref):
-        i, j = pl.program_id(0), pl.program_id(1)
 
-        @pl.when(j == 0)
-        def _():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+@functools.lru_cache(maxsize=64)
+def _flash_walk_call(b, kvp, rows, lanes, page, per, c, dh, window, sinks,
+                     ahead, q_dtype, pool_dtype, interpret, flash_step,
+                     precision):
+    """:func:`paged_flash_decode`'s Pallas call for ``b`` lanes of ``rows``
+    query rows a pool row (``c`` positions a lane) over pools ``(n_pages,
+    kvp, page, lanes)``, ``per`` pages a block, the copies ``ahead`` blocks
+    ahead: a function of the seven prefetched scalars, the packed queries
+    and the two pools.  KEPT for every set of sizes: a ``pallas_call`` is a
+    jitted function that inlines, so the layers of a program trace and
+    lower the kernel ONCE a table width and not once each (that time is
+    set-up time, and the walk's body traces slower than a grid step's
+    did), and each call still takes its own place's name in the device
+    trace.  ``flash_step`` and ``precision`` are what the trace reads
+    beside the sizes (``_flash_step`` and the matmul policy it looks
+    up)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    block = per * page
+    slots = ahead + 1
 
-        pos = pos_ref[i]
+    def kernel(ptab_ref, pos_ref, count_ref, walk_ref, begin_ref, apart_ref,
+               skip_ref, q_ref, k_ref, v_ref, o_ref, k_buf, v_buf, sem_ref,
+               acc_ref, l_ref, m_ref):
+        i = pl.program_id(0)
 
-        @pl.when(_is_live(j, first_ref[i], last_ref[i], sink))
-        def _():
-            k_pos = j * page + jax.lax.broadcasted_iota(
-                jnp.int32, (rows, page), 1)
-            q_pos = pos + jax.lax.broadcasted_iota(
-                jnp.int32, (rows, page), 0) % c
-            live = _band(k_pos, q_pos, window, sinks, k_pos <= q_pos)
-            _flash_step(q_ref[0], k_ref[0], v_ref[0], live, dh,
-                        acc_ref, l_ref, m_ref)
+        # (``jax.lax`` on the scalars, not ``jax.numpy``'s operators: every
+        # one of those is a jitted function traced and lowered anew for
+        # every layer of every program, and that time is set-up time)
+        add, mul, lt, rem, select = (jax.lax.add, jax.lax.mul, jax.lax.lt,
+                                     jax.lax.rem, jax.lax.select)
 
-        @pl.when(j == m_pages - 1)
-        def _():
-            o_ref[0] = (acc_ref[...]
-                        / l_ref[...][..., None]).astype(o_ref.dtype)
+        def copies(lane, t, slot, do):
+            """``do`` with the copy of each page of block ``t`` of
+            ``lane``'s walk into ``slot``, keys then values."""
+            def one(n, carry):
+                entry = n
+                if window:
+                    entry = select(lt(n, apart_ref[lane]), n,
+                                   add(n, skip_ref[lane]))
+                at = pl.ds(pl.multiple_of(
+                    mul(jax.lax.sub(n, mul(t, per)), page), page), page)
+                for e, (pool_ref, buf_ref) in enumerate(
+                        ((k_ref, k_buf), (v_ref, v_buf))):
+                    do(pltpu.make_async_copy(
+                        pool_ref.at[ptab_ref[lane, entry]],
+                        buf_ref.at[slot, :, at], sem_ref.at[e, slot]))
+                return carry
 
-    def lane(i, j, *_):
+            jax.lax.fori_loop(
+                mul(t, per),
+                jax.lax.min(mul(add(t, 1), per), count_ref[lane]), one, 0)
+
+        def after(lane, t):
+            """The block behind block ``t`` of ``lane`` in the call's walk:
+            the lane's next, or the next lane's first (lane ``b``: none)."""
+            more = lt(add(t, 1), walk_ref[jax.lax.min(lane, b - 1)])
+            return (select(more, lane, add(lane, 1)),
+                    select(more, add(t, 1), mul(t, 0)))
+
+        if per > 1:
+            @pl.when(i == 0)
+            def _():
+                k_buf[...] = jnp.zeros_like(k_buf)
+                v_buf[...] = jnp.zeros_like(v_buf)
+
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+
+        def step(t, carry):
+            # the call's blocks take the slots in turn: block g of the
+            # whole walk lies in slot g % slots, and its copies start
+            # ``ahead`` steps before it is multiplied (into the slot the
+            # step before this one read)
+            g = add(begin_ref[i], t)
+            lane, far = i, t
+            for _ in range(ahead):
+                lane, far = after(lane, far)
+
+            @pl.when(lt(lane, b))
+            def _():
+                copies(lane, far, rem(add(g, slots + ahead), slots),
+                       lambda copy: copy.start())
+
+            # (the first lane's walk begins ``ahead`` steps early: those
+            # only start the call's first blocks)
+            @pl.when(jax.lax.ge(t, 0))
+            def _():
+                slot = rem(g, slots)
+                copies(i, t, slot, lambda copy: copy.wait())
+                # where each row of the block stands among the rows of
+                # the lane's live pages, and from that in its positions
+                at = add(jax.lax.broadcasted_iota(
+                    jnp.int32, (rows, block), 1), mul(t, block))
+                k_pos = at
+                if window:
+                    k_pos = select(lt(at, mul(apart_ref[i], page)), at,
+                                   add(at, mul(skip_ref[i], page)))
+                q_pos = add(rem(jax.lax.broadcasted_iota(
+                    jnp.int32, (rows, block), 0), c), pos_ref[i])
+                live = _band(k_pos, q_pos, window, sinks, jax.lax.bitwise_and(
+                    jax.lax.le(k_pos, q_pos),
+                    lt(at, mul(count_ref[i], page))))
+                flash_step(q_ref[0], k_buf[slot], v_buf[slot], live, dh,
+                           acc_ref, l_ref, m_ref)
+            return carry
+
+        jax.lax.fori_loop(select(i == 0, -ahead, 0), walk_ref[i], step, 0)
+        o_ref[0] = (acc_ref[...] / l_ref[...][..., None]).astype(o_ref.dtype)
+
+    def lane(i, *_):
         return (i, 0, 0, 0)
 
-    def history(i, j, pt, ps, fs, ls):
-        return (pt[i, _live_entry(j, fs[i], ls[i], sink)], 0, 0, 0)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(b, m_pages),
-        in_specs=[
-            pl.BlockSpec((1, kvp, rows, lanes), lane),
-            pl.BlockSpec((1, kvp, page, lanes), history),
-            pl.BlockSpec((1, kvp, page, lanes), history),
-        ],
+        num_scalar_prefetch=7,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, kvp, rows, lanes), lane),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, kvp, rows, lanes), lane),
-        scratch_shapes=[pltpu.VMEM((kvp, rows, lanes), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((slots, kvp, block, lanes), pool_dtype),
+                        pltpu.VMEM((slots, kvp, block, lanes), pool_dtype),
+                        pltpu.SemaphoreType.DMA((2, slots)),
+                        pltpu.VMEM((kvp, rows, lanes), jnp.float32),
                         pltpu.VMEM((kvp, rows), jnp.float32),
                         pltpu.VMEM((kvp, rows), jnp.float32)],
     )
-    o = pl.pallas_call(
+    return pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvp, rows, lanes), q.dtype),
-        interpret=_interpret(interpret),
-    )(jnp.asarray(ptab, jnp.int32), jnp.asarray(pos, jnp.int32),
-      first, last, qp, k_pool, v_pool)
-    return _unpack_outputs(o, r).reshape(b, h, c, dh)
+        out_shape=jax.ShapeDtypeStruct((b, kvp, rows, lanes), q_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_FLASH_VMEM),
+        interpret=interpret)
 
 
 #: float32 scores (kv heads x query rows x page) that one grid step of the

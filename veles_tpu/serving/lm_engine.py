@@ -87,8 +87,10 @@ Decode/verify additionally slice the page table to the LIVE width
 ladder (``_live_width``): a step pays for the pages the batch actually
 occupies, one program per power-of-two ladder entry.  Inside the
 kernels a page no query of its lane can see costs neither a fetch nor a
-softmax step (ISSUE 29); the host counts what each dispatch handed them
-and what of it was live (``attn_page_steps`` / ``attn_page_steps_live``,
+softmax step (ISSUE 29), and the decode kernels walk a lane's live pages
+alone (ISSUES 41, 43); the host counts what each dispatch handed them,
+what of it was live and the blocks the flash-decode kernel made of it
+(``attn_page_steps`` / ``attn_page_steps_live`` / ``attn_walk_blocks``,
 :meth:`LMEngine._note_attn_dispatch`).
 
 SHARDED SERVING (ISSUE 8, ``tp=N``) runs every program above under a
@@ -2896,29 +2898,34 @@ class LMEngine(Logger):
         self.recorder.moe(held, hit)
 
     def _attn_page_steps(self, pos, width, span=0, rows=slice(None)):
-        """``(given, live)``: the page steps a dispatch hands the
-        attention kernels and the live ones among them (ISSUE 29), or None
+        """``(given, live, blocks)``: the page steps a dispatch hands the
+        attention kernels, the live ones among them (ISSUE 29) and the
+        blocks the flash-decode kernel walks for them (ISSUE 43), or None
         where the kernels are not active: ``pos`` the positions of the
         lanes ``rows`` as the program gets them, ``width`` its table's,
         ``span`` the query rows a lane (0: a prefill chunk, whose kernel
         walks the history below ``pos``; the latent kind's also walks the
         chunk's own page, written before it: its query rows count like a
-        decode's).  A decode or verify step of the latent kind is handed
-        only what it walks (``paged_latent_decode`` loops over a lane's own
-        pages, whatever the table's width, ISSUE 41): ``given`` is ``live``
-        there.  Read where the dispatch's tables are made (the sliding
-        kind's short table begins at its base THEN).  Host integers over at
-        most ``slots`` lanes, by the kernels' own ``live_pages``."""
+        decode's).  A decode or verify step is handed only what it walks
+        (``paged_latent_decode`` and ``paged_flash_decode`` loop over a
+        lane's own live pages, whatever the table's width, ISSUES 41, 43):
+        ``given`` is ``live`` there, and what the two leave apart is the
+        gridded prefill kernels' chunk dispatches.  ``blocks`` counts the
+        flash-decode kernel's alone, by its own rule for the pages a block
+        (``flash_block_pages`` of this pool at this width): live pages
+        over blocks x that many is how full its blocks run.  Read where the
+        dispatch's tables are made (the sliding kind's short table begins
+        at its base THEN).  Host integers over at most ``slots`` lanes, by
+        the kernels' own ``live_pages``."""
         if not self._kernel_active:
             return None
-        from veles_tpu.ops.pallas_kernels import (live_page_count,
-                                                  live_pages)
+        from veles_tpu.ops import pallas_kernels as PK
         pos = numpy.atleast_1d(pos).astype(numpy.int64)
         latent = self.cfg.latent is not None
-        walked = latent and span > 0
+        walked = span > 0
         if latent and not span:
             span = self.prefill_chunk
-        given = live = 0
+        given = live = blocks = 0
         for kind, layers in self._layers_of_kind:
             p, w = pos, width
             if self._wt is not None and kind == model_config.SLIDING:
@@ -2927,12 +2934,18 @@ class LMEngine(Logger):
                 w = min(width, self._wt.width)
             window = (self.cfg.window if self._wt is None
                       or kind == model_config.SLIDING else None)
-            seen = layers * int(live_page_count(*live_pages(
+            seen = PK.live_page_count(*PK.live_pages(
                 p, span, self.prefill_chunk, w, window, self.sinks,
-                xp=numpy)).sum())
-            live += seen
-            given += seen if walked else layers * p.size * w
-        return given, live
+                xp=numpy))
+            total = int(seen.sum())
+            live += layers * total
+            given += layers * (total if walked else p.size * w)
+            if walked and not latent:
+                blocks += layers * int(PK.flash_walk_blocks(
+                    seen, PK.flash_block_pages(
+                        self._storage_shape, self._storage_dtype.itemsize,
+                        w)).sum())
+        return given, live, blocks
 
     def _note_attn_dispatch(self, steps=None, calls=1):
         """Per-dispatch kernel accounting (ISSUE 7): which path the
@@ -2943,7 +2956,8 @@ class LMEngine(Logger):
         A dispatch through the kernels also counts its page steps
         (``steps``, :meth:`_attn_page_steps`): the counters
         ``attn_page_steps`` / ``attn_page_steps_live`` and the recorder's
-        open turn; ``calls`` the steps of a fused program (counted at the
+        open turn, and ``attn_walk_blocks`` where the flash-decode kernel
+        walked; ``calls`` the steps of a fused program (counted at the
         positions it entered with)."""
         if not self.attn_kernel:
             return
@@ -2951,9 +2965,11 @@ class LMEngine(Logger):
                          else "attn_kernel_fallbacks")
         if steps is None:
             return
-        given, live = steps
+        given, live, blocks = steps
         self.metrics.inc("attn_page_steps", calls * given)
         self.metrics.inc("attn_page_steps_live", calls * live)
+        if blocks:
+            self.metrics.inc("attn_walk_blocks", calls * blocks)
         self.recorder.attn_pages(calls * given, calls * live)
 
     def kv_bytes_resident(self):
