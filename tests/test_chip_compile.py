@@ -813,6 +813,57 @@ def test_latent_decode_kernel_compiles_at_the_verify_cells_width(one_chip,
                               ((32, 9), I32)) < KERNEL_VMEM // 2
 
 
+def test_latent_prefill_kernel_compiles_inside_its_limit(one_chip):
+    """ISSUE 44: the expanded kernel at the one shape the three latent
+    cells give it (1 lane x 32 heads x a chunk of 1024 rows in bfloat16, a
+    table of 33 and of 9 pages) compiles for the chip under the limit it
+    asks for itself, which is under half the chip's fast memory: units of
+    the whole chunk, two heads' units a loop body (float32 scores of 1024
+    x 1024 twice, their exponentials and casts beside 14 MB of blocked
+    operands and accumulators)."""
+    assert PK._LATENT_VMEM <= 64 << 20
+    shapes = lambda m: (  # noqa: E731
+        ((1, 32, 1024, 128), BF16), ((1, 32, 1024, 64), BF16),
+        ((32, 512, 128), BF16), ((32, 512, 128), BF16),
+        ((16 * m + 1, 1, 1024, 640), BF16), ((1, m), I32), ((1,), I32))
+    run = lambda qn, qr, wk, wv, k, pt, ps: PK.paged_latent_prefill(  # noqa: E731
+        qn, qr, wk, wv, k, pt, ps, 0.1447, 512, interpret=False)
+    for m in (33, 9):
+        text = compile_for(one_chip, run, *shapes(m))
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+        assert "bf16[1,32,1024,128]" in text
+    jaxpr = jax.make_jaxpr(run)(*(jax.ShapeDtypeStruct(shape, dtype)
+                                  for shape, dtype in shapes(33)))
+    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    assert calls[0].params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes \
+        == PK._LATENT_VMEM
+
+
+@pytest.mark.parametrize("fixture,latent_layers", [
+    ("latent_engine", 2), ("mtp_engine", 2), ("kda_engine", 1)])
+def test_a_chunk_program_calls_the_prefill_kernel_once_a_latent_layer(
+        request, fixture, latent_layers):
+    """ISSUE 44: compiled for the chip, each latent configuration's chunk
+    program holds exactly one Pallas call under ``attn.latent`` a latent
+    layer whose result is ``[1, heads, chunk, v]``: the expanded prefill
+    kernel, by the name and shape ``benchmark/lib/latent.py::
+    is_prefill_kernel`` finds it by (``[1,32,1024,128]`` in the cells).
+    The kernel's call is kept by its sizes and shared by the layers; each
+    layer's call still stands in the program under its own place's name."""
+    import re
+    engine = request.getfixturevalue(fixture)[0]
+    lat = engine.cfg.latent
+    text = program_text(request.getfixturevalue(fixture), "chunk")
+    shape = "bf16[1,%d,%d,%d]" % (engine.cfg.n_heads, engine.prefill_chunk,
+                                  lat.v)
+    calls = re.findall(
+        r"= %s\S* custom-call\([^\n]*"
+        r'custom_call_target="tpu_custom_call"[^\n]*'
+        r'op_name="[^"]*attn\.latent/pallas_call' % re.escape(shape), text)
+    assert len(calls) == latent_layers
+
+
 @pytest.mark.parametrize("rows,k,n,groups", [
     (4096, 3584, 1024, 64), (4096, 1024, 3584, 64), (1024, 3072, 3072, 32),
     (1000, 3584, 1024, 64)],

@@ -1,6 +1,8 @@
 """Pallas kernels (interpret mode on CPU = same kernel code as TPU) and
 stochastic pooling (SURVEY §2.4 custom-kernel candidates)."""
 
+import functools
+
 import numpy
 import pytest
 
@@ -1114,6 +1116,295 @@ class TestPagedLatentDecode:
         numpy.testing.assert_allclose(
             numpy.asarray(got), numpy.asarray(self._xla(*args, pos)),
             rtol=1e-5, atol=1e-5)
+
+
+def _rolled_latent_prefill(q_nope, q_rope, wk, wv, pool, ptab, pos, scale,
+                           kv_rank, heads=4, q_rows=256):
+    """``paged_latent_prefill`` as it stood before ISSUE 44 (four heads a
+    grid step; on a history page a rolled loop over blocks of 256 query
+    rows, one block's scores, softmax and ``p . v`` after another's), kept
+    here as the yardstick: the new order sums what it summed."""
+    import math
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from veles_tpu.ops import functional as F
+
+    b, h, c, nope = q_nope.shape
+    rope = q_rope.shape[-1]
+    vdim = wv.shape[-1]
+    page, row = pool.shape[2:]
+    tail = row - kv_rank
+    m_pages = ptab.shape[1]
+    hb = math.gcd(h, heads)
+    qb = math.gcd(c, q_rows)
+    dtype = q_nope.dtype
+    precision = F._PRECISION if dtype == jnp.float32 else None
+    q = jnp.concatenate(
+        [q_nope, q_rope, jnp.zeros((b, h, c, tail - rope), dtype)], axis=-1)
+    _, last, _ = PK.live_pages(jnp.asarray(pos, jnp.int32), c, page, m_pages)
+
+    def dot_nt(x, y):
+        return jax.lax.dot_general(
+            x, y, (((1,), (1,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32)
+
+    def dot_nn(x, y):
+        return jax.lax.dot_general(
+            x, y, (((1,), (0,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32)
+
+    def update(e, rows, s, v, acc_ref, l_ref, m_ref):
+        m_prev = m_ref[e, rows, :]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[e, rows, :] = l_ref[e, rows, :] * alpha \
+            + p.sum(axis=-1, keepdims=True)
+        acc_ref[e, rows, :] = acc_ref[e, rows, :] * alpha \
+            + dot_nn(p.astype(v.dtype), v)
+        m_ref[e, rows, :] = m_new
+
+    def kernel(ptab_ref, pos_ref, last_ref, q_ref, wk_ref, wv_ref, pool_ref,
+               o_ref, acc_ref, l_ref, m_ref):
+        i, j = pl.program_id(0), pl.program_id(2)
+
+        @pl.when(j == 0)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            m_ref[...] = jnp.full_like(m_ref, PK.NEG_INF)
+
+        def expand(e):
+            lat = pool_ref[0, 0]
+            c_kv = lat[:, :kv_rank]
+            k_nope = dot_nn(c_kv, wk_ref[e]).astype(dtype)
+            v = dot_nn(c_kv, wv_ref[e]).astype(dtype)
+            return k_nope, lat[:, kv_rank:], v
+
+        def scores(e, rows, k_nope, k_tail):
+            qn = q_ref[0, e, rows, :nope]
+            qt = q_ref[0, e, rows, nope:]
+            return (dot_nt(qn, k_nope) + dot_nt(qt, k_tail)) * scale
+
+        @pl.when(j < last_ref[i])
+        def _():
+            def head(e, _):
+                k_nope, k_tail, v = expand(e)
+
+                def block(t, _):
+                    rows = pl.ds(pl.multiple_of(t * qb, qb), qb)
+                    update(e, rows, scores(e, rows, k_nope, k_tail), v,
+                           acc_ref, l_ref, m_ref)
+                    return 0
+                return jax.lax.fori_loop(0, c // qb, block, 0)
+            jax.lax.fori_loop(0, hb, head, 0)
+
+        @pl.when(j == last_ref[i])
+        def _():
+            def head(e, _):
+                k_nope, k_tail, v = expand(e)
+                for lo in range(0, c, qb):
+                    keys = lo + qb
+                    rows = pl.ds(lo, qb)
+                    s = scores(e, rows, k_nope[:keys], k_tail[:keys])
+                    k_pos = jax.lax.broadcasted_iota(
+                        jnp.int32, (qb, keys), 1)
+                    q_pos = lo + jax.lax.broadcasted_iota(
+                        jnp.int32, (qb, keys), 0)
+                    s = jnp.where(k_pos <= q_pos, s, PK.NEG_INF)
+                    update(e, rows, s, v[:keys], acc_ref, l_ref, m_ref)
+                return 0
+            jax.lax.fori_loop(0, hb, head, 0)
+
+        @pl.when(j == m_pages - 1)
+        def _():
+            o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+    def lane(i, g, j, *_):
+        return (i, g, 0, 0)
+
+    def weights(i, g, j, *_):
+        return (g, 0, 0)
+
+    def history(i, g, j, pt, ps, ls):
+        return (pt[i, jnp.maximum(jnp.minimum(j, ls[i]), 0)], 0, 0, 0)
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b, h // hb, m_pages),
+            in_specs=[pl.BlockSpec((1, hb, c, nope + tail), lane),
+                      pl.BlockSpec((hb, kv_rank, nope), weights),
+                      pl.BlockSpec((hb, kv_rank, vdim), weights),
+                      pl.BlockSpec((1, 1, page, row), history)],
+            out_specs=pl.BlockSpec((1, hb, c, vdim), lane),
+            scratch_shapes=[pltpu.VMEM((hb, c, vdim), jnp.float32),
+                            pltpu.VMEM((hb, c, 1), jnp.float32),
+                            pltpu.VMEM((hb, c, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, h, c, vdim), dtype),
+        interpret=True,
+    )(jnp.asarray(ptab, jnp.int32), jnp.asarray(pos, jnp.int32), last,
+      q, wk, wv, pool)
+
+
+@pytest.mark.kernel_parity
+class TestPagedLatentPrefill:
+    """ISSUE 44: the expanded latent-attention kernel of a chunk takes, on
+    a history page, units of ``_LATENT_Q_ROWS`` query rows against the whole
+    page, ``_LATENT_CHAINS`` of them a loop body with every unit's scores
+    before the first's softmax: against the expanded XLA form over the
+    gathered view (``attend_expanded``), against the rolled kernel it
+    replaces, and with everything it must not touch poisoned."""
+
+    PAGE, M = 8, 6
+
+    def _record(self, heads, dtype="float32"):
+        import types
+        from veles_tpu.model_config import LatentConfig
+        return types.SimpleNamespace(
+            latent=LatentConfig(q_rank=8, kv_rank=96, nope=16, rope=16, v=16),
+            yarn=None, dtype=dtype, n_heads=heads)
+
+    def _setup(self, starts, seed, heads=4, page=None, m=None,
+               dtype="float32"):
+        """Lanes whose chunk starts at table entry ``starts[i]``, the pages
+        behind a lane's chunk pointing at a page of NaN."""
+        cfg = self._record(heads, dtype)
+        lat, page, m = cfg.latent, page or self.PAGE, m or self.M
+        rng = numpy.random.RandomState(seed)
+        b = len(starts)
+        as_dtype = jnp.dtype(dtype)
+        p = {"wk_b": jnp.asarray(rng.randn(heads, lat.kv_rank, lat.nope)
+                                 * 0.2, as_dtype),
+             "wv_b": jnp.asarray(rng.randn(heads, lat.kv_rank, lat.v) * 0.2,
+                                 as_dtype)}
+        q_nope = jnp.asarray(rng.randn(b, heads, page, lat.nope), as_dtype)
+        q_rope = jnp.asarray(rng.randn(b, heads, page, lat.rope), as_dtype)
+        pool = rng.randn(b * m + 1, 1, page, lat.row).astype(numpy.float32)
+        pool[..., lat.width:] = 0.0
+        pool[-1] = numpy.nan
+        ptab = rng.permutation(b * m).reshape(b, m).astype(numpy.int32)
+        starts = numpy.asarray(starts)
+        dead = numpy.arange(m)[None, :] > starts[:, None]
+        ptab = numpy.where(dead, b * m, ptab).astype(numpy.int32)
+        pos = (starts * page).astype(numpy.int32)
+        return (cfg, p, q_nope, q_rope, jnp.asarray(pool, as_dtype),
+                jnp.asarray(ptab), jnp.asarray(pos))
+
+    def _kernel(self, cfg, p, q_nope, q_rope, pool, ptab, pos, fn=None):
+        from veles_tpu.ops import latent
+        return (fn or PK.paged_latent_prefill)(
+            q_nope, q_rope, p["wk_b"], p["wv_b"], pool, ptab, pos,
+            latent.softmax_scale(cfg), cfg.latent.kv_rank)
+
+    def _xla(self, cfg, p, q_nope, q_rope, pool, ptab, pos):
+        """``attend_expanded`` over the gathered view, the dead pages' NaN
+        taken out of the view first (the plain form multiplies what it
+        masks)."""
+        from veles_tpu.ops import attention as A, latent
+        c = q_nope.shape[2]
+        view = A.paged_view(pool, ptab)[:, 0]
+        live = jax.vmap(lambda at: A.chunk_live_mask(
+            at, c, view.shape[1]))(pos)[:, None]
+        view = jnp.where(live[:, 0].any(axis=1)[..., None], view, 0)
+        return latent.attend_expanded(p, q_nope, q_rope, view, live, cfg)
+
+    def _close(self, got, ref, dtype="float32"):
+        assert numpy.isfinite(numpy.asarray(got, numpy.float32)).all()
+        tol = (dict(rtol=1e-5, atol=2e-5) if dtype == "float32"
+               else dict(rtol=3e-2, atol=3e-2))
+        numpy.testing.assert_allclose(numpy.asarray(got, numpy.float32),
+                                      numpy.asarray(ref, numpy.float32),
+                                      **tol)
+
+    @pytest.mark.parametrize("start", [0, 3, 5], ids=["first", "middle",
+                                                      "last"])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_matches_the_expanded_xla_form(self, dtype, start):
+        """The chunk at the table's first, a middle and its last entry:
+        the expanded form's outputs, in float32 to rounding and in
+        bfloat16."""
+        args = self._setup([start], seed=start, dtype=dtype)
+        self._close(self._kernel(*args), self._xla(*args), dtype)
+
+    @pytest.mark.parametrize("page", [8, 24, 256, 1024])
+    def test_every_page_size_under_the_shipped_constants(self, page):
+        """Nothing patched: a page under the constants and no multiple of
+        them (8, 24: one unit a head, or three of 8 rows), a page that is
+        one block of its own diagonal (256) and the cells' page (1024: the
+        whole chunk a unit, the chunk's own page by four blocks, two a
+        body) give the expanded form's outputs and the rolled kernel's."""
+        args = self._setup([1], seed=page, heads=2, page=page, m=3)
+        got = self._kernel(*args)
+        self._close(got, self._xla(*args))
+        self._close(got, self._kernel(*args, fn=_rolled_latent_prefill))
+
+    @pytest.mark.parametrize("heads", [2, 4, 32])
+    def test_heads_a_grid_step(self, heads):
+        """Fewer heads than a grid step takes, just as many, and the
+        cells' 32 (eight steps a page): every head its own sums."""
+        args = self._setup([2], seed=heads, heads=heads)
+        got = self._kernel(*args)
+        self._close(got, self._xla(*args))
+        numpy.testing.assert_array_equal(
+            numpy.asarray(got),
+            numpy.asarray(self._kernel(*args, fn=_rolled_latent_prefill)))
+
+    @pytest.mark.parametrize("heads,rows,chains", [
+        (4, 8, 1), (4, 8, 2), (4, 16, 2), (4, 32, 2), (2, 32, 2), (4, 32, 4),
+        (3, 32, 2)])
+    def test_every_order_keeps_the_rolled_kernels_bits(self, monkeypatch,
+                                                       heads, rows, chains):
+        """Pages of 32 rows under orders that take every path of the body
+        (units of a quarter, a half and the whole of a head's rows; one,
+        two and four a loop body; chains of heads where a head is one unit,
+        also where the heads a step do not divide by them): each query
+        row's sums are those of the rolled kernel, bit for bit."""
+        monkeypatch.setattr(PK, "_LATENT_Q_ROWS", rows)
+        monkeypatch.setattr(PK, "_LATENT_CHAINS", chains)
+        monkeypatch.setattr(PK, "_LATENT_DIAGONAL_ROWS", 8)
+        args = self._setup([0, 2, 3], seed=rows + chains, heads=heads,
+                           page=32, m=4)
+        got = self._kernel(*args)
+        self._close(got, self._xla(*args))
+        numpy.testing.assert_array_equal(
+            numpy.asarray(got),
+            numpy.asarray(self._kernel(
+                *args, fn=functools.partial(_rolled_latent_prefill,
+                                            q_rows=8))))
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_a_table_wider_than_the_chunk_needs(self, dtype):
+        """Twelve entries where the chunk sees three, the other nine at a
+        page of NaN: finite, and what a table of three gives, bit for
+        bit."""
+        args = self._setup([2], seed=7, m=12, dtype=dtype)
+        wide = self._kernel(*args)
+        narrow = self._kernel(*args[:5], args[5][:, :3], args[6])
+        assert numpy.isfinite(numpy.asarray(wide, numpy.float32)).all()
+        numpy.testing.assert_array_equal(numpy.asarray(wide),
+                                         numpy.asarray(narrow))
+        self._close(wide, self._xla(*args), dtype)
+
+    def test_two_lanes_at_different_depths(self):
+        """One call, a lane's chunk at its table's first entry and
+        another's at its fifth: each lane what it gets alone."""
+        args = self._setup([0, 4], seed=11)
+        both = self._kernel(*args)
+        self._close(both, self._xla(*args))
+        for i in range(2):
+            alone = self._kernel(args[0], args[1], *(
+                a[i:i + 1] for a in args[2:4]), args[4], *(
+                    a[i:i + 1] for a in args[5:]))
+            numpy.testing.assert_array_equal(numpy.asarray(both[i:i + 1]),
+                                             numpy.asarray(alone))
+
+    def test_chunk_must_equal_page(self):
+        args = self._setup([1], seed=1)
+        with pytest.raises(ValueError, match="chunk"):
+            PK.paged_latent_prefill(
+                args[2][:, :, :4], args[3][:, :, :4], args[1]["wk_b"],
+                args[1]["wv_b"], *args[4:], 0.17, 96)
 
 
 class TestServingKernelSupport:

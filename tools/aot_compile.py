@@ -23,7 +23,10 @@ by hand::
   also on its own) the same two programs for the latent kind at the
   benchmark cell's own configuration and geometry
   (``benchmark/configs/xing4.0-29b-a4b.json``: 6 layers, 16 lanes, pages of
-  1024, a table of 33; one pool a layer).  For each engine
+  1024, a table of 33; one pool a layer), and before them the expanded
+  prefill kernel alone at the latent cells' one shape (``tools/
+  latent_prefill_sweep.py``; tables of 33 and 9): its compile seconds and
+  the least fast memory it compiles under (ISSUE 44).  For each engine
   program it prints the copies of a whole KV pool and the pool leaves
   updated in place (``compiled_storage_report``) and the copies with a
   weight matrix's shape (``compiled_param_copies``), and exits non-zero
@@ -320,11 +323,61 @@ def flash(one_chip):
                                               interpret=False)), *args)
 
 
+def latent_prefill_kernel(one_chip):
+    """ISSUE 44: the expanded prefill kernel alone at the shape the three
+    latent cells give it, under its own constants: seconds to lower and
+    compile, and the least limit of fast memory (whole MiB) it compiles
+    under, found by halving between nothing and the kernel's own limit."""
+    from tools import latent_prefill_sweep as sweep
+    from veles_tpu.ops import pallas_kernels as PK
+    bf16, own = jnp.bfloat16, PK._LATENT_VMEM
+
+    def compiles(width, limit):
+        shapes = (((1, sweep.HEADS, sweep.CHUNK, sweep.NOPE), bf16),
+                  ((1, sweep.HEADS, sweep.CHUNK, sweep.ROPE), bf16),
+                  ((sweep.HEADS, sweep.RANK, sweep.NOPE), bf16),
+                  ((sweep.HEADS, sweep.RANK, sweep.VDIM), bf16),
+                  ((width + 1, 1, sweep.CHUNK, sweep.ROW), bf16),
+                  ((1, width), jnp.int32), ((1,), jnp.int32))
+        try:
+            with sweep.patched(PK, "_LATENT_VMEM", limit):
+                jax.jit(lambda qn, qr, wk, wv, pool, tab, pos:
+                        PK.paged_latent_prefill(
+                            qn, qr, wk, wv, pool, tab, pos, sweep.SCALE,
+                            sweep.RANK, interpret=False)).lower(*(
+                                jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+                                for shape, dtype in shapes)).compile()
+            return True
+        except Exception as e:  # noqa: BLE001 — the compiler's refusal
+            if "vmem" not in str(e):
+                raise
+            return False
+
+    for width, _ in sweep.CELLS.values():
+        begin = time.time()
+        if not compiles(width, own):
+            raise SystemExit("the latent prefill kernel does not compile "
+                             "under its own limit of %d MiB" % (own >> 20))
+        seconds = time.time() - begin
+        low, high = 0, own >> 20        # MiB: refused, accepted
+        while high - low > 1:
+            mid = (low + high) // 2
+            low, high = (low, mid) if compiles(width, mid << 20) else (
+                mid, high)
+        print("latent prefill kernel, table %-3d      OK %6.1fs  (%d heads "
+              "a step, units of %d rows, %d a body)  fast memory %d of %d "
+              "MiB" % (width, seconds, PK._LATENT_HEADS, PK._LATENT_Q_ROWS,
+                       PK._LATENT_CHAINS, high, own >> 20), flush=True)
+
+
 def latent(one_chip):
     """ISSUE 34: the latent kind at the benchmark cell's configuration and
     geometry: the chunk program (expanded attention) and the decode program
-    (absorbed) at the narrowest and the widest table."""
+    (absorbed) at the narrowest and the widest table; before them the
+    prefill kernel alone (``latent_prefill_kernel``)."""
     import json
+    latent_prefill_kernel(one_chip)
     from benchmark.reference import xing4
     from veles_tpu import model_config
     with open(os.path.join(os.path.dirname(os.path.dirname(

@@ -947,8 +947,17 @@ def paged_flash_prefill(q, k_new, v_new, k_pool, v_pool, ptab, pos,
 # rebuilds a page's keys and values through ``W_kvb`` in fast memory, a
 # block of heads at a time).  The prefill kernel walks the page table on
 # its grid and skips dead pages as the kernels above do (``live_pages``);
-# the decode kernel's grid is the lanes alone and the walk is a loop inside
-# it, as long as the lane is deep, over blocks it copies itself (ISSUE 41:
+# within a grid step its work is ordered for the matrix unit (ISSUE 44): the
+# passes it makes, re-expansion and the row's zero half included, take 5.44
+# us a head and page of 1024 x 1024 at the chip's peak, the softmax's vector
+# work 1.4-2.6 us beside them, and in a rolled loop of one 256-row block
+# after another the call took 7.39: a key or value tile stood in the matrix
+# unit for 256 rows only, and no block's matmuls could run under another's
+# softmax.  Now a unit is the whole chunk against the whole page and a loop
+# body holds two heads' units, scores first: 5.85 us, where its matmuls
+# alone take 5.52.  The decode kernel's grid is the lanes alone and the
+# walk is a loop inside it, as long as the lane is deep, over blocks it
+# copies itself (ISSUE 41:
 # on the grid every table entry paid a grid step, 0.06 us a dead one and
 # 2.4 us a live one whose copy takes 1.8; the loop's copies follow one
 # another through the whole call and the call takes what they take).
@@ -1104,13 +1113,56 @@ def paged_latent_decode(q, pool, ptab, pos, scale, interpret=None):
 
 #: heads one grid step of the latent prefill kernel takes (their
 #: accumulators, queries and outputs stay in fast memory while the lane's
-#: pages stream past: a page is fetched heads / this many times), and the
-#: query rows one softmax update takes (float32 scores of rows x page)
+#: pages stream past: a page is fetched heads / this many times); the query
+#: rows one softmax update takes on a HISTORY page (a *unit*: float32 scores
+#: of rows x page; every expanded key and value tile then serves that many
+#: rows while it stays in the matrix unit); the units whose sums do not meet
+#: that one loop body holds, the score matmuls of all of them standing
+#: before the first's softmax (two halves of a head's rows, or two heads
+#: where a unit is the whole chunk); and the rows of a block of the chunk's
+#: OWN page, which takes only the keys up to its diagonal.  Chosen on the
+#: chip at the cells' one shape (1 lane x 32 heads x 1024 rows in bfloat16,
+#: pages of 1024 x 640, a table of 33; ``tools/latent_prefill_sweep.py``;
+#: PERF.md section 6, PR 44), us a head and history page (the slope between
+#: the chunk at page 4 and at page 31; the floor of the passes is 5.44):
+#:
+#:     rows a unit      256    512    1024
+#:     one a body       7.39   6.73   6.41      (256 | 1: the parent's order)
+#:     two a body       6.22   5.96   5.85      (256 rows, four a body: 5.88)
+#:
+#: matmuls alone 6.19 at 256 | 1 and 5.52 at 1024 | 2, softmax alone 2.56 and
+#: 1.43.  Both halves gain from the larger unit, the chains hide what is left
+#: of the softmax, and the two together leave 0.33 us over the matmuls alone.
+#: 2 | 4 | 8 heads a step are one speed a page (5.84 | 5.85 | 5.85) and differ
+#: by the dead steps and first fetches of a call alone (a chunk at page 0:
+#: 220 | 194 | 182 us; at page 16: 3249 | 3209 | 3182): 4 stays, at half the
+#: fast memory of 8.  The own page's block (128 | 256 | 512) moves nothing.
 _LATENT_HEADS = 4
-_LATENT_Q_ROWS = 256
+_LATENT_Q_ROWS = 1024
+_LATENT_CHAINS = 2
+_LATENT_DIAGONAL_ROWS = 256
 #: fast memory the latent prefill kernel may take (the chip's default
-#: gives a kernel 16 MiB of 128)
+#: gives a kernel 16 MiB of 128; at the cells' shape it compiles from 24 MiB
+#: on: ``tools/aot_compile.py latent``)
 _LATENT_VMEM = 48 << 20
+
+
+def _latent_dot(x, y, transposed, precision):
+    """``x (m, k) . y (k, n)``, or ``. y (n, k)^T`` where ``transposed``,
+    summed in float32: the latent prefill kernel's every matmul."""
+    return jax.lax.dot_general(
+        x, y, (((1,), (1 if transposed else 0,)), ((), ())),
+        precision=precision, preferred_element_type=jnp.float32)
+
+
+def _latent_softmax(s, m_prev):
+    """One online-softmax step's vector work on float32 scores ``s (rows,
+    keys)`` under the running maximum ``m_prev (rows, 1)``: the new
+    maximum, the factor the old sums shrink by, the exponentials and their
+    row sums."""
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    return m_new, jnp.exp(m_prev - m_new), p, p.sum(axis=-1, keepdims=True)
 
 
 def paged_latent_prefill(q_nope, q_rope, wk, wv, pool, ptab, pos, scale,
@@ -1127,58 +1179,76 @@ def paged_latent_prefill(q_nope, q_rope, wk, wv, pool, ptab, pos, scale,
     on a live page rebuilds the block's keys and values from the page's
     latents (``c_kv W_k[h]``, ``c_kv W_v[h]``: the re-expansion, once a
     cached token a head a chunk) and runs the online softmax, ``s =
-    (q_nope . k_nope + q_rope . k_rope) * scale`` in float32, a block of
-    query rows at a time.  History pages lie wholly below the chunk's
-    frontier and need no mask; the chunk's own page (the last live one) is
-    causal, and a block of query rows there takes only the keys up to its
-    own diagonal (``pos`` is page-aligned, so which those are is static).
-    Dead pages (behind the chunk) cost neither a fetch nor a step.
-    Returns (b, h, c, v)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    (q_nope . k_nope + q_rope . k_rope) * scale`` in float32.  History
+    pages lie wholly below the chunk's frontier and need no mask; the
+    chunk's own page (the last live one) is causal, and a block of query
+    rows there takes only the keys up to its own diagonal (``pos`` is
+    page-aligned, so which those are is static).  Dead pages (behind the
+    chunk) cost neither a fetch nor a step.
 
+    THE ORDER OF A STEP'S WORK (ISSUE 44; ``_latent_prefill_call`` holds
+    the body): on a history page a unit of ``_LATENT_Q_ROWS`` query rows
+    meets the whole page at once, so a key or value tile that stands in the
+    matrix unit serves that many rows before the next is loaded; and a loop
+    body holds ``_LATENT_CHAINS`` units whose sums do not meet, every
+    unit's score matmuls written before the first unit's softmax, so that
+    the vector units' work on one unit (maximum, exponent, sum, cast) has
+    another unit's matmuls to run under.  Returns (b, h, c, v)."""
     b, h, c, nope = q_nope.shape
     rope = q_rope.shape[-1]
-    vdim = wv.shape[-1]
     page, row = pool.shape[2:]
     if c != page:
         raise ValueError("prefill kernel needs chunk (%d) == page (%d)"
                          % (c, page))
     tail = row - kv_rank
-    m_pages = ptab.shape[1]
-    hb = math.gcd(h, _LATENT_HEADS)
-    qb = math.gcd(c, _LATENT_Q_ROWS)
     dtype = q_nope.dtype
-    precision = F._PRECISION if dtype == jnp.float32 else None
     # the queries' rotated part beside zeros, as wide as the row's tail
     q = jnp.concatenate(
         [q_nope, q_rope,
          jnp.zeros((b, h, c, tail - rope), dtype)], axis=-1)
-    _, last, _ = live_pages(jnp.asarray(pos, jnp.int32), c, page, m_pages)
+    _, last, _ = live_pages(jnp.asarray(pos, jnp.int32), c, page,
+                            ptab.shape[1])
+    call = _latent_prefill_call(
+        b, h, c, nope, row, kv_rank, wv.shape[-1], ptab.shape[1],
+        math.gcd(h, _LATENT_HEADS), math.gcd(c, _LATENT_Q_ROWS),
+        _LATENT_CHAINS, math.gcd(c, _LATENT_DIAGONAL_ROWS), _LATENT_VMEM,
+        float(scale), jnp.dtype(dtype),
+        F._PRECISION if dtype == jnp.float32 else None,
+        _interpret(interpret), _latent_dot, _latent_softmax)
+    return call(jnp.asarray(ptab, jnp.int32), jnp.asarray(pos, jnp.int32),
+                last, q, wk, wv, pool)
 
-    def dot_nt(x, y):      # x (m, k) . y (n, k)^T
-        return jax.lax.dot_general(
-            x, y, (((1,), (1,)), ((), ())), precision=precision,
-            preferred_element_type=jnp.float32)
 
-    def dot_nn(x, y):
-        return jax.lax.dot_general(
-            x, y, (((1,), (0,)), ((), ())), precision=precision,
-            preferred_element_type=jnp.float32)
+@functools.lru_cache(maxsize=64)
+def _latent_prefill_call(b, h, c, nope, row, kv_rank, vdim, m_pages, hb, qb,
+                         chains, db, vmem, scale, dtype, precision,
+                         interpret, dot, softmax):
+    """:func:`paged_latent_prefill`'s Pallas call for ``b`` lanes of ``h``
+    heads over a table of ``m_pages`` pages of ``c`` rows ``row`` wide:
+    ``hb`` heads a grid step, units of ``qb`` query rows on a history page,
+    ``chains`` of them a loop body, blocks of ``db`` rows on the chunk's own
+    page.  A function of the three prefetched scalars, the queries ``[nope |
+    tail]``, the two weights and the pool.  KEPT for every set of sizes, as
+    ``_flash_walk_call`` is and for its reason: the latent layers of a chunk
+    program trace and lower the body ONCE, which is what pays for a body
+    that holds several units (that time is set-up time).  ``dot`` and
+    ``softmax`` are what the trace reads beside the sizes (``_latent_dot``,
+    ``_latent_softmax``)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    blocks = c // qb
+    # the units of a loop body: blocks of one head's rows, or heads where a
+    # head has one block
+    by_rows = math.gcd(blocks, chains) if blocks > 1 else 1
+    by_heads = math.gcd(hb, chains) if blocks == 1 else 1
 
-    def update(e, rows, s, v, acc_ref, l_ref, m_ref):
-        """The online-softmax recurrence on the query rows ``rows`` (a
-        slice) of head ``e`` against scores ``s`` (qb, keys) and values
-        ``v``."""
-        m_prev = m_ref[e, rows, :]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[e, rows, :] = l_ref[e, rows, :] * alpha \
-            + p.sum(axis=-1, keepdims=True)
-        acc_ref[e, rows, :] = acc_ref[e, rows, :] * alpha \
-            + dot_nn(p.astype(v.dtype), v)
-        m_ref[e, rows, :] = m_new
+    def loop(n, body):
+        """``body(t)`` for t in [0, n): a loop that is not unrolled (every
+        unrolled body is compiled again), or the one call."""
+        if n == 1:
+            body(0)
+        else:
+            jax.lax.fori_loop(0, n, lambda t, _: body(t) or 0, 0)
 
     def kernel(ptab_ref, pos_ref, last_ref, q_ref, wk_ref, wv_ref, pool_ref,
                o_ref, acc_ref, l_ref, m_ref):
@@ -1193,48 +1263,78 @@ def paged_latent_prefill(q_nope, q_rope, wk, wv, pool, ptab, pos, scale,
         def expand(e):
             lat = pool_ref[0, 0]
             c_kv = lat[:, :kv_rank]
-            k_nope = dot_nn(c_kv, wk_ref[e]).astype(dtype)
-            v = dot_nn(c_kv, wv_ref[e]).astype(dtype)
+            k_nope = dot(c_kv, wk_ref[e], False, precision).astype(dtype)
+            v = dot(c_kv, wv_ref[e], False, precision).astype(dtype)
             return k_nope, lat[:, kv_rank:], v
 
         def scores(e, rows, k_nope, k_tail):
             qn = q_ref[0, e, rows, :nope]
             qt = q_ref[0, e, rows, nope:]
-            return (dot_nt(qn, k_nope) + dot_nt(qt, k_tail)) * scale
+            return (dot(qn, k_nope, True, precision)
+                    + dot(qt, k_tail, True, precision)) * scale
 
-        # the heads of a block, and on a history page the blocks of query
-        # rows, are loops and not unrolled: every unrolled body is compiled
-        # again for every layer of the chunk program
+        def update(e, rows, s, v):
+            """The online-softmax recurrence on the query rows ``rows`` (a
+            slice) of head ``e`` against scores ``s`` (rows, keys) and
+            values ``v``."""
+            m_new, alpha, p, p_sum = softmax(s, m_ref[e, rows, :])
+            l_ref[e, rows, :] = l_ref[e, rows, :] * alpha + p_sum
+            acc_ref[e, rows, :] = acc_ref[e, rows, :] * alpha \
+                + dot(p.astype(v.dtype), v, False, precision)
+            m_ref[e, rows, :] = m_new
+
+        def chained(units):
+            """``units`` of (head, rows, k_nope, k_tail, v, mask | None):
+            every unit's scores, then every unit's softmax and ``p . v``."""
+            ss = []
+            for e, rows, k_nope, k_tail, v, mask in units:
+                s = scores(e, rows, k_nope, k_tail)
+                ss.append(s if mask is None else jnp.where(mask, s, NEG_INF))
+            for (e, rows, _, _, v, _), s in zip(units, ss):
+                update(e, rows, s, v)
 
         @pl.when(j < last_ref[i])
         def _():           # a history page: every key visible to every row
-            def head(e, _):
-                k_nope, k_tail, v = expand(e)
+            if blocks == 1:
+                everything = pl.ds(0, c)
 
-                def block(t, _):
-                    rows = pl.ds(pl.multiple_of(t * qb, qb), qb)
-                    update(e, rows, scores(e, rows, k_nope, k_tail), v,
-                           acc_ref, l_ref, m_ref)
-                    return 0
-                return jax.lax.fori_loop(0, c // qb, block, 0)
-            jax.lax.fori_loop(0, hb, head, 0)
+                def heads(g):
+                    # (the second head's expansion, matrix-unit work alone,
+                    # also stands before the first head's softmax)
+                    chained([(e, everything) + expand(e) + (None,)
+                             for e in (g * by_heads + n
+                                       for n in range(by_heads))])
+                loop(hb // by_heads, heads)
+            else:
+                def head(e):
+                    kv = expand(e)
+
+                    def group(g):
+                        chained([(e, pl.ds(pl.multiple_of(
+                            (g * by_rows + n) * qb, qb), qb)) + kv + (None,)
+                            for n in range(by_rows)])
+                    loop(blocks // by_rows, group)
+                loop(hb, head)
 
         @pl.when(j == last_ref[i])
         def _():           # the chunk's own page: causal
-            def head(e, _):
+            own = math.gcd(c // db, chains)
+
+            def head(e):
                 k_nope, k_tail, v = expand(e)
-                for lo in range(0, c, qb):
-                    keys = lo + qb          # up to this block's diagonal
-                    rows = pl.ds(lo, qb)
-                    s = scores(e, rows, k_nope[:keys], k_tail[:keys])
-                    k_pos = jax.lax.broadcasted_iota(
-                        jnp.int32, (qb, keys), 1)
-                    q_pos = lo + jax.lax.broadcasted_iota(
-                        jnp.int32, (qb, keys), 0)
-                    s = jnp.where(k_pos <= q_pos, s, NEG_INF)
-                    update(e, rows, s, v[:keys], acc_ref, l_ref, m_ref)
-                return 0
-            jax.lax.fori_loop(0, hb, head, 0)
+                for lo in range(0, c, own * db):
+                    units = []
+                    for at in range(lo, lo + own * db, db):
+                        keys = at + db      # up to this block's diagonal
+                        k_pos = jax.lax.broadcasted_iota(
+                            jnp.int32, (db, keys), 1)
+                        q_pos = at + jax.lax.broadcasted_iota(
+                            jnp.int32, (db, keys), 0)
+                        units.append((e, pl.ds(at, db), k_nope[:keys],
+                                      k_tail[:keys], v[:keys],
+                                      k_pos <= q_pos))
+                    chained(units)
+            loop(hb, head)
 
         @pl.when(j == m_pages - 1)
         def _():
@@ -1252,10 +1352,10 @@ def paged_latent_prefill(q_nope, q_rope, wk, wv, pool, ptab, pos, scale,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, h // hb, m_pages),
-        in_specs=[pl.BlockSpec((1, hb, c, nope + tail), lane),
+        in_specs=[pl.BlockSpec((1, hb, c, nope + row - kv_rank), lane),
                   pl.BlockSpec((hb, kv_rank, nope), weights),
                   pl.BlockSpec((hb, kv_rank, vdim), weights),
-                  pl.BlockSpec((1, 1, page, row), history)],
+                  pl.BlockSpec((1, 1, c, row), history)],
         out_specs=pl.BlockSpec((1, hb, c, vdim), lane),
         scratch_shapes=[pltpu.VMEM((hb, c, vdim), jnp.float32),
                         pltpu.VMEM((hb, c, 1), jnp.float32),
@@ -1264,11 +1364,8 @@ def paged_latent_prefill(q_nope, q_rope, wk, wv, pool, ptab, pos, scale,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, c, vdim), dtype),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_LATENT_VMEM),
-        interpret=_interpret(interpret),
-    )(jnp.asarray(ptab, jnp.int32), jnp.asarray(pos, jnp.int32), last,
-      q, wk, wv, pool)
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
+        interpret=interpret)
 
 
 def serving_kernels_supported(paged, n_heads, kv_heads, head_dim,
