@@ -2,7 +2,8 @@
 ``test_lm_fastpath.py``, ``test_kv_pool.py``, ``test_tracing.py``,
 ``test_serving.py``): the small ``pre_ln`` model and its greedy reference,
 the small two-kinds ``sandwich`` model (``test_afmoe.py`` reads it from
-here), the jit-cache
+here), an engine of each kind the benchmark's cells run with its tokens'
+check (:func:`make_engine`, :func:`check_tokens`), the jit-cache
 guard, and the feature-set tables of the parity matrices."""
 
 import functools
@@ -34,53 +35,31 @@ def _greedy(params, prompt, n_new, max_len, n_heads=2):
 def jit_guard():
     """Collects an engine's jitted programs and asserts the compile
     count stayed bounded: ONE program per (shape) family — chunk
-    prefill, verify, install/extract, step — regardless of how many
-    prompt lengths and feature mixes the workload threw at it.  The
-    acceptance criterion's guard: a fast path that silently forked a
-    compile per prompt length would be a dispatch-latency regression
-    dressed as a feature."""
-    def check(engine, prefill_buckets=1):
-        if engine._paged:
-            # paged mode (ISSUE 6): the page-table indirection is
-            # traced DATA, so the whole mixed-length workload owns
-            # exactly one chunk and one page-copy program; step/verify
-            # own one program PER LIVE-WIDTH LADDER ENTRY (ISSUE 7
-            # satellite — the table is sliced to the batch's live page
-            # span, the paged analogue of the contiguous prompt
-            # buckets), still a static bound independent of the
-            # workload's prompt-length mix
-            widths = len(engine._width_ladder)
-            progs = {
-                "step": (engine._step_jit, widths),
-                "chunk": (engine._chunk_jit, 1),
-                "page_copy": (engine._page_copy_jit, 1),
-            }
-            if engine._verify_jit is not None:
-                progs["verify"] = (engine._verify_jit, widths)
-            if engine._megastep_jit is not None:
-                # ISSUE 13: the fused program's asserted compile bound
-                # — ONE megastep program per (live-width ladder entry
-                # × K) family, K fixed per engine
-                progs["megastep"] = (engine._megastep_jit, widths)
-            for name, (fn, bound) in progs.items():
-                size = fn._cache_size()
-                assert size <= bound, (
-                    "%s program compiled %d variants (bound %d)"
-                    % (name, size, bound))
-            return
+    prefill, page copy, and per live-width ladder entry verify and
+    step — regardless of how many prompt lengths and feature mixes the
+    workload threw at it.  The acceptance criterion's guard: a fast
+    path that silently forked a compile per prompt length would be a
+    dispatch-latency regression dressed as a feature."""
+    def check(engine):
+        # the page-table indirection is traced DATA (ISSUE 6), so the
+        # whole mixed-length workload owns exactly one chunk and one
+        # page-copy program; step/verify own one program PER LIVE-WIDTH
+        # LADDER ENTRY (ISSUE 7 satellite — the table is sliced to the
+        # batch's live page span), still a static bound independent of
+        # the workload's prompt-length mix
+        widths = len(engine._width_ladder)
         progs = {
-            "step": (engine._step_jit, 1),
-            "install": (engine._install_jit, 1),
-            "prefill": (engine._prefill_jit, prefill_buckets),
+            "step": (engine._step_jit, widths),
+            "chunk": (engine._chunk_jit, 1),
+            "page_copy": (engine._page_copy_jit, 1),
         }
-        if engine._chunk_jit is not None:
-            progs["chunk"] = (engine._chunk_jit, 1)
-            progs["chunk_install"] = (engine._chunk_install_jit, 1)
-            progs["chunk_extract"] = (engine._chunk_extract_jit, 1)
         if engine._verify_jit is not None:
-            progs["verify"] = (engine._verify_jit, 1)
+            progs["verify"] = (engine._verify_jit, widths)
         if engine._megastep_jit is not None:
-            progs["megastep"] = (engine._megastep_jit, 1)
+            # ISSUE 13: the fused program's asserted compile bound
+            # — ONE megastep program per (live-width ladder entry
+            # × K) family, K fixed per engine
+            progs["megastep"] = (engine._megastep_jit, widths)
         for name, (fn, bound) in progs.items():
             size = fn._cache_size()
             assert size <= bound, (
@@ -92,12 +71,10 @@ def jit_guard():
 #: the feature-off engine's parity (incl. slot reuse) is already pinned
 #: by tests/test_serving.py::TestLMEngine — these legs cover what's new
 FEATURE_SETS = [
-    {"prefill_chunk": 8},
+    # the default page (32 of 96: three pages a lane) under speculation
     {"spec_k": 3},
-    {"prefix_cache": 32, "prefill_chunk": 8},
-    {"prefix_cache": 32, "prefill_chunk": 8, "spec_k": 3},
-    # paged KV (ISSUE 6) — the page-table indirection under every
-    # fast-path combination; paged_kv=12 also exercises a pool SMALLER
+    # the page-table indirection (ISSUE 6) under every fast-path
+    # combination at pages of 8; paged_kv=12 also exercises a pool SMALLER
     # than slots×max_pages (lanes contend for pages and still finish)
     {"paged_kv": True, "prefill_chunk": 8},
     {"paged_kv": 12, "prefill_chunk": 8},
@@ -132,26 +109,26 @@ FEATURE_SETS = [
 ]
 
 
-#: ISSUE 27: both KV layouts, with and without speculation and the
-#: fused decode loop — every family that returns the storage
+#: ISSUE 27: with and without speculation and the fused decode loop —
+#: every family that returns the storage; a ``kind`` names one of
+#: :func:`make_engine`'s models (a latent pool, slots of recurrent state,
+#: the drafting module's pool beside the stack's)
 IN_PLACE_SETS = [
-    {},
-    {"prefill_chunk": 8, "prefix_cache": 32},
-    {"spec_k": 3},
-    {"megastep": 4},
     {"paged_kv": True, "prefill_chunk": 8},
     {"paged_kv": True, "prefill_chunk": 8, "prefix_cache": 32,
      "spec_k": 3},
     {"paged_kv": True, "prefill_chunk": 8, "megastep": 4},
     {"paged_kv": True, "prefill_chunk": 8, "attn_kernel": "force"},
     {"tp": 2, "paged_kv": True, "prefill_chunk": 8},
+    {"kind": "latent"},
+    {"kind": "linear"},
+    {"kind": "mtp", "spec_k": 1},
 ]
 
 
 #: ISSUE 13 parity matrix: K ∈ {1, 4, 8} × the fast-path features.
-#: Every paged leg is tier-1; the contiguous layout keeps one
-#: representative (plain at K=4) and its other legs, and the K=1 no-op
-#: family (pinned by test_validation_and_noop), ride the slow suite.
+#: Every leg is tier-1 but the K=1 no-op family (pinned by
+#: test_validation_and_noop), which rides the slow suite.
 MEGASTEP_SETS = [
     # K=1 parity rides the slow suite: test_validation_and_noop pins
     # K=1 == tick path (no fused program built), and the tick path's
@@ -159,15 +136,11 @@ MEGASTEP_SETS = [
     # this entry re-proved both at 15s (watchdog-headroom discipline)
     pytest.param(1, {"paged_kv": True, "prefill_chunk": 8,
                      "spec_k": 3}, marks=pytest.mark.slow),
-    (4, {}),
     (8, {"paged_kv": True, "prefill_chunk": 8, "prefix_cache": 32,
          "spec_k": 3}),
     (4, {"tp": 2, "paged_kv": True, "prefill_chunk": 8, "spec_k": 3}),
     (4, {"paged_kv": True, "prefill_chunk": 8,
          "attn_kernel": "force"}),
-    pytest.param(4, {"prefill_chunk": 8}, marks=pytest.mark.slow),
-    pytest.param(4, {"spec_k": 3}, marks=pytest.mark.slow),
-    pytest.param(8, {}, marks=pytest.mark.slow),
     (4, {"paged_kv": True, "prefill_chunk": 8}),
     (8, {"paged_kv": True, "prefill_chunk": 8}),
     (4, {"paged_kv": True, "prefill_chunk": 8, "prefix_cache": 32,
@@ -213,7 +186,7 @@ def kinds_model(seed=3):
     :data:`SMALL`: sliding and full layers (two kinds of cache: a page
     table and an allocator each, ``kv_pool.WindowTables``), an expert
     layer, a window of 8 — the second cell's engine at test size.
-    Positions end at 64; the engine takes it paged, without prefix
+    Positions end at 64; the engine takes it without prefix
     cache, speculation, megastep or ``tp``."""
     return record(), _kinds_weights(seed)
 
@@ -252,3 +225,132 @@ def assert_greedy(engine, prompt, out, n_new, params=None):
                        numpy.arange(len(prompt) - 1, len(seq) - 1), SMALL)
     gap = ref.max(-1) - ref[numpy.arange(n_new), out]
     assert float(gap.max()) <= 1e-4, gap
+
+
+#: the kinds of engine :func:`make_engine` builds, each at the size of its
+#: own test file: the classic block, two kinds of paged cache under an
+#: expert layer, a pool of latent rows, slots of recurrent state beside a
+#: pool, and a model that drafts with its own module (``spec_k=1``)
+KINDS = ("pre_ln", "window", "latent", "linear", "mtp")
+
+
+@functools.lru_cache(maxsize=None)
+def _model(kind):
+    """(record, the engine's float32 weights, checker(prompt, out))."""
+    import jax
+    import jax.numpy as jnp
+    if kind == "pre_ln":
+        return 2, _params(max_len=64), None
+    if kind == "window":
+        return (*kinds_model(), None)
+    if kind == "mtp":
+        import test_joyai as small
+        from benchmark.reference import joyai as reference
+        init = "mixed_0.002"      # some drafts taken, some refused
+        cfg, record = small.config(init), small.record(init)
+        w = small.weights(init)[0]
+    else:
+        over = {}
+        if kind == "latent":
+            import test_xing4 as small
+            from benchmark.reference import xing4 as reference
+            # (its own file keeps the 20 iterations; every one is
+            # unrolled twice a layer in each of an engine's programs,
+            # 45 s a start() where 4 take 12)
+            over = {"hc_sinkhorn_iters": 4}
+        else:
+            import test_qwen3_next as small
+            from benchmark.reference import qwen3_next as reference
+        cfg, record = dict(small.SMALL, **over), small.record(**over)
+        w = reference.make_weights(3, cfg)
+
+    def check(prompt, out):
+        seq = numpy.concatenate([prompt, out])
+        ref = numpy.asarray(reference.logits(
+            w, seq, numpy.arange(len(prompt) - 1, len(seq) - 1), cfg))
+        gap = ref.max(-1) - ref[numpy.arange(len(out)), out]
+        assert float(gap.max()) <= 1e-4, gap
+    return record, jax.tree.map(lambda a: a.astype(jnp.float32), w), check
+
+
+def make_engine(kind="pre_ln", name="ahead", **over):
+    """An engine of ``kind`` as the benchmark's cells deploy theirs (a
+    pool, pages of 8, three lanes of 64 positions; the drafting kind with
+    ``spec_k=1``), not started."""
+    from veles_tpu.serving import LMEngine, ServingMetrics
+    record, params, _ = _model(kind)
+    kw = dict(max_len=64, slots=3, paged_kv=True, prefill_chunk=8,
+              metrics=ServingMetrics(name), name=name)
+    if kind == "mtp":
+        kw["spec_k"] = 1
+    kw.update(over)
+    return LMEngine(params, record, **kw)
+
+
+def check_tokens(kind, engine, prompt, out, n_new):
+    """``out`` is ``n_new`` tokens, each the choice of ``kind``'s
+    reference given what precedes it."""
+    check = _model(kind)[2]
+    out = numpy.asarray(out)
+    assert out.shape == (n_new,)
+    if check is None:
+        assert_greedy(engine, prompt, out, n_new)
+    else:
+        check(numpy.asarray(prompt), out)
+
+
+def vocab_of(engine):
+    return int(engine.params["embed"].shape[0])
+
+
+#: (prompt length, n_new) of a round: more requests than lanes, prompts of
+#: one to four chunks of 8, answers that end while others prefill — and an
+#: answer of ONE token (its tail chunk's first token is its last) and of two
+#: (freed by count under its only step)
+ROUND = [(5, 9), (19, 6), (3, 1), (26, 12), (9, 2), (12, 7), (30, 5)]
+
+
+def tokens(n, seed, vocab):
+    return numpy.random.default_rng(seed).integers(0, vocab, n)
+
+
+def serve(engine, round_=ROUND, seed=40):
+    """Start, serve one round (all submitted at once), stop: the prompts
+    and the served continuations."""
+    engine.start()
+    try:
+        prompts = [tokens(n, seed + i, vocab_of(engine))
+                   for i, (n, _) in enumerate(round_)]
+        futures = [engine.submit(p, n_new)
+                   for p, (_, n_new) in zip(prompts, round_)]
+        outs = [f.result(timeout=300) for f in futures]
+    finally:
+        engine.stop()
+    return prompts, outs
+
+
+def counters(engine):
+    return engine.metrics.snapshot()["counters"]
+
+
+def pipeline_balances(engine):
+    """Idle, nothing is left in flight, and every decode dispatch was either
+    followed by one sent ahead of its fetch or drained (ISSUE 39)."""
+    c = counters(engine)
+    assert not engine._flights and engine._older == 0
+    assert c.get("dispatches_sent_ahead", 0) + c.get("pipeline_drains", 0) \
+        == c["decode_dispatches"]
+    return c
+
+
+def stamps_of(turns):
+    from veles_tpu.serving import tracing
+    return turns[:, tracing.COL_STAMPS:tracing.COL_END + 1]
+
+
+def follows_a_step(turns):
+    """Bool per turn: the turn before dispatched a decode program (its step
+    was in flight while this one was prepared)."""
+    from veles_tpu.serving import tracing
+    step = turns[:, tracing.COL_STEP_PROGRAM] > 0
+    return numpy.concatenate([[False], step[:-1]])

@@ -148,54 +148,53 @@ def test_engine_serves_the_references_tokens(weights, features,
                                SMALL)
             gap = ref.max(-1) - ref[numpy.arange(len(o)), o]
             assert float(gap.max()) <= 1e-4
-        if eng._paged:
-            assert eng.verify_pool_invariants()["used_pages"] == 0
-            assert eng._wt.verify()["held"] == 0
-            assert max(held) <= SMALL["sliding_window"] // page + 2
-            snap = eng.metrics.snapshot()
-            assert snap["counters"]["kv_pages_released_window"] > 0
-            assert snap["gauges"]["kv_pages_free.window"] \
-                == snap["gauges"]["kv_pages_total.window"]
-            assert snap["gauges"]["kv_pages_free.full"] == 48
-            assert snap["gauges"]["kv_storage_in_place"] == 1
-            assert snap["counters"].get("kv_storage_rebuilds", 0) == 0
-            # the step's counts, fetched with its tokens: counters, and
-            # the recorder's per-turn columns
-            c = snap["counters"]
-            steps = c["decode_dispatches"]
-            assert c["moe_assignments_held"] + c["moe_assignments_elsewhere"] \
-                == steps * eng.slots * 3 * 3      # lanes x top_k x layers
-            from veles_tpu.serving import tracing
-            turns = eng.recorder.turns()
-            assert int(turns[:, tracing.COL_MOE_HIT].sum()) \
-                == c["moe_experts_hit"]
-            # the largest load of any expert in any step is a gauge alone
-            # (ISSUE 38: the turn row holds what a reader reads): the
-            # largest of the loads the steps fetched with their tokens.
-            # (Idle lanes' rows are routed too, so the reference's routing
-            # of the requests alone does not give a step's load.)
-            assert len(loads) == steps
-            assert snap["gauges"]["moe_max_expert_load"] == max(loads)
-            assert 1 <= max(loads) <= eng.slots
-            # ISSUE 29: the page steps handed to the kernels and the live
-            # ones, over both kinds of table (the sliding kind's relative
-            # to its base); nothing is counted without the kernels
-            steps = (c.get("attn_page_steps"), c.get("attn_page_steps_live"))
-            assert steps == (count() if eng._kernel_active
-                             else (None, None))
-            assert int(turns[:, tracing.COL_ATTN_STEPS].sum()) \
-                == (steps[0] or 0)
-            assert int(turns[:, tracing.COL_ATTN_LIVE].sum()) \
-                == (steps[1] or 0)
-            if eng._kernel_active:
-                # ISSUE 43: a decode step is handed its live pages alone,
-                # and these pools' pages are so small that a lane's walk
-                # is ONE block a layer, whatever it holds
-                given, live, blocks = count(per=1 << 20)
-                assert given == live
-                assert c["attn_walk_blocks"] == blocks \
-                    == c["decode_dispatches"] * eng.slots \
-                    * len(eng.params["blocks"])
+        assert eng.verify_pool_invariants()["used_pages"] == 0
+        assert eng._wt.verify()["held"] == 0
+        assert max(held) <= SMALL["sliding_window"] // page + 2
+        snap = eng.metrics.snapshot()
+        assert snap["counters"]["kv_pages_released_window"] > 0
+        assert snap["gauges"]["kv_pages_free.window"] \
+            == snap["gauges"]["kv_pages_total.window"]
+        assert snap["gauges"]["kv_pages_free.full"] == 48
+        assert snap["gauges"]["kv_storage_in_place"] == 1
+        assert snap["counters"].get("kv_storage_rebuilds", 0) == 0
+        # the step's counts, fetched with its tokens: counters, and
+        # the recorder's per-turn columns
+        c = snap["counters"]
+        steps = c["decode_dispatches"]
+        assert c["moe_assignments_held"] + c["moe_assignments_elsewhere"] \
+            == steps * eng.slots * 3 * 3      # lanes x top_k x layers
+        from veles_tpu.serving import tracing
+        turns = eng.recorder.turns()
+        assert int(turns[:, tracing.COL_MOE_HIT].sum()) \
+            == c["moe_experts_hit"]
+        # the largest load of any expert in any step is a gauge alone
+        # (ISSUE 38: the turn row holds what a reader reads): the
+        # largest of the loads the steps fetched with their tokens.
+        # (Idle lanes' rows are routed too, so the reference's routing
+        # of the requests alone does not give a step's load.)
+        assert len(loads) == steps
+        assert snap["gauges"]["moe_max_expert_load"] == max(loads)
+        assert 1 <= max(loads) <= eng.slots
+        # ISSUE 29: the page steps handed to the kernels and the live
+        # ones, over both kinds of table (the sliding kind's relative
+        # to its base); nothing is counted without the kernels
+        steps = (c.get("attn_page_steps"), c.get("attn_page_steps_live"))
+        assert steps == (count() if eng._kernel_active
+                         else (None, None))
+        assert int(turns[:, tracing.COL_ATTN_STEPS].sum()) \
+            == (steps[0] or 0)
+        assert int(turns[:, tracing.COL_ATTN_LIVE].sum()) \
+            == (steps[1] or 0)
+        if eng._kernel_active:
+            # ISSUE 43: a decode step is handed its live pages alone,
+            # and these pools' pages are so small that a lane's walk
+            # is ONE block a layer, whatever it holds
+            given, live, blocks = count(per=1 << 20)
+            assert given == live
+            assert c["attn_walk_blocks"] == blocks \
+                == c["decode_dispatches"] * eng.slots \
+                * len(eng.params["blocks"])
     finally:
         eng.stop()
 
@@ -323,12 +322,25 @@ def test_window_tables_accounting():
 @pytest.mark.parametrize("option,match", [
     ({"prefix_cache": 8}, "prefix_cache"), ({"spec_k": 2}, "spec_k"),
     ({"megastep": 4}, "megastep"),
-    ({"tp": 2}, "tp >= 2"), ({"paged_kv": 0}, "expert layer needs paged")])
+    ({"tp": 2}, "tp >= 2")])
 def test_what_was_not_widened_says_so(weights, option, match):
     from veles_tpu.serving import LMEngine
     with pytest.raises(ValueError, match=match):
         LMEngine(weights[1], record(), max_len=64, slots=2,
                  **dict({"paged_kv": 24, "prefill_chunk": PAGE}, **option))
+
+
+def test_the_default_pool_is_every_lanes_whole_table(weights):
+    """``paged_kv`` 0 (the default) names no other layout: the pool then
+    holds every lane's whole table, ``slots x max_len / page`` pages."""
+    from veles_tpu.serving import LMEngine
+    eng = LMEngine(weights[1], record(), max_len=64, slots=2,
+                   prefill_chunk=PAGE)
+    assert eng._pool.num_pages == 2 * 64 // PAGE
+    assert eng._page_tables.shape == (2, 64 // PAGE)
+    # the sliding layers' pool: the window's pages a lane, no more
+    assert eng._wt.pool.num_pages \
+        == 2 * eng.cfg.window_pages(PAGE) < eng._pool.num_pages
 
 
 def test_pipeline_stages_refuse_the_block():

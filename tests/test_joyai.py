@@ -517,21 +517,22 @@ def test_the_shares_and_the_shared_expert_make_the_uncut_layer():
     ("window", "sliding layer's released pages"),
     ("linear", "linear layer's\\s+recurrent state")])
 def test_speculation_is_refused_where_a_draft_cannot_be_undone(kind, match):
-    import test_lm_ahead as ahead
+    import lm_cases
     with pytest.raises(ValueError, match=match):
-        ahead.make_engine(kind, spec_k=1)
+        lm_cases.make_engine(kind, spec_k=1)
 
 
 def test_a_latent_model_without_a_module_drafts_from_its_text():
     """The refusal went for the latent kind: without a module the engine
     drafts by prompt lookup (the synchronous driver) and serves the same
     tokens as without."""
-    import test_lm_ahead as ahead
-    _, _, check = ahead._model("latent")
+    import lm_cases
+    _, _, check = lm_cases._model("latent")
     prompt = numpy.tile(tokens(6, 5), 4)        # text that repeats
     outs = []
     for k in (0, 2):
-        eng = ahead.make_engine("latent", name="latent_spec%d" % k, spec_k=k)
+        eng = lm_cases.make_engine("latent", name="latent_spec%d" % k,
+                                   spec_k=k)
         assert not eng._mtp and (eng._verify_jit is not None) == bool(k)
         eng.start()
         try:

@@ -17,7 +17,8 @@ import time
 import numpy
 import pytest
 
-from lm_cases import _params, assert_greedy, served_model
+from lm_cases import (_params, assert_greedy, check_tokens, make_engine,
+                      served_model)
 from veles_tpu.serving.kv_pool import KVPagePool
 
 
@@ -119,19 +120,32 @@ class TestTrieEvictionReleasesPages:
 
 
 def _paged_engine(caches, name, **kw):
-    """A paged engine over ``caches`` kinds of cache: ``one`` is the
+    """An engine over ``caches`` kinds of cache: ``one`` is the
     ``pre_ln`` model; ``two`` is the small ``sandwich`` model (sliding
-    and full layers: a table and an allocator each)."""
+    and full layers: a table and an allocator each); ``latent``,
+    ``linear`` and ``mtp`` are :func:`lm_cases.make_engine`'s (one pool
+    of latent rows; slots of state beside a pool; the drafting module's
+    pool behind the stack's, two steps' headroom a lane)."""
+    if caches not in ("one", "two"):
+        return make_engine(caches, name=name, **kw)
     from veles_tpu.serving import LMEngine
     record, params, max_len = served_model(caches == "two")
     return LMEngine(params, record, max_len=max_len, prefill_chunk=8,
                     name=name, **kw)
 
 
+def _served(caches, engine, prompt, out, n_new):
+    """``out`` is the reference's ``n_new`` tokens after ``prompt``."""
+    if caches in ("one", "two"):
+        assert_greedy(engine, prompt, out, n_new)
+    else:
+        check_tokens(caches, engine, prompt, out, n_new)
+
+
 def _trie(caches, capacity):
     """The prefix cache's keyword for ``one`` kind of cache; none for
-    ``two``: that engine refuses it, and its legs say what they check
-    in the trie's place."""
+    the others: those engines refuse it, and their legs say what they
+    check in the trie's place."""
     return {"prefix_cache": capacity} if caches == "one" else {}
 
 
@@ -149,17 +163,21 @@ def _home_whole(engine):
 
 
 CACHES = pytest.mark.parametrize("caches", ["one", "two"])
+KINDS = pytest.mark.parametrize(
+    "caches", ["one", "two", "latent", "linear", "mtp"])
 
 
 class TestEngineLifecycle:
-    @CACHES
+    @KINDS
     def test_refcount_release_on_lane_finish(self, caches):
         """Two shared-prefix requests through a paged engine: while the
         trie holds the shared chunks their pages stay allocated (refs
         from the trie), every lane-owned page returns to the free list
         at finish, and evicting the trie drains the pool back to
         FULL — no page leaks across the request lifecycle.  Two kinds
-        of cache (no trie): both allocators are whole at finish."""
+        of cache (no trie): both allocators are whole at finish; a
+        lane's slot of state and the drafting module's pages (and the
+        headroom of its two steps in flight) come home with them."""
         rng = numpy.random.RandomState(7)
         shared = rng.randint(0, 16, 16).tolist()     # 2 full chunks
         prompts = [shared + rng.randint(0, 16, 3).tolist()
@@ -418,19 +436,27 @@ class TestStorageLost:
     LONG = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9, 7, 9, 3, 2, 3, 8, 4,
             6, 2, 6, 4, 3, 3, 8, 3, 2, 7, 9, 5, 1, 2, 8, 8, 4, 1, 9, 7]
 
+    #: ``paged``: the classic block with the prefix cache; ``kinds``: two
+    #: kinds of paged cache; and what else a lane may hold that goes down
+    #: with the storage: rows of a ``latent`` pool, a slot of recurrent
+    #: state (``linear``), rows of the drafting module's pool (``mtp``)
+    LAYOUTS = ["paged", "kinds", "latent", "linear", "mtp"]
+
     @staticmethod
     def _engine(layout, **extra):
-        """The engine of ``layout``: ``paged`` and ``contiguous`` with
-        the prefix cache, ``kinds`` paged over two kinds of cache."""
-        if layout == "kinds":
-            return _paged_engine("two", "kv_lost", slots=2, paged_kv=True,
-                                 **extra)
+        if layout != "paged":
+            return _paged_engine({"kinds": "two"}.get(layout, layout),
+                                 "kv_lost_" + layout, slots=2,
+                                 paged_kv=True, **extra)
         from veles_tpu.serving import LMEngine
-        features = {"prefill_chunk": 8, "prefix_cache": 32}
-        if layout == "paged":
-            features["paged_kv"] = True
         return LMEngine(_params(), n_heads=2, max_len=96, slots=2,
-                        name="kv_lost", **features, **extra)
+                        name="kv_lost", paged_kv=True, prefill_chunk=8,
+                        prefix_cache=32, **extra)
+
+    @staticmethod
+    def _served(layout, engine, prompt, out, n_new):
+        _served({"paged": "one", "kinds": "two"}.get(layout, layout),
+                engine, prompt, out, n_new)
 
     @staticmethod
     def _consuming(engine, attr, ready):
@@ -453,7 +479,7 @@ class TestStorageLost:
         setattr(engine, attr, stub)
         return fired
 
-    @pytest.mark.parametrize("layout", ["paged", "contiguous", "kinds"])
+    @pytest.mark.parametrize("layout", LAYOUTS)
     @pytest.mark.parametrize("path", ["decode", "chunk"])
     def test_consumed_storage_fails_holders_and_rebuilds(self, layout,
                                                          path):
@@ -469,11 +495,11 @@ class TestStorageLost:
                 ln is not None and not ln.pending and ln.emitted
                 for ln in lanes))
         try:
-            # something for the trie to hold (and, paged, pages with it)
+            # something for the trie to hold, and pages with it
             seed_prompt = self.LONG[:20]
-            assert_greedy(engine, seed_prompt,
-                          engine.submit(seed_prompt, 3).result(timeout=120), 3)
-            assert layout == "kinds" or engine._trie.size >= 2
+            self._served(layout, engine, seed_prompt,
+                         engine.submit(seed_prompt, 3).result(timeout=120), 3)
+            assert layout != "paged" or engine._trie.size >= 2
             fa = engine.submit([1, 2, 3], 30)        # decodes
             fb = engine.submit(self.LONG[::-1], 4)   # prefills, 5 chunks
             fc = engine.submit([2, 4, 6, 8], 6)      # queued: no slot
@@ -482,22 +508,22 @@ class TestStorageLost:
                     f.result(timeout=120)
             assert fired
             # the queued request held nothing: served token for token
-            assert_greedy(engine, [2, 4, 6, 8], fc.result(timeout=120), 6)
+            self._served(layout, engine, [2, 4, 6, 8],
+                         fc.result(timeout=120), 6)
             assert engine.metrics.counter("kv_storage_rebuilds") == 1
             # dropped with the rows
-            assert layout == "kinds" or engine._trie.size == 0
+            assert layout != "paged" or engine._trie.size == 0
             leaves = [a for pair in engine._storage() for a in pair]
             assert not any(a.is_deleted() for a in leaves)
             # and the next one, through the fresh storage, as well
-            assert_greedy(engine, self.LONG,
-                          engine.submit(self.LONG, 5).result(timeout=120), 5)
+            self._served(layout, engine, self.LONG,
+                         engine.submit(self.LONG, 5).result(timeout=120), 5)
             assert engine.metrics.counter("kv_storage_rebuilds") == 1
         finally:
             engine.stop()
-        if layout != "contiguous":
-            # the allocator is whole: what is not free, the trie holds
-            # for the prompts served since
-            _home_whole(engine)
+        # the allocator is whole: what is not free, the trie holds
+        # for the prompts served since
+        _home_whole(engine)
         if layout == "paged":
             engine._trie.clear()
             assert engine._pool.free_pages == engine._pool.num_pages
@@ -537,7 +563,7 @@ class TestStorageLost:
         finally:
             engine.stop()
 
-    @pytest.mark.parametrize("layout", ["paged", "contiguous", "kinds"])
+    @pytest.mark.parametrize("layout", LAYOUTS)
     @pytest.mark.parametrize("site", ["engine.step", "engine.chunk"])
     def test_injected_fault_keeps_todays_behaviour(self, layout, site):
         """An injected fault fires BEFORE the program is called: the
@@ -561,10 +587,10 @@ class TestStorageLost:
                 else (fb, fa, [1, 2, 3]))
             with pytest.raises(InjectedFault):
                 failed.result(timeout=120)
-            assert_greedy(engine, prompt, survivor.result(timeout=120),
-                          30 if survivor is fa else 4)
+            self._served(layout, engine, prompt,
+                         survivor.result(timeout=120),
+                         30 if survivor is fa else 4)
             assert engine.metrics.counter("kv_storage_rebuilds") == 0
         finally:
             engine.stop()
-        if layout != "contiguous":
-            engine.verify_pool_invariants()
+        engine.verify_pool_invariants()

@@ -446,11 +446,20 @@ def test_a_lane_holds_state_for_six_layers_and_pages_of_one_pool(weights):
 
 @pytest.mark.parametrize("option, match", [
     ({"spec_k": 2}, "spec_k"), ({"prefix_cache": 4}, "prefix_cache"),
-    ({"megastep": 2}, "megastep"), ({"tp": 2}, "tp >= 2"),
-    ({"paged_kv": 0}, "needs paged_kv")])
+    ({"megastep": 2}, "megastep"), ({"tp": 2}, "tp >= 2")])
 def test_what_was_not_widened_says_so(weights, option, match):
     with pytest.raises(ValueError, match=match):
         engine(weights[1], **option)
+
+
+def test_the_default_pool_is_every_lanes_whole_table(weights):
+    """``paged_kv`` 0 (the default) names no other layout: the pool then
+    holds every lane's whole table, ``slots x max_len / page`` pages."""
+    eng = engine(weights[1], paged_kv=0)
+    assert eng._pool.num_pages == 4 * 128 // 16
+    assert eng._page_tables.shape == (4, 128 // 16)
+    # and a slot of state a lane beside it
+    assert eng.metrics.snapshot()["gauges"]["state_slots_total"] == 4
 
 
 # -------------------------------------------------------------- the record

@@ -212,7 +212,8 @@ class TestFullTree:
         assert stats["guarded_attrs"] >= 50
         assert stats["module_globals"] >= 2
         assert stats["traced_functions"] >= 40
-        assert stats["census_sites"] >= 10
+        # (chunk, step, page_copy, verify, megastep: the engine's five)
+        assert stats["census_sites"] >= 5
         assert stats["hot_path_methods"] >= 12
         assert stats["lifecycle_sites"] >= 1
         # the shared-parse satellite: one ast.parse per file, under

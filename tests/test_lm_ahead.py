@@ -20,7 +20,6 @@ dispatch in flight, and the two counters of the mechanism.
 
 No case asserts a duration: only order, counts, identities and tokens."""
 
-import functools
 import threading
 
 import numpy
@@ -29,110 +28,11 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from lm_cases import _params, assert_greedy, kinds_model
+from lm_cases import (ROUND, assert_greedy, check_tokens, counters,
+                      follows_a_step, make_engine, pipeline_balances, serve,
+                      stamps_of, tokens, vocab_of)
 from veles_tpu import model_config
 from veles_tpu.serving import tracing
-
-#: (prompt length, n_new) of a round: more requests than lanes, prompts of
-#: one to four chunks of 8, answers that end while others prefill — and an
-#: answer of ONE token (its tail chunk's first token is its last) and of two
-#: (freed by count under its only step)
-ROUND = [(5, 9), (19, 6), (3, 1), (26, 12), (9, 2), (12, 7), (30, 5)]
-
-
-def tokens(n, seed, vocab):
-    return numpy.random.default_rng(seed).integers(0, vocab, n)
-
-
-@functools.lru_cache(maxsize=None)
-def _model(kind):
-    """(record, the engine's float32 weights, checker(prompt, out))."""
-    if kind == "pre_ln":
-        params = _params(max_len=64)
-        return 2, params, None
-    if kind == "window":
-        record, params = kinds_model()
-        return record, params, None
-    if kind == "latent":
-        import test_xing4 as small
-        from benchmark.reference import xing4 as reference
-    else:
-        import test_qwen3_next as small
-        from benchmark.reference import qwen3_next as reference
-    w = reference.make_weights(3, small.SMALL)
-
-    def check(prompt, out):
-        seq = numpy.concatenate([prompt, out])
-        ref = reference.logits(
-            w, seq, numpy.arange(len(prompt) - 1, len(seq) - 1), small.SMALL)
-        gap = ref.max(-1) - ref[numpy.arange(len(out)), out]
-        assert float(gap.max()) <= 1e-4, gap
-    return (small.record(), jax.tree.map(lambda a: a.astype(jnp.float32), w),
-            check)
-
-
-def make_engine(kind="pre_ln", name="ahead", **over):
-    from veles_tpu.serving import LMEngine, ServingMetrics
-    record, params, _ = _model(kind)
-    kw = dict(max_len=64, slots=3, paged_kv=True, prefill_chunk=8,
-              metrics=ServingMetrics(name), name=name)
-    kw.update(over)
-    return LMEngine(params, record, **kw)
-
-
-def check_tokens(kind, engine, prompt, out, n_new):
-    check = _model(kind)[2]
-    out = numpy.asarray(out)
-    assert out.shape == (n_new,)
-    if check is None:
-        assert_greedy(engine, prompt, out, n_new)
-    else:
-        check(numpy.asarray(prompt), out)
-
-
-def vocab_of(engine):
-    return int(engine.params["embed"].shape[0])
-
-
-def serve(engine, round_=ROUND, seed=40):
-    """Start, serve one round (all submitted at once), stop: the prompts
-    and the served continuations."""
-    engine.start()
-    try:
-        prompts = [tokens(n, seed + i, vocab_of(engine))
-                   for i, (n, _) in enumerate(round_)]
-        futures = [engine.submit(p, n_new)
-                   for p, (_, n_new) in zip(prompts, round_)]
-        outs = [f.result(timeout=300) for f in futures]
-    finally:
-        engine.stop()
-    return prompts, outs
-
-
-def counters(engine):
-    return engine.metrics.snapshot()["counters"]
-
-
-def pipeline_balances(engine):
-    """Idle, nothing is left in flight, and every decode dispatch was either
-    followed by one sent ahead of its fetch or drained (ISSUE 39)."""
-    c = counters(engine)
-    assert not engine._flights and engine._older == 0
-    assert c.get("dispatches_sent_ahead", 0) + c.get("pipeline_drains", 0) \
-        == c["decode_dispatches"]
-    return c
-
-
-def stamps_of(turns):
-    return turns[:, tracing.COL_STAMPS:tracing.COL_END + 1]
-
-
-def follows_a_step(turns):
-    """Bool per turn: the turn before dispatched a decode program (its step
-    was in flight while this one was prepared)."""
-    step = turns[:, tracing.COL_STEP_PROGRAM] > 0
-    return numpy.concatenate([[False], step[:-1]])
-
 
 # ------------------------------------------------------- (a) the tokens
 @pytest.mark.parametrize("kind", ["pre_ln", "window", "latent", "linear"])
@@ -157,98 +57,6 @@ def test_the_reordered_loop_serves_the_references_tokens(kind):
     # the one-token answer ends at its tail chunk, after the step that took
     # its lane for a decoding one was prepared: that one preparation goes
     assert c.get("ahead_discarded", 0) <= 1
-
-
-# ------------------------------------------------------ (b) a turn's row
-DRIVERS = {
-    "plain": dict(),
-    "plain_window": dict(kind="window"),
-    "speculative": dict(spec_k=2),
-    "megastep": dict(megastep=4),
-    "contiguous": dict(paged_kv=0),
-}
-
-
-@pytest.mark.parametrize("driver", sorted(DRIVERS))
-def test_a_turns_row_keeps_its_shape(driver):
-    """What ``benchmark/lib/spans.py`` relies on, per driver: stamps never
-    go back, turns leave no hole, a row holds at most one chunk and one
-    step with ``prefill.dispatch`` <= ``step.dispatch`` <= ``step.emit``,
-    every phase has its name in ``tracing.PHASES``; the ``ahead.*`` phases
-    are empty where no step is in flight and for every driver that cannot
-    split its turn; token stamps number ``n_new`` a request and sum to
-    ``tokens_out``."""
-    kw = dict(DRIVERS[driver])
-    kind = kw.pop("kind", "pre_ln")
-    round_ = [(n, max(n_new, 2)) for n, n_new in ROUND]
-    engine = make_engine(kind, name="row_" + driver, **kw)
-    prompts, outs = serve(engine, round_)
-    for p, o, (_, n_new) in zip(prompts, outs, round_):
-        check_tokens(kind, engine, p, o, n_new)
-    rec, c = engine.recorder, counters(engine)
-    turns = rec.turns()
-    s = stamps_of(turns)
-    assert (numpy.diff(s, axis=1) >= 0).all()
-    assert (s[1:, 0] == s[:-1, -1]).all()
-    assert turns[:, tracing.COL_SEQ].tolist() == list(range(1, len(turns) + 1))
-    assert len(tracing.PHASES) == s.shape[1] - 1
-    assert tracing.PHASES.index("step.dispatch") \
-        < tracing.PHASES.index("ahead.emit") \
-        < tracing.PHASES.index("ahead.admit") \
-        < tracing.PHASES.index("ahead.prepare") \
-        < tracing.PHASES.index("step.fetch") \
-        < tracing.PHASES.index("step.emit")
-    assert {e["name"] for e in rec.chrome_events(1, last=len(turns))
-            if e["ph"] == "X" and e["tid"] == 1} <= set(tracing.PHASES)
-    step = turns[:, tracing.COL_STEP_PROGRAM] > 0
-    chunk = turns[:, tracing.COL_PREFILL_PROGRAM] > 0
-    assert int(step.sum()) == c["decode_dispatches"]
-    assert int(chunk.sum()) == c.get("prefill_dispatches", 0)
-    assert (s[:, tracing.PREFILL_DISPATCH] <= s[:, tracing.STEP_DISPATCH]).all()
-    assert (s[:, tracing.STEP_DISPATCH] <= s[:, tracing.STEP_EMIT]).all()
-    ahead = s[:, tracing.STEP_FETCH] - s[:, tracing.AHEAD_EMIT]
-    assert not ahead[~step].any()
-    splits = driver.startswith("plain")
-    rows = rec.dispatches()
-    of_step = rows[:, tracing.DCOL_PHASE] == tracing.STEP_DISPATCH
-    late = rows[:, tracing.DCOL_FETCH_TURN] - rows[:, tracing.DCOL_TURN]
-    assert (rows[:, tracing.DCOL_FETCHED] > 0)[of_step].all()
-    if splits:
-        # ISSUE 39: a step whose follower was sent ahead of its fetch is
-        # fetched in the follower's turn, a step that was drained in its own
-        c = pipeline_balances(engine)
-        assert int((late[of_step] == 1).sum()) \
-            == c["dispatches_sent_ahead"] > c["decode_dispatches"] // 2
-        assert int((late[of_step] == 0).sum()) == c["pipeline_drains"]
-        # and a tail chunk's token rides with the step behind it
-        assert set(late[~of_step & (rows[:, tracing.DCOL_FETCHED] > 0)]
-                   .tolist()) <= {0, 1}
-    else:
-        assert not late[rows[:, tracing.DCOL_FETCHED] > 0].any()
-        assert "dispatches_sent_ahead" not in c and "pipeline_drains" not in c
-    if splits:
-        assert (ahead[step] > 0).all()
-        # a turn prepared under the step before skips admission and the
-        # chunk's preparation: the tick runs into the first dispatch
-        made = follows_a_step(turns) & step
-        assert int(made.sum()) == c["turns_prepared_ahead"] > 0
-        assert (s[made, tracing.ADMIT]
-                == s[made, tracing.PREFILL_DISPATCH]).all()
-        assert (s[made & ~chunk, tracing.ADMIT]
-                == s[made & ~chunk, tracing.STEP_PREPARE]).all()
-    else:
-        assert not ahead.any()
-        assert "turns_prepared_ahead" not in c
-    assert c.get("ahead_discarded", 0) == 0
-    reqs = rec.requests()
-    assert len(reqs) == len(round_)
-    for r, (_, n_new) in zip(sorted(reqs, key=lambda r: r.enqueue), round_):
-        assert r.outcome == "ok"
-        assert r.tokens_out == len(r.token_ns) == r.n_new == n_new
-        assert list(r.token_ns) == sorted(r.token_ns)
-        assert r.enqueue <= r.admit <= r.first_token <= r.done
-    assert sum(r.tokens_out for r in reqs) == c["tokens_out"] \
-        == int(turns[:, tracing.COL_TOKENS].sum())
 
 
 @pytest.mark.parametrize("kind", ["pre_ln", "window", "latent", "linear"])

@@ -43,16 +43,7 @@ class TestFastPathParity:
             for p, f, exp in zip(prompts, futures, expected):
                 got = numpy.concatenate([p, f.result(timeout=120)])
                 numpy.testing.assert_array_equal(got, exp)
-            # without chunking, whole-prompt prefill legitimately owns
-            # one program per power-of-two bucket (incl. the warmup's);
-            # with chunking, the chunk program replaces them all
-            if features.get("prefill_chunk"):
-                buckets = 1
-            else:
-                from veles_tpu.serving import prompt_bucket
-                buckets = len({prompt_bucket(n, 96)
-                               for n in [1] + [len(p) for p in prompts]})
-            jit_guard(engine, prefill_buckets=buckets)
+            jit_guard(engine)
             if features.get("tp") and features.get("attn_kernel"):
                 # kernels under a tp mesh are a structural fallback —
                 # the XLA path must have served (and metered) every

@@ -48,28 +48,6 @@ class TestAttnKernelRouting:
         finally:
             engine.stop()
 
-    def test_contiguous_geometry_falls_back(self):
-        """attn_kernel on a CONTIGUOUS engine is an unsupported
-        geometry — fallback with a reason naming paged_kv, never an
-        error, and the serving output stays exactly greedy."""
-        from veles_tpu.serving import LMEngine
-        params = _params()
-        engine = LMEngine(params, n_heads=2, max_len=96, slots=1,
-                          prefill_chunk=8, attn_kernel="force",
-                          name="ak_contig").start()
-        try:
-            assert not engine._kernel_active
-            assert "paged_kv" in engine._kernel_fallback_reason
-            got = numpy.concatenate(
-                [[7, 7, 7], engine.submit([7, 7, 7], 4).result(
-                    timeout=60)])
-            numpy.testing.assert_array_equal(
-                got, _greedy(params, [7, 7, 7], 4, 96))
-            c = engine.metrics.snapshot()["counters"]
-            assert c["attn_kernel_fallbacks"] > 0
-        finally:
-            engine.stop()
-
     @pytest.mark.parametrize("model", ["pre_ln", "kinds"])
     def test_force_counts_kernel_dispatches(self, model):
         """'force' on CPU runs the interpret-mode kernels for real:

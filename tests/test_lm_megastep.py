@@ -44,13 +44,7 @@ class TestMegastep:
             for p, f, exp in zip(prompts, futures, expected):
                 got = numpy.concatenate([p, f.result(timeout=300)])
                 numpy.testing.assert_array_equal(got, exp)
-            if features.get("prefill_chunk"):
-                buckets = 1
-            else:
-                from veles_tpu.serving import prompt_bucket
-                buckets = len({prompt_bucket(n, 96)
-                               for n in [1] + [len(p) for p in prompts]})
-            jit_guard(engine, prefill_buckets=buckets)
+            jit_guard(engine)
             if K >= 2:
                 c = engine.metrics.snapshot()["counters"]
                 assert c["megastep_dispatches"] >= 1
@@ -109,11 +103,7 @@ class TestMegastep:
             engine._megastep_jit = real
             engine.stop()
 
-    @pytest.mark.parametrize("layout", [
-        {}, {"paged_kv": True, "prefill_chunk": 8}],
-        ids=["contiguous", "paged"])
-    def test_fault_inside_megastep_fails_exactly_active_lanes(self,
-                                                              layout):
+    def test_fault_inside_megastep_fails_exactly_active_lanes(self):
         """CHAOS: an engine.step fault injected into the fused
         dispatch fails the lanes that were IN that megastep — and only
         them; the queued request decodes exactly greedy afterwards,
@@ -128,7 +118,8 @@ class TestMegastep:
         tracer = SpanTracer(mode="all", last=16)
         engine = LMEngine(params, n_heads=2, max_len=96, slots=1,
                           megastep=4, faults=plan, tracer=tracer,
-                          name="ms_chaos", **layout).start()
+                          name="ms_chaos", paged_kv=True,
+                          prefill_chunk=8).start()
         try:
             fa = engine.submit([1, 2, 3], 6)
             fb = engine.submit([2, 4, 6, 8], 6)
@@ -146,8 +137,7 @@ class TestMegastep:
             assert any(s["name"] == "decode.megastep"
                        and "error" in s["attrs"]
                        for s in errs[0]["spans"])
-            if layout:
-                assert engine.verify_pool_invariants()["used_pages"] == 0
+            assert engine.verify_pool_invariants()["used_pages"] == 0
         finally:
             engine.stop()
 
@@ -302,7 +292,7 @@ class TestShardedDecode:
                           name="dev_pin").start()
         try:
             assert list(engine.params["embed"].devices()) == [dev]
-            assert list(engine._caches[0][0].devices()) == [dev]
+            assert list(engine._kv_pools[0][0].devices()) == [dev]
             got = numpy.concatenate(
                 [[5, 6, 7], engine.submit([5, 6, 7], 4).result(
                     timeout=60)])

@@ -1,5 +1,5 @@
-"""The paged KV layout through the engine (ISSUE 6) and the storage every
-program takes donated (ISSUE 27): zero-copy prefix sharing, the paged
+"""The paged KV pool through the engine (ISSUE 6) and the storage every
+program takes donated (ISSUE 27): zero-copy prefix sharing, the
 compile bound, pool pressure (queue, shed, never a hang), long-context
 options through the page table, and every dispatch consuming the arrays
 that went into it.  Split from ``test_lm_fastpath.py`` (PR 30)."""
@@ -7,31 +7,49 @@ that went into it.  Split from ``test_lm_fastpath.py`` (PR 30)."""
 import numpy
 import pytest
 
+from veles_tpu import model_config
 from lm_cases import (IN_PLACE_SETS, _greedy, _params,  # noqa: F401
-                      assert_greedy, jit_guard, served_model)
+                      assert_greedy, check_tokens, jit_guard, make_engine,
+                      served_model)
 
 
 class TestStorageInPlace:
     """ISSUE 27: every engine program that returns the KV storage takes
     it DONATED — the arrays that go into a dispatch are consumed by it
     (no dispatch copies a pool or holds a second one), and the tokens
-    are what they were."""
+    are what they were.  Every pool AND every slot of state: a latent
+    layer's one pool, a linear layer's (state, convolution tail), the
+    drafting module's pool behind the stack's."""
 
     @staticmethod
     def _leaves(engine):
         return [a for pair in engine._storage() for a in pair]
 
     @pytest.mark.parametrize("features", IN_PLACE_SETS,
-                             ids=lambda f: "+".join(sorted(f)) or "off")
+                             ids=lambda f: f.get("kind")
+                             or "+".join(sorted(f)))
     def test_dispatches_consume_their_storage(self, features,
                                               serving_mesh):
         from veles_tpu.serving import LMEngine
         if features.get("tp"):
             serving_mesh(features["tp"])
-        params = _params()
-        engine = LMEngine(params, n_heads=2, max_len=96, slots=2,
-                          name="in_place", **features)
+        features = dict(features)
+        kind = features.pop("kind", "pre_ln")
+        if kind == "pre_ln":
+            params = _params()
+            engine = LMEngine(params, n_heads=2, max_len=96, slots=2,
+                              name="in_place", **features)
+        else:
+            engine = make_engine(kind, name="in_place_" + kind, slots=2,
+                                 **features)
         made = self._leaves(engine)
+        # (k, v) pools, or (state, tail), or one pool of latent rows a
+        # layer, the module's own layer counted where it drafts
+        cfg = engine.cfg
+        assert len(made) == sum(
+            1 if cfg.latent is not None
+            and cfg.kind(i) != model_config.LINEAR else 2
+            for i in range(engine._n_pools()))
         assert not any(a.is_deleted() for a in made)
         engine.start()
         try:
@@ -55,10 +73,13 @@ class TestStorageInPlace:
 
             setattr(engine, name, watched)
             prompt = [5, 1, 5, 1, 5, 1, 5, 1, 5, 2, 3]
-            got = numpy.concatenate(
-                [prompt, engine.submit(prompt, 9).result(timeout=120)])
-            numpy.testing.assert_array_equal(
-                got, _greedy(params, prompt, 9, 96))
+            got = engine.submit(prompt, 9).result(timeout=120)
+            if kind == "pre_ln":
+                numpy.testing.assert_array_equal(
+                    numpy.concatenate([prompt, got]),
+                    _greedy(params, prompt, 9, 96))
+            else:
+                check_tokens(kind, engine, prompt, got, 9)
             assert handed, "no decode dispatch ran"
             assert all(a.is_deleted() for a in warm)
             for leaves in handed:
@@ -70,33 +91,6 @@ class TestStorageInPlace:
         finally:
             engine.stop()
 
-    def test_reading_programs_do_not_donate(self):
-        """``chunk_extract`` only READS the caches and ``prefill`` never
-        sees them: neither may consume anything — and the parameters go
-        into every program and stay."""
-        import jax
-        from veles_tpu.serving import LMEngine
-        params = _params()
-        engine = LMEngine(params, n_heads=2, max_len=96, slots=2,
-                          prefill_chunk=8, prefix_cache=32,
-                          name="in_place_ro").start()
-        try:
-            p = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9, 7, 9, 3, 2, 3]
-            for _ in range(2):           # the second one hits the trie
-                got = numpy.concatenate(
-                    [p, engine.submit(p, 5).result(timeout=120)])
-                numpy.testing.assert_array_equal(
-                    got, _greedy(params, p, 5, 96))
-            assert engine.metrics.counter("prefix_hit_chunks") >= 1
-            # the trie's rows came out of chunk_extract and are alive
-            node = next(iter(engine._trie.root.children.values()))
-            assert not any(a.is_deleted()
-                           for pair in node.rows for a in pair)
-            assert not any(a.is_deleted()
-                           for a in jax.tree.leaves(engine.params))
-        finally:
-            engine.stop()
-
 
 class TestPagedKV:
     """ISSUE 6 acceptance: zero-copy prefix sharing, the paged compile
@@ -104,9 +98,8 @@ class TestPagedKV:
 
     def test_shared_prefix_zero_copy(self):
         """ACCEPTANCE: 8 requests sharing a 40-token system prompt
-        under paged_kv — every shared-prefix hit installs a page
-        REFERENCE (kv_pages_referenced >= 7 requests × 5 chunks), the
-        row-copy counter stays at ZERO on the pure-hit path, no
+        — every shared-prefix hit installs a page
+        REFERENCE (kv_pages_referenced >= 7 requests × 5 chunks), no
         copy-on-write fires (appends land past the prompt), and every
         reply is bit-identical to the per-request greedy generate."""
         from veles_tpu.serving import LMEngine
@@ -126,7 +119,6 @@ class TestPagedKV:
                     [p, engine.submit(p, 4).result(timeout=60)])
                 numpy.testing.assert_array_equal(got, exp)
             c = engine.metrics.snapshot()["counters"]
-            assert c.get("kv_row_copies", 0) == 0, c
             assert c.get("kv_cow_copies", 0) == 0, c
             assert c["kv_pages_referenced"] >= 7 * (len(shared) // C), c
             assert c["prefix_hit_tokens"] >= 7 * len(shared) // C * C

@@ -1410,11 +1410,10 @@ class TestPagedLatentPrefill:
 class TestServingKernelSupport:
     def test_structural_checks(self):
         from veles_tpu.ops import pallas_kernels as PK
-        assert PK.serving_kernels_supported(True, 4, 2, 16, 8) \
-            == (True, None)
-        ok, reason = PK.serving_kernels_supported(False, 4, 2, 16, 8)
-        assert not ok and "paged_kv" in reason
-        ok, reason = PK.serving_kernels_supported(True, 4, 3, 16, 8)
+        assert PK.serving_kernels_supported(4, 2, 16, 8) == (True, None)
+        ok, reason = PK.serving_kernels_supported(4, 2, 16, 8, tp=2)
+        assert not ok and "tensor-parallel" in reason
+        ok, reason = PK.serving_kernels_supported(4, 3, 16, 8)
         assert not ok and "divisible" in reason
 
     @pytest.mark.parametrize("kv,dh,pack", [

@@ -142,13 +142,10 @@ class TestMicroBatcher:
         assert (out == 1).all()
 
     def test_bucket_ladder(self):
-        from veles_tpu.serving import batch_buckets, prompt_bucket
+        from veles_tpu.serving import batch_buckets
         assert batch_buckets(8) == [1, 2, 4, 8]
         assert batch_buckets(6) == [1, 2, 4, 6]
         assert batch_buckets(1) == [1]
-        assert prompt_bucket(3, 64) == 16
-        assert prompt_bucket(17, 64) == 32
-        assert prompt_bucket(40, 48) == 48      # capped at the cache
 
 
 class TestBatchedHTTP:
@@ -492,11 +489,11 @@ class TestLMEngine:
         real_step = engine._step_jit
         calls = {"n": 0}
 
-        def flaky_step(p, caches, last, pos):
+        def flaky_step(*args):
             calls["n"] += 1
             if calls["n"] == 1:
                 raise RuntimeError("injected device fault")
-            return real_step(p, caches, last, pos)
+            return real_step(*args)
 
         engine._step_jit = flaky_step
         try:
@@ -1278,7 +1275,7 @@ class TestResilience:
     def test_probe_warm_absorbs_first_compile(self):
         """Satellite (ISSUE 11): warm_probes() runs each replica's
         first synthetic probe with a generous budget BEFORE monitoring
-        starts, so a slow first-compile of the probe's prompt bucket
+        starts, so a slow first dispatch of the probe's prompt chunk
         (the foot-gun the HealthChecker docstring warns about) can
         never count as a failed probe and walk an innocent replica
         toward quarantine."""
@@ -1286,9 +1283,9 @@ class TestResilience:
         params = _tiny_params()
         engine = LMEngine(params, n_heads=2, max_len=48, slots=1,
                           name="warm_r0").start()
-        # emulate a slow first probe-bucket compile: the FIRST prefill
+        # emulate a slow first compile: the FIRST prompt-chunk
         # dispatch after start stalls well past the probe timeout
-        real = engine._prefill_jit
+        real = engine._chunk_jit
         state = {"first": True}
 
         def slow_first(*a):
@@ -1297,7 +1294,7 @@ class TestResilience:
                 time.sleep(0.6)
             return real(*a)
 
-        engine._prefill_jit = slow_first
+        engine._chunk_jit = slow_first
         router = Router([engine])
         checker = HealthChecker(router, interval_s=0.05,
                                 probe_timeout_s=0.25,
@@ -1692,11 +1689,11 @@ class TestWeightSwap:
                     and time.monotonic() < deadline:
                 time.sleep(0.01)
             assert replicas[0].weights_version == 1
-            # NOW the canary goes bad: every prefill faults, so the
+            # NOW the canary goes bad: every prompt chunk faults, so the
             # checker's synthetic 1-token probe dies — step()
             # (synchronous) walks it to quarantine, and the deploy's
             # watch sees the circuit
-            plan.arm("engine.prefill", kind="error")
+            plan.arm("engine.chunk", kind="error")
             deadline = time.monotonic() + 60
             while router._live[0] and time.monotonic() < deadline:
                 checker.step()

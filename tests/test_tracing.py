@@ -13,7 +13,7 @@ import pytest
 
 import jax.numpy as jnp
 
-from lm_cases import assert_greedy, kinds_model
+from lm_cases import assert_greedy, check_tokens, kinds_model, make_engine
 from veles_tpu import prng
 from veles_tpu.ops.transformer import generate, init_transformer_params
 
@@ -622,8 +622,15 @@ class TestLoopRecorder:
         "plain_kinds": dict(prefill_chunk=8, paged_kv=True, kinds=True),
     }
 
-    def _engine(self, name="rec_t", kinds=False, **kw):
+    #: and the plain one over what else a lane may hold
+    #: (``lm_cases.make_engine``): a pool of latent rows, a slot of
+    #: recurrent state, the drafting module's pool and two tokens a step
+    KINDS = ["plain_latent", "plain_linear", "plain_mtp"]
+
+    def _engine(self, name="rec_t", kinds=False, kind=None, **kw):
         from veles_tpu.serving import LMEngine, ServingMetrics
+        if kind is not None:
+            return make_engine(kind, name=name, slots=2, **kw)
         record, params = kinds_model() if kinds else (2, tiny_params())
         return LMEngine(params, record, max_len=64, slots=2,
                         metrics=ServingMetrics(name), name=name, **kw)
@@ -814,26 +821,29 @@ class TestLoopRecorder:
             assert 0 <= r.lane < engine.slots
         assert sum(r.tokens_out for r in reqs) == counters["tokens_out"]
 
-    @pytest.mark.parametrize("driver", sorted(DRIVERS) + ["contiguous"])
+    @pytest.mark.parametrize("driver", sorted(DRIVERS) + KINDS)
     def test_every_dispatch_has_a_record_of_its_own(self, driver):
-        """ISSUE 38, per decode driver and for the chunked contiguous
-        layout: one dispatch record per call of a jitted program (they
-        number the two dispatch counters; the contiguous layout's
-        whole-prompt prefill at admission has no phase and no record),
+        """ISSUE 38, per decode driver and per kind of engine: one
+        dispatch record per call of a jitted program (they
+        number the two dispatch counters),
         numbered in call order; its stamps never go back; a decode
         dispatch and a tail chunk were waited for and fetched in the turn
         that called them, a chunk that is no tail never; and each names
         the turn whose row holds its program and its call's stamp.
-        ISSUE 39: the paged plain driver fetches one dispatch late, so
+        ISSUE 39: the plain driver fetches one dispatch late, so
         its records say the turn after (``DCOL_FETCH_TURN``) wherever the
         next step was called first, and a dispatch is no longer over
         before the next is called; the waits are still made in call
         order, inside the ``step.fetch`` of the turn that made them."""
         from veles_tpu.serving import tracing as t
-        kw = self.DRIVERS.get(driver, dict(prefill_chunk=8))
-        engine, outs = self._serve(**kw)
+        kind = driver[len("plain_"):] if driver in self.KINDS else None
+        engine, outs = self._serve(**(self.DRIVERS.get(driver)
+                                      or dict(kind=kind)))
         for p, out in zip(self.PROMPTS, outs):
-            assert_greedy(engine, p, out, self.N_NEW)
+            if kind is None:
+                assert_greedy(engine, p, out, self.N_NEW)
+            else:
+                check_tokens(kind, engine, p, out, self.N_NEW)
         rec = engine.recorder
         counters = engine.metrics.snapshot()["counters"]
         rows, turns = rec.dispatches(), rec.turns()
@@ -1136,8 +1146,9 @@ class TestLoopRecorder:
         assert r.enqueue <= r.done and r.lane == -1
 
     @pytest.mark.parametrize("layout", [
-        {}, {"prefill_chunk": 8, "paged_kv": True}],
-        ids=["contiguous", "paged"])
+        {"prefill_chunk": 8, "paged_kv": True}, {"kind": "latent"},
+        {"kind": "linear"}, {"kind": "mtp"}],
+        ids=["paged", "latent", "linear", "mtp"])
     def test_failed_request_leaves_its_outcome(self, layout):
         from veles_tpu.serving import FaultPlan, InjectedFault
         plan = FaultPlan(seed=0).arm("engine.step", kind="error",
@@ -1287,7 +1298,7 @@ class TestLoopRecorder:
                 and e["tid"] == tracks["engine loop rec_http"]]
         assert {e["name"] for e in loop} <= set(tracing.PHASES)
         assert any(e["name"] == "step.dispatch"
-                   and e["args"]["program"] == "step_one" for e in loop)
+                   and e["args"]["program"] == "step_all" for e in loop)
         # ISSUE 38: the dispatches of those turns on a track of their own,
         # a slice each from the call to the fetch, named by program
         sent = [e for e in trace["traceEvents"] if e["ph"] == "X"
@@ -1295,14 +1306,15 @@ class TestLoopRecorder:
         assert tracks["engine loop rec_http dispatches"] \
             == tracks["engine loop rec_http"] + 1
         assert {e["name"] for e in sent} <= set(engine.recorder.programs[1:])
-        assert any(e["name"] == "step_one" for e in sent)
+        assert any(e["name"] == "step_all" for e in sent)
         shown = {e["args"]["turn"] for e in loop}
         for e in sent:
             assert set(e["args"]) == {"dispatch", "turn", "fetch_turn",
                                       "lanes", "tokens"}
             assert e["args"]["turn"] in shown and e["dur"] > 0
-            if e["name"] == "step_one":
-                assert e["args"]["fetch_turn"] == e["args"]["turn"]
+            if e["name"] == "step_all":
+                # (fetched in its own turn, a drain, or one dispatch late)
+                assert e["args"]["fetch_turn"] - e["args"]["turn"] in (0, 1)
                 assert e["args"]["lanes"] == 1
                 # over the phases of its turn from step.dispatch on
                 inside = [p for p in loop

@@ -281,13 +281,24 @@ def test_dispatches_consume_the_latent_pools(weights):
     # (spec_k is refused no longer: ISSUE 40, tests/test_joyai.py; a model
     # without a module takes the module's option for what it says)
     ({"spec_k": 2, "megastep": 2}, "megastep"),
-    ({"megastep": 4}, "megastep"), ({"tp": 2}, "tp >= 2"),
-    ({"paged_kv": 0}, "latent attention needs paged_kv")])
+    ({"megastep": 4}, "megastep"), ({"tp": 2}, "tp >= 2")])
 def test_what_was_not_widened_says_so(weights, option, match):
     from veles_tpu.serving import LMEngine
     with pytest.raises(ValueError, match=match):
         LMEngine(weights[1], record(), max_len=64, slots=2,
                  **dict({"paged_kv": 24, "prefill_chunk": PAGE}, **option))
+
+
+def test_the_default_pool_is_every_lanes_whole_table(weights):
+    """``paged_kv`` 0 (the default) names no other layout: the pool then
+    holds every lane's whole table, ``slots x max_len / page`` pages."""
+    from veles_tpu.serving import LMEngine
+    eng = LMEngine(weights[1], record(), max_len=64, slots=2,
+                   prefill_chunk=PAGE)
+    assert eng._pool.num_pages == 2 * 64 // PAGE
+    assert eng._page_tables.shape == (2, 64 // PAGE)
+    # ONE pool of latent rows a layer
+    assert all(len(layer) == 1 for layer in eng._storage())
 
 
 def test_the_contiguous_cached_path_refuses_latent(weights):

@@ -13,13 +13,11 @@ numbers docs/PERF.md records:
   -friendly shape: templated text, code, logs), measuring decode
   dispatches per generated token and tokens/s.
 
-The PAGED KV legs (ISSUE 6) toggle ``paged_kv`` against the same
-workloads plus a ``mixed_length`` one, and report the memory facts:
-KV bytes resident, row copies performed on prefix hits (ZERO on the
-paged path — asserted, not just reported), pages served by reference,
-and — the acceptance headline — the lane count achievable at FIXED KV
-memory on a mixed-length prompt distribution vs the contiguous layout
-(``fixed_kv_memory``: same bytes, ≥2× the lanes).
+The ``paged`` legs (ISSUE 6) run pages of ``chunk`` tokens (the
+``baseline`` / ``spec`` legs the engine's default page) on the same
+workloads plus a ``mixed_length`` one, and report the memory facts: KV
+bytes resident, pages served by reference on prefix hits, and
+copy-on-write copies.
 
 The SHARDED legs (ISSUE 8) run every workload tensor-parallel
 (``tp2`` — one engine over a 2-device mesh), data-parallel
@@ -110,8 +108,8 @@ def repetitive_prompts(n, vocab, length, seed=3):
 
 def mixed_length_prompts(n, vocab, lo, hi, seed=13):
     """Lengths spread uniformly across [lo, hi] — the distribution
-    where per-lane paging pays: a contiguous layout charges every one
-    of these the worst case, a paged one only its own span."""
+    where per-lane paging pays: a lane reserves its own span of pages,
+    not the worst case."""
     rng = numpy.random.RandomState(seed)
     return [rng.randint(0, vocab, int(length)).tolist()
             for length in rng.randint(lo, hi + 1, n)]
@@ -149,7 +147,7 @@ def _emulate_device_latency(engines, seconds):
 
     for engine in engines:
         for name in ("_step_jit", "_verify_jit", "_chunk_jit",
-                     "_prefill_jit", "_megastep_jit"):
+                     "_megastep_jit"):
             fn = getattr(engine, name, None)
             if fn is not None:
                 setattr(engine, name, wrap(fn))
@@ -316,15 +314,6 @@ def run_leg(params, n_heads, max_len, prompts, n_new, expect,
                 raise AssertionError(
                     "attn_kernel leg on CPU did not increment the "
                     "fallback counter under %r" % (features,))
-        if features.get("paged_kv"):
-            # the paged layout has NO row-copy install path — a prefix
-            # hit is a page reference; any copy counted here is a bug
-            if cc.get("kv_row_copies", 0) or c.get("kv_row_copies", 0):
-                raise AssertionError(
-                    "paged leg performed %d KV row copies under %r — "
-                    "prefix hits must be page references"
-                    % (cc.get("kv_row_copies", 0)
-                       + c.get("kv_row_copies", 0), features))
         megastep_cols = {}
         if features.get("megastep"):
             lane_iters = c.get("megastep_lane_iterations", 0)
@@ -386,14 +375,11 @@ def run_leg(params, n_heads, max_len, prompts, n_new, expect,
                 round(c["draft_accepted"] / c["draft_tokens"], 3)
                 if c.get("draft_tokens") else None),
             "ttft_mean_s": round(warm["ttft_mean"], 5),
-            # paged-KV memory facts (contiguous legs report them too,
-            # for the side-by-side): device KV footprint, row copies
-            # paid installing prefix hits (cold pass — 0 when paged),
-            # pages served by reference, copy-on-write count, and the
-            # peak concurrent lanes the layout actually sustained
+            # KV memory facts: device KV footprint, pages served by
+            # reference on prefix hits (cold pass), copy-on-write
+            # count, and the peak concurrent lanes the pool sustained
             "kv_bytes_resident": sum(e.kv_bytes_resident()
                                      for e in engines),
-            "kv_row_copies": cc.get("kv_row_copies", 0),
             "kv_pages_referenced": cc.get("kv_pages_referenced", 0),
             "kv_cow_copies": (cc.get("kv_cow_copies", 0)
                               + c.get("kv_cow_copies", 0)),
@@ -450,46 +436,6 @@ def run_leg(params, n_heads, max_len, prompts, n_new, expect,
         return record
     finally:
         server.stop()
-
-
-def fixed_kv_memory_comparison(params, n_heads, max_len, chunk, n_new,
-                               vocab, budget_slots=4, requests=16):
-    """ACCEPTANCE leg: the SAME mixed-length workload through (a) the
-    contiguous layout sized to ``budget_slots`` worst-case lanes and
-    (b) a paged pool of EXACTLY the same KV bytes
-    (``budget_slots·max_len/chunk`` pages) — reporting the lane count
-    each layout sustains.  The contiguous layout is structurally capped
-    at ``budget_slots``; the paged pool turns the headroom between the
-    mixed lengths and the worst case into extra concurrent lanes."""
-    lo, hi = max(4, chunk // 2), max(chunk, (max_len - n_new) // 2)
-    prompts = mixed_length_prompts(requests, vocab, lo, hi)
-    expect = expected_rows(params, prompts, n_new, n_heads, max_len)
-    fpt = decode_flops_per_token(
-        vocab, params["embed"].shape[1], len(params["blocks"]),
-        int(numpy.mean([len(p) for p in prompts])) + n_new // 2,
-        n_heads=n_heads)
-    contig = run_leg(params, n_heads, max_len, prompts, n_new, expect,
-                     slots=budget_slots, flops_per_token=fpt)
-    # -1: the reserved scratch page counts against the byte budget, so
-    # both layouts hold EXACTLY budget_slots·max_len KV rows per block
-    pool_pages = budget_slots * max_len // chunk - 1
-    paged = run_leg(params, n_heads, max_len, prompts, n_new, expect,
-                    slots=min(requests, pool_pages),
-                    paged_kv=pool_pages, prefill_chunk=chunk,
-                    flops_per_token=fpt)
-    ratio = paged["slots_busy_peak"] / float(budget_slots)
-    return {
-        "budget_slots_contiguous": budget_slots,
-        "kv_bytes_contiguous": contig["kv_bytes_resident"],
-        "kv_bytes_paged": paged["kv_bytes_resident"],
-        "pool_pages": pool_pages,
-        "prompt_lengths": sorted(len(p) for p in prompts),
-        "slots_peak_contiguous": contig["slots_busy_peak"],
-        "slots_peak_paged": paged["slots_busy_peak"],
-        "slots_ratio_vs_contiguous": round(ratio, 2),
-        "contiguous": contig,
-        "paged": paged,
-    }
 
 
 def replica_scaling_comparison(params, n_heads, max_len, chunk, n_new,
@@ -581,16 +527,13 @@ def run_bench(smoke=False, slots=4, chunk=16, cache=256, spec_k=4,
     d_model = int(params["embed"].shape[1])
     n_layers = len(params["blocks"])
     feature_sets = {
+        # the engine's defaults (its own page), alone and speculating
         "baseline": {},
-        "chunked": {"prefill_chunk": chunk},
-        "prefix_cache": {"prefix_cache": cache, "prefill_chunk": chunk},
         "spec": {"spec_k": spec_k},
-        "all": {"prefix_cache": cache, "prefill_chunk": chunk,
-                "spec_k": spec_k},
-        # ISSUE 6: the paged KV pool, alone and under the full fast
-        # path — same workloads, so the row-copy and footprint columns
-        # read off directly against the contiguous legs above
+        # ISSUE 6: pages of ``chunk`` tokens, alone, under the prefix
+        # cache and under the full fast path — same workloads
         "paged": {"paged_kv": True, "prefill_chunk": chunk},
+        "prefix_cache": {"prefix_cache": cache, "prefill_chunk": chunk},
         "paged_all": {"paged_kv": True, "prefix_cache": cache,
                       "prefill_chunk": chunk, "spec_k": spec_k},
         # ISSUE 7: the Pallas serving kernels against the same
@@ -694,11 +637,6 @@ def run_bench(smoke=False, slots=4, chunk=16, cache=256, spec_k=4,
             print("%s/%s: %s" % (wname, fname, json.dumps(legs[fname])),
                   file=sys.stderr)
             stream_summary()
-    # the fixed-KV-memory acceptance leg: same bytes, how many lanes?
-    results["fixed_kv_memory"] = fixed_kv_memory_comparison(
-        params, n_heads, max_len, chunk, n_new, vocab,
-        budget_slots=2 if smoke else 4, requests=requests * 2)
-    stream_summary()
     # the replica-scaling acceptance leg (ISSUE 8): device-bound
     # regime, 1 engine vs 2 replicas on the same mixed-length traffic
     results["replica_scaling"] = replica_scaling_comparison(
@@ -710,7 +648,6 @@ def run_bench(smoke=False, slots=4, chunk=16, cache=256, spec_k=4,
     sp_cache = results["workloads"]["shared_prefix"]["prefix_cache"]
     sp_paged = results["workloads"]["shared_prefix"]["paged_all"]
     sp_base = results["workloads"]["shared_prefix"]["baseline"]
-    fixed = results["fixed_kv_memory"]
     results["headline"] = {
         "dispatches_per_token_plain_single_lane":
             lane1["baseline"]["dispatches_per_token"],
@@ -731,14 +668,9 @@ def run_bench(smoke=False, slots=4, chunk=16, cache=256, spec_k=4,
         "prefill_flops_saved_frac": round(
             1 - sp_cache["prefill_tokens"]
             / max(sp_base["prefill_tokens"], 1), 3),
-        # ISSUE 6: zero-copy prefix sharing + fixed-memory lane count
-        "kv_row_copies_contiguous_shared_prefix":
-            sp_cache["kv_row_copies"],
-        "kv_row_copies_paged_shared_prefix": sp_paged["kv_row_copies"],
+        # ISSUE 6: zero-copy prefix sharing
         "kv_pages_referenced_shared_prefix":
             sp_paged["kv_pages_referenced"],
-        "slots_at_fixed_kv_memory_ratio":
-            fixed["slots_ratio_vs_contiguous"],
         # ISSUE 7: the kernel-vs-XLA MFU pair on the same workload
         # (identical on CPU where the kernel leg falls back — the
         # split is a TPU-session fact) plus the which-path evidence
@@ -793,11 +725,6 @@ def _latest_mfu(results):
         for leg in legs.values():
             if leg.get("mfu") is not None:
                 mfu = leg["mfu"]
-    fixed = results.get("fixed_kv_memory") or {}
-    for key in ("contiguous", "paged"):
-        leg = fixed.get(key)
-        if leg and leg.get("mfu") is not None:
-            mfu = leg["mfu"]
     return mfu
 
 
@@ -806,10 +733,9 @@ def summary_record(results):
     same shape as ``bench.py::summary_record`` (metric/value/unit/
     vs_baseline/configs), with the metric-selection priority in ONE
     place so the per-leg partial stream and the final emit can never
-    disagree: the fixed-KV-memory slot ratio once that leg has run
-    (the ISSUE 6 acceptance headline), any paged shared-prefix leg's
-    zero-row-copy fact before that, tokens/s of the newest completed
-    leg as the early-partial fallback.  EVERY line carries an ``mfu``
+    disagree: the megastep's dispatches/token once the headline is
+    made, tokens/s of the newest completed leg as the early-partial
+    fallback.  EVERY line carries an ``mfu``
     column (ISSUE 7): the newest completed leg's model-FLOPs
     utilization, so a killed run still banks the kernel-vs-XLA
     reading."""
@@ -828,32 +754,12 @@ def summary_record(results):
             "vs_baseline": 0.547,
             "configs": results,
         }, 0
-    fixed = results.get("fixed_kv_memory") or {}
-    if fixed.get("slots_ratio_vs_contiguous") is not None:
-        return {
-            "metric": "lm_paged_slots_at_fixed_kv_memory_ratio",
-            "mfu": mfu,
-            "value": fixed["slots_ratio_vs_contiguous"],
-            "unit": "x_vs_contiguous",
-            "vs_baseline": 1.0,
-            "configs": results,
-        }, 0
     workloads = results.get("workloads") or {}
-    paged_sp = (workloads.get("shared_prefix") or {}).get("paged_all") \
-        or (workloads.get("shared_prefix") or {}).get("paged")
-    if paged_sp is not None:
-        return {
-            "metric": "lm_paged_shared_prefix_kv_row_copies",
-            "mfu": mfu,
-            "value": paged_sp["kv_row_copies"],
-            "unit": "rows",
-            "vs_baseline": None,
-            "configs": results,
-        }, 0
     latest = None
     for legs in workloads.values():
         for leg in legs.values():
-            latest = leg
+            if "tokens_per_sec" in leg:      # (a skipped leg has none)
+                latest = leg
     if latest is not None:
         return {
             "metric": "lm_fastpath_tokens_per_sec",

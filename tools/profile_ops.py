@@ -112,10 +112,9 @@ def _r3_cumsum_lrn(x, alpha=1e-4, beta=0.75, n=5, k=2.0):
 # ---- serving attention rows (ISSUE 7) --------------------------------
 def attention_rows(kernels="auto"):
     """Per-op cost of the serving hot loop's attention programs at the
-    lm-bench geometry: decode step (c=1) and chunked prefill (c=page),
-    contiguous vs paged storage, XLA vs the Pallas serving kernels —
-    the same pairs tools/lm_bench.py reads end-to-end, isolated here
-    per dispatch (autotuning seed data).
+    lm-bench geometry: decode step (c=1) and chunked prefill (c=page)
+    over paged storage, XLA vs the Pallas serving kernels, isolated
+    here per dispatch (autotuning seed data).
 
     ``kernels``: 'auto' rows the Pallas kernels only on real TPU
     hardware (off-TPU they would run in interpret mode — minutes per
@@ -133,29 +132,20 @@ def attention_rows(kernels="auto"):
     dh = d_model // n_heads
     m = max_len // page                       # pages per lane
     n_pages = b * m + 1                       # + reserved scratch page
-    kc = jnp.asarray(rng.randn(b, kv, max_len, dh), jnp.float32)
-    vc = jnp.asarray(rng.randn(b, kv, max_len, dh), jnp.float32)
     kp = jnp.asarray(rng.randn(n_pages, kv, page, dh), jnp.float32)
     vp = jnp.asarray(rng.randn(n_pages, kv, page, dh), jnp.float32)
     ptab = jnp.asarray(
         1 + numpy.arange(b * m).reshape(b, m), jnp.int32)
     pos_mid = jnp.full((b,), max_len // 2, jnp.int32)  # page-aligned
-    pos_scalar = jnp.asarray(max_len // 2, jnp.int32)  # contiguous path
 
     x1 = jnp.asarray(rng.randn(b, 1, d_model), jnp.float32)
     xc = jnp.asarray(rng.randn(b, page, d_model), jnp.float32)
-
-    def contig(a):
-        return A.mha_chunk_step(
-            params, a, kc, vc, pos_scalar, n_heads, rope=True)[0]
 
     def paged(kern=None):
         return lambda a: A.mha_paged_chunk_step(
             params, a, kp, vp, ptab, pos_mid, n_heads, rope=True,
             attn_kernel=kern)[0]
 
-    bench_op("attn decode step c=1 (contiguous)", contig, x1)
-    bench_op("attn chunk prefill c=%d (contiguous)" % page, contig, xc)
     bench_op("attn decode step c=1 (paged, xla)", paged(), x1)
     bench_op("attn chunk prefill c=%d (paged, xla)" % page, paged(),
              xc)

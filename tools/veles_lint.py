@@ -109,10 +109,8 @@ PURITY_MODULES = (
 #: the analysis set (the TRACED_REGISTRY discipline, applied here)
 HOT_PATH_REGISTRY = (
     ("veles_tpu/serving/lm_engine.py", "_admit"),
-    ("veles_tpu/serving/lm_engine.py", "_admit_chunked"),
     ("veles_tpu/serving/lm_engine.py", "_admit_paged"),
     ("veles_tpu/serving/lm_engine.py", "_cow_guard"),
-    ("veles_tpu/serving/lm_engine.py", "_advance_prefill"),
     ("veles_tpu/serving/lm_engine.py", "_prepare_chunk_paged"),
     ("veles_tpu/serving/lm_engine.py", "_dispatch_chunk_paged"),
     ("veles_tpu/serving/lm_engine.py", "_dispatch_decode"),
@@ -611,7 +609,7 @@ class _PurityPass:
     def _aliases(self, tree):
         """name -> value expr for simple ``name = <call>`` bindings
         anywhere in the module (function-local included) — how
-        ``step_all = jax.vmap(step_one)`` resolves to ``step_one``."""
+        ``propose_all = jax.vmap(<lambda>)`` resolves to the lambda."""
         out = {}
         for node in ast.walk(tree):
             if isinstance(node, ast.Assign) \

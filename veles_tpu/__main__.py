@@ -183,17 +183,19 @@ def build_argparser():
                         help="with --serve-slots: radix prefix cache "
                              "over prompt KV, capacity CHUNKS cached "
                              "chunks (LRU) — requests sharing a system "
-                             "prompt / few-shot header reuse its "
-                             "prefill instead of recomputing it; "
-                             "0 = off")
+                             "prompt / few-shot header reference its "
+                             "KV pages instead of recomputing them "
+                             "(no copy); 0 = off")
     parser.add_argument("--serve-prefill-chunk", type=int, default=0,
                         metavar="TOKENS",
-                        help="with --serve-slots: run prompt prefill "
-                             "as TOKENS-sized chunks interleaved with "
-                             "decode steps (bounded compile buckets, "
-                             "no head-of-line blocking behind long "
-                             "prompts); 0 = whole-prompt prefill at "
-                             "power-of-two buckets")
+                        help="with --serve-slots: the KV page and "
+                             "the prompt chunk in tokens — prompt "
+                             "prefill runs as TOKENS-sized chunks "
+                             "interleaved with decode steps (one chunk "
+                             "program for every prompt length, no "
+                             "head-of-line blocking behind long "
+                             "prompts); must divide max_len; 0 = the "
+                             "largest divisor of max_len not above 32")
     parser.add_argument("--serve-spec-k", type=int, default=0,
                         metavar="K",
                         help="with --serve-slots: prompt-lookup "
@@ -209,20 +211,18 @@ def build_argparser():
                              "0 = one token per dispatch")
     parser.add_argument("--serve-paged-kv", type=int, default=0,
                         metavar="PAGES",
-                        help="with --serve-slots: paged KV cache — "
-                             "store decode KV in PAGES fixed-size "
-                             "pages (page = the prefill chunk; "
-                             "max_len must divide by it) shared by "
-                             "every lane through per-lane page "
-                             "tables; prefix-cache hits become "
-                             "zero-copy page references and slot "
-                             "count stops being bounded by "
-                             "slots*max_len memory (output still "
-                             "bit-identical to greedy); -1 = size "
-                             "the pool to the contiguous footprint "
-                             "(slots * max_len / chunk pages, + the "
-                             "reserved scratch page); 0 = "
-                             "contiguous KV")
+                        help="with --serve-slots: the size of the KV "
+                             "pool in PAGES (page = the prefill "
+                             "chunk) — the engine keeps decode KV in "
+                             "fixed-size pages shared by every lane "
+                             "through per-lane page tables, so "
+                             "prefix-cache hits are zero-copy page "
+                             "references and the slot count is "
+                             "bounded by the pool, not by "
+                             "slots*max_len memory; 0 (default) or -1 "
+                             "= every lane's whole table (slots * "
+                             "max_len / chunk pages, + the reserved "
+                             "scratch page)")
     parser.add_argument("--serve-megastep", type=int, default=0,
                         metavar="K",
                         help="with --serve-slots: fused multi-step "
@@ -238,7 +238,7 @@ def build_argparser():
     parser.add_argument("--serve-attn-kernel", default="off",
                         choices=("off", "auto", "force"),
                         metavar="MODE",
-                        help="with --serve-slots and --serve-paged-kv: "
+                        help="with --serve-slots: "
                              "run the engine's attention through the "
                              "Pallas serving kernels (flash-decode "
                              "over the paged KV pool + fused chunked "
@@ -255,7 +255,7 @@ def build_argparser():
                         help="with --serve-slots: tensor-parallel "
                              "decode — run every engine program over "
                              "an N-device mesh (weights head-sharded, "
-                             "KV cache/pool sharded head-wise; N must "
+                             "KV pool sharded head-wise; N must "
                              "divide the model's attention and KV "
                              "head counts; greedy output stays "
                              "bit-identical).  0 = single-device "

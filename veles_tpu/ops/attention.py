@@ -555,43 +555,6 @@ def chunk_live_mask(pos, c, cache_len, window=None, sinks=0):
     return live
 
 
-def mha_chunk_step(params, x, k_cache, v_cache, pos, n_heads,
-                   rope=False, window=None, sinks=0, layer=0):
-    """``c`` decode/prefill positions against the KV cache in ONE pass —
-    the multi-token generalization of :func:`mha_decode_step` (which is
-    the c=1 case) serving both CHUNKED PREFILL (a prompt slice lands in
-    the cache without recomputing what precedes it) and SPECULATIVE
-    VERIFICATION (a draft of tokens scored in one dispatch).
-
-    x: (batch, c, d_model) — activations for positions
-    [pos, pos + c); k_cache/v_cache: (batch, kv_heads, max_len,
-    head_dim) with positions [0, pos) filled; ``pos`` is traced.
-    Writes the c new K/V rows at [pos, pos + c) and attends each query
-    i causally over cache positions <= pos + i (window/sinks as in
-    :func:`mha_decode_step`), so position j's output is exactly what a
-    full prefill (or j one-token decode steps) would produce.  The
-    caller must guarantee ``pos + c <= max_len`` — dynamic_update_slice
-    CLAMPS out-of-range starts, which would silently shift the write
-    onto committed rows."""
-    cfg = model_config.of(n_heads, rope, window, sinks)
-    rope, window = cfg.layer_rope(layer), cfg.layer_window(layer)
-    c = x.shape[1]
-    q, k_new, v_new, gate = _qkv_cached(params, x, cfg)    # (b, h, c, dh)
-    if rope:
-        pos_arr = pos + jnp.arange(c)
-        q = cfg_rotate(q, pos_arr, cfg)
-        k_new = cfg_rotate(k_new, pos_arr, cfg)
-    k_cache = jax.lax.dynamic_update_slice(
-        k_cache, k_new, (0, 0, pos, 0))
-    v_cache = jax.lax.dynamic_update_slice(
-        v_cache, v_new, (0, 0, pos, 0))
-    live = chunk_live_mask(pos, c, k_cache.shape[2], window, cfg.sinks)
-    o = _attend(q, _repeat_kv(k_cache, cfg.n_heads),
-                _repeat_kv(v_cache, cfg.n_heads),
-                live[None, None, :, :])          # (b, h, c, cache_len)
-    return _merge(params, o, gate, cfg), k_cache, v_cache
-
-
 def mha_decode_step(params, x, k_cache, v_cache, pos, n_heads,
                     rope=False, window=None, sinks=0, layer=0):
     """One autoregressive decode step with a KV cache.
@@ -737,8 +700,9 @@ def mha_paged_chunk_step(params, x, k_pool, v_pool, ptab, pos, n_heads,
                          attn_kernel=None, write_mask=None, layer=0,
                          base=None):
     """``c`` positions per lane against the PAGED KV pool in one pass —
-    :func:`mha_chunk_step` with the storage indirected through a page
-    table, batched over lanes (each at its own traced ``pos``).
+    the multi-token generalization of :func:`mha_decode_step` with the
+    storage indirected through a page table, batched over lanes (each at
+    its own traced ``pos``).
 
     x: (b, c, d_model) — b lanes' activations for their positions
     [pos[i], pos[i]+c); k_pool/v_pool: (n_pages, kv_heads, page,

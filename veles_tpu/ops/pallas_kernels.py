@@ -1368,8 +1368,7 @@ def _latent_prefill_call(b, h, c, nope, row, kv_rank, vdim, m_pages, hb, qb,
         interpret=interpret)
 
 
-def serving_kernels_supported(paged, n_heads, kv_heads, head_dim,
-                              page, tp=0):
+def serving_kernels_supported(n_heads, kv_heads, head_dim, page, tp=0):
     """(ok, reason) — can the serving attention kernels carry this
     engine geometry?  The checks are STRUCTURAL (what the kernels
     cannot express), not platform: platform routing (TPU vs interpret
@@ -1383,9 +1382,6 @@ def serving_kernels_supported(paged, n_heads, kv_heads, head_dim,
         return False, ("tensor-parallel mesh (tp=%d): the Pallas "
                        "serving kernels are single-device programs; "
                        "the XLA path serves sharded decode" % tp)
-    if not paged:
-        return False, ("contiguous KV layout (the kernels walk a page "
-                       "table; enable paged_kv)")
     if n_heads % kv_heads:
         return False, ("n_heads %d not divisible by kv_heads %d"
                        % (n_heads, kv_heads))

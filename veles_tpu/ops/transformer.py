@@ -376,19 +376,6 @@ def block_decode_step(blk, h, k_cache, v_cache, pos, n_heads,
                          layer, pos=pos, n_heads=cfg)[:3]
 
 
-def block_chunk_step(blk, h, k_cache, v_cache, pos, n_heads,
-                     rope=False, window=None, sinks=0, layer=0):
-    """One block over ``c`` consecutive positions against its KV cache —
-    the multi-token sibling of :func:`block_decode_step` (same wiring,
-    ``attention.mha_chunk_step`` core).  Serves chunked prefill and
-    speculative-draft verification; at c=1 it computes exactly what
-    ``block_decode_step`` computes."""
-    from veles_tpu.ops.attention import mha_chunk_step
-    cfg = model_config.of(n_heads, rope, window, sinks)
-    return _cached_block(mha_chunk_step, blk, h, k_cache, v_cache, cfg,
-                         layer, pos=pos, n_heads=cfg)[:3]
-
-
 def chunk_embed(params, tokens, pos, cfg=None):
     """Token (+ positional at [pos, pos+c), absent under RoPE) embedding
     for a mid-sequence chunk — :func:`embed_tokens` generalized to a
@@ -404,33 +391,15 @@ def chunk_embed(params, tokens, pos, cfg=None):
     return h
 
 
-def chunk_apply(params, tokens, caches, pos, n_heads, rope=False,
-                window=None, sinks=0):
-    """Run ``c`` consecutive tokens through the whole stack against the
-    caches in ONE pass: embed at [pos, pos+c), every block via
-    :func:`block_chunk_step`.  Returns (h (b, c, d), caches) with the
-    chunk's K/V written at [pos, pos+c) — the building block of chunked
-    prefill (c = chunk size) and prompt-lookup speculative decoding
-    (c = 1 + draft length).  Position j's hidden state equals the full
-    ``prefill`` / step-by-step decode result for the same tokens, so
-    everything downstream stays bit-identical to ``generate``."""
-    cfg = model_config.of(n_heads, rope, window, sinks)
-    h = chunk_embed(params, tokens, pos, cfg)
-    new_caches = []
-    for i, (blk, (kc, vc)) in enumerate(zip(params["blocks"], caches)):
-        h, kc, vc = block_chunk_step(blk, h, kc, vc, pos, cfg, layer=i)
-        new_caches.append((kc, vc))
-    return h, new_caches
-
-
 def block_paged_chunk_step(blk, h, k_pool, v_pool, ptab, pos, n_heads,
                            rope=False, window=None, sinks=0,
                            attn_kernel=None, write_mask=None, layer=0,
                            base=None):
     """One block over ``c`` positions per lane against the PAGED KV
-    pool — :func:`block_chunk_step` with storage indirected through a
-    per-lane page table (``attention.mha_paged_chunk_step`` core), and
-    batched over lanes so decode/verify advance every lane in ONE
+    pool — the multi-token sibling of :func:`block_decode_step` with the
+    storage indirected through a per-lane page table
+    (``attention.mha_paged_chunk_step`` core), and batched over lanes
+    so decode/verify advance every lane in ONE
     dispatch without vmapping the shared pool.  ``attn_kernel``
     (static: None | 'decode' | 'prefill') routes attention through the
     Pallas serving kernels (ISSUE 7); ``write_mask`` (traced (b,)
@@ -502,8 +471,10 @@ def paged_chunk_apply(params, tokens, pools, ptab, pos, n_heads,
                       attn_kernel=None, write_mask=None, base=None,
                       with_stats=False, rows=None, slots=None):
     """Run ``c`` consecutive tokens PER LANE through the whole stack
-    against the paged KV pools in one pass — :func:`chunk_apply` with
-    (pools, page table) in place of per-lane contiguous caches.
+    against the paged KV pools in one pass: embed at [pos, pos+c), every
+    block via :func:`block_paged_chunk_step` — the building block of
+    chunked prefill (c = chunk size), the decode step (c = 1) and
+    speculative verification (c = 1 + draft length).
 
     tokens: (b, c) int32; pools: per-block [(k_pool, v_pool)] each
     (n_pages, kv_heads, page, head_dim); ptab: (b, m); pos: (b,)

@@ -537,25 +537,26 @@ def serve_lm(workflow, host="127.0.0.1", port=8180, max_new=256,
     ``slots > 0`` starts a :class:`veles_tpu.serving.LMEngine` and
     routes GREEDY requests (temperature 0, the default) through
     slot-based continuous batching: concurrent prompts decode side by
-    side over one shared KV cache, each request gets its exact
+    side over one paged KV pool, each request gets its exact
     ``n_new`` (no tier overshoot), and output is bit-identical to the
     direct path.  Sampled requests (temperature > 0) always take the
     direct path below.
 
-    The LM serving FAST PATH (ISSUE 4) rides on the engine:
-    ``prefix_cache=N`` caches N chunks of prompt KV in a radix trie
-    (shared system prompts prefill once), ``prefill_chunk=C`` runs
-    prompts as C-token chunks interleaved with decode, ``spec_k=K``
-    enables prompt-lookup speculative decoding (several tokens per
-    dispatch on repetitive text), ``queue_tokens=T`` budgets admission
-    by queued prompt tokens, and ``paged_kv=N`` (ISSUE 6) switches KV
-    storage to N fixed-size pages (page = ``prefill_chunk`` tokens,
-    requires ``max_len`` divisible by it; ``True`` sizes the pool to
-    the contiguous footprint) behind per-lane page tables — lanes
-    reserve only their own span, prefix hits are zero-copy page
-    references with copy-on-write, and a request the pool cannot place
-    queues or sheds (429/503) instead of wedging.
-    ``attn_kernel='auto'`` (ISSUE 7) swaps the paged engine's
+    The engine keeps KV in fixed-size pages behind per-lane page
+    tables (ISSUE 6): ``prefill_chunk=C`` is the page and the prompt
+    chunk in tokens (prompts run as C-token chunks interleaved with
+    decode; ``max_len`` must be divisible by it; 0 = the largest
+    divisor of ``max_len`` not above 32) and ``paged_kv=N`` the pool's
+    size in pages (0 or ``True`` = every lane's whole table, ``slots x
+    max_len / C``) — lanes reserve only their own span, and a request
+    the pool cannot place queues or sheds (429/503) instead of wedging.
+    On it ride (ISSUE 4) ``prefix_cache=N``, which caches N chunks of
+    prompt KV in a radix trie (shared system prompts prefill once; hits
+    are zero-copy page references with copy-on-write), ``spec_k=K``,
+    prompt-lookup speculative decoding (several tokens per dispatch on
+    repetitive text), and ``queue_tokens=T``, which budgets admission
+    by queued prompt tokens.
+    ``attn_kernel='auto'`` (ISSUE 7) swaps the engine's
     attention for the Pallas flash-decode / fused-prefill kernels on
     real TPU hardware, with an automatic XLA fallback (off-TPU or
     unsupported geometry — logged once, counted on ``/metrics`` as

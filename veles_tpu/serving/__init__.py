@@ -135,7 +135,7 @@ from veles_tpu.serving.kv_pool import KVPagePool
 from veles_tpu.serving.lockcheck import (LockOrderViolation,
                                          LockOrderWitness)
 from veles_tpu.serving.lm_engine import (LMEngine, RadixPrefixCache,
-                                         prompt_bucket, propose_draft)
+                                         propose_draft)
 from veles_tpu.serving.metrics import (ServingMetrics, get,
                                        render_prometheus)
 from veles_tpu.serving.model_manager import (ModelManager,
@@ -166,7 +166,7 @@ __all__ = ["MicroBatcher", "LMEngine", "RadixPrefixCache",
            "InjectedFault",
            "InjectedHTTPError", "NoLiveReplicas", "Overloaded",
            "DeadlineExceeded",
-           "PoolExhausted", "batch_buckets", "prompt_bucket",
+           "PoolExhausted", "batch_buckets",
            "propose_draft", "get", "load_lm_params",
            "render_prometheus",
            "replica_device_slices", "validate_lm_params"]

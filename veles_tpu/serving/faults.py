@@ -35,9 +35,8 @@ Sites (each a no-op when unarmed):
 ===================== ==================================================
 ``engine.submit``     LMEngine.submit admission (PoolExhausted storms)
 ``engine.tick``       top of the engine worker loop (latency / freeze)
-``engine.prefill``    whole-prompt prefill dispatch
-``engine.chunk``      chunked-prefill dispatch (contiguous and paged)
-``engine.cow``        paged copy-on-write page-copy dispatch
+``engine.chunk``      prompt-chunk prefill dispatch
+``engine.cow``        copy-on-write page-copy dispatch
 ``engine.step``       batched decode-step dispatch
 ``engine.verify``     speculative verify dispatch
 ``engine.swap``       weight-swap apply (LMEngine.swap_weights; a

@@ -6,9 +6,7 @@ page, head_dim)``, see ``ops/attention.py::paged_view``), and THIS
 class decides which lane (or prefix-cache entry) owns which page.  All
 state is host-side integers — allocation never touches the device, so
 a prefix-cache hit that installs page REFERENCES into a lane's page
-table is zero-copy and zero-dispatch by construction (the contiguous
-path's row-copy install, docs/PERF.md's "correctness crutch", simply
-has no paged equivalent to pay).
+table is zero-copy and zero-dispatch by construction.
 
 Three invariants the engine leans on:
 

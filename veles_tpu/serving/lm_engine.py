@@ -1,40 +1,37 @@
-"""Continuous LM decode — slot-based batching over one shared KV cache.
+"""Continuous LM decode — slot-based batching over one paged KV pool.
 
 The LM-traffic half of the serving subsystem (ISSUE 1).  ``serve_lm``'s
 direct path decodes one prompt at a time: a second client waits for the
 whole first decode even though the decode step is embarrassingly
-batchable.  :class:`LMEngine` keeps a fixed pool of ``slots`` decode
-lanes sharing one batched KV cache (per block: (slots, kv_heads,
-max_len, head_dim)) and runs ONE vmapped decode step per token across
-every active lane — vLLM-style continuous batching on a jit substrate:
+batchable.  :class:`LMEngine` keeps ``slots`` decode lanes over ONE pool
+of KV pages a layer, each lane behind its own page table, and runs ONE
+batched decode step per token across every active lane — vLLM-style
+continuous batching on a jit substrate:
 
-- an arriving prompt is PREFILLED into any free slot mid-flight
-  (``ops/transformer.py::prefill`` at a power-of-two prompt bucket,
-  installed into the big cache at the slot index);
-- every engine tick advances ALL active slots by one token via a single
-  jitted vmap of ``ops/transformer.py::block_decode_step`` (per-slot
-  positions — each lane is at its own depth in its own sequence);
-- a finished sequence frees its slot immediately and the next queued
-  prompt takes it, so decode throughput scales with slot count instead
-  of serializing per prompt.
+- an arriving prompt takes any free slot mid-flight and is PREFILLED one
+  chunk of ``prefill_chunk`` tokens (one page) a turn, interleaved with
+  the decode steps (``ops/transformer.py::paged_chunk_apply``): ONE chunk
+  program serves every prompt length, and a long prompt never
+  head-of-line-blocks the decode lanes;
+- every engine tick advances ALL active slots by one token in a single
+  jitted step over the shared pools (per-slot positions — each lane is
+  at its own depth in its own sequence);
+- a finished sequence frees its slot and its pages immediately and the
+  next queued prompt takes them, so decode throughput scales with slot
+  count instead of serializing per prompt.
 
-The SERVING FAST PATH (ISSUE 4) adds three independently-toggled
-optimizations, each preserving the greedy contract below:
+Two optimizations ride it (ISSUE 4), each preserving the greedy contract
+below:
 
 - ``prefix_cache=N`` — a chunk-granular RADIX PREFIX CACHE
   (:class:`RadixPrefixCache`) over prompt tokens: prompts sharing a
   prefix (system prompts, few-shot headers) reuse the already-computed
-  KV rows for their shared full chunks instead of re-running prefill
+  KV pages for their shared full chunks instead of re-running prefill
   FLOPs.  Entries are ref-counted while a lane uses their trie path and
-  LRU-evicted at capacity ``N`` chunks; rows are COPIED into the lane's
-  shared-cache rows on install, so a later eviction (or poisoning
-  attempt) can never corrupt an in-flight decode — correctness never
-  depends on cache state, only speed does.
-- ``prefill_chunk=C`` — CHUNKED PREFILL: the prompt runs as
-  ceil(len/C) fixed-width chunk dispatches
-  (``ops/transformer.py::chunk_apply``) interleaved with decode steps,
-  so one long prompt neither head-of-line-blocks the decode lanes nor
-  forks a compile per prompt-length bucket (ONE chunk program total).
+  LRU-evicted at capacity ``N`` chunks; a shared page is copied before
+  anybody writes into it, so a later eviction (or poisoning attempt) can
+  never corrupt an in-flight decode — correctness never depends on cache
+  state, only speed does.
 - ``spec_k=K`` — PROMPT-LOOKUP SPECULATIVE DECODING: an n-gram match
   against the lane's own prompt+output proposes K draft tokens (no
   draft model), verified in ONE batched chunk dispatch; every accepted
@@ -44,11 +41,12 @@ optimizations, each preserving the greedy contract below:
   repetitive or structured text — while a full miss still yields the
   one greedy token a plain step would have.
 
-The PAGED KV CACHE (ISSUE 6, ``paged_kv=N``) replaces the contiguous
-per-slot KV region with fixed-size PAGES (page = ``prefill_chunk``
-tokens) drawn from one global pool per block, indexed through a
-per-lane page table (``ops/attention.py::paged_view``/``paged_write``;
-allocator in ``serving/kv_pool.py``):
+The KV CACHE IS PAGED (ISSUE 6): fixed-size PAGES (page =
+``prefill_chunk`` tokens) drawn from one global pool per block, indexed
+through a per-lane page table (``ops/attention.py::paged_view``/
+``paged_write``; allocator in ``serving/kv_pool.py``).  ``paged_kv=N`` is
+the pool's size in pages (0, the default: every lane's whole table,
+``slots × max_len / page``):
 
 - a lane RESERVES only the pages its own ``len(prompt) + n_new +
   spec_k`` span needs, so slot count is bounded by the POOL, not by
@@ -57,8 +55,7 @@ allocator in ``serving/kv_pool.py``):
   bytes;
 - prefix-cache hits become page REFERENCES: the trie stores page ids,
   a hit bumps a ref-count and writes the id into the lane's table —
-  zero device copies, zero dispatches (the contiguous path's row-copy
-  install is metered as ``kv_row_copies`` for contrast, and stays);
+  zero device copies, zero dispatches;
 - appends into a SHARED page copy-on-write first (one page-copy
   dispatch; the other referents keep bit-identical rows) — structurally
   rare, because shared pages are exactly full prompt chunks and lanes
@@ -70,7 +67,7 @@ allocator in ``serving/kv_pool.py``):
   ``PoolExhausted`` (HTTP 429) — pool pressure never wedges a lane.
 
 The SERVING ATTENTION KERNELS (ISSUE 7, ``attn_kernel=``) swap the
-paged programs' attention core for the Pallas suite in
+programs' attention core for the Pallas suite in
 ``ops/pallas_kernels.py``: the decode/verify dispatches run
 :func:`~veles_tpu.ops.pallas_kernels.paged_flash_decode` (the page
 table walked INSIDE the kernel — no ``paged_view`` gather ever
@@ -79,7 +76,7 @@ materializes a lane's dense cache view) and the chunk program runs
 attended from VMEM and installed into the pool in the kernel
 epilogue).  Routing resolves ONCE at construction: 'auto' (or True)
 uses the kernels on real TPU hardware and falls back to the XLA path
-everywhere else (off-TPU, contiguous KV layout, unsupported geometry
+everywhere else (off-TPU, unsupported geometry
 — logged once, metered per dispatch as ``attn_kernel_fallbacks`` vs
 ``attn_kernel_dispatches``); 'force' insists even off-TPU (interpret
 mode — the parity tests' end-to-end gear, far too slow for traffic).
@@ -144,7 +141,7 @@ the serving inner loop:
   inside the program;
 - a lane that exhausts its ``n_new`` mid-program is MASKED, not
   returned: its carry freezes (position/last token stop advancing),
-  its emitted slots read -1, and — paged — its K/V writes are
+  its emitted slots read -1, and its K/V writes are
   redirected to the scratch page (``paged_write(write_mask=)``), so a
   dead iteration can never touch an allocated page.  The wasted
   iterations are metered (``megastep_wasted_iterations``) so the K
@@ -162,18 +159,17 @@ the serving inner loop:
 ``megastep=1`` (and 0, the default) keeps today's per-tick path
 bit-for-bit; any K is bit-identical to it anyway (the scan body IS the
 step program), which the parity matrix pins across the full
-{paged_kv, prefix_cache, prefill_chunk, spec_k, attn_kernel, tp}
-feature set.  With the Pallas ``paged_flash_decode`` kernel active the
-whole K-step loop never leaves the device.  The scan is the ONE fused
+{prefix_cache, spec_k, attn_kernel, tp} feature set.  With the Pallas
+``paged_flash_decode`` kernel active the whole K-step loop never leaves
+the device.  The scan is the ONE fused
 driver: a turn of the loop decodes through exactly one of
 ``_step_megastep`` (``megastep >= 2``), ``_step_speculative``
 (``spec_k``) or ``_step_plain``, all three through
 ``_dispatch_decode``.
 
-The KV STORAGE IS UPDATED IN PLACE (ISSUE 27).  The pools (paged) or
-the caches (contiguous) are one tree of device arrays that every
-program except ``prefill`` and ``chunk_extract`` takes and returns.
-Each of those programs takes it DONATED (:meth:`LMEngine._jit`,
+The KV STORAGE IS UPDATED IN PLACE (ISSUE 27).  The pools are one tree
+of device arrays that every program takes and returns.
+Each program takes it DONATED (:meth:`LMEngine._jit`,
 ``storage=``): the compiled program aliases the output to the input and
 writes the new rows into the arrays as they lie — no dispatch copies a
 pool, none holds a second one, and the jit call allocates no output
@@ -198,7 +194,7 @@ everything a turn does, ONE value needs the tokens the host waits for:
 the ``last`` argument of the next step.  The engine has no stop token —
 a lane ends by count — so positions, pages, windows, widths and tables
 all follow from what the host already knows when a step goes out.  The
-paged plain driver therefore does the rest of its turn between the
+plain driver therefore does the rest of its turn between the
 step's jit call and the wait for its tokens, while the device runs the
 step (:meth:`LMEngine._under_step`; the recorder's ``ahead.emit``,
 ``ahead.admit``, ``ahead.prepare``): the step is counted into its lanes
@@ -214,18 +210,17 @@ tick -> deliver -> admit -> chunk -> step — when a lane left its slot
 meanwhile (a failed fetch, a fault site, a teardown), a lane was
 withdrawn or a weight swap waits; ``turns_prepared_ahead`` and
 ``ahead_discarded`` in ``/metrics.json`` count both.  The speculative
-driver (it drafts from the emitted tokens), the megastep (its tokens
-stay inside the scan) and the contiguous layout (its admission
-dispatches programs) cannot split their turn: they leave nothing
+driver (it drafts from the emitted tokens) and the megastep (its tokens
+stay inside the scan) cannot split their turn: they leave nothing
 prepared and the same loop runs them in the old order.
 
 TWO DISPATCHES IN FLIGHT (ISSUE 39).  That one value never leaves the
 device either: the tokens are the step program's second output, and the
-paged programs take the lanes' last tokens as the dispatch before left
+programs take the lanes' last tokens as the dispatch before left
 them there (``LMEngine._last_dev``: a step returns every lane's token, a
 tail chunk writes its first token at its lane's slot, a lane that does
 not decode is masked to token 0 inside the step).  The host needs the
-tokens only to answer requests, so the paged plain driver waits for the
+tokens only to answer requests, so the plain driver waits for the
 tokens of step N only AFTER it has called turn N+1's chunk and step:
 tick -> the prepared chunk's jit call -> the step's jit call -> [under
 the step: count it in, deliver, shed and admit, prepare] -> wait for the
@@ -289,14 +284,10 @@ combination is enabled, which is the serving contract (sampled
 requests fall back to the direct path upstream; the Pallas kernels'
 online softmax matches the XLA softmax to fp32 roundoff, preserving
 every greedy argmax the parity matrix pins).  Compile count is
-bounded: one step program, one prefill program per prompt bucket, one
-install program, plus (fast path) one chunk-prefill program, one
-chunk-install/extract pair, and one verify program per (engine) ``k``;
-paged mode compiles one chunk, one step, one verify and one page-copy
-program TOTAL (the page-table indirection is traced data, never a
-shape).  The megastep adds ONE fused program per (live-width ladder
-entry × K) family — K is fixed per engine, so that is one program
-contiguous / one per ladder entry paged, the jit-guard-asserted bound.
+bounded: one chunk and one page-copy program, and one step (and
+verify, or fused megastep) program per live-width ladder entry — the
+page-table indirection is traced data, never a shape, and K is fixed per
+engine: the jit-guard-asserted bound.
 """
 
 from __future__ import annotations
@@ -340,7 +331,7 @@ class _Request:
         self.t_enq = self.t_enq_ns * 1e-9
         self.deadline = self.t_enq + deadline_s
         self.cancelled = False
-        #: paged mode: worst-case page demand (admission reservation)
+        #: worst-case page demand (admission reservation)
         self.pages = pages
         #: tracing (ISSUE 12): the request's TraceContext (or None) and
         #: its open queue-wait span handle — how the worker thread
@@ -350,7 +341,7 @@ class _Request:
 
 
 class _Slot:
-    """Host-side lane state; device state lives in the shared caches."""
+    """Host-side lane state; device state lives in the shared pools."""
 
     __slots__ = ("request", "emitted", "remaining", "pending", "pinned",
                  "cursor", "pages", "inflight", "drafts")
@@ -366,7 +357,7 @@ class _Slot:
         #: trie node of the last matched/inserted chunk (None once the
         #: cache refused an insert — stop extending this lane's path)
         self.cursor = None
-        #: paged mode: page ids backing this lane's table row, in
+        #: page ids backing this lane's table row, in
         #: lane-local order (owned AND referenced; released at finish)
         self.pages = []
         #: a lane that drafts with the model's own module (ISSUE 40): its
@@ -386,19 +377,17 @@ _Chunk = collections.namedtuple(
     "_Chunk", "slot lane tokens start is_tail args steps")
 
 #: one plain decode step's arguments, all but ``last`` (the tokens of the
-#: step before: the paged layout passes them on the device from output to
-#: argument, the contiguous one puts them), on the device
-#: (:meth:`LMEngine._prepare_step`): the lanes it advances as ``(slot,
-#: lane)`` pairs, the table width, the table argument as a tuple (empty for
-#: the contiguous layout), the positions and (paged) the mask of those
-#: lanes on the device, and the page steps it hands the attention kernels
-#: (:meth:`LMEngine._attn_page_steps`)
+#: step before, which pass on the device from output to argument), on the
+#: device (:meth:`LMEngine._prepare_step`): the lanes it advances as
+#: ``(slot, lane)`` pairs, the table width, the table argument, the
+#: positions and the mask of those lanes on the device, and the page steps
+#: it hands the attention kernels (:meth:`LMEngine._attn_page_steps`)
 _Step = collections.namedtuple(
     "_Step", "pairs width tables pos_dev live_dev steps")
 
 
 class _Flight:
-    """One dispatch of the paged plain driver whose tokens the host has
+    """One dispatch of the plain driver whose tokens the host has
     not fetched yet (ISSUE 39): its recorder handle, its outputs but the
     storage as they lie on the device (the lanes' tokens first, indexed
     by slot; a step's expert counts behind them), the lanes whose tokens
@@ -431,15 +420,6 @@ class _Ahead:
         self.gen = gen
         self.busy = busy
         self.queued = queued
-
-
-def prompt_bucket(true_len, max_len, floor=16):
-    """Power-of-two prompt pad width (compile-count bound), capped at
-    the cache length."""
-    bucket = floor
-    while bucket < true_len:
-        bucket *= 2
-    return min(bucket, max_len)
 
 
 def propose_draft(history, k, max_ngram=3):
@@ -539,7 +519,7 @@ class _PrefixNode:
 
     def __init__(self, key, rows, parent):
         self.key = key                # tuple of the chunk's tokens
-        self.rows = rows              # per-block [(k, v)] (1, H, C, D)
+        self.rows = rows              # the page that holds their rows
         self.children = {}
         self.refs = 0
         self.last_use = 0
@@ -549,7 +529,7 @@ class _PrefixNode:
 class RadixPrefixCache:
     """Radix trie over prompt tokens at CHUNK granularity.
 
-    A node holds the per-block KV rows of exactly ``chunk`` tokens whose
+    A node holds the page of KV rows of exactly ``chunk`` tokens whose
     absolute positions are [depth·chunk, (depth+1)·chunk) — valid for
     ANY prompt sharing that token prefix, because causal attention makes
     a position's K/V depend only on the tokens at and before it.  Keys
@@ -562,12 +542,11 @@ class RadixPrefixCache:
     chunks.  Lookup/insert/evict all run on the single engine worker
     thread — no locking.
 
-    ``rows`` is opaque to the trie: the contiguous engine stores device
-    ROW COPIES, the paged engine stores a PAGE ID (zero-copy sharing).
-    ``on_evict(rows)`` fires whenever an entry is dropped — the paged
-    engine releases the page's pool reference there, so trie eviction
-    IS the pool's reclamation path under pressure (and pinned entries
-    refusing eviction is what keeps lane-held pages safe).
+    ``rows`` is opaque to the trie: the engine stores a PAGE ID
+    (zero-copy sharing).  ``on_evict(rows)`` fires whenever an entry is
+    dropped — the engine releases the page's pool reference there, so
+    trie eviction IS the pool's reclamation path under pressure (and
+    pinned entries refusing eviction is what keeps lane-held pages safe).
     """
 
     def __init__(self, capacity, chunk, on_evict=None):
@@ -635,13 +614,13 @@ class RadixPrefixCache:
 
     def evict_one(self):
         """Drop the LRU unpinned leaf NOW (pool-pressure reclamation:
-        the paged engine calls this until its page reservation fits or
+        the engine calls this until its page reservation fits or
         nothing more can go).  Returns True when an entry was dropped."""
         return self._evict_one()
 
     def clear(self):
         """Drop EVERY entry, pinned or not, firing ``on_evict`` for
-        each (the paged engine's page references go home) — the rows
+        each (the engine's page references go home) — the rows
         the entries pointed at are gone with the KV storage
         (``LMEngine._storage_lost``); the lanes that pinned them were
         failed first."""
@@ -658,7 +637,7 @@ class RadixPrefixCache:
         """Upper bound on entries pool-pressure eviction can reclaim:
         the UNPINNED count (an unpinned interior node above a pinned
         child is counted but unreachable — close enough, since lanes
-        pin whole root-anchored paths).  The paged engine checks this
+        pin whole root-anchored paths).  The engine checks this
         BEFORE evicting, so a hopeless reservation cannot flush the
         whole cache for nothing."""
         count, stack = 0, [self.root]
@@ -709,15 +688,17 @@ class LMEngine(Logger):
 
     One worker thread owns the device state; clients :meth:`submit`
     single prompts (or :meth:`generate` a batch) and block on futures.
-    ``max_len`` pins the shared cache length: every request must satisfy
+    ``max_len`` pins a lane's page table: every request must satisfy
     ``len(prompt) + n_new <= max_len`` (+ ``spec_k`` of speculation
     headroom when ``spec_k > 0`` — a verify dispatch writes up to k
     positions past the committed front).
 
-    Fast-path knobs (ISSUE 4, all default-off; see the module
-    docstring): ``prefill_chunk=C`` chunked prefill, ``prefix_cache=N``
-    radix KV reuse over N cached chunks (implies chunking; default
-    chunk 32), ``spec_k=K`` prompt-lookup speculative decoding with
+    The cache (see the module docstring): ``prefill_chunk=C`` is the page
+    and the prompt chunk in tokens (0: the largest divisor of ``max_len``
+    not above 32; it must divide ``max_len``), ``paged_kv=N`` the pool's
+    size in pages (0 or True: every lane's whole table, ``slots x
+    max_len / C``).  ``prefix_cache=N`` radix KV reuse over N cached
+    chunks, ``spec_k=K`` prompt-lookup speculative decoding with
     ``spec_ngram`` match length.  ``queue_tokens=T`` budgets ADMISSION
     by queued prompt tokens (not just request count): a long-prompt
     flood 429s early instead of building an unbounded prefill backlog
@@ -735,7 +716,7 @@ class LMEngine(Logger):
     #: lock-discipline map (ISSUE 15, checked by tools/veles_lint.py):
     #: the CROSS-THREAD state — client admission vs the worker loop —
     #: lives under ``_cond``.  Everything else (_lanes, _free, _pos,
-    #: _last, _caches, _kv_pools, _page_tables, _pool, _trie,
+    #: _last, _last_dev, _kv_pools, _page_tables, _pool, _trie,
     #: _pool_blocked) is owned by the worker thread alone and is
     #: deliberately NOT guarded (checkpoint() documents the torn-read
     #: consequences for its best-effort pool section).
@@ -807,14 +788,12 @@ class LMEngine(Logger):
         self.queue_depth = int(queue_depth)
         self.deadline_s = float(deadline_s)
         self.queue_tokens = int(queue_tokens)
-        self._paged = bool(paged_kv)
-        if (prefix_cache or self._paged) and not prefill_chunk:
-            prefill_chunk = min(32, self.max_len)   # cache granularity
-            if self._paged:
-                # the page size must divide max_len (the bit-parity
-                # condition below) — default to the largest divisor
-                while self.max_len % prefill_chunk:
-                    prefill_chunk -= 1
+        if not prefill_chunk:
+            # the page size must divide max_len (the bit-parity
+            # condition below) — default to the largest divisor
+            prefill_chunk = min(32, self.max_len)
+            while self.max_len % prefill_chunk:
+                prefill_chunk -= 1
         self.prefill_chunk = int(prefill_chunk)
         self.spec_k = int(spec_k)
         self.spec_ngram = int(spec_ngram)
@@ -824,8 +803,7 @@ class LMEngine(Logger):
         if self.spec_k < 0 or self.spec_k + 1 >= self.max_len:
             raise ValueError("spec_k %d out of range (max_len %d)"
                              % (self.spec_k, self.max_len))
-        if self.spec_k and self.prefill_chunk \
-                and self.spec_k + 1 > self.prefill_chunk:
+        if self.spec_k + 1 > self.prefill_chunk:
             # a prefilling lane parks its step position at the chunk
             # frontier; the next chunk overwrites the verify dispatch's
             # k+1 garbage writes only when they fit inside one chunk
@@ -877,24 +855,7 @@ class LMEngine(Logger):
         #: may be in flight that the host has not seen the counts of
         self.headroom = (2 * (self.spec_k + 1) if self._mtp
                          else self.spec_k)
-        if not self._paged and self.cfg.latent is not None:
-            raise ValueError(
-                "LMEngine: latent attention needs paged_kv — its cache is "
-                "the paged pool of latent rows, and no contiguous layout "
-                "holds them")
-        if not self._paged and self.cfg.linear is not None:
-            raise ValueError(
-                "LMEngine: linear layers need paged_kv — their cached path "
-                "keeps a state slot a lane beside the full layers' paged "
-                "pool, and the contiguous layout has no place for it")
-        if not self._paged and any(
-                self.cfg.ffn_kind(i, blk) == model_config.MOE
-                for i, blk in enumerate(params["blocks"])):
-            raise ValueError(
-                "LMEngine: an expert layer needs paged_kv — the contiguous "
-                "layout's step is a vmap over lanes, and the grouped "
-                "matmul of ops/moe.py has no batching rule")
-        if self._paged and self.max_len % self.prefill_chunk:
+        if self.max_len % self.prefill_chunk:
             # the paged lane view must tile max_len exactly: a partial
             # tail page would either truncate placeable rows or attend
             # rows past max_len (the chunk program additionally relies
@@ -933,8 +894,8 @@ class LMEngine(Logger):
                     "tp=%d must divide kv_heads %d (the KV cache "
                     "shards head-wise)" % (self.tp, kv_heads))
             # the KV arrays below shard over their kv_heads axis so
-            # paged_view / mha_paged_chunk_step (and the contiguous
-            # decode) stay one-program-per-family — the page-table
+            # paged_view / mha_paged_chunk_step stay
+            # one-program-per-family — the page-table
             # indirection and the head shard compose, neither is a
             # shape
             self._kv_shard = NamedSharding(
@@ -963,8 +924,8 @@ class LMEngine(Logger):
             from veles_tpu.ops.pallas_kernels import (
                 on_tpu, serving_kernels_supported)
             ok, reason = serving_kernels_supported(
-                self._paged, self.n_heads, kv_heads, head_dim,
-                self.prefill_chunk, tp=self.tp)
+                self.n_heads, kv_heads, head_dim, self.prefill_chunk,
+                tp=self.tp)
             if ok and (self.attn_kernel == "force" or on_tpu()):
                 self._kernel_active = True
             else:
@@ -982,12 +943,6 @@ class LMEngine(Logger):
         self._backend = ("pallas" if self._kernel_active
                          else "xla-tp%d" % self.tp if self.tp >= 2
                          else "xla")
-        self._caches = None
-        self._kv_pools = None
-        self._pool = None
-        self._page_tables = None
-        self._max_pages = 0
-        self._width_ladder = []
         #: the sliding layers' tables and allocator (kv_pool.WindowTables;
         #: None for a stack of one kind)
         self._wt = None
@@ -997,97 +952,92 @@ class LMEngine(Logger):
         #: lanes decode in the step being dispatched
         self._state_shapes = None
         self._decoding = numpy.zeros(self.slots, bool)
-        if self._paged:
-            self._max_pages = self.max_len // self.prefill_chunk
-            # decode/verify table-width ladder (ISSUE 7 satellite): a
-            # step only needs pages up to the batch's live frontier,
-            # not the full max_len span — the table is sliced to the
-            # smallest power-of-two width covering every lane, so the
-            # per-token gather (or kernel grid) scales with what's
-            # actually resident.  Power-of-two steps bound the compile
-            # count at one step/verify program per LADDER ENTRY (the
-            # jit-guard's per-family bound), the same discipline as the
-            # contiguous path's prompt buckets.
-            self._width_ladder = []
-            w = 1
-            while w < self._max_pages:
-                self._width_ladder.append(w)
-                w *= 2
-            self._width_ladder.append(self._max_pages)
-            num_pages = (self.slots * self._max_pages
-                         if paged_kv is True else int(paged_kv))
-            if num_pages < 1:
-                raise ValueError("paged_kv pool must hold >= 1 page")
-            self._pool = KVPagePool(num_pages, self.prefill_chunk)
-            # +1: the scratch page.  With the serving kernels active a
-            # row packs as many heads as fill the chip's lanes
-            # (pool_pack) — the pool then lies on the chip the way the
-            # kernels read it, and no dispatch converts it
-            pack = 1
-            if self._kernel_active:
-                from veles_tpu.ops.pallas_kernels import pool_pack
-                pack = pool_pack(kv_heads, head_dim)
-            self._storage_shape = (num_pages + 1, kv_heads // pack,
-                                   self.prefill_chunk, head_dim * pack)
-            self._page_tables = numpy.zeros(
-                (self.slots, self._max_pages), numpy.int32)
-            self.metrics.set_gauge("kv_pages_total", num_pages)
-            #: the layers that hold pages (a linear layer holds a slot of
-            #: state), the module's own among them where it drafts
-            n_paged = sum(self.cfg.kind(i) != model_config.LINEAR
-                          for i in range(self._n_pools()))
-            if self.cfg.latent is not None:
-                # the pool's real bytes a token over the latent layers,
-                # padding included (width x 2 x layers if nothing were
-                # padded)
+        self._max_pages = self.max_len // self.prefill_chunk
+        # decode/verify table-width ladder (ISSUE 7 satellite): a
+        # step only needs pages up to the batch's live frontier,
+        # not the full max_len span — the table is sliced to the
+        # smallest power-of-two width covering every lane, so the
+        # per-token gather (or kernel grid) scales with what's
+        # actually resident.  Power-of-two steps bound the compile
+        # count at one step/verify program per LADDER ENTRY (the
+        # jit-guard's per-family bound).
+        self._width_ladder = []
+        w = 1
+        while w < self._max_pages:
+            self._width_ladder.append(w)
+            w *= 2
+        self._width_ladder.append(self._max_pages)
+        # the pool's size in pages: N, or (0, True, a negative flag
+        # value) every lane's whole table
+        num_pages = 0 if paged_kv is True else int(paged_kv)
+        if num_pages < 1:
+            num_pages = self.slots * self._max_pages
+        self._pool = KVPagePool(num_pages, self.prefill_chunk)
+        # +1: the scratch page.  With the serving kernels active a
+        # row packs as many heads as fill the chip's lanes
+        # (pool_pack) — the pool then lies on the chip the way the
+        # kernels read it, and no dispatch converts it
+        pack = 1
+        if self._kernel_active:
+            from veles_tpu.ops.pallas_kernels import pool_pack
+            pack = pool_pack(kv_heads, head_dim)
+        self._storage_shape = (num_pages + 1, kv_heads // pack,
+                               self.prefill_chunk, head_dim * pack)
+        self._page_tables = numpy.zeros(
+            (self.slots, self._max_pages), numpy.int32)
+        self.metrics.set_gauge("kv_pages_total", num_pages)
+        #: the layers that hold pages (a linear layer holds a slot of
+        #: state), the module's own among them where it drafts
+        n_paged = sum(self.cfg.kind(i) != model_config.LINEAR
+                      for i in range(self._n_pools()))
+        if self.cfg.latent is not None:
+            # the pool's real bytes a token over the latent layers,
+            # padding included (width x 2 x layers if nothing were
+            # padded)
+            self.metrics.set_gauge(
+                "kv_bytes_per_token",
+                head_dim * embed.dtype.itemsize * n_paged)
+        if self.cfg.linear is not None:
+            # two kinds of cache in one manager (ISSUE 36): a slot of
+            # recurrent state and convolution tail a lane for every
+            # linear layer, of a fixed size whatever the lane holds,
+            # beside ONE page table for the full layers (k and v
+            # pools, or one pool of latent rows, ISSUE 42).  A lane's
+            # slot IS its lane: taken at admission, reset by the
+            # chunk that starts at 0, freed with the lane's pages
+            self._state_shapes = self.cfg.linear.state_shapes(
+                self.slots)
+            state, tail = self._state_shapes
+            self.metrics.set_gauge("state_slots_total", self.slots)
+            self.metrics.set_gauge(
+                "state_bytes_per_lane", len(self.cfg.state_layers) * (
+                    4 * int(numpy.prod(state[1:]))
+                    + embed.dtype.itemsize * int(numpy.prod(tail[1:]))))
+            if self.cfg.latent is None:
                 self.metrics.set_gauge(
-                    "kv_bytes_per_token",
-                    head_dim * embed.dtype.itemsize * n_paged)
-            if self.cfg.linear is not None:
-                # two kinds of cache in one manager (ISSUE 36): a slot of
-                # recurrent state and convolution tail a lane for every
-                # linear layer, of a fixed size whatever the lane holds,
-                # beside ONE page table for the full layers (k and v
-                # pools, or one pool of latent rows, ISSUE 42).  A lane's
-                # slot IS its lane: taken at admission, reset by the
-                # chunk that starts at 0, freed with the lane's pages
-                self._state_shapes = self.cfg.linear.state_shapes(
-                    self.slots)
-                state, tail = self._state_shapes
-                self.metrics.set_gauge("state_slots_total", self.slots)
-                self.metrics.set_gauge(
-                    "state_bytes_per_lane", len(self.cfg.state_layers) * (
-                        4 * int(numpy.prod(state[1:]))
-                        + embed.dtype.itemsize * int(numpy.prod(tail[1:]))))
-                if self.cfg.latent is None:
-                    self.metrics.set_gauge(
-                        "kv_bytes_per_token", 2 * kv_heads * head_dim
-                        * embed.dtype.itemsize * n_paged)
-            if model_config.SLIDING in self.cfg.kinds:
-                # two kinds of cache (ISSUE 28): a page table, an
-                # allocator and pools of their own for the sliding
-                # layers, where a lane never holds more than the
-                # window's pages
-                wpages = min(num_pages, self.slots
-                             * self.cfg.window_pages(self.prefill_chunk))
-                self._wt = WindowTables(
-                    KVPagePool(wpages, self.prefill_chunk), self.slots,
-                    self.cfg.window)
-                self._window_shape = (wpages + 1,) + self._storage_shape[1:]
-                self.metrics.set_gauge("kv_pages_total.window", wpages)
-        else:
-            self._storage_shape = (self.slots, kv_heads, self.max_len,
-                                   head_dim)
+                    "kv_bytes_per_token", 2 * kv_heads * head_dim
+                    * embed.dtype.itemsize * n_paged)
+        if model_config.SLIDING in self.cfg.kinds:
+            # two kinds of cache (ISSUE 28): a page table, an
+            # allocator and pools of their own for the sliding
+            # layers, where a lane never holds more than the
+            # window's pages
+            wpages = min(num_pages, self.slots
+                         * self.cfg.window_pages(self.prefill_chunk))
+            self._wt = WindowTables(
+                KVPagePool(wpages, self.prefill_chunk), self.slots,
+                self.cfg.window)
+            self._window_shape = (wpages + 1,) + self._storage_shape[1:]
+            self.metrics.set_gauge("kv_pages_total.window", wpages)
         self._storage_dtype = embed.dtype
-        self._set_storage(self._zero_storage())
+        self._kv_pools = self._zero_storage()
         #: (cache kind, its layers) for the count of the attention
         #: kernels' page steps (:meth:`_note_attn_dispatch`)
         self._layers_of_kind = sorted(collections.Counter(
             self.cfg.kind(i) for i in range(self._n_pools())
             if self.cfg.kind(i) != model_config.LINEAR).items())
         self._trie = (RadixPrefixCache(
-            prefix_cache, self.prefill_chunk,
-            on_evict=self._pool.release if self._paged else None)
+            prefix_cache, self.prefill_chunk, on_evict=self._pool.release)
             if prefix_cache else None)
         #: per-slot device-facing scalars, host-owned between ticks
         self._pos = numpy.zeros(self.slots, numpy.int32)
@@ -1111,10 +1061,10 @@ class LMEngine(Logger):
         self._lanes_gen = 0
         self._rr = 0
         #: two dispatches in flight (ISSUE 39), the worker thread's own:
-        #: the paged programs' ``last`` argument as the dispatch before
+        #: the programs' ``last`` argument as the dispatch before
         #: left it on the device (warm-up makes the first); the
         #: dispatches whose tokens are not fetched yet, oldest first
-        #: (:class:`_Flight`; only the paged plain driver leaves any),
+        #: (:class:`_Flight`; only the plain driver leaves any),
         #: and how many of them were there when the turn began
         self._last_dev = None
         self._flights = collections.deque()
@@ -1137,14 +1087,12 @@ class LMEngine(Logger):
         self.recorder = None
         self._build_jits()
         #: whether a decode step's tokens are fetched one dispatch late
-        #: (ISSUE 39): the paged plain driver's order, read off what was
-        #: built (the speculative driver drafts from the tokens, the
-        #: megastep fetches once in K, the contiguous layout dispatches
-        #: programs at admission)
-        self._late_fetch = bool(self._paged and self._verify_jit is None
-                                and self._megastep_jit is None)
-        if self._paged:
-            self._update_pool_gauges()
+        #: (ISSUE 39): the plain driver's order, read off what was built
+        #: (the speculative driver drafts from the tokens, the megastep
+        #: fetches once in K)
+        self._late_fetch = (self._verify_jit is None
+                            and self._megastep_jit is None)
+        self._update_pool_gauges()
 
     # ----------------------------------------------------------- placement
     def _fault(self, site):
@@ -1221,10 +1169,9 @@ class LMEngine(Logger):
 
     def _zero_storage(self):
         """Fresh zero KV storage — one (k, v) pair per block of the
-        pool's (paged) or the caches' (contiguous) shape — placed per
-        the engine's layout: head-sharded over the tp mesh, committed
-        to the replica's device, or left uncommitted (the
-        single-device default)."""
+        pool's shape — placed per the engine's layout: head-sharded
+        over the tp mesh, committed to the replica's device, or left
+        uncommitted (the single-device default)."""
         import jax
         import jax.numpy as jnp
         where = (self._kv_shard if self._mesh is not None
@@ -1254,7 +1201,7 @@ class LMEngine(Logger):
         return [(zeros(shape), zeros(shape)) for shape in shapes]
 
     def _zero_last(self):
-        """The paged programs' ``last`` argument before any dispatch has
+        """The programs' ``last`` argument before any dispatch has
         made one (ISSUE 39): zeros, placed where the programs return it
         (replicated over the tp mesh, committed to the replica's device,
         or left uncommitted), so the first call compiles the program every
@@ -1269,27 +1216,18 @@ class LMEngine(Logger):
         return jax.device_put(zeros, where)
 
     def _storage(self):
-        return self._kv_pools if self._paged else self._caches
+        return self._kv_pools
 
-    def _set_storage(self, storage):
-        if self._paged:
-            self._kv_pools = storage
-        else:
-            self._caches = storage
-
-    def _jit(self, fn, out_shardings=None, storage=None):
+    def _jit(self, fn, out_shardings, storage):
         """``jax.jit`` of one engine program.  ``storage`` is the
-        position of the KV storage (the pools when paged, the caches
-        when contiguous) among ``fn``'s arguments, for every program
-        that RETURNS the storage: that argument is DONATED, so the
-        compiled program updates it in place — no dispatch copies a
-        pool or holds a second one, and the caller's old tree is dead
-        the moment the call returns (every call site rebinds the
-        storage to the program's first output in the same statement;
-        :meth:`_donating` is the rule for a call that raises).  A
-        program that only reads the storage (``chunk_extract``) or
-        never sees it (``prefill``) passes None.  The parameters are
-        never donated.
+        position of the KV storage (the pools) among ``fn``'s
+        arguments: every program RETURNS the storage, and that argument
+        is DONATED, so the compiled program updates it in place — no
+        dispatch copies a pool or holds a second one, and the caller's
+        old tree is dead the moment the call returns (every call site
+        rebinds the storage to the program's first output in the same
+        statement; :meth:`_donating` is the rule for a call that
+        raises).  The parameters are never donated.
 
         Under a tp mesh the output layout is PINNED: without the pin,
         GSPMD's chosen output sharding compares unequal to the
@@ -1298,9 +1236,7 @@ class LMEngine(Logger):
         the jit-guard forbids (and an output laid out otherwise could
         not take the donated buffer)."""
         import jax
-        kwargs = {}
-        if storage is not None:
-            kwargs["donate_argnums"] = (storage,)
+        kwargs = {"donate_argnums": (storage,)}
         if out_shardings is not None:
             kwargs["out_shardings"] = out_shardings
         return jax.jit(fn, **kwargs)
@@ -1317,158 +1253,15 @@ class LMEngine(Logger):
 
     # ------------------------------------------------------------- jitted core
     def _build_jits(self):
-        import jax
-        import jax.numpy as jnp
-        from veles_tpu.ops.transformer import (block_decode_step,
-                                               chunk_apply, chunk_embed,
-                                               head_logits, prefill)
-        cfg, max_len = self.cfg, self.max_len
-        C = self.prefill_chunk
-        if self._paged:
-            self._build_paged_jits()
-            return
-
-        def prefill_one(params, prompt, true_len):
-            # prompt (1, bucket) int32, true_len traced: positions
-            # < true_len are exact under causal attention regardless of
-            # pad content (see transformer._generate_impl), so one
-            # compile serves every prompt length in the bucket
-            h, caches = prefill(params, prompt, cfg, max_len)
-            logits = head_logits(params, jax.lax.dynamic_slice_in_dim(
-                h, true_len - 1, 1, axis=1), cfg)[:, 0, :]
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[0]
-            return tok, caches
-
-        def install(caches, rows, slot):
-            # scatter one prefilled lane (rows of (1,H,L,D)) into the
-            # shared cache at a TRACED slot index — one compile total
-            return [(k.at[slot].set(rk[0]), v.at[slot].set(rv[0]))
-                    for (k, v), (rk, rv) in zip(caches, rows)]
-
-        def step_one(params, cache_rows, tok, pos):
-            # one lane, one token: feed ``tok`` at ``pos`` against this
-            # lane's cache rows; vmapped below over the slot axis so
-            # every lane advances in ONE dispatch at its own position
-            x = chunk_embed(params, tok[None, None], pos, cfg)
-            new_rows = []
-            for i, (blk, (kc, vc)) in enumerate(zip(params["blocks"],
-                                                    cache_rows)):
-                x, kc, vc = block_decode_step(
-                    blk, x, kc[None], vc[None], pos, cfg, layer=i)
-                new_rows.append((kc[0], vc[0]))
-            logits = head_logits(params, x, cfg)[0, 0, :]
-            return new_rows, jnp.argmax(logits).astype(jnp.int32)
-
-        kv_tree, repl = self._out_shard_trees()
-        pair = (kv_tree, repl) if kv_tree is not None else None
-        step_all = jax.vmap(step_one, in_axes=(None, 0, 0, 0))
-        # programs: prefill
-        self._prefill_jit = self._jit(
-            prefill_one, (repl, kv_tree) if kv_tree is not None else None)
-        # programs: install
-        self._install_jit = self._jit(install, kv_tree, storage=0)
-        # programs: step
-        self._step_jit = self._jit(step_all, pair, storage=1)
-
-        self._chunk_jit = None
-        self._chunk_install_jit = None
-        self._chunk_extract_jit = None
-        self._page_copy_jit = None
-        if C:
-            def chunk_slot(params, caches, tokens, slot, start,
-                           last_idx):
-                # one prompt chunk for ONE lane, straight into the
-                # shared caches at a TRACED (slot, start): positions
-                # [start, start+C) computed against everything already
-                # committed below them.  ``last_idx`` picks the chunk
-                # offset whose next-token argmax to return (only read
-                # on the final chunk).  One compile for every chunk of
-                # every prompt length.
-                rows = [(jax.lax.dynamic_slice_in_dim(kc, slot, 1, 0),
-                         jax.lax.dynamic_slice_in_dim(vc, slot, 1, 0))
-                        for kc, vc in caches]
-                h, rows = chunk_apply(params, tokens[None], rows, start,
-                                      cfg)
-                caches = [
-                    (jax.lax.dynamic_update_slice(kc, rk,
-                                                  (slot, 0, 0, 0)),
-                     jax.lax.dynamic_update_slice(vc, rv,
-                                                  (slot, 0, 0, 0)))
-                    for (kc, vc), (rk, rv) in zip(caches, rows)]
-                logits = head_logits(
-                    params, jax.lax.dynamic_slice_in_dim(
-                        h, last_idx, 1, axis=1), cfg)[:, 0, :]
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[0]
-                return caches, tok
-
-            def chunk_extract(caches, slot, start):
-                # copy one lane's chunk rows OUT (prefix-cache insert)
-                return [
-                    (jax.lax.dynamic_slice(
-                        kc, (slot, 0, start, 0),
-                        (1, kc.shape[1], C, kc.shape[3])),
-                     jax.lax.dynamic_slice(
-                        vc, (slot, 0, start, 0),
-                        (1, vc.shape[1], C, vc.shape[3])))
-                    for kc, vc in caches]
-
-            def chunk_install(caches, rows, slot, start):
-                # copy cached chunk rows IN (copy-on-install: the trie
-                # entry and the lane's rows never alias)
-                return [
-                    (jax.lax.dynamic_update_slice(kc, rk,
-                                                  (slot, 0, start, 0)),
-                     jax.lax.dynamic_update_slice(vc, rv,
-                                                  (slot, 0, start, 0)))
-                    for (kc, vc), (rk, rv) in zip(caches, rows)]
-
-            # programs: chunk
-            self._chunk_jit = self._jit(chunk_slot, pair, storage=1)
-            # programs: chunk_extract
-            self._chunk_extract_jit = self._jit(chunk_extract, kv_tree)
-            # programs: chunk_install
-            self._chunk_install_jit = self._jit(chunk_install, kv_tree,
-                                                storage=0)
-
-        self._verify_jit = None
-        verify_all = None
-        if self.spec_k:
-            def verify_one(params, cache_rows, toks, pos):
-                # toks (k+1,) = [last committed, draft…] fed at
-                # positions [pos, pos+k]; returns the greedy argmax
-                # AFTER each fed token — the host accepts the longest
-                # draft prefix that matches the verifier's own pick, so
-                # output is exact by construction
-                rows = [(kc[None], vc[None]) for kc, vc in cache_rows]
-                h, rows = chunk_apply(params, toks[None], rows, pos, cfg)
-                logits = head_logits(params, h, cfg)[0]  # (k+1, vocab)
-                out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return [(kc[0], vc[0]) for kc, vc in rows], out
-
-            verify_all = jax.vmap(verify_one, in_axes=(None, 0, 0, 0))
-            # programs: verify
-            self._verify_jit = self._jit(verify_all, pair, storage=1)
-
-        # ---- decode megastep (ISSUE 13): K fused iterations of the
-        # step (or propose→verify→accept) per dispatch — the scan body
-        # IS the vmapped program above, so any K is bit-identical to K
-        # repeated ticks; early-exit lanes freeze their carry (their
-        # writes land at their own frozen in-bounds rows, harmless —
-        # the lane is finished and its slot recycles at the boundary)
-        self._wire_megastep_jit(kv_tree, repl, step_all=step_all,
-                                verify_all=verify_all)
-
-    def _build_paged_jits(self):
-        """The PAGED program set — every shape is fixed by (slots,
-        max_pages, chunk, k), so the whole mixed-length workload
-        compiles exactly one program per family: ``_chunk_jit`` (one
-        lane, one prompt chunk), ``_step_jit`` (every lane, one token,
-        batched over the shared pool — vmap cannot carry a shared
-        mutable pool, so the batching is explicit), ``_verify_jit``
-        (every lane, k+1 speculative positions) and ``_page_copy_jit``
-        (copy-on-write).  The whole-prompt prefill/install/extract
-        programs have no paged counterpart (prefill is always chunked;
-        prefix hits install page IDS, not rows).
+        """The program set — every shape is fixed by (slots, max_pages,
+        chunk, k), so the whole mixed-length workload compiles exactly
+        one program per family: ``_chunk_jit`` (one lane, one prompt
+        chunk), ``_step_jit`` (every lane, one token, batched over the
+        shared pool — vmap cannot carry a shared mutable pool, so the
+        batching is explicit), ``_verify_jit`` (every lane, k+1
+        speculative positions) and ``_page_copy_jit`` (copy-on-write).
+        Prefill is always chunked; prefix hits install page IDS, not
+        rows.
 
         Two ISSUE 7 refinements: when the engine resolved
         ``attn_kernel`` active, every program's attention routes
@@ -1600,10 +1393,6 @@ class LMEngine(Logger):
         self._step_jit = self._jit(step_all, pair, storage=1)
         # programs: page_copy
         self._page_copy_jit = self._jit(page_copy, kv_tree, storage=0)
-        self._prefill_jit = None
-        self._install_jit = None
-        self._chunk_install_jit = None
-        self._chunk_extract_jit = None
         self._verify_jit = None
         if self.spec_k and not self._mtp:
             def verify_all(params, pools, ptabs, toks, pos):
@@ -1622,39 +1411,28 @@ class LMEngine(Logger):
         # decode megastep (ISSUE 13): the fused K-iteration program —
         # the page-table slice stays a traced-data argument, so the
         # compile bound is one program per (live-width ladder entry × K)
-        # family, K fixed per engine
-        self._wire_megastep_jit(kv_tree, repl)
+        # family, K fixed per engine; its outputs are (storage, last,
+        # pos, emitted[, accs])
+        self._megastep_jit = None
+        if self.megastep >= 2:
+            n_out = 5 if self.spec_k else 4
+            out_sh = ((kv_tree,) + (repl,) * (n_out - 1)
+                      if kv_tree is not None else None)
+            # programs: megastep
+            self._megastep_jit = self._jit(self._make_megastep_body(),
+                                           out_sh, storage=1)
 
     # --------------------------------------------------------- megastep
-    def _wire_megastep_jit(self, kv_tree, repl, step_all=None,
-                           verify_all=None):
-        """Build and jit the fused megastep program (or leave it None
-        below K=2) — THE one wiring both layout builders share, so the
-        output arity and the tp-mesh out_shardings pin (storage, last,
-        pos, emitted[, accs]) can never drift between them."""
-        self._megastep_jit = None
-        if self.megastep < 2:
-            return
-        mega = self._make_megastep_body(step_all=step_all,
-                                        verify_all=verify_all)
-        n_out = 5 if self.spec_k else 4
-        out_sh = ((kv_tree,) + (repl,) * (n_out - 1)
-                  if kv_tree is not None else None)
-        # programs: megastep
-        self._megastep_jit = self._jit(mega, out_sh, storage=1)
-
-    def _make_megastep_body(self, step_all=None, verify_all=None):
+    def _make_megastep_body(self):
         """Build the fused K-iteration decode program (ISSUE 13) for
-        this engine's layout and speculation mode — the scan body IS
-        the per-tick batched step (or propose → verify → accept leg),
-        so any K is bit-identical to K repeated host ticks by
-        construction.
+        this engine's speculation mode — the scan body IS the per-tick
+        batched step (or propose → verify → accept leg), so any K is
+        bit-identical to K repeated host ticks by construction.
 
-        Signature of the returned function, one per (layout, spec_k):
-        ``(params, storage[, ptabs], last, pos, left[, hist, hlen]) ->
-        (storage, last, pos, emitted[, accs])`` — ``ptabs`` on the
-        paged layout, ``hist, hlen`` and ``accs`` with ``spec_k`` —
-        where ``storage`` is the contiguous caches or the paged pools,
+        Signature of the returned function, one per ``spec_k``:
+        ``(params, storage, ptabs, last, pos, left[, hist, hlen]) ->
+        (storage, last, pos, emitted[, accs])`` — ``hist, hlen`` and
+        ``accs`` with ``spec_k`` — where ``storage`` is the pools,
         ``emitted`` is (K, slots) int32 — or (K, slots, spec_k+1)
         speculative — with -1 marking positions a frozen (early-exited
         or never-active) lane did not emit, and ``accs`` (K, slots)
@@ -1663,14 +1441,9 @@ class LMEngine(Logger):
 
         EARLY-EXIT MASKING: a lane whose ``left`` hits 0 freezes — its
         last token, position and history stop advancing, its emitted
-        slots read -1, and (paged) its K/V writes are redirected to
-        the scratch page via ``write_mask`` so a dead iteration can
-        never touch an allocated (possibly trie-shared) page.  On the
-        contiguous layout frozen writes land at the lane's own frozen
-        in-bounds row (the position clamp below keeps the speculative
-        write window inside [0, max_len)), which is harmless: the lane
-        is finished and its slot recycles at the boundary, exactly the
-        existing free-/prefilling-slot garbage-write discipline.
+        slots read -1, and its K/V writes are redirected to the scratch
+        page via ``write_mask`` so a dead iteration can never touch an
+        allocated (possibly trie-shared) page.
 
         SPECULATIVE leg: the draft comes from
         ``ops/transformer.py::propose_draft_in_graph`` over a carried
@@ -1681,28 +1454,23 @@ class LMEngine(Logger):
         spec_k composes with the megastep at zero host round-trips."""
         import jax
         import jax.numpy as jnp
+        from veles_tpu.ops.transformer import (head_logits,
+                                               paged_chunk_apply)
         K, k = self.megastep, self.spec_k
-        paged = self._paged
         cfg = self.cfg
         kern = self._kernel_active
         L = self.max_len
-        if paged:
-            from veles_tpu.ops.transformer import (head_logits,
-                                                   paged_chunk_apply)
 
         if not k:
             def plain_iter(params, storage, ptabs, carry):
                 last, pos, left = carry
                 active = left > 0
-                if paged:
-                    h, storage = paged_chunk_apply(
-                        params, last[:, None], storage, ptabs, pos, cfg,
-                        attn_kernel="decode" if kern else None,
-                        write_mask=active)
-                    logits = head_logits(params, h)[:, 0, :]
-                    toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                else:
-                    storage, toks = step_all(params, storage, last, pos)
+                h, storage = paged_chunk_apply(
+                    params, last[:, None], storage, ptabs, pos, cfg,
+                    attn_kernel="decode" if kern else None,
+                    write_mask=active)
+                logits = head_logits(params, h)[:, 0, :]
+                toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 emit = jnp.where(active, toks, -1)
                 last = jnp.where(active, toks, last)
                 pos = jnp.where(active, pos + 1, pos)
@@ -1720,10 +1488,7 @@ class LMEngine(Logger):
                     body, (storage, (last, pos, left)), None, length=K)
                 return storage, rest[0], rest[1], emitted
 
-            if paged:
-                return mega_plain
-            return lambda params, storage, last, pos, left: mega_plain(
-                params, storage, None, last, pos, left)
+            return mega_plain
 
         from veles_tpu.ops.transformer import propose_draft_in_graph
         ngram = self.spec_ngram
@@ -1741,15 +1506,12 @@ class LMEngine(Logger):
             active = left > 0
             draft, _found = propose_all(hist, hlen)
             toks = jnp.concatenate([last[:, None], draft], axis=1)
-            if paged:
-                h, storage = paged_chunk_apply(
-                    params, toks, storage, ptabs, pos, cfg,
-                    attn_kernel="decode" if kern else None,
-                    write_mask=active)
-                logits = head_logits(params, h)
-                out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            else:
-                storage, out = verify_all(params, storage, toks, pos)
+            h, storage = paged_chunk_apply(
+                params, toks, storage, ptabs, pos, cfg,
+                attn_kernel="decode" if kern else None,
+                write_mask=active)
+            logits = head_logits(params, h)
+            out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             # leading draft/argmax matches; accepted tokens ARE
             # out[:acc], so the emit window is simply out[:take]
             matches = (draft == out[:, :k]).astype(jnp.int32)
@@ -1787,10 +1549,7 @@ class LMEngine(Logger):
                 length=K)
             return storage, rest[0], rest[1], emitted, accs
 
-        if paged:
-            return mega_spec
-        return lambda params, storage, last, pos, left, hist, hlen: \
-            mega_spec(params, storage, None, last, pos, left, hist, hlen)
+        return mega_spec
 
     # --------------------------------------------------------------- lifecycle
     def _warmup(self):
@@ -1813,93 +1572,52 @@ class LMEngine(Logger):
         returns one storage leaf as it went into the last."""
         zero = xfer.to_device(0, numpy.int32)
         zeros = xfer.to_device(numpy.zeros(self.slots, numpy.int32))
-        if self._paged:
-            ptabs = numpy.zeros((self.slots, self._max_pages),
-                                numpy.int32)
-            # (the lanes' last tokens pass from one program's output to
-            # the next one's argument, as they will in traffic)
-            self._kv_pools, self._last_dev = self._chunk_jit(
-                self.params, self._kv_pools,
-                self._table_args(ptabs[0], 0),
-                xfer.to_device(numpy.zeros(
-                    self.prefill_chunk + int(self._mtp), numpy.int32)),
-                zero, zero,
-                xfer.to_device(-1, numpy.int32), self._zero_last())
-            self._kv_pools = self._page_copy_jit(self._kv_pools, zero,
-                                                 zero)
-            none = xfer.to_device(numpy.zeros(self.slots, bool))
-            # step/verify (or the fused megastep, which REPLACES them
-            # on the decode loop) compile one program per
-            # live-width ladder entry (ISSUE 7) — warm EVERY entry now,
-            # or the first request to cross each width boundary pays
-            # its compile inside the serving loop
-            for w in self._width_ladder:
-                wtab = self._table_args(ptabs[:, :w], slice(None))
-                if self._megastep_jit is not None:
-                    args = [self.params, self._kv_pools, wtab,
-                            zeros, zeros, zeros]
-                    if self.spec_k:
-                        args += [xfer.to_device(numpy.zeros(
-                            (self.slots, self.max_len), numpy.int32)),
-                            zeros]
-                    went_in = self._kv_pools[0][0]
-                    self._kv_pools = self._megastep_jit(*args)[0]
-                    continue
-                if self._verify_jit is not None:
-                    self._kv_pools, _ = self._verify_jit(
-                        self.params, self._kv_pools, wtab,
-                        xfer.to_device(numpy.zeros(
-                            (self.slots, self.spec_k + 1),
-                            numpy.int32)), zeros)
-                went_in = self._kv_pools[0][0]
-                self._kv_pools, self._last_dev = self._step_jit(
-                    self.params, self._kv_pools, wtab, self._last_dev,
-                    *(() if self._mtp else (zeros,)), none)[:2]
-        else:
-            tok, rows = self._prefill_jit(
-                self.params,
-                xfer.to_device(numpy.zeros(
-                    (1, prompt_bucket(1, self.max_len)), numpy.int32)),
-                xfer.to_device(1, numpy.int32))
-            self._caches = self._install_jit(self._caches, rows, zero)
-            if self._chunk_jit is not None:
-                self._caches, _ = self._chunk_jit(
-                    self.params, self._caches,
-                    xfer.to_device(numpy.zeros(self.prefill_chunk,
-                                               numpy.int32)), zero,
-                    zero, zero)
-                crows = self._chunk_extract_jit(self._caches, zero,
-                                                zero)
-                self._caches = self._chunk_install_jit(self._caches,
-                                                       crows, zero,
-                                                       zero)
+        ptabs = numpy.zeros((self.slots, self._max_pages),
+                            numpy.int32)
+        # (the lanes' last tokens pass from one program's output to
+        # the next one's argument, as they will in traffic)
+        self._kv_pools, self._last_dev = self._chunk_jit(
+            self.params, self._kv_pools,
+            self._table_args(ptabs[0], 0),
+            xfer.to_device(numpy.zeros(
+                self.prefill_chunk + int(self._mtp), numpy.int32)),
+            zero, zero,
+            xfer.to_device(-1, numpy.int32), self._zero_last())
+        self._kv_pools = self._page_copy_jit(self._kv_pools, zero,
+                                             zero)
+        none = xfer.to_device(numpy.zeros(self.slots, bool))
+        # step/verify (or the fused megastep, which REPLACES them
+        # on the decode loop) compile one program per
+        # live-width ladder entry (ISSUE 7) — warm EVERY entry now,
+        # or the first request to cross each width boundary pays
+        # its compile inside the serving loop
+        for w in self._width_ladder:
+            wtab = self._table_args(ptabs[:, :w], slice(None))
             if self._megastep_jit is not None:
-                args = [self.params, self._caches, zeros, zeros, zeros]
+                args = [self.params, self._kv_pools, wtab,
+                        zeros, zeros, zeros]
                 if self.spec_k:
                     args += [xfer.to_device(numpy.zeros(
                         (self.slots, self.max_len), numpy.int32)),
                         zeros]
-                went_in = self._caches[0][0]
-                self._caches = self._megastep_jit(*args)[0]
-            else:
-                if self._verify_jit is not None:
-                    self._caches, _ = self._verify_jit(
-                        self.params, self._caches,
-                        xfer.to_device(numpy.zeros(
-                            (self.slots, self.spec_k + 1),
-                            numpy.int32)), zeros)
-                went_in = self._caches[0][0]
-                self._caches, _ = self._step_jit(
-                    self.params, self._caches, zeros,
-                    xfer.to_device(numpy.ones(self.slots,
-                                              numpy.int32)))
+                went_in = self._kv_pools[0][0]
+                self._kv_pools = self._megastep_jit(*args)[0]
+                continue
+            if self._verify_jit is not None:
+                self._kv_pools, _ = self._verify_jit(
+                    self.params, self._kv_pools, wtab,
+                    xfer.to_device(numpy.zeros(
+                        (self.slots, self.spec_k + 1),
+                        numpy.int32)), zeros)
+            went_in = self._kv_pools[0][0]
+            self._kv_pools, self._last_dev = self._step_jit(
+                self.params, self._kv_pools, wtab, self._last_dev,
+                *(() if self._mtp else (zeros,)), none)[:2]
         return went_in
 
     def start(self):
         # warm every program before traffic: the discarded warmup
-        # writes land at positions of free slots (paged: the scratch
-        # page) that the next prefill/chunk overwrites — or a live
-        # mask excludes — before they are ever attended.  Warmup runs
+        # writes land on the scratch page.  Warmup runs
         # under the transfer-guard witness (dispatch arguments built
         # through the explicit xfer shims, like the worker loop).
         with xfer.guard():
@@ -2123,9 +1841,7 @@ class LMEngine(Logger):
                 self._queued_pages += req.pages
             self.metrics.set_gauge("queue_depth", len(self._queue))
             self.metrics.set_gauge("queue_tokens", self._queued_tokens)
-            if self._paged:
-                self.metrics.set_gauge("queue_pages",
-                                       self._queued_pages)
+            self.metrics.set_gauge("queue_pages", self._queued_pages)
         self.metrics.inc("requests_requeued_for_swap", len(reqs))
 
     # ------------------------------------------------------------------ client
@@ -2143,16 +1859,13 @@ class LMEngine(Logger):
             raise ValueError("prompt %d + n_new %d%s exceeds the engine "
                              "cache length %d"
                              % (len(prompt), n_new, extra, self.max_len))
-        demand = 0
-        if self._paged:
-            span = len(prompt) + n_new + self.headroom
-            demand = -(-span // self.prefill_chunk)
-            if demand > self._pool.num_pages:
-                raise ValueError(
-                    "prompt %d + n_new %d needs %d KV pages but the "
-                    "pool holds %d — this request can never be placed"
-                    % (len(prompt), n_new, demand,
-                       self._pool.num_pages))
+        span = len(prompt) + n_new + self.headroom
+        demand = -(-span // self.prefill_chunk)
+        if demand > self._pool.num_pages:
+            raise ValueError(
+                "prompt %d + n_new %d needs %d KV pages but the "
+                "pool holds %d — this request can never be placed"
+                % (len(prompt), n_new, demand, self._pool.num_pages))
         self._fault("engine.submit")
         # tracing (ISSUE 12): join the caller's request context (HTTP /
         # router) or root one here (direct engine use, benches) —
@@ -2189,7 +1902,7 @@ class LMEngine(Logger):
                 self.metrics.record_reject()
                 self.metrics.inc("rejected_tokens", len(prompt))
                 raise Overloaded()
-            if self._paged and self._queue and \
+            if self._queue and \
                     self._queued_pages + demand > 2 * self._pool.num_pages:
                 # pool-pressure admission: once TWO full pools' worth
                 # of page demand is queued (one generation decoding,
@@ -2233,9 +1946,7 @@ class LMEngine(Logger):
             self.metrics.set_gauge_max("queue_depth_peak",
                                        len(self._queue))
             self.metrics.set_gauge("queue_tokens", self._queued_tokens)
-            if self._paged:
-                self.metrics.set_gauge("queue_pages",
-                                       self._queued_pages)
+            self.metrics.set_gauge("queue_pages", self._queued_pages)
             self._cond.notify()
         return req.future
 
@@ -2310,7 +2021,7 @@ class LMEngine(Logger):
     def checkpoint(self):
         """JSON-safe snapshot of the HOST-side serving state (ISSUE
         10): every ADMITTED-but-unresolved request (the admission
-        journal), the slot frontiers, and — paged — the page tables
+        journal), the slot frontiers, the page tables
         and the pool's full ref/pin/free bookkeeping.  Taken under the
         engine lock, so the request set is consistent; cheap enough to
         take per admission tick.
@@ -2340,17 +2051,16 @@ class LMEngine(Logger):
                            "slots": self.slots,
                            "prefill_chunk": self.prefill_chunk,
                            "spec_k": self.spec_k,
-                           "paged_kv": bool(self._paged),
-                           "pool_pages": (self._pool.num_pages
-                                          if self._paged else 0)},
+                           # (format 1 has both; there is one layout)
+                           "paged_kv": True,
+                           "pool_pages": self._pool.num_pages},
                 "requests": entries,
                 "slot_frontiers": {
                     "pos": [int(x) for x in self._pos],
                     "last": [int(x) for x in self._last]},
             }
-            if self._paged:
-                state["pool"] = self._pool.snapshot()
-                state["page_tables"] = self._page_tables.tolist()
+            state["pool"] = self._pool.snapshot()
+            state["page_tables"] = self._page_tables.tolist()
             if self._trie is not None:
                 state["prefix_cache_chunks"] = self._trie.size
         return state
@@ -2395,8 +2105,7 @@ class LMEngine(Logger):
                     "journaled request rid=%s needs %d cache positions "
                     "but this engine holds %d"
                     % (entry.get("rid"), span, self.max_len))
-            if self._paged and -(-span // self.prefill_chunk) \
-                    > self._pool.num_pages:
+            if -(-span // self.prefill_chunk) > self._pool.num_pages:
                 raise ValueError(
                     "journaled request rid=%s needs %d KV pages but "
                     "this engine's pool holds %d — restore into a "
@@ -2429,7 +2138,7 @@ class LMEngine(Logger):
         return futures
 
     def verify_pool_invariants(self):
-        """Cross-check the paged allocator against the engine's OWN
+        """Cross-check the page allocator against the engine's OWN
         references (ISSUE 10): every page's refcount must equal the
         lane references (one per lane holding it, each also pinned)
         plus the trie references (one per node storing it), and the
@@ -2438,8 +2147,6 @@ class LMEngine(Logger):
         violated page; returns a summary dict when sound.  Call
         quiesced (no worker mid-tick) — the chaos tests run it after
         traffic drains and after restore."""
-        if not self._paged:
-            return {"paged": False}
         if self._wt is not None:
             self._wt.verify()
             for slot, lane in enumerate(self._lanes):
@@ -2493,15 +2200,13 @@ class LMEngine(Logger):
 
     # ------------------------------------------------------------------ worker
     def _admit(self):   # hot-path
-        """Move queued prompts into free slots.  Feature-off requests
-        (and chunked-ineligible ones) prefill whole at a power-of-two
-        bucket as before; with ``prefill_chunk`` the lane only LOOKS UP
-        the prefix cache and installs its hits here — compute chunks run
-        one per tick, interleaved with decode (no head-of-line block).
-        Paged mode additionally RESERVES the lane's worst-case pages;
-        when the pool cannot cover them the request goes BACK to the
-        queue head (FIFO — retried next tick as lanes free pages, shed
-        at its deadline) instead of wedging or being skipped."""
+        """Move queued prompts into free slots.  A lane only LOOKS UP
+        the prefix cache and takes its hits here (:meth:`_admit_paged`)
+        — compute chunks run one per tick, interleaved with decode (no
+        head-of-line block) — and RESERVES its worst-case pages; when
+        the pool cannot cover them the request goes BACK to the queue
+        head (FIFO — retried next tick as lanes free pages, shed at its
+        deadline) instead of wedging or being skipped."""
         if self._peek_swap() is not None:
             # a finish-on-old swap is quiescing: admitting now would
             # extend old-weights serving indefinitely — the queue
@@ -2517,9 +2222,7 @@ class LMEngine(Logger):
                 self.metrics.set_gauge("queue_depth", len(self._queue))
                 self.metrics.set_gauge("queue_tokens",
                                        self._queued_tokens)
-                if self._paged:
-                    self.metrics.set_gauge("queue_pages",
-                                           self._queued_pages)
+                self.metrics.set_gauge("queue_pages", self._queued_pages)
             if req is None:
                 return
             if req.cancelled:            # raced _cancel's dequeue
@@ -2534,131 +2237,28 @@ class LMEngine(Logger):
                         time.monotonic() - req.t_enq)))
                 continue
             slot = self._free.pop()
-            C = self.prefill_chunk
-            if self._paged:
-                if not self._admit_paged(slot, req):
-                    # pool pressure: back to the HEAD (order preserved;
-                    # deadline still sheds it) and stop admitting
-                    self._free.append(slot)
-                    self._pool_blocked = True
-                    with self._cond:
-                        self._queue.appendleft(req)
-                        self._queued_tokens += req.true_len
-                        self._queued_pages += req.pages
-                        self.metrics.set_gauge("queue_depth",
-                                               len(self._queue))
-                        self.metrics.set_gauge("queue_tokens",
-                                               self._queued_tokens)
-                        self.metrics.set_gauge("queue_pages",
-                                               self._queued_pages)
-                    return
-                continue
-            if C and ((req.true_len - 1) // C + 1) * C <= self.max_len:
-                self._admit_chunked(slot, req)
-                continue
-            bucket = prompt_bucket(req.true_len, self.max_len)
-            prompt = req.prompt
-            if bucket > req.true_len:
-                prompt = numpy.pad(prompt,
-                                   (0, bucket - req.true_len))
-            self._trace_admitted(req, slot)
-            t0p = time.monotonic()
-            try:
-                self._fault("engine.prefill")
-                tok, rows = self._prefill_jit(
-                    self.params,
-                    xfer.to_device(prompt[None], numpy.int32),
-                    xfer.to_device(req.true_len, numpy.int32))
-                with self._donating():
-                    self._caches = self._install_jit(
-                        self._caches, rows,
-                        xfer.to_device(slot, numpy.int32))
-                    self._tfence(self._caches, req.trace is not None)
-            except Exception as e:   # noqa: BLE001 — fails THIS request
-                # a prefill fault (bad bucket compile, device error)
-                # must fail its own request, not wedge the engine
-                self.metrics.record_error()
-                self.warning("prefill failed: %s", e)
-                if req.trace is not None:
-                    req.trace.tracer.add(
-                        req.trace, "prefill", "prefill", t0p,
-                        time.monotonic(),
-                        attrs={"bucket": bucket, "error": str(e)})
+            if not self._admit_paged(slot, req):
+                # pool pressure: back to the HEAD (order preserved;
+                # deadline still sheds it) and stop admitting
                 self._free.append(slot)
-                if not req.future.cancelled():
-                    req.future.set_exception(e)
-                continue
-            self.metrics.record_queue_wait(
-                time.monotonic() - req.t_enq)
-            self.metrics.inc("prefill_tokens", req.true_len)
-            if req.trace is not None:
-                req.trace.tracer.add(
-                    req.trace, "prefill", "prefill", t0p,
-                    time.monotonic(),
-                    attrs={"bucket": bucket,
-                           "backend": self._backend})
-            lane = _Slot(req)
-            self._lanes[slot] = lane
-            self._emit_first(slot, lane, int(xfer.to_host(tok)))
-
-    def _admit_chunked(self, slot, req):   # hot-path
-        """Chunked admission: match the prefix cache (full chunks only,
-        never the chunk holding the last prompt token — the tail must
-        run to produce the first token's logits), COPY hits into the
-        lane's cache rows, and queue the rest as per-tick chunk work."""
-        C = self.prefill_chunk
-        n_full = (req.true_len - 1) // C
-        self._trace_admitted(req, slot)
-        lane = _Slot(req)
-        matched = 0
-        if self._trie is not None:
-            keys = [tuple(int(t) for t in req.prompt[i * C:(i + 1) * C])
-                    for i in range(n_full)]
-            nodes = self._trie.match(keys)
-            lane.pinned.extend(nodes)
-            lane.cursor = nodes[-1] if nodes else self._trie.root
-            try:
-                with self._donating():
-                    for i, node in enumerate(nodes):
-                        self._caches = self._chunk_install_jit(
-                            self._caches, node.rows,
-                            xfer.to_device(slot, numpy.int32),
-                            xfer.to_device(i * C, numpy.int32))
-            except Exception as e:   # noqa: BLE001 — fails THIS request
-                self.metrics.record_error()
-                self.warning("prefix-cache install failed: %s", e)
-                self._teardown_slot(slot, lane, e)
+                self._pool_blocked = True
+                with self._cond:
+                    self._queue.appendleft(req)
+                    self._queued_tokens += req.true_len
+                    self._queued_pages += req.pages
+                    self.metrics.set_gauge("queue_depth",
+                                           len(self._queue))
+                    self.metrics.set_gauge("queue_tokens",
+                                           self._queued_tokens)
+                    self.metrics.set_gauge("queue_pages",
+                                           self._queued_pages)
                 return
-            matched = len(nodes)
-            self.metrics.inc("prefix_hit_chunks", matched)
-            self.metrics.inc("prefix_hit_tokens", matched * C)
-            # every contiguous hit is a device ROW COPY install — the
-            # cost the paged layout's page references eliminate
-            self.metrics.inc("kv_row_copies", matched * C)
-            self.metrics.set_gauge("prefix_cache_chunks",
-                                   self._trie.size)
-            if matched and req.trace is not None:
-                req.trace.tracer.instant(
-                    req.trace, "prefix.hit", cat="prefill",
-                    attrs={"chunks": matched, "tokens": matched * C})
-        for i in range(matched, n_full):
-            lane.pending.append((req.prompt[i * C:(i + 1) * C], i * C,
-                                 False))
-        tail = req.prompt[n_full * C:]
-        if len(tail) < C:
-            tail = numpy.pad(tail, (0, C - len(tail)))
-        lane.pending.append((tail, n_full * C, True))
-        self.metrics.record_queue_wait(time.monotonic() - req.t_enq)
-        self._lanes[slot] = lane
-        # park the step position at the chunk frontier: the vmapped
-        # decode dispatch steps EVERY slot, and a prefilling lane's
-        # garbage write must land where its own next chunk (<= C wide,
-        # and spec_k + 1 <= C) overwrites before anything attends it
-        self._pos[slot] = lane.pending[0][1]
 
-    # -------------------------------------------------------------- paged mode
     def _admit_paged(self, slot, req):   # hot-path
-        """Paged admission: reserve the lane's WORST-CASE page span up
+        """Admission: match the prefix cache (full chunks only, never
+        the chunk holding the last prompt token — the tail must run to
+        produce the first token's logits), queue the rest as per-tick
+        chunk work, and reserve the lane's WORST-CASE page span up
         front (no mid-decode allocation, so decode can never deadlock
         on pages), with prefix-cache hits substituting page REFERENCES
         (ref-count bump, no device work at all) for fresh pages.
@@ -2973,10 +2573,11 @@ class LMEngine(Logger):
         self.recorder.attn_pages(calls * given, calls * live)
 
     def kv_bytes_resident(self):
-        """Device bytes held for KV storage — the pool (paged) or the
-        contiguous slot caches; what the bench reports as footprint."""
+        """Device bytes held for KV storage (the pools, and the state
+        slots where layers keep one); what the bench reports as
+        footprint."""
         return sum(a.size * a.dtype.itemsize
-                   for pair in self._storage() for a in pair)
+                   for pair in self._kv_pools for a in pair)
 
     def _pick_prefill(self, prefilling):   # hot-path
         """The lane whose prompt chunk goes next, as ``(slot, lane)``: at
@@ -2992,121 +2593,16 @@ class LMEngine(Logger):
             return None
         return slot, lane
 
-    def _advance_prefill(self, slot, lane):   # hot-path
-        """Run ONE pending prompt chunk for this lane (a tick's worth of
-        prefill — decode lanes step in between, so a long prompt never
-        head-of-line-blocks them).  Computed full chunks feed the prefix
-        cache; the tail chunk yields the first generated token."""
-        req = lane.request
-        if self._paged:
-            chunk = self._prepare_chunk_paged(slot, lane, req)
-            if chunk is not None:
-                self._dispatch_chunk_paged(chunk)
-            return
-        tokens, start, is_tail = lane.pending.pop(0)
-        if not is_tail and self._trie is not None \
-                and lane.cursor is not None:
-            # LATE HIT: a sibling lane prefilling the same prompt may
-            # have inserted this very chunk since admission — install
-            # its rows instead of recomputing, so concurrent
-            # shared-prefix arrivals converge on ONE prefill
-            node = self._trie.lookup_child(
-                lane.cursor, tuple(int(t) for t in tokens))
-            if node is not None:
-                try:
-                    with self._donating():
-                        self._caches = self._chunk_install_jit(
-                            self._caches, node.rows,
-                            xfer.to_device(slot, numpy.int32),
-                            xfer.to_device(start, numpy.int32))
-                except Exception as e:   # noqa: BLE001 — this request
-                    self._trie.release([node])
-                    self.metrics.record_error()
-                    self.warning("prefix-cache install failed: %s", e)
-                    self._teardown_slot(slot, lane, e)
-                    return
-                lane.pinned.append(node)
-                lane.cursor = node
-                self.metrics.inc("prefix_hit_chunks")
-                self.metrics.inc("prefix_hit_tokens", len(tokens))
-                self.metrics.inc("kv_row_copies", len(tokens))
-                if req.trace is not None:
-                    req.trace.tracer.instant(
-                        req.trace, "prefix.hit", cat="prefill",
-                        attrs={"late": True, "start": start})
-                self._pos[slot] = lane.pending[0][1]
-                return
-        last_idx = (req.true_len - 1 - start) if is_tail else 0
-        t0 = time.monotonic()
-        try:
-            self._fault("engine.chunk")
-            args = (xfer.to_device(tokens, numpy.int32),
-                    xfer.to_device(slot, numpy.int32),
-                    xfer.to_device(start, numpy.int32),
-                    xfer.to_device(last_idx, numpy.int32))
-            rec = self.recorder
-            sent = rec.dispatch(tracing.PREFILL_DISPATCH, self._chunk_jit)
-            with self._donating():
-                self._caches, tok = self._chunk_jit(
-                    self.params, self._caches, *args)
-                rec.returned(sent)
-                if not is_tail and self._trie is not None \
-                        and lane.cursor is not None:
-                    rows = self._chunk_extract_jit(
-                        self._caches, xfer.to_device(slot, numpy.int32),
-                        xfer.to_device(start, numpy.int32))
-                    node = self._trie.insert(
-                        lane.cursor, tuple(int(t) for t in tokens),
-                        rows)
-                    if node is not None:
-                        lane.pinned.append(node)
-                    lane.cursor = node
-                    self.metrics.set_gauge("prefix_cache_chunks",
-                                           self._trie.size)
-                self._tfence(self._caches, req.trace is not None)
-                if is_tail:      # the first token crosses in here too
-                    rec.waiting(sent)
-                    tok = int(xfer.to_host(tok))
-                    rec.fetched(sent)
-        except Exception as e:   # noqa: BLE001 — fails THIS request
-            self.metrics.record_error()
-            self.warning("chunk prefill failed: %s", e)
-            if req.trace is not None:
-                # the FAILED dispatch is part of the timeline — the
-                # flight recorder must show where the request died (no
-                # backend attr: failed spans stay out of the ledger)
-                req.trace.tracer.add(
-                    req.trace, "prefill.chunk", "prefill", t0,
-                    time.monotonic(),
-                    attrs={"start": start, "error": str(e)})
-            self._teardown_slot(slot, lane, e)
-            return
-        self.metrics.inc("prefill_dispatches")
-        self._note_attn_dispatch()
-        self.metrics.inc("prefill_tokens",
-                         (req.true_len - start) if is_tail
-                         else len(tokens))
-        # enqueue time by design (a tail chunk's includes the wait for
-        # its token); device wall rides traced spans (_tfence)
-        self.metrics.record_decode_step(time.monotonic() - t0)
-        if req.trace is not None:
-            req.trace.tracer.add(
-                req.trace, "prefill.chunk", "prefill", t0,
-                time.monotonic(),
-                attrs={"start": start, "tail": is_tail,
-                       "bucket": self.prefill_chunk,
-                       "backend": self._backend})
-        if is_tail:
-            self._emit_first(slot, lane, tok)
-        else:
-            self._pos[slot] = lane.pending[0][1]
-
     def _prepare_chunk_paged(self, slot, lane, req):   # hot-path
-        """The lane's next pending prompt chunk with everything but its
-        jit call done, as a :class:`_Chunk`, or None where nothing is
-        left to dispatch: a LATE HIT swaps the lane's reserved page for a
-        REFERENCE to the sibling's page (release one, retain the other —
-        zero device work), and a guard that fails tears the lane down.
+        """The lane's next pending prompt chunk (ONE a turn — decode
+        lanes step in between, so a long prompt never head-of-line-blocks
+        them) with everything but its jit call done, as a
+        :class:`_Chunk`, or None where nothing is left to dispatch: a
+        LATE HIT (a sibling lane prefilling the same prompt has inserted
+        this very chunk since admission, so concurrent shared-prefix
+        arrivals converge on ONE prefill) swaps the lane's reserved page
+        for a REFERENCE to the sibling's page (release one, retain the
+        other — zero device work), and a guard that fails tears the lane down.
         The lane's step position moves to where the chunk will leave it
         (the next chunk's start; ``true_len`` behind a tail chunk), so a
         decode step prepared before the chunk goes out (ISSUE 37) parks
@@ -3300,7 +2796,7 @@ class LMEngine(Logger):
         if self._trie is not None and lane.pinned:
             self._trie.release(lane.pinned)
             lane.pinned = []
-        if self._paged and lane.pages:
+        if lane.pages:
             # ref-count release on lane finish: owned pages return to
             # the free list; shared (trie/sibling-referenced) pages
             # just lose this lane's reference and survive
@@ -3326,8 +2822,7 @@ class LMEngine(Logger):
         self._pos[slot] = 0
         self._last[slot] = 0
         self._unseen[slot] = 0
-        if self._paged:
-            self._page_tables[slot, :] = KVPagePool.SCRATCH
+        self._page_tables[slot, :] = KVPagePool.SCRATCH
         if self._state_shapes is not None:
             self._update_pool_gauges()
 
@@ -3379,9 +2874,9 @@ class LMEngine(Logger):
         rows in it and installs fresh storage BEFORE the exception
         reaches the handler, which then finds its own work already
         failed (``_teardown_slot`` tolerates that).
-        ``_kv_pools`` / ``_caches`` never point at deleted or poisoned
-        buffers when the loop takes its next turn."""
-        leaf = self._storage()[0][0]
+        ``_kv_pools`` never points at deleted or poisoned buffers when
+        the loop takes its next turn."""
+        leaf = self._kv_pools[0][0]
         try:
             yield
         except Exception as e:
@@ -3392,7 +2887,7 @@ class LMEngine(Logger):
     def _storage_lost(self, exc):
         """The KV storage went down with a failed dispatch
         (:meth:`_donating`): every request that holds pages or a slot
-        row — decoding lanes, prefilling lanes — fails with ``exc``;
+        of state — decoding lanes, prefilling lanes — fails with ``exc``;
         the prefix trie is dropped (its rows are gone); the page
         allocator comes home whole through those
         releases and every table row parks on scratch; fresh zero
@@ -3416,16 +2911,15 @@ class LMEngine(Logger):
         if self._trie is not None:
             self._trie.clear()
             self.metrics.set_gauge("prefix_cache_chunks", 0)
-        if self._paged:
-            self._page_tables[:] = KVPagePool.SCRATCH
-            self._update_pool_gauges()
+        self._page_tables[:] = KVPagePool.SCRATCH
+        self._update_pool_gauges()
         for flight in flights:
             for _, lane in flight.pairs:
                 if not lane.request.future.done():
                     lane.request.future.set_exception(exc)
         # a declared boundary: making the zeros is no hot-path transfer
         with xfer.boundary():
-            self._set_storage(self._zero_storage())
+            self._kv_pools = self._zero_storage()
             if self._last_dev is not None:
                 self._last_dev = self._zero_last()
         self.metrics.inc("kv_storage_rebuilds")
@@ -3466,7 +2960,7 @@ class LMEngine(Logger):
         dispatch's own record (ISSUE 38) takes the same stamps, and the
         jit call's return, under the handle ``sent``.
 
-        With ``pairs`` (the paged plain driver: the lanes the step
+        With ``pairs`` (the plain driver: the lanes the step
         advances) the fetch is ONE DISPATCH LATE (ISSUE 39): the outputs
         stay on the device as a :class:`_Flight`, the tokens as the next
         dispatch's ``last`` argument, and what the host waits for behind
@@ -3479,9 +2973,9 @@ class LMEngine(Logger):
         rec = self.recorder
         sent = rec.dispatch(tracing.STEP_DISPATCH, decode_jit, lanes)
         with self._donating():
-            out = decode_jit(self.params, self._storage(), *args)
+            out = decode_jit(self.params, self._kv_pools, *args)
             rec.returned(sent)
-            self._set_storage(out[0])
+            self._kv_pools = out[0]
             if pairs is not None:
                 if any(not flight.first for flight in self._flights):
                     # (the step before is still unfetched)
@@ -3500,16 +2994,14 @@ class LMEngine(Logger):
                     self.metrics.inc("pipeline_drains")
                     n = len(self._flights)
                 self._fetch_flights(n, True)
-                self._tfence(self._storage(),
+                self._tfence(self._kv_pools,
                              any(c is not None for c in tctxs))
                 return None
-            if under is not None:
-                under()
             rec.waiting(sent, tracing.STEP_FETCH)
             host = xfer.to_host(tuple(out[1:]))
             # (the storage as it is NOW: a copy-on-write made under the
             # step has donated ``out[0]`` on)
-            self._tfence(self._storage(),
+            self._tfence(self._kv_pools,
                          any(c is not None for c in tctxs))
         rec.fetched(sent, tracing.STEP_EMIT)
         return host
@@ -3627,13 +3119,9 @@ class LMEngine(Logger):
         # (rows a lane writes, and how far a lane that drafts may be
         # ahead of what the host has seen of it: ISSUE 40)
         rows = self.spec_k + 1 if self._mtp else 1
-        if self._paged:
-            active = self._cow_guard_active(active, self.headroom + rows)
-            if not active:
-                return None
-        w = None
-        tables = ()
-        live = None
+        active = self._cow_guard_active(active, self.headroom + rows)
+        if not active:
+            return None
         try:
             if self._wt is not None:
                 due = self._wt.due(self._pos)
@@ -3641,22 +3129,21 @@ class LMEngine(Logger):
                     if due[slot]:
                         p = int(self._pos[slot])
                         self._slide_window(slot, p, p + 1)
-            if self._paged:
-                w = self._live_width(rows)
-                if self._state_shapes is not None:
-                    self._decoding[:] = False
-                    self._decoding[active] = True
-                tables = (self._table_args(self._page_tables[:, :w],
-                                           slice(None)),)
-                # the lanes that decode, for the program to know whose
-                # token on the device is one (ISSUE 39): the linear
-                # kind's table argument carries the same mask
-                if self._state_shapes is not None:
-                    live = tables[0][1]
-                else:
-                    live = numpy.zeros(self.slots, bool)
-                    live[active] = True
-                    live = xfer.to_device(live)
+            w = self._live_width(rows)
+            if self._state_shapes is not None:
+                self._decoding[:] = False
+                self._decoding[active] = True
+            tables = (self._table_args(self._page_tables[:, :w],
+                                       slice(None)),)
+            # the lanes that decode, for the program to know whose
+            # token on the device is one (ISSUE 39): the linear
+            # kind's table argument carries the same mask
+            if self._state_shapes is not None:
+                live = tables[0][1]
+            else:
+                live = numpy.zeros(self.slots, bool)
+                live[active] = True
+                live = xfer.to_device(live)
             pos = self._pos + self._unseen
             return _Step([(slot, self._lanes[slot]) for slot in active],
                          w, tables,
@@ -3678,14 +3165,12 @@ class LMEngine(Logger):
 
         ``step`` holds the arguments made under the step before
         (:meth:`_under_step`; None: they are made here, the old order,
-        behind the outstanding fetches).  The paged layout takes ``last``
-        from the device as the dispatch before left it, does the rest of
-        its turn WHILE the device runs the step (:meth:`_under_step`, the
-        ``ahead.*`` phases), and then waits for the tokens of the step
-        BEFORE this one (:meth:`_dispatch_decode`, ISSUE 39); they wait
-        for the next stretch (:meth:`_deliver`).  The contiguous layout,
-        whose admission dispatches programs of its own, keeps the old
-        order: put ``last``, call, wait, count, commit and deliver."""
+        behind the outstanding fetches).  The step takes ``last`` from
+        the device as the dispatch before left it; the rest of the turn
+        is done WHILE the device runs the step (:meth:`_under_step`, the
+        ``ahead.*`` phases), and then the host waits for the tokens of
+        the step BEFORE this one (:meth:`_dispatch_decode`, ISSUE 39);
+        they wait for the next stretch (:meth:`_deliver`)."""
         made_ahead = step is not None
         if step is None:
             self._drain()
@@ -3700,24 +3185,19 @@ class LMEngine(Logger):
             tctxs = [lane.request.trace for _, lane in pairs]
         # (a step that verifies a draft keeps the verify span's name)
         span = "decode.verify" if self._mtp else "decode.step"
-        under, went = None, []
-        if self._paged:
-            def under():
-                went.append(True)
-                self._under_step(step, made_ahead)
+        went = []
+
+        def under():
+            went.append(True)
+            self._under_step(step, made_ahead)
         t0 = time.monotonic()
         try:
             self._fault("engine.step")
-            if self._paged:
-                self._dispatch_decode(
-                    self._step_jit, step.tables + (
-                        (self._last_dev, step.live_dev) if self._mtp else
-                        (self._last_dev, step.pos_dev, step.live_dev)),
-                    len(pairs), tctxs, under, pairs)
-            else:
-                toks = self._dispatch_decode(
-                    self._step_jit, (xfer.to_device(self._last.copy()),
-                                     step.pos_dev), len(pairs), tctxs)[0]
+            self._dispatch_decode(
+                self._step_jit, step.tables + (
+                    (self._last_dev, step.live_dev) if self._mtp else
+                    (self._last_dev, step.pos_dev, step.live_dev)),
+                len(pairs), tctxs, under, pairs)
         except Exception as e:   # noqa: BLE001 — fails the lanes
             if self._tracer is not None:
                 self._tracer.add_many(
@@ -3727,27 +3207,14 @@ class LMEngine(Logger):
             return
         self.metrics.record_decode_step(time.monotonic() - t0)
         if self._tracer is not None:
-            attrs = {"batch": len(pairs),
-                     "bucket": (step.width if step.width is not None
-                                else self.slots),
+            attrs = {"batch": len(pairs), "bucket": step.width,
                      "backend": self._backend}
             if self._mtp:
                 attrs["k"] = self.spec_k
             self._tracer.add_many(tctxs, span, "decode", t0,
                                   time.monotonic(), attrs=attrs)
-        if self._paged:
-            return
-        self._note_step(step)
-        self._advance_by_count(pairs)
-        toks = toks.tolist()
-        for slot, lane in pairs:
-            if self._lanes[slot] is lane:
-                self._last[slot] = toks[slot]
-            self._undelivered.append((slot, lane, toks[slot],
-                                      lane.remaining == 0, False))
-        self._deliver()
 
-    def _note_step(self, step, made_ahead=False):
+    def _note_step(self, step, made_ahead):
         """The counters of one plain decode dispatch."""
         self.metrics.record_dispatch(len(step.pairs))
         self.metrics.inc("decode_dispatches")
@@ -3895,10 +3362,9 @@ class LMEngine(Logger):
         greedy decode by construction, at < 1 dispatch/token whenever
         drafts hit."""
         k = self.spec_k
-        if self._paged:
-            active = self._cow_guard_active(active, k + 1)
-            if not active:
-                return
+        active = self._cow_guard_active(active, k + 1)
+        if not active:
+            return
         toks_in = numpy.zeros((self.slots, k + 1), numpy.int32)
         drafts = [None] * self.slots
         real_lens = [0] * self.slots
@@ -3921,19 +3387,15 @@ class LMEngine(Logger):
                 drafts[slot] = padded
                 real_lens[slot] = len(draft)
                 self.metrics.inc("draft_tokens", len(draft))
-        w = None
+        w = self._live_width(k + 1)
         tctxs = ()
         if self._tracer is not None:
             tctxs = [self._lanes[s].request.trace for s in active]
         t0 = time.monotonic()
         try:
             self._fault("engine.verify")
-            args = ()
-            if self._paged:
-                w = self._live_width(k + 1)
-                args = (xfer.to_device(self._page_tables[:, :w]),)
-            args += (xfer.to_device(toks_in),
-                     xfer.to_device(self._pos))
+            args = (xfer.to_device(self._page_tables[:, :w]),
+                    xfer.to_device(toks_in), xfer.to_device(self._pos))
             out, = self._dispatch_decode(self._verify_jit, args,
                                          len(active), tctxs)
         except Exception as e:   # noqa: BLE001 — fails the lanes
@@ -3952,8 +3414,7 @@ class LMEngine(Logger):
         if self._tracer is not None:
             self._tracer.add_many(
                 tctxs, "decode.verify", "decode", t0, time.monotonic(),
-                attrs={"batch": len(active), "k": k,
-                       "bucket": w if w is not None else self.slots,
+                attrs={"batch": len(active), "k": k, "bucket": w,
                        "backend": self._backend})
         for slot in active:
             lane = self._lanes[slot]
@@ -3995,10 +3456,9 @@ class LMEngine(Logger):
         # _cow_guard clamps to each lane's reservation, _live_width to
         # max_pages)
         span = K * (k + 1) + k if k else K
-        if self._paged:
-            active = self._cow_guard_active(active, span)
-            if not active:
-                return
+        active = self._cow_guard_active(active, span)
+        if not active:
+            return
         left = numpy.zeros(self.slots, numpy.int32)
         for slot in active:
             left[slot] = self._lanes[slot].remaining
@@ -4016,20 +3476,17 @@ class LMEngine(Logger):
                 hist[slot, :len(row)] = row
                 hlen[slot] = len(row)
             extra = (xfer.to_device(hist), xfer.to_device(hlen))
-        w = None
+        w = self._live_width(span)
         tctxs = ()
         if self._tracer is not None:
             tctxs = [self._lanes[s].request.trace for s in active]
         t0 = time.monotonic()
         try:
             self._fault("engine.step")
-            args = ()
-            if self._paged:
-                w = self._live_width(span)
-                args = (xfer.to_device(self._page_tables[:, :w]),)
-            args += (xfer.to_device(self._last),
-                     xfer.to_device(self._pos),
-                     xfer.to_device(left)) + extra
+            args = (xfer.to_device(self._page_tables[:, :w]),
+                    xfer.to_device(self._last),
+                    xfer.to_device(self._pos),
+                    xfer.to_device(left)) + extra
             last, pos, emitted, *accs = self._dispatch_decode(
                 self._megastep_jit, args, len(active), tctxs)
             accs = accs[0] if k else None
@@ -4086,8 +3543,7 @@ class LMEngine(Logger):
             self._tracer.add_many(
                 tctxs, "decode.megastep", "decode", t0, t1,
                 attrs={"batch": len(active), "K": K, "tokens": total,
-                       "bucket": "%sxK%d" % (w if w is not None
-                                             else self.slots, K),
+                       "bucket": "%sxK%d" % (w, K),
                        "backend": self._backend},
                 each_attrs=[{"lane_tokens": lane_tokens[s]}
                             for s in active])
@@ -4128,9 +3584,7 @@ class LMEngine(Logger):
             self._queue = keep
             self.metrics.set_gauge("queue_depth", len(self._queue))
             self.metrics.set_gauge("queue_tokens", self._queued_tokens)
-            if self._paged:
-                self.metrics.set_gauge("queue_pages",
-                                       self._queued_pages)
+            self.metrics.set_gauge("queue_pages", self._queued_pages)
         window = self.megastep if self.megastep >= 2 else 1
         for req in shed:
             self.metrics.record_shed()
@@ -4214,11 +3668,11 @@ class LMEngine(Logger):
         A turn begins when the step before has its tokens on the host
         (``rec.turn()``).  The old order, which every driver takes when
         no step was in flight (an idle engine, only prefilling lanes, the
-        first turn) and the speculative driver, the megastep and the
-        contiguous layout take always: tick (fault site, weight swap) ->
+        first turn) and the speculative driver and the megastep take
+        always: tick (fault site, weight swap) ->
         the tokens owed to the lanes -> shed and admit -> one prompt
         chunk's arguments and jit call -> the decode step's arguments,
-        jit call, wait, emit.  The paged plain driver does everything of
+        jit call, wait, emit.  The plain driver does everything of
         that which needs no token UNDER its step (:meth:`_under_step`,
         between the jit call's return and the wait), so the turn after is
         tick -> the prepared chunk's jit call -> the step's jit call
@@ -4284,7 +3738,11 @@ class LMEngine(Logger):
                     rec.mark(tracing.PREFILL_PREPARE)
                     picked = self._pick_prefill(prefilling)
                     if picked is not None:
-                        self._advance_prefill(*picked)
+                        slot, lane = picked
+                        chunk = self._prepare_chunk_paged(slot, lane,
+                                                          lane.request)
+                        if chunk is not None:
+                            self._dispatch_chunk_paged(chunk)
             else:
                 # admitted, chosen and prepared under the step before:
                 # the chunk goes out at once

@@ -47,8 +47,8 @@ _witness = None
 #: these runs serializes every other thread behind device wall time —
 #: the lock-held-across-dispatch class of bug the witness flags
 DISPATCH_SITES = frozenset((
-    "engine.prefill", "engine.chunk", "engine.cow", "engine.step",
-    "engine.verify", "engine.fence", "batcher.dispatch",
+    "engine.chunk", "engine.cow", "engine.step", "engine.verify",
+    "engine.fence", "batcher.dispatch",
 ))
 
 

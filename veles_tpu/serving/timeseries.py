@@ -147,10 +147,8 @@ def engine_program_cache_size(engine):
     families (test gear replaces ``_step_jit`` with a plain callable)
     and of jaxlibs without the introspection hook."""
     total = 0
-    for attr in ("_prefill_jit", "_install_jit", "_step_jit",
-                 "_chunk_jit", "_chunk_install_jit",
-                 "_chunk_extract_jit", "_verify_jit", "_page_copy_jit",
-                 "_megastep_jit"):
+    for attr in ("_step_jit", "_chunk_jit", "_verify_jit",
+                 "_page_copy_jit", "_megastep_jit"):
         fn = getattr(engine, attr, None)
         size = getattr(fn, "_cache_size", None)
         if size is None:

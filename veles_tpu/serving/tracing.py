@@ -758,7 +758,7 @@ def format_waterfall(record, width=40):
 #: argument that needs no token (``ahead.prepare``).  A turn that follows
 #: such a stretch skips ``loop.admit`` and ``prefill.prepare`` and its
 #: ``step.prepare`` is one put; a driver that cannot split its turn (the
-#: speculative one, the megastep, the contiguous layout) leaves the three
+#: speculative one, the megastep) leaves the three
 #: empty and every turn in the old order.
 PHASES = ("loop.tick", "loop.admit", "loop.wait", "prefill.prepare",
           "prefill.dispatch", "step.prepare", "step.dispatch",
