@@ -714,10 +714,101 @@ def test_kda_programs_update_state_and_pool_in_place(kda_engine, program):
     assert compiled_grouped_matmuls(text) == (0, 3 * 2)
 
 
+def test_ssd_kernels_compile_at_the_cells_widths(one_chip):
+    """ISSUE 46: the state-space rule's two kernels at 64 lanes and 64
+    heads of 128 x 64 in ONE group: the recurrent step without the delta
+    correction on the packed state (32 rows of two heads, 128 x 128, C and B
+    one a group), and the chunk kernel over a 1024-row chunk in inner chunks
+    of 128."""
+    from veles_tpu.ops import linear_attn
+    lanes, h, dk, dv = 64, 64, 128, 64
+    state = ((lanes, h // 2, dk, 2 * dv), F32)
+    text = compile_for(
+        one_chip,
+        lambda s, q, k, v, b, g, a: PK.gdn_decode(
+            s, q, k, v, b, g, a, correct=False, interpret=False),
+        state, ((lanes, 1, dk), F32), ((lanes, 1, dk), F32),
+        ((lanes, h, dv), F32), ((lanes, h), F32), ((lanes, h), F32),
+        ((lanes,), jnp.bool_))
+    assert "tpu_custom_call" in text
+    row = lambda *tail: ((1, 1024) + tail, F32)  # noqa: E731
+    text = compile_for(
+        one_chip,
+        lambda s, sl, fr, *rows: PK.ssd_chunk(
+            s, sl, fr, *rows, chunk=linear_attn.SSD_CHUNK, interpret=False),
+        state, ((1,), I32), ((1,), jnp.bool_), row(1, dk), row(1, dk),
+        row(h, dv), row(h), row(h))
+    assert "tpu_custom_call" in text
+
+
+@pytest.fixture(scope="module")
+def ssd_engine(one_chip):
+    """A small ``LMEngine`` for two state-space layers around one plain
+    attention layer, the Pallas serving kernels active, 64 lanes, the
+    published head sizes (one group, heads of 128 x 64 packed two a row;
+    attention heads of 64) in bfloat16."""
+    from benchmark.reference import granite_hybrid
+    from veles_tpu import model_config
+    from veles_tpu.serving import LMEngine
+    cfg = {
+        "model_type": "granitemoehybrid", "hidden_size": 256,
+        "num_attention_heads": 4, "num_key_value_heads": 2,
+        "intermediate_size": 512, "shared_intermediate_size": 512,
+        "mamba_n_heads": 8, "mamba_d_head": 64, "mamba_n_groups": 1,
+        "mamba_d_state": 128, "mamba_d_conv": 4, "mamba_expand": 2,
+        "mamba_conv_bias": True, "mamba_proj_bias": False,
+        "embedding_multiplier": 12, "attention_multiplier": 0.015625,
+        "residual_multiplier": 0.22, "logits_scaling": 8,
+        "position_embedding_type": "nope", "tie_word_embeddings": True,
+        "num_local_experts": 0, "num_experts_per_tok": 0,
+        "rms_norm_eps": 1e-5, "num_hidden_layers": 3,
+        "layer_types": ["mamba", "attention", "mamba"], "vocab_size": 512,
+        "initializer_std": 0.02}
+    params = granite_hybrid.make_weights(1, cfg)
+    monkeypatch = pytest.MonkeyPatch()
+    monkeypatch.setattr(PK, "on_tpu", lambda: True)
+    try:
+        engine = LMEngine(params, model_config.from_published(cfg),
+                          max_len=2048, slots=64, prefill_chunk=256,
+                          paged_kv=128, attn_kernel="auto", name="aot_ssd")
+        assert engine._kernel_active
+        yield described(engine, one_chip)
+    finally:
+        monkeypatch.undo()
+
+
+@pytest.mark.parametrize("program", ["chunk", "decode_w8"])
+def test_ssd_programs_update_state_and_pool_in_place(ssd_engine, program):
+    """ISSUE 46: compiled for the chip, neither program copies a packed
+    state, a convolution tail or a pool, every leaf is listed under
+    ``input_output_alias``, and each state-space layer's kernel stands in
+    the program under the scope the benchmark's readers find it by
+    (``ssd.chunk`` in the chunk program, ``ssd.decode`` in the step)."""
+    import re
+    from veles_tpu.serving.lm_engine import compiled_storage_report
+    engine = ssd_engine[0]
+    text = program_text(ssd_engine, program)
+    leaves = jax.tree.leaves(engine._kv_pools)
+    kinds = {leaf.shape: leaf for leaf in leaves}
+    assert set(kinds) == {(64, 4, 128, 128), (64, 3, 768),
+                          (129, 1, 256, 128)}
+    for leaf in kinds.values():
+        copies, aliased = compiled_storage_report(text, leaf)
+        assert copies == 0, "%d copies of %s in %s" % (copies, leaf.shape,
+                                                       program)
+    assert aliased == len(leaves) == 6
+    scope = "ssd.chunk" if program == "chunk" else "ssd.decode"
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*'
+                       r'op_name="[^"]*attn\.linear/%s/pallas_call'
+                       % re.escape(scope), text)
+    assert len(calls) == 2
+
+
 @pytest.mark.parametrize("program", PROGRAMS)
 @pytest.mark.parametrize("fixture", ["kernel_engine", "kinds_engine",
                                      "latent_engine", "linear_engine",
-                                     "mtp_engine", "kda_engine"])
+                                     "mtp_engine", "kda_engine",
+                                     "ssd_engine"])
 def test_engine_programs_read_the_weights_where_they_lie(request, fixture,
                                                          program):
     """ISSUE 31: compiled for the chip, no engine program holds a copy

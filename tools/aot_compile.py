@@ -8,7 +8,7 @@ script holds the whole-program ones, which take up to minutes and are run
 by hand::
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \\
-        python tools/aot_compile.py [alexnet] [lm] [flash] [latent] [linear] [kda] [mtp] [mesh] [tp]
+        python tools/aot_compile.py [alexnet] [lm] [flash] [latent] [linear] [kda] [ssd] [mtp] [mesh] [tp]
 
 - ``alexnet``: the graph loop's train step and the epoch-scan window
   program at minibatch 128, 227x227 crops, 1000 classes, fp32 and bf16;
@@ -430,7 +430,9 @@ def linear(one_chip, config="qwen3-next-80b-a3b-ep4", tag="engine linear"):
     grouped matmul at 640 assignment rows) at the narrowest and the widest
     table.  ``kda`` (ISSUE 42) is the same for ``ling-3.0-flash-vl-ep4``:
     a decay a key channel, the full layer latent (one pool), 512 assignment
-    rows a decode step."""
+    rows a decode step.  ``ssd`` (ISSUE 46) for ``granite-4.0-h-micro``: all
+    40 layers, 36 of them the state-space rule on a packed state, no expert
+    layer, a tied head over 100352 rows."""
     import importlib
     import json
     from veles_tpu import model_config
@@ -506,6 +508,8 @@ def main(argv):
         linear(one_chip)
     if "kda" in want:
         linear(one_chip, "ling-3.0-flash-vl-ep4", "engine kda")
+    if "ssd" in want:
+        linear(one_chip, "granite-4.0-h-micro", "engine ssd")
     if "mtp" in want:
         mtp(one_chip)
     if "mesh" in want:

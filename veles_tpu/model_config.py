@@ -45,6 +45,14 @@ Two blocks:
   the first ``partial_rotary`` of a head.  ``norm_centred``: every RMSNorm
   gain is stored about zero (``1 + w``).
 
+  The ``linear`` layers' rule may be the state-space one
+  (``LinearConfig.rule == "ssd"``: Mamba-2, the same slots under a rule
+  without the delta correction); beside them the ``full`` layers may be
+  PLAIN grouped-query attention (``plain_full``) without any position
+  signal (``rope`` false), every layer's feed forward dense, and the stack
+  may carry Granite's four multipliers and a tied head (``embed_mult``,
+  ``residual_mult``, ``attn_scale``, ``logits_div``, ``tied``).
+
 A record with ``attn_kinds`` naming both ``sliding`` and ``full`` layers has
 two kinds of KV cache (``kinds``): the engine keeps a page table and an
 allocator for each.  A latent record has one kind (``full``): one table,
@@ -143,7 +151,17 @@ class LinearConfig:
     (Gated DeltaNet: ``g = -exp(A_log) softplus(a + dt_bias)``) or by one a
     key ``channel`` (Kimi Delta Attention: ``g = lower_bound sigmoid(
     exp(A_log) (x W_f + dt_bias))``, in ``(lower_bound, 0)``); ``gate``: the
-    output gate is ``silu`` or ``sigmoid`` of ``x W_z``."""
+    output gate is ``silu`` or ``sigmoid`` of ``x W_z``.
+
+    ``rule == "ssd"``: a state-space layer (Mamba-2, arXiv:2405.21060) in
+    the same slots: ``k_heads`` GROUPS whose one ``B`` (the key) and one
+    ``C`` (the query) of ``k_dim`` (the state size) serve ``v_heads /
+    k_heads`` heads of ``v_dim`` each, the convolution over ``[x | B | C]``
+    WITH a bias, ``dt = softplus(x W_dt + dt_bias)`` in ``beta``'s place and
+    ``g = -exp(A_log) dt``; the rule has NO delta correction (``S <-
+    exp(g) S + B (dt x)^T``; ``y = S^T C + D x``), no L2 norm and no scale
+    on ``C``, and the output is ``(rms(y * silu(z)) * w_n) W_o`` with the
+    norm over the WHOLE inner width, after the gate."""
     k_heads: int
     v_heads: int
     k_dim: int
@@ -154,12 +172,21 @@ class LinearConfig:
     #: the least log decay a token (``channel`` only): the chunked rule's
     #: exponents about a block's first row stay under 16 x this
     lower_bound: Optional[float] = None
+    rule: str = "delta"               # | "ssd"
 
     def __post_init__(self):
         if self.decay not in ("head", "channel") \
                 or self.gate not in ("silu", "sigmoid"):
             raise ValueError("unknown decay %r or gate %r"
                              % (self.decay, self.gate))
+        if self.rule not in ("delta", "ssd") or (
+                self.rule == "ssd" and (self.decay, self.gate)
+                != ("head", "silu")):
+            raise ValueError("unknown rule %r (the state-space rule decays "
+                             "by head under a silu gate)" % (self.rule,))
+        if self.v_heads % self.k_heads:
+            raise ValueError("k_heads %d must divide v_heads %d"
+                             % (self.k_heads, self.v_heads))
         if (self.decay == "channel") != (self.lower_bound is not None):
             raise ValueError("a decay per channel and its lower_bound come "
                              "together")
@@ -180,15 +207,34 @@ class LinearConfig:
 
     @property
     def conv_width(self):
-        """Channels the convolution runs over: ``[q | k | v]``."""
+        """Channels the convolution runs over: ``[q | k | v]`` (``[x | B |
+        C]`` of a state-space layer: the same sum)."""
         return 2 * self.key_width + self.value_width
+
+    @property
+    def pack(self):
+        """Value heads that lie side by side in one row of the stored state
+        (a state-space layer's only): as many heads of one group as fill the
+        chip's 128 lanes (2 of 64).  The chip pads an array's minor axis to
+        128 lanes whatever is asked for, so a state of (.., k_dim, 64) would
+        hold and move twice its bytes; the heads of a group share ``B`` and
+        ``C``, so a row of several heads' values is updated by one outer
+        product and read by one sum, as a head of the wider value would
+        be."""
+        if self.rule != "ssd":
+            return 1
+        r = max(1, 128 // self.v_dim)
+        while (self.v_heads // self.k_heads) % r:
+            r -= 1
+        return r
 
     def state_shapes(self, slots):
         """(recurrent state, convolution tail) of ``slots`` sequences: ``S``
-        (slots, v_heads, k_dim, v_dim), always float32, and the last
-        ``conv - 1`` pre-convolution rows (slots, conv - 1, conv_width) in
-        the model's dtype."""
-        return ((slots, self.v_heads, self.k_dim, self.v_dim),
+        (slots, v_heads / pack, k_dim, pack x v_dim), always float32, and
+        the last ``conv - 1`` pre-convolution rows (slots, conv - 1,
+        conv_width) in the model's dtype."""
+        r = self.pack
+        return ((slots, self.v_heads // r, self.k_dim, r * self.v_dim),
                 (slots, self.conv - 1, self.conv_width))
 
 
@@ -256,6 +302,20 @@ class ModelConfig:
     #: output and the next token's embedding (``ops/transformer.py::
     #: mtp_forward``); ``ffn_kinds`` names its layer behind the stack's
     nextn: int = 0
+    #: Granite's four multipliers (``pre_rms`` over one stream): the token
+    #: rows times ``embed_mult``; every sublayer's output times
+    #: ``residual_mult`` as it joins the stream; the softmax scale of the
+    #: ``full`` layers in ``head_dim ** -0.5``'s place; the logits divided
+    #: by ``logits_div``
+    embed_mult: Optional[float] = None
+    residual_mult: float = 1.0
+    attn_scale: Optional[float] = None
+    logits_div: float = 1.0
+    #: the head is the embedding's transpose: the tree carries no ``head``
+    tied: bool = False
+    #: the ``full`` layers beside linear ones are PLAIN grouped-query
+    #: attention: no output gate in ``wq``, no per-head norms
+    plain_full: bool = False
 
     def __post_init__(self):
         if self.block not in ("pre_ln", "sandwich", "pre_rms"):
@@ -272,6 +332,14 @@ class ModelConfig:
                 and self.linear is None:
             raise ValueError("a latent stack names its layers' kinds only "
                              "beside linear layers")
+        if (self.embed_mult is not None or self.residual_mult != 1.0
+                or self.attn_scale is not None or self.logits_div != 1.0
+                or self.tied or self.plain_full) and (
+                    self.block != "pre_rms" or self.latent is not None
+                    or self.hyper is not None):
+            raise ValueError("the multipliers, the tied head and plain full "
+                             "layers belong to the pre_rms block over one "
+                             "stream, without latent attention")
         if self.nextn not in (0, 1) or (self.nextn and (
                 self.latent is None or self.hyper is not None
                 or self.linear is not None)):
@@ -362,6 +430,16 @@ class ModelConfig:
     def embed_scale(self, d_model):
         return math.sqrt(d_model) if self.block == "sandwich" else None
 
+    def query_scale(self, head_dim):
+        """What a ``full`` layer's queries are multiplied by BEFORE the
+        attention (whose softmax scale stays ``head_dim ** -0.5``, in the
+        kernels too): 1, or ``attn_scale x sqrt(head_dim)``, so that the
+        scores come out times ``attn_scale`` (1/8 for 1/64 at a head of 64:
+        a power of two, exact in any dtype)."""
+        if self.attn_scale is None:
+            return 1.0
+        return self.attn_scale * math.sqrt(head_dim)
+
     @property
     def wide(self):
         """The residual stream is float32 whatever the model's dtype."""
@@ -393,11 +471,14 @@ def of(cfg_or_heads, rope=False, window=None, sinks=0):
 
 def from_published(cfg):
     """The record of a published ``config.json`` (a dict under its own
-    keys), by ``model_type``.  ``afmoe``, ``qwen3_next``,
+    keys), by ``model_type``.  ``granitemoehybrid`` is read where it has no
+    routed experts (Granite 4.0-H Micro).  ``afmoe``, ``qwen3_next``,
     ``joyai_llm_flash`` and ``ling3_flash`` also read two keys of a
     deployment's share where they are given: ``held_experts`` ``[lo, n]``
     (this tree's experts, of ``router_width`` that the router scores)."""
     family = cfg.get("model_type")
+    if family == "granitemoehybrid":
+        return _granite_hybrid(cfg)
     if family == "ling3_flash":
         return _ling3(cfg)
     if family == "xing4_0":
@@ -616,4 +697,56 @@ def _ling3(cfg):
                       held=None if held is None else tuple(held),
                       shared=cfg["moe_shared_expert_intermediate_size"] > 0,
                       n_group=cfg["n_group"], topk_group=cfg["topk_group"]),
+        dtype=cfg.get("dtype", "bfloat16"), eps=cfg["rms_norm_eps"])
+
+
+def _granite_hybrid(cfg):
+    """``model_type: granitemoehybrid`` WITHOUT routed experts
+    (``num_local_experts`` 0: IBM Granite 4.0-H Micro): ``layer_types`` of
+    ``mamba`` (a Mamba-2 state-space mixer, ``LinearConfig.rule == "ssd"``)
+    and ``attention`` (plain grouped-query attention with NO position signal
+    at all, ``position_embedding_type`` ``nope``), a dense gated-SiLU feed
+    forward of ``shared_intermediate_size`` after EVERY mixer, Granite's four
+    multipliers and a tied head.  What the record cannot compute it refuses
+    by key."""
+    def refuse(key, why):
+        raise ValueError("granitemoehybrid: %s %r: %s"
+                         % (key, cfg.get(key), why))
+
+    for key in ("num_local_experts", "num_experts_per_tok", "attention_bias",
+                "mamba_proj_bias", "rope_scaling"):
+        if cfg.get(key):
+            refuse(key, "not computed here (the routed experts beside a "
+                   "state-space mixer are ROADMAP's)"
+                   if key.startswith("num_") else "not computed here")
+    for key, want in (("position_embedding_type", "nope"),
+                      ("normalization_function", "rmsnorm"),
+                      ("hidden_act", "silu"), ("mamba_conv_bias", True),
+                      ("tie_word_embeddings", True),
+                      ("intermediate_size", cfg["shared_intermediate_size"]),
+                      ("mamba_expand", cfg["mamba_n_heads"]
+                       * cfg["mamba_d_head"] // cfg["hidden_size"])):
+        if cfg.get(key, want) != want:
+            refuse(key, "only %r is computed here" % (want,))
+    types = cfg["layer_types"]
+    n = cfg["num_hidden_layers"]
+    names = {"mamba": LINEAR, "attention": FULL}
+    if len(types) != n or set(types) - set(names):
+        raise ValueError("granitemoehybrid: layer_types names %d layers of "
+                         "%d, of kinds %r" % (len(types), n, set(types)))
+    heads = cfg["num_attention_heads"]
+    return ModelConfig(
+        n_heads=heads, block="pre_rms",
+        n_kv_heads=cfg["num_key_value_heads"],
+        head_dim=cfg.get("head_dim") or cfg["hidden_size"] // heads,
+        rope=False, attn_kinds=tuple(names[t] for t in types),
+        linear=LinearConfig(
+            k_heads=cfg["mamba_n_groups"], v_heads=cfg["mamba_n_heads"],
+            k_dim=cfg["mamba_d_state"], v_dim=cfg["mamba_d_head"],
+            conv=cfg["mamba_d_conv"], rule="ssd"),
+        ffn_kinds=(DENSE,) * n,
+        embed_mult=float(cfg["embedding_multiplier"]),
+        residual_mult=float(cfg["residual_multiplier"]),
+        attn_scale=float(cfg["attention_multiplier"]),
+        logits_div=float(cfg["logits_scaling"]), tied=True, plain_full=True,
         dtype=cfg.get("dtype", "bfloat16"), eps=cfg["rms_norm_eps"])

@@ -385,7 +385,8 @@ def _heads(params, x, flat, cfg):
     """q (b, h, s, dh), k and v (b, kv, s, dh) from their ``flat``
     projections, and the output gate (b, s, h·dh; None where the block has
     none) of ``x`` (b, s, d), not yet rotated.  The ``sandwich`` block
-    norms q and k per head."""
+    norms q and k per head; a ``pre_rms`` stack's gated layers too, where
+    they are not plain (``cfg.plain_full``)."""
     b, s, d = x.shape
     dh = cfg.head_size(d)
     kv = cfg.kv_heads(params, d)
@@ -394,6 +395,10 @@ def _heads(params, x, flat, cfg):
         return y.reshape(b, s, heads, dh).transpose(0, 2, 1, 3)
 
     q, k, v = flat
+    if cfg.plain_full:
+        # plain grouped-query heads; the record's softmax scale rides on q
+        q = split(q, cfg.n_heads) * jnp.asarray(cfg.query_scale(dh), q.dtype)
+        return q, split(k, kv), split(v, kv), None
     if cfg.block == "pre_rms":
         # the output gate is the second half of each head's ``wq`` columns
         q = q.reshape(b, s, cfg.n_heads, 2 * dh)

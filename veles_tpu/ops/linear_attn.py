@@ -27,6 +27,26 @@ channel), the rule's first line becomes ``S <- diag(exp(g_t)) S``, and the
 output gate is ``sigmoid(z_t)`` (``linear.gate``).  Everywhere below ``g`` is
 ``(.., h)`` or ``(.., h, k_dim)`` and the same functions take both.
 
+THE STATE-SPACE RULE (``linear.rule == "ssd"``: Mamba-2, arXiv:2405.21060) is
+the same recurrence WITHOUT the delta correction, in the same slots: ``[x | B
+| C] = x W_qkv`` (``v_heads`` heads of ``v_dim``, then ``k_heads`` GROUPS of
+``k_dim`` twice: a group's one ``B`` is the key and its one ``C`` the query of
+``v_heads / k_heads`` heads), the convolution WITH a bias (``conv_bias``), ``dt
+= softplus(x W_dt + dt_bias)`` in ``beta``'s place, ``g = -exp(A_log) dt``, no
+L2 norm and no scale::
+
+    S <- exp(g_t) S + B_t (dt_t x_t)^T;  y_t = S^T C_t + D x_t
+
+(``d = beta v`` where the delta rule has ``beta (v - S^T k)``: ``correct`` is
+false), and the output is ``(rms(y_t * silu(z_t)) * w_n) W_o`` with the norm
+over the WHOLE inner width, after the gate.  Its stored state packs
+``linear.pack`` heads of a group side by side in one row (``(slots, v_heads /
+pack, k_dim, pack x v_dim)``): the functions here see heads apart
+(``_apart`` / ``_packed``), the kernels take the rows as they lie.  Chunked,
+``U = beta V``, ``W`` is absent and nothing is solved; the inner chunk is
+``SSD_CHUNK`` rows, and ``pallas_kernels.ssd_chunk`` computes ``C B^T`` and the
+key-side products once a chunk for all of a group's heads.
+
 WHAT A SEQUENCE KEEPS is ``S`` of every value head (float32) and the last ``conv -
 1`` rows of ``[q | k | v]`` BEFORE the convolution, whatever its length.
 
@@ -65,8 +85,9 @@ every accumulator are float32 whatever the model's dtype; the dots of the
 rule itself run at ``HIGHEST`` (they are small: the state is the cost).
 Everything here runs under the scope ``attn.linear``; the Pallas calls of a
 decay per channel under ``kda.decode`` / ``kda.chunk`` inside it (a call
-takes its innermost scope's name, and the benchmark's readers tell the two
-rules' kernels apart by it).
+takes its innermost scope's name, and the benchmark's readers tell the
+rules' kernels apart by it), those of the state-space rule under ``ssd.decode``
+/ ``ssd.chunk``.
 """
 
 from __future__ import annotations
@@ -80,6 +101,9 @@ from veles_tpu.ops.attention import cfg_matmul
 
 #: rows of one inner chunk of the chunked rule
 CHUNK = 64
+#: rows of one inner chunk of the state-space rule (nothing is solved, so
+#: the block may be as wide as the chip's matrix unit)
+SSD_CHUNK = 128
 #: rows of one block of the pairwise decays of a decay per channel (the block
 #: of ``solve_unit_lower``)
 SUB = 16
@@ -94,8 +118,8 @@ def _f32(x):
 # ------------------------------------------------------------ projections
 def _inputs(p, x, cfg, cached):
     """(qkv (b, c, conv_width) before the convolution, z (b, c, value
-    width), beta (b, c, v_heads) and g (b, c, v_heads) or (b, c, v_heads,
-    k_dim) float32) of ``x`` (b, c, d)."""
+    width), beta (b, c, v_heads; ``dt`` of a state-space layer) and g (b, c,
+    v_heads) or (b, c, v_heads, k_dim) float32) of ``x`` (b, c, d)."""
     hold = jax.lax.optimization_barrier if cached else (lambda y: y)
     qkv = hold(cfg_matmul(cfg, x, p["w_qkv"]))
     z = hold(cfg_matmul(cfg, x, p["w_z"]))
@@ -106,6 +130,9 @@ def _inputs(p, x, cfg, cached):
 
     lin = cfg.linear
     h = lin.v_heads
+    if lin.rule == "ssd":
+        dt = jax.nn.softplus(wide(p["w_dt"]) + _f32(p["dt_bias"]))
+        return qkv, z, dt, -jnp.exp(_f32(p["A_log"])) * dt
     if lin.decay == "channel":
         f = hold(wide(p["w_f"])).reshape(x.shape[:2] + (h, lin.k_dim))
         g = lin.lower_bound * jax.nn.sigmoid(
@@ -119,13 +146,17 @@ def _inputs(p, x, cfg, cached):
     return qkv, z, beta, g
 
 
-def _convolve(tail, qkv, w, rows):
-    """The causal depthwise convolution and SiLU of ``qkv`` (b, c, ch)
-    behind the sequence's last rows ``tail`` (b, conv - 1, ch): (float32
-    (b, c, ch), the new tail: the ``conv - 1`` rows that end at row
-    ``rows`` of the chunk; ``rows = 0`` hands the old tail back)."""
+def _convolve(tail, qkv, w, rows, bias=None):
+    """The causal depthwise convolution (plus ``bias`` (ch,) where the layer
+    has one) and SiLU of ``qkv`` (b, c, ch) behind the sequence's last rows
+    ``tail`` (b, conv - 1, ch): (float32 (b, c, ch), the new tail: the
+    ``conv - 1`` rows that end at row ``rows`` of the chunk; ``rows = 0``
+    hands the old tail back)."""
     k = w.shape[0]
     c = qkv.shape[1]
+
+    def act(acc):
+        return jax.nn.silu(acc if bias is None else acc + _f32(bias))
     if c == 1:
         # a decode step: the tail moves on by its one row or stays, and no
         # array of ``conv`` rows is built.  (Slices of the two put together,
@@ -135,22 +166,30 @@ def _convolve(tail, qkv, w, rows):
         acc = sum(_f32(tail[:, j:j + 1]) * _f32(w[j]) for j in range(k - 1)) \
             + _f32(qkv) * _f32(w[k - 1])
         moved = jnp.concatenate([tail[:, 1:], qkv], axis=1)
-        return jax.nn.silu(acc), jnp.where((rows > 0)[:, None, None], moved,
-                                           tail)
+        return act(acc), jnp.where((rows > 0)[:, None, None], moved,
+                                   tail)
     seq = jnp.concatenate([tail, qkv], axis=1)           # (b, c + k - 1, ch)
     acc = sum(_f32(seq[:, j:j + c]) * _f32(w[j]) for j in range(k))
     new_tail = jax.vmap(lambda s, r: jax.lax.dynamic_slice_in_dim(
         s, r, k - 1, axis=0))(seq, rows)
-    return jax.nn.silu(acc), new_tail
+    return act(acc), new_tail
 
 
 def _heads(act, cfg):
     """q, k (b, c, v_heads, k_dim) normalised (q scaled), each key head
     repeated for its value heads, and v (b, c, v_heads, v_dim), float32,
-    from the convolved ``act`` (b, c, conv_width)."""
+    from the convolved ``act`` (b, c, conv_width).  A state-space layer's
+    ``C`` and ``B`` come as they are, one a GROUP (b, c, k_heads, k_dim),
+    from ``[x | B | C]``."""
     lin = cfg.linear
     b, c, _ = act.shape
     kw = lin.key_width
+    if lin.rule == "ssd":
+        vw = lin.value_width
+        groups = (b, c, lin.k_heads, lin.k_dim)
+        return (act[..., vw + kw:].reshape(groups),
+                act[..., vw:vw + kw].reshape(groups),
+                act[..., :vw].reshape(b, c, lin.v_heads, lin.v_dim))
 
     def unit(y):
         y = y.reshape(b, c, lin.k_heads, lin.k_dim)
@@ -163,11 +202,19 @@ def _heads(act, cfg):
     return q, k, v
 
 
-def _output(p, o, z, cfg):
+def _output(p, o, z, cfg, x=None):
     """``(rms(o) * w_n * gate(z)) W_o``: o (b, c, v_heads, v_dim) float32,
-    z (b, c, value width); the gate ``silu`` or ``sigmoid``."""
+    z (b, c, value width); the gate ``silu`` or ``sigmoid``.  A state-space
+    layer: ``(rms((o + D x) * silu(z)) * w_n) W_o``, the norm over all heads
+    at once and AFTER the gate (``x``: the heads' inputs, as ``o``)."""
     lin = cfg.linear
     b, c = o.shape[:2]
+    if lin.rule == "ssd":
+        y = (o + _f32(p["D"])[:, None] * x).reshape(b, c, -1) \
+            * jax.nn.silu(_f32(z))
+        y = y * jax.lax.rsqrt((y * y).mean(-1, keepdims=True) + cfg.eps) \
+            * _f32(p["norm"])
+        return cfg_matmul(cfg, y.astype(z.dtype), p["wo"])
     o = o * jax.lax.rsqrt((o * o).mean(-1, keepdims=True) + cfg.eps) \
         * _f32(p["norm"])
     gate = jax.nn.silu if lin.gate == "silu" else jax.nn.sigmoid
@@ -176,15 +223,17 @@ def _output(p, o, z, cfg):
 
 
 # -------------------------------------------------------------- two orders
-def recurrent_step(state, q, k, v, beta, g):
+def recurrent_step(state, q, k, v, beta, g, correct=True):
     """One row a lane by the rule as written: state (b, h, dk, dv); q, k
-    (b, h, dk); v (b, h, dv); beta (b, h); g (b, h) or (b, h, dk).  Returns
-    (o (b, h, dv), the new state)."""
+    (b, h, dk); v (b, h, dv); beta (b, h); g (b, h) or (b, h, dk).
+    ``correct`` false: the state-space rule, ``d = beta v``.  Returns (o (b,
+    h, dv), the new state)."""
     decay = jnp.exp(g)
     state = state * (decay[..., None] if g.ndim == 3
                      else decay[..., None, None])
-    kv = jnp.einsum("bhkv,bhk->bhv", state, k, precision=_HI)
-    d = beta[..., None] * (v - kv)
+    kv = jnp.einsum("bhkv,bhk->bhv", state, k, precision=_HI) \
+        if correct else None
+    d = beta[..., None] * (v if kv is None else v - kv)
     state = state + k[..., :, None] * d[..., None, :]
     return jnp.einsum("bhkv,bhk->bhv", state, q, precision=_HI), state
 
@@ -242,24 +291,25 @@ def _pair_products(q, k, gam):
     return jnp.concatenate(kk, axis=-2), jnp.concatenate(qk, axis=-2)
 
 
-def chunk_terms(q, k, v, beta, g):
+def chunk_terms(q, k, v, beta, g, correct=True, chunk=CHUNK):
     """What the sequential pass of the chunked rule reads, for all inner
     chunks at once: q, k (b, L, h, dk), v (b, L, h, dv), beta (b, L, h), g
-    (b, L, h) or (b, L, h, dk), L a multiple of ``CHUNK``.  Returns float32
+    (b, L, h) or (b, L, h, dk), L a multiple of ``chunk``.  Returns float32
     ``(W, U, Qg (b, h, n, C, .), Att (b, h, n, C, C), KdT (b, h, n, dk, C),
     decay (b, h, n) or (b, h, n, dk))``: ``V' = U - W S``; ``O = Qg S + Att
     V'``; ``S <- decay S + KdT V'`` (``decay`` by rows of ``S`` where it is
-    one a channel)."""
+    one a channel).  ``correct`` false (the state-space rule, one decay a
+    head): ``W`` is None, ``U = beta V`` and nothing is solved."""
     b, length, h, _ = q.shape
-    n = length // CHUNK
+    n = length // chunk
 
     def split(y):                       # (b, L, h, ...) -> (b, h, n, C, ...)
-        y = y.reshape((b, n, CHUNK, h) + y.shape[3:])
+        y = y.reshape((b, n, chunk, h) + y.shape[3:])
         return jnp.moveaxis(y, 3, 1)
 
     q, k, v, beta, g = (split(y) for y in (q, k, v, beta, g))
-    low = jnp.tril(jnp.ones((CHUNK, CHUNK), bool))
-    eye = jnp.eye(CHUNK, dtype=bool)
+    low = jnp.tril(jnp.ones((chunk, chunk), bool))
+    eye = jnp.eye(chunk, dtype=bool)
     if g.ndim == 5:
         gam = jnp.cumsum(g, axis=-2)                        # (b, h, n, C, dk)
         kk, qk = _pair_products(q, k, gam)
@@ -272,12 +322,16 @@ def chunk_terms(q, k, v, beta, g):
         gam = jnp.cumsum(g, axis=-1)                        # (b, h, n, C)
         diff = gam[..., :, None] - gam[..., None, :]        # gam_i - gam_j
         pair = jnp.exp(jnp.where(low, diff, -jnp.inf))      # 0 above diag
-        kk = jnp.einsum("bhnik,bhnjk->bhnij", k, k, precision=_HI)
-        a = beta[..., None] * kk * jnp.where(eye, 0.0, pair)
+        if correct:
+            kk = jnp.einsum("bhnik,bhnjk->bhnij", k, k, precision=_HI)
+            a = beta[..., None] * kk * jnp.where(eye, 0.0, pair)
         att = jnp.einsum("bhnik,bhnjk->bhnij", q, k, precision=_HI) * pair
         scale = jnp.exp(gam)[..., None]
         last = gam[..., -1:]
         to_last, decay = jnp.exp(last - gam)[..., None], jnp.exp(last[..., 0])
+    if not correct:
+        return (None, beta[..., None] * v, q * scale, att,
+                jnp.swapaxes(k * to_last, -1, -2), decay)
     rhs = jnp.concatenate([beta[..., None] * scale * k,
                            beta[..., None] * v], axis=-1)
     solved = solve_unit_lower(a, rhs)
@@ -291,7 +345,8 @@ def chunk_pass(state, terms):
     (b, h, dk, dv) -> (O (b, h, n, C, dv), the state after the last)."""
     def body(s, t):
         w, u, qg, att, kdt, decay = t
-        vp = u - jnp.einsum("bhck,bhkv->bhcv", w, s, precision=_HI)
+        vp = u if w is None else \
+            u - jnp.einsum("bhck,bhkv->bhcv", w, s, precision=_HI)
         o = jnp.einsum("bhck,bhkv->bhcv", qg, s, precision=_HI) \
             + jnp.einsum("bhij,bhjv->bhiv", att, vp, precision=_HI)
         s = (decay[..., None] if decay.ndim == 3
@@ -300,7 +355,8 @@ def chunk_pass(state, terms):
         return s, o
 
     state, o = jax.lax.scan(
-        body, state, tuple(jnp.moveaxis(t, 2, 0) for t in terms))
+        body, state, tuple(None if t is None else jnp.moveaxis(t, 2, 0)
+                           for t in terms))
     return jnp.moveaxis(o, 0, 2), state
 
 
@@ -308,10 +364,33 @@ def chunk_pass(state, terms):
 def _kernel_scope(lin, order):
     """The scope a Pallas call of the rule runs under: the layer's own
     (``attn.linear``) for one decay a head, ``kda.<order>`` for one a
-    channel."""
+    channel, ``ssd.<order>`` for the state-space rule."""
+    if lin.rule == "ssd":
+        return jax.named_scope("ssd." + order)
     if lin.decay == "channel":
         return jax.named_scope("kda." + order)
     return contextlib.nullcontext()
+
+
+def _apart(state, lin):
+    """The stored state (s, v_heads / r, dk, r x dv) with its heads apart:
+    (s, v_heads, dk, dv)."""
+    r = lin.pack
+    if r == 1:
+        return state
+    s, packs, dk, _ = state.shape
+    return state.reshape(s, packs, dk, r, lin.v_dim).swapaxes(2, 3) \
+        .reshape(s, packs * r, dk, lin.v_dim)
+
+
+def _packed(state, lin):
+    """:func:`_apart`'s inverse."""
+    r = lin.pack
+    if r == 1:
+        return state
+    s, h, dk, dv = state.shape
+    return state.reshape(s, h // r, r, dk, dv).swapaxes(2, 3) \
+        .reshape(s, h // r, dk, r * dv)
 
 
 def linear_paged_chunk_step(p, x, state, tail, cfg, rows, slots=None,
@@ -320,8 +399,9 @@ def linear_paged_chunk_step(p, x, state, tail, cfg, rows, slots=None,
     state: ``attention.mha_paged_chunk_step`` for the kind that holds no
     pages.
 
-    x: (b, c, d); state: (slots, v_heads, k_dim, v_dim) float32 and tail:
-    (slots, conv - 1, conv_width), one slot a lane of the engine; ``rows``
+    x: (b, c, d); state: (slots, v_heads, k_dim, v_dim) float32 (a
+    state-space layer's: ``linear.pack`` heads to a row) and tail: (slots,
+    conv - 1, conv_width), one slot a lane of the engine; ``rows``
     (b,) int32: how many of a lane's ``c`` rows are real (the others move
     nothing; 0: the lane's slot comes back bit for bit); ``slots`` (b,)
     int32: each lane's slot, or None where lane ``i`` IS slot ``i``.  One
@@ -343,9 +423,19 @@ def linear_paged_chunk_step(p, x, state, tail, cfg, rows, slots=None,
         mine = tail if slots is None else tail[slots]
         if fresh is not None:
             mine = jnp.where(fresh[:, None, None], 0, mine).astype(tail.dtype)
-        act, new_tail = _convolve(mine, qkv, p["conv"], rows)
+        # (a layer without a bias calls with four arguments, as the
+        # benchmark's planted faults wrap it)
+        act, new_tail = _convolve(
+            mine, qkv, p["conv"], rows,
+            **({"bias": p["conv_bias"]} if "conv_bias" in p else {}))
         tail = new_tail if slots is None else tail.at[slots].set(new_tail)
         q, k, v = _heads(act, cfg)
+        ssd = lin.rule == "ssd"
+        if ssd and not attn_kernel:
+            # a group's C and B for each of its heads (the kernels take
+            # them one a group)
+            q, k = (jnp.repeat(y, lin.v_heads // y.shape[2], axis=2)
+                    for y in (q, k))
         if c == 1:
             if attn_kernel:
                 from veles_tpu.ops import pallas_kernels as PK
@@ -354,36 +444,49 @@ def linear_paged_chunk_step(p, x, state, tail, cfg, rows, slots=None,
                 with _kernel_scope(lin, "decode"):
                     o, state = PK.gdn_decode(
                         state, q[:, 0], k[:, 0], v[:, 0], beta[:, 0],
-                        g[:, 0], rows > 0)
+                        g[:, 0], rows > 0, correct=not ssd)
             else:
-                s0 = state if slots is None else state[slots]
+                s0 = _apart(state if slots is None else state[slots], lin)
                 o, s1 = recurrent_step(s0, q[:, 0], k[:, 0], v[:, 0],
-                                       beta[:, 0], g[:, 0])
-                s1 = jnp.where((rows > 0)[:, None, None, None], s1, s0)
+                                       beta[:, 0], g[:, 0], correct=not ssd)
+                s1 = _packed(
+                    jnp.where((rows > 0)[:, None, None, None], s1, s0), lin)
                 state = s1 if slots is None else state.at[slots].set(s1)
             o = o[:, None]
         else:
-            pad = -c % CHUNK
+            inner = SSD_CHUNK if ssd else CHUNK
+            pad = -c % inner
+            rule = (q, k, v, beta, g)
             if pad:
-                q, k, v, beta, g = (jnp.pad(
+                rule = tuple(jnp.pad(
                     y, [(0, 0), (0, pad)] + [(0, 0)] * (y.ndim - 2))
-                    for y in (q, k, v, beta, g))
-            terms = chunk_terms(q, k, v, beta, g)
+                    for y in rule)
+            # (the chunk kernel of the state-space rule takes the rows as
+            # they are and makes its own terms)
+            terms = None if ssd and attn_kernel else chunk_terms(
+                *rule, correct=not ssd, chunk=inner)
             ids = jnp.arange(b) if slots is None else slots
             if fresh is None:
                 fresh = jnp.zeros((b,), bool)
             if attn_kernel:
                 from veles_tpu.ops import pallas_kernels as PK
                 with _kernel_scope(lin, "chunk"):
-                    o, state = PK.gdn_chunk(state, ids, fresh, *terms)
+                    if ssd:
+                        o, state = PK.ssd_chunk(state, ids, fresh, *rule,
+                                                chunk=inner)
+                    else:
+                        o, state = PK.gdn_chunk(state, ids, fresh, *terms)
             else:
-                s0 = jnp.where(fresh[:, None, None, None], 0.0, state[ids])
+                s0 = jnp.where(fresh[:, None, None, None], 0.0,
+                               _apart(state[ids], lin))
                 o, s1 = chunk_pass(s0, terms)
-                state = state.at[ids].set(s1)
-            # (b, h, n, C, dv) -> (b, c, h, dv)
-            o = jnp.moveaxis(o, 1, 3).reshape(
-                b, c + pad, lin.v_heads, lin.v_dim)[:, :c]
-        return _output(p, o, z, cfg), state, tail
+                state = state.at[ids].set(_packed(s1, lin))
+            if terms is not None:
+                # (b, h, n, C, dv) -> (b, c, h, dv)
+                o = jnp.moveaxis(o, 1, 3).reshape(
+                    b, c + pad, lin.v_heads, lin.v_dim)
+            o = o[:, :c]
+        return _output(p, o, z, cfg, v if ssd else None), state, tail
 
 
 def linear_forward(p, x, cfg):

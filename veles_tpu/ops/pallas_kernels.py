@@ -1562,12 +1562,16 @@ def grouped_matmul(xs, w, sizes, tm=_GMM_ROWS, interpret=None):
 # ``ops/linear_attn.py``'s two orders on the lanes' recurrent state
 # ``(slots, heads, k_dim, v_dim)`` float32, taken aliased in and out: a call
 # touches the slots it is told to and no other, and what it does not touch
-# keeps its bytes.
+# keeps its bytes.  The state-space rule (the same recurrence without the
+# delta correction) steps through ``gdn_decode`` with ``correct`` false and
+# has a chunk kernel of its own, ``ssd_chunk``: its heads share their keys
+# and queries by group, which the delta rule's never do.
 
 _GDN_VMEM = 48 << 20
 
 
-def gdn_decode(state, q, k, v, beta, g, active, interpret=None):
+def gdn_decode(state, q, k, v, beta, g, active, correct=True,
+               interpret=None):
     """The recurrent rule for ONE row a lane, on the lanes that decode:
     state (b, h, dk, dv); q, k (b, h, dk); v (b, h, dv); beta (b, h); g (b,
     h), one decay a head, or (b, h, dk), one a key channel (a row of ``S``),
@@ -1575,6 +1579,13 @@ def gdn_decode(state, q, k, v, beta, g, active, interpret=None):
     d = beta (v - S^T k); S <- S + k d^T; o = S^T q``
     (``linear_attn.recurrent_step``, sum for sum).  Returns (o (b, h, dv),
     zeros for a lane that is not active; the state).
+
+    ``correct`` false (static) is the state-space rule: ``d = beta v``.  Its
+    state lies PACKED, ``r`` heads side by side in a row (state (b, h / r,
+    dk, r dv); ``r`` read off the shapes), and q, k come one a group (b,
+    groups, dk): the ``r`` heads of a row share them, so the row is decayed,
+    updated and summed as ONE head of ``r dv`` values whose decay and
+    ``beta`` differ by column.
 
     The grid walks the ACTIVE lanes first (their indices prefetched, in
     order) and a step takes one lane's whole state, 2 MiB at 32 heads of
@@ -1596,13 +1607,17 @@ def gdn_decode(state, q, k, v, beta, g, active, interpret=None):
     # k and q as columns (k_dim on sublanes, a head a lane), v and the
     # scalars of a head as rows; a decay per channel is a column too
     per_channel = g.ndim == 3
+    if q.shape[1] != h:
+        q, k = (jnp.repeat(y, h // y.shape[1], axis=1) for y in (q, k))
     cols = [jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2)]
     rows = [v, jnp.broadcast_to(beta[..., None], v.shape)]
     if per_channel:
         cols.append(jnp.swapaxes(jnp.exp(g), 1, 2))
     else:
         rows.insert(1, jnp.broadcast_to(jnp.exp(g)[..., None], v.shape))
-    cols, rows = jnp.stack(cols, axis=1), jnp.stack(rows, axis=1)
+    cols = jnp.stack(cols, axis=1)
+    # (the heads of a packed row lie side by side, as their values do)
+    rows = jnp.stack(rows, axis=1).reshape(b, len(rows), h, dv)
     n_cols, n_rows = cols.shape[1], rows.shape[1]
 
     def kernel(ids_ref, n_ref, s_ref, c_ref, r_ref, o_ref, so_ref):
@@ -1616,9 +1631,10 @@ def gdn_decode(state, q, k, v, beta, g, active, interpret=None):
                 ve = r_ref[0, 0, e:e + 1, :]                  # (1, dv)
                 s = s_ref[0, e] * (c_ref[0, 2, :, e:e + 1] if per_channel
                                    else r_ref[0, 1, e:e + 1, :])
-                d = r_ref[0, n_rows - 1, e:e + 1, :] * (
-                    ve - (s * kc).sum(axis=0, keepdims=True))
-                s = s + kc * d
+                beta_e = r_ref[0, n_rows - 1, e:e + 1, :]
+                if correct:
+                    ve = ve - (s * kc).sum(axis=0, keepdims=True)
+                s = s + kc * (beta_e * ve)
                 so_ref[0, e] = s
                 o_ref[0, e:e + 1, :] = (s * qc).sum(axis=0, keepdims=True)
 
@@ -1650,7 +1666,7 @@ def gdn_decode(state, q, k, v, beta, g, active, interpret=None):
             vmem_limit_bytes=_GDN_VMEM),
         interpret=_interpret(interpret),
     )(ids, n.reshape(1), state, cols, rows)
-    return jnp.where(active[:, None, None], o, 0.0), state
+    return jnp.where(active[:, None, None], o, 0.0).reshape(v.shape), state
 
 
 def gdn_chunk(state, slots, fresh, w, u, qg, att, kdt, decay,
@@ -1736,3 +1752,131 @@ def gdn_chunk(state, slots, fresh, w, u, qg, att, kdt, decay,
         interpret=_interpret(interpret),
     )(jnp.asarray(slots, jnp.int32), jnp.asarray(fresh, jnp.int32),
       state, w, u, qg, att, kdt, decay)
+
+
+def ssd_chunk(state, slots, fresh, q, k, v, beta, g, chunk, interpret=None):
+    """The chunked state-space rule (``linear_attn``: the rule without the
+    delta correction) over inner chunks of ``chunk`` rows, on the PACKED
+    state: state (slots, h / r, dk, r dv) float32, ``r`` heads of a group
+    side by side in a row; q, k (b, L, groups, dk): a group's ``C`` and
+    ``B``; v (b, L, h, dv); beta (``dt``) and g (b, L, h), all float32, L a
+    multiple of ``chunk``; ``slots`` (b,) the lanes' slots, ``fresh`` (b,)
+    bool (the slot's state is read as zeros).  Per lane and row of heads,
+    for each inner chunk in order, with ``gam`` the running sum of ``g`` in
+    the chunk and ``gam_Q`` its last::
+
+        O = exp(gam) (C S) + tril((C B^T) exp(gam_i - gam_j)) (beta V)
+        S <- exp(gam_Q) S + B^T (exp(gam_Q - gam) beta V)
+
+    (``linear_attn.chunk_terms`` / ``chunk_pass`` with ``correct`` false, sum
+    for sum but that the decay to the chunk's end multiplies ``V`` where
+    they scale ``B``).  ``C B^T`` and ``B^T`` are made once a chunk for all
+    the heads of a group; a grid step takes one row of ``r`` heads through
+    one inner chunk, the row's state (dk, r dv) in fast memory from the
+    first inner chunk to the last: ``C S`` and the state's update are one
+    matmul each for the ``r`` heads, the pairwise decays (which differ by
+    head) one each.  Returns (O (b, L, h, dv), the state with the lanes'
+    slots rewritten, in place)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, length, h, dv = v.shape
+    groups, dk = q.shape[2:]
+    packs = state.shape[1]
+    r, n = h // packs, length // chunk
+    wide = r * dv
+    hi = jax.lax.Precision.HIGHEST
+
+    def by_group(y):                    # (b, L, G, dk) -> (b, G, n, C, dk)
+        return jnp.moveaxis(y.reshape(b, n, chunk, groups, dk), 3, 1)
+
+    qn, kn = by_group(q), by_group(k)
+    cb = jnp.einsum("bgnik,bgnjk->bgnij", qn, kn, precision=hi)
+    kt = jnp.swapaxes(kn, -1, -2)                           # (b, G, n, dk, C)
+    gam = jnp.cumsum(g.reshape(b, n, chunk, packs, r), axis=2)
+    gcol = jnp.moveaxis(gam, 3, 1)                       # (b, packs, n, C, r)
+    grow = jnp.swapaxes(gcol, -1, -2)                    # (b, packs, n, r, C)
+    u = (beta[..., None] * v).reshape(b, length, h * dv)
+
+    def dot(x, y):
+        return jnp.dot(x, y, precision=hi,
+                       preferred_element_type=jnp.float32)
+
+    def kernel(slot_ref, fresh_ref, s_ref, q_ref, kt_ref, cb_ref, u_ref,
+               gc_ref, gr_ref, o_ref, so_ref, acc_ref):
+        t = pl.program_id(2)
+        old = fresh_ref[pl.program_id(0)] == 0
+
+        @pl.when(t == 0)
+        def _():
+            acc_ref[...] = jnp.where(old, s_ref[0, 0], 0.0)
+
+        s = acc_ref[...]
+        gc, gr = gc_ref[0, 0, 0], gr_ref[0, 0, 0]        # (C, r), (r, C)
+        ub, cbm = u_ref[0], cb_ref[0, 0, 0]
+        head = jax.lax.broadcasted_iota(jnp.int32, (1, wide), 1) // dv
+
+        def lanes(col):                 # (rows, r) -> (rows, r dv)
+            out = col[:, 0:1]
+            for a in range(1, r):
+                out = jnp.where(head == a, col[:, a:a + 1], out)
+            return out
+
+        low = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) \
+            >= jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+        o = lanes(jnp.exp(gc)) * dot(q_ref[0, 0, 0], s)
+        for a in range(r):
+            pair = jnp.exp(jnp.where(
+                low, gc[:, a:a + 1] - gr[a:a + 1, :], -jnp.inf))
+            o = o + dot(cbm * pair,
+                        ub if r == 1 else jnp.where(head == a, ub, 0.0))
+        o_ref[0] = o
+        last = gc[chunk - 1:chunk, :]
+        s = lanes(jnp.exp(last)) * s \
+            + dot(kt_ref[0, 0, 0], ub * lanes(jnp.exp(last - gc)))
+        acc_ref[...] = s
+
+        @pl.when(t == n - 1)
+        def _():
+            so_ref[0, 0] = s
+
+    per_group = packs // groups
+
+    def slot(i, j, t, sl, fr):
+        return (sl[i], j, 0, 0)
+
+    def shared(i, j, t, *_):
+        return (i, j // per_group, t, 0, 0)
+
+    def own(i, j, t, *_):
+        return (i, j, t, 0, 0)
+
+    def rows(i, j, t, *_):
+        return (i, t, j)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, packs, n),
+        in_specs=[pl.BlockSpec((1, 1, dk, wide), slot),
+                  pl.BlockSpec((1, 1, 1, chunk, dk), shared),
+                  pl.BlockSpec((1, 1, 1, dk, chunk), shared),
+                  pl.BlockSpec((1, 1, 1, chunk, chunk), shared),
+                  pl.BlockSpec((1, chunk, wide), rows),
+                  pl.BlockSpec((1, 1, 1, chunk, r), own),
+                  pl.BlockSpec((1, 1, 1, r, chunk), own)],
+        out_specs=(pl.BlockSpec((1, chunk, wide), rows),
+                   pl.BlockSpec((1, 1, dk, wide), slot)),
+        scratch_shapes=[pltpu.VMEM((dk, wide), jnp.float32)],
+    )
+    o, state = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=(jax.ShapeDtypeStruct((b, length, h * dv), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)),
+        input_output_aliases={2: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_GDN_VMEM),
+        interpret=_interpret(interpret),
+    )(jnp.asarray(slots, jnp.int32), jnp.asarray(fresh, jnp.int32),
+      state, qn, kt, cb, u, gcol, grow)
+    return o.reshape(b, length, h, dv), state

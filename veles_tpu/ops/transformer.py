@@ -116,11 +116,16 @@ def _wire(blk, h, cfg, layer, attend, with_aux=False, token_mask=None):
             return _block_ffn(blk, normed.astype(cfg.dtype), cfg, layer,
                               normed)
 
+        def joined(h, out):
+            out = out.astype(h.dtype)
+            return h + (out if cfg.residual_mult == 1.0
+                        else cfg.residual_mult * out)
+
         if cfg.hyper is None:
             out, state = attn_half(h)
-            h = h + out.astype(h.dtype)
+            h = joined(h, out)
             ff, stats = ffn_half(h)
-            return h + ff.astype(h.dtype), state, stats
+            return joined(h, ff), state, stats
         h, state = hyper.around(blk["hc_attn"], h, cfg, attn_half)
         h, stats = hyper.around(blk["hc_mlp"], h, cfg, ffn_half)
         return h, state, stats
@@ -210,8 +215,11 @@ def _scaled_embed(params, tokens, cfg):
     import jax.numpy as jnp
     h = jnp.take(params["embed"], tokens, axis=0)
     if cfg is not None and cfg.block == "pre_rms":
-        # float32 too, unscaled; copied into the residual's streams
+        # float32 too, unscaled but for a record that states a multiplier;
+        # copied into the residual's streams
         h = h.astype(jnp.float32)
+        if cfg.embed_mult is not None:
+            h = h * cfg.embed_mult
         if cfg.hyper is None:
             return h
         from veles_tpu.ops import hyper
@@ -244,6 +252,12 @@ def head_logits(params, h, cfg=None):
     if cfg is not None and cfg.wide:
         h = rms_norm(h, params["ln_f"], cfg.eps, cfg.dtype,
                      cfg.norm_centred)
+        if cfg.tied:
+            # the embedding as it lies, contracted over its columns: no
+            # transposed copy of the vocabulary's rows
+            return jnp.einsum("...d,vd->...v", h, params["embed"],
+                              preferred_element_type=jnp.float32) \
+                / cfg.logits_div
         return jnp.matmul(h, params["head"],
                           preferred_element_type=jnp.float32)
     h = _layernorm(h, params["ln_f"]["g"], params["ln_f"]["b"])
