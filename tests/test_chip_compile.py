@@ -741,6 +741,21 @@ def test_ssd_kernels_compile_at_the_cells_widths(one_chip):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("rows", [64, 1024], ids=["step", "chunk"])
+def test_gated_norm_kernel_compiles_at_the_cells_widths(one_chip, rows):
+    """ISSUE 47: the state-space layer's gated norm over 4096 channels, the
+    heads' inputs read out of the convolution's 4352: a decode step's 64
+    rows (one block) and a chunk's 1024 (blocks of ``_NORM_ROWS``, both
+    buffers of every operand under the kernel's own limit)."""
+    text = compile_for(
+        one_chip,
+        lambda o, x, z, d, w: PK.gated_rms_norm(o, x, z, d, w, 1e-5,
+                                                interpret=False),
+        ((rows, 4096), F32), ((rows, 4352), F32), ((rows, 4096), BF16),
+        ((1, 4096), F32), ((1, 4096), F32))
+    assert "tpu_custom_call" in text
+
+
 @pytest.fixture(scope="module")
 def ssd_engine(one_chip):
     """A small ``LMEngine`` for two state-space layers around one plain
@@ -802,6 +817,22 @@ def test_ssd_programs_update_state_and_pool_in_place(ssd_engine, program):
                        r'op_name="[^"]*attn\.linear/%s/pallas_call'
                        % re.escape(scope), text)
     assert len(calls) == 2
+
+
+@pytest.mark.parametrize("program", ["chunk", "decode_w8"])
+def test_ssd_programs_call_one_rule_kernel_and_one_norm_a_layer(ssd_engine,
+                                                                program):
+    """ISSUE 47: the benchmark's two rooflines count the operations named
+    ``ssd ...`` as ONE call a Mamba layer and dispatch, so the gated norm's
+    kernel runs under a scope of its own: a program holds one custom call
+    under ``ssd.chunk`` (the step: ``ssd.decode``) and one under
+    ``norm.gated`` for each of the two Mamba layers, and no other whose
+    innermost scope starts with ``ssd.``."""
+    from veles_tpu.serving.lm_engine import compiled_kernel_scopes
+    scopes = compiled_kernel_scopes(program_text(ssd_engine, program))
+    rule = "ssd.chunk" if program == "chunk" else "ssd.decode"
+    assert sorted(s for s in scopes if s.startswith(("ssd.", "norm."))) \
+        == ["norm.gated"] * 2 + [rule] * 2
 
 
 @pytest.mark.parametrize("program", PROGRAMS)

@@ -232,6 +232,42 @@ def test_the_kernels_equal_their_twins(groups):
     assert bool((s[rest] == apart[rest]).all())
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("b, c", [(64, 1), (1, 300)],
+                         ids=["step", "ragged chunk"])
+def test_the_gated_norm_kernel_equals_its_twin(b, c, dtype):
+    """ISSUE 47: ``_output`` handed the convolution's whole output (what the
+    serving kernels' caller does) runs ``pallas_kernels.gated_rms_norm``, in
+    interpret mode here, and equals the ``jax.numpy`` branch on the heads'
+    inputs sliced out of it: a decode step's 64 rows in one block, and a
+    chunk whose 300 rows leave a last block of 44.  ``W_o`` is the identity,
+    so what is compared is the normalised row itself: float32 to 1e-6 of the
+    largest output (a skip that cancels ``o`` is rounded once where the
+    compiler fuses the multiply into the add), bfloat16 to one unit in the
+    last place."""
+    cfg = record(hidden_size=256, mamba_d_head=64, mamba_n_groups=1,
+                 mamba_d_state=128, dtype=dtype)
+    lin = cfg.linear
+    width = lin.value_width
+    assert (width, lin.conv_width) == (512, 768)
+    rng = numpy.random.default_rng(47)
+    p = {"D": normal(rng, lin.v_heads), "norm": 1 + 0.1 * normal(rng, width),
+         "wo": jnp.eye(width, dtype=dtype)}
+    act = normal(rng, b, c, lin.conv_width)
+    o = normal(rng, b, c, lin.v_heads, lin.v_dim)
+    z = normal(rng, b, c, width).astype(dtype)
+    want = numpy.asarray(linear_attn._output(
+        p, o, z, cfg, act[..., :width].reshape(o.shape)), numpy.float32)
+    got = linear_attn._output(p, o, z, cfg, act)
+    assert got.dtype == z.dtype and got.shape == want.shape
+    off = numpy.abs(numpy.asarray(got, numpy.float32) - want)
+    if dtype == "float32":
+        assert off.max() <= 1e-6 * numpy.abs(want).max()
+    else:
+        ulp = 2.0 ** (numpy.floor(numpy.log2(numpy.abs(want) + 1e-30)) - 7)
+        assert (off <= ulp).all()
+
+
 def test_a_padded_row_moves_no_state(weights):
     """A chunk of 8 rows of which 5 are real leaves the state and tail that
     the 5 rows alone leave, whatever ids lie behind them; ``rows`` 0 hands

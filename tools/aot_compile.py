@@ -224,6 +224,28 @@ def grouped_matmuls(name, text, eng, tokens):
                          % (name, (ragged, kernel), want))
 
 
+def state_space_calls(name, text, eng):
+    """Print the Pallas calls of a compiled program's state-space layers by
+    innermost scope (ISSUE 47) and exit non-zero unless each layer has ONE
+    under ``ssd.`` (the benchmark's two rooflines count the operations named
+    ``ssd ...`` as one a layer and dispatch) and one under ``norm.gated``."""
+    from veles_tpu import model_config
+    from veles_tpu.serving.lm_engine import compiled_kernel_scopes
+    lin = eng.cfg.linear
+    if lin is None or lin.rule != "ssd":
+        return
+    layers = sum(eng.cfg.kind(i) == model_config.LINEAR
+                 for i in range(len(eng.params["blocks"])))
+    scopes = compiled_kernel_scopes(text)
+    rule = sum(scope.startswith("ssd.") for scope in scopes)
+    norm = scopes.count("norm.gated")
+    print("%-34s %d state-space layers: ssd.* calls x%d  norm.gated x%d"
+          % (name, layers, rule, norm), flush=True)
+    if (rule, norm) != (layers, layers):
+        raise SystemExit("%s: %d ssd.* and %d norm.gated calls, expected %d "
+                         "of each" % (name, rule, norm, layers))
+
+
 def engine_programs(tag, eng, one_chip, widths):
     """Compile ``eng``'s chunk program and its decode program at
     ``widths`` of the page table; each must hold a Pallas kernel and
@@ -257,6 +279,7 @@ def engine_programs(tag, eng, one_chip, widths):
                     s(()), s(()), s(()), state)
     storage_in_place(name, text, eng)
     grouped_matmuls(name, text, eng, page)
+    state_space_calls(name, text, eng)
     for width in widths:
         name = "%s decode step width %d" % (tag, width)
         text = compile_(name, eng._step_jit, a_params, pools,
@@ -268,6 +291,7 @@ def engine_programs(tag, eng, one_chip, widths):
         storage_in_place(name, text, eng)
         grouped_matmuls(name, text, eng, slots * (eng.spec_k + 1
                                                   if eng._mtp else 1))
+        state_space_calls(name, text, eng)
 
 
 def lm(one_chip):
@@ -432,7 +456,8 @@ def linear(one_chip, config="qwen3-next-80b-a3b-ep4", tag="engine linear"):
     a decay a key channel, the full layer latent (one pool), 512 assignment
     rows a decode step.  ``ssd`` (ISSUE 46) for ``granite-4.0-h-micro``: all
     40 layers, 36 of them the state-space rule on a packed state, no expert
-    layer, a tied head over 100352 rows."""
+    layer, a tied head over 100352 rows; each program's ``ssd.*`` and
+    ``norm.gated`` calls are counted against the 36 (ISSUE 47)."""
     import importlib
     import json
     from veles_tpu import model_config

@@ -45,7 +45,15 @@ pack, k_dim, pack x v_dim)``): the functions here see heads apart
 (``_apart`` / ``_packed``), the kernels take the rows as they lie.  Chunked,
 ``U = beta V``, ``W`` is absent and nothing is solved; the inner chunk is
 ``SSD_CHUNK`` rows, and ``pallas_kernels.ssd_chunk`` computes ``C B^T`` and the
-key-side products once a chunk for all of a group's heads.
+key-side products once a chunk for all of a group's heads.  The gated norm
+``rms((o + D x) * silu(z)) * w_n`` is ``jax.numpy`` here (``_output``: the
+CPU, the float32 tests, ``linear_forward``); with the serving kernels, in
+the step and the chunk program alike, it is ONE call,
+``pallas_kernels.gated_rms_norm``, which reads ``o``, ``z`` and the heads'
+inputs (out of the convolution's output, where they lie) once and keeps the
+gated row in fast memory between its sum of squares and its scale (the
+compiler made two fusions of it and computed the gate in each: PERF.md
+section 6, PR 47).
 
 WHAT A SEQUENCE KEEPS is ``S`` of every value head (float32) and the last ``conv -
 1`` rows of ``[q | k | v]`` BEFORE the convolution, whatever its length.
@@ -87,7 +95,8 @@ Everything here runs under the scope ``attn.linear``; the Pallas calls of a
 decay per channel under ``kda.decode`` / ``kda.chunk`` inside it (a call
 takes its innermost scope's name, and the benchmark's readers tell the
 rules' kernels apart by it), those of the state-space rule under ``ssd.decode``
-/ ``ssd.chunk``.
+/ ``ssd.chunk`` and its gated norm under ``norm.gated`` (NOT under ``ssd.``:
+the readers count the calls named ``ssd`` as one a layer and dispatch).
 """
 
 from __future__ import annotations
@@ -206,10 +215,25 @@ def _output(p, o, z, cfg, x=None):
     """``(rms(o) * w_n * gate(z)) W_o``: o (b, c, v_heads, v_dim) float32,
     z (b, c, value width); the gate ``silu`` or ``sigmoid``.  A state-space
     layer: ``(rms((o + D x) * silu(z)) * w_n) W_o``, the norm over all heads
-    at once and AFTER the gate (``x``: the heads' inputs, as ``o``)."""
+    at once and AFTER the gate (``x``: the heads' inputs, as ``o``).  With
+    the serving kernels ``x`` is the convolution's whole output (b, c, conv
+    width), whose first channels the heads' inputs are, and the gated norm
+    is ONE call, ``pallas_kernels.gated_rms_norm``, under the scope
+    ``norm.gated`` (told by ``x``'s rank and not by a sixth argument: the
+    benchmark's planted faults wrap this function with these five)."""
     lin = cfg.linear
     b, c = o.shape[:2]
     if lin.rule == "ssd":
+        if x.ndim == 3:
+            from veles_tpu.ops import pallas_kernels as PK
+            rows = b * c
+            with jax.named_scope("norm.gated"):
+                y = PK.gated_rms_norm(
+                    o.reshape(rows, -1), x.reshape(rows, -1),
+                    z.reshape(rows, -1),
+                    jnp.repeat(_f32(p["D"]), lin.v_dim)[None],
+                    _f32(p["norm"])[None], cfg.eps).reshape(b, c, -1)
+            return cfg_matmul(cfg, y, p["wo"])
         y = (o + _f32(p["D"])[:, None] * x).reshape(b, c, -1) \
             * jax.nn.silu(_f32(z))
         y = y * jax.lax.rsqrt((y * y).mean(-1, keepdims=True) + cfg.eps) \
@@ -486,7 +510,10 @@ def linear_paged_chunk_step(p, x, state, tail, cfg, rows, slots=None,
                 o = jnp.moveaxis(o, 1, 3).reshape(
                     b, c + pad, lin.v_heads, lin.v_dim)
             o = o[:, :c]
-        return _output(p, o, z, cfg, v if ssd else None), state, tail
+        # (the gated norm's kernel reads the heads' inputs where the
+        # convolution left them)
+        x = None if not ssd else act if attn_kernel else v
+        return _output(p, o, z, cfg, x), state, tail
 
 
 def linear_forward(p, x, cfg):

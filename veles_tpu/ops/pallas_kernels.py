@@ -1880,3 +1880,69 @@ def ssd_chunk(state, slots, fresh, q, k, v, beta, g, chunk, interpret=None):
     )(jnp.asarray(slots, jnp.int32), jnp.asarray(fresh, jnp.int32),
       state, qn, kt, cb, u, gcol, grow)
     return o.reshape(b, length, h, dv), state
+
+
+# --------------------------------------- the state-space layer's gated norm
+#: rows of one block: 2 MB of each float32 operand, 12 MB with both buffers
+_NORM_ROWS = 128
+_NORM_VMEM = 32 << 20
+#: the chip's lanes: the width of one tile of a row's sum of squares
+_LANES = 128
+
+
+def gated_rms_norm(o, x, z, d, w, eps, interpret=None):
+    """``rms((o + d x) * silu(z)) * w``, the state-space layer's gated norm
+    (``linear_attn._output``'s ``rule == "ssd"`` branch, sum for sum but for
+    the order of a row's additions), every row read once and nothing of the
+    gated row kept in memory between the statistic and the scale: o (rows,
+    width) float32; x (rows, >= width) float32, of which the first ``width``
+    channels are read where they lie (the convolution's whole output is
+    handed in, not a slice of it); z (rows, width) in the model's dtype; d, w
+    (1, width) float32.  Returns (rows, width) in ``z``'s dtype.
+
+    The grid walks blocks of ``_NORM_ROWS`` rows (a decode step's 64 are one
+    block); a row's squares are added tile on tile over its ``width / 128``
+    lane tiles and reduced across lanes once.  A padded or idle row is
+    normalised like any other."""
+    rows, width = o.shape
+    return _gated_norm_call(rows, width, min(_NORM_ROWS, rows), z.dtype,
+                            eps, _interpret(interpret))(o, x, z, d, w)
+
+
+@functools.lru_cache(maxsize=16)
+def _gated_norm_call(rows, width, tm, dtype, eps, interpret):
+    """:func:`gated_rms_norm`'s Pallas call for ``rows`` rows in blocks of
+    ``tm``, KEPT for every set of sizes as ``_flash_walk_call`` is: the 36
+    layers of a program trace and lower the kernel once, not once each
+    (set-up time)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(o_ref, x_ref, z_ref, d_ref, w_ref, y_ref):
+        zf = z_ref[...].astype(jnp.float32)
+        y = (o_ref[...] + d_ref[...] * x_ref[...]) * jax.nn.silu(zf)
+        sq = y * y
+        tile = _LANES if width % _LANES == 0 else width
+        tiles = sq[:, :tile]
+        for t in range(tile, width, tile):
+            tiles = tiles + sq[:, t:t + tile]
+        mean = tiles.sum(axis=-1, keepdims=True) / width
+        y_ref[...] = (y * jax.lax.rsqrt(mean + eps)
+                      * w_ref[...]).astype(y_ref.dtype)
+
+    def block(i):
+        return (i, 0)
+
+    def whole(i):
+        return (0, 0)
+
+    return pl.pallas_call(
+        kernel, grid=(pl.cdiv(rows, tm),),
+        in_specs=[pl.BlockSpec((tm, width), block)] * 3
+        + [pl.BlockSpec((1, width), whole)] * 2,
+        out_specs=pl.BlockSpec((tm, width), block),
+        out_shape=jax.ShapeDtypeStruct((rows, width), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_NORM_VMEM),
+        interpret=interpret)

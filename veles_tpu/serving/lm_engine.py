@@ -507,6 +507,18 @@ def compiled_grouped_matmuls(text):
     return len(ragged), len(kernel)
 
 
+def compiled_kernel_scopes(text):
+    """The innermost ``jax.named_scope`` of every Pallas call in one
+    COMPILED engine program's text, in order: the name the device trace
+    gives the call, and what the benchmark's readers find a kernel by (a
+    roofline that counts the calls named ``ssd`` as one a layer must find
+    one: ISSUE 47)."""
+    import re
+    return re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*'
+        r'op_name="[^"]*?([\w.]+)/pallas_call', text)
+
+
 def _hlo_shape(leaf):
     """``leaf``'s shape and dtype as compiled text writes them."""
     name = {"float32": "f32", "bfloat16": "bf16", "float16": "f16"}[
